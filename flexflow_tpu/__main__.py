@@ -59,10 +59,6 @@ def main(argv=None):
 
     env_args = shlex.split(os.environ.get("FF_LAUNCH_ARGS", ""))
     flexflow_tpu._launch_config = FFConfig.parse_args(env_args + launcher_args)
-    if os.environ.get("FLEXFLOW_PLATFORM"):
-        import jax
-
-        jax.config.update("jax_platforms", os.environ["FLEXFLOW_PLATFORM"])
     sys.argv = [script] + script_args
     runpy.run_path(script, run_name="__main__")
     return 0

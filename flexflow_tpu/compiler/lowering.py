@@ -108,6 +108,7 @@ def build_forward(
                           state=dict(state),
                           compute_dtype=str(cast_to) if cast_to else None,
                           mesh=mesh, op_attrs=op_attrs,
+                          op_shardings=strategy.op_shardings,
                           enable_fusion=enable_fusion)
         env: Dict[int, jax.Array] = {}
         for t, arr in zip(graph_inputs, input_arrays):
@@ -149,6 +150,7 @@ def build_forward(
                         state=l_state,
                         compute_dtype=str(cast_to) if cast_to else None,
                         mesh=mesh, op_attrs=op_attrs,
+                        op_shardings=strategy.op_shardings,
                         enable_fusion=enable_fusion)
                     l_outs = get_op_def(_l.op_type).lower(_l, l_ins, l_w, sub)
                     if mesh is not None:

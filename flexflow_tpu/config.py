@@ -13,6 +13,32 @@ import dataclasses
 import os
 from typing import Dict, List, Optional, Tuple
 
+# Everything this package caches on disk lives INSIDE the checkout (the
+# parent of the package directory) at fixed, git-ignored paths — never in
+# the home directory, where a run could be steered by files that are not in
+# the repository: .ff_cache/ (searched strategies, learned cost model) and
+# .jax_cache/ (XLA executables).
+CHECKOUT_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+FF_CACHE_ROOT = os.path.join(CHECKOUT_ROOT, ".ff_cache")
+_compile_cache_dir: Optional[str] = None
+
+
+def ensure_compile_cache() -> str:
+    """Give JAX's persistent compilation cache a home; returns the
+    directory. Called by every compile entry point (compile_model,
+    compile_serving). $JAX_COMPILATION_CACHE_DIR wins: JAX reads it itself
+    and this sets nothing. Otherwise the FIXED path <checkout>/.jax_cache —
+    the path is part of the cache key, so it must never move."""
+    global _compile_cache_dir
+    if _compile_cache_dir is None:
+        _compile_cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR", "")
+        if not _compile_cache_dir:
+            import jax
+
+            _compile_cache_dir = os.path.join(CHECKOUT_ROOT, ".jax_cache")
+            jax.config.update("jax_compilation_cache_dir", _compile_cache_dir)
+    return _compile_cache_dir
+
 
 @dataclasses.dataclass
 class FFConfig:
@@ -47,7 +73,7 @@ class FFConfig:
     substitution_json: str = ""
     # persistent strategy cache (search/strategy_cache.py): warm compile()
     # of an unchanged (graph, machine, knobs, calibration) skips the search.
-    # dir "" -> $FF_STRATEGY_CACHE_DIR or ~/.cache/flexflow_tpu/strategy
+    # dir "" -> $FF_STRATEGY_CACHE_DIR or <checkout>/.ff_cache/strategy
     strategy_cache: bool = True
     strategy_cache_dir: str = ""
     # event-driven task-graph re-rank of the DP finalists (reference
@@ -61,7 +87,7 @@ class FFConfig:
     simulator_segment_size: int = 16 * 1024 * 1024  # model.cc:3493
     simulator_topk: int = 4
     # learned cost model file; "" = $FF_COST_MODEL_PATH or
-    # ~/.cache/flexflow_tpu/cost_model.json
+    # <checkout>/.ff_cache/cost_model.json
     cost_model_path: str = ""
     # refit the learned model from this run's telemetry at fit end
     # (tools/refit_cost_model.py — the drift report's self-calibration)

@@ -10,7 +10,7 @@ configuration: FF launch flags (mesh shape, search budget, ...) in
 `FF_LAUNCH_ARGS` (consumed by FFConfig.parse_args() with argv=None — real
 CLI/kernel invocations only, never explicit programmatic argv — and by the
 launcher), the
-platform pin in `FLEXFLOW_PLATFORM`, and XLA device-count flags for
+platform pin in `JAX_PLATFORMS`, and XLA device-count flags for
 virtual-mesh notebooks.
 
 `python -m flexflow_tpu.jupyter.install --config cfg.json` installs the
@@ -74,13 +74,13 @@ def load_config(path: str) -> Tuple[str, List[str], Dict[str, str]]:
     env = dict(cfg.get("env", {}))
     platform = _value(cfg, "platform")
     if platform:
-        env["FLEXFLOW_PLATFORM"] = str(platform)
+        env["JAX_PLATFORMS"] = str(platform)
     vdev = _value(cfg, "virtual_devices")
     if vdev:
         env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
                             f" --xla_force_host_platform_device_count="
                             f"{int(vdev)}").strip()
-        env.setdefault("FLEXFLOW_PLATFORM", "cpu")
+        env.setdefault("JAX_PLATFORMS", "cpu")
     return name, argv, env
 
 

@@ -32,10 +32,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
-try:
-    from jax import shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 
 def collective_matmul_supported(mesh, axis: str, m: int, n: int) -> bool:
@@ -99,5 +96,5 @@ def collective_matmul(x, w, mesh: Mesh, axis: str,
     out_spec = PartitionSpec(None, w_spec[1])
     fn = shard_map(partial(_ring_matmul, axis=axis, p=p), mesh=mesh,
                    in_specs=(x_spec, w_spec), out_specs=out_spec,
-                   check_rep=False)
+                   check_vma=False)
     return fn(x, w)

@@ -11,9 +11,9 @@ stable softmax, so the f32 copy of the context never touches HBM.
 
 Decode contexts are short (pages_per_slot * page_size positions) and the
 query is 1..K+1 tokens (speculative verify), so the kernel keeps the whole
-context per grid step instead of blocking it — the VMEM budget check in
-`dequant_decode_attention` rejects shapes where that stops being true and
-the caller (ops/attention_ops.py) falls back to the einsum path.
+context per grid step instead of blocking it — `dequant_supported` says
+where that stops being true, and the caller (ops/attention_ops.py) asks it
+before choosing between this kernel and the einsum path.
 
 CPU runs use pallas interpret mode (tests/benches); all accumulation is
 f32 regardless of the query dtype.
@@ -27,6 +27,7 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = float("-inf")
 # int8 k + v context, their f32 scales, and one f32 widened operand per
@@ -39,14 +40,18 @@ def _interpret() -> bool:
 
 
 def _params():
-    from jax.experimental.pallas import tpu as pltpu
-
-    cls = getattr(pltpu, "CompilerParams", None) or \
-        getattr(pltpu, "TPUCompilerParams")
-    return cls(dimension_semantics=("parallel", "parallel"))
+    return pltpu.CompilerParams(dimension_semantics=("parallel", "parallel"))
 
 
-def _kernel(q_ref, kq_ref, ks_ref, vq_ref, vs_ref, pos_ref, o_ref, *, scale):
+def dequant_supported(ctx_len: int, depth: int) -> bool:
+    """Whether the fused kernel covers this gathered-context geometry (the
+    whole int8 context + scales + one widened operand stay in VMEM per
+    grid step). The auto path asks BEFORE tracing; beyond it decode takes
+    the einsum dequant path."""
+    return 2 * ctx_len * depth * (1 + 4) + 8 * ctx_len <= _VMEM_CTX_BYTES
+
+
+def _kernel(pos_ref, q_ref, kq_ref, ks_ref, vq_ref, vs_ref, o_ref, *, scale):
     q = q_ref[0, 0].astype(jnp.float32)            # (s, d)
     k = kq_ref[0, 0].astype(jnp.float32) * ks_ref[0, 0]   # (L, d) dequant
     v = vq_ref[0, 0].astype(jnp.float32) * vs_ref[0, 0]
@@ -55,7 +60,7 @@ def _kernel(q_ref, kq_ref, ks_ref, vq_ref, vs_ref, pos_ref, o_ref, *, scale):
     sq, L = s_mat.shape
     # causal-by-construction over the cached extent: query token i sits at
     # position pos + i, so it attends cached positions 0..pos+i inclusive
-    pos = pos_ref[0, 0]
+    pos = pos_ref[pl.program_id(0)]
     row = jax.lax.broadcasted_iota(jnp.int32, (sq, L), 0)
     col = jax.lax.broadcasted_iota(jnp.int32, (sq, L), 1)
     s_mat = jnp.where(col <= pos + row, s_mat, _NEG_INF)
@@ -71,14 +76,14 @@ def dequant_decode_attention(qh, kq, ks, vq, vs, pos,
     """qh (b, s, h, d) queries; kq/vq (b, L, h, d) int8 gathered context;
     ks/vs (b, L, h) f32 scales; pos (b,) int32 cached-extent per slot.
     Returns (b, s, h, d) in qh's dtype. Raises ValueError on unsupported
-    shapes/dtypes — callers fall back to the einsum dequant path."""
+    shapes/dtypes — callers precheck with dequant_supported."""
     if qh.ndim != 4 or kq.ndim != 4 or ks.ndim != 3:
         raise ValueError(f"bad ranks q={qh.shape} kq={kq.shape} ks={ks.shape}")
     if kq.dtype != jnp.int8 or vq.dtype != jnp.int8:
         raise ValueError(f"context must be int8, got {kq.dtype}/{vq.dtype}")
     b, s, h, d = qh.shape
     L = kq.shape[1]
-    if 2 * L * d * (1 + 4) + 8 * L > _VMEM_CTX_BYTES:
+    if not dequant_supported(L, d):
         raise ValueError(f"context {L} x depth {d} exceeds the VMEM budget; "
                          "use the einsum dequant path")
     if scale is None:
@@ -89,21 +94,23 @@ def dequant_decode_attention(qh, kq, ks, vq, vs, pos,
     # trailing singleton keeps the scale blocks' last-two dims tileable
     kst = jnp.swapaxes(ks, 1, 2)[..., None]        # (b, h, L, 1)
     vst = jnp.swapaxes(vs, 1, 2)[..., None]
-    posb = pos.astype(jnp.int32).reshape(b, 1)
+    # pos is a per-slot scalar: scalar-prefetched into SMEM (a (1, 1) VMEM
+    # block of a (b, 1) array is not a legal TPU tile)
+    def blk(rows, cols):   # one (batch, head) slab per grid step
+        return pl.BlockSpec((1, 1, rows, cols),
+                            lambda b_, h_, pos_: (b_, h_, 0, 0))
+
     out = pl.pallas_call(
         functools.partial(_kernel, scale=float(scale)),
-        grid=(b, h),
-        in_specs=[
-            pl.BlockSpec((1, 1, s, d), lambda b_, h_: (b_, h_, 0, 0)),
-            pl.BlockSpec((1, 1, L, d), lambda b_, h_: (b_, h_, 0, 0)),
-            pl.BlockSpec((1, 1, L, 1), lambda b_, h_: (b_, h_, 0, 0)),
-            pl.BlockSpec((1, 1, L, d), lambda b_, h_: (b_, h_, 0, 0)),
-            pl.BlockSpec((1, 1, L, 1), lambda b_, h_: (b_, h_, 0, 0)),
-            pl.BlockSpec((1, 1), lambda b_, h_: (b_, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, s, d), lambda b_, h_: (b_, h_, 0, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b, h),
+            in_specs=[blk(s, d), blk(L, d), blk(L, 1), blk(L, d), blk(L, 1)],
+            out_specs=blk(s, d),
+        ),
         out_shape=jax.ShapeDtypeStruct((b, h, s, d), qh.dtype),
         compiler_params=_params(),
         interpret=_interpret(),
-    )(qt, kqt, kst, vqt, vst, posb)
+        name="ff_dequant_attention",
+    )(pos.astype(jnp.int32), qt, kqt, kst, vqt, vst)
     return jnp.swapaxes(out, 1, 2)

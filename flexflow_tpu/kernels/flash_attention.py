@@ -39,7 +39,7 @@ _VMEM_SEQ_BYTES = 6 * 1024 * 1024
 # 256KB block per operand (q, do, dq accumulators all carry it); bf16 keeps
 # the full 512. 160KB leaves the d=64 behavior exactly as before.
 _VMEM_BLOCK_BYTES = 160 * 1024
-# narrow heads (d <= 64, the 54%-MFU case in BENCH_r05) get a larger
+# narrow heads (d <= 64, GPT-2's case) get a larger
 # per-block budget: a 1024 x 64 f32 block is 256KB and three such operands
 # are still < 1MB of VMEM, while the doubled rows-per-grid-step halve the
 # k/v streaming overhead that starves the MXU at short blocks. Wider heads
@@ -100,12 +100,9 @@ def _interpret() -> bool:
 def _params():
     from jax.experimental.pallas import tpu as pltpu
 
-    # batch/head/q-block grid dims are independent; lets Mosaic pipeline
-    # them. The class was renamed across jax releases (TPUCompilerParams
-    # -> CompilerParams); accept either spelling.
-    cls = getattr(pltpu, "CompilerParams", None) or \
-        getattr(pltpu, "TPUCompilerParams")
-    return cls(dimension_semantics=("parallel", "parallel", "arbitrary"))
+    # batch/head/q-block grid dims are independent; lets Mosaic pipeline them
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"))
 
 
 # --------------------------------------------------------------------- forward
@@ -175,6 +172,7 @@ def _fwd(q, k, v, causal, scale):
         ],
         compiler_params=_params(),
         interpret=_interpret(),
+        name="ff_flash_attention_fwd",
     )(q, k, v)
     return o, lse
 
@@ -279,6 +277,7 @@ def _bwd(causal, scale, res, g):
         out_shape=jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
         compiler_params=_params(),
         interpret=_interpret(),
+        name="ff_flash_attention_dq",
     )(q, k, v, g, lse, delta)
 
     dk, dv = pl.pallas_call(
@@ -290,6 +289,7 @@ def _bwd(causal, scale, res, g):
                    jax.ShapeDtypeStruct((b, h, sk, d), v.dtype)],
         compiler_params=_params(),
         interpret=_interpret(),
+        name="ff_flash_attention_dkv",
     )(q, k, v, g, lse, delta)
     return dq, dk, dv
 
@@ -312,7 +312,8 @@ def flash_attention(q, k, v, causal: bool = False, scale: float | None = None):
     """q: (b, h, sq, d), k/v: (b, h, sk, d) -> (b, h, sq, d).
 
     Raises ValueError when shapes don't qualify (sequence not divisible by a
-    block size, causal with sq != sk) — callers fall back to the einsum path.
+    block size, causal with sq != sk) — callers precheck with
+    flash_supported; a kernel that was chosen and then fails must fail.
     """
     if q.ndim != 4 or k.ndim != 4 or v.ndim != 4:
         raise ValueError(f"expected rank-4 q/k/v, got {q.shape}/{k.shape}/{v.shape}")
@@ -326,11 +327,9 @@ def flash_attention(q, k, v, causal: bool = False, scale: float | None = None):
     for s_, d_, it in ((q.shape[2], q.shape[3], q.dtype.itemsize),
                       (k.shape[2], k.shape[3], k.dtype.itemsize)):
         if 2 * s_ * d_ * it > _VMEM_SEQ_BYTES:
-            # the Mosaic-reject precheck: shapes whose VMEM-resident
-            # operands can't fit raise HERE, at trace time, where the
-            # attention op's auto path catches ValueError and falls back
-            # to the einsum reference path (ops/attention_ops.py) instead
-            # of dying inside the backend compiler
+            # the Mosaic-reject precheck (same bound as flash_supported):
+            # shapes whose VMEM-resident operands can't fit raise HERE, at
+            # trace time, with a message that names the shape
             raise ValueError(
                 f"sequence {s_} x depth {d_} exceeds the VMEM-resident budget "
                 f"({_VMEM_SEQ_BYTES} bytes); use the einsum or ring path")

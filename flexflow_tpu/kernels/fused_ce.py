@@ -30,6 +30,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.sharding import PartitionSpec
+
+from flexflow_tpu.kernels.partition import (local_shape, multi_device,
+                                            per_shard)
+from flexflow_tpu.parallel.sharding import used_axes
 
 _NEG_INF = float("-inf")
 _ROW_BLOCKS = (256, 128, 64, 32, 16, 8)
@@ -69,9 +74,29 @@ def fused_ce_supported(shape, dtype) -> bool:
     return n > 0 and v > 0 and _pick_blocks(n, v, dt.itemsize) is not None
 
 
+def _dims(ndim: int, pspec):
+    """`pspec` as exactly `ndim` per-dim shardings."""
+    return (tuple(pspec or ()) + (None,) * ndim)[:ndim]
+
+
+def _shard_shape(shape, mesh, pspec):
+    """The logits shape ONE device sees — `shape` itself on one device.
+    None when the kernel cannot be split per shard: the strategy shards the
+    vocab dim (the log-sum-exp would need a cross-device reduction) or a
+    sharded dim does not divide."""
+    if not multi_device(mesh):
+        return tuple(shape)
+    dims = _dims(len(shape), pspec)
+    if dims[-1] is not None:
+        return None
+    return local_shape(shape, dims, mesh)
+
+
 def use_fused_ce(loss_type, logits, mode: str,
-                 enable_fusion: bool = True) -> bool:
-    """The compile-time gate: cfg.fused_loss x loss type x shape precheck."""
+                 enable_fusion: bool = True, mesh=None, pspec=None) -> bool:
+    """The compile-time gate: cfg.fused_loss x loss type x shape precheck
+    (on the per-shard shape when `mesh` has more than one device and the
+    logits are laid out as `pspec`)."""
     from flexflow_tpu.losses import LossType
 
     if mode == "off":
@@ -83,13 +108,14 @@ def use_fused_ce(loss_type, logits, mode: str,
                 f"--fused-loss=on requires sparse_categorical_crossentropy "
                 f"(got {loss_type})")
         return False
-    ok = fused_ce_supported(logits.shape, logits.dtype)
+    local = _shard_shape(logits.shape, mesh, pspec)
+    ok = local is not None and fused_ce_supported(local, logits.dtype)
     if mode == "on":
         if not ok:
             raise ValueError(
                 f"--fused-loss=on but logits {logits.shape} {logits.dtype} "
-                f"don't qualify (need rows % 8 == 0, vocab % 128 == 0, "
-                f"f32/bf16)")
+                f"(per-device {local}) don't qualify (need rows % 8 == 0, "
+                f"vocab % 128 == 0 and unsharded, f32/bf16)")
         return True
     return ok and enable_fusion
 
@@ -101,9 +127,7 @@ def _interpret() -> bool:
 def _params(semantics):
     from jax.experimental.pallas import tpu as pltpu
 
-    cls = getattr(pltpu, "CompilerParams", None) or \
-        getattr(pltpu, "TPUCompilerParams")
-    return cls(dimension_semantics=semantics)
+    return pltpu.CompilerParams(dimension_semantics=semantics)
 
 
 # --------------------------------------------------------------------- forward
@@ -163,6 +187,7 @@ def _forward(x2, y2):
         # vocab is the accumulation dim: must run in order per row block
         compiler_params=_params(("parallel", "arbitrary")),
         interpret=_interpret(),
+        name="ff_fused_ce_fwd",
     )(x2, y2)
     return loss, lse
 
@@ -198,6 +223,7 @@ def _backward(x2, y2, lse, gscale):
         out_shape=jax.ShapeDtypeStruct((n, v), x2.dtype),
         compiler_params=_params(("parallel", "parallel")),
         interpret=_interpret(),
+        name="ff_fused_ce_bwd",
     )(x2, y2, lse, g)
     return dx
 
@@ -224,8 +250,12 @@ _fce.defvjp(_fce_fwd, _fce_bwd)
 
 
 # ------------------------------------------------------------------ public API
-def fused_cross_entropy(logits, labels) -> jax.Array:
-    """Mean sparse cross-entropy over all leading dims.
+def fused_cross_entropy(logits, labels, mesh=None, pspec=None) -> jax.Array:
+    """Mean sparse cross-entropy over all leading dims. On a multi-device
+    `mesh` with the logits laid out as `pspec` (vocab unsharded), every
+    device runs the kernel on its own rows and returns its mean; the global
+    mean is taken outside the manual region (equal shards, no collective
+    inside — kernels/partition.py).
 
     logits: [..., vocab] (f32 or bf16, kept in native dtype); labels:
     integer ids broadcastable to logits.shape[:-1]. Numerically equivalent
@@ -233,11 +263,22 @@ def fused_cross_entropy(logits, labels) -> jax.Array:
     logits.astype(f32), labels)). Raises ValueError on unsupported shapes —
     callers precheck with fused_ce_supported / use_fused_ce.
     """
-    if not fused_ce_supported(logits.shape, logits.dtype):
+    local = _shard_shape(logits.shape, mesh, pspec)
+    if local is None or not fused_ce_supported(local, logits.dtype):
         raise ValueError(f"fused_cross_entropy: unsupported logits "
-                         f"{logits.shape} {logits.dtype}")
+                         f"{logits.shape} {logits.dtype} (per-device {local})")
     v = logits.shape[-1]
-    n = logits.size // v
-    x2 = logits.reshape(n, v)
-    y2 = labels.reshape(n, 1).astype(jnp.int32)
-    return _fce(x2, y2)
+
+    def local_mean(x, y):
+        n = x.size // v
+        return _fce(x.reshape(n, v), y.reshape(n, 1).astype(jnp.int32))[None]
+
+    labels = labels.reshape(logits.shape[:-1])
+    if not multi_device(mesh):
+        return local_mean(logits, labels)[0]
+    lead = _dims(logits.ndim, pspec)[:-1]
+    means = per_shard(local_mean, mesh,
+                      (PartitionSpec(*lead, None), PartitionSpec(*lead)),
+                      PartitionSpec(tuple(used_axes(lead)) or None)
+                      )(logits, labels)
+    return jnp.mean(means)

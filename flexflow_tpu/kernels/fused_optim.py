@@ -34,6 +34,9 @@ import jax
 import jax.numpy as jnp
 import optax
 from jax.experimental import pallas as pl
+from jax.sharding import NamedSharding, PartitionSpec
+
+from flexflow_tpu.kernels.partition import per_shard
 
 _LANES = 128
 _BLOCK_ROWS = 256
@@ -46,9 +49,7 @@ def _interpret() -> bool:
 def _params():
     from jax.experimental.pallas import tpu as pltpu
 
-    cls = getattr(pltpu, "CompilerParams", None) or \
-        getattr(pltpu, "TPUCompilerParams")
-    return cls(dimension_semantics=("parallel",))
+    return pltpu.CompilerParams(dimension_semantics=("parallel",))
 
 
 # ----------------------------------------------------------------- planning
@@ -155,6 +156,7 @@ def _adam_leaf(g, mu, nu, p, sc, plan):
                    jax.ShapeDtypeStruct(g2.shape, sd)],
         compiler_params=_params(),
         interpret=_interpret(),
+        name="ff_fused_optim_adam",
     )(g2, mu2, nu2, p2, sc)
     return (_unpad(upd2, g.shape, g.size),
             _unpad(mu_o2, g.shape, g.size),
@@ -164,7 +166,8 @@ def _adam_leaf(g, mu, nu, p, sc, plan):
 def _sgd_leaf(g, t, p, plan):
     g2, br = _pad2d(g)
     p2, _ = _pad2d(p)
-    common = dict(compiler_params=_params(), interpret=_interpret())
+    common = dict(compiler_params=_params(), interpret=_interpret(),
+                  name="ff_fused_optim_sgd")
     if t is None:
         upd2 = pl.pallas_call(
             functools.partial(_sgd_plain_kernel, lr=plan["lr"],
@@ -221,11 +224,31 @@ def _tree3(out_tree, grads):
 
 
 # ------------------------------------------------------------------ update
-def fused_update(plan: Dict[str, Any], grads, opt_state, params
-                 ) -> Optional[Tuple[Any, Any]]:
+def fused_update(plan: Dict[str, Any], grads, opt_state, params,
+                 mesh=None, shardings=None) -> Optional[Tuple[Any, Any]]:
     """tx.update replacement: (updates, new_opt_state), or None when the
-    live state doesn't match the plan (caller falls back to tx.update)."""
-    tm = jax.tree_util.tree_map
+    live state doesn't match the plan (caller falls back to tx.update).
+
+    On a multi-device `mesh`, `shardings` (a NamedSharding per param — the
+    moment layout) splits every leaf's kernel per shard: the update is
+    elementwise, so each device runs it on its own slice of (g, mu, nu, p)
+    with no collective (kernels/partition.py)."""
+    if shardings is None:
+        shardings = jax.tree_util.tree_map(lambda _: None, grads)
+
+    def tm(leaf_fn, n_out, *trees, consts=()):
+        # `consts` ride along replicated (a traced value must enter a
+        # shard_map as an argument, not a closure capture)
+        def one(sh, *leaves):
+            spec = sh.spec if sh is not None else PartitionSpec()
+            return per_shard(
+                leaf_fn, mesh,
+                (spec,) * len(leaves) + (PartitionSpec(),) * len(consts),
+                (spec,) * n_out if n_out > 1 else spec)(*leaves, *consts)
+
+        return jax.tree_util.tree_map(
+            one, shardings, *trees,
+            is_leaf=lambda x: x is None or isinstance(x, NamedSharding))
     if plan["kind"] == "adam":
         s = _find_node(opt_state, optax.ScaleByAdamState)
         if s is None:
@@ -236,8 +259,8 @@ def fused_update(plan: Dict[str, Any], grads, opt_state, params
         bc2 = 1.0 - plan["b2"] ** c32
         sc = jnp.zeros((1, _LANES), jnp.float32)
         sc = sc.at[0, 0].set(bc1).at[0, 1].set(bc2)
-        out = tm(lambda g, m, n, p: _adam_leaf(g, m, n, p, sc, plan),
-                 grads, s.mu, s.nu, params)
+        out = tm(lambda g, m, n, p, sc: _adam_leaf(g, m, n, p, sc, plan), 3,
+                 grads, s.mu, s.nu, params, consts=(sc,))
         upd, mu, nu = _tree3(out, grads)
         new_s = optax.ScaleByAdamState(count=count, mu=mu, nu=nu)
         return upd, _replace_node(opt_state, optax.ScaleByAdamState, new_s)
@@ -246,13 +269,14 @@ def fused_update(plan: Dict[str, Any], grads, opt_state, params
             s = _find_node(opt_state, optax.TraceState)
             if s is None:
                 return None
-            out = tm(lambda g, t, p: _sgd_leaf(g, t, p, plan),
+            out = tm(lambda g, t, p: _sgd_leaf(g, t, p, plan), 2,
                      grads, s.trace, params)
             outer = jax.tree_util.tree_structure(grads)
             inner = jax.tree_util.tree_structure((0, 0))
             upd, trace = jax.tree_util.tree_transpose(outer, inner, out)
             new_s = optax.TraceState(trace=trace)
             return upd, _replace_node(opt_state, optax.TraceState, new_s)
-        upd = tm(lambda g, p: _sgd_leaf(g, None, p, plan)[0], grads, params)
+        upd = tm(lambda g, p: _sgd_leaf(g, None, p, plan)[0], 1,
+                 grads, params)
         return upd, opt_state
     return None

@@ -2,12 +2,16 @@
 the hot host-side paths via ctypes (which releases the GIL for the call —
 batch assembly overlaps the training step in the prefetch thread).
 
-Falls back silently: every caller treats `batch_gather(...) -> None` /
-ImportError as "use the pure-Python path"."""
+`_native.so` is a build product (git-ignored), compiled from the committed
+native.cc on the machine that runs. Every caller treats
+`batch_gather(...) -> None` as "use the numpy path", so a helper that
+cannot be built is reported ONCE, loudly (a warning naming the compiler's
+error) — never dropped in silence."""
 
 from __future__ import annotations
 
 import ctypes
+import logging
 import os
 import subprocess
 import threading
@@ -21,10 +25,18 @@ _SO = os.path.join(_HERE, "_native.so")
 _lock = threading.Lock()
 _lib = None
 _failed = False  # one build attempt per process; don't re-spawn c++ on failure
+_log = logging.getLogger(__name__)
+
+
+def _give_up(why: str) -> None:
+    global _failed
+    _failed = True
+    _log.warning("flexflow_tpu native helper unavailable, using the numpy/"
+                 "python paths (batch assembly, topo order): %s", why)
 
 
 def _build() -> Optional[ctypes.CDLL]:
-    global _lib, _failed
+    global _lib
     with _lock:
         if _lib is not None:
             return _lib
@@ -39,10 +51,11 @@ def _build() -> Optional[ctypes.CDLL]:
                    "-o", tmp, _SRC]
             try:
                 subprocess.run(cmd, check=True, capture_output=True,
-                               timeout=120)
+                               text=True, timeout=120)
                 os.replace(tmp, _SO)
-            except Exception:
-                _failed = True
+            except (OSError, subprocess.SubprocessError) as e:
+                _give_up(f"`{' '.join(cmd)}` failed: {e}\n"
+                         f"{getattr(e, 'stderr', '') or ''}")
                 try:
                     os.unlink(tmp)
                 except OSError:
@@ -50,8 +63,8 @@ def _build() -> Optional[ctypes.CDLL]:
                 return None
         try:
             lib = ctypes.CDLL(_SO)
-        except OSError:
-            _failed = True
+        except OSError as e:
+            _give_up(f"cannot load {_SO}: {e}")
             return None
         lib.ff_batch_gather.restype = ctypes.c_int
         lib.ff_batch_gather.argtypes = [

@@ -14,15 +14,18 @@ expressed by sharding the per-head projection weights on a model axis.
 
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec
 
 from typing import TYPE_CHECKING
 if TYPE_CHECKING:
     from flexflow_tpu.core.layer import Layer
 from flexflow_tpu.core.tensor import TensorSpec
+from flexflow_tpu.kernels.partition import dividing, multi_device, per_shard
 from flexflow_tpu.ops.op_type import OperatorType
 from flexflow_tpu.ops.registry import register_op, LoweringCtx
 
@@ -144,17 +147,22 @@ def _mha_decode_lower(layer: Layer, inputs, weights, ctx: LoweringCtx):
         Vq = v_pool[pt].reshape(b, -1, heads, hd)
         Ks = k_scale[pt].reshape(b, -1, heads)
         Vs = v_scale[pt].reshape(b, -1, heads)
-        if ctx.enable_fusion:
-            try:
-                from flexflow_tpu.kernels.dequant_attention import (
-                    dequant_decode_attention,
-                )
+        from flexflow_tpu.kernels.dequant_attention import (
+            dequant_decode_attention, dequant_supported)
 
-                out = dequant_decode_attention(qh, Kq, Ks, Vq, Vs, pos,
-                                               scale=scale)
-            except Exception:
-                out = None  # einsum dequant fallback below
-        if out is None:
+        # the path is chosen from the shape (and, on a multi-device mesh,
+        # from whether the strategy says how to split the kernel), BEFORE
+        # tracing; a kernel that was chosen and then fails to trace/compile
+        # must fail the program
+        spec = _attn_pspec(layer, ctx, None, heads)
+        if ctx.enable_fusion and dequant_supported(Kq.shape[1], hd) \
+                and spec is not None:
+            sspec = PartitionSpec(*spec[:3])
+            out = per_shard(
+                functools.partial(dequant_decode_attention, scale=scale),
+                ctx.mesh, (spec, spec, sspec, spec, sspec, PartitionSpec()),
+                spec)(qh, Kq, Ks, Vq, Vs, pos)
+        else:
             K = (Kq.astype(jnp.float32) * Ks[..., None]).astype(dt)
             V = (Vq.astype(jnp.float32) * Vs[..., None]).astype(dt)
     else:
@@ -175,6 +183,39 @@ def _mha_decode_lower(layer: Layer, inputs, weights, ctx: LoweringCtx):
     if "bo" in weights:
         y = y + weights["bo"].astype(dt)
     return [y]
+
+
+def _attn_pspec(layer: Layer, ctx: LoweringCtx, batch, heads: int):
+    """(b, s, h, d) PartitionSpec the strategy puts on this attention's
+    per-head tensors — batch on the op output's batch axes, heads on wq's
+    column axes (each kept only where it divides; nothing to split on one
+    device). None when a Pallas kernel cannot be placed: a multi-device
+    mesh and a layer the strategy does not know (a fork_join branch
+    sub-layer). `batch=None` leaves the batch dim replicated (decode
+    slots)."""
+    if not multi_device(ctx.mesh):
+        return PartitionSpec(None, None, None, None)
+    sh = ctx.op_shardings.get(layer.name)
+    if sh is None:
+        return None
+    out0 = sh.outputs[0] if sh.outputs else []
+    wq = sh.weights.get("wq") or []
+    bdim = dividing(out0[0], batch, ctx.mesh) if out0 and batch else None
+    taken = (bdim,) if isinstance(bdim, str) else tuple(bdim or ())
+    hdim = dividing(wq[1], heads, ctx.mesh, taken) if len(wq) > 1 else None
+    return PartitionSpec(bdim, None, hdim, None)
+
+
+def _flash_covers(qh, kh, vh, causal: bool) -> bool:
+    """The auto path's precheck, from shapes alone: exactly the conditions
+    flash_attention() validates (q/k/v are (b, s, h, d))."""
+    from flexflow_tpu.kernels.flash_attention import flash_supported
+
+    sq, sk, d = qh.shape[1], kh.shape[1], qh.shape[3]
+    if vh.shape[1] != sk or (causal and sq != sk):
+        return False
+    it = qh.dtype.itemsize
+    return flash_supported(sq, d, it) and flash_supported(sk, d, it)
 
 
 def _mha_lower(layer: Layer, inputs, weights, ctx: LoweringCtx):
@@ -240,20 +281,23 @@ def _mha_lower(layer: Layer, inputs, weights, ctx: LoweringCtx):
         raise NotImplementedError("impl='flash' does not support attention-prob "
                                   "dropout; use dropout=0.0 or impl='xla'")
     # "auto" uses the fused pallas kernel only when fusion is enabled
-    # (--fusion, reference FusedOp gate); impl="flash" forces it regardless
+    # (--fusion, reference FusedOp gate) AND the shape qualifies — decided
+    # here, before tracing; impl="flash" forces it regardless. A kernel that
+    # was chosen and then raises (trace, Mosaic compile) propagates: it
+    # never silently becomes the einsum path.
+    spec = _attn_pspec(layer, ctx, qh.shape[0], heads)
     if out is None and not needs_dropout and (
-            impl == "flash" or (impl == "auto" and ctx.enable_fusion)):
-        try:
-            from flexflow_tpu.kernels.flash_attention import flash_attention_qkv
+            impl == "flash" or (impl == "auto" and ctx.enable_fusion
+                                and _flash_covers(qh, kh, vh, causal)
+                                and spec is not None)):
+        from flexflow_tpu.kernels.flash_attention import flash_attention_qkv
 
-            out = flash_attention_qkv(qh, kh, vh, causal=causal, scale=scale)
-        except Exception:
-            # auto falls back to the einsum path on ANY flash failure
-            # (unsupported shapes raise ValueError; the experimental pallas
-            # stack may raise other types at trace time)
-            if impl == "flash":
-                raise
-            out = None
+        # seq and depth stay whole per shard, so _flash_covers holds there
+        # (a forced impl="flash" with no placement goes in unsplit)
+        out = per_shard(
+            functools.partial(flash_attention_qkv, causal=causal, scale=scale),
+            ctx.mesh if spec is not None else None,
+            (spec, spec, spec), spec)(qh, kh, vh)
     if out is None:
         logits = jnp.einsum("bqhd,bkhd->bhqk", qh, kh) * scale
         if causal:

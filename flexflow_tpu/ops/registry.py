@@ -47,6 +47,10 @@ class LoweringCtx:
     # placement on disjoint device subsets)
     mesh: Optional[Any] = None
     op_attrs: Dict[str, Dict[str, Any]] = dataclasses.field(default_factory=dict)
+    # the strategy's per-layer OpSharding (layer name -> outputs/weights dim
+    # shardings): what a lowering needs to run a Pallas kernel per shard on
+    # a multi-device mesh (kernels/partition.py)
+    op_shardings: Dict[str, Any] = dataclasses.field(default_factory=dict)
     # --fusion flag (reference FusedOp gate, model.cc apply_fusion): False
     # disables fused custom kernels (pallas flash attention) in "auto" mode
     enable_fusion: bool = True

@@ -50,25 +50,12 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec
 
-try:  # jax >= 0.6 exposes shard_map at top level; experimental is deprecated
-    from jax import shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 
 def _pvary(x, axes):
-    """Mark x as varying over `axes` in the vma type system (pcast on new
-    jax; pvary on older; identity on jax predating varying-manual-axes
-    entirely — there the promotion is unnecessary because shard_map does
-    not type-check cotangent vma)."""
-    try:
-        return jax.lax.pcast(x, axes, to="varying")
-    except (AttributeError, TypeError):
-        pass
-    try:
-        return jax.lax.pvary(x, tuple(axes))
-    except AttributeError:  # pragma: no cover
-        return x
+    """Mark x as varying over `axes` in shard_map's vma type system."""
+    return jax.lax.pcast(x, tuple(axes), to="varying")
 
 
 def _batch_pspec(mesh: Mesh, axis: str, batch_len: int,

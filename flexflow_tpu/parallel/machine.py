@@ -29,6 +29,32 @@ CHIP_PRESETS = {
     "cpu-sim": (1e11, 50e9, 8e9, 1e9),
 }
 
+# `jax.devices()[0].device_kind` as the runtime reports it -> CHIP_PRESETS
+# key. Only strings that were READ from a device are listed (v5e: on the
+# chip, PR 22); a chip that is not here is an error, never priced as
+# another chip. Add a row when the string has been read on that chip.
+DEVICE_KINDS = {
+    "TPU v5 lite": "v5e",
+}
+
+
+def chip_for_device_kind(kind: str) -> str:
+    try:
+        return DEVICE_KINDS[kind]
+    except KeyError:
+        raise ValueError(
+            f"device_kind {kind!r} is not in machine.DEVICE_KINDS "
+            f"({sorted(DEVICE_KINDS)}): add its peaks to CHIP_PRESETS "
+            "instead of pricing it as another chip") from None
+
+
+def _preset(chip: str):
+    try:
+        return CHIP_PRESETS[chip]
+    except KeyError:
+        raise ValueError(f"unknown chip {chip!r}; known: "
+                         f"{sorted(CHIP_PRESETS)}") from None
+
 
 @dataclasses.dataclass
 class MachineSpec:
@@ -59,11 +85,11 @@ class MachineSpec:
     # time behind. 0 = fully additive costing. Collectives are async
     # ICI/HBM DMAs, which genuinely overlap compute; the single-chip
     # compute proxy CANNOT observe this (a TPU core runs compute HLOs
-    # serially — CALIBRATION.md's negative control). The 0.7 default rests
+    # serially — tools/calibrate.py's negative control). The 0.7 default rests
     # on the async-DMA architecture, stays below 1.0 because collectives
     # sit on dataflow edges (their producer must finish first), and is
     # cross-checked by the whole-model scheduling calibration
-    # (CALIBRATION.md simulated/step ~0.94). search/simulator.py replaces
+    # (tools/calibrate.py, simulated/step). search/simulator.py replaces
     # this factor entirely with event-driven replay (simulator_mode=
     # "taskgraph").
     overlap_frac: float = 0.7
@@ -75,7 +101,7 @@ class MachineSpec:
     host_bw: float = 0.0
 
     def __post_init__(self):
-        preset = CHIP_PRESETS.get(self.chip, CHIP_PRESETS["v5e"])
+        preset = _preset(self.chip)
         if not self.flops:
             self.flops = preset[0]
         if not self.hbm_bw:
@@ -93,7 +119,7 @@ class MachineSpec:
         return math.prod(self.mesh_axes.values()) if self.mesh_axes else 1
 
     def axis_bw(self, axis: str) -> float:
-        return self.ici_bw.get(axis, CHIP_PRESETS.get(self.chip, CHIP_PRESETS["v5e"])[3])
+        return self.ici_bw.get(axis, _preset(self.chip)[3])
 
     def axis_topology(self, axis: str) -> str:
         if axis in self.axis_type:
@@ -155,12 +181,10 @@ class MachineSpec:
         discovery in FFConfig; src/runtime/model.cc FFConfig ctor).
         `dcn_axes` marks cross-slice axes so their bandwidth binds to DCN."""
         devs = jax.devices()
-        chip = "cpu-sim" if devs[0].platform == "cpu" else "v5e"
-        kind = getattr(devs[0], "device_kind", "").lower()
-        if "v5p" in kind or "v5 p" in kind:
-            chip = "v5p"
-        elif "v4" in kind:
-            chip = "v4"
+        if devs[0].platform == "cpu":
+            chip = "cpu-sim"
+        else:
+            chip = chip_for_device_kind(devs[0].device_kind)
         if not mesh_axes:
             mesh_axes = {"data": len(devs)}
         return MachineSpec(mesh_axes=dict(mesh_axes), chip=chip,
@@ -168,12 +192,23 @@ class MachineSpec:
 
 
 def build_mesh(spec: MachineSpec) -> jax.sharding.Mesh:
-    """Materialize the logical mesh over the visible devices."""
+    """Materialize the logical mesh over the visible devices. A mesh that
+    spans ALL of them is laid out by mesh_utils.create_device_mesh, which
+    reads the chips' coordinates: on a real 2x2 host enumeration order is
+    not ring order (ids 0,1,2,3 sit at (0,0),(1,0),(0,1),(1,1)), and a
+    logical axis should ride physical neighbours. A sub-mesh (fewer devices
+    than visible) has no torus of its own and takes enumeration order; the
+    CPU's virtual devices have no coordinates and are just reshaped."""
+    from jax.experimental import mesh_utils
+
     shape = tuple(spec.mesh_axes.values())
     names = tuple(spec.mesh_axes.keys())
     n = math.prod(shape)
     devs = jax.devices()
     if n > len(devs):
         raise ValueError(f"mesh {spec.mesh_axes} needs {n} devices, have {len(devs)}")
-    arr = np.array(devs[:n]).reshape(shape)
+    if n == len(devs):
+        arr = mesh_utils.create_device_mesh(shape, devices=devs)
+    else:
+        arr = np.array(devs[:n]).reshape(shape)
     return jax.sharding.Mesh(arr, names)

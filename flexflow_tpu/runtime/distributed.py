@@ -30,39 +30,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 
-def _enable_cpu_collectives():
-    """Multi-process runs on the CPU backend (the fake-multi-node test
-    regime) need a cross-process collectives implementation: since jax
-    0.4.x the CPU client ships gloo but does NOT select it by default —
-    collectives then fail with "Multiprocess computations aren't
-    implemented on the CPU backend" (the standing multihost-test failure
-    this revives). Only flips the knob when the CPU platform is selected
-    and BEFORE the backend initializes; harmless no-op elsewhere. Returns
-    an undo callable: gloo needs the distributed client, so a process
-    whose initialize FAILED must put the knob back or its later
-    single-process backend init crashes."""
-    try:
-        platforms = jax.config.jax_platforms or ""
-    except AttributeError:
-        platforms = ""
-    if "cpu" not in platforms:
-        return lambda: None
-    # jax 0.4.37 exposes the knob to update() but not as a config
-    # attribute — read via the flag holder, defaulting to the flag's
-    # factory default ("none")
-    try:
-        prev = jax.config._value_holders[
-            "jax_cpu_collectives_implementation"].value
-    except (AttributeError, KeyError):
-        prev = "none"
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:  # older/newer jax without the knob: leave as-is
-        return lambda: None
-    return lambda: jax.config.update(
-        "jax_cpu_collectives_implementation", prev)
-
-
 def init_distributed(coordinator_address: Optional[str] = None,
                      num_processes: Optional[int] = None,
                      process_id: Optional[int] = None,
@@ -87,14 +54,9 @@ def init_distributed(coordinator_address: Optional[str] = None,
         kwargs["process_id"] = process_id
     if local_device_ids is not None:
         kwargs["local_device_ids"] = list(local_device_ids)
-    undo = _enable_cpu_collectives()
-    try:
-        run_resilient("distributed/init",
-                      lambda: jax.distributed.initialize(**kwargs),
-                      retry_policy)
-    except BaseException:
-        undo()
-        raise
+    run_resilient("distributed/init",
+                  lambda: jax.distributed.initialize(**kwargs),
+                  retry_policy)
 
 
 def is_multiprocess() -> bool:

@@ -90,7 +90,7 @@ class RetryPolicy:
     max_delay: float = 2.0
     jitter: float = 0.25
     seed: int = 0
-    # plausibly-transient failures only: XlaRuntimeError (tunnel/collective
+    # plausibly-transient failures only: XlaRuntimeError (collective
     # hiccups) and InjectedFault are RuntimeErrors, filesystem/socket races
     # are OS/Connection/Timeout errors. Deterministic programming errors
     # (ValueError/TypeError — a sharding bug, a bad serialization tree)

@@ -309,7 +309,7 @@ def overlapped_step_cost(comp: float, comm: float, machine: MachineSpec) -> floa
     simulator.h:785-827): XLA's async collectives + latency-hiding scheduler
     hide collective time behind up to machine.overlap_frac of the consumer's
     pure compute; only the residual serializes. overlap_frac=0 degenerates
-    to additive costing. Calibrated by tools/calibrate.py (CALIBRATION.md)."""
+    to additive costing. Calibrated by tools/calibrate.py."""
     return comp + max(0.0, comm - machine.overlap_frac * comp)
 
 

@@ -394,12 +394,14 @@ class LearnedCost:
 
 # ------------------------------------------------------------- resolution
 def resolve_model_path(cfg) -> str:
-    """--cost-model-path > $FF_COST_MODEL_PATH > the ~/.cache default
+    """--cost-model-path > $FF_COST_MODEL_PATH > the checkout-local default
     (sibling of the strategy cache, so one `rm -r` clears both tiers)."""
+    from flexflow_tpu.config import FF_CACHE_ROOT
+
     return os.path.expanduser(
         getattr(cfg, "cost_model_path", "") or
         os.environ.get("FF_COST_MODEL_PATH", "") or
-        os.path.join("~", ".cache", "flexflow_tpu", "cost_model.json"))
+        os.path.join(FF_CACHE_ROOT, "cost_model.json"))
 
 
 def load_for_config(cfg, machine) -> Optional[LearnedCost]:

@@ -24,7 +24,7 @@ and reported as `invalidated`, never silently applied.
 
 Layout: one `<key>.json` per entry under the cache dir
 (`--strategy-cache-dir` > `$FF_STRATEGY_CACHE_DIR` >
-`~/.cache/flexflow_tpu/strategy`), carrying the strategy plus a meta block
+`<checkout>/.ff_cache/strategy`), carrying the strategy plus a meta block
 (fingerprints, predicted cost, search wall-clock) for `profile_report()`
 cache-stats and `tools/bench_search.py`.
 """
@@ -65,10 +65,13 @@ STATS = CacheStats()
 
 
 def resolve_dir(cfg) -> str:
-    """--strategy-cache-dir > $FF_STRATEGY_CACHE_DIR > ~/.cache default."""
+    """--strategy-cache-dir > $FF_STRATEGY_CACHE_DIR > the checkout-local
+    default (never the home directory)."""
+    from flexflow_tpu.config import FF_CACHE_ROOT
+
     d = getattr(cfg, "strategy_cache_dir", "") or \
         os.environ.get("FF_STRATEGY_CACHE_DIR", "") or \
-        os.path.join("~", ".cache", "flexflow_tpu", "strategy")
+        os.path.join(FF_CACHE_ROOT, "strategy")
     return os.path.expanduser(d)
 
 

@@ -50,6 +50,7 @@ from flexflow_tpu.runtime.resilience import (RetryPolicy, committed_snapshots,
                                              run_resilient)
 from flexflow_tpu.compiler.compile import (build_init_fn, resolve_machine,
                                            _overlay_parallel_ops)
+from flexflow_tpu.config import ensure_compile_cache
 from flexflow_tpu.compiler.lowering import build_forward, constrainable
 from flexflow_tpu.core.graph import topo_order
 from flexflow_tpu.ops.op_type import OperatorType
@@ -133,6 +134,7 @@ def compile_serving(model, max_batch_slots: Optional[int] = None,
     clone lowered with the searched decode strategy) batch-verifies the K
     drafted tokens in one pass."""
     cfg = model.config
+    ensure_compile_cache()
     # --telemetry-dir arms the process-global span stream for serving-only
     # flows too (compile_model does the same; request traces, serve/hist
     # and serve/slo events all ride this sink)
@@ -711,7 +713,7 @@ class ServingCompiled:
         def per_device_bytes(tree):
             if tree is None:
                 return 0
-            dev = jax.devices()[0]
+            dev = self.mesh.devices.flat[0]
             total = 0
             for leaf in jax.tree_util.tree_leaves(tree):
                 shards = getattr(leaf, "addressable_shards", None)

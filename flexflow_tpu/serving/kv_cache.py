@@ -509,7 +509,8 @@ class PagedKVCache:
     def device_bytes(self) -> int:
         """Pool bytes resident on device 0 (the measured side of the
         KV-cache watermark accounting)."""
-        dev = jax.devices()[0]
+        dev = (self.mesh.devices.flat[0] if self.mesh is not None
+               else jax.devices()[0])
         total = 0
         for n in self.attn_layers:
             # every leaf of the layer's cache state — values AND, for a

@@ -1,9 +1,9 @@
 """Worker for the 2-process multi-host test (mpi_wrapper analog) — run by
 tests/test_multihost.py, one subprocess per "host", each with 4 virtual CPU
 devices; jax.distributed stitches them into one 8-device world. CPU
-cross-process collectives ride gloo (init_distributed flips
-jax_cpu_collectives_implementation — without it jax >= 0.4.x fails with
-"Multiprocess computations aren't implemented on the CPU backend")."""
+cross-process collectives ride gloo (jax's default CPU collectives). The
+worker pins ITSELF to the CPU platform: a child must never reach for a chip
+its parent may hold."""
 
 import os
 import sys
@@ -13,12 +13,11 @@ import time
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 port, nproc, pid = sys.argv[1], int(sys.argv[2]), int(sys.argv[3])
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
                            + " --xla_force_host_platform_device_count=4")
 
 import jax
-
-jax.config.update("jax_platforms", "cpu")
 
 
 _PHASE = "start"

@@ -318,13 +318,13 @@ def test_bench_history_check_smoke():
 def test_bench_history_flags_broken_artifact(tmp_path):
     repo = tmp_path / "repo"
     repo.mkdir()
-    (repo / "BENCH_r01.json").write_text(json.dumps(
-        {"parsed": {"metric": "m", "value": 1.0}}))
+    (repo / "BENCH_zero.json").write_text(json.dumps(
+        {"opt_state_reduction_actual": 1.0}))
     assert bench_history.main(["--check", "--repo", str(repo)]) == 0
-    (repo / "BENCH_r02.json").write_text("{not json")
+    (repo / "BENCH_pipeline.json").write_text("{not json")
     with pytest.raises(AssertionError, match="unparseable"):
         bench_history.main(["--check", "--repo", str(repo)])
-    (repo / "BENCH_r02.json").write_text(json.dumps({"parsed": {}}))
+    (repo / "BENCH_pipeline.json").write_text(json.dumps({}))
     with pytest.raises(AssertionError, match="headline"):
         bench_history.main(["--check", "--repo", str(repo)])
 

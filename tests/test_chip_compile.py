@@ -1,0 +1,333 @@
+"""The main path's Pallas kernels, compiled by the chip's own compiler.
+
+Every other kernel test runs in pallas interpret mode on the CPU mesh, which
+accepts block shapes and VMEM footprints that Mosaic refuses. Here each
+kernel is lowered with interpret=False for a DESCRIBED v5e:2x2 device (no
+chip attached, nothing runs) at GPT-2-medium widths, so a tiling or VMEM
+refusal fails tier-1 instead of the first chip call. The same kernels are
+then compiled on a Mesh of the four described devices — per shard, the way
+kernels/partition.py places them — because the chip's compiler refuses a
+Mosaic call that GSPMD would have to partition, and only a multi-device
+compile shows that. Last, XLA's own memory analysis of a --remat train step,
+from the compiler whose answer matters.
+
+The topology is described inside a module-scoped fixture of THIS file only:
+one process at a time may load the TPU library, so nothing here may happen
+at import, in a skipif/parametrize argument, in conftest.py or in a child
+process (the xdist workers each import every test file).
+"""
+
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path[:0] = [ROOT, os.path.join(ROOT, "tools")]  # chip_smoke, bench_mfu
+
+KERNEL_MODULES = ("flash_attention", "fused_ce", "fused_optim",
+                  "dequant_attention")
+
+# GPT-2 medium: batch 8, 16 heads of 64, seq 1024, vocab padded to 50304
+B, H, S, D = 8, 16, 1024, 64
+VOCAB_PADDED = 50304
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        t = topologies.get_topology_desc(platform="tpu",
+                                         topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a described-chip executable can be written to the persistent cache
+    # but not read back without a chip: keep these compiles out of it
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield t
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def mesh2x2(topo):
+    """The four described chips as the {data:2, model:2} mesh of
+    `chip_smoke.py --chips 4`."""
+    return Mesh(np.array(topo.devices).reshape(2, 2), ("data", "model"))
+
+
+@pytest.fixture
+def described_devices(topo, monkeypatch):
+    """FFModel.compile builds its mesh (and detects its chip) from
+    jax.devices(): hand it the described chips for the length of a test.
+    jax.default_backend() still says cpu — nothing can be placed or run,
+    the step is compiled from shapes."""
+    def hand_out(n):
+        devs = list(topo.devices)[:n]
+        monkeypatch.setattr(jax, "devices", lambda *a, **k: devs)
+
+    return hand_out
+
+
+@pytest.fixture
+def mosaic(monkeypatch):
+    """The kernels key interpret mode on jax.default_backend(), which still
+    says cpu here: force the Mosaic path from the test."""
+    import importlib
+
+    for name in KERNEL_MODULES:
+        mod = importlib.import_module(f"flexflow_tpu.kernels.{name}")
+        monkeypatch.setattr(mod, "_interpret", lambda: False)
+
+
+def _compile(fn, one_chip, *shapes):
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip) for s, dt in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text, "kernel was not lowered through Mosaic"
+    return compiled
+
+
+def test_device_kind_is_in_the_peaks_table(topo):
+    from flexflow_tpu.parallel.machine import CHIP_PRESETS, chip_for_device_kind
+
+    kind = topo.devices[0].device_kind
+    assert chip_for_device_kind(kind) in CHIP_PRESETS
+
+
+def test_flash_attention_forward(one_chip, mosaic):
+    from flexflow_tpu.kernels.flash_attention import flash_attention
+
+    qkv = ((B, H, S, D), jnp.bfloat16)
+    _compile(lambda q, k, v: flash_attention(q, k, v, causal=True),
+             one_chip, qkv, qkv, qkv)
+
+
+def test_flash_attention_backward(one_chip, mosaic):
+    """dq and dk/dv kernels: grad w.r.t. all three operands."""
+    from flexflow_tpu.kernels.flash_attention import flash_attention
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True).astype(jnp.float32).sum()
+
+    qkv = ((B, H, S, D), jnp.bfloat16)
+    compiled = _compile(jax.grad(loss, argnums=(0, 1, 2)),
+                        one_chip, qkv, qkv, qkv)
+    # forward (residuals) + dq + dkv
+    assert compiled.as_text().count("tpu_custom_call") >= 3
+
+
+def test_fused_ce_forward_backward(one_chip, mosaic):
+    from flexflow_tpu.kernels.fused_ce import (fused_ce_supported,
+                                               fused_cross_entropy)
+
+    # GPT-2's published vocab is not lane-aligned: the default (unpadded)
+    # model takes the optax loss, and only the padded vocab selects the kernel
+    assert not fused_ce_supported((B, S, 50257), jnp.bfloat16)
+    assert fused_ce_supported((B * S, VOCAB_PADDED), jnp.bfloat16)
+    compiled = _compile(jax.value_and_grad(fused_cross_entropy), one_chip,
+                        ((B * S, VOCAB_PADDED), jnp.bfloat16),
+                        ((B * S,), jnp.int32))
+    assert compiled.as_text().count("tpu_custom_call") >= 2
+
+
+@pytest.mark.parametrize("state_dtype", ["float32", "bfloat16"])
+def test_fused_adam(one_chip, mosaic, state_dtype):
+    import optax
+
+    from flexflow_tpu.kernels.fused_optim import fused_update, plan_for
+    from flexflow_tpu.optimizers import AdamOptimizer
+
+    opt = AdamOptimizer(alpha=1e-4, state_dtype=state_dtype)
+    plan = plan_for(opt)
+    assert plan is not None
+    sd = jnp.dtype(state_dtype)
+    shape = (1024, 4096)
+
+    def step(g, mu, nu, p, count):
+        state = (optax.ScaleByAdamState(count=count, mu={"w": mu},
+                                        nu={"w": nu}),)
+        upd, new_state = fused_update(plan, {"w": g}, state, {"w": p})
+        return upd["w"], new_state[0].mu["w"], new_state[0].nu["w"]
+
+    _compile(step, one_chip, (shape, jnp.float32), (shape, sd), (shape, sd),
+             (shape, jnp.float32), ((), jnp.int32))
+
+
+@pytest.mark.parametrize("q_tokens", [1, 5])
+def test_dequant_decode_attention(one_chip, mosaic, q_tokens):
+    """The serving engine's int8 geometry: [slots, L, 16, 64] gathered
+    context with per-(entry, head) scales; 1 query token for plain decode,
+    K+1 for the speculative-verify window."""
+    from flexflow_tpu.kernels.dequant_attention import dequant_decode_attention
+
+    slots, L = 8, 1024
+    _compile(dequant_decode_attention, one_chip,
+             ((slots, q_tokens, H, D), jnp.bfloat16),
+             ((slots, L, H, D), jnp.int8), ((slots, L, H), jnp.float32),
+             ((slots, L, H, D), jnp.int8), ((slots, L, H), jnp.float32),
+             ((slots,), jnp.int32))
+
+
+# ------------------------------------------------ on a mesh of four devices
+def _compile_on(mesh, fn, *args):
+    """args: (shape, dtype, PartitionSpec) on `mesh`."""
+    shapes = [jax.ShapeDtypeStruct(s, dt, sharding=NamedSharding(mesh, spec))
+              for s, dt, spec in args]
+    return jax.jit(fn).lower(*shapes).compile()
+
+
+def test_flash_attention_per_shard_on_the_mesh(mesh2x2, mosaic):
+    """Forward and both backward kernels under shard_map with the specs the
+    attention op derives from a {data, model} strategy: batch on data, heads
+    on model. (b, s, h, d) is the op's own layout."""
+    from flexflow_tpu.kernels.flash_attention import flash_attention_qkv
+    from flexflow_tpu.kernels.partition import per_shard
+
+    spec = P("data", None, "model", None)
+    attn = per_shard(lambda q, k, v: flash_attention_qkv(q, k, v, causal=True),
+                     mesh2x2, (spec, spec, spec), spec)
+
+    def loss(q, k, v):
+        return attn(q, k, v).astype(jnp.float32).sum()
+
+    qkv = ((B, S, H, D), jnp.bfloat16, spec)
+    text = _compile_on(mesh2x2, jax.grad(loss, argnums=(0, 1, 2)),
+                       qkv, qkv, qkv).as_text()
+    assert text.count("tpu_custom_call") >= 3
+
+
+def test_mosaic_kernel_outside_shard_map_is_refused_on_the_mesh(mesh2x2,
+                                                                mosaic):
+    """Why partition.py exists: the same kernel handed to GSPMD on sharded
+    operands does not compile. If this ever passes, per_shard can go."""
+    from flexflow_tpu.kernels.flash_attention import flash_attention_qkv
+
+    qkv = ((B, S, H, D), jnp.bfloat16, P("data", None, "model", None))
+    with pytest.raises(Exception, match="Mosaic kernels cannot be "
+                                        "automatically partitioned"):
+        _compile_on(mesh2x2,
+                    lambda q, k, v: flash_attention_qkv(q, k, v, causal=True),
+                    qkv, qkv, qkv)
+
+
+def test_dequant_decode_attention_per_shard_on_the_mesh(mesh2x2, mosaic):
+    """Decode slots stay replicated, heads split on model — the specs
+    ops/attention_ops.py passes for the int8 KV cache."""
+    import functools
+
+    from flexflow_tpu.kernels.dequant_attention import dequant_decode_attention
+    from flexflow_tpu.kernels.partition import per_shard
+
+    slots, L = 8, 1024
+    spec, sspec = P(None, None, "model", None), P(None, None, "model")
+    fn = per_shard(functools.partial(dequant_decode_attention, scale=0.125),
+                   mesh2x2, (spec, spec, sspec, spec, sspec, P()), spec)
+    text = _compile_on(
+        mesh2x2, fn,
+        ((slots, 1, H, D), jnp.bfloat16, spec),
+        ((slots, L, H, D), jnp.int8, spec), ((slots, L, H), jnp.float32, sspec),
+        ((slots, L, H, D), jnp.int8, spec), ((slots, L, H), jnp.float32, sspec),
+        ((slots,), jnp.int32, P())).as_text()
+    assert "tpu_custom_call" in text
+
+
+def test_fused_ce_per_shard_on_the_mesh(mesh2x2, mosaic):
+    """Rows split over data, vocab whole on every device (a vocab-sharded
+    layout is not selected: use_fused_ce says no and the optax loss runs)."""
+    from flexflow_tpu.kernels.fused_ce import fused_cross_entropy, use_fused_ce
+
+    pspec = P("data", None, None)
+    logits = jax.ShapeDtypeStruct((B, S, VOCAB_PADDED), jnp.bfloat16)
+    assert use_fused_ce("sparse_categorical_crossentropy", logits, "auto",
+                        True, mesh2x2, pspec)
+    assert not use_fused_ce("sparse_categorical_crossentropy", logits, "auto",
+                            True, mesh2x2, P("data", None, "model"))
+    text = _compile_on(
+        mesh2x2,
+        jax.value_and_grad(
+            lambda x, y: fused_cross_entropy(x, y, mesh2x2, pspec)),
+        ((B, S, VOCAB_PADDED), jnp.bfloat16, pspec),
+        ((B, S), jnp.int32, P("data", None))).as_text()
+    assert text.count("tpu_custom_call") >= 2
+
+
+def _train_step_shapes(cm, label_shape):
+    """The jitted train step's arguments as shapes with cm's own shardings
+    (a described device holds no array)."""
+    def sds(s, sh):
+        return jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh)
+
+    pshapes, pshards = cm._param_templates()
+    params = jax.tree_util.tree_map(sds, pshapes, pshards)
+    opt = jax.tree_util.tree_map(sds, jax.eval_shape(cm.tx.init, pshapes),
+                                 cm._opt_sh)
+    ins = [jax.ShapeDtypeStruct(t.spec.shape, t.spec.dtype.jnp_dtype,
+                                sharding=cm.input_sharding(t))
+           for t in cm.model.input_tensors]
+    label = jax.ShapeDtypeStruct(label_shape, jnp.int32,
+                                 sharding=cm.label_sharding(label_shape))
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32,
+                               sharding=NamedSharding(cm.mesh, P()))
+    return params, opt, {}, ins, label, key
+
+
+def test_searched_train_step_on_the_mesh(described_devices, mosaic):
+    """The whole sharded step through the normal entry points: chip_smoke's
+    GPT-2 medium at depth 2, searched on {data:2, model:2} as its --chips 4
+    leg is. Every kernel call site of the lowering must sit inside per_shard
+    or the chip's compiler refuses the program here."""
+    import chip_smoke as cs
+
+    described_devices(4)
+    gcfg = cs.gpt2_medium()
+    gcfg.layers = 2
+    _, cm, _, _ = cs._build(gcfg, B, 0, init=False, search_budget=32,
+                            mesh_shape={"data": 2, "model": 2})
+    assert cm.strategy.name.startswith("unity")
+    text = cm.train_step.lower(
+        *_train_step_shapes(cm, (B, gcfg.seq))).compile().as_text()
+    kernels = cs.kernels_in(text)
+    assert kernels["flash_attention"] >= 3 * gcfg.layers, kernels
+    assert kernels["fused_optim"] >= 1, kernels
+    assert sum(cs.collectives_in(text).values()) > 0
+
+
+def test_remat_shrinks_the_compiled_steps_temp_memory(described_devices):
+    """tools/bench_mfu.py's remat_live claim — per-layer jax.checkpoint
+    shrinks the live temp buffers of the COMPILED train step — asked of the
+    chip's compiler, same model and sizes as that leg."""
+    import bench_mfu
+
+    from flexflow_tpu import FFConfig, SGDOptimizer
+    from flexflow_tpu.losses import LossType
+
+    described_devices(1)
+    batch, hidden, layers = 1024, 256, 8
+    temp = {}
+    for remat in (False, True):
+        bench_mfu._guid_reset()
+        cfg = FFConfig(batch_size=batch, only_data_parallel=True, remat=remat,
+                       seed=3, strategy_cache=False, log_level="warning")
+        cm = bench_mfu._chain_model(cfg, batch, hidden, layers).compile(
+            SGDOptimizer(lr=0.01), LossType.SPARSE_CATEGORICAL_CROSSENTROPY,
+            metrics=[])
+        compiled = cm.train_step.lower(
+            *_train_step_shapes(cm, (batch,))).compile()
+        temp[remat] = compiled.memory_analysis().temp_size_in_bytes
+    assert 0 < temp[True] < temp[False], temp

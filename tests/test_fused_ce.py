@@ -125,26 +125,28 @@ def _fit(devices, fused_loss: str):
     # consecutive builds shift the guid-derived dropout streams: pin them
     Layer._next_guid[0] = 100
     Tensor._next_guid[0] = 1000
-    cfg = FFConfig(batch_size=8, mesh_shape={"data": 4, "model": 2},
+    # batch 32 over data=4: 8 rows per device — the kernel runs PER SHARD
+    # on a multi-device mesh, and its row block is 8
+    cfg = FFConfig(batch_size=32, mesh_shape={"data": 4, "model": 2},
                    only_data_parallel=False, search_budget=0,
                    fused_loss=fused_loss, seed=3)
     m = FFModel(cfg)
-    x = m.create_tensor([8, 32], name="x")
+    x = m.create_tensor([32, 32], name="x")
     h = m.dense(x, 64, activation="gelu", name="up")
     m.dense(h, 256, name="head")  # vocab-like: 256 % 128 == 0
     cmod = m.compile(SGDOptimizer(lr=0.05),
                      LossType.SPARSE_CATEGORICAL_CROSSENTROPY, metrics=[])
     cmod.init(seed=0)
     rng = np.random.default_rng(0)
-    xs = rng.normal(size=(16, 32)).astype(np.float32)
-    ys = rng.integers(0, 256, size=(16,)).astype(np.int32)
+    xs = rng.normal(size=(64, 32)).astype(np.float32)
+    ys = rng.integers(0, 256, size=(64,)).astype(np.int32)
     return [h["loss"] for h in cmod.fit([xs], ys, epochs=2, verbose=False)]
 
 
 def test_e2e_loss_parity_on_sharded_mesh(devices):
     """Acceptance: fused vs reference loss within 1e-5 on the real
-    compile path over a 4x2 mesh (the kernel runs under jit+GSPMD with
-    sharded logits, interpret mode on CPU)."""
+    compile path over a 4x2 mesh (the kernel runs per shard of the
+    batch-sharded logits under shard_map, interpret mode on CPU)."""
     base = _fit(devices, "off")
     fused = _fit(devices, "on")
     assert np.allclose(base, fused, atol=1e-5, rtol=1e-5)

@@ -35,7 +35,7 @@ def test_install_kernelspec_prefix(tmp_path):
     assert "--mesh data=4,model=2" in spec["env"]["FF_LAUNCH_ARGS"]
     assert "--budget 8" in spec["env"]["FF_LAUNCH_ARGS"]
     assert "device_count=8" in spec["env"]["XLA_FLAGS"]
-    assert spec["env"]["FLEXFLOW_PLATFORM"] == "cpu"
+    assert spec["env"]["JAX_PLATFORMS"] == "cpu"
 
 
 def test_reference_config_vocabulary(tmp_path):

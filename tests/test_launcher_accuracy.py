@@ -11,7 +11,7 @@ import numpy as np
 
 def test_launcher_runs_script_with_flags():
     env = dict(os.environ)
-    env["FLEXFLOW_PLATFORM"] = "cpu"
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
                         + " --xla_force_host_platform_device_count=8")
     env["PYTHONPATH"] = "/root/repo" + os.pathsep + env.get("PYTHONPATH", "")

@@ -338,7 +338,7 @@ def test_learned_flags_wired():
     assert cfg.auto_refit is True
     d = FFConfig()
     assert d.simulator_mode == "additive"  # learned is an explicit opt-in
-    assert d.cost_model_path == ""         # "" -> env var -> ~/.cache default
+    assert d.cost_model_path == ""         # "" -> env var -> checkout default
     assert d.auto_refit is False
     with pytest.raises(SystemExit):
         FFConfig.parse_args(["--simulator-mode", "psychic"])
@@ -354,7 +354,7 @@ def test_learned_flags_wired():
         assert lc.resolve_model_path(d) == "/tmp/env.json"
         del os.environ["FF_COST_MODEL_PATH"]
         assert lc.resolve_model_path(d).endswith(
-            os.path.join(".cache", "flexflow_tpu", "cost_model.json"))
+            os.path.join(".ff_cache", "cost_model.json"))
     finally:
         if old is not None:
             os.environ["FF_COST_MODEL_PATH"] = old

@@ -90,7 +90,7 @@ def test_measurement_flips_search_decision(devices):
 
 def test_calibration_harness(devices, tmp_path):
     """tools/calibrate.py produces the analytic/measured/whole-step table
-    (SURVEY §7 hard part #1 quantified; committed as CALIBRATION.md)."""
+    (SURVEY §7 hard part #1 quantified)."""
     import sys
 
     sys.path.insert(0, "/root/repo/tools")

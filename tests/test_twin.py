@@ -131,8 +131,8 @@ def test_window_overhead_calibration_identity():
 
 
 def test_validate_gates_on_worst_metric():
-    live = {"tokens_per_s_per_chip": 100.0, "ttft_p99_s": 0.10}
-    twin = {"tokens_per_s_per_chip": 110.0, "ttft_p99_s": 0.13}
+    live = {"tokens_per_s_per_cpu_device": 100.0, "ttft_p99_s": 0.10}
+    twin = {"tokens_per_s_per_cpu_device": 110.0, "ttft_p99_s": 0.13}
     v = validate(live, twin, max_rel_err=0.25)
     assert v["max_rel_err"] == pytest.approx(0.30)
     assert not v["ok"]  # ttft is off by 30%: the worst metric gates

@@ -360,6 +360,10 @@ def main(argv=None) -> int:
                    help="CI smoke: 2 replicas, identity/parity/rollout "
                         "invariants only (no timing gates)")
     args = p.parse_args(argv)
+    import jax  # a CPU-mesh counting tool: say what it ran on
+    print(f"[bench_fleet] platform={jax.default_backend()} "
+          f"devices={len(jax.devices())}: counts and parity "
+          "facts, never a device metric", file=sys.stderr)
     floor = args.step_floor_ms / 1e3
     n_engines = 2 if args.check else 4
     if args.check:

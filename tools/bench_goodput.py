@@ -129,6 +129,10 @@ def main(argv=None) -> int:
                    help="CI smoke: tiny twin, assert >=95%% accounting, "
                         "checkpoint-induced goodput drop, loss parity")
     args = p.parse_args(argv)
+    import jax  # a CPU-mesh counting tool: say what it ran on
+    print(f"[bench_goodput] platform={jax.default_backend()} "
+          f"devices={len(jax.devices())}: counts and parity "
+          "facts, never a device metric", file=sys.stderr)
     if args.check:
         args.model, args.epochs = "gpt2_check", 2
 

@@ -3,12 +3,10 @@
 
 Every PR that claims a performance win ships a BENCH_*.json evidence file
 (bench_search / bench_step / bench_zero / bench_pipeline / bench_resilience
-/ profile_attribution / the driver's per-round BENCH_rNN chip runs), but
-the trajectory across them was invisible — answering "did samples/s/chip
-regress since round 3?" meant opening five files by hand. This tool knows
-each family's headline metric and renders one (metric, source, value,
-delta-vs-previous) table, chronological within a metric (BENCH_rNN rounds
-sort by round number; one-off family files carry their own headline).
+/ profile_attribution / ...), but the trajectory across them was invisible.
+This tool knows each family's headline metric and renders one (metric,
+source, value, delta-vs-previous) table. These are CPU-mesh counts and
+parity facts; on-chip numbers live in the driver's PERF_LEDGER.jsonl.
 
 Usage:
     python tools/bench_history.py [--repo DIR] [--json]
@@ -29,26 +27,11 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def _round_metrics(d: Dict[str, Any]) -> List[Tuple[str, float]]:
-    """BENCH_rNN.json (driver chip rounds): the parsed headline metric plus
-    the secondary series worth trending."""
-    p = d.get("parsed") or {}
-    out = []
-    if p.get("metric") and p.get("value") is not None:
-        out.append((str(p["metric"]), float(p["value"])))
-    for k in ("mfu", "step_ms", "head_dim128_samples_per_sec_per_chip",
-              "head_dim128_mfu", "bert_samples_per_sec_per_chip"):
-        if p.get(k) is not None:
-            out.append((k, float(p[k])))
-    return out
-
-
 # family -> (filename regex, extractor returning [(metric, value), ...]);
 # an extractor returning an EMPTY list means "headline missing" (--check
 # fails on it — an evidence file without its claim is a broken artifact)
 FAMILIES: Dict[str, Tuple[str, Callable[[Dict[str, Any]],
                                         List[Tuple[str, float]]]]] = {
-    "round": (r"^BENCH_r(\d+)\.json$", _round_metrics),
     "search_fastpath": (
         r"^BENCH_search_fastpath\.json$",
         lambda d: [(k, float(d[k])) for k in
@@ -91,7 +74,7 @@ FAMILIES: Dict[str, Tuple[str, Callable[[Dict[str, Any]],
     "serve": (
         r"^BENCH_serve\.json$",
         lambda d: [(k, float(d[k])) for k in
-                   ("tokens_per_s_per_chip", "ttft_p99_s",
+                   ("tokens_per_s_per_cpu_device", "ttft_p99_s",
                     "per_token_p99_s", "spec_accept_rate",
                     "kv_itemsize")
                    if d.get(k) is not None]),
@@ -99,7 +82,7 @@ FAMILIES: Dict[str, Tuple[str, Callable[[Dict[str, Any]],
         r"^BENCH_spec\.json$",
         lambda d: [(k, float(d[k])) for k in
                    ("spec_speedup_best", "spec_accept_rate_best",
-                    "spec_tokens_best", "int8_tokens_per_s_per_chip",
+                    "spec_tokens_best", "int8_tokens_per_s_per_cpu_device",
                     "int8_kv_shard_degree", "bf16_kv_shard_degree",
                     "legs_passed")
                    if d.get(k) is not None]),
@@ -194,13 +177,12 @@ def scan(repo: str = REPO) -> List[Dict[str, Any]]:
 
 def trajectory(recs: List[Dict[str, Any]]) -> List[Dict[str, Any]]:
     """Flatten records into the table: one row per (metric, source), with
-    delta vs the previous occurrence of the SAME metric (chronological by
-    the BENCH_rNN round number; one-off families have no predecessor)."""
+    delta vs the previous occurrence of the SAME metric (one-off family
+    files have no predecessor)."""
     rows: List[Dict[str, Any]] = []
     last: Dict[str, float] = {}
     ordered = sorted((r for r in recs if "metrics" in r),
-                     key=lambda r: (r["family"] != "round", r.get("order", 0),
-                                    r["file"]))
+                     key=lambda r: (r.get("order", 0), r["file"]))
     for rec in ordered:
         for name, value in rec["metrics"]:
             prev = last.get(name)
@@ -237,14 +219,6 @@ def _check(repo: str) -> int:
         f"{r['file']}: {r['error']}" for r in bad)
     rows = trajectory(recs)
     assert rows, "no headline metrics extracted"
-    # the chip-round series must actually chain (deltas computed);
-    # match the round FAMILY regex, not a "BENCH_r" prefix (which would
-    # also swallow BENCH_resilience.json)
-    rounds = [r for r in rows
-              if re.match(FAMILIES["round"][0], r["source"])]
-    if len({r["source"] for r in rounds}) > 1:
-        assert any(r["delta"] is not None for r in rounds), \
-            "multi-round series produced no deltas"
     print(f"bench_history --check OK ({len(recs)} files, "
           f"{len(rows)} metric rows)")
     return 0

@@ -286,6 +286,10 @@ def main(argv=None) -> int:
     p.add_argument("--out", default="", help="also write the JSON here")
     p.add_argument("--check", action="store_true")
     args = p.parse_args(argv)
+    import jax  # a CPU-mesh counting tool: say what it ran on
+    print(f"[bench_learned] platform={jax.default_backend()} "
+          f"devices={len(jax.devices())}: counts and parity "
+          "facts, never a device metric", file=sys.stderr)
     if args.check:
         return _check()
 

@@ -225,6 +225,10 @@ def main(argv=None) -> int:
                    help="CI smoke: 2x host tier, capacity gate skipped; "
                         "parity, ledger and crossover still asserted")
     args = p.parse_args(argv)
+    import jax  # a CPU-mesh counting tool: say what it ran on
+    print(f"[bench_longctx] platform={jax.default_backend()} "
+          f"devices={len(jax.devices())}: counts and parity "
+          "facts, never a device metric", file=sys.stderr)
 
     fails: list = []
     capacity = _capacity_leg(args.check, fails)

@@ -422,6 +422,10 @@ def main(argv=None) -> int:
                     help="CI smoke: assert every leg's contract, write "
                          "nothing, exit nonzero on regression")
     args = ap.parse_args(argv)
+    import jax  # a CPU-mesh counting tool: say what it ran on
+    print(f"[bench_mfu] platform={jax.default_backend()} "
+          f"devices={len(jax.devices())}: counts and parity "
+          "facts, never a device metric", file=sys.stderr)
     result = run(check=args.check)
     if args.check:
         if result.get("failures"):

@@ -18,7 +18,7 @@ count M = accum_steps), reports:
     block_until_ready). Wall-clock concurrency across the 8 VIRTUAL cpu
     devices shares the host's cores, so a wall-clock bubble would mostly
     measure the host scheduler — the twin measures the schedule with real
-    kernel times instead (the same honesty note as MULTICHIP_r0x).
+    kernel times instead (a CPU-mesh wall clock is not a device time).
 
   python tools/bench_pipeline.py                 # full sweep
   python tools/bench_pipeline.py --check         # CI smoke (tiny twin):
@@ -211,6 +211,10 @@ def main(argv=None) -> int:
                    help="CI smoke: tiny twin, assert memory reduction, "
                         "bubble accuracy, 1f1b >= gpipe, loss parity")
     args = p.parse_args(argv)
+    import jax  # a CPU-mesh counting tool: say what it ran on
+    print(f"[bench_pipeline] platform={jax.default_backend()} "
+          f"devices={len(jax.devices())}: counts and parity "
+          "facts, never a device metric", file=sys.stderr)
     stages_list = [int(s) for s in args.stages.split(",") if s]
     if args.check:
         # repeats=2: the schedule-throughput comparison is wall clock on a

@@ -159,8 +159,8 @@ def leg_overhead(eng, gc, n_dev, n_requests, rate, seed, reps, checks):
                f"({overhead_pct:.2f}%)")
     return {
         "reps": reps,
-        "tokens_per_s_per_chip_traced": round(on_best, 2),
-        "tokens_per_s_per_chip_untraced": round(off_best, 2),
+        "tokens_per_s_per_cpu_device_traced": round(on_best, 2),
+        "tokens_per_s_per_cpu_device_untraced": round(off_best, 2),
         "overhead_pct": round(overhead_pct, 3),
     }
 
@@ -352,6 +352,10 @@ def main(argv=None) -> int:
     p.add_argument("--check", action="store_true",
                    help="CI smoke: tiny twin, assert every leg invariant")
     args = p.parse_args(argv)
+    import jax  # a CPU-mesh counting tool: say what it ran on
+    print(f"[bench_reqtrace] platform={jax.default_backend()} "
+          f"devices={len(jax.devices())}: counts and parity "
+          "facts, never a device metric", file=sys.stderr)
     if args.check:
         args.requests = min(args.requests, 12)
         args.rate = min(args.rate, 6.0)

@@ -162,6 +162,10 @@ def main(argv=None) -> int:
     p.add_argument("--epochs", type=int, default=0)
     p.add_argument("--out", type=str, default="")
     args = p.parse_args(argv)
+    import jax  # a CPU-mesh counting tool: say what it ran on
+    print(f"[bench_resilience] platform={jax.default_backend()} "
+          f"devices={len(jax.devices())}: counts and parity "
+          "facts, never a device metric", file=sys.stderr)
     if args.worker:
         return worker(args)
 

@@ -94,6 +94,10 @@ def main(argv=None) -> int:
                    help="CI smoke: tiny graph, assert warm >= 2x cold + "
                         "zero warm DP expansions + identical strategy")
     args = p.parse_args(argv)
+    import jax  # a CPU-mesh counting tool: say what it ran on
+    print(f"[bench_search] platform={jax.default_backend()} "
+          f"devices={len(jax.devices())}: counts and parity "
+          "facts, never a device metric", file=sys.stderr)
 
     from flexflow_tpu.parallel.machine import MachineSpec
     from flexflow_tpu.search import strategy_cache as sc

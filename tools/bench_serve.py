@@ -7,7 +7,7 @@ arrival trace (seeded — arrivals don't wait for the server, so queueing
 delay shows up in TTFT exactly as it would against a real frontend).
 Per arrival-rate leg it reports:
 
-  tokens_per_s_per_chip — generated tokens / wall / device count
+  tokens_per_s_per_cpu_device — generated tokens / wall / device count
   ttft_p50_s/ttft_p99_s — time-to-first-token quantiles (arrival ->
       first prefill logit materialization, queueing included)
   per_token_p50_s/per_token_p99_s — decode-step latency quantiles at
@@ -117,7 +117,7 @@ def _run_leg(eng, gc, n_dev, rate, n_requests, seed):
         "tokens": tokens,
         "wall_s": round(wall, 3),
         "tokens_per_s": round(tokens / wall, 2),
-        "tokens_per_s_per_chip": round(tokens / wall / n_dev, 2),
+        "tokens_per_s_per_cpu_device": round(tokens / wall / n_dev, 2),
         "ttft_p50_s": hq("ttft", 0.5, lambda: _quantile(ttfts, 0.5)),
         "ttft_p99_s": hq("ttft", 0.99, lambda: _quantile(ttfts, 0.99)),
         "per_token_p50_s": hq("decode_step", 0.5,
@@ -148,6 +148,10 @@ def main(argv=None) -> int:
                    help="CI smoke: tiny twin, assert completion + ordered "
                         "finite quantiles + KV memory accounting")
     args = p.parse_args(argv)
+    import jax  # a CPU-mesh counting tool: say what it ran on
+    print(f"[bench_serve] platform={jax.default_backend()} "
+          f"devices={len(jax.devices())}: counts and parity "
+          "facts, never a device metric", file=sys.stderr)
     if args.check:
         args.requests = min(args.requests, 8)
 
@@ -176,7 +180,7 @@ def main(argv=None) -> int:
         "kv_scale_itemsize": eng.kv_spec.scale_itemsize,
         "spec_tokens": eng.spec_tokens,
         # headline metrics (bench_history "serve" family)
-        "tokens_per_s_per_chip": max(l["tokens_per_s_per_chip"] for l in legs),
+        "tokens_per_s_per_cpu_device": max(l["tokens_per_s_per_cpu_device"] for l in legs),
         "ttft_p99_s": legs[-1]["ttft_p99_s"],
         "per_token_p99_s": legs[-1]["per_token_p99_s"],
         "spec_accept_rate": next(
@@ -206,7 +210,7 @@ def main(argv=None) -> int:
                         and 0.0 <= leg[lo] <= leg[hi]):
                     fail(f"rate {leg['arrival_rate_req_s']}: quantiles "
                          f"{lo}={leg[lo]} {hi}={leg[hi]} not ordered/finite")
-            if leg["tokens_per_s_per_chip"] <= 0:
+            if leg["tokens_per_s_per_cpu_device"] <= 0:
                 fail("zero serving throughput")
         if ms["predicted_kv_cache_bytes"] <= 0 or \
                 ms["actual_kv_cache_bytes_per_device"] != \

@@ -145,7 +145,7 @@ def _run(eng, gc, n, prompt_len, max_new, n_dev):
         "requests": len(done),
         "tokens": toks,
         "wall_s": round(wall, 3),
-        "tokens_per_s_per_chip": round(toks / wall / n_dev, 2),
+        "tokens_per_s_per_cpu_device": round(toks / wall / n_dev, 2),
         "spec_rounds": sched.stats["spec_rounds"],
         "spec_accept_rate": (
             round(sched.stats["spec_accepted_tokens"] / drafted, 4)
@@ -196,7 +196,7 @@ def _speculation_legs(check: bool, depths, n_requests: int, cache_dir: str,
         leg["name"] = f"spec-K{K}"
         leg["spec_tokens"] = K
         leg["speedup_vs_baseline"] = round(
-            leg["tokens_per_s_per_chip"] / base_leg["tokens_per_s_per_chip"],
+            leg["tokens_per_s_per_cpu_device"] / base_leg["tokens_per_s_per_cpu_device"],
             3)
         leg["bitwise_parity"] = streams == base_streams
         if not leg["bitwise_parity"]:
@@ -207,8 +207,8 @@ def _speculation_legs(check: bool, depths, n_requests: int, cache_dir: str,
         if not leg["all_complete"]:
             fails.append(f"spec K={K}: incomplete requests")
         legs.append(leg)
-        if best is None or leg["tokens_per_s_per_chip"] > \
-                best["tokens_per_s_per_chip"]:
+        if best is None or leg["tokens_per_s_per_cpu_device"] > \
+                best["tokens_per_s_per_cpu_device"]:
             best = leg
     return {
         "devices": n_dev,
@@ -218,7 +218,7 @@ def _speculation_legs(check: bool, depths, n_requests: int, cache_dir: str,
         "spec_speedup_best": best["speedup_vs_baseline"],
         "spec_accept_rate_best": best["spec_accept_rate"],
         "spec_tokens_best": best["spec_tokens"],
-        "baseline_tokens_per_s_per_chip": base_leg["tokens_per_s_per_chip"],
+        "baseline_tokens_per_s_per_cpu_device": base_leg["tokens_per_s_per_cpu_device"],
     }
 
 
@@ -262,7 +262,7 @@ def _int8_divergence_leg(check: bool, cache_dir: str, fails: list):
     if not leg["all_complete"]:
         fails.append("int8 serving leg: incomplete requests")
     out["int8_serve"] = leg
-    out["int8_tokens_per_s_per_chip"] = leg["tokens_per_s_per_chip"]
+    out["int8_tokens_per_s_per_cpu_device"] = leg["tokens_per_s_per_cpu_device"]
     return out
 
 
@@ -279,6 +279,10 @@ def main(argv=None) -> int:
                         "divergence + KV accounting asserted; the speedup "
                         "gate is skipped (acceptance ~0 untrained)")
     args = p.parse_args(argv)
+    import jax  # a CPU-mesh counting tool: say what it ran on
+    print(f"[bench_spec] platform={jax.default_backend()} "
+          f"devices={len(jax.devices())}: counts and parity "
+          "facts, never a device metric", file=sys.stderr)
     depths = [int(s) for s in args.depths.split(",") if s.strip()]
     if args.check:
         depths = depths[:1]
@@ -301,7 +305,7 @@ def main(argv=None) -> int:
         "spec_speedup_best": spec["spec_speedup_best"],
         "spec_accept_rate_best": spec["spec_accept_rate_best"],
         "spec_tokens_best": spec["spec_tokens_best"],
-        "int8_tokens_per_s_per_chip": int8["int8_tokens_per_s_per_chip"],
+        "int8_tokens_per_s_per_cpu_device": int8["int8_tokens_per_s_per_cpu_device"],
         "int8_kv_shard_degree": int8["int8_kv_shard_degree"],
         "bf16_kv_shard_degree": int8["bf16_kv_shard_degree"],
         "legs_passed": int(not fails),

@@ -163,18 +163,28 @@ def leg_hot_swap(eng, eng_ref, gc, cm, root, n_requests, rate, seed, checks):
     reqs = _trace(rng, n_requests, rate, gc.vocab, max(2, gc.seq // 4),
                   eng.max_decode_len)
     sched = _scheduler(eng)
+    # the run is sized by EVENTS, not by wall time: the tail of the trace is
+    # held back (arrival = never) until the first swap has landed, so the
+    # trace cannot drain before the snapshot is written and the watcher's
+    # poll has seen it. The scheduler idles (and keeps polling) meanwhile.
+    tail = reqs[-max(2, n_requests // 4):]
+    for r in tail:
+        r.arrival_s = float("inf")
 
     def dropper():
         # first snapshot once serving has actually started (slots are in
-        # flight), the second once the first swap landed — guarantees
-        # both pointer flips happen with live traffic when timing allows
+        # flight); once the first swap landed, the held tail arrives and the
+        # second snapshot drops — both pointer flips happen with live traffic
         deadline = time.monotonic() + 30.0
         while sched.prefills < 1 and time.monotonic() < deadline:
             time.sleep(0.01)
         _snapshot(cm, root, 1)
-        deadline = time.monotonic() + 10.0
+        deadline = time.monotonic() + 30.0
         while sched.stats["swaps"] < 1 and time.monotonic() < deadline:
             time.sleep(0.01)
+        now = sched._now()
+        for i, r in enumerate(tail):   # released on a deadline too, so the
+            r.arrival_s = now + 0.01 * i  # run ends and the check can fail
         _snapshot(cm, root, 2)
 
     th = threading.Thread(target=dropper, daemon=True)
@@ -376,6 +386,10 @@ def main(argv=None) -> int:
     p.add_argument("--check", action="store_true",
                    help="CI smoke: tiny twin, assert every leg invariant")
     args = p.parse_args(argv)
+    import jax  # a CPU-mesh counting tool: say what it ran on
+    print(f"[bench_swap] platform={jax.default_backend()} "
+          f"devices={len(jax.devices())}: counts and parity "
+          "facts, never a device metric", file=sys.stderr)
     if args.check:
         args.requests = min(args.requests, 16)
         args.rate = min(args.rate, 6.0)

@@ -183,7 +183,7 @@ def leg_twin_vs_live(checks, seed, bound, n_requests=80, overload=3.0):
         toks = sum(len(r.tokens) for r in done)
         live_hists = sched.tracer.hists if sched.tracer else {}
         live = {
-            "tokens_per_s_per_chip": toks / wall / n_dev,
+            "tokens_per_s_per_cpu_device": toks / wall / n_dev,
             "ttft_p99_s": live_hists["ttft"].quantile(0.99),
         }
 
@@ -208,7 +208,7 @@ def leg_twin_vs_live(checks, seed, bound, n_requests=80, overload=3.0):
                    f"source={costs.source}")
         sim = simulate(trace.records, spec, costs)
         twin = {
-            "tokens_per_s_per_chip": sim.stats["tokens_per_s"] / n_dev,
+            "tokens_per_s_per_cpu_device": sim.stats["tokens_per_s"] / n_dev,
             "ttft_p99_s": sim.hists["ttft"].quantile(0.99),
         }
         val = validate(live, twin, max_rel_err=bound)
@@ -377,6 +377,10 @@ def main(argv=None) -> int:
                    help="CI smoke: relaxed twin-vs-live bound (CPU timing "
                         "jitter), no fleet-ratio gates")
     args = p.parse_args(argv)
+    import jax  # a CPU-mesh counting tool: say what it ran on
+    print(f"[bench_twin] platform={jax.default_backend()} "
+          f"devices={len(jax.devices())}: counts and parity "
+          "facts, never a device metric", file=sys.stderr)
     bound = max(args.bound, 0.5) if args.check else args.bound
 
     checks = Checks()

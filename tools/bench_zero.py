@@ -159,6 +159,10 @@ def main(argv=None) -> int:
                         "opt-state reduction (predicted AND actual), 1e-6 "
                         "loss parity, and accum equivalence")
     args = p.parse_args(argv)
+    import jax  # a CPU-mesh counting tool: say what it ran on
+    print(f"[bench_zero] platform={jax.default_backend()} "
+          f"devices={len(jax.devices())}: counts and parity "
+          "facts, never a device metric", file=sys.stderr)
     if args.check:
         args.model, args.epochs, args.repeats = "gpt2_check", 2, 1
 

@@ -48,7 +48,7 @@ def build_example() -> str:
 def run_example(n_devices: int = 4) -> str:
     exe = build_example()
     env = dict(os.environ)
-    env["FLEXFLOW_PLATFORM"] = "cpu"
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
                         + f" --xla_force_host_platform_device_count={n_devices}")
     env["PYTHONPATH"] = ROOT + os.pathsep + env.get("PYTHONPATH", "")
