@@ -845,84 +845,97 @@ class CompiledModel:
              checkpoint_every_secs=None):
         from flexflow_tpu.runtime.resilience import FitResilience
 
-        xs = x if isinstance(x, (list, tuple)) else [x]
-        batch_size = batch_size or self.cfg.batch_size
-        epochs = epochs or self.cfg.epochs
-        if self.params is None:
-            self.init()
-        batch_size = self._coerce_batch(batch_size)
-        # resilience (runtime/resilience.py): durable periodic checkpoints,
-        # SIGTERM/SIGINT drain, resume="auto". None when fully off — the
-        # loop below then runs exactly the PR-2 async pipeline.
-        res = FitResilience.build(self, resume, checkpoint_dir,
-                                  checkpoint_every_steps,
-                                  checkpoint_every_secs)
-        if res is not None:
-            # effective (per-call) knobs, not cfg: they define what the
-            # manifest's progress counters mean, for save AND resume check
-            res.set_effective(batch_size, self._accum_steps)
-        # goodput accounting (flexflow_tpu/health.py): one meter per fit;
-        # restore-from-checkpoint time is the "resume" bucket (it happens
-        # before any epoch wall-clock starts)
-        gm = self._goodput = health.GoodputMeter()
-        t_res = time.perf_counter()
-        progress = res.resume_now(verbose) if res is not None else None
-        gm.add("resume", time.perf_counter() - t_res)
-        loader = SingleDataLoader(xs, y, batch_size, shuffle=True, seed=self.cfg.seed)
-        in_sh = [self.input_sharding(t) for t in self.model.input_tensors]
-        lab_sh = self.label_sharding((batch_size,) + tuple(np.asarray(y).shape[1:]))
-        base_rng = jax.random.PRNGKey(self.cfg.seed + 17)
-        self._drift_windows = []  # this fit's drift-monitor windows
-        history = []
-        # --profiling (reference config.h:126): capture an xplane trace of
-        # the whole fit (the Legion-trace/profiler analog, flexflow_c.cc:1747)
-        prof_ctx = None
-        if self.cfg.profiling:
-            import os
+        with tel.span("fit/call", cat="fit") as call:
+            with tel.span("fit/setup", cat="fit"):
+                xs = x if isinstance(x, (list, tuple)) else [x]
+                batch_size = batch_size or self.cfg.batch_size
+                epochs = epochs or self.cfg.epochs
+                if self.params is None:
+                    self.init()
+                batch_size = self._coerce_batch(batch_size)
+                # resilience (runtime/resilience.py): durable periodic
+                # checkpoints, SIGTERM/SIGINT drain, resume="auto". None
+                # when fully off — the loop below then runs exactly the
+                # PR-2 async pipeline.
+                res = FitResilience.build(self, resume, checkpoint_dir,
+                                          checkpoint_every_steps,
+                                          checkpoint_every_secs)
+                if res is not None:
+                    # effective (per-call) knobs, not cfg: they define
+                    # what the manifest's progress counters mean, for save
+                    # AND resume check
+                    res.set_effective(batch_size, self._accum_steps)
+                # goodput accounting (flexflow_tpu/health.py): one meter
+                # per fit; restore-from-checkpoint time is the "resume"
+                # bucket (it happens before any epoch wall-clock starts)
+                gm = self._goodput = health.GoodputMeter()
+                t_res = time.perf_counter()
+                progress = res.resume_now(verbose) if res is not None \
+                    else None
+                gm.add("resume", time.perf_counter() - t_res)
+                loader = SingleDataLoader(xs, y, batch_size, shuffle=True,
+                                          seed=self.cfg.seed)
+                in_sh = [self.input_sharding(t)
+                         for t in self.model.input_tensors]
+                lab_sh = self.label_sharding(
+                    (batch_size,) + tuple(np.asarray(y).shape[1:]))
+                base_rng = jax.random.PRNGKey(self.cfg.seed + 17)
+                self._drift_windows = []  # this fit's drift-monitor windows
+                # --profiling (reference config.h:126): capture an xplane
+                # trace of the whole fit (the Legion-trace/profiler analog,
+                # flexflow_c.cc:1747)
+                prof_ctx = None
+                if self.cfg.profiling:
+                    import os
 
-            pdir = self.cfg.profile_dir or "./ff_profile"
-            os.makedirs(pdir, exist_ok=True)
-            prof_ctx = jax.profiler.trace(pdir)
-            prof_ctx.__enter__()
-        try:
-            history = self._fit_epochs(epochs, loader, in_sh, lab_sh,
-                                       base_rng, batch_size, callbacks,
-                                       verbose, sync_every,
-                                       steps_per_dispatch, res, progress,
-                                       gm)
-        finally:
-            if prof_ctx is not None:
-                prof_ctx.__exit__(None, None, None)
-                if verbose:
-                    print(f"[profiling] trace written to "
-                          f"{self.cfg.profile_dir or './ff_profile'}")
-        self._fit_end_report(verbose)
-        # per-op work only on the success path (it launches measurement
-        # jits; on an error path it would mask the real exception).
-        # --profile-ops: attribute the fit's REAL measured step time to
-        # individual ops (flexflow_tpu/attribution.py) — only when someone
-        # consumes the result (printed table or the telemetry corpus), and
-        # not when profile_report below runs the same join anyway
-        will_report = prof_ctx is not None and verbose
-        if self.cfg.profile_ops and (verbose or tel.enabled()) \
-                and not will_report:
-            self.op_attribution(print_table=verbose)
-        if will_report:
-            self.profile_report()
-        # self-calibration (ISSUE 14): --auto-refit closes the drift loop —
-        # fold this run's telemetry through span_dataset into a refreshed
-        # learned cost model. Runs AFTER op_attribution so the refit sees
-        # THIS fit's op/attr rows, and on every profiled fit (not only a
-        # tripped drift warn) so the corpus keeps growing; the model file's
-        # content hash re-keys the strategy cache either way.
-        if getattr(self.cfg, "auto_refit", False):
-            from flexflow_tpu.search.learned_cost import auto_refit
+                    pdir = self.cfg.profile_dir or "./ff_profile"
+                    os.makedirs(pdir, exist_ok=True)
+                    prof_ctx = jax.profiler.trace(pdir)
+                    prof_ctx.__enter__()
+            it0 = self._iteration
+            try:
+                history = self._fit_epochs(epochs, loader, in_sh, lab_sh,
+                                           base_rng, batch_size, callbacks,
+                                           verbose, sync_every,
+                                           steps_per_dispatch, res, progress,
+                                           gm)
+            finally:
+                if prof_ctx is not None:
+                    prof_ctx.__exit__(None, None, None)
+                    if verbose:
+                        print(f"[profiling] trace written to "
+                              f"{self.cfg.profile_dir or './ff_profile'}")
+            call.set(steps=self._iteration - it0)
+            with tel.span("fit/finish", cat="fit"):
+                self._fit_end_report(verbose)
+                # per-op work only on the success path (it launches
+                # measurement jits; on an error path it would mask the real
+                # exception). --profile-ops: attribute the fit's REAL
+                # measured step time to individual ops
+                # (flexflow_tpu/attribution.py) — only when someone consumes
+                # the result (printed table or the telemetry corpus), and
+                # not when profile_report below runs the same join anyway
+                will_report = prof_ctx is not None and verbose
+                if self.cfg.profile_ops and (verbose or tel.enabled()) \
+                        and not will_report:
+                    self.op_attribution(print_table=verbose)
+                if will_report:
+                    self.profile_report()
+                # self-calibration (ISSUE 14): --auto-refit closes the drift
+                # loop — fold this run's telemetry through span_dataset into
+                # a refreshed learned cost model. Runs AFTER op_attribution
+                # so the refit sees THIS fit's op/attr rows, and on every
+                # profiled fit (not only a tripped drift warn) so the corpus
+                # keeps growing; the model file's content hash re-keys the
+                # strategy cache either way.
+                if getattr(self.cfg, "auto_refit", False):
+                    from flexflow_tpu.search.learned_cost import auto_refit
 
-            info = auto_refit(self.cfg)
-            if info is not None and verbose:
-                print(f"[refit] cost model <- {info['rows']} corpus rows "
-                      f"({len(info['kinds'])} op kinds) -> {info['path']} "
-                      f"[{info['fingerprint']}]")
+                    info = auto_refit(self.cfg)
+                    if info is not None and verbose:
+                        print(f"[refit] cost model <- {info['rows']} corpus "
+                              f"rows ({len(info['kinds'])} op kinds) -> "
+                              f"{info['path']} [{info['fingerprint']}]")
         return history
 
     def _fit_end_report(self, verbose: bool) -> None:
@@ -1011,15 +1024,10 @@ class CompiledModel:
         lab_sh_k = NamedSharding(self.mesh,
                                  PartitionSpec(None, *lab_sh_u.spec))
         stats = self.step_stats = {"dispatches": 0, "host_syncs": 0,
-                                   "barriers": 0, "fused_steps": 0}
-        # telemetry + xplane step labels: `rec` is captured once (a local
-        # bool) so the disabled path stays the exact PR-2 loop — same
-        # dispatches, same host syncs, no per-step allocations beyond it.
-        # Under --profiling each dispatch also runs inside a
-        # StepTraceAnnotation, so the xplane trace is step-labeled.
-        rec = tel.enabled()
-        prof = jax.profiler.StepTraceAnnotation if self.cfg.profiling \
-            else None
+                                   "barriers": 0, "fused_steps": 0,
+                                   "epoch_end_syncs": 0}
+        # every tel.span below times and labels (ring, profiler step
+        # annotation, file sink); none adds a dispatch or a host sync
         faults_on = _faults.active()
         if res is not None:
             res.install_guard()
@@ -1089,14 +1097,10 @@ class CompiledModel:
                                        _pm.sums, _pm.train_all, history)
 
               while True:
-                  # telemetry: the gap between "want next batch" and
-                  # "prefetcher delivered" is the data-wait cost the async
-                  # loop is supposed to hide
-                  if rec:
-                      t_w = tel.now_us()
-                      item = next(gen, None)
-                      tel.record("fit/prefetch_wait", t_w, cat="fit")
-                  else:
+                  # the gap between "want next batch" and "prefetcher
+                  # delivered" is the data-wait cost the async loop is
+                  # supposed to hide
+                  with tel.span("fit/prefetch_wait", cat="fit"):
                       item = next(gen, None)
                   gm.lap("prefetch_wait")
                   if item is None:
@@ -1127,11 +1131,8 @@ class CompiledModel:
                                   self.params = \
                                       jax.tree_util.tree_unflatten(
                                           tdef, leaves)
-                  if rec:
-                      t_d = tel.now_us()
-                  ann = prof("train", step_num=self._iteration) \
-                      if prof is not None else tel.NULL_SPAN
-                  with ann:
+                  with tel.span("fit/dispatch", cat="fit",
+                                step_num=self._iteration, kind=kind) as sp:
                       if kind == "k":
                           (self.params, self.opt_state, self.state, loss,
                            mvals) = multi(self.params, self.opt_state,
@@ -1146,34 +1147,29 @@ class CompiledModel:
                                                     self.opt_state,
                                                     self.state, dx, dy, rng)
                           steps = 1
+                      sp.set(steps=steps, iteration=self._iteration + steps)
                   gm.lap("dispatch")
                   self._iteration += steps
                   nb += steps
                   since_sync += steps
                   ep_disp += 1
                   stats["dispatches"] += 1
-                  if rec:
-                      tel.record("fit/dispatch", t_d, cat="fit", kind=kind,
-                                 steps=steps, iteration=self._iteration)
                   if sent is not None:
                       sent.push(steps, mvals)  # strips health/* keys
                   pml.update_deferred(steps, {"loss": loss})
                   pm.update_deferred(batch_size * accum * steps, mvals)
                   gm.lap("loop")
                   if sync and since_sync >= sync:
-                      if rec:
-                          t_s = tel.now_us()
-                      pml.materialize()
-                      pm.materialize()
-                      if sent is not None:
-                          # sentinel window check rides the EXISTING sync
-                          # (no extra materialization point)
-                          sent.check(self._iteration,
-                                     loss_sum=pml.sums.get("loss", 0.0),
-                                     steps_total=nb)
-                      if rec:
-                          tel.record("fit/host_sync", t_s, cat="fit",
-                                     iteration=self._iteration)
+                      with tel.span("fit/host_sync", cat="fit",
+                                    iteration=self._iteration):
+                          pml.materialize()
+                          pm.materialize()
+                          if sent is not None:
+                              # sentinel window check rides the EXISTING
+                              # sync (no extra materialization point)
+                              sent.check(self._iteration,
+                                         loss_sum=pml.sums.get("loss", 0.0),
+                                         steps_total=nb)
                       stats["host_syncs"] += 1
                       ep_sync += 1
                       since_sync = 0
@@ -1181,12 +1177,9 @@ class CompiledModel:
                   elif ep_disp % ahead == 0:
                       # bounded dispatch-ahead: wait for the device to catch
                       # up (no host transfer, just a queue-depth barrier)
-                      if rec:
-                          t_b = tel.now_us()
-                      jax.block_until_ready(loss)
-                      if rec:
-                          tel.record("fit/barrier_sync", t_b, cat="fit",
-                                     iteration=self._iteration)
+                      with tel.span("fit/barrier_sync", cat="fit",
+                                    iteration=self._iteration):
+                          jax.block_until_ready(loss)
                       stats["barriers"] += 1
                       gm.lap("barrier")
                   if res is not None:
@@ -1196,27 +1189,23 @@ class CompiledModel:
                       cb.on_batch_end(self._iteration, {"loss": float(loss)})
                   if kind == "1":
                       self._maybe_recompile()
-              # epoch end: the one unavoidable materialization (not counted
-              # as a mid-epoch host sync)
-              if rec:
-                  t_s = tel.now_us()
-              pml.materialize()
-              if sent is not None:
-                  sent.check(self._iteration,
-                             loss_sum=pml.sums.get("loss", 0.0),
-                             steps_total=nb)
-              if rec:
-                  tel.record("fit/host_sync", t_s, cat="fit",
-                             scope="epoch_end")
+              # epoch end: the one unavoidable materialization (counted on
+              # its own, not as a mid-epoch host sync)
+              with tel.span("fit/epoch_end_sync", cat="fit"):
+                  pml.materialize()
+                  if sent is not None:
+                      sent.check(self._iteration,
+                                 loss_sum=pml.sums.get("loss", 0.0),
+                                 steps_total=nb)
+              stats["epoch_end_syncs"] += 1
               gm.lap("host_sync")
               dt = time.perf_counter() - t0
               # drift/throughput count only work executed THIS session: a
               # resumed epoch's re-seeded steps/samples ran before the
               # snapshot, against a wall clock that started at resume
               self._drift_windows.append((nb - seed_steps, dt))
-              if rec:
-                  tel.record("fit/epoch", tel.now_us() - dt * 1e6,
-                             cat="fit", epoch=epoch, steps=nb)
+              tel.record("fit/epoch", tel.now_us() - dt * 1e6, cat="fit",
+                         epoch=epoch, steps=nb)
               grec = gm.epoch_end(dt, epoch)
               # HBM watermark at the epoch boundary (outside the epoch
               # wall; memory_stats() on real backends, live-buffer bytes

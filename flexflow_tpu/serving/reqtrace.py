@@ -21,10 +21,12 @@ Two pieces live here:
     (drafted vs committed vs rejected tokens), any param swap landing
     mid-flight, to the terminal outcome. Stages TILE the request's wall
     time (each span starts where the previous one ended), so accounting
-    is >=95% by construction; spans are emitted as `serve/req/<stage>`
-    through the existing telemetry sink with tid "slot<k>" (the Chrome
-    export reads as one timeline row per decode slot) and finished
-    traces are retained in a bounded ring for live queries.
+    is >=95% by construction; spans go as `serve/req/<stage>` into
+    telemetry's ring, always, and into its file sink when one is
+    configured, with tid "slot<k>" (the Chrome export reads as one
+    timeline row per decode slot), each naming the prefill `wave` or
+    decode `window` that ended it; finished traces are retained in a
+    bounded ring of their own for live queries.
 
 Zero-sync contract: the tracer NEVER reads a device value or calls
 perf_counter itself — every timestamp it sees is one the scheduler
@@ -240,12 +242,11 @@ class RequestTracer:
         tr["stages"].append({"stage": name, "start_s": start, "end_s": end,
                              **extra})
         tr["cursor"] = end
-        if tel.enabled():
-            slot = tr["slot"]
-            tel.record(f"serve/req/{name}", self._to_us(start),
-                       self._to_us(end), cat="serve",
-                       tid=("queue" if slot is None else f"slot{slot}"),
-                       rid=req.rid, **extra)
+        slot = tr["slot"]
+        tel.record(f"serve/req/{name}", self._to_us(start),
+                   self._to_us(end), cat="serve",
+                   tid=("queue" if slot is None else f"slot{slot}"),
+                   rid=req.rid, **extra)
 
     def on_admit(self, req, t_pre_s: float, t_first_s: float,
                  wave: int) -> None:
@@ -254,7 +255,7 @@ class RequestTracer:
         tr = self._live.get(req.rid)
         if tr is None:
             return
-        self.stage(req, "queue", t_pre_s)
+        self.stage(req, "queue", t_pre_s, wave=wave)
         tr["slot"] = req.slot
         self.stage(req, "prefill", t_first_s, wave=wave,
                    prompt_tokens=len(req.prompt))
@@ -265,13 +266,14 @@ class RequestTracer:
 
     def on_decode_window(self, active_reqs: Sequence[Any], end_s: float,
                          steps: int, per_step_s: float,
-                         tokens_kept: Dict[int, int]) -> None:
-        """One materialized dispatch window, attributed to every slot that
-        was active in it."""
+                         tokens_kept: Dict[int, int], window: int) -> None:
+        """One materialized dispatch window (the scheduler's `window`-th),
+        attributed to every slot that was active in it."""
         self.hists["decode_step"].add(per_step_s, n=max(1, steps))
         for req in active_reqs:
             self.stage(req, "decode", end_s, steps=steps,
-                       tokens=tokens_kept.get(req.slot, steps))
+                       tokens=tokens_kept.get(req.slot, steps),
+                       window=window)
 
     def on_spec_round(self, req, end_s: float, drafted: int, committed: int,
                       rejected: int) -> None:

@@ -374,7 +374,34 @@ class ContinuousBatchingScheduler:
         """Place as many waiting requests as slots/pages/chunk budget
         allow (priority-first), prefill them as one batch, commit K/V,
         record TTFT. Returns True if any were admitted. Host page tables
-        are pushed BEFORE the commit so the scatter sees the new pages."""
+        are pushed BEFORE the commit so the scatter sees the new pages.
+        One `serve/admit` span per wave, from the first placement to the
+        last first token; none when no batch formed."""
+        with tel.span("serve/admit", cat="serve",
+                      wave=self.prefills + 1) as wave:
+            with tel.span("serve/admit/place", cat="serve"):
+                batch = self._place(waiting, active, now_s)
+                if batch:
+                    self.kv.push()
+                    if self._spec:
+                        self.draft.kv.push()
+                    ids = np.zeros((self.slots, self.seq), np.int32)
+                    lengths = np.zeros((self.slots,), np.int32)
+                    for req in batch:
+                        n = min(len(req.prompt), self.seq)
+                        ids[req.slot, :n] = req.prompt[:n]
+                        lengths[req.slot] = n
+            if not batch:
+                wave.cancel()
+                return False
+            wave.set(requests=len(batch), prompt_tokens=int(lengths.sum()),
+                     padded_tokens=self.slots * self.seq)
+            return self._prefill_wave(batch, ids, lengths, active, next_host)
+
+    def _place(self, waiting: List[Request], active: Dict[int, Request],
+               now_s: float) -> List[Request]:
+        """The placement loop: requests that got a slot and their pages,
+        in admission order (removed from `waiting`)."""
         free = self.kv.free_slots()
         batch: List[Request] = []
         chunk_used = 0
@@ -418,24 +445,21 @@ class ContinuousBatchingScheduler:
             req.slot = slot
             chunk_used += len(req.prompt)
             batch.append(waiting.pop(i))
-        if not batch:
-            return False
-        self.kv.push()
-        if self._spec:
-            self.draft.kv.push()
-        ids = np.zeros((self.slots, self.seq), np.int32)
-        lengths = np.zeros((self.slots,), np.int32)
-        for req in batch:
-            n = min(len(req.prompt), self.seq)
-            ids[req.slot, :n] = req.prompt[:n]
-            lengths[req.slot] = n
+        return batch
+
+    def _prefill_wave(self, batch: List[Request], ids: np.ndarray,
+                      lengths: np.ndarray, active: Dict[int, Request],
+                      next_host: np.ndarray) -> bool:
+        """Prefill the placed batch, commit its K/V, bring the logits to
+        the host and take each request's first token."""
         t_pre = time.perf_counter()
         try:
-            logits, kv_state = run_resilient(
-                "serve/prefill",
-                lambda: self.engine.prefill(
-                    self.params, self.prompt_inputs_fn(ids, lengths)),
-                policy=self.retry_policy)
+            with tel.span("serve/prefill/dispatch", cat="serve"):
+                logits, kv_state = run_resilient(
+                    "serve/prefill",
+                    lambda: self.engine.prefill(
+                        self.params, self.prompt_inputs_fn(ids, lengths)),
+                    policy=self.retry_policy)
         except Exception as e:  # noqa: BLE001 — permanent prefill fault:
             for req in batch:   # fail ONLY the batch being admitted
                 self.kv.evict(req.slot)
@@ -446,8 +470,9 @@ class ContinuousBatchingScheduler:
             if self._spec:
                 self.draft.kv.push()
             return False
-        self.kv.commit_prefill(kv_state,
-                               np.arange(self.slots, dtype=np.int32), lengths)
+        with tel.span("serve/prefill/commit", cat="serve"):
+            self.kv.commit_prefill(
+                kv_state, np.arange(self.slots, dtype=np.int32), lengths)
         if self._spec:
             # the draft prefills the SAME prompt batch into its own cache;
             # positions stay pairwise consistent with the target from here
@@ -468,30 +493,38 @@ class ContinuousBatchingScheduler:
             self.draft.kv.commit_prefill(
                 dkv_state, np.arange(self.slots, dtype=np.int32), lengths)
         self.prefills += 1
-        lg = np.asarray(logits)  # sync: TTFT is a real materialization
+        # one sync point in two parts: waiting for the device, then moving
+        # the bytes (TTFT is a real materialization)
+        with tel.span("serve/prefill/device_wait", cat="serve"):
+            jax.block_until_ready(logits)
+        with tel.span("serve/prefill/logits_to_host", cat="serve") as sp:
+            lg = np.asarray(logits)
+            sp.set(bytes=int(lg.nbytes))
         t_first = time.perf_counter()
         serve_ms = 1e3 * (t_first - t_pre)
         self._ema_serve_ms = (serve_ms if not self._ema_serve_ms
                               else 0.5 * self._ema_serve_ms + 0.5 * serve_ms)
         t_pre_off = t_pre - self._t0
         t_first_off = t_first - self._t0
-        for req in batch:
-            first = int(lg[req.slot, lengths[req.slot] - 1].argmax())
-            req.tokens.append(first)
-            req.ttft_s = t_first_off - req.arrival_s
-            req.admit_s = t_pre_off
-            next_host[req.slot, 0] = first
-            active[req.slot] = req
-            if self.tracer is not None:
-                # closes the queue stage at prefill dispatch and spans the
-                # prefill wave to the TTFT sync — both timestamps already
-                # taken above, nothing extra is materialized
-                self.tracer.on_admit(req, t_pre_off, t_first_off,
-                                     wave=self.prefills)
-            tel.event("serve/request_admitted", cat="serve", rid=req.rid,
-                      slot=req.slot, prompt_len=int(lengths[req.slot]),
-                      priority=req.priority, ttft_s=req.ttft_s,
-                      queue_wait_s=max(0.0, t_pre_off - req.arrival_s))
+        with tel.span("serve/prefill/first_tokens", cat="serve"):
+            for req in batch:
+                first = int(lg[req.slot, lengths[req.slot] - 1].argmax())
+                req.tokens.append(first)
+                req.ttft_s = t_first_off - req.arrival_s
+                req.admit_s = t_pre_off
+                next_host[req.slot, 0] = first
+                active[req.slot] = req
+                if self.tracer is not None:
+                    # closes the queue stage at prefill dispatch and spans
+                    # the prefill wave to the TTFT sync — both timestamps
+                    # already taken above, nothing extra is materialized
+                    self.tracer.on_admit(req, t_pre_off, t_first_off,
+                                         wave=self.prefills)
+                tel.event("serve/request_admitted", cat="serve",
+                          rid=req.rid, slot=req.slot,
+                          prompt_len=int(lengths[req.slot]),
+                          priority=req.priority, ttft_s=req.ttft_s,
+                          queue_wait_s=max(0.0, t_pre_off - req.arrival_s))
         return True
 
     # ---------------------------------------------------------- tier rotation
@@ -593,11 +626,6 @@ class ContinuousBatchingScheduler:
         self._autotuned = True
         tuned = derive_prefetch_ahead(self._autotune_transfer_s,
                                       decode_step_s, self.prefetch_ahead)
-        tel.event("serve/kv_prefetch_autotune", cat="serve",
-                  learned_transfer_s=float(self._autotune_transfer_s),
-                  decode_step_s=float(decode_step_s),
-                  prefetch_ahead=int(tuned),
-                  fallback=int(self.prefetch_ahead))
         self.prefetch_ahead = tuned
 
     # -------------------------------------------------- disaggregated handoff
@@ -619,8 +647,6 @@ class ContinuousBatchingScheduler:
             req.slot = None
             moved = True
             self.handoffs += 1
-            tel.event("serve/request_handoff", cat="serve", rid=req.rid,
-                      pages=int(payload["pages"]), tokens=len(req.tokens))
             self.handoff(req, payload)
         if moved:
             self.kv.push()
@@ -643,8 +669,6 @@ class ContinuousBatchingScheduler:
             self.parked[slot] = req
             if self.tracer is not None:
                 self.tracer.on_submit(req, now_s)
-            tel.event("serve/request_adopted", cat="serve", rid=req.rid,
-                      slot=slot, pages=int(payload["pages"]))
         self._pending_handoffs = still
 
     def _emit_tier(self) -> None:
@@ -703,10 +727,27 @@ class ContinuousBatchingScheduler:
         inside the window is masked out of the committed advance), evicts
         finished slots, and applies the decode watchdog. Returns the last
         step's tokens (the next window's seed)."""
-        mats = [np.asarray(t) for t in window_toks]
-        steps = len(mats)
+        steps = len(window_toks)
+        window = self.materializations + 1
+        with tel.span("serve/decode/window_sync", cat="serve",
+                      window=window, steps=steps):
+            mats = [np.asarray(t) for t in window_toks]
         t_now = time.perf_counter()
         self.materializations += 1
+        with tel.span("serve/decode/commit", cat="serve",
+                      window=window) as sp:
+            seed, committed = self._commit_window(mats, state, active,
+                                                  window_t0, t_now)
+            sp.set(tokens_committed=committed)
+        return seed
+
+    def _commit_window(self, mats: List[np.ndarray], state,
+                       active: Dict[int, Request], window_t0: float,
+                       t_now: float):
+        """The host side of a drained window: extend token lists, advance
+        the KV mirrors, finish and evict. Returns (next seed, tokens
+        committed)."""
+        steps = len(mats)
         per_step = (t_now - window_t0) / steps
         self.step_times.extend([per_step] * steps)
         self._maybe_autotune(per_step)
@@ -727,25 +768,20 @@ class ContinuousBatchingScheduler:
             # it, using the t_now this sync already produced
             self.tracer.on_decode_window(
                 list(active.values()), t_now - self._t0, steps, per_step,
-                {slot: int(adv[slot]) for slot in active})
+                {slot: int(adv[slot]) for slot in active},
+                window=self.materializations)
         self.kv.adopt(state)
         self.kv.sync_after(steps, advances=adv)
         for slot in finished:
             self._finish(active.pop(slot), self._now())
-        if self.stats["overdecode_tokens"]:
-            tel.counter("serve/overdecode_tokens",
-                        self.stats["overdecode_tokens"], cat="serve")
         if self.decode_timeout_ms and active and \
                 per_step * 1e3 > self.decode_timeout_ms:
             # bounded-step watchdog: the window came back slower than the
             # per-step budget — evict the longest-resident slot instead
             # of letting one wedged sequence stall every neighbour
             self.stats["decode_timeouts"] += 1
-            tel.event("serve/decode_timeout", cat="serve",
-                      per_step_ms=1e3 * per_step,
-                      budget_ms=self.decode_timeout_ms)
             self._evict_wedged(active, "timeout", self._now(), None)
-        return mats[-1].copy()
+        return mats[-1].copy(), int(adv.sum())
 
     # --------------------------------------------------------- speculation
     def _spec_round(self, active: Dict[int, Request],
@@ -858,9 +894,6 @@ class ContinuousBatchingScheduler:
         if self.decode_timeout_ms and active and \
                 1e3 * wall / (K + 1) > self.decode_timeout_ms:
             self.stats["decode_timeouts"] += 1
-            tel.event("serve/decode_timeout", cat="serve",
-                      per_step_ms=1e3 * wall / (K + 1),
-                      budget_ms=self.decode_timeout_ms)
             self._evict_wedged(active, "timeout", self._now(), None)
         return out
 
@@ -873,6 +906,10 @@ class ContinuousBatchingScheduler:
         to completion; returns the COMPLETED ones with tokens + latency
         fields filled. Shed and failed requests land in `self.shed` /
         `self.failed` with their outcome + reason stamped."""
+        with tel.span("serve/run", cat="serve", requests=len(requests)):
+            return self._run(requests)
+
+    def _run(self, requests: List[Request]) -> List[Request]:
         self._t0 = time.perf_counter()
         if self.tracer is not None:
             self.tracer.begin(self._t0)
@@ -970,15 +1007,17 @@ class ContinuousBatchingScheduler:
                     # open loop: idle until the next arrival (short naps
                     # when watching, so snapshot polls keep happening)
                     wait = max(0.0, queue[0].arrival_s - self._now())
-                    time.sleep(min(wait, 0.05)
-                               if (self.engine.watching
-                                   or self.control is not None)
-                               else wait)
+                    with tel.span("serve/idle_wait", cat="serve"):
+                        time.sleep(min(wait, 0.05)
+                                   if (self.engine.watching
+                                       or self.control is not None)
+                                   else wait)
                 elif self.feed is not None and not waiting \
                         and not self.parked and not self._pending_handoffs:
                     # fed loop with nothing in hand: nap instead of
                     # spinning on the (still open) feed
-                    time.sleep(0.002)
+                    with tel.span("serve/idle_wait", cat="serve"):
+                        time.sleep(0.002)
                 continue
             if self._spec:
                 # speculative rounds are self-contained (draft chain +
@@ -995,38 +1034,40 @@ class ContinuousBatchingScheduler:
                 state = self.kv.state
                 next_dev = jnp.asarray(next_host)
                 continue
-            inputs = self.step_inputs_fn(next_dev, state)
-            try:
-                logits, state = run_resilient(
-                    "serve/decode_step",
-                    lambda s=state, ins=inputs:
-                        self.engine.decode_step(self.params, s, ins),
-                    policy=self.retry_policy)
-            except Exception as e:  # noqa: BLE001 — permanent decode fault
-                # drain what WAS dispatched successfully, then evict the
-                # wedged slot; every other slot keeps serving
-                if window_toks:
-                    next_host = self._materialize(window_toks, state, active,
-                                                  window_t0)
-                    window_toks = []
-                if active:
-                    self._evict_wedged(active, "failed", self._now(), e)
-                state = self.kv.state
-                next_dev = jnp.asarray(next_host)
-                window_t0 = time.perf_counter()
-                continue
-            with self.exec_lock:
-                # the argmax over model-sharded logits is its own collective
-                # program; under a fleet it must not interleave with a
-                # sibling replica's collectives (the engine call above
-                # serializes inside the proxy — this is the one launch the
-                # scheduler itself owns)
-                next_dev = jnp.argmax(
-                    logits[:, -1, :], axis=-1).astype(jnp.int32)[:, None]
-                if self._exec_serialized:
-                    jax.block_until_ready(next_dev)
-            window_toks.append(next_dev)
-            self.decode_steps += 1
+            with tel.span("serve/decode/dispatch", cat="serve",
+                          window=self.materializations + 1):
+                inputs = self.step_inputs_fn(next_dev, state)
+                try:
+                    logits, state = run_resilient(
+                        "serve/decode_step",
+                        lambda s=state, ins=inputs:
+                            self.engine.decode_step(self.params, s, ins),
+                        policy=self.retry_policy)
+                except Exception as e:  # noqa: BLE001 — permanent fault
+                    # drain what WAS dispatched successfully, then evict
+                    # the wedged slot; every other slot keeps serving
+                    if window_toks:
+                        next_host = self._materialize(
+                            window_toks, state, active, window_t0)
+                        window_toks = []
+                    if active:
+                        self._evict_wedged(active, "failed", self._now(), e)
+                    state = self.kv.state
+                    next_dev = jnp.asarray(next_host)
+                    window_t0 = time.perf_counter()
+                    continue
+                with self.exec_lock:
+                    # the argmax over model-sharded logits is its own
+                    # collective program; under a fleet it must not
+                    # interleave with a sibling replica's collectives (the
+                    # engine call above serializes inside the proxy — this
+                    # is the one launch the scheduler itself owns)
+                    next_dev = jnp.argmax(
+                        logits[:, -1, :], axis=-1).astype(jnp.int32)[:, None]
+                    if self._exec_serialized:
+                        jax.block_until_ready(next_dev)
+                window_toks.append(next_dev)
+                self.decode_steps += 1
         if self.tiered:
             # final tier ledger: counters into telemetry (monitor/prom) and
             # into stats (the bench + tests read them from here)

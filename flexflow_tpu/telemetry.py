@@ -6,41 +6,66 @@ and the whole-fit `jax.profiler.trace` each lived in their own corner with
 no shared event stream. This module is the shared stream: a lightweight,
 thread-safe, process-global sink that the compiler (graph_optimize /
 substitution rounds / DP / strategy-cache / simulator re-rank), the fit
-loop (prefetch wait / dispatch / host sync / barrier), the pipeline
-executor (per-stage, per-microbatch phase ops), the dataloader prefetch
-threads (queue occupancy) and the async checkpoint writer all emit into.
+loop (prefetch wait / dispatch / host sync / barrier), the serving
+scheduler (admit / prefill / decode windows), the pipeline executor
+(per-stage, per-microbatch phase ops), the dataloader prefetch threads
+(queue occupancy) and the async checkpoint writer all emit into.
 
 Design contract:
-  * OFF by default, near-zero overhead when disabled: `enabled()` is one
-    global read; hot loops guard their instrumentation on a local copy of
-    it and the `span()` helper returns a shared no-op context manager.
-    The disabled fit path performs exactly the same dispatches/host syncs
-    as before (tests/test_telemetry.py pins this against the PR-2
-    baseline counters).
-  * Enabled via `configure(dir)` — `--telemetry-dir` through FFConfig /
-    compile_model — writing JSON Lines to `<dir>/telemetry-<pid>.jsonl`.
-  * Timestamps are MICROSECONDS on a process-monotonic clock
-    (time.perf_counter since import), so events map 1:1 onto the Chrome
-    trace-event format `tools/trace_report.py` renders (ph "X" complete
-    span / "i" instant / "C" counter, ts/dur in us).
+  * Spans (`span()`, `record()`, JAX's compile phases) ALWAYS land in a
+    bounded in-memory ring (`RING_SIZE` records; `ring_spans()` reads it):
+    a flight recorder that is on when the rare slow call happens, and the
+    way a benchmark in the same process reads the program's spans. A
+    `span()` also enters a `jax.profiler.TraceAnnotation("ff/<name>")`, so
+    the same span lies in the profiler's host plane, on the profiler's
+    clock, whenever a profiler session is open. "Off" means: no profiler
+    session, no file sink; a span then costs two `perf_counter_ns` calls,
+    an inactive annotation and a deque append (about 2 us), and never adds
+    a dispatch or a host sync (tests/test_telemetry.py pins this against
+    the PR-2 baseline counters).
+  * The FILE sink is off by default and enabled via `configure(dir)` —
+    `--telemetry-dir` through FFConfig / compile_model — writing JSON
+    Lines to `<dir>/telemetry-<pid>.jsonl`. `enabled()` says whether it
+    is on; `event()` and `counter()` are sink-only.
+  * The ring's clock is `time.perf_counter_ns()`. The sink's timestamps
+    are MICROSECONDS on the same clock since import (`now_us()`), so
+    events map 1:1 onto the Chrome trace-event format
+    `tools/trace_report.py` renders (ph "X" complete span / "i" instant /
+    "C" counter, ts/dur in us).
 
-Record schema (one JSON object per line):
+Ring record: `Span(name, start_ns, end_ns, thread, parent, args, id)`;
+`parent` is the `id` of the span that was open on that thread (0: none).
+
+Sink record schema (one JSON object per line):
   {"name": str, "ph": "X"|"i"|"C", "ts": us, "dur": us (X only),
    "pid": int, "tid": thread-name, "cat": str?, "args": dict?}
 """
 
 from __future__ import annotations
 
+import itertools
 import json
 import os
 import statistics
 import threading
-import time
-from typing import Any, Dict, Iterable, List, Optional, Sequence, Tuple
+from collections import deque
+from time import perf_counter_ns
+from typing import (Any, Dict, Iterable, List, NamedTuple, Optional,
+                    Sequence, Tuple)
+
+from jax import monitoring as _monitoring
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 _LOCK = threading.Lock()
 _SINK: Optional["_Sink"] = None
-_T0 = time.perf_counter()  # process epoch all ts are relative to
+_T0_NS = perf_counter_ns()  # process epoch all sink ts are relative to
+
+# the ring holds this many spans; a benchmark run must stay under it
+# (`ring_peak()`), a long-lived process keeps the newest
+RING_SIZE = 65536
+ANNOTATION_PREFIX = "ff/"
+# a JAX compile phase shorter than this gets no ring record of its own
+JAX_SPAN_MIN_NS = 1_000_000
 
 # cost-model drift guardrail: measured/predicted step-time ratios beyond
 # this factor (either direction) flag the calibration as stale — the
@@ -184,8 +209,119 @@ def sink_path() -> Optional[str]:
 
 def now_us() -> float:
     """Microseconds on the process-monotonic clock (the ts domain of every
-    emitted event and of the Chrome trace export)."""
-    return (time.perf_counter() - _T0) * 1e6
+    sink event and of the Chrome trace export)."""
+    return (perf_counter_ns() - _T0_NS) / 1e3
+
+
+# ------------------------------------------------------------------- ring
+class Span(NamedTuple):
+    """One ring record. `start_ns`/`end_ns` are `perf_counter_ns()`."""
+    name: str
+    start_ns: int
+    end_ns: int
+    thread: str
+    parent: int                     # id of the enclosing span, 0 = none
+    args: Optional[Dict[str, Any]]
+    id: int
+
+
+class _ThreadState(threading.local):
+    def __init__(self):
+        self.stack: List[int] = []      # ids of the spans open here
+        # (start ns, seconds) of this thread's compile phases that no
+        # later phase has enclosed yet
+        self.phases: "deque[Tuple[int, float]]" = deque(maxlen=4096)
+
+
+_RING: "deque[Span]" = deque(maxlen=RING_SIZE)
+_IDS = itertools.count(1)
+_TLS = _ThreadState()
+_PEAK = 0                           # most records held before a clear
+
+# JAX's compile phases (jax.monitoring duration events) as spans
+_JAX_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "jax/trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jax/lower",
+    "/jax/core/compile/backend_compile_duration": "jax/backend_compile",
+}
+# always on: name -> [count, seconds] since the process began (a phase's
+# seconds are its own: less the phases nested in it)
+totals: Dict[str, List[float]] = {n: [0, 0.0] for n in _JAX_EVENTS.values()}
+# phases under JAX_SPAN_MIN_NS since the last flush:
+# name -> [count, seconds, first start ns, last end ns]
+_SMALL: Dict[str, List[Any]] = {}
+
+
+def _flush_small() -> None:
+    """One ring record per name for the short compile phases gathered
+    since the last flush (`args` holds their count and summed own seconds).
+    Flushed whenever a root span opens and before the ring is read, so
+    every phase that ended before a root span lies before it in the
+    ring, and 461 Mosaic lowerings are one record, not 461."""
+    with _LOCK:
+        gathered = list(_SMALL.items())
+        _SMALL.clear()
+    for name, (count, secs, start, end) in gathered:
+        _emit(name, start, end, "compile", "jax", 0,
+              {"count": count, "seconds": secs}, next(_IDS))
+
+
+def _on_jax_duration(event: str, secs: float, **kw: Any) -> None:
+    name = _JAX_EVENTS.get(event)
+    if name is None:
+        return
+    end = perf_counter_ns()
+    start = end - int(secs * 1e9)
+    # phases nest (a jit traced while another is traced): what is counted
+    # is a phase's OWN seconds, less the phases that ended inside it, so
+    # the totals add up to time spent and not to more
+    recent = _TLS.phases
+    own = secs
+    while recent and recent[-1][0] >= start:
+        own -= recent.pop()[1]
+    recent.append((start, secs))
+    own = max(0.0, own)
+    with _LOCK:
+        tot = totals[name]
+        tot[0] += 1
+        tot[1] += own
+        if end - start < JAX_SPAN_MIN_NS:
+            small = _SMALL.setdefault(name, [0, 0.0, start, end])
+            small[0] += 1
+            small[1] += own
+            small[3] = end
+            return
+    # parent = the span open on this thread: a compile inside fit/dispatch
+    # or serve/decode/dispatch names the step that recompiled
+    stack = _TLS.stack
+    _emit(name, start, end, "compile", None, stack[-1] if stack else 0,
+          {"fun": kw.get("fun_name"), "seconds": own}, next(_IDS))
+
+
+_monitoring.register_event_duration_secs_listener(_on_jax_duration)
+
+
+def ring_spans(name: Optional[str] = None,
+               since_ns: Optional[int] = None) -> List[Span]:
+    """The ring's records, oldest first (a span is recorded when it ENDS,
+    so a parent follows its children); optionally only those called
+    `name` and/or started at or after `since_ns`."""
+    _flush_small()
+    return [s for s in list(_RING)
+            if (name is None or s.name == name)
+            and (since_ns is None or s.start_ns >= since_ns)]
+
+
+def ring_clear() -> None:
+    global _PEAK
+    _PEAK = max(_PEAK, len(_RING))
+    _RING.clear()
+
+
+def ring_peak() -> int:
+    """The most records the ring has held; RING_SIZE means it wrapped (or
+    was about to) and the oldest records are gone."""
+    return max(_PEAK, len(_RING))
 
 
 def _base(name: str, ph: str, ts: float, cat: Optional[str],
@@ -202,26 +338,40 @@ def _base(name: str, ph: str, ts: float, cat: Optional[str],
     return obj
 
 
+def _emit(name: str, start_ns: int, end_ns: int, cat: Optional[str],
+          tid: Optional[str], parent: int, args: Optional[Dict[str, Any]],
+          span_id: int) -> None:
+    """One finished span: into the ring, and into the file sink if one is
+    configured."""
+    _RING.append(Span(name, start_ns, end_ns,
+                      tid if tid is not None
+                      else threading.current_thread().name,
+                      parent, args, span_id))
+    s = _SINK
+    if s is not None:
+        obj = _base(name, "X", (start_ns - _T0_NS) / 1e3, cat, args, tid=tid)
+        obj["dur"] = max(0.0, (end_ns - start_ns) / 1e3)
+        s.emit(obj)
+
+
 def record(name: str, start_us: float, end_us: Optional[float] = None,
            cat: Optional[str] = None, tid: Optional[str] = None,
            **args: Any) -> None:
-    """Emit a complete span from explicit timestamps — the hot-loop path:
-    callers guard on enabled(), stamp now_us() inline, and pay nothing
-    (not even a context-manager frame) when telemetry is off. `tid`
-    overrides the default thread-name track — the serving request tracer
-    uses "slot<k>" so the Chrome export reads as one row per decode slot
-    instead of one row per host thread."""
-    s = _SINK
-    if s is None:
-        return
+    """A complete span from explicit `now_us()` timestamps, for an
+    interval known only afterwards (the serving request tracer's stages,
+    whose boundaries are stamps the scheduler already took). It gets no
+    profiler annotation. `tid` overrides the default thread-name track —
+    the request tracer uses "slot<k>" so the Chrome export reads as one
+    row per decode slot instead of one row per host thread."""
     end = now_us() if end_us is None else end_us
-    obj = _base(name, "X", start_us, cat, args or None, tid=tid)
-    obj["dur"] = max(0.0, end - start_us)
-    s.emit(obj)
+    start_ns = _T0_NS + round(start_us * 1e3)
+    stack = _TLS.stack
+    _emit(name, start_ns, start_ns + round((end - start_us) * 1e3), cat,
+          tid, stack[-1] if stack else 0, args or None, next(_IDS))
 
 
 def event(name: str, cat: Optional[str] = None, **args: Any) -> None:
-    """Instant event (Chrome ph "i")."""
+    """Instant event (Chrome ph "i"); file sink only."""
     s = _SINK
     if s is None:
         return
@@ -247,7 +397,8 @@ def retry(site: str, attempt: int, exc: BaseException, **args: Any) -> None:
 
 
 def counter(name: str, value: float, cat: Optional[str] = None) -> None:
-    """Counter sample (Chrome ph "C") — e.g. dataloader queue occupancy."""
+    """Counter sample (Chrome ph "C") — e.g. dataloader queue occupancy;
+    file sink only."""
     s = _SINK
     if s is None:
         return
@@ -256,49 +407,63 @@ def counter(name: str, value: float, cat: Optional[str] = None) -> None:
 
 
 class _Span:
-    __slots__ = ("_name", "_cat", "_args", "_t0")
+    __slots__ = ("name", "args", "id", "parent", "_cat", "_ann", "_t0")
 
     def __init__(self, name: str, cat: Optional[str],
-                 args: Dict[str, Any]):
-        self._name = name
+                 step_num: Optional[int], args: Dict[str, Any]):
+        self.name = name
+        self.args = args
         self._cat = cat
-        self._args = args
+        label = ANNOTATION_PREFIX + name
+        self._ann = (TraceAnnotation(label) if step_num is None
+                     else StepTraceAnnotation(label, step_num=step_num))
+
+    def set(self, **args: Any) -> None:
+        """Add what is known only inside the span (bytes moved, steps)."""
+        self.args.update(args)
+
+    def cancel(self) -> None:
+        """Record nothing: the span turned out to cover no work (an
+        admission pass in which no batch formed)."""
+        self.name = None
 
     def __enter__(self) -> "_Span":
-        self._t0 = now_us()
+        stack = _TLS.stack
+        if stack:
+            self.parent = stack[-1]
+        else:
+            self.parent = 0
+            if _SMALL:
+                _flush_small()
+        self.id = next(_IDS)
+        stack.append(self.id)
+        # the annotation outside the stamps: the ring's span lies inside
+        # the profiler's, by the cost of one clock read at each end
+        self._ann.__enter__()
+        self._t0 = perf_counter_ns()
         return self
 
     def __exit__(self, et, ev, tb) -> bool:
-        args = self._args
+        t1 = perf_counter_ns()
+        self._ann.__exit__(et, ev, tb)
+        _TLS.stack.pop()
+        if self.name is None:
+            return False
         if et is not None:
-            args = dict(args, error=repr(ev))
-        record(self._name, self._t0, cat=self._cat, **args)
+            self.args["error"] = repr(ev)
+        _emit(self.name, self._t0, t1, self._cat, None, self.parent,
+              self.args or None, self.id)
         return False
 
 
-class _NullSpan:
-    """Shared no-op context manager: `with span(...)` costs two attribute
-    calls when telemetry is disabled (reentrant; one module singleton)."""
-
-    __slots__ = ()
-
-    def __enter__(self) -> "_NullSpan":
-        return self
-
-    def __exit__(self, *exc) -> bool:
-        return False
-
-
-NULL_SPAN = _NullSpan()
-
-
-def span(name: str, cat: Optional[str] = None, **args: Any):
-    """Context manager recording a complete span around its body. Returns
-    the shared no-op when disabled. For per-step hot loops prefer the
-    record()/now_us() pair under an enabled() guard."""
-    if _SINK is None:
-        return NULL_SPAN
-    return _Span(name, cat, args)
+def span(name: str, cat: Optional[str] = None,
+         step_num: Optional[int] = None, **args: Any) -> _Span:
+    """Context manager recording a complete span around its body: into
+    the ring, as the profiler annotation "ff/<name>" (a
+    `StepTraceAnnotation` when `step_num` is given), and into the file
+    sink when one is configured. The hot-loop helper: see the module
+    docstring for what it costs."""
+    return _Span(name, cat, step_num, args)
 
 
 # ------------------------------------------------------------------ readers

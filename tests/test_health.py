@@ -178,7 +178,8 @@ def test_sentinels_on_keep_baseline_counters(devices):
     cm_on, h_on = fit()  # health_sentinels defaults ON
     assert cm_on.cfg.health_sentinels is True
     assert cm_on.step_stats == {"dispatches": 16, "host_syncs": 0,
-                                "barriers": 0, "fused_steps": 0}
+                                "barriers": 0, "fused_steps": 0,
+                                "epoch_end_syncs": 2}
     assert not any(k.startswith("health/") for e in h_on for k in e)
     assert cm_on._sentinels is not None
     assert cm_on._sentinels.state.status()["nonfinite_steps"] == 0

@@ -458,3 +458,121 @@ def test_bench_reqtrace_check_smoke(devices, capsys):
 
     assert bench_reqtrace.main(["--check", "--requests", "8"]) == 0
     assert "CHECK PASS" in capsys.readouterr().out
+
+
+# ----------------------------------------------- scheduler spans (PR 25)
+def _ring_run(eng, gc, n=6, **kw):
+    from flexflow_tpu import telemetry as tel
+
+    tel.ring_clear()
+    sched = _sched(eng)
+    reqs = _reqs(n, gc, **kw)
+    sched.run(reqs)
+    spans = tel.ring_spans()
+    run = [s for s in spans if s.name == "serve/run"][-1]
+    return sched, reqs, spans, run
+
+
+def test_serve_run_children_cover_it(rt_serve):
+    """The scheduler's own spans account for its loop: the direct children
+    of serve/run cover at least 95 % of it."""
+    eng, gc = rt_serve
+    _sched_, reqs, spans, run = _ring_run(eng, gc, n=8, max_new=5)
+    assert run.args == {"requests": 8}
+    kids = sorted((s for s in spans if s.parent == run.id),
+                  key=lambda s: s.start_ns)
+    assert {s.name for s in kids} >= {"serve/admit", "serve/decode/dispatch",
+                                      "serve/decode/window_sync",
+                                      "serve/decode/commit"}
+    covered, edge = 0, run.start_ns
+    for s in kids:
+        assert run.start_ns <= s.start_ns and s.end_ns <= run.end_ns
+        covered += max(0, s.end_ns - max(edge, s.start_ns))
+        edge = max(edge, s.end_ns)
+    assert covered >= 0.95 * (run.end_ns - run.start_ns)
+
+
+def test_prefill_wave_spans_carry_the_counts(rt_serve):
+    eng, gc = rt_serve
+    sched, reqs, spans, run = _ring_run(eng, gc, n=6, prompt_len=5)
+    waves = [s for s in spans if s.name == "serve/admit"]
+    assert len(waves) == sched.prefills >= 2      # 6 requests, 4 slots
+    assert [w.args["wave"] for w in waves] == list(range(1, len(waves) + 1))
+    assert sum(w.args["requests"] for w in waves) == 6
+    assert sum(w.args["prompt_tokens"] for w in waves) == 6 * 5
+    assert all(w.args["padded_tokens"] == sched.slots * sched.seq
+               for w in waves)
+    copies = [s for s in spans if s.name == "serve/prefill/logits_to_host"]
+    logits_dtype = np.dtype(eng.cfg.compute_dtype
+                            if eng.cfg.compute_dtype != "bfloat16"
+                            else "float16")        # same item size
+    assert [c.args["bytes"] for c in copies] == \
+        [sched.slots * sched.seq * gc.vocab * logits_dtype.itemsize] \
+        * len(waves)
+    # each wave holds its parts, in order
+    for w in waves:
+        parts = [s.name for s in spans if s.parent == w.id]
+        assert parts == ["serve/admit/place", "serve/prefill/dispatch",
+                         "serve/prefill/commit", "serve/prefill/device_wait",
+                         "serve/prefill/logits_to_host",
+                         "serve/prefill/first_tokens"]
+
+
+def test_request_stage_spans_name_their_wave_and_window(rt_serve):
+    eng, gc = rt_serve
+    sched, reqs, spans, run = _ring_run(eng, gc, n=6)
+    waves = {s.args["wave"]: s for s in spans if s.name == "serve/admit"}
+    queued = {s.args["rid"]: s for s in spans if s.name == "serve/req/queue"}
+    assert set(queued) == {r.rid for r in reqs}
+    for r in reqs:
+        wave = waves[queued[r.rid].args["wave"]]
+        # the wave that admitted it: the request's prefill dispatch stamp
+        # (admit_s, from the scheduler's start) lies inside that span
+        t_admit = run.start_ns + int(r.admit_s * 1e9)
+        assert wave.start_ns - 1_000_000 <= t_admit <= wave.end_ns
+    windows = {s.args["window"] for s in spans
+               if s.name == "serve/decode/window_sync"}
+    # a drained window's stage names it (the residual stage that a
+    # finishing request closes has no steps and no window)
+    decoded = [s for s in spans if s.name == "serve/req/decode"
+               and "steps" in s.args]
+    assert decoded and {s.args["window"] for s in decoded} <= windows
+    commits = [s for s in spans if s.name == "serve/decode/commit"]
+    assert sum(c.args["tokens_committed"] for c in commits) == \
+        sum(len(r.tokens) - 1 for r in reqs)
+    steps = sum(s.args["steps"] for s in spans
+                if s.name == "serve/decode/window_sync")
+    assert steps == sched.decode_steps == \
+        len([s for s in spans if s.name == "serve/decode/dispatch"])
+
+
+def test_spans_change_no_token_and_no_dispatch(rt_serve, monkeypatch):
+    """Tokens, decode steps, prefill waves and materializations equal a
+    run in which every span is a no-op: the spans only time."""
+    import contextlib
+
+    from flexflow_tpu import telemetry as tel
+
+    eng, gc = rt_serve
+
+    def leg():
+        sched = _sched(eng)
+        done = sched.run(_reqs(6, gc, max_new=5))
+        return ({r.rid: list(r.tokens) for r in done}, sched.decode_steps,
+                sched.prefills, sched.materializations)
+
+    with_spans = leg()
+
+    class _Null(contextlib.nullcontext):
+        def __enter__(self):
+            return self
+
+        def set(self, **kw):
+            pass
+
+        def cancel(self):
+            pass
+
+    monkeypatch.setattr(tel, "span", lambda *a, **kw: _Null())
+    monkeypatch.setattr(tel, "record", lambda *a, **kw: None)
+    assert leg() == with_spans

@@ -162,7 +162,8 @@ def test_fused_fit_amortizes_dispatches(devices):
     _, h_sync = _fit_run(sync_every=1, steps_per_dispatch=1)
     cm, h_fused = _fit_run(sync_every=0, steps_per_dispatch=4)
     assert cm.step_stats == {"dispatches": 4, "host_syncs": 0,
-                             "barriers": 0, "fused_steps": 16}
+                             "barriers": 0, "fused_steps": 16,
+                             "epoch_end_syncs": 2}
     assert h_fused[-1]["dispatches"] == 2.0
     assert h_fused[-1]["loss"] == pytest.approx(h_sync[-1]["loss"], abs=1e-6)
     assert h_fused[-1]["accuracy"] == pytest.approx(

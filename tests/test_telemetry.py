@@ -82,8 +82,13 @@ def test_span_event_counter_roundtrip(tmp_path):
     assert all({"name", "ph", "ts", "pid", "tid"} <= set(e) for e in evs)
     tel.shutdown()
     assert not tel.enabled()
-    # spans become shared no-ops when disabled (and record() is a no-op)
-    assert tel.span("x") is tel.NULL_SPAN
+    # with the sink off a span still lands in the ring, and only there
+    tel.ring_clear()
+    with tel.span("unit/after_shutdown"):
+        pass
+    assert [s.name for s in tel.ring_spans()] == ["unit/after_shutdown"]
+    assert "unit/after_shutdown" not in {e["name"]
+                                         for e in tel.read_events(tdir)}
 
 
 def test_fit_emits_spans_and_drift(tmp_path, capsys):
@@ -94,7 +99,7 @@ def test_fit_emits_spans_and_drift(tmp_path, capsys):
     names = {e["name"] for e in evs}
     # every layer reported in: compile, fit loop, dataloader
     assert {"compile/compile_model", "fit/dispatch", "fit/prefetch_wait",
-            "fit/host_sync", "fit/epoch",
+            "fit/epoch_end_sync", "fit/epoch",
             "dataloader/queue_depth"} <= names, names
     # one dispatch span per dispatch the loop counted
     disp = [e for e in evs if e["name"] == "fit/dispatch"]
@@ -124,7 +129,8 @@ def test_disabled_telemetry_zero_overhead_and_bit_identical():
     assert not tel.enabled()
     # PR-2 baseline counters (test_step_pipeline pins the same numbers)
     assert cm_off.step_stats == {"dispatches": 16, "host_syncs": 0,
-                                 "barriers": 0, "fused_steps": 0}
+                                 "barriers": 0, "fused_steps": 0,
+                                 "epoch_end_syncs": 2}
     with tempfile.TemporaryDirectory() as td:
         cm_on, h_on = _fit(telemetry_dir=os.path.join(td, "tele"))
         tel.shutdown()
@@ -382,3 +388,170 @@ def test_drift_stats_thresholds():
                tel.drift_stats(1.0, [(1, 10.0), (1, 10.0)])):
         lines = tel.format_drift(d2)
         assert lines and all(l.startswith("[drift]") for l in lines)
+
+
+# ------------------------------------------------------------------ the ring
+def test_ring_is_on_with_no_sink():
+    """Spans land in the in-memory ring with no telemetry_dir anywhere;
+    events and counters stay sink-only."""
+    assert not tel.enabled()
+    tel.ring_clear()
+    with tel.span("unit/ring", cat="test", n=1) as sp:
+        sp.set(found=2)
+    tel.event("unit/event")
+    tel.counter("unit/counter", 1)
+    (rec,) = tel.ring_spans()
+    assert rec.name == "unit/ring" and rec.args == {"n": 1, "found": 2}
+    assert rec.end_ns >= rec.start_ns and rec.parent == 0
+    assert tel.ring_spans("unit/other") == []
+    assert tel.ring_spans(since_ns=rec.end_ns + 1) == []
+
+
+def test_ring_is_bounded_and_reports_its_peak():
+    tel.ring_clear()
+    t = tel.now_us()
+    for i in range(tel.RING_SIZE + 10):
+        tel.record("unit/fill", t, t + 1.0, i=i)
+    spans = tel.ring_spans()
+    assert len(spans) == tel.RING_SIZE == tel.ring_peak()
+    assert spans[0].args == {"i": 10}       # the oldest ten are gone
+    tel.ring_clear()
+    assert tel.ring_spans() == []
+    assert tel.ring_peak() == tel.RING_SIZE  # the peak outlives a clear
+
+
+def test_ring_keeps_parent_ids_and_cancelled_spans_out():
+    tel.ring_clear()
+    with tel.span("unit/outer") as outer:
+        with tel.span("unit/inner"):
+            tel.record("unit/afterwards", tel.now_us() - 5.0)
+        with tel.span("unit/nothing") as none:
+            none.cancel()
+    by = {s.name: s for s in tel.ring_spans()}
+    assert set(by) == {"unit/outer", "unit/inner", "unit/afterwards"}
+    assert by["unit/inner"].parent == by["unit/outer"].id == outer.id
+    assert by["unit/afterwards"].parent == by["unit/inner"].id
+    assert by["unit/outer"].parent == 0
+    # a child is recorded when it ends: before its parent
+    assert [s.name for s in tel.ring_spans()][-1] == "unit/outer"
+
+
+def test_ring_is_safe_from_two_threads():
+    """Two threads (and a reader) at once: no record lost, every child's
+    parent is a span of its own thread."""
+    import threading
+
+    tel.ring_clear()
+    n = 2000
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+
+    def work(tag):
+        for _ in range(n):
+            with tel.span(f"unit/{tag}/outer"):
+                with tel.span(f"unit/{tag}/inner"):
+                    pass
+
+    try:
+        threads = [threading.Thread(target=work, args=(t,), name=f"w-{t}")
+                   for t in ("a", "b")]
+        for th in threads:
+            th.start()
+        while any(th.is_alive() for th in threads):
+            tel.ring_spans()            # a reader while both write
+        for th in threads:
+            th.join(timeout=60)
+            assert not th.is_alive()
+    finally:
+        sys.setswitchinterval(old)
+    spans = tel.ring_spans()
+    assert len(spans) == 4 * n
+    by_id = {s.id: s for s in spans}
+    assert len(by_id) == 4 * n
+    for s in spans:
+        if s.name.endswith("/inner"):
+            parent = by_id[s.parent]
+            assert parent.thread == s.thread
+            assert parent.name == s.name.replace("inner", "outer")
+
+
+def test_sink_receives_the_same_records_as_the_ring(tmp_path):
+    tdir = str(tmp_path / "tele")
+    tel.configure(tdir)
+    tel.ring_clear()
+    with tel.span("unit/both", cat="test", k=3):
+        with tel.span("unit/child"):
+            pass
+    tel.flush()
+    ring = tel.ring_spans()         # by end; read_events sorts by start
+    lines = [e for e in tel.read_events(tdir) if e["ph"] == "X"]
+    assert [s.name for s in ring] == ["unit/child", "unit/both"]
+    assert [e["name"] for e in lines] == ["unit/both", "unit/child"]
+    for e, s in zip(lines, reversed(ring)):
+        assert e["dur"] == pytest.approx((s.end_ns - s.start_ns) / 1e3)
+        assert e.get("args") == s.args and e["tid"] == s.thread
+
+
+def test_nested_compile_phases_count_once():
+    """A jit traced while another is traced: the totals (and each
+    record's `seconds`) hold a phase's own time, so they add up to time
+    spent; phases under a millisecond gather into one record."""
+    ev = "/jax/core/compile/jaxpr_trace_duration"
+    tel.ring_clear()
+    before = list(tel.totals["jax/trace"])
+    time.sleep(0.02)
+    tel._on_jax_duration(ev, 0.0004)          # short, nested in the next
+    tel._on_jax_duration(ev, 0.004)           # nested in the next
+    tel._on_jax_duration(ev, 0.010, fun_name="outer")
+    tel._on_jax_duration("/jax/some/other_event", 5.0)
+    count, secs = tel.totals["jax/trace"]
+    assert count - before[0] == 3
+    assert secs - before[1] == pytest.approx(0.010)
+    recs = tel.ring_spans("jax/trace")
+    assert sorted(round(r.args["seconds"], 6) for r in recs) == \
+        [0.0004, 0.0036, 0.006]
+    assert [r.args.get("fun") for r in recs if "count" not in r.args] == \
+        [None, "outer"]
+
+
+def test_fit_call_span_tree_and_epoch_end_counter():
+    """fit/call holds fit/setup, one fit/dispatch per dispatch, one
+    fit/epoch_end_sync per epoch and fit/finish; the epoch-end
+    materialization is a program counter now."""
+    tel.ring_clear()
+    cm, _ = _fit(epochs=2)
+    assert cm.step_stats["epoch_end_syncs"] == 2
+    spans = tel.ring_spans()
+    call = [s for s in spans if s.name == "fit/call"][-1]
+    assert call.args == {"steps": 16}
+    kids = [s for s in spans if s.parent == call.id]
+    names = [s.name for s in kids]
+    assert names[0] == "fit/setup" and names[-1] == "fit/finish"
+    assert names.count("fit/dispatch") == cm.step_stats["dispatches"] == 16
+    assert names.count("fit/epoch_end_sync") == 2
+    assert names.count("fit/epoch") == 2
+    assert names.count("fit/host_sync") == cm.step_stats["host_syncs"] == 0
+    assert all(call.start_ns <= s.start_ns and s.end_ns <= call.end_ns
+               for s in kids)
+    disp = [s for s in kids if s.name == "fit/dispatch"]
+    assert [s.args["iteration"] for s in disp] == list(range(1, 17))
+    assert all(s.args["steps"] == 1 and s.args["kind"] == "1" for s in disp)
+
+
+def test_recompile_inside_a_step_names_the_dispatch():
+    """The first dispatch of a fresh model compiles the step: the
+    jax/backend_compile span's parent is that fit/dispatch."""
+    tel.ring_clear()
+    _fit(epochs=1, n=64)
+    spans = tel.ring_spans()
+    by_id = {s.id: s for s in spans}
+    first = [s for s in spans if s.name == "fit/dispatch"][0]
+    compiles = [s for s in spans if s.name == "jax/backend_compile"
+                and s.parent == first.id]
+    assert compiles, [(s.name, by_id.get(s.parent)) for s in spans
+                      if s.name.startswith("jax/")]
+    assert all(first.start_ns <= s.start_ns and s.end_ns <= first.end_ns
+               for s in compiles)
+    later = [s for s in spans if s.name == "fit/dispatch"][1:]
+    assert not any(s.parent in {d.id for d in later} for s in spans
+                   if s.name == "jax/backend_compile")
