@@ -304,11 +304,15 @@ def run_serve(model, cm, gcfg, seed: int, n_requests: int = 6,
                                dec_state)
     decode_text = dispatched_text(eng._decode_jit, eng.params, dec_state,
                                   list(step_in))
+    # the program the scheduler dispatched: the one that takes the first
+    # tokens on the device (the full-logits jit compiled nothing)
+    assert eng._prefill_jit._cache_size() == 0
+    no_lengths = np.zeros((eng.slots,), np.int32)
     prefill_text = dispatched_text(
-        eng._prefill_jit, eng.params,
+        eng._prefill_first_tokens_jit, eng.params,
         [jax.numpy.asarray(a) for a in gpt2_prompt_inputs(
-            np.zeros((eng.slots, gcfg.seq), np.int32),
-            np.zeros((eng.slots,), np.int32))])
+            np.zeros((eng.slots, gcfg.seq), np.int32), no_lengths)],
+        jax.numpy.asarray(no_lengths))
     emit(phase="serve", requests=n_requests, completed=len(complete),
          shed=len(sched.shed), failed=len(sched.failed),
          prompt_tokens=[len(r.prompt) for r in reqs], max_new_tokens=max_new,

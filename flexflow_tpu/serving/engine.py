@@ -232,6 +232,20 @@ def compile_serving(model, max_batch_slots: Optional[int] = None,
                                draft=draft_engine)
 
 
+def _positionwise_head(prefill_model) -> Optional[Any]:
+    """The prefill graph's last layer when it acts on every position alone
+    (a Dense over the last axis of a `[slots, S, d]` tensor some layer
+    made), else None."""
+    last = prefill_model.layers[-1]
+    if last.op_type is not OperatorType.LINEAR or len(last.inputs) != 1 \
+            or callable(last.params.get("activation")):
+        return None
+    x = last.inputs[0]
+    if x.owner is None or len(x.spec.shape) != 3:
+        return None
+    return last
+
+
 class ServingCompiled:
     """The two jitted serving programs + the paged cache they share."""
 
@@ -277,23 +291,45 @@ class ServingCompiled:
 
         pre_out = prefill_model.layers[-1].outputs[:1]
         dec_out = decode_model.layers[-1].outputs[:1]
+        fwd_kw = dict(seq_length=self.cfg.seq_length or None,
+                      compute_dtype=self.cfg.compute_dtype,
+                      enable_fusion=self.cfg.enable_fusion)
         pre_fwd = build_forward(prefill_model.layers,
                                 prefill_model.input_tensors, pre_out, mesh,
-                                prefill_strategy,
-                                seq_length=self.cfg.seq_length or None,
-                                compute_dtype=self.cfg.compute_dtype,
-                                enable_fusion=self.cfg.enable_fusion)
+                                prefill_strategy, **fwd_kw)
         dec_fwd = build_forward(decode_model.layers,
                                 decode_model.input_tensors, dec_out, mesh,
-                                decode_strategy,
-                                seq_length=self.cfg.seq_length or None,
-                                compute_dtype=self.cfg.compute_dtype,
-                                enable_fusion=self.cfg.enable_fusion)
+                                decode_strategy, **fwd_kw)
         rng0 = jax.random.PRNGKey(0)  # deterministic-mode hard default
 
         def _prefill(params, inputs):
             outs, kv_state = pre_fwd(params, {}, inputs, False, rng0)
             return outs[0], kv_state
+
+        # the program the scheduler runs: each slot's first token, taken on
+        # the device. A position-wise last layer (a Dense over the last
+        # axis, as the graph shows it) is applied to the gathered
+        # `[slots, 1, d]` rows alone, so the `[slots, S, vocab]` logits are
+        # never formed; any other last layer is gathered on its output.
+        head = _positionwise_head(prefill_model)
+        body_fwd, head_fwd = pre_fwd, None
+        if head is not None:
+            body_fwd = build_forward(prefill_model.layers[:-1],
+                                     prefill_model.input_tensors,
+                                     head.inputs, mesh, prefill_strategy,
+                                     **fwd_kw)
+            head_fwd = build_forward([head], head.inputs, pre_out, mesh,
+                                     prefill_strategy, **fwd_kw)
+
+        def _prefill_first_tokens(params, inputs, lengths):
+            last = jnp.maximum(lengths.astype(jnp.int32) - 1, 0)
+            outs, kv_state = body_fwd(params, {}, inputs, False, rng0)
+            rows = jnp.take_along_axis(outs[0], last[:, None, None], axis=1,
+                                       mode="clip")
+            if head_fwd is not None:
+                rows = head_fwd(params, {}, [rows], False, rng0)[0][0]
+            tokens = jnp.argmax(rows[:, 0, :], axis=-1).astype(jnp.int32)
+            return tokens, kv_state
 
         def _decode(params, state, inputs):
             outs, ns = dec_fwd(params, state, inputs, False, rng0)
@@ -305,6 +341,7 @@ class ServingCompiled:
             return outs[0], ns
 
         self._prefill_jit = jax.jit(_prefill)
+        self._prefill_first_tokens_jit = jax.jit(_prefill_first_tokens)
         self._decode_jit = jax.jit(_decode)
         self._decode_fn = _decode
         self._verify_jit = None
@@ -315,10 +352,7 @@ class ServingCompiled:
             ver_out = verify_model.layers[-1].outputs[:1]
             ver_fwd = build_forward(verify_model.layers,
                                     verify_model.input_tensors, ver_out, mesh,
-                                    decode_strategy,
-                                    seq_length=self.cfg.seq_length or None,
-                                    compute_dtype=self.cfg.compute_dtype,
-                                    enable_fusion=self.cfg.enable_fusion)
+                                    decode_strategy, **fwd_kw)
             ver_steps = self.spec_tokens + 1
 
             def _verify(params, state, inputs):
@@ -597,16 +631,29 @@ class ServingCompiled:
         self._last_poll = 0.0
 
     # ------------------------------------------------------------ programs
-    def prefill(self, params, input_arrays):
-        """Run the prefill program: returns (logits, kv_state) where
-        kv_state maps each attention layer to its `[slots, S, h, d]`
-        per-head K/V for `PagedKVCache.commit_prefill`."""
+    def _run_prefill(self, jitted, *args):
         if not tel.enabled():
-            return self._prefill_jit(params, list(input_arrays))
+            return jitted(*args)
         t0 = tel.now_us()
-        out = self._prefill_jit(params, list(input_arrays))
+        out = jitted(*args)
         tel.record("serve/prefill", t0, cat="serve", slots=self.slots)
         return out
+
+    def prefill(self, params, input_arrays):
+        """Run the full-logits prefill program: returns (logits, kv_state)
+        where kv_state maps each attention layer to its `[slots, S, h, d]`
+        per-head K/V for `PagedKVCache.commit_prefill`. For callers that
+        want rows; the scheduler serves with `prefill_first_tokens`."""
+        return self._run_prefill(self._prefill_jit, params, list(input_arrays))
+
+    def prefill_first_tokens(self, params, input_arrays, lengths):
+        """The prefill the scheduler serves with: returns (tokens, kv_state)
+        where tokens is `[slots]` int32, the greedy token after each slot's
+        last real position (`lengths - 1`, row 0 for an empty slot), taken
+        inside the program so no logits leave the device. `kv_state` is
+        `prefill`'s."""
+        return self._run_prefill(self._prefill_first_tokens_jit, params,
+                                 list(input_arrays), lengths)
 
     def decode_step(self, params, state, input_arrays):
         """One single-token step over all slots: returns (logits

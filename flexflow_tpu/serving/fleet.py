@@ -239,10 +239,11 @@ class _SharedRuntimeEngine:
     fleet only installs the proxy for in-process multi-replica serving."""
 
     _DEVICE_CALLS = frozenset((
-        "prefill", "decode_step", "spec_round_step", "verify_step",
-        "poll_swap", "hot_swap", "rollback", "load_params"))
+        "prefill", "prefill_first_tokens", "decode_step", "spec_round_step",
+        "verify_step", "poll_swap", "hot_swap", "rollback", "load_params"))
     _FLOORED = frozenset((
-        "prefill", "decode_step", "spec_round_step", "verify_step"))
+        "prefill", "prefill_first_tokens", "decode_step", "spec_round_step",
+        "verify_step"))
 
     def __init__(self, eng: Any, lock: threading.Lock,
                  step_floor_s: float = 0.0):

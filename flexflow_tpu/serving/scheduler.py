@@ -7,8 +7,10 @@ Policy (the vLLM-style loop, on PR 2's async-dispatch discipline):
   backpressure, the request stays queued). Admitted prompts are right-
   padded into the `[slots, S]` prefill batch at their slot's row, run
   through the prefill program once ("prefill-then-join"), their K/V
-  committed into the paged cache, and their first token (argmax of the
-  last real-position logits) recorded as time-to-first-token.
+  committed into the paged cache, and their first token recorded as
+  time-to-first-token. The program takes it itself (argmax at each slot's
+  last real position, `engine.prefill_first_tokens`): `slots` int32 reach
+  the host, never the `[slots, S, vocab]` logits.
 - DECODE: between sync points the host dispatches up to `dispatch_ahead`
   single-token steps without materializing anything — each step's argmax
   feeds the next step as a device array, the device-resident loop of the
@@ -450,15 +452,16 @@ class ContinuousBatchingScheduler:
     def _prefill_wave(self, batch: List[Request], ids: np.ndarray,
                       lengths: np.ndarray, active: Dict[int, Request],
                       next_host: np.ndarray) -> bool:
-        """Prefill the placed batch, commit its K/V, bring the logits to
-        the host and take each request's first token."""
+        """Prefill the placed batch, commit its K/V and bring each
+        request's first token (taken on the device) to the host."""
         t_pre = time.perf_counter()
         try:
             with tel.span("serve/prefill/dispatch", cat="serve"):
-                logits, kv_state = run_resilient(
+                first_tokens, kv_state = run_resilient(
                     "serve/prefill",
-                    lambda: self.engine.prefill(
-                        self.params, self.prompt_inputs_fn(ids, lengths)),
+                    lambda: self.engine.prefill_first_tokens(
+                        self.params, self.prompt_inputs_fn(ids, lengths),
+                        lengths),
                     policy=self.retry_policy)
         except Exception as e:  # noqa: BLE001 — permanent prefill fault:
             for req in batch:   # fail ONLY the batch being admitted
@@ -477,10 +480,11 @@ class ContinuousBatchingScheduler:
             # the draft prefills the SAME prompt batch into its own cache;
             # positions stay pairwise consistent with the target from here
             try:
-                _dlg, dkv_state = run_resilient(
+                _dtok, dkv_state = run_resilient(
                     "serve/prefill",
-                    lambda: self.draft.prefill(
-                        self.draft.params, self.prompt_inputs_fn(ids, lengths)),
+                    lambda: self.draft.prefill_first_tokens(
+                        self.draft.params,
+                        self.prompt_inputs_fn(ids, lengths), lengths),
                     policy=self.retry_policy)
             except Exception as e:  # noqa: BLE001
                 for req in batch:
@@ -494,12 +498,14 @@ class ContinuousBatchingScheduler:
                 dkv_state, np.arange(self.slots, dtype=np.int32), lengths)
         self.prefills += 1
         # one sync point in two parts: waiting for the device, then moving
-        # the bytes (TTFT is a real materialization)
+        # the bytes (TTFT is a real materialization). The span keeps the
+        # name it had when whole logits crossed here: its `bytes` say
+        # which it was, `slots * 4` now
         with tel.span("serve/prefill/device_wait", cat="serve"):
-            jax.block_until_ready(logits)
+            jax.block_until_ready(first_tokens)
         with tel.span("serve/prefill/logits_to_host", cat="serve") as sp:
-            lg = np.asarray(logits)
-            sp.set(bytes=int(lg.nbytes))
+            tok = np.asarray(first_tokens)
+            sp.set(bytes=int(tok.nbytes))
         t_first = time.perf_counter()
         serve_ms = 1e3 * (t_first - t_pre)
         self._ema_serve_ms = (serve_ms if not self._ema_serve_ms
@@ -508,7 +514,7 @@ class ContinuousBatchingScheduler:
         t_first_off = t_first - self._t0
         with tel.span("serve/prefill/first_tokens", cat="serve"):
             for req in batch:
-                first = int(lg[req.slot, lengths[req.slot] - 1].argmax())
+                first = int(tok[req.slot])
                 req.tokens.append(first)
                 req.ttft_s = t_first_off - req.arrival_s
                 req.admit_s = t_pre_off

@@ -384,6 +384,35 @@ def test_scheduler_autotune_closes_loop_once():
     assert s2.prefetch_ahead == 4
 
 
+# ------------------------------------------------- shared-runtime engine proxy
+@pytest.mark.parametrize("call", ["prefill", "prefill_first_tokens",
+                                  "decode_step"])
+def test_shared_runtime_proxy_locks_and_floors_every_program(call):
+    """Every compiled program a replica's scheduler dispatches, the
+    first-token prefill it serves with included, runs under the fleet lock
+    and reserves its simulated device-step floor."""
+    import threading
+    import time
+
+    from flexflow_tpu.serving.fleet import _SharedRuntimeEngine
+
+    assert call in _SharedRuntimeEngine._DEVICE_CALLS
+    assert call in _SharedRuntimeEngine._FLOORED
+    lock = threading.Lock()
+    held = []
+
+    class Eng:
+        slots = 4
+
+    setattr(Eng, call, lambda self, *a: held.append(lock.locked()) or a)
+    proxy = _SharedRuntimeEngine(Eng(), lock, step_floor_s=0.02)
+    assert proxy.slots == 4            # plain attributes pass through
+    t0 = time.perf_counter()
+    assert getattr(proxy, call)(1, 2) == (1, 2)
+    assert held == [True] and not lock.locked()
+    assert time.perf_counter() - t0 >= 0.02
+
+
 # ------------------------------------------------------------- bench smoke
 @pytest.mark.slow  # ~18s: two engines + five serve legs (identity,
 # scaling, mixed priorities, disagg handoff, rolling swap)

@@ -15,7 +15,9 @@ as the CI smoke of the open-loop bench.
 
 import os
 import sys
+import time
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -211,6 +213,85 @@ def test_inference_determinism(gpt2_serve, rng):
     assert (np.asarray(s1) == np.asarray(s2)).all()
 
 
+# ------------------------------------------- first tokens taken on the device
+def _first_token_case(name, slots, seq):
+    return {
+        "one": np.ones((slots,), np.int32),
+        "mid": np.full((slots,), seq // 2, np.int32),
+        "seq": np.full((slots,), seq, np.int32),
+        "mixed": np.array([1 + (5 * i) % seq for i in range(slots)], np.int32),
+        "empty_slot": np.array([0] + [3 + i for i in range(slots - 1)],
+                               np.int32),
+    }[name]
+
+
+@pytest.mark.parametrize("case", ["one", "mid", "seq", "mixed", "empty_slot"])
+def test_prefill_first_tokens_match_full_logits(gpt2_serve, rng, case):
+    """The program the scheduler runs returns, per slot, the argmax of
+    `prefill`'s logits at the last real position (row 0 for an empty slot),
+    and the same K/V bit for bit."""
+    eng, gc = gpt2_serve
+    lengths = _first_token_case(case, eng.slots, gc.seq)
+    ids = rng.integers(1, gc.vocab, size=(eng.slots, gc.seq)).astype(np.int32)
+    for s, n in enumerate(lengths):
+        ids[s, n:] = 0
+    inputs = gpt2_prompt_inputs(ids, lengths)
+    logits, kv_full = eng.prefill(eng.params, inputs)
+    tokens, kv_state = eng.prefill_first_tokens(eng.params, inputs, lengths)
+    tokens = np.asarray(tokens)
+    assert tokens.shape == (eng.slots,) and tokens.dtype == np.int32
+    rows = np.asarray(logits)[np.arange(eng.slots), np.maximum(lengths - 1, 0)]
+    np.testing.assert_array_equal(tokens, rows.argmax(axis=-1))
+    assert set(kv_state) == set(kv_full)
+    for name in kv_full:
+        for a, b in zip(jax.tree_util.tree_leaves(kv_state[name]),
+                        jax.tree_util.tree_leaves(kv_full[name])):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_prefill_first_tokens_gathers_on_another_last_layers_output(devices,
+                                                                    rng):
+    """The generic transformer ends in a LayerNorm, not a Dense: its first
+    tokens are gathered on the program's output, with the same answer."""
+    from flexflow_tpu.serving.engine import _positionwise_head
+    model = FFModel(_serve_cfg(max_batch_slots=2))
+    seq, d_model = 12, 32
+    build_transformer(model, batch=8, seq=seq, d_model=d_model, heads=4,
+                      d_ff=64, layers=1, classes=0, causal=True, dropout=0.1)
+    eng = compile_serving(model, max_decode_len=4)
+    eng.init(seed=0)
+    assert _positionwise_head(eng.prefill_model) is None
+    x = rng.normal(size=(eng.slots, seq, d_model)).astype(np.float32)
+    lengths = np.array([seq, 0], np.int32)
+    out, kv_full = eng.prefill(eng.params, [x])
+    tokens, kv_state = eng.prefill_first_tokens(eng.params, [x], lengths)
+    rows = np.asarray(out)[np.arange(eng.slots), np.maximum(lengths - 1, 0)]
+    np.testing.assert_array_equal(np.asarray(tokens), rows.argmax(axis=-1))
+    for a, b in zip(jax.tree_util.tree_leaves(kv_state),
+                    jax.tree_util.tree_leaves(kv_full)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_head_is_applied_after_the_gather_only_when_positionwise(gpt2_serve):
+    """Chosen from the graph: gpt2's lm_head is a Dense over the last axis
+    of a layer's `[slots, S, d]` output; a Dense on a graph input, a Dense
+    with an opaque activation, or any other last layer is gathered on its
+    output instead."""
+    from flexflow_tpu.serving.engine import _positionwise_head
+    eng, _ = gpt2_serve
+    assert _positionwise_head(eng.prefill_model) is eng.prefill_model.layers[-1]
+    m = FFModel(FFConfig(log_level="warning"))
+    x = m.create_tensor([4, 8, 16], name="x")
+    m.dense(x, 32, name="on_input")
+    assert _positionwise_head(m) is None
+    m.dense(m.dense(x, 16), 32, activation=lambda v: v.sum(1, keepdims=True))
+    assert _positionwise_head(m) is None
+    m.softmax(m.dense(x, 32))
+    assert _positionwise_head(m) is None
+    m.dense(m.dense(x, 16), 32)
+    assert _positionwise_head(m) is m.layers[-1]
+
+
 # ----------------------------------------------------------- strategy cache
 def test_strategy_cache_warm_hit_both_programs(gpt2_serve):
     """A second compile_serving of the same graph/machine/knobs restores
@@ -318,6 +399,35 @@ def test_scheduler_page_backpressure(gpt2_serve, rng):
     sched = ContinuousBatchingScheduler(eng, eng.params, gpt2_prompt_inputs,
                                         gpt2_step_inputs, dispatch_ahead=2)
     assert len(sched.run(reqs)) == 2
+
+
+def test_scheduler_never_compiles_the_full_logits_program(gpt2_serve, rng):
+    """A scheduler run takes every first token from the device-side program:
+    the full-logits jit compiles nothing for it, the wave brings `slots`
+    int32 to the host, and each request's first token is the one the
+    full-logits path reads."""
+    from flexflow_tpu import telemetry as tel
+    eng, gc = gpt2_serve
+    n = eng.slots + 2
+    reqs = [Request(rid=i, prompt=list(rng.integers(1, gc.vocab, size=2 + i)),
+                    max_new_tokens=3, arrival_s=0.0) for i in range(n)]
+    sched = ContinuousBatchingScheduler(eng, eng.params, gpt2_prompt_inputs,
+                                        gpt2_step_inputs, dispatch_ahead=2)
+    full_before = eng._prefill_jit._cache_size()
+    t_run = time.perf_counter_ns()
+    done = sched.run(reqs)
+    assert eng._prefill_jit._cache_size() == full_before
+    assert eng._prefill_first_tokens_jit._cache_size() == 1
+    copies = tel.ring_spans("serve/prefill/logits_to_host", since_ns=t_run)
+    assert [c.args["bytes"] for c in copies] == [eng.slots * 4] * sched.prefills
+    assert len(done) == n
+    for r in done:
+        ids = np.zeros((eng.slots, gc.seq), np.int32)
+        lengths = np.zeros((eng.slots,), np.int32)
+        ids[0, :len(r.prompt)] = r.prompt
+        lengths[0] = len(r.prompt)
+        logits, _ = eng.prefill(eng.params, gpt2_prompt_inputs(ids, lengths))
+        assert r.tokens[0] == int(np.asarray(logits)[0, len(r.prompt) - 1].argmax())
 
 
 # ------------------------------------------------------------------ CI smoke
