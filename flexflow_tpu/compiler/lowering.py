@@ -19,7 +19,7 @@ from flexflow_tpu.core.graph import topo_order
 from flexflow_tpu.core.layer import Layer
 from flexflow_tpu.core.tensor import Tensor
 from flexflow_tpu.ops import get_op_def
-from flexflow_tpu.ops.registry import LoweringCtx
+from flexflow_tpu.ops.registry import STATS_KEY, LoweringCtx
 from flexflow_tpu.parallel.sharding import Strategy, used_axes
 
 
@@ -58,9 +58,12 @@ def build_forward(
     seq_length: Optional[int] = None,
     compute_dtype: Optional[str] = None,
     enable_fusion: bool = True,
+    collect_stats: bool = False,
 ) -> Callable:
     """Returns forward(params, state, input_arrays, training, rng)
-    -> (output_arrays, new_state)."""
+    -> (output_arrays, new_state). `collect_stats`: new_state[STATS_KEY]
+    holds the counters that ops reported through ctx.add_stat, where any
+    did (the serving programs)."""
     import jax.numpy as jnp
 
     order = topo_order(layers)
@@ -85,7 +88,7 @@ def build_forward(
 
     from flexflow_tpu.ops.op_type import OperatorType as _OT
 
-    _norm_types = (_OT.LAYERNORM, _OT.BATCHNORM)
+    _norm_types = (_OT.LAYERNORM, _OT.BATCHNORM, _OT.RMSNORM)
     # per-layer weight names exempt from the compute-dtype cast: norm params
     # (gamma/beta) — including norms nested inside fork_join branches, whose
     # weights surface as "b{i}.{sublayer}.{w}" on the composite layer
@@ -109,7 +112,8 @@ def build_forward(
                           compute_dtype=str(cast_to) if cast_to else None,
                           mesh=mesh, op_attrs=op_attrs,
                           op_shardings=strategy.op_shardings,
-                          enable_fusion=enable_fusion)
+                          enable_fusion=enable_fusion,
+                          stats={} if collect_stats else None)
         env: Dict[int, jax.Array] = {}
         for t, arr in zip(graph_inputs, input_arrays):
             if cast_to is not None and jnp.issubdtype(arr.dtype, jnp.floating):
@@ -175,6 +179,8 @@ def build_forward(
         result = [env[t.guid] for t in outputs]
         new_state = dict(state)
         new_state.update(ctx.new_state)
+        if ctx.stats:
+            new_state[STATS_KEY] = ctx.stats
         return result, new_state
 
     return forward

@@ -130,16 +130,26 @@ class FFModel:
                             add_zero_attn: bool = False, causal: bool = False,
                             kernel_initializer=None, impl: str = "auto",
                             decode: bool = False, kv_out: bool = False,
+                            num_kv_heads: int = 0,
+                            scale: Optional[float] = None,
                             name=None) -> Tensor:
         # decode: single-token serving step reading/writing the paged KV
         # cache via lowering state; kv_out: prefill variant that exposes
-        # per-head K/V for cache commit (flexflow_tpu/serving)
+        # per-head K/V for cache commit (flexflow_tpu/serving).
+        # num_kv_heads: grouped-query attention (0 = one K/V head a query
+        # head); scale: the factor on q k^T (None = 1/sqrt(head_dim)). Both
+        # enter the params only where set, so graphs without them keep
+        # their fingerprints.
+        params = {"embed_dim": int(embed_dim), "num_heads": int(num_heads), "kdim": kdim,
+                  "vdim": vdim, "dropout": dropout, "bias": bias, "add_bias_kv": add_bias_kv,
+                  "add_zero_attn": add_zero_attn, "causal": causal, "impl": impl,
+                  "decode": decode, "kv_out": kv_out}
+        if num_kv_heads and int(num_kv_heads) != int(num_heads):
+            params["num_kv_heads"] = int(num_kv_heads)
+        if scale is not None:
+            params["scale"] = float(scale)
         return self._add_layer(
-            OperatorType.MULTIHEAD_ATTENTION,
-            {"embed_dim": int(embed_dim), "num_heads": int(num_heads), "kdim": kdim,
-             "vdim": vdim, "dropout": dropout, "bias": bias, "add_bias_kv": add_bias_kv,
-             "add_zero_attn": add_zero_attn, "causal": causal, "impl": impl,
-             "decode": decode, "kv_out": kv_out},
+            OperatorType.MULTIHEAD_ATTENTION, params,
             [query, key, value], name,
             {"wq": kernel_initializer, "wk": kernel_initializer, "wv": kernel_initializer,
              "wo": kernel_initializer})[0]
@@ -239,6 +249,26 @@ class FFModel:
                                {"axes": axes, "elementwise_affine": elementwise_affine,
                                 "eps": eps},
                                [input], name)[0]
+
+    def rms_norm(self, input, eps: float = 1e-5, name=None):
+        """x / sqrt(mean(x^2) + eps) * gamma over the last axis."""
+        return self._add_layer(OperatorType.RMSNORM, {"eps": eps}, [input],
+                               name)[0]
+
+    def mamba2(self, input: Tensor, heads: int, head_dim: int, d_state: int,
+               d_conv: int = 4, chunk: int = 256, n_groups: int = 1,
+               eps: float = 1e-5, valid: Optional[Tensor] = None,
+               initializers: Optional[Dict[str, Any]] = None,
+               name=None) -> Tensor:
+        """Mamba-2 mixer over `[batch, seq, d]` (ops/ssm_ops.py). `valid`
+        `[batch, seq]` int: which positions hold a token."""
+        ins = [input] + ([valid] if valid is not None else [])
+        return self._add_layer(
+            OperatorType.MAMBA2,
+            {"heads": int(heads), "head_dim": int(head_dim),
+             "d_state": int(d_state), "d_conv": int(d_conv),
+             "chunk": int(chunk), "n_groups": int(n_groups), "eps": eps},
+            ins, name, initializers)[0]
 
     def softmax(self, input, axis: int = -1, name=None):
         return self._add_layer(OperatorType.SOFTMAX, {"axis": axis}, [input], name)[0]
@@ -361,6 +391,23 @@ class FFModel:
     def aggregate_spec(self, gates, assign, positions, expert_outputs, name=None) -> Tensor:
         return self._add_layer(OperatorType.AGGREGATE_SPEC, {},
                                [gates, assign, positions, expert_outputs], name)[0]
+
+    def moe_layer(self, input: Tensor, num_experts: int, top_k: int,
+                  expert_width: int, experts_held=None,
+                  valid: Optional[Tensor] = None,
+                  initializers: Optional[Dict[str, Any]] = None,
+                  name=None) -> Tensor:
+        """Dropless top-k layer of gated-SiLU experts over `[batch, seq,
+        d]`, routed over all `num_experts`, computing those in
+        `experts_held = (lo, hi)` (default: all); ops/moe_ops.py."""
+        lo, hi = experts_held if experts_held is not None else (0, num_experts)
+        ins = [input] + ([valid] if valid is not None else [])
+        return self._add_layer(
+            OperatorType.MOE_LAYER,
+            {"num_experts": int(num_experts), "top_k": int(top_k),
+             "expert_width": int(expert_width),
+             "experts_held": (int(lo), int(hi))},
+            ins, name, initializers)[0]
 
     def cache(self, input: Tensor, num_batches: int = 1, name=None) -> Tensor:
         return self._add_layer(OperatorType.CACHE, {"num_batches": num_batches}, [input], name)[0]

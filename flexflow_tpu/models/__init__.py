@@ -10,6 +10,8 @@ from flexflow_tpu.models.resnet import build_resnet50, build_resnet_block
 from flexflow_tpu.models.dlrm import build_dlrm
 from flexflow_tpu.models.transformer import build_transformer
 from flexflow_tpu.models.gpt2 import build_gpt2, GPT2Config
+from flexflow_tpu.models.granite_hybrid import (GraniteHybridConfig,
+                                                build_granite_hybrid)
 from flexflow_tpu.models.bert import build_bert
 from flexflow_tpu.models.moe import build_moe_mlp
 from flexflow_tpu.models.inception import build_inception_v3
@@ -22,4 +24,5 @@ __all__ = [
     "build_candle_uno", "build_xdl", "build_resnext50", "resnext_block",
     "build_dlrm", "build_transformer", "build_gpt2", "GPT2Config",
     "build_bert", "build_moe_mlp", "build_inception_v3",
+    "build_granite_hybrid", "GraniteHybridConfig",
 ]

@@ -21,6 +21,7 @@ from flexflow_tpu.ops import (  # noqa: F401
     embed_ops,
     attention_ops,
     moe_ops,
+    ssm_ops,
     parallel_ops,
     fork_join,
 )

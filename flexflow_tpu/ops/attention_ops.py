@@ -37,6 +37,7 @@ def _mha_infer(layer: Layer):
     heads = p["num_heads"]
     if embed % heads:
         raise ValueError("num_heads must divide embed_dim")
+    kv_embed = _kv_heads(p) * (embed // heads)
     # kdim/vdim are the key/value input feature dims (torch/reference
     # semantics); they must match the actual inputs if given.
     if p.get("kdim") and p["kdim"] != k.shape[-1]:
@@ -45,16 +46,16 @@ def _mha_infer(layer: Layer):
         raise ValueError(f"vdim={p['vdim']} != value feature dim {v.shape[-1]}")
     layer.weight_specs = {
         "wq": TensorSpec((q.shape[-1], embed), q.dtype),
-        "wk": TensorSpec((k.shape[-1], embed), q.dtype),
-        "wv": TensorSpec((v.shape[-1], embed), q.dtype),
+        "wk": TensorSpec((k.shape[-1], kv_embed), q.dtype),
+        "wv": TensorSpec((v.shape[-1], kv_embed), q.dtype),
         "wo": TensorSpec((embed, embed), q.dtype),
     }
     if p.get("bias", True):
         layer.weight_specs.update(
             {
                 "bq": TensorSpec((embed,), q.dtype),
-                "bk": TensorSpec((embed,), q.dtype),
-                "bv": TensorSpec((embed,), q.dtype),
+                "bk": TensorSpec((kv_embed,), q.dtype),
+                "bv": TensorSpec((kv_embed,), q.dtype),
                 "bo": TensorSpec((embed,), q.dtype),
             }
         )
@@ -62,6 +63,26 @@ def _mha_infer(layer: Layer):
         layer.weight_specs["bias_k"] = TensorSpec((embed,), q.dtype)
         layer.weight_specs["bias_v"] = TensorSpec((embed,), q.dtype)
     return [q.with_shape(q.shape[:-1] + (embed,))]
+
+
+def _kv_heads(p) -> int:
+    """Grouped-query attention: `num_kv_heads` K/V heads, each read by
+    num_heads / num_kv_heads query heads in a row (query head j reads K/V
+    head j // group). Absent or 0: one K/V head a query head."""
+    kv = int(p.get("num_kv_heads") or p["num_heads"])
+    if p["num_heads"] % kv:
+        raise ValueError("num_kv_heads must divide num_heads")
+    if kv != p["num_heads"] and (p.get("add_bias_kv") or p.get("add_zero_attn")):
+        raise NotImplementedError("grouped K/V heads with add_bias_kv/"
+                                  "add_zero_attn")
+    return kv
+
+
+def _scale(p, head_dim: int) -> float:
+    """The factor on q k^T: `scale` where the model states one (an
+    attention multiplier), else 1 / sqrt(head_dim)."""
+    return float(p["scale"]) if p.get("scale") is not None \
+        else 1.0 / math.sqrt(head_dim)
 
 
 def _split_heads(x, heads):
@@ -105,9 +126,12 @@ def _mha_decode_lower(layer: Layer, inputs, weights, ctx: LoweringCtx):
             y = y + weights[b].astype(dt)
         return y
 
+    kvh = _kv_heads(p)
     qh = _split_heads(proj(inputs[0], "wq", "bq"), heads)  # (slots, s, h, d)
-    kh = _split_heads(proj(inputs[1], "wk", "bk"), heads)
-    vh = _split_heads(proj(inputs[2], "wv", "bv"), heads)
+    kh = _split_heads(proj(inputs[1], "wk", "bk"), kvh)    # (slots, s, kvh, d)
+    vh = _split_heads(proj(inputs[2], "wv", "bv"), kvh)
+    if kvh != heads and "k_scale" in ctx.state[layer.name]:
+        raise NotImplementedError("grouped K/V heads with a quantized cache")
 
     cache = ctx.state[layer.name]
     k_pool, v_pool = cache["k"], cache["v"]
@@ -139,7 +163,7 @@ def _mha_decode_lower(layer: Layer, inputs, weights, ctx: LoweringCtx):
         v_pool = v_pool.at[pageix, off].set(vh.astype(v_pool.dtype))
         ctx.new_state[layer.name] = {"k": k_pool, "v": v_pool}
 
-    scale = 1.0 / math.sqrt(hd)
+    scale = _scale(p, hd)
     out = None
     if quantized:
         # gather the int8 context + scales: [slots, L, h, (d)]
@@ -167,17 +191,20 @@ def _mha_decode_lower(layer: Layer, inputs, weights, ctx: LoweringCtx):
             V = (Vq.astype(jnp.float32) * Vs[..., None]).astype(dt)
     else:
         # gather each slot's pages: [slots, pages_per_slot, page, h, d]
-        K = k_pool[pt].reshape(b, -1, heads, hd).astype(dt)
-        V = v_pool[pt].reshape(b, -1, heads, hd).astype(dt)
+        K = k_pool[pt].reshape(b, -1, kvh, hd).astype(dt)
+        V = v_pool[pt].reshape(b, -1, kvh, hd).astype(dt)
     if out is None:
-        logits = jnp.einsum("bqhd,bkhd->bhqk", qh, K) * scale
+        # query heads as [kvh groups, r in a group] against their group's
+        # K/V head (r = 1: one K/V head a query head)
+        qg = qh.reshape(b, s, kvh, heads // kvh, hd)
+        logits = jnp.einsum("bqgrd,bkgd->bgrqk", qg, K) * scale
         # causal-by-construction: query token i (at position pos+i, just
         # written) attends cached positions 0..pos+i inclusive
-        keep = (jnp.arange(K.shape[1])[None, None, None, :]
-                <= t[:, None, :, None])
+        keep = (jnp.arange(K.shape[1])[None, None, None, None, :]
+                <= t[:, None, None, :, None])
         logits = jnp.where(keep, logits, jnp.finfo(logits.dtype).min)
         probs = jax.nn.softmax(logits, axis=-1)
-        out = jnp.einsum("bhqk,bkhd->bqhd", probs, V)
+        out = jnp.einsum("bgrqk,bkgd->bqgrd", probs, V)
     out = out.reshape(b, s, embed)
     y = out @ weights["wo"].astype(dt)
     if "bo" in weights:
@@ -229,6 +256,7 @@ def _mha_lower(layer: Layer, inputs, weights, ctx: LoweringCtx):
     if p.get("decode", False):
         return _mha_decode_lower(layer, inputs, weights, ctx)
     heads = p["num_heads"]
+    kvh = _kv_heads(p)
     embed = p["embed_dim"]
     dt = q.dtype
 
@@ -244,8 +272,8 @@ def _mha_lower(layer: Layer, inputs, weights, ctx: LoweringCtx):
         # serving prefill: expose the per-head K/V of the prompt tokens so
         # the engine can commit them into the paged cache (captured BEFORE
         # any bias_kv/zero_attn positions could pollute the cache)
-        ctx.new_state[layer.name] = {"k": _split_heads(kp, heads),
-                                     "v": _split_heads(vp, heads)}
+        ctx.new_state[layer.name] = {"k": _split_heads(kp, kvh),
+                                     "v": _split_heads(vp, kvh)}
     if "bias_k" in weights:  # add_bias_kv: learned extra kv position
         b_ = k.shape[0]
         kp = jnp.concatenate([kp, jnp.broadcast_to(weights["bias_k"].astype(dt), (b_, 1, embed))], axis=1)
@@ -255,12 +283,17 @@ def _mha_lower(layer: Layer, inputs, weights, ctx: LoweringCtx):
         kp = jnp.concatenate([kp, jnp.zeros((b_, 1, embed), dt)], axis=1)
         vp = jnp.concatenate([vp, jnp.zeros((b_, 1, embed), dt)], axis=1)
     qh = _split_heads(proj(q, "wq", "bq"), heads)  # (b, sq, h, d)
-    kh = _split_heads(kp, heads)
-    vh = _split_heads(vp, heads)
+    kh = _split_heads(kp, kvh)
+    vh = _split_heads(vp, kvh)
+    if kvh != heads:
+        # each K/V head once per query head of its group: the kernels and
+        # the einsum below then see one K/V head a query head
+        kh = jnp.repeat(kh, heads // kvh, axis=2)
+        vh = jnp.repeat(vh, heads // kvh, axis=2)
 
     impl = p.get("impl", "auto")
     causal = p.get("causal", False)
-    scale = 1.0 / math.sqrt(embed // heads)
+    scale = _scale(p, embed // heads)
     out = None
     # flash kernel has no probs-dropout path: fall back (or fail under
     # impl="flash") rather than silently dropping the dropout mask
@@ -331,12 +364,25 @@ def _mha_flops(layer: Layer):
     q, k = layer.inputs[0].spec, layer.inputs[1].spec
     b, sq, e = q.shape
     sk = k.shape[1]
-    proj = 2.0 * b * (3 * sq + sq) * e * e  # q,k,v,o projections (approx sq≈sk)
+    kv_share = _kv_heads(layer.params) / layer.params["num_heads"]
+    # q,k,v,o projections (approx sq≈sk); grouped K/V heads project less
+    proj = 2.0 * b * (2 * sq + 2 * sq * kv_share) * e * e
     attn = 2.0 * b * sq * sk * e * 2  # qk^T and att@v
     return proj + attn
 
 
-register_op(OperatorType.MULTIHEAD_ATTENTION, _mha_infer, _mha_lower, _mha_flops)
+def _mha_serving_params(params: dict, kind: str) -> dict:
+    """The prefill twin hands out its K/V (kv_out), the decode twin reads
+    the paged cache; no dropout in either (inference determinism is a
+    property of the PROGRAM, not a flag callers must remember)."""
+    if kind == "decode":
+        # the decode path is its own fixed lowering
+        return dict(params, dropout=0.0, decode=True, impl="xla")
+    return dict(params, dropout=0.0, kv_out=True)
+
+
+register_op(OperatorType.MULTIHEAD_ATTENTION, _mha_infer, _mha_lower, _mha_flops,
+            serving_params=_mha_serving_params, state_kind="paged_kv")
 
 
 def _sdpa_infer(layer: Layer):

@@ -131,3 +131,125 @@ def _cache_lower(layer: Layer, inputs, weights, ctx):
 
 
 register_op(OperatorType.CACHE, _cache_infer, _cache_lower)
+
+
+# ---------------------------------------------------------------- moe_layer
+def _moe_layer_infer(layer: Layer):
+    x = layer.inputs[0].spec
+    p = layer.params
+    lo, hi = p["experts_held"]
+    if not 0 <= lo < hi <= p["num_experts"]:
+        raise ValueError(f"experts_held {lo, hi} outside 0..{p['num_experts']}")
+    d, width = x.shape[-1], p["expert_width"]
+    layer.weight_specs = {
+        "router": TensorSpec((d, p["num_experts"]), x.dtype),
+        "w_in": TensorSpec((hi - lo, d, 2 * width), x.dtype),
+        "w_out": TensorSpec((hi - lo, width, d), x.dtype),
+    }
+    return [x]
+
+
+# tokens routed at a time: a longer input goes through in blocks of this
+# many (lax.map), so that the `tokens * k` row buffers of the grouped
+# product stay a fraction of a prefill wave's
+MOE_TOKEN_BLOCK = 4096
+
+
+def _route_tokens(xt, exists, weights, p):
+    """One block of tokens `[tokens, d]` through the routed layer: (this
+    holder's part of the output `[tokens, d]`, rows on each held expert
+    `[held]`)."""
+    k = p["top_k"]
+    lo, hi = p["experts_held"]
+    held_n = hi - lo
+    tokens, _d = xt.shape
+    dt = xt.dtype
+    scores = jnp.dot(xt.astype(jnp.float32),
+                     weights["router"].astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    top, experts = jax.lax.top_k(scores, k)                    # [tokens, k]
+    gate = jax.nn.softmax(top, axis=-1)
+    held = (experts >= lo) & (experts < hi) & exists
+    local = jnp.where(held, experts - lo, held_n).reshape(-1)  # absent: last
+    order = jnp.argsort(local, stable=True)
+    sizes = jnp.bincount(local, length=held_n + 1)[:held_n].astype(jnp.int32)
+    rows = xt[order // k]                                      # [tokens*k, d]
+    ab = jax.lax.ragged_dot(rows, weights["w_in"].astype(dt), sizes)
+    width = p["expert_width"]
+    mid = (jax.nn.silu(ab[:, :width].astype(jnp.float32))
+           * ab[:, width:].astype(jnp.float32)).astype(dt)
+    out = jax.lax.ragged_dot(mid, weights["w_out"].astype(dt), sizes)
+    # back to (token, choice) order, one choice at a time (a [tokens, k, d]
+    # buffer in f32 would be the largest of the program); rows past the
+    # last group hold nothing that was computed, so they are selected
+    # away, not multiplied by 0
+    where = jnp.argsort(order).reshape(tokens, k)
+    y = jnp.zeros(xt.shape, jnp.float32)
+    for j in range(k):
+        y = y + jnp.where(held[:, j:j + 1],
+                          gate[:, j:j + 1] * out[where[:, j]].astype(jnp.float32),
+                          0.0)
+    return y.astype(dt), sizes
+
+
+def _moe_layer_lower(layer: Layer, inputs, weights, ctx):
+    """Dropless top-k layer of gated-SiLU experts over `[batch, seq, d]`,
+    for a holder of the experts `experts_held = (lo, hi)`.
+
+    The router scores ALL `num_experts` in f32 (the matmul at HIGHEST
+    precision: a lower one moves the k-th and (k+1)-th scores past each
+    other), takes the top k and a softmax over those k in f32. Each (token,
+    choice) whose expert is held here is computed; the others contribute
+    nothing, so the result is this holder's part of the layer's output
+    (the parts of all holders add up to the whole layer). Rows are sorted
+    by expert and multiplied as one grouped product (`jax.lax.ragged_dot`),
+    whose cost follows the rows that were routed here: the static
+    `tokens * k` rows, absent ones sorted behind the last group and never
+    multiplied. No capacity, no drops. The optional second input `valid`
+    `[batch, seq]` names the tokens that exist; the others are not routed.
+
+    Reports (ctx.add_stat): moe_routed_pairs, moe_held_pairs, moe_load_max
+    (rows on the fullest held expert), moe_load_mean (held pairs over
+    experts held), moe_experts_hit (held experts with a row)."""
+    x = inputs[0]
+    p = layer.params
+    b, s, d = x.shape
+    tokens = b * s
+    xt = x.reshape(tokens, d)
+    exists = jnp.ones((tokens, 1), bool) if len(inputs) < 2 \
+        else inputs[1].reshape(tokens, 1) > 0
+    if tokens > MOE_TOKEN_BLOCK and tokens % MOE_TOKEN_BLOCK == 0:
+        blocks = tokens // MOE_TOKEN_BLOCK
+        y, sizes = jax.lax.map(
+            lambda block: _route_tokens(block[0], block[1], weights, p),
+            (xt.reshape(blocks, MOE_TOKEN_BLOCK, d),
+             exists.reshape(blocks, MOE_TOKEN_BLOCK, 1)))
+        sizes = jnp.sum(sizes, axis=0)
+    else:
+        y, sizes = _route_tokens(xt, exists, weights, p)
+    n_held = jnp.sum(sizes)
+    ctx.add_stat("moe_routed_pairs",
+                 jnp.sum(exists).astype(jnp.int32) * p["top_k"])
+    ctx.add_stat("moe_held_pairs", n_held)
+    ctx.add_stat("moe_load_max", jnp.max(sizes))
+    ctx.add_stat("moe_load_mean",
+                 n_held.astype(jnp.float32) / sizes.shape[0])
+    ctx.add_stat("moe_experts_hit", jnp.sum(sizes > 0).astype(jnp.int32))
+    return [y.reshape(b, s, d)]
+
+
+def _moe_layer_flops(layer: Layer):
+    """Forward, for the EXPECTED rows here: each token's k choices fall on
+    the held experts in the share held / num_experts."""
+    x = layer.inputs[0].spec
+    p = layer.params
+    lo, hi = p["experts_held"]
+    d = x.shape[-1]
+    tokens = x.num_elements // d
+    pairs = tokens * p["top_k"] * (hi - lo) / p["num_experts"]
+    return 2.0 * tokens * d * p["num_experts"] \
+        + 2.0 * pairs * 3 * d * p["expert_width"]
+
+
+register_op(OperatorType.MOE_LAYER, _moe_layer_infer, _moe_layer_lower,
+            _moe_layer_flops)

@@ -85,4 +85,27 @@ def _dropout_lower(layer: Layer, inputs, weights, ctx: LoweringCtx):
     return [jnp.where(mask, x / keep, 0.0).astype(x.dtype)]
 
 
-register_op(OperatorType.DROPOUT, _dropout_infer, _dropout_lower)
+register_op(OperatorType.DROPOUT, _dropout_infer, _dropout_lower,
+            serving_params=lambda params, kind: dict(params, rate=0.0))
+
+
+def _rms_infer(layer: Layer):
+    x = layer.inputs[0].spec
+    layer.weight_specs = {"gamma": TensorSpec((x.shape[-1],), x.dtype)}
+    return [x]
+
+
+def rms_norm(x, gamma, eps):
+    """x / sqrt(mean(x^2) + eps) * gamma over the last axis, statistics in
+    f32, result in x's dtype."""
+    xf = x.astype(jnp.float32)
+    y = xf * lax.rsqrt(jnp.mean(jnp.square(xf), axis=-1, keepdims=True) + eps)
+    return (y * gamma.astype(jnp.float32)).astype(x.dtype)
+
+
+def _rms_lower(layer: Layer, inputs, weights, ctx):
+    return [rms_norm(inputs[0], weights["gamma"], layer.params.get("eps", 1e-5))]
+
+
+register_op(OperatorType.RMSNORM, _rms_infer, _rms_lower,
+            flops=lambda layer: 4.0 * layer.inputs[0].spec.num_elements)

@@ -30,6 +30,7 @@ class OperatorType(enum.Enum):
     # normalization
     BATCHNORM = "batch_norm"
     LAYERNORM = "layer_norm"
+    RMSNORM = "rms_norm"
     # element binary
     EW_ADD = "add"
     EW_SUB = "subtract"
@@ -92,6 +93,12 @@ class OperatorType(enum.Enum):
     AGGREGATE_SPEC = "aggregate_spec"
     CACHE = "cache"
     EXPERTS = "experts"
+    # dropless top-k routed layer of gated experts, told which experts it
+    # holds (ops/moe_ops.py; the capacity-factor ops above stay as the
+    # reference framework's group_by/aggregate)
+    MOE_LAYER = "moe_layer"
+    # state-space mixer (Mamba-2, ops/ssm_ops.py)
+    MAMBA2 = "mamba2"
     # fused compute op (reference: src/ops/fused.cc)
     FUSED = "fused"
     # inter-op placement composite (reference: nonsequence splits,
@@ -121,8 +128,11 @@ WEIGHTED_OPS = frozenset(
         OperatorType.EMBEDDING,
         OperatorType.BATCHNORM,
         OperatorType.LAYERNORM,
+        OperatorType.RMSNORM,
         OperatorType.MULTIHEAD_ATTENTION,
         OperatorType.EXPERTS,
+        OperatorType.MOE_LAYER,
+        OperatorType.MAMBA2,
         OperatorType.FORK_JOIN,
     }
 )

@@ -54,6 +54,15 @@ class LoweringCtx:
     # --fusion flag (reference FusedOp gate, model.cc apply_fusion): False
     # disables fused custom kernels (pallas flash attention) in "auto" mode
     enable_fusion: bool = True
+    # small per-call counters an op reports beside its outputs (routed rows
+    # of an expert layer): None = not collected (training, evaluation); a
+    # dict = summed over the layers that report under one name, and handed
+    # out as new_state[STATS_KEY] by build_forward (the serving programs)
+    stats: Optional[Dict[str, Any]] = None
+
+    def add_stat(self, name: str, value) -> None:
+        if self.stats is not None:
+            self.stats[name] = self.stats.get(name, 0) + value
 
     def rng_for(self, layer: Layer) -> jax.Array:
         if self.rng is None:
@@ -66,6 +75,15 @@ class OpDef:
     infer: Callable[[Layer], List[TensorSpec]]
     lower: Callable[[Layer, List[jnp.ndarray], Dict[str, jnp.ndarray], LoweringCtx], List[jnp.ndarray]]
     flops: Optional[Callable[[Layer], float]] = None  # per forward pass
+    # what the serving stack asks of an op (flexflow_tpu/serving):
+    # serving_params(params, kind) -> the params of its prefill / decode
+    # twin (kind "prefill" | "decode"; None: the layer's own);
+    # state_kind: the per-request state it carries ("paged_kv": K/V pages
+    # of the paged pool, "recurrent": fixed-size per-slot arrays);
+    # slot_state(layer) -> {name: (per-slot shape, dtype)} for "recurrent"
+    serving_params: Optional[Callable[[Dict[str, Any], str], Dict[str, Any]]] = None
+    state_kind: Optional[str] = None
+    slot_state: Optional[Callable[[Layer], Dict[str, Any]]] = None
 
     def flop_count(self, layer: Layer) -> float:
         if self.flops is not None:
@@ -76,9 +94,12 @@ class OpDef:
 
 _REGISTRY: Dict[OperatorType, OpDef] = {}
 
+# where build_forward(collect_stats=True) puts LoweringCtx.stats
+STATS_KEY = "serve/stats"
 
-def register_op(op_type: OperatorType, infer, lower, flops=None) -> OpDef:
-    d = OpDef(infer=infer, lower=lower, flops=flops)
+
+def register_op(op_type: OperatorType, infer, lower, flops=None, **serving) -> OpDef:
+    d = OpDef(infer=infer, lower=lower, flops=flops, **serving)
     _REGISTRY[op_type] = d
     return d
 

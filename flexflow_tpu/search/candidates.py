@@ -394,9 +394,10 @@ def _layer_candidates(layer: "Layer", machine: MachineSpec, batch_sizes,
 
     elif t is OperatorType.MULTIHEAD_ATTENTION:
         heads = layer.params["num_heads"]
+        kv_heads = int(layer.params.get("num_kv_heads") or heads)
         for m in maxes:
             dm = machine.mesh_axes[m]
-            if heads % dm:
+            if heads % dm or kv_heads % dm:
                 continue
             wd = {w: [None, m] for w in ("wq", "wk", "wv")}
             wd["wo"] = [m, None]

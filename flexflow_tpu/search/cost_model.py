@@ -98,6 +98,10 @@ class KVCacheSpec:
     # context grows at fixed HBM-page budget.
     host_pages: int = 0
     device_pages: int = 0
+    # fixed-size per-slot state beside the pages (the recurrent layers'
+    # SSM state and conv tail, summed over those layers): resident for
+    # every slot, read and written by every decode step, never paged
+    state_bytes_per_slot: int = 0
 
     @property
     def padded_len(self) -> int:
@@ -122,7 +126,8 @@ class KVCacheSpec:
         return self.pool_pages * self.page_bytes()
 
     def total_bytes(self) -> int:
-        return self.layers * self.layer_bytes()
+        return self.layers * self.layer_bytes() \
+            + self.slots * self.state_bytes_per_slot
 
     def per_device_bytes(self, model_degree: int = 1) -> int:
         """Resident bytes per device with the heads dim sharded
@@ -146,9 +151,13 @@ class KVCacheSpec:
         return self.layers * self.host_pages * self.page_bytes()
 
     def fingerprint(self) -> tuple:
-        return (self.layers, self.heads, self.head_dim, self.slots,
-                self.pages_per_slot, self.page_size, self.itemsize,
-                self.scale_itemsize, self.host_pages, self.device_pages)
+        fp = (self.layers, self.heads, self.head_dim, self.slots,
+              self.pages_per_slot, self.page_size, self.itemsize,
+              self.scale_itemsize, self.host_pages, self.device_pages)
+        # only where there is such state: the keys of caches that hold
+        # strategies of models without it stay what they were
+        return fp + ((self.state_bytes_per_slot,)
+                     if self.state_bytes_per_slot else ())
 
 
 def zero_divisor(spec: TensorSpec, dims: Sequence[DimSharding],

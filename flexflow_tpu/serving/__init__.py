@@ -20,7 +20,9 @@ from flexflow_tpu.serving.reqtrace import (RequestTracer, StreamingHistogram,
                                            TERMINAL_FIELDS, terminal_record)
 from flexflow_tpu.serving.scheduler import (ContinuousBatchingScheduler,
                                             Request, gpt2_prompt_inputs,
-                                            gpt2_step_inputs)
+                                            gpt2_step_inputs,
+                                            valid_prompt_inputs,
+                                            valid_step_inputs)
 from flexflow_tpu.serving.tracefmt import (Trace, TraceRecord, load_trace,
                                            save_trace)
 from flexflow_tpu.serving.twin import (TwinCosts, TwinResult, TwinSpec,
@@ -30,6 +32,7 @@ __all__ = [
     "compile_serving", "ServingCompiled", "PagedKVCache", "KVPoolExhausted",
     "ContinuousBatchingScheduler", "Request", "clone_for_serving",
     "serving_optimize", "gpt2_prompt_inputs", "gpt2_step_inputs",
+    "valid_prompt_inputs", "valid_step_inputs",
     "PAGE_TABLE_KEY", "POS_KEY", "ACTIVE_KEY",
     "RequestTracer", "StreamingHistogram", "TERMINAL_FIELDS",
     "terminal_record",

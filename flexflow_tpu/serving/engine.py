@@ -59,7 +59,7 @@ from flexflow_tpu.parallel.machine import MachineSpec, build_mesh
 from flexflow_tpu.search import cost_model as cm
 from flexflow_tpu.serving.kv_cache import (ACTIVE_KEY, POS_KEY, PagedKVCache)
 from flexflow_tpu.serving.program import (attn_head_degree, clone_for_serving,
-                                          serving_optimize)
+                                          recurrent_layers, serving_optimize)
 
 log = logging.getLogger("flexflow_tpu")
 
@@ -153,8 +153,12 @@ def compile_serving(model, max_batch_slots: Optional[int] = None,
     if not attn_params:
         raise ValueError("compile_serving needs a model with attention "
                          "layers (nothing to cache)")
-    heads = int(attn_params[0]["num_heads"])
     embed = int(attn_params[0]["embed_dim"])
+    head_dim = embed // int(attn_params[0]["num_heads"])
+    # the pools hold the K/V heads: fewer than the query heads where they
+    # are grouped
+    heads = int(attn_params[0].get("num_kv_heads")
+                or attn_params[0]["num_heads"])
     seq = int(model.input_tensors[0].spec.shape[1])
     if draft is None and spec_k > 0 and getattr(cfg, "serve_draft_model", ""):
         draft = _draft_from_spec(cfg, cfg.serve_draft_model,
@@ -162,11 +166,40 @@ def compile_serving(model, max_batch_slots: Optional[int] = None,
     with tel.span("serve/compile_serving", cat="compile", slots=slots,
                   max_decode_len=max_new, kv_page_size=page,
                   spec_tokens=spec_k if draft is not None else 0,
-                  kv_dtype=str(kv_dtype)):
+                  kv_dtype=str(kv_dtype)) as compile_span:
         machine = resolve_machine(cfg)
         mesh = build_mesh(machine)
         pre_model, attn = clone_for_serving(model, "prefill", slots)
         dec_model, _ = clone_for_serving(model, "decode", slots)
+        recurrent = recurrent_layers(dec_model)
+        state_bytes = sum(
+            int(np.prod(shape)) * jnp.dtype(dt).itemsize
+            for leaves in recurrent.values() for shape, dt in leaves.values())
+        if recurrent:
+            # what a layer with per-slot recurrent state does not support
+            # yet fails here, not silently later
+            kind = dec_model.get_layer_by_name(next(iter(recurrent))).op_type
+            unsupported = [
+                what for what, asked in (
+                    ("the host KV tier (--kv-host-pages): parking a slot "
+                     "would have to move its state with its pages",
+                     int(getattr(cfg, "kv_host_pages", 0) or 0) > 0),
+                    ("speculative decoding: the verify pass would have to "
+                     "roll the state back", draft is not None and spec_k > 0))
+                if asked]
+            if unsupported:
+                raise NotImplementedError(
+                    f"compile_serving: the model has {len(recurrent)} "
+                    f"{kind.value} layers with per-slot recurrent state, "
+                    "which do not support " + "; ".join(unsupported))
+        compile_span.set(kv_layers=len(attn), state_layers=len(recurrent),
+                         state_bytes_per_slot=state_bytes)
+        for l in model.layers:
+            if l.op_type is OperatorType.MOE_LAYER:
+                lo, hi = l.params["experts_held"]
+                compile_span.set(experts_held=hi - lo,
+                                 experts_routed_over=l.params["num_experts"])
+                break
         # tiered KV (--kv-host-pages H > 0): host pages SUBSTITUTE device
         # pages — the HBM pool shrinks to slots*pages_per_slot - H (floored
         # at one slot's worth, the minimum a decoding slot must keep hot),
@@ -181,11 +214,12 @@ def compile_serving(model, max_batch_slots: Optional[int] = None,
         prefetch_ahead = max(1, int(getattr(cfg, "kv_prefetch_ahead", 2)
                                     or 2))
         kv_spec = cm.KVCacheSpec(
-            layers=len(attn), heads=heads, head_dim=embed // heads,
+            layers=len(attn), heads=heads, head_dim=head_dim,
             slots=slots, pages_per_slot=pages_per_slot,
             page_size=page, itemsize=kv_itemsize,
             scale_itemsize=kv_scale_itemsize,
-            host_pages=host_pages, device_pages=device_pages)
+            host_pages=host_pages, device_pages=device_pages,
+            state_bytes_per_slot=state_bytes)
         searched = (getattr(cfg, "search_budget", 0) > 0
                     and not cfg.only_data_parallel
                     and machine.num_devices > 1)
@@ -229,7 +263,7 @@ def compile_serving(model, max_batch_slots: Optional[int] = None,
                                kv_dtype=kv_dtype, kv_quantized=kv_quantized,
                                verify_model=ver_model,
                                spec_tokens=spec_k if draft_engine else 0,
-                               draft=draft_engine)
+                               draft=draft_engine, recurrent=recurrent)
 
 
 def _positionwise_head(prefill_model) -> Optional[Any]:
@@ -253,7 +287,8 @@ class ServingCompiled:
                  decode_model, prefill_strategy, decode_strategy,
                  attn_layers: List[str], kv_spec: "cm.KVCacheSpec",
                  max_decode_len: int, kv_dtype=None, kv_quantized: bool = False,
-                 verify_model=None, spec_tokens: int = 0, draft=None):
+                 verify_model=None, spec_tokens: int = 0, draft=None,
+                 recurrent: Optional[Dict[str, Dict[str, tuple]]] = None):
         self.model = model
         self.cfg = model.config
         self.machine = machine
@@ -280,7 +315,8 @@ class ServingCompiled:
         heads_axis = _wq_heads_axis(decode_strategy, self.attn_layers)
         self.kv = PagedKVCache(kv_spec, self.attn_layers, mesh,
                                heads_axis=heads_axis, dtype=self.kv_dtype,
-                               quantized=self.kv_quantized, machine=machine)
+                               quantized=self.kv_quantized, machine=machine,
+                               recurrent=recurrent)
         deg = 1
         if self.kv.heads_axis is not None:
             axes = (self.kv.heads_axis,) if isinstance(self.kv.heads_axis, str) \
@@ -293,7 +329,8 @@ class ServingCompiled:
         dec_out = decode_model.layers[-1].outputs[:1]
         fwd_kw = dict(seq_length=self.cfg.seq_length or None,
                       compute_dtype=self.cfg.compute_dtype,
-                      enable_fusion=self.cfg.enable_fusion)
+                      enable_fusion=self.cfg.enable_fusion,
+                      collect_stats=True)
         pre_fwd = build_forward(prefill_model.layers,
                                 prefill_model.input_tensors, pre_out, mesh,
                                 prefill_strategy, **fwd_kw)
@@ -651,7 +688,9 @@ class ServingCompiled:
         where tokens is `[slots]` int32, the greedy token after each slot's
         last real position (`lengths - 1`, row 0 for an empty slot), taken
         inside the program so no logits leave the device. `kv_state` is
-        `prefill`'s."""
+        `prefill`'s: per layer that carries state, what `commit_prefill`
+        takes, and under STATS_KEY the wave's counters where ops report
+        any."""
         return self._run_prefill(self._prefill_first_tokens_jit, params,
                                  list(input_arrays), lengths)
 
@@ -659,13 +698,15 @@ class ServingCompiled:
         """One single-token step over all slots: returns (logits
         `[slots, 1, vocab]`, new cache state with positions advanced).
         Dispatch-only from the host's view — no sync, so the scheduler can
-        keep a bounded number of steps in flight."""
-        if not tel.enabled():
-            return self._decode_jit(params, state, list(input_arrays))
-        t0 = tel.now_us()
-        out = self._decode_jit(params, state, list(input_arrays))
-        tel.record("serve/decode_step", t0, cat="serve")
-        return out
+        keep a bounded number of steps in flight. Where ops report
+        counters, the step's ride in the new state under STATS_KEY, as a
+        prefill's do in its kv_state: whoever reads them pops them, so what
+        goes into the next step has the shape of what came into this one."""
+        t0 = tel.now_us() if tel.enabled() else None
+        logits, new_state = self._decode_jit(params, state, list(input_arrays))
+        if t0 is not None:
+            tel.record("serve/decode_step", t0, cat="serve")
+        return logits, new_state
 
     def verify_step(self, params, state, input_arrays):
         """One speculative-verify pass: the `[slots, K+1]` decode-mode

@@ -25,6 +25,15 @@ decode program as lowering state (`compile.build_forward`'s state →
 new_state channel): `state[layer_name] = {"k", "v"}`,
 `state["serve/page_table"]`, `state["serve/pos"]`, `state["serve/active"]`.
 
+Recurrent layers (a state-space mixer) keep the other kind of per-request
+state in the same manager: fixed-size arrays per slot, `state[layer_name] =
+{leaf: [slots, ...]}` (an SSM state and a conv tail), never paged.
+`commit_prefill` writes them for the slots a wave prefilled (`lengths > 0`)
+and leaves every other slot's untouched; the decode program advances them
+for the slots its inputs name as live. What they do not support yet raises
+NotImplementedError: the host tier, park/spill and the hand-off, which
+would all have to move this state with the pages.
+
 Host cold tier (--kv-host-pages > 0): causal decode streams a slot's whole
 committed working set every step, so pages cannot go cold while their slot
 decodes — the tier works at SLOT granularity. `spill` parks an active slot:
@@ -132,15 +141,42 @@ def _commit_prefill(cache_state, kv_state, slot_ids, lengths):
     return new
 
 
+@jax.jit
+def _commit_state(old_state, new_state, slot_ids, lengths):
+    """Write the prefill program's recurrent state (`[Bp, ...]` per leaf)
+    into the per-slot arrays of the slots in `slot_ids` that the wave
+    prefilled (`lengths > 0`); a slot that sat the wave out keeps what it
+    had."""
+    took = lengths > 0
+    out = {}
+    for name, leaves in new_state.items():
+        out[name] = {}
+        for key, fresh in leaves.items():
+            old = old_state[name][key]
+            sel = took.reshape((-1,) + (1,) * (fresh.ndim - 1))
+            out[name][key] = old.at[slot_ids].set(
+                jnp.where(sel, fresh.astype(old.dtype), old[slot_ids]))
+    return out
+
+
+def _tree_bytes(tree) -> int:
+    return sum(int(x.nbytes) for x in jax.tree_util.tree_leaves(tree))
+
+
 class PagedKVCache:
-    """Device-resident paged KV pools + host-side page accounting."""
+    """Device-resident paged KV pools + host-side page accounting, and the
+    per-slot state of recurrent layers beside them."""
 
     def __init__(self, spec: KVCacheSpec, attn_layers: List[str],
                  mesh: Optional[Mesh] = None, heads_axis=None,
-                 dtype=jnp.float32, quantized: bool = False, machine=None):
+                 dtype=jnp.float32, quantized: bool = False, machine=None,
+                 recurrent: Optional[Dict[str, Dict[str, tuple]]] = None):
         self.spec = spec
         self.machine = machine  # host_bw source for transfer pricing rows
         self.attn_layers = list(attn_layers)
+        # {layer: {leaf: (per-slot shape, dtype)}} (program.recurrent_layers)
+        # (compile_serving refuses a host tier beside them)
+        self.recurrent = dict(recurrent or {})
         self.mesh = mesh
         self.heads_axis = None
         self.quantized = bool(quantized)
@@ -184,6 +220,12 @@ class PagedKVCache:
             return st
 
         self.state: Dict = {n: layer_state() for n in self.attn_layers}
+        for n, leaves in self.recurrent.items():
+            self.state[n] = {
+                key: (jax.device_put(z, self._repl) if self._repl is not None
+                      else z)
+                for key, (shape, dt) in leaves.items()
+                for z in [jnp.zeros((spec.slots,) + tuple(shape), dt)]}
         # host mirrors (authoritative at scheduler sync points)
         self._table = np.zeros((spec.slots, spec.pages_per_slot), np.int32)
         self._pos = np.zeros((spec.slots,), np.int32)
@@ -300,6 +342,13 @@ class PagedKVCache:
         """Slots whose KV sits in the host tier with no prefetch in flight
         — the scheduler's rotation candidates."""
         return [s for s in self._cold if s not in self._inflight]
+
+    def _no_recurrent(self, what: str) -> None:
+        if self.recurrent:
+            raise NotImplementedError(
+                f"{what}: a model with recurrent layers "
+                f"({sorted(self.recurrent)[0]}, ...) keeps per-slot state "
+                "that this path does not move")
 
     def can_spill(self, slot: int) -> bool:
         return bool(self.host_pages) and bool(self._active[slot]) and \
@@ -434,6 +483,7 @@ class PagedKVCache:
         the prefill replica spills the slot after commit, exports it here,
         evicts, and the fleet delivers the payload to a decode replica's
         `import_parked`. Non-destructive — the caller evicts afterwards."""
+        self._no_recurrent("export_parked")
         host_ids = self._cold.get(slot)
         if host_ids is None:
             raise ValueError(f"slot {slot} is not parked (spill it first)")
@@ -460,6 +510,7 @@ class PagedKVCache:
         other op. Raises `KVPoolExhausted` when the host free list is
         short — backpressure, the fleet retries the delivery."""
         import time as _time
+        self._no_recurrent("import_parked")
         if self._active[slot] or slot in self._cold:
             raise ValueError(f"slot {slot} is occupied")
         need = int(payload["pages"])
@@ -496,11 +547,26 @@ class PagedKVCache:
 
     # ---------------------------------------------------------- device ops
     def commit_prefill(self, kv_state, slot_ids, lengths) -> None:
-        """Write the prefill program's captured K/V into the pools."""
-        self.state = _commit_prefill(
-            self.state, {n: kv_state[n] for n in self.attn_layers},
-            self._put_repl(np.asarray(slot_ids, np.int32)),
-            self._put_repl(np.asarray(lengths, np.int32)))
+        """Write the prefill program's captured K/V into the pools, and
+        its recurrent state into the slots the wave prefilled."""
+        from flexflow_tpu import telemetry as tel
+
+        slot_ids = self._put_repl(np.asarray(slot_ids, np.int32))
+        lengths = self._put_repl(np.asarray(lengths, np.int32))
+        paged = {k: v for k, v in self.state.items()
+                 if k not in self.recurrent}
+        fresh = {n: kv_state[n] for n in self.attn_layers}
+        with tel.span("serve/prefill/commit_kv", cat="serve",
+                      bytes=_tree_bytes(fresh)):
+            state = _commit_prefill(paged, fresh, slot_ids, lengths)
+        if self.recurrent:
+            fresh = {n: kv_state[n] for n in self.recurrent}
+            with tel.span("serve/prefill/commit_state", cat="serve",
+                          bytes=_tree_bytes(fresh)):
+                state.update(_commit_state(
+                    {n: self.state[n] for n in self.recurrent}, fresh,
+                    slot_ids, lengths))
+        self.state = state
 
     def adopt(self, new_state) -> None:
         """Take ownership of the state returned by a decode step."""
@@ -512,7 +578,7 @@ class PagedKVCache:
         dev = (self.mesh.devices.flat[0] if self.mesh is not None
                else jax.devices()[0])
         total = 0
-        for n in self.attn_layers:
+        for n in self.attn_layers + list(self.recurrent):
             # every leaf of the layer's cache state — values AND, for a
             # quantized cache, the per-(entry, head) scale arrays
             for leaf in self.state[n].values():

@@ -41,7 +41,7 @@ DP expansions.
 from __future__ import annotations
 
 import time
-from typing import List, Optional, Tuple
+from typing import Dict, List, Optional, Tuple
 
 from flexflow_tpu.core.graph import topo_order
 from flexflow_tpu.core.layer import Layer
@@ -54,21 +54,26 @@ from flexflow_tpu.search import cost_model as cm
 
 
 def _serving_params(layer: Layer, kind: str) -> dict:
-    """Per-op param overrides for a serving clone. Dropout is hard-zeroed
-    everywhere (inference determinism is a property of the PROGRAM, not a
-    flag callers must remember); attention switches into the kv_out
-    (prefill) or paged-cache decode mode."""
+    """A layer's params in a serving clone: what its op's `serving_params`
+    says its prefill / decode twin looks like (attention's kv_out and
+    paged-cache modes, dropout hard-zeroed, a state-space mixer's state_out
+    and one-step forms), the layer's own where the op has none."""
+    hook = get_op_def(layer.op_type).serving_params
     p = dict(layer.params)
-    if layer.op_type is OperatorType.MULTIHEAD_ATTENTION:
-        p["dropout"] = 0.0
-        if kind == "decode":
-            p["decode"] = True
-            p["impl"] = "xla"  # the decode path is its own fixed lowering
-        else:
-            p["kv_out"] = True
-    elif layer.op_type is OperatorType.DROPOUT:
-        p["rate"] = 0.0
-    return p
+    return p if hook is None else hook(p, kind)
+
+
+def recurrent_layers(model) -> Dict[str, Dict[str, tuple]]:
+    """The layers of `model` that carry fixed-size per-request state (their
+    op's `state_kind` is "recurrent"), in topo order: {layer name: {leaf:
+    (per-slot shape, dtype)}}. The paged K/V of attention layers is the
+    other kind, listed by `clone_for_serving`."""
+    out: Dict[str, Dict[str, tuple]] = {}
+    for l in topo_order(model.layers):
+        d = get_op_def(l.op_type)
+        if d.state_kind == "recurrent":
+            out[l.name] = d.slot_state(l)
+    return out
 
 
 def clone_for_serving(model, kind: str, slots: int,
@@ -118,7 +123,7 @@ def clone_for_serving(model, kind: str, slots: int,
             nt = nl.add_output(spec, idx=i, name=l.outputs[i].name)
             tmap[l.outputs[i].guid] = nt
         sm.layers.append(nl)
-        if l.op_type is OperatorType.MULTIHEAD_ATTENTION:
+        if get_op_def(l.op_type).state_kind == "paged_kv":
             attn.append(l.name)
     return sm, attn
 

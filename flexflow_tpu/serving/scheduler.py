@@ -92,8 +92,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from flexflow_tpu import telemetry as tel
+from flexflow_tpu.ops.registry import STATS_KEY
 from flexflow_tpu.runtime.resilience import RetryPolicy, run_resilient
-from flexflow_tpu.serving.kv_cache import (KVPoolExhausted, POS_KEY,
+from flexflow_tpu.serving.kv_cache import (ACTIVE_KEY, KVPoolExhausted, POS_KEY,
                                            derive_prefetch_ahead,
                                            learned_kv_transfer_seconds)
 from flexflow_tpu.serving.reqtrace import RequestTracer, terminal_record
@@ -133,6 +134,35 @@ def gpt2_step_inputs(tokens, state) -> List[Any]:
     if s > 1:
         pos = pos + jnp.arange(s, dtype=state[POS_KEY].dtype)[None, :]
     return [tokens, pos]
+
+
+def valid_prompt_inputs(ids: np.ndarray, lengths: np.ndarray) -> List[np.ndarray]:
+    """Prefill inputs of a model without positions (`input_ids`, `valid`):
+    token ids, and which positions of the padded wave hold a prompt token.
+    Layers whose state depends on where a row ends (a state-space mixer)
+    read it from `valid`."""
+    valid = np.arange(ids.shape[1])[None, :] < np.asarray(lengths)[:, None]
+    return [ids.astype(np.int32), valid.astype(np.int32)]
+
+
+def valid_step_inputs(tokens, state) -> List[Any]:
+    """Decode inputs of such a model: the next token of every slot, and
+    which slots are live (device-side, from the cache state: no host sync),
+    so that a step advances the recurrent state of live slots only."""
+    live = state[ACTIVE_KEY].astype(jnp.int32)[:, None]
+    return [tokens, jnp.broadcast_to(live, tokens.shape)]
+
+
+def _stat_totals(stats: List[Any]) -> Dict[str, float]:
+    """The programs' own counters (one dict of device scalars per decode
+    step or prefill wave, as it came out under STATS_KEY), summed per name
+    on the host. Called where the step's tokens are materialized anyway, so it
+    waits for nothing new."""
+    totals: Dict[str, float] = {}
+    for step in jax.device_get([s for s in stats if s]):
+        for name, value in step.items():
+            totals[name] = totals.get(name, 0) + value.item()
+    return totals
 
 
 def _urgency(r: Request):
@@ -240,6 +270,11 @@ class ContinuousBatchingScheduler:
         # (see fleet._SharedRuntimeEngine).
         self.exec_lock: Any = threading.RLock()
         self._exec_serialized = False
+        if self.handoff is not None and getattr(self.kv, "recurrent", None):
+            raise NotImplementedError(
+                "prefill-only handoff: the model has layers with per-slot "
+                f"recurrent state ({sorted(self.kv.recurrent)[0]}, ...), "
+                "which the hand-off does not move")
         if self.handoff is not None and self._spec:
             raise ValueError("prefill-only handoff does not compose with "
                              "speculative decoding (no draft-cache handoff)")
@@ -262,6 +297,8 @@ class ContinuousBatchingScheduler:
         self.decode_steps = 0
         self.prefills = 0
         self.materializations = 0  # host syncs that drained a window
+        # the counters of the dispatched, unmaterialized steps
+        self._window_stats: List[Any] = []
         # request-level tracing (ISSUE 15): zero-sync by construction —
         # the tracer only ever sees timestamps the loop already took at
         # its sync points. With reqtrace off there is NO tracer and the
@@ -501,8 +538,10 @@ class ContinuousBatchingScheduler:
         # the bytes (TTFT is a real materialization). The span keeps the
         # name it had when whole logits crossed here: its `bytes` say
         # which it was, `slots * 4` now
-        with tel.span("serve/prefill/device_wait", cat="serve"):
+        with tel.span("serve/prefill/device_wait", cat="serve") as sp:
             jax.block_until_ready(first_tokens)
+            if STATS_KEY in kv_state:
+                sp.set(**_stat_totals([kv_state[STATS_KEY]]))
         with tel.span("serve/prefill/logits_to_host", cat="serve") as sp:
             tok = np.asarray(first_tokens)
             sp.set(bytes=int(tok.nbytes))
@@ -736,8 +775,11 @@ class ContinuousBatchingScheduler:
         steps = len(window_toks)
         window = self.materializations + 1
         with tel.span("serve/decode/window_sync", cat="serve",
-                      window=window, steps=steps):
+                      window=window, steps=steps) as sp:
             mats = [np.asarray(t) for t in window_toks]
+            if self._window_stats:
+                sp.set(**_stat_totals(self._window_stats))
+                self._window_stats = []
         t_now = time.perf_counter()
         self.materializations += 1
         with tel.span("serve/decode/commit", cat="serve",
@@ -834,6 +876,8 @@ class ContinuousBatchingScheduler:
                 vlogits, tstate = self.engine.verify_step(
                     self.params, tstate, self.step_inputs_fn(ver_in, tstate))
                 t_pred_dev = jnp.argmax(vlogits, axis=-1).astype(jnp.int32)
+            for s in (tstate, dstate):
+                s.pop(STATS_KEY, None)   # counters: read by plain decode only
             t_pred = np.asarray(t_pred_dev)
             drafted = np.asarray(ver_in)[:, 1:]              # [slots, K]
             if self._exec_serialized:
@@ -1073,6 +1117,9 @@ class ContinuousBatchingScheduler:
                     if self._exec_serialized:
                         jax.block_until_ready(next_dev)
                 window_toks.append(next_dev)
+                stats = state.pop(STATS_KEY, None)
+                if stats:
+                    self._window_stats.append(stats)
                 self.decode_steps += 1
         if self.tiered:
             # final tier ledger: counters into telemetry (monitor/prom) and

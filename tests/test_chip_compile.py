@@ -331,3 +331,64 @@ def test_remat_shrinks_the_compiled_steps_temp_memory(described_devices):
             *_train_step_shapes(cm, (batch,))).compile()
         temp[remat] = compiled.memory_analysis().temp_size_in_bytes
     assert 0 < temp[True] < temp[False], temp
+
+
+def test_granite_serving_programs_fit_one_chip(described_devices, mosaic,
+                                               one_chip, monkeypatch):
+    """`granite-4.0-h-small.serve-chat`'s two programs at the cell's own
+    sizes (16 slots, width 1024, 9.93 GB of bf16 weights), through the
+    normal entry points: the chip's compiler must hold the prefill wave
+    beside the weights, the state and the cache (its first compile ran
+    1 GB over the chip: a `[tokens, k, d]` f32 combine and every mixer's
+    xBC kept live to the program's end), and the grouped product and the
+    flash kernel must be what it lowers to."""
+    sys.path.insert(0, os.path.join(ROOT, "benchmarks"))
+    from families import family_of
+    from harness import manifest as mf
+
+    from flexflow_tpu import FFConfig, FFModel
+    from flexflow_tpu.compiler.compile import build_init_fn
+    from flexflow_tpu.core.graph import topo_order
+    from flexflow_tpu.serving import compile_serving
+
+    described_devices(1)
+    # a described device holds no array: the state manager's zeros stay put
+    monkeypatch.setattr(jax, "device_put", lambda x, *a, **k: x)
+    cell = mf.load_cell(mf.load_manifest(), "granite-4.0-h-small.serve-chat")
+    slots = cell.system["max_batch_slots"]
+    model = FFModel(FFConfig(batch_size=slots, seed=3, strategy_cache=False,
+                             log_level="warning", **cell.system["ffconfig"]))
+    g = family_of(cell.config).build(model, cell.config, slots)
+    eng = compile_serving(model, max_batch_slots=slots,
+                          max_decode_len=cell.system["max_decode_len"],
+                          kv_page_size=cell.system["kv_page_size"])
+
+    def sds(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    init = build_init_fn(topo_order(eng.decode_model.layers),
+                         model._initializer_overrides)
+    params = jax.tree_util.tree_map(
+        sds, jax.eval_shape(init, jax.random.PRNGKey(0)))
+    state = jax.tree_util.tree_map(sds, eng.kv.state)
+    held = sum(x.size * x.dtype.itemsize
+               for x in jax.tree_util.tree_leaves(state))
+    assert 0.65e9 < held < 0.75e9
+    decode = eng._decode_jit.lower(
+        params, state, [i32(slots, 1), i32(slots, 1)]).compile()
+    prefill = eng._prefill_first_tokens_jit.lower(
+        params, [i32(slots, g.seq), i32(slots, g.seq)], i32(slots)).compile()
+    chip = 15.75e9          # what the compiler has of a v5e chip's 16 GB
+    for program, beside in ((decode, 0), (prefill, held)):
+        m = program.memory_analysis()
+        assert 9.9e9 < m.argument_size_in_bytes
+        need = (m.argument_size_in_bytes + m.output_size_in_bytes
+                + m.temp_size_in_bytes + beside)
+        assert need < 0.9 * chip, (need, m)
+    assert prefill.memory_analysis().temp_size_in_bytes < 2.5e9
+    text = prefill.as_text()
+    assert "ragged-dot" in text and "tpu_custom_call" in text
+    assert "ragged-dot" in decode.as_text()
