@@ -96,8 +96,9 @@ def _mha_decode_lower(layer: Layer, inputs, weights, ctx: LoweringCtx):
     Inputs are [slots, s, embed] — s=1 for the plain decode program, s=K+1
     for the speculative-verify program (one batched pass teacher-forcing
     the K drafted tokens). The cache lives in lowering state:
-      ctx.state[layer.name]    = {"k": [pages, page, h, d], "v": ...,
-                                  optionally "k_scale"/"v_scale" for int8}
+      ctx.state[layer.name]    = {"k": [pages, page, h * d], "v": ...,
+                                  optionally "k_scale"/"v_scale"
+                                  [pages, page, h] for int8}
       ctx.state["serve/page_table"] = [slots, pages_per_slot] int32 page ids
       ctx.state["serve/pos"]        = [slots] int32 count of cached tokens
 
@@ -112,7 +113,13 @@ def _mha_decode_lower(layer: Layer, inputs, weights, ctx: LoweringCtx):
     at the reserved scratch page 0 with pos 0, so their writes land in
     scratch and their (garbage but finite) outputs are ignored by the
     scheduler. Everything is a fixed-shape gather/scatter — no resharding,
-    no recompilation across steps."""
+    no recompilation across steps. Heads and head_dim are one axis in the
+    pools (kv_cache.py says why): the token rows are merged before the
+    scatter, and the gathered context (as large as a pool) stays merged —
+    the query rows are laid over the merged axis instead — so with the
+    state donated a step appends in place and the page gather is the only
+    op that touches a whole pool. The int8 kernel takes the gathered
+    `[b, L, h, d]`."""
     q = inputs[0]
     p = layer.params
     heads = p["num_heads"]
@@ -147,20 +154,22 @@ def _mha_decode_lower(layer: Layer, inputs, weights, ctx: LoweringCtx):
     pageix = jnp.where(in_range,
                        pt[rows[:, None], jnp.minimum(pg, pt.shape[1] - 1)], 0)
     off = t % page
-    if quantized:
-        from flexflow_tpu.serving.kv_cache import kv_quantize
+    from flexflow_tpu.serving.kv_cache import kv_quantize, merge_heads
 
+    if quantized:
         qk, ks = kv_quantize(kh)
         qv, vs = kv_quantize(vh)
-        k_pool = k_pool.at[pageix, off].set(qk)
-        v_pool = v_pool.at[pageix, off].set(qv)
+        k_pool = k_pool.at[pageix, off].set(merge_heads(qk))
+        v_pool = v_pool.at[pageix, off].set(merge_heads(qv))
         k_scale = cache["k_scale"].at[pageix, off].set(ks)
         v_scale = cache["v_scale"].at[pageix, off].set(vs)
         ctx.new_state[layer.name] = {"k": k_pool, "v": v_pool,
                                      "k_scale": k_scale, "v_scale": v_scale}
     else:
-        k_pool = k_pool.at[pageix, off].set(kh.astype(k_pool.dtype))
-        v_pool = v_pool.at[pageix, off].set(vh.astype(v_pool.dtype))
+        k_pool = k_pool.at[pageix, off].set(
+            merge_heads(kh).astype(k_pool.dtype))
+        v_pool = v_pool.at[pageix, off].set(
+            merge_heads(vh).astype(v_pool.dtype))
         ctx.new_state[layer.name] = {"k": k_pool, "v": v_pool}
 
     scale = _scale(p, hd)
@@ -187,29 +196,56 @@ def _mha_decode_lower(layer: Layer, inputs, weights, ctx: LoweringCtx):
                 ctx.mesh, (spec, spec, sspec, spec, sspec, PartitionSpec()),
                 spec)(qh, Kq, Ks, Vq, Vs, pos)
         else:
-            K = (Kq.astype(jnp.float32) * Ks[..., None]).astype(dt)
-            V = (Vq.astype(jnp.float32) * Vs[..., None]).astype(dt)
+            K = merge_heads((Kq.astype(jnp.float32) * Ks[..., None]).astype(dt))
+            V = merge_heads((Vq.astype(jnp.float32) * Vs[..., None]).astype(dt))
     else:
-        # gather each slot's pages: [slots, pages_per_slot, page, h, d]
-        K = k_pool[pt].reshape(b, -1, kvh, hd).astype(dt)
-        V = v_pool[pt].reshape(b, -1, kvh, hd).astype(dt)
+        # gather each slot's pages, heads still merged: [slots, L, kvh * d]
+        K = k_pool[pt].reshape(b, -1, kvh * hd).astype(dt)
+        V = v_pool[pt].reshape(b, -1, kvh * hd).astype(dt)
     if out is None:
         # query heads as [kvh groups, r in a group] against their group's
-        # K/V head (r = 1: one K/V head a query head)
+        # K/V head (r = 1: one K/V head a query head); on a mesh each shard
+        # attends over the heads it holds, as the pools are split
         qg = qh.reshape(b, s, kvh, heads // kvh, hd)
-        logits = jnp.einsum("bqgrd,bkgd->bgrqk", qg, K) * scale
-        # causal-by-construction: query token i (at position pos+i, just
-        # written) attends cached positions 0..pos+i inclusive
-        keep = (jnp.arange(K.shape[1])[None, None, None, None, :]
-                <= t[:, None, None, :, None])
-        logits = jnp.where(keep, logits, jnp.finfo(logits.dtype).min)
-        probs = jax.nn.softmax(logits, axis=-1)
-        out = jnp.einsum("bgrqk,bkgd->bqgrd", probs, V)
+        attend = functools.partial(_merged_axis_attention, scale=scale)
+        spec = _attn_pspec(layer, ctx, b, kvh)
+        if spec is not None:
+            rows = PartitionSpec(spec[0], None, spec[2], None, None)
+            merged = PartitionSpec(spec[0], None, spec[2])
+            attend = per_shard(
+                attend, ctx.mesh,
+                (rows, merged, merged, PartitionSpec(spec[0], None)), rows)
+        out = attend(qg, K, V, t)
     out = out.reshape(b, s, embed)
     y = out @ weights["wo"].astype(dt)
     if "bo" in weights:
         y = y + weights["bo"].astype(dt)
     return [y]
+
+
+def _merged_axis_attention(qg, K, V, t, *, scale):
+    """Decode attention against a gathered context that stays as the pools
+    hold it: `qg` `[b, s, g, r, d]` query rows (g K/V heads, r query heads
+    a group), `K`/`V` `[b, L, g * d]`, `t` `[b, s]` the rows' positions;
+    returns `[b, s, g, r, d]`. The context is as large as a pool, and
+    splitting its merged axis into heads of under 128 would relay all of
+    it. So the query side, a few rows, is laid over the merged axis
+    instead: group g's rows are zero outside K/V head g's span, one product
+    over the merged axis is then each head's own q k^T (the other spans add
+    exact zeros), and of probs @ V each group keeps its own span."""
+    b, s, g, r, d = qg.shape
+    own = jnp.eye(g, dtype=qg.dtype)
+    qm = jnp.einsum("bqgrd,gh->bqgrhd", qg, own).reshape(b, s, g, r, g * d)
+    logits = jnp.einsum("bqgre,bke->bgrqk", qm, K) * scale
+    # causal-by-construction: query token i (at position pos+i, just
+    # written) attends cached positions 0..pos+i inclusive
+    keep = (jnp.arange(K.shape[1])[None, None, None, None, :]
+            <= t[:, None, None, :, None])
+    logits = jnp.where(keep, logits, jnp.finfo(logits.dtype).min)
+    probs = jax.nn.softmax(logits, axis=-1)
+    spans = jnp.einsum("bgrqk,bke->bqgre", probs, V)
+    return jnp.einsum("bqgrhd,gh->bqgrd", spans.reshape(b, s, g, r, g, d),
+                      own)
 
 
 def _attn_pspec(layer: Layer, ctx: LoweringCtx, batch, heads: int):

@@ -22,10 +22,19 @@ scheduler between decode steps, when no dispatched window is in flight —
 loads any newer committed snapshot into a SECOND param tree (graph
 fingerprint validated first, `CheckpointMismatchError` on a foreign
 model), then activates it with a pointer flip. In-flight work holds
-references to the old tree (the serving jits never donate), so no
-request is dropped or corrupted. Previous versions are retained in
+references to the old tree (params are never donated; cache state always
+is), so no request is dropped or corrupted. Previous versions are retained in
 memory (`retain` trees, default 2 = double buffer); `rollback()` re-pins
 one — pinning stops `poll_swap` auto-advancing until `unpin()`.
+
+Donation: `decode_step`, `verify_step`, `spec_round_step` and
+`kv.commit_prefill` donate the cache STATE they are handed (argument 1 of
+the step programs; target and draft state of the speculative round) and
+append the step's K/V to the pools in place; `params` are argument 0 and
+never donated. The one rule for callers: the state handed in is dead after
+the call — use what comes back (`kv.adopt` it before anything else reads
+`kv.state`). kv_cache.py says why the pools are `[pages, page, heads *
+head_dim]`.
 """
 
 from __future__ import annotations
@@ -57,7 +66,8 @@ from flexflow_tpu.ops.op_type import OperatorType
 from flexflow_tpu.parallel.default_strategy import data_parallel_strategy
 from flexflow_tpu.parallel.machine import MachineSpec, build_mesh
 from flexflow_tpu.search import cost_model as cm
-from flexflow_tpu.serving.kv_cache import (ACTIVE_KEY, POS_KEY, PagedKVCache)
+from flexflow_tpu.serving.kv_cache import (ACTIVE_KEY, POS_KEY, PagedKVCache,
+                                           _tree_bytes)
 from flexflow_tpu.serving.program import (attn_head_degree, clone_for_serving,
                                           recurrent_layers, serving_optimize)
 
@@ -258,12 +268,18 @@ def compile_serving(model, max_batch_slots: Optional[int] = None,
                      attn_head_degree(dec_st, attn, machine)) / 2**20,
                  kv_dtype,
                  f" spec_tokens={spec_k}" if draft_engine else "")
-        return ServingCompiled(model, machine, mesh, pre_model, dec_model,
-                               pre_st, dec_st, attn, kv_spec, max_new,
-                               kv_dtype=kv_dtype, kv_quantized=kv_quantized,
-                               verify_model=ver_model,
-                               spec_tokens=spec_k if draft_engine else 0,
-                               draft=draft_engine, recurrent=recurrent)
+        engine = ServingCompiled(model, machine, mesh, pre_model, dec_model,
+                                 pre_st, dec_st, attn, kv_spec, max_new,
+                                 kv_dtype=kv_dtype, kv_quantized=kv_quantized,
+                                 verify_model=ver_model,
+                                 spec_tokens=spec_k if draft_engine else 0,
+                                 draft=draft_engine, recurrent=recurrent)
+        # the in-place append's engagement: the pool as it lies at rest and
+        # the bytes of the state leaves a decode step is told to donate
+        compile_span.set(
+            kv_pool_shape=list(engine.kv.state[attn[0]]["k"].shape),
+            decode_state_donated_bytes=_tree_bytes(engine.kv.state))
+        return engine
 
 
 def _positionwise_head(prefill_model) -> Optional[Any]:
@@ -379,7 +395,9 @@ class ServingCompiled:
 
         self._prefill_jit = jax.jit(_prefill)
         self._prefill_first_tokens_jit = jax.jit(_prefill_first_tokens)
-        self._decode_jit = jax.jit(_decode)
+        # the state is donated (the step appends to the pools it was
+        # handed), the params never (hot-swap: in-flight work holds them)
+        self._decode_jit = jax.jit(_decode, donate_argnums=(1,))
         self._decode_fn = _decode
         self._verify_jit = None
         self._verify_fn = None
@@ -401,7 +419,7 @@ class ServingCompiled:
                     ACTIVE_KEY].astype(state[POS_KEY].dtype)
                 return outs[0], ns
 
-            self._verify_jit = jax.jit(_verify)
+            self._verify_jit = jax.jit(_verify, donate_argnums=(1,))
             self._verify_fn = _verify
         self.params: Optional[Dict[str, Any]] = None
         if tel.enabled():
@@ -532,7 +550,9 @@ class ServingCompiled:
         """Discover-and-swap: if the watch root holds a committed snapshot
         newer than the active version (and no rollback pin is set), load
         and activate it. Called by the scheduler between decode steps —
-        never while a dispatched window is in flight. Returns True iff the
+        never while a dispatched window is in flight (the serving programs
+        donate their cache state, never `params`, so a step already
+        dispatched keeps the tree it was given). Returns True iff the
         live params changed. A snapshot that fails validation or whose
         read escalates past the retry budget is rejected (counted +
         telemetry `error` event) and the engine keeps serving the current
@@ -572,7 +592,8 @@ class ServingCompiled:
         (fingerprint-validated, `run_resilient` around the read so a
         transient IO fault costs a retry) and activate it with a pointer
         flip. In-flight dispatches keep their references to the previous
-        tree — the serving jits never donate — so nothing is dropped."""
+        tree — params are never donated; cache state always is — so
+        nothing is dropped."""
         t0 = time.perf_counter()
         t0_us = tel.now_us() if tel.enabled() else 0
 
@@ -697,6 +718,9 @@ class ServingCompiled:
     def decode_step(self, params, state, input_arrays):
         """One single-token step over all slots: returns (logits
         `[slots, 1, vocab]`, new cache state with positions advanced).
+        `state` is DONATED: the step appends this token's K/V to the pools
+        it was handed, and the tree passed in is dead once this returns —
+        use the one that comes back. `params` are not donated.
         Dispatch-only from the host's view — no sync, so the scheduler can
         keep a bounded number of steps in flight. Where ops report
         counters, the step's ride in the new state under STATS_KEY, as a
@@ -714,7 +738,8 @@ class ServingCompiled:
         tokens and returns logits `[slots, K+1, vocab]` — K+1 next-token
         distributions from ONE bandwidth-amortized weight stream. The
         cache caches all K+1 entries; the scheduler rolls positions back
-        to the accepted extent afterwards."""
+        to the accepted extent afterwards. `state` is donated, as in
+        `decode_step`."""
         if self._verify_jit is None:
             raise RuntimeError("verify_step: engine compiled without a "
                                "draft (pass draft=/--serve-draft-model and "
@@ -734,6 +759,9 @@ class ServingCompiled:
             (params, draft_params, state, draft_state, last[slots,1])
                 -> (t_pred[slots,K+1], ver_in[slots,K+1],
                     new_state, new_draft_state)
+
+        `state` and `draft_state` are donated (both caches append in
+        place); neither params tree is.
 
         Per-dispatch host overhead is what kills speculation on a fast
         decode path: run unfused, a round pays K+1 program launches to
@@ -770,7 +798,7 @@ class ServingCompiled:
             t_pred = jnp.argmax(vlogits, axis=-1).astype(jnp.int32)
             return t_pred, ver_in, state, dstate
 
-        self._spec_jit = jax.jit(_spec_round)
+        self._spec_jit = jax.jit(_spec_round, donate_argnums=(2, 3))
         self._spec_src = step_inputs_fn
         return self._spec_jit
 

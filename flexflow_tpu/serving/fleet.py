@@ -195,7 +195,7 @@ class _LockedKV:
     the pools themselves are replica-private."""
 
     _DEVICE_CALLS = frozenset((
-        "push", "commit_prefill", "spill", "prefetch", "join", "adopt",
+        "push", "commit_prefill", "spill", "prefetch", "join",
         "sync_after", "export_parked", "import_parked"))
 
     def __init__(self, kv: Any, lock: threading.Lock):

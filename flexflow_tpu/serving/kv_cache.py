@@ -1,14 +1,32 @@
 """Paged, sharded KV cache for the decode program.
 
 Layout (per attention layer): one K pool and one V pool of shape
-`[pool_pages, page_size, heads, head_dim]`, where `pool_pages =
-slots * pages_per_slot + 1` — page 0 is a reserved SCRATCH page that
-inactive slots (and any out-of-range write) land in, so every decode step
-is a fixed-shape scatter/gather with no branches. The pools are sharded
-over the heads dim along the model axis the decode strategy chose for the
-attention weights (q/k/v projections write their head shard, attention
-reads it — no resharding anywhere in the cache path, the layout-derivation
-requirement of ISSUE 10).
+`[pool_pages, page_size, heads * head_dim]` (heads-major in the merged
+axis), where `pool_pages = slots * pages_per_slot + 1` — page 0 is a
+reserved SCRATCH page that inactive slots (and any out-of-range write) land
+in, so every decode step is a fixed-shape scatter/gather with no branches.
+Heads and head_dim are ONE axis at rest because of the chip's default
+layout: with a minor dimension under 128 (GPT-2's head_dim 64) a
+`[pages, page, heads, head_dim]` array is tiled with the page index in the
+lanes, scatter and gather want it row-major, and every step then relaid
+every pool there and back (96 whole-pool copies, 23 of a 36 ms step). A
+merged axis is at least 128 wide for every model here, row-major is its
+default, and nothing is relaid. Writers merge the token rows to
+`[.., heads * head_dim]` before the scatter; the decode attention reads the
+gathered pages as they lie (splitting them would relay a pool's worth
+again: it lays the query rows over the merged axis instead), the int8
+kernel splits what it gathers. The pools are sharded over the merged axis
+along the model axis the decode strategy chose for the attention weights'
+heads (a shard holds whole heads; q/k/v projections write their head shard,
+attention reads it — no resharding anywhere in the cache path, the
+layout-derivation requirement of ISSUE 10).
+
+Ownership: every program that writes the pools — the decode, verify and
+speculative-round steps, `commit_prefill` — DONATES the cache state it is
+handed and appends in place. The tree handed in is dead after the call;
+use what comes back. `self.state` is therefore always the newest tree
+(`adopt` is a pointer set, and the scheduler adopts after every dispatch):
+nothing may keep an older one. Parameters are never donated.
 
 Paging: a per-slot page table `[slots, pages_per_slot]` of int32 page ids
 maps token position t to `table[slot, t // page_size]` at offset
@@ -50,6 +68,7 @@ both tiers.
 
 from __future__ import annotations
 
+import functools
 from typing import Dict, List, Optional
 
 import jax
@@ -100,12 +119,19 @@ class KVPoolExhausted(Exception):
         self.have = have
 
 
-@jax.jit
+def merge_heads(x):
+    """`[.., heads, head_dim]` token rows as the pools hold them:
+    `[.., heads * head_dim]`, heads-major."""
+    return x.reshape(x.shape[:-2] + (x.shape[-2] * x.shape[-1],))
+
+
+@functools.partial(jax.jit, donate_argnums=(0,))
 def _commit_prefill(cache_state, kv_state, slot_ids, lengths):
     """Scatter prefilled per-head K/V (`[Bp, S, h, d]` per layer, from the
     prefill program's kv_out state) into the pools of the slots in
-    `slot_ids`. Positions >= lengths[r] (right padding) and positions past
-    the slot's allocated pages are routed to the scratch page."""
+    `slot_ids`, in place: `cache_state` is donated. Positions >= lengths[r]
+    (right padding) and positions past the slot's allocated pages are
+    routed to the scratch page."""
     new = dict(cache_state)
     pt = cache_state[PAGE_TABLE_KEY]
     for name, kv in kv_state.items():
@@ -127,26 +153,28 @@ def _commit_prefill(cache_state, kv_state, slot_ids, lengths):
             qk, ks = kv_quantize(kh)
             qv, vs = kv_quantize(vh)
             new[name] = {
-                "k": pool_k.at[pageix, off].set(qk),
-                "v": cache_state[name]["v"].at[pageix, off].set(qv),
+                "k": pool_k.at[pageix, off].set(merge_heads(qk)),
+                "v": cache_state[name]["v"].at[pageix, off].set(
+                    merge_heads(qv)),
                 "k_scale": cache_state[name]["k_scale"].at[pageix, off].set(ks),
                 "v_scale": cache_state[name]["v_scale"].at[pageix, off].set(vs),
             }
         else:
             new[name] = {
-                "k": pool_k.at[pageix, off].set(kh.astype(pool_k.dtype)),
+                "k": pool_k.at[pageix, off].set(
+                    merge_heads(kh).astype(pool_k.dtype)),
                 "v": cache_state[name]["v"].at[pageix, off].set(
-                    vh.astype(pool_k.dtype)),
+                    merge_heads(vh).astype(pool_k.dtype)),
             }
     return new
 
 
-@jax.jit
+@functools.partial(jax.jit, donate_argnums=(0,))
 def _commit_state(old_state, new_state, slot_ids, lengths):
     """Write the prefill program's recurrent state (`[Bp, ...]` per leaf)
     into the per-slot arrays of the slots in `slot_ids` that the wave
-    prefilled (`lengths > 0`); a slot that sat the wave out keeps what it
-    had."""
+    prefilled (`lengths > 0`), in place (`old_state` is donated); a slot
+    that sat the wave out keeps what it had."""
     took = lengths > 0
     out = {}
     for name, leaves in new_state.items():
@@ -181,7 +209,6 @@ class PagedKVCache:
         self.heads_axis = None
         self.quantized = bool(quantized)
         pool_pspec = PartitionSpec()
-        scale_pspec = PartitionSpec()
         if mesh is not None and heads_axis is not None:
             axes = (heads_axis,) if isinstance(heads_axis, str) \
                 else tuple(heads_axis)
@@ -190,15 +217,14 @@ class PagedKVCache:
                 deg *= mesh.shape.get(a, 1)
             if all(a in mesh.shape for a in axes) and spec.heads % deg == 0:
                 self.heads_axis = heads_axis
-                pool_pspec = PartitionSpec(None, None, heads_axis, None)
-                scale_pspec = PartitionSpec(None, None, heads_axis)
+                # a shard of the merged axis holds whole heads, so the
+                # pools and the scales' heads dim split alike
+                pool_pspec = PartitionSpec(None, None, heads_axis)
         self._pool_sharding = (NamedSharding(mesh, pool_pspec)
                                if mesh is not None else None)
-        self._scale_sharding = (NamedSharding(mesh, scale_pspec)
-                                if mesh is not None else None)
         self._repl = (NamedSharding(mesh, PartitionSpec())
                       if mesh is not None else None)
-        shape = (spec.pool_pages, spec.page_size, spec.heads, spec.head_dim)
+        shape = (spec.pool_pages, spec.page_size, spec.heads * spec.head_dim)
 
         def pool():
             z = jnp.zeros(shape, jnp.int8 if self.quantized else dtype)
@@ -208,9 +234,9 @@ class PagedKVCache:
         def scales():
             # per-(page entry, head) f32 scales, sharded like the pools'
             # heads dim so the quantized cache needs no resharding either
-            z = jnp.zeros(shape[:3], jnp.float32)
-            return (jax.device_put(z, self._scale_sharding)
-                    if self._scale_sharding is not None else z)
+            z = jnp.zeros(shape[:2] + (spec.heads,), jnp.float32)
+            return (jax.device_put(z, self._pool_sharding)
+                    if self._pool_sharding is not None else z)
 
         def layer_state():
             st = {"k": pool(), "v": pool()}
@@ -233,7 +259,7 @@ class PagedKVCache:
         self.free_pages: List[int] = list(range(1, spec.pool_pages))
         self._slot_pages: Dict[int, List[int]] = {}
         # host cold tier: per-layer pinned buffers shaped like the pools
-        # minus the page dim ([host_pages, page_size, heads, head_dim] for
+        # minus the page dim ([host_pages, page_size, heads * head_dim] for
         # values, [host_pages, page_size, heads] for quantized scales)
         self.host_pages = int(spec.host_pages)
         self._host: Dict[str, Dict[str, np.ndarray]] = {}
@@ -441,10 +467,8 @@ class PagedKVCache:
                 st = dict(self.state[n])
                 for key, leaf in st.items():
                     rows = jnp.asarray(self._host[n][key][host_ids])
-                    sh = (self._pool_sharding if leaf.ndim == 4
-                          else self._scale_sharding)
-                    if sh is not None:
-                        rows = jax.device_put(rows, sh)
+                    if self._pool_sharding is not None:
+                        rows = jax.device_put(rows, self._pool_sharding)
                     st[key] = leaf.at[idx].set(rows.astype(leaf.dtype))
                 self.state[n] = st
         row = np.zeros(self.spec.pages_per_slot, np.int32)
@@ -556,20 +580,25 @@ class PagedKVCache:
         paged = {k: v for k, v in self.state.items()
                  if k not in self.recurrent}
         fresh = {n: kv_state[n] for n in self.attn_layers}
+        # each commit consumes the leaves it is handed: what comes back is
+        # adopted before the next one reads `self.state`
         with tel.span("serve/prefill/commit_kv", cat="serve",
                       bytes=_tree_bytes(fresh)):
-            state = _commit_prefill(paged, fresh, slot_ids, lengths)
+            self.state = {**self.state,
+                          **_commit_prefill(paged, fresh, slot_ids, lengths)}
         if self.recurrent:
             fresh = {n: kv_state[n] for n in self.recurrent}
             with tel.span("serve/prefill/commit_state", cat="serve",
                           bytes=_tree_bytes(fresh)):
-                state.update(_commit_state(
+                self.state.update(_commit_state(
                     {n: self.state[n] for n in self.recurrent}, fresh,
                     slot_ids, lengths))
-        self.state = state
 
     def adopt(self, new_state) -> None:
-        """Take ownership of the state returned by a decode step."""
+        """Take ownership of the state returned by a decode step (a pointer
+        set). The step consumed the tree it was handed, so whoever
+        dispatches one adopts what came back before anything else reads
+        `self.state`."""
         self.state = new_state
 
     def device_bytes(self) -> int:

@@ -764,7 +764,7 @@ class ContinuousBatchingScheduler:
         rem = min(r.max_new_tokens - len(r.tokens) for r in active.values())
         return max(1, min(self.dispatch_ahead, rem))
 
-    def _materialize(self, window_toks: List[Any], state,
+    def _materialize(self, window_toks: List[Any],
                      active: Dict[int, Request], window_t0: float
                      ) -> np.ndarray:
         """Drain a dispatched window: one host sync pulls every step's
@@ -784,17 +784,18 @@ class ContinuousBatchingScheduler:
         self.materializations += 1
         with tel.span("serve/decode/commit", cat="serve",
                       window=window) as sp:
-            seed, committed = self._commit_window(mats, state, active,
+            seed, committed = self._commit_window(mats, active,
                                                   window_t0, t_now)
             sp.set(tokens_committed=committed)
         return seed
 
-    def _commit_window(self, mats: List[np.ndarray], state,
+    def _commit_window(self, mats: List[np.ndarray],
                        active: Dict[int, Request], window_t0: float,
                        t_now: float):
         """The host side of a drained window: extend token lists, advance
-        the KV mirrors, finish and evict. Returns (next seed, tokens
-        committed)."""
+        the KV mirrors, finish and evict. The cache state needs no hand-over
+        here: `self.kv.state` is the newest tree after every dispatch.
+        Returns (next seed, tokens committed)."""
         steps = len(mats)
         per_step = (t_now - window_t0) / steps
         self.step_times.extend([per_step] * steps)
@@ -818,7 +819,6 @@ class ContinuousBatchingScheduler:
                 list(active.values()), t_now - self._t0, steps, per_step,
                 {slot: int(adv[slot]) for slot in active},
                 window=self.materializations)
-        self.kv.adopt(state)
         self.kv.sync_after(steps, advances=adv)
         for slot in finished:
             self._finish(active.pop(slot), self._now())
@@ -845,43 +845,63 @@ class ContinuousBatchingScheduler:
 
         Device work and materializations all happen before any host
         mutation, so a retried round (transient decode fault) replays
-        cleanly off the unchanged host mirrors."""
+        cleanly off the unchanged host mirrors. Every launch donates the
+        cache state it is handed, so what it returns is adopted at once
+        (counters popped: plain decode alone reads them); a round that
+        fails after a launch rolls the device positions back to the
+        committed extent, as acceptance does, before the fault surfaces —
+        the replay then rewrites the entries past it."""
         K = self.spec_tokens
         t0 = time.perf_counter()
+
+        def adopt(kv, state):
+            state.pop(STATS_KEY, None)
+            kv.adopt(state)
+
         with self.exec_lock:
-            dstate = self.draft.kv.state
-            tstate = self.kv.state
             last = jnp.asarray(next_host)
-            if self._spec_fused is not None:
-                # the whole round is ONE program launch (see
-                # engine.build_spec_program) — the draft chain's argmax
-                # feedback never leaves the device
-                t_pred_dev, ver_in, tstate, dstate = \
-                    self.engine.spec_round_step(
-                        self.params, self.draft.params, tstate, dstate,
-                        last, self.step_inputs_fn)
-            else:
-                # unfused fallback (untraceable step_inputs_fn): K+1
-                # launches
-                cur = last
-                drafts = []
-                for _ in range(K):
-                    dlogits, dstate = self.draft.decode_step(
-                        self.draft.params, dstate,
-                        self.step_inputs_fn(cur, dstate))
-                    cur = jnp.argmax(dlogits[:, -1, :], axis=-1).astype(
-                        jnp.int32)[:, None]
-                    drafts.append(cur)
-                ver_in = jnp.concatenate([last] + drafts, axis=1)
-                vlogits, tstate = self.engine.verify_step(
-                    self.params, tstate, self.step_inputs_fn(ver_in, tstate))
-                t_pred_dev = jnp.argmax(vlogits, axis=-1).astype(jnp.int32)
-            for s in (tstate, dstate):
-                s.pop(STATS_KEY, None)   # counters: read by plain decode only
-            t_pred = np.asarray(t_pred_dev)
-            drafted = np.asarray(ver_in)[:, 1:]              # [slots, K]
-            if self._exec_serialized:
-                jax.block_until_ready((tstate, dstate))
+            try:
+                if self._spec_fused is not None:
+                    # the whole round is ONE program launch (see
+                    # engine.build_spec_program) — the draft chain's argmax
+                    # feedback never leaves the device
+                    t_pred_dev, ver_in, tstate, dstate = \
+                        self.engine.spec_round_step(
+                            self.params, self.draft.params, self.kv.state,
+                            self.draft.kv.state, last, self.step_inputs_fn)
+                    adopt(self.kv, tstate)
+                    adopt(self.draft.kv, dstate)
+                else:
+                    # unfused fallback (untraceable step_inputs_fn): K+1
+                    # launches
+                    cur = last
+                    drafts = []
+                    for _ in range(K):
+                        dstate = self.draft.kv.state
+                        dlogits, dstate = self.draft.decode_step(
+                            self.draft.params, dstate,
+                            self.step_inputs_fn(cur, dstate))
+                        adopt(self.draft.kv, dstate)
+                        cur = jnp.argmax(dlogits[:, -1, :], axis=-1).astype(
+                            jnp.int32)[:, None]
+                        drafts.append(cur)
+                    ver_in = jnp.concatenate([last] + drafts, axis=1)
+                    tstate = self.kv.state
+                    vlogits, tstate = self.engine.verify_step(
+                        self.params, tstate,
+                        self.step_inputs_fn(ver_in, tstate))
+                    adopt(self.kv, tstate)
+                    t_pred_dev = jnp.argmax(vlogits, axis=-1).astype(
+                        jnp.int32)
+                t_pred = np.asarray(t_pred_dev)
+                drafted = np.asarray(ver_in)[:, 1:]          # [slots, K]
+                if self._exec_serialized:
+                    jax.block_until_ready((self.kv.state,
+                                           self.draft.kv.state))
+            except Exception:
+                for kv in (self.kv, self.draft.kv):
+                    kv.push()
+                raise
         wall = time.perf_counter() - t0
         self.materializations += 1
         t_end_off = (t0 + wall) - self._t0
@@ -915,8 +935,7 @@ class ContinuousBatchingScheduler:
                 self.tracer.on_spec_round(
                     req, t_end_off, drafted=K, committed=ncommit,
                     rejected=K - min(j, K))
-        for kv, st in ((self.kv, tstate), (self.draft.kv, dstate)):
-            kv.adopt(st)
+        for kv in (self.kv, self.draft.kv):
             kv.sync_after(0, advances=adv)
             kv.push()  # re-publish the COMMITTED extent: the device-side
             #            speculative advance (K for draft, K+1 for the
@@ -967,7 +986,9 @@ class ContinuousBatchingScheduler:
         waiting: List[Request] = []
         active: Dict[int, Request] = {}
         next_host = np.zeros((self.slots, 1), np.int32)
-        state = self.kv.state
+        # the cache state is never held here: every program that writes the
+        # pools donates the tree it is handed, and `self.kv.state` is the
+        # newest one after every dispatch and every commit
         next_dev = jnp.asarray(next_host)
         window_toks: List[Any] = []  # dispatched, unmaterialized [slots,1]
         window_t0 = time.perf_counter()
@@ -998,10 +1019,8 @@ class ContinuousBatchingScheduler:
             if want_sync and window_toks:
                 # materialize the dispatched window: one host sync drains
                 # every step's tokens (tiny [slots,1] arrays)
-                next_host = self._materialize(window_toks, state, active,
-                                              window_t0)
+                next_host = self._materialize(window_toks, active, window_t0)
                 window_toks = []
-                state = self.kv.state
                 window_t0 = time.perf_counter()
             if not window_toks and (self.control is not None
                                     or self.engine.watching):
@@ -1014,7 +1033,6 @@ class ContinuousBatchingScheduler:
                 if swapped:
                     self.params = self.engine.params
                     self.stats["swaps"] += 1
-                    state = self.kv.state
                     if self.tracer is not None:
                         # the swap landed between windows: mark it inside
                         # every in-flight request's timeline
@@ -1035,22 +1053,19 @@ class ContinuousBatchingScheduler:
                 self._rotate(active, next_host, self._now())
             if waiting and self.kv.free_slots():
                 if self._admit(waiting, active, next_host, self._now()):
-                    state = self.kv.state
                     next_dev = jnp.asarray(next_host)
                     window_t0 = time.perf_counter()
             if self.handoff is not None and active and not window_toks:
                 # prefill replica: everything admitted leaves for the
                 # decode pool right after its TTFT materialization
                 self._handoff_all(active)
-                state = self.kv.state
             if self.tiered and not window_toks:
-                # rotation/spill mutate device state outside _admit's
-                # refresh; re-anchor at drained-window points only — with
-                # steps in flight the local `state` is AHEAD of the pool
-                # mirror, and resetting to it would re-dispatch the last
-                # materialized token (untiered runs keep the exact pre-PR
-                # dispatch sequence)
-                state = self.kv.state
+                # rotation/spill change which slots decode outside _admit's
+                # refresh; re-seed at drained-window points only — with
+                # steps in flight `next_host` is BEHIND the device, and
+                # resetting to it would re-dispatch the last materialized
+                # token (untiered runs keep the exact pre-PR dispatch
+                # sequence)
                 next_dev = jnp.asarray(next_host)
             if not active:
                 if queue and not waiting:
@@ -1081,13 +1096,21 @@ class ContinuousBatchingScheduler:
                 except Exception as e:  # noqa: BLE001 — permanent fault
                     if active:
                         self._evict_wedged(active, "failed", self._now(), e)
-                state = self.kv.state
                 next_dev = jnp.asarray(next_host)
                 continue
             with tel.span("serve/decode/dispatch", cat="serve",
                           window=self.materializations + 1):
+                state = self.kv.state
                 inputs = self.step_inputs_fn(next_dev, state)
                 try:
+                    # the step DONATES `s` and a retry re-calls with the
+                    # same `s`. What a retry may assume: a dispatch that
+                    # raised before it was enqueued (every injected fault
+                    # fires ahead of fn; a refused launch) has not consumed
+                    # its input, and replays identical work. One that
+                    # consumed `s` and then raised cannot be retried with
+                    # it: the replay fails on the deleted buffers, the
+                    # budget runs out and the fault is permanent.
                     logits, state = run_resilient(
                         "serve/decode_step",
                         lambda s=state, ins=inputs:
@@ -1095,17 +1118,26 @@ class ContinuousBatchingScheduler:
                         policy=self.retry_policy)
                 except Exception as e:  # noqa: BLE001 — permanent fault
                     # drain what WAS dispatched successfully, then evict
-                    # the wedged slot; every other slot keeps serving
+                    # the wedged slot; every other slot keeps serving.
+                    # Both go through `self.kv.state`, the tree the last
+                    # successful dispatch returned
                     if window_toks:
                         next_host = self._materialize(
-                            window_toks, state, active, window_t0)
+                            window_toks, active, window_t0)
                         window_toks = []
                     if active:
                         self._evict_wedged(active, "failed", self._now(), e)
-                    state = self.kv.state
                     next_dev = jnp.asarray(next_host)
                     window_t0 = time.perf_counter()
                     continue
+                stats = state.pop(STATS_KEY, None)
+                if stats:
+                    self._window_stats.append(stats)
+                # the tree that went in is dead (donated): the pool holds
+                # the newest one after every dispatch, window in flight or
+                # not, so pushes, admissions, rotations and the fault path
+                # never touch a consumed buffer
+                self.kv.adopt(state)
                 with self.exec_lock:
                     # the argmax over model-sharded logits is its own
                     # collective program; under a fleet it must not
@@ -1117,9 +1149,6 @@ class ContinuousBatchingScheduler:
                     if self._exec_serialized:
                         jax.block_until_ready(next_dev)
                 window_toks.append(next_dev)
-                stats = state.pop(STATS_KEY, None)
-                if stats:
-                    self._window_stats.append(stats)
                 self.decode_steps += 1
         if self.tiered:
             # final tier ledger: counters into telemetry (monitor/prom) and

@@ -18,6 +18,7 @@ process (the xdist workers each import every test file).
 """
 
 import os
+import re
 import sys
 
 import jax
@@ -333,15 +334,9 @@ def test_remat_shrinks_the_compiled_steps_temp_memory(described_devices):
     assert 0 < temp[True] < temp[False], temp
 
 
-def test_granite_serving_programs_fit_one_chip(described_devices, mosaic,
-                                               one_chip, monkeypatch):
-    """`granite-4.0-h-small.serve-chat`'s two programs at the cell's own
-    sizes (16 slots, width 1024, 9.93 GB of bf16 weights), through the
-    normal entry points: the chip's compiler must hold the prefill wave
-    beside the weights, the state and the cache (its first compile ran
-    1 GB over the chip: a `[tokens, k, d]` f32 combine and every mixer's
-    xBC kept live to the program's end), and the grouped product and the
-    flash kernel must be what it lowers to."""
+def _described_engine(cell_name, described_devices, monkeypatch, one_chip):
+    """A benchmark cell's serving engine through the normal entry points on
+    one described chip, with its parameters and cache state as shapes."""
     sys.path.insert(0, os.path.join(ROOT, "benchmarks"))
     from families import family_of
     from harness import manifest as mf
@@ -354,7 +349,7 @@ def test_granite_serving_programs_fit_one_chip(described_devices, mosaic,
     described_devices(1)
     # a described device holds no array: the state manager's zeros stay put
     monkeypatch.setattr(jax, "device_put", lambda x, *a, **k: x)
-    cell = mf.load_cell(mf.load_manifest(), "granite-4.0-h-small.serve-chat")
+    cell = mf.load_cell(mf.load_manifest(), cell_name)
     slots = cell.system["max_batch_slots"]
     model = FFModel(FFConfig(batch_size=slots, seed=3, strategy_cache=False,
                              log_level="warning", **cell.system["ffconfig"]))
@@ -366,21 +361,41 @@ def test_granite_serving_programs_fit_one_chip(described_devices, mosaic,
     def sds(x):
         return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
 
-    def i32(*shape):
-        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
-
     init = build_init_fn(topo_order(eng.decode_model.layers),
                          model._initializer_overrides)
     params = jax.tree_util.tree_map(
         sds, jax.eval_shape(init, jax.random.PRNGKey(0)))
     state = jax.tree_util.tree_map(sds, eng.kv.state)
+    return eng, g, params, state
+
+
+def _i32(one_chip, *shape):
+    return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+
+def test_granite_serving_programs_fit_one_chip(described_devices, mosaic,
+                                               one_chip, monkeypatch):
+    """`granite-4.0-h-small.serve-chat`'s two programs at the cell's own
+    sizes (16 slots, width 1024, 9.93 GB of bf16 weights), through the
+    normal entry points: the chip's compiler must hold the prefill wave
+    beside the weights, the state and the cache (its first compile ran
+    1 GB over the chip: a `[tokens, k, d]` f32 combine and every mixer's
+    xBC kept live to the program's end), and the grouped product and the
+    flash kernel must be what it lowers to. The decode step appends to the
+    pools it was handed: no whole-pool copy, every pool aliased."""
+    eng, g, params, state = _described_engine(
+        "granite-4.0-h-small.serve-chat", described_devices, monkeypatch,
+        one_chip)
+    slots = eng.slots
     held = sum(x.size * x.dtype.itemsize
                for x in jax.tree_util.tree_leaves(state))
     assert 0.65e9 < held < 0.75e9
     decode = eng._decode_jit.lower(
-        params, state, [i32(slots, 1), i32(slots, 1)]).compile()
+        params, state,
+        [_i32(one_chip, slots, 1), _i32(one_chip, slots, 1)]).compile()
     prefill = eng._prefill_first_tokens_jit.lower(
-        params, [i32(slots, g.seq), i32(slots, g.seq)], i32(slots)).compile()
+        params, [_i32(one_chip, slots, g.seq), _i32(one_chip, slots, g.seq)],
+        _i32(one_chip, slots)).compile()
     chip = 15.75e9          # what the compiler has of a v5e chip's 16 GB
     for program, beside in ((decode, 0), (prefill, held)):
         m = program.memory_analysis()
@@ -392,3 +407,83 @@ def test_granite_serving_programs_fit_one_chip(described_devices, mosaic,
     text = prefill.as_text()
     assert "ragged-dot" in text and "tpu_custom_call" in text
     assert "ragged-dot" in decode.as_text()
+    _assert_appends_in_place(decode, eng)
+
+
+def _entry_ops(text):
+    """(op name, result type) of every instruction of optimized HLO's
+    entry computation."""
+    lines = text.splitlines()
+    start = next(i for i, l in enumerate(lines) if l.startswith("ENTRY "))
+    ops = []
+    for line in lines[start + 1:]:
+        if line.startswith("}"):
+            break
+        m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = (.*?) ([a-z][\w\-]*)\(", line)
+        if m:
+            ops.append((m.group(2), m.group(1)))
+    return ops
+
+
+def _assert_appends_in_place(program, eng):
+    """No op of the program's entry computation copies or relays an array
+    of a pool's size (`copy`, `copy-start`, `reshape`, `transpose`: the
+    pools themselves, and the gathered context, which is as large), and
+    the compiler aliases every pool leaf it was told is donated onto an
+    output. One thing is let through: where the pools are few (granite's
+    two) the compiler stages them through its alternate memory (`S(1)`;
+    four slices in, the scatter and the gather there, one `copy-start` back
+    onto the aliased buffer) — its own placement, as on the parent, no
+    relayout and no fresh buffer."""
+    pools = [eng.kv.state[n][key] for n in eng.attn_layers
+             for key in ("k", "v")]
+    size = pools[0].size         # slots * pages_per_slot + 1 pages: both
+    moved, staged = [], 0
+    for op, ty in _entry_ops(program.as_text()):
+        shape = re.search(r"\[([\d,]+)\]", ty)
+        if op not in ("copy", "copy-start", "reshape", "transpose") \
+                or shape is None or not 0.9 * size <= np.prod(
+                    [int(d) for d in shape.group(1).split(",")]) <= size:
+            continue
+        layouts = re.findall(r"\{[^}]*\}", ty)     # copy-start: dest, source
+        if op == "copy-start" and "S(1)" in layouts[1] \
+                and "S(1)" not in layouts[0]:
+            staged += 1
+        else:
+            moved.append((op, ty))
+    assert not moved, (len(moved), moved[:4])
+    assert staged <= 2, staged
+    held = sum(leaf.size * leaf.dtype.itemsize for n in eng.attn_layers
+               for leaf in eng.kv.state[n].values())
+    assert program.memory_analysis().alias_size_in_bytes >= held
+
+
+def test_gpt2_medium_decode_and_commit_append_in_place(described_devices,
+                                                       one_chip, monkeypatch):
+    """`gpt2-medium.serve-chat`'s decode step and prefill commit at the
+    cell's geometry (16 slots, `max_decode_len` 256, page 16, bf16). With
+    head_dim 64 in the minor dimension the chip's default layout of a
+    `[pages, page, h, d]` pool puts the page index in the lanes, and every
+    step relaid every pool for the scatter and back for the output: 2
+    copies x 2 pools x 24 layers of 42 MB each, and nothing aliased. The
+    pools are `[pages, page, h * d]` at rest, the state is donated and the
+    gathered context is read as it lies (split into heads of 64 it was
+    relaid again, 48 `reshape` a step), so the only whole-pool op left is
+    the page gather."""
+    from flexflow_tpu.serving import kv_cache
+
+    eng, g, params, state = _described_engine(
+        "gpt2-medium.serve-chat", described_devices, monkeypatch, one_chip)
+    slots = eng.slots
+    decode = eng._decode_jit.lower(
+        params, state,
+        [_i32(one_chip, slots, 1), _i32(one_chip, slots, 1)]).compile()
+    _assert_appends_in_place(decode, eng)
+    spec = eng.kv_spec
+    fresh = {n: {key: jax.ShapeDtypeStruct(
+                     (slots, g.seq, spec.heads, spec.head_dim),
+                     jnp.bfloat16, sharding=one_chip) for key in ("k", "v")}
+             for n in eng.attn_layers}
+    commit = kv_cache._commit_prefill.lower(
+        state, fresh, _i32(one_chip, slots), _i32(one_chip, slots)).compile()
+    _assert_appends_in_place(commit, eng)

@@ -109,7 +109,10 @@ def test_kv_pools_sharded_on_model_axis(gpt2_serve):
     assert eng.kv_shard_degree > 1
     k = eng.kv.state[eng.attn_layers[0]]["k"]
     shard0 = k.addressable_shards[0].data
-    assert shard0.shape[2] * eng.kv_shard_degree == eng.kv_spec.heads
+    # heads and head_dim are one axis at rest: a shard holds whole heads
+    assert shard0.shape[2] * eng.kv_shard_degree \
+        == eng.kv_spec.heads * eng.kv_spec.head_dim
+    assert shard0.shape[2] % eng.kv_spec.head_dim == 0
 
 
 # ----------------------------------------------------------- decode parity
@@ -204,13 +207,15 @@ def test_inference_determinism(gpt2_serve, rng):
     a, _ = eng.prefill(eng.params, gpt2_prompt_inputs(ids, lengths))
     b, _ = eng.prefill(eng.params, gpt2_prompt_inputs(ids, lengths))
     assert (np.asarray(a) == np.asarray(b)).all()
-    state = eng.kv.state
     step = np.ones((slots, 1), np.int32)
-    s1, _ = eng.decode_step(eng.params, state,
-                            gpt2_step_inputs(jnp.asarray(step), state))
-    s2, _ = eng.decode_step(eng.params, state,
-                            gpt2_step_inputs(jnp.asarray(step), state))
-    assert (np.asarray(s1) == np.asarray(s2)).all()
+
+    def one_step():
+        # a step consumes the state it is handed: each pass gets a copy
+        state = jax.tree_util.tree_map(jnp.copy, eng.kv.state)
+        return eng.decode_step(eng.params, state,
+                               gpt2_step_inputs(jnp.asarray(step), state))[0]
+
+    assert (np.asarray(one_step()) == np.asarray(one_step())).all()
 
 
 # ------------------------------------------- first tokens taken on the device
@@ -356,6 +361,44 @@ def test_scheduler_continuous_batching(gpt2_serve, rng):
     assert sched.prefills >= 2  # continuous batching: a second wave joined
     assert len(eng.kv.free_slots()) == eng.slots
     assert len(eng.kv.free_pages) == eng.kv_spec.pool_pages - 1
+
+
+# what the parent of ISSUE 29 served for this request set, with 4-D pools
+# (`[pages, page, heads, head_dim]`) and nothing donated: pinned once
+PARENT_TOKENS = {
+    0: [159, 177, 170, 13, 170, 179], 1: [178, 24, 130, 198, 198, 41],
+    2: [29, 13, 25, 198, 67, 83], 3: [13, 25, 198, 156, 83, 61],
+    4: [61, 198, 36, 173, 61, 253], 5: [198, 87, 182, 96, 131, 126]}
+
+
+def test_dense_donated_pools_serve_the_parents_tokens(gpt2_serve):
+    """Heads and head_dim merged at rest and the state donated to every
+    step: the same values land in the same (page, offset, head) cells, so
+    the served tokens are the 4-D un-donated layout's, bit for bit. And
+    with a window in flight the scheduler never holds a consumed tree:
+    what it hands each dispatch is `kv.state`, alive in every leaf."""
+    eng, gc = gpt2_serve
+    assert eng.kv.state[eng.attn_layers[0]]["k"].shape[2:] \
+        == (eng.kv_spec.heads * eng.kv_spec.head_dim,)
+    rng = np.random.default_rng(29)
+    reqs = [Request(rid=i, prompt=list(rng.integers(1, gc.vocab, size=3 + i)),
+                    max_new_tokens=6, arrival_s=0.0) for i in range(6)]
+    handed = []
+
+    def step_inputs(tokens, state):
+        handed.append(state is eng.kv.state and not any(
+            x.is_deleted() for x in jax.tree_util.tree_leaves(eng.kv.state)))
+        return gpt2_step_inputs(tokens, state)
+
+    sched = ContinuousBatchingScheduler(eng, eng.params, gpt2_prompt_inputs,
+                                        step_inputs, eos_id=None,
+                                        dispatch_ahead=4)
+    done = sched.run(reqs)
+    assert {r.rid: list(r.tokens) for r in done} == PARENT_TOKENS
+    assert len(handed) == sched.decode_steps and all(handed)
+    assert sched.materializations < sched.decode_steps   # windows of > 1 step
+    assert not any(x.is_deleted()
+                   for x in jax.tree_util.tree_leaves(eng.kv.state))
 
 
 def test_scheduler_eos_eviction(gpt2_serve, rng):
@@ -511,3 +554,36 @@ def test_serve_telemetry_stream(gpt2_serve, rng, tmp_path):
         txt = f.read()
     assert "flexflow_serve_tokens_per_second" in txt
     assert "flexflow_serve_ttft_p99_seconds" in txt
+
+
+def test_compile_serving_span_says_how_the_step_appends(devices, tmp_path,
+                                                        capsys):
+    """The in-place append's engagement counters: `serve/compile_serving`
+    carries the pool's shape at rest and the bytes of the state leaves a
+    decode step is told to donate, and trace_report prints them with the
+    span's other args."""
+    import trace_report
+
+    from flexflow_tpu import telemetry as tel
+
+    tdir = str(tmp_path / "tel")
+    model = FFModel(_serve_cfg(telemetry_dir=tdir, search_budget=0,
+                               only_data_parallel=True))
+    build_gpt2(model, _gpt2_cfg(), batch=8)
+    try:
+        eng = compile_serving(model)
+    finally:
+        tel.shutdown()
+    spec = eng.kv_spec
+    shape = [spec.pool_pages, spec.page_size, spec.heads * spec.head_dim]
+    donated = sum(int(x.nbytes)
+                  for x in jax.tree_util.tree_leaves(eng.kv.state))
+    made = [sp.args for sp in tel.ring_spans()
+            if sp.name == "serve/compile_serving"][-1]
+    assert made["kv_pool_shape"] == shape
+    assert made["decode_state_donated_bytes"] == donated \
+        >= 2 * spec.layers * int(np.prod(shape)) * 4
+    trace_report.render(tdir, out_path=None)
+    out = capsys.readouterr().out
+    assert f"decode_state_donated_bytes={donated}" in out
+    assert f"kv_pool_shape={shape}" in out

@@ -17,6 +17,7 @@ synthetic event stream (pure `gather`)."""
 import os
 import sys
 
+import jax
 import numpy as np
 import pytest
 
@@ -66,8 +67,8 @@ def serve_env(devices, tmp_path_factory):
     return eng, gc, cm, root
 
 
-def _snapshot(cm, root, step):
-    cm.init(seed=step)
+def _snapshot(cm, root, step, seed=None):
+    cm.init(seed=step if seed is None else seed)
     cm._iteration = step
     return save_durable(cm, root, block=True)
 
@@ -350,6 +351,179 @@ def test_swap_rejects_mismatched_snapshot(serve_env, tmp_path):
         assert eng.health_report()["serving"]["rejected"] == rej0 + 1
     finally:
         eng._watch_root = None
+
+
+# ------------------------------------------- donated cache state (ISSUE 29)
+def _alive(kv):
+    return not any(x.is_deleted()
+                   for x in jax.tree_util.tree_leaves(kv.state))
+
+
+def _serve_watching_state(eng, gc, during=None, host_inputs=False):
+    """Serve six requests through four slots with windows of up to 4 steps
+    in flight. Every program that writes the pools donates the state it is
+    handed, so at every dispatch (a fused speculative round: after every
+    round) the caches must hold the newest trees, alive in every leaf.
+    `host_inputs` makes the step inputs untraceable, which sends a
+    speculative engine down its unfused rounds. Returns (tokens by rid,
+    sched, the checks made)."""
+    alive = []
+    kvs = [eng.kv] + ([eng.draft.kv] if eng.draft is not None else [])
+
+    def step_inputs(tokens, state):
+        if host_inputs:
+            tokens = jax.numpy.asarray(np.asarray(tokens))
+        alive.append(any(state is kv.state for kv in kvs)
+                     and all(_alive(kv) for kv in kvs))
+        if during is not None:
+            during(len(alive))
+        return gpt2_step_inputs(tokens, state)
+
+    fused = eng.draft is not None and not host_inputs
+    sched = ContinuousBatchingScheduler(
+        eng, eng.params, gpt2_prompt_inputs,
+        gpt2_step_inputs if fused else step_inputs, eos_id=None,
+        dispatch_ahead=4,
+        retry_policy=RetryPolicy(attempts=3, base_delay=0.001, seed=3))
+    if fused:   # the round traces its step inputs: watch the rounds
+        one_round = sched._spec_round
+
+        def watched_round(active, next_host):
+            out = one_round(active, next_host)
+            alive.append(all(_alive(kv) for kv in kvs))
+            return out
+        sched._spec_round = watched_round
+    done = sched.run(_reqs(6, gc, max_new=6))
+    alive.append(all(_alive(kv) for kv in kvs))
+    return {r.rid: list(r.tokens) for r in done}, sched, alive
+
+
+def _small_engine(draft=None, **cfg_kw):
+    cfg = FFConfig(search_budget=16, mesh_shape=dict(MESH),
+                   log_level="warning", max_batch_slots=4, kv_page_size=4,
+                   strategy_cache=False, **cfg_kw)
+    m = FFModel(cfg)
+    build_gpt2(m, _gpt2_cfg(), batch=8)
+    if draft is None:
+        return compile_serving(m, max_decode_len=6)
+    dm = FFModel(cfg)
+    build_gpt2(dm, draft, batch=8)
+    return compile_serving(m, max_decode_len=6, draft=dm, spec_tokens=2)
+
+
+def _fault_mid_window(eng, gc, cm, root):
+    """A transient dispatch fault with steps of the window in flight: the
+    retry replays the same dispatch (an injected fault fires ahead of it,
+    so its state was not consumed)."""
+    want = _serve_watching_state(eng, gc)[0]
+    faults.configure("serve/decode_step@2")
+    try:
+        got, _, alive = _serve_watching_state(eng, gc)
+        assert dict(faults.fired()).get("serve/decode_step") == 1
+    finally:
+        faults.clear()
+    return want, got, alive
+
+
+def _spec_round_with_rollback(eng, gc, cm, root, fused=True):
+    """A draft that is nearly always wrong: every round rolls both caches
+    back to the committed extent (POS_KEY re-published, the state kept)."""
+    want = _serve_watching_state(eng, gc)[0]
+    spec = _small_engine(draft=GPT2Config(vocab=256, seq=16, d_model=16,
+                                          heads=2, layers=1, dropout=0.0))
+    spec.load_params(eng.params)
+    spec.draft.init(seed=7)
+    got, sched, alive = _serve_watching_state(spec, gc,
+                                              host_inputs=not fused)
+    assert (sched._spec_fused is not None) == fused
+    assert 0 < sched.stats["spec_rounds"]
+    assert sched.stats["spec_accepted_tokens"] \
+        < sched.stats["spec_drafted_tokens"]
+    return want, got, alive
+
+
+def _spec_round_unfused(eng, gc, cm, root):
+    """The same through K + 1 launches a round, each adopted at once."""
+    return _spec_round_with_rollback(eng, gc, cm, root, fused=False)
+
+
+def _spill_prefetch_join(eng, gc, cm, root):
+    """Half the pages in the host tier: slots park (spill), come back
+    (prefetch writes the pools outside any step) and rejoin."""
+    want = _serve_watching_state(eng, gc)[0]
+    tier = _small_engine(kv_host_pages=12, kv_prefetch_ahead=2)
+    tier.load_params(eng.params)
+    got, sched, alive = _serve_watching_state(tier, gc)
+    ts = sched.kv.tier_stats()
+    assert 0 < ts["kv_spills"] == ts["kv_refills"]
+    return want, got, alive
+
+
+def _hot_swap_between_windows(eng, gc, cm, root):
+    """A snapshot of the weights being served lands mid-run: the swap
+    happens at the next drained window; the params (never donated) change
+    hands, the cache state (always donated) stays the one the last step
+    returned."""
+    step = (eng.active_version or 0) + 1
+    root = os.path.join(root, "same_weights")   # the module's root has others
+    try:
+        _snapshot(cm, root, step, seed=29)
+        eng.watch(root, poll_interval_s=0.0)
+        assert eng.poll_swap(force=True)
+        eng._watch_root = None                  # the clean run: no swap
+        want = _serve_watching_state(eng, gc)[0]
+        eng.watch(root, poll_interval_s=0.0)
+
+        def during(dispatches):
+            if dispatches == 3:                 # the same weights, newer
+                _snapshot(cm, root, step + 1, seed=29)
+        got, sched, alive = _serve_watching_state(eng, gc, during=during)
+        assert sched.stats["swaps"] == 1 and eng.active_version == step + 1
+    finally:
+        eng.unpin()
+        eng._watch_root = None
+    return want, got, alive
+
+
+def _spec_fault_after_a_launch(eng, gc, cm, root):
+    """A draft that is always right (the target's own weights), and the
+    verify launch of the second round fails after the draft's K launches
+    consumed and advanced the draft cache: the round rolls the device
+    positions back before the fault surfaces, so the retry drafts from the
+    committed extent and is accepted as a clean run's round is."""
+    want = _serve_watching_state(eng, gc)[0]
+    spec = _small_engine(draft=_gpt2_cfg())
+    spec.load_params(eng.params)
+    spec.draft.load_params(eng.params)
+    clean = _serve_watching_state(spec, gc, host_inputs=True)[1]
+    verify, calls = spec.verify_step, []
+
+    def flaky(*a, **kw):
+        calls.append(1)
+        if len(calls) == 2:
+            raise RuntimeError("injected: verify launch refused")
+        return verify(*a, **kw)
+    spec.verify_step = flaky
+    got, sched, alive = _serve_watching_state(spec, gc, host_inputs=True)
+    assert sched._spec_fused is None and len(calls) > 2
+    assert sched.stats["spec_accepted_tokens"] \
+        == clean.stats["spec_accepted_tokens"] > 0
+    return want, got, alive
+
+
+@pytest.mark.parametrize("case", [
+    _fault_mid_window, _spec_round_with_rollback, _spec_round_unfused,
+    _spec_fault_after_a_launch,
+    _spill_prefetch_join, _hot_swap_between_windows],
+    ids=lambda f: f.__name__.lstrip("_"))
+def test_donated_state_serves_the_same_tokens(serve_env, case):
+    """The decode, verify and speculative-round steps and the prefill
+    commit consume the cache state they are handed. None of what goes on
+    around a window may meet a consumed tree, and each serves the tokens
+    of a run without it."""
+    want, got, alive = case(*serve_env)
+    assert len(want) == 6 and got == want
+    assert len(alive) > 6 and all(alive)
 
 
 # ---------------------------------------------------------- observability

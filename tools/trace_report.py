@@ -295,6 +295,12 @@ def render(path: str, out_path: Optional[str] = None, top: int = 0,
             print(f"[ops] drift top-K: worst={d.get('worst')} "
                   f"explains(top-k)={100 * (d.get('explained') or 0):.0f}% "
                   "of the per-op misprediction")
+        for ev in events:
+            # what compile_serving built: layers with state, experts held,
+            # the pool as it lies at rest, the bytes a step donates
+            if ev.get("name") == "serve/compile_serving" and ev.get("args"):
+                print("[serve] compile_serving: " + " ".join(
+                    f"{k}={v}" for k, v in sorted(ev["args"].items())))
         for ev in errors:
             print(f"[error] {ev['name']}: {ev.get('args', {})}")
     return {"events": events, "summary": rows, "chrome": chrome,
