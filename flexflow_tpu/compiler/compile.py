@@ -965,8 +965,8 @@ class CompiledModel:
 
         `self.step_stats` counts dispatches / host_syncs / barriers /
         fused_steps for the whole fit; each epoch's history entry carries
-        its own dispatches/host_syncs (tools/bench_step.py --check asserts
-        dispatches <= ceil(num_batches/K) and zero mid-epoch host syncs in
+        its own dispatches/host_syncs (tests/test_step_pipeline.py asserts
+        ceil(num_batches/K) dispatches and zero mid-epoch host syncs in
         the default config).
 
         `res` (runtime/resilience.FitResilience, None = off) adds durable
@@ -1288,7 +1288,7 @@ class CompiledModel:
         model PREDICTS for this compile's strategy + optimizer (params +
         grads + moments under the OptMemSpec accounting, ZeRO divisor
         included) next to what the live buffers ACTUALLY hold (summed
-        addressable-shard bytes on device 0). tools/bench_zero.py asserts
+        addressable-shard bytes on device 0). tests/test_zero.py asserts
         the two agree on the ~data-degree optimizer-state reduction."""
         from flexflow_tpu.search import cost_model as cmod
 

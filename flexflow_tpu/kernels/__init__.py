@@ -6,16 +6,10 @@ fused_ce — blockwise online-logsumexp sparse cross-entropy (fwd + custom
 VJP): the loss never materializes an f32 [N, vocab] array.
 fused_optim — single-pass Adam/SGD moment update, replacing the optax
 tree_map chain while keeping its exact state layout.
-collective_matmul — all-gather/matmul overlap on the model axis (ring of
-chunked matmuls via ppermute).
 dequant_attention — fused int8-dequant + decode attention over the
 quantized paged KV cache (serving --kv-cache-dtype int8).
 """
 
-from flexflow_tpu.kernels.collective_matmul import (  # noqa: F401
-    collective_matmul,
-    collective_matmul_supported,
-)
 from flexflow_tpu.kernels.dequant_attention import (  # noqa: F401
     dequant_decode_attention,
 )

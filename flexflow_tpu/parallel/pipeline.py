@@ -993,7 +993,7 @@ class PipelinedModel:
         """Per-device persistent-memory report, pipeline edition: one
         representative device PER STAGE (live addressable-shard bytes of
         that stage's params/opt state), next to the non-pipelined
-        prediction — tools/bench_pipeline.py asserts the ~S x reduction
+        prediction — tests/test_pipeline.py asserts the reduction
         against the S=1 twin's live buffers."""
         def dev_bytes(tree, dev):
             total = 0

@@ -4,7 +4,7 @@ Reference gap (ISSUE 6): the reference leans on Legion's resilient task
 runtime; our JAX rebuild has explicit recovery code (runtime/resilience.py
 retry/backoff, durable checkpoints, preemption drain) and every one of
 those paths must be EXERCISABLE on demand, deterministically, in tests and
-in the kill-and-resume smoke (tools/bench_resilience.py). This module is
+in the kill-and-resume tests (tests/test_resilience.py). This module is
 the switchboard: a `FaultPlan` arms named SITES to raise at chosen
 indices, and each instrumented callsite asks `check(site)` before doing
 the real work — so an armed fault fires BEFORE any state is mutated
@@ -131,9 +131,9 @@ _SPECS: List[FaultSpec] = []
 _COUNTS: Dict[str, int] = {}
 _FIRED: Dict[str, int] = {}
 
-# FF_FAULT_PLAN at import: subprocess harnesses (bench_resilience --check,
-# the SIGTERM/SIGKILL smokes) arm the plan via the environment before the
-# worker imports anything
+# FF_FAULT_PLAN at import: a harness that starts the worker as a process of
+# its own arms the plan via the environment before the worker imports
+# anything
 if os.environ.get("FF_FAULT_PLAN"):
     _SPECS = parse_plan(os.environ["FF_FAULT_PLAN"])
 

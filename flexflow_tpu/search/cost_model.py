@@ -545,7 +545,7 @@ def pipeline_phase_times(stage_costs: Sequence[float]):
     into its backward via value_and_grad, which shares the forward pass —
     no recompute there). Keep this in lockstep with
     PipelinedModel._build_stage_fns or predicted bubbles drift from
-    measured ones (tools/bench_pipeline.py asserts 25%)."""
+    measured ones."""
     fwd = [c / 3.0 for c in stage_costs]
     bwd = [float(c) for c in stage_costs]
     fwd[-1] = 0.0

@@ -36,8 +36,7 @@ resolving — with either absent, behavior is bitwise-identical to today):
    passthroughs always survive — a memory-capped search keeps its escape
    hatch); per-segment, layout finalists whose learned strategy score
    exceeds the best by FINALIST_MARGIN skip the expensive event-driven
-   re-rank (`search/sim_rerank`). Both cuts are pinned winner-safe by
-   tools/bench_learned.py on the gpt2 twin.
+   re-rank (`search/sim_rerank`).
 3. the SELF-CALIBRATING REFIT LOOP (tools/refit_cost_model.py): a drift
    warning now points at (and `--auto-refit` triggers) a refit from the
    run's own telemetry instead of a hand-run calibration sweep —
@@ -58,8 +57,8 @@ import numpy as np
 MODEL_SCHEMA_VERSION = 1
 
 # per-layer candidate pruning: drop candidates whose learned op time exceeds
-# ratio x the layer's best learned time (None disables — bench_learned.py
-# toggles this for the pruning on/off leg). Generous on purpose: per-op
+# ratio x the layer's best learned time (None disables). Generous on
+# purpose: per-op
 # times ignore the resharding edge costs the DP prices, so a tight ratio
 # could prune a candidate that wins on cheaper edges.
 DP_PRUNE_RATIO: Optional[float] = 2.0

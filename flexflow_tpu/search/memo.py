@@ -16,8 +16,7 @@ as immutable after construction (every call site in this codebase builds a
 fresh spec instead of mutating) — the fingerprint is cached on the instance.
 
 `FF_SEARCH_MEMO=0` (or `set_enabled(False)`) disables every table — the
-escape hatch used by tests and `tools/bench_search.py --baseline` to compare
-against the unmemoized path. Memoization never changes arithmetic: a miss
+escape hatch used by tests to compare against the unmemoized path. Memoization never changes arithmetic: a miss
 runs exactly the original code, a hit returns the float that code produced,
 so memoized and unmemoized costs are bitwise-equal.
 """

@@ -26,7 +26,7 @@ Layout: one `<key>.json` per entry under the cache dir
 (`--strategy-cache-dir` > `$FF_STRATEGY_CACHE_DIR` >
 `<checkout>/.ff_cache/strategy`), carrying the strategy plus a meta block
 (fingerprints, predicted cost, search wall-clock) for `profile_report()`
-cache-stats and `tools/bench_search.py`.
+cache-stats.
 """
 
 from __future__ import annotations

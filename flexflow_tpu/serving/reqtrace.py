@@ -13,8 +13,8 @@ Two pieces live here:
     Exports real Prometheus histogram series (`*_bucket{le=...}` with
     cumulative counts + `_sum` + `_count`) and answers quantiles with
     within-bucket interpolation — the single source of truth for serving
-    latency percentiles (bench_serve and monitor both read it, so they
-    can no longer disagree).
+    latency percentiles (the monitor and the twin's calibration both read
+    it, so they cannot disagree).
   * `RequestTracer` — the per-request lifecycle trace. Every request
     carries a stage cursor from submission through queue-wait, its
     prefill wave, each decode-window materialization / speculative round

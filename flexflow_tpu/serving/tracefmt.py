@@ -3,8 +3,8 @@
 ROADMAP item 5's unlocking refactor: ONE versioned JSONL schema of
 request arrivals shared by (a) live serving (`--serve-trace-out` exports
 the traffic a scheduler/fleet actually saw), (b) the open-loop Poisson
-generators in tools/bench_serve.py and tools/bench_fleet.py (every bench
-leg doubles as a replayable planning scenario), and (c) the twin's
+generator of tools/monitor.py and tools/twin.py (synthetic load doubles
+as a replayable planning scenario), and (c) the twin's
 loader (`serving/twin.py` replays any trace offline). Recorded
 production traffic and synthetic load are interchangeable inputs.
 
@@ -191,23 +191,6 @@ def poisson_records(rng: np.random.Generator, n: int, rate: float,
                         prompt=[int(t) for t in
                                 rng.integers(1, vocab, size=prompt_len)])
             for i in range(n)]
-
-
-def burst_records(rng: np.random.Generator, n_base: int, base_rate: float,
-                  burst_factor: float, burst_frac: float, vocab: int,
-                  prompt_len: int, max_new: int) -> List[TraceRecord]:
-    """A steady-state segment followed by a `burst_factor` x arrival-rate
-    burst covering the last `burst_frac` of requests — the autoscale
-    leg's 10x-burst scenario, as a plain trace."""
-    n_burst = max(1, int(n_base * burst_frac))
-    steady = poisson_records(rng, n_base, base_rate, vocab, prompt_len,
-                             max_new)
-    t_end = steady[-1].arrival_ts if steady else 0.0
-    burst = poisson_records(rng, n_burst, base_rate * burst_factor, vocab,
-                            prompt_len, max_new, t0=t_end)
-    for i, r in enumerate(burst):
-        r.rid = n_base + i
-    return steady + burst
 
 
 def scale_rate(records: Sequence[TraceRecord],

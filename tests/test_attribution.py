@@ -18,7 +18,6 @@ import pytest
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "tools"))
 
-import bench_history
 import profile_attribution
 import span_dataset
 import trace_report
@@ -304,29 +303,9 @@ def test_perf_probe_emits_into_sink(tmp_path):
 # ------------------------------------------------------------- CI wiring
 def test_span_dataset_check_smoke():
     """tools/span_dataset.py --check wired into tier-1 (the --check
-    convention of bench_search/bench_step/bench_resilience)."""
+    convention of the operator tools)."""
     assert span_dataset.main(["--check"]) == 0
     assert not tel.enabled()
-
-
-def test_bench_history_check_smoke():
-    """tools/bench_history.py --check: every BENCH_*.json parses and
-    carries its headline metric."""
-    assert bench_history.main(["--check"]) == 0
-
-
-def test_bench_history_flags_broken_artifact(tmp_path):
-    repo = tmp_path / "repo"
-    repo.mkdir()
-    (repo / "BENCH_zero.json").write_text(json.dumps(
-        {"opt_state_reduction_actual": 1.0}))
-    assert bench_history.main(["--check", "--repo", str(repo)]) == 0
-    (repo / "BENCH_pipeline.json").write_text("{not json")
-    with pytest.raises(AssertionError, match="unparseable"):
-        bench_history.main(["--check", "--repo", str(repo)])
-    (repo / "BENCH_pipeline.json").write_text(json.dumps({}))
-    with pytest.raises(AssertionError, match="headline"):
-        bench_history.main(["--check", "--repo", str(repo)])
 
 
 def test_profile_attribution_check_smoke():

@@ -5,19 +5,16 @@ AdmissionControl policy brain, exact cross-replica histogram merges, the
 merged SLO scoreboard vs a union-fed tracker, the least-loaded/burn-aware
 router, rolling-swap cursor gating + rollback-on-burn, and the autotuned
 `--kv-prefetch-ahead` derivation (flag = fallback, learned model =
-authority). tools/bench_fleet.py --check rides along as the CI smoke of
-the real-engine paths: single-replica bitwise identity vs the pre-fleet
-scheduler, weak scaling, disagg prefill->decode KV handoff parity, and a
-zero-drop rolling rollout.
+authority). Two real engines then serve through the fleet: a
+single-replica fleet is bitwise the plain scheduler, a disaggregated pair
+hands every request's KV pages off once and serves the colocated pair's
+tokens, and a rollout swaps every replica under load without a drop.
 """
 
-import os
-import sys
+import time
 
 import numpy as np
 import pytest
-
-sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "tools"))
 
 from flexflow_tpu.health import SLOTracker, parse_slo
 from flexflow_tpu.serving import (AdmissionControl, FleetRouter,
@@ -413,14 +410,127 @@ def test_shared_runtime_proxy_locks_and_floors_every_program(call):
     assert time.perf_counter() - t0 >= 0.02
 
 
-# ------------------------------------------------------------- bench smoke
-@pytest.mark.slow  # ~18s: two engines + five serve legs (identity,
-# scaling, mixed priorities, disagg handoff, rolling swap)
-def test_bench_fleet_check_smoke(devices, capsys):
-    """tools/bench_fleet.py --check end to end on the CPU twin: bitwise
-    single-replica identity vs the pre-fleet scheduler, 2-replica weak
-    scaling, mixed-priority TTFT ordering, disagg prefill->decode handoff
-    parity, and a zero-drop rolling swap."""
-    import bench_fleet
-    assert bench_fleet.main(["--check"]) == 0
-    assert "CHECK PASS" in capsys.readouterr().out
+# ------------------------------------------------------- two real engines
+@pytest.fixture(scope="module")
+def fleet_env(devices, tmp_path_factory):
+    """Two replicas of one searched serving graph, each with its own KV
+    pools and a host tier (the disaggregated handoff travels through it),
+    and a training-side model of the same graph to drop snapshots."""
+    from flexflow_tpu import FFConfig, FFModel, SGDOptimizer
+    from flexflow_tpu.models import GPT2Config, build_gpt2
+    from flexflow_tpu.serving import compile_serving
+
+    gc = GPT2Config(vocab=256, seq=16, d_model=64, heads=2, layers=1,
+                    dropout=0.0)
+    engines = []
+    for _ in range(2):
+        m = FFModel(FFConfig(search_budget=16,
+                             mesh_shape={"data": 2, "model": 4},
+                             log_level="warning", max_batch_slots=4,
+                             kv_page_size=4, kv_host_pages=16))
+        build_gpt2(m, gc, batch=8)
+        eng = compile_serving(m, max_decode_len=4)
+        eng.init(seed=0)
+        engines.append(eng)
+    tm = FFModel(FFConfig(search_budget=0, only_data_parallel=True,
+                          log_level="warning", max_batch_slots=4,
+                          kv_page_size=4, async_checkpoint=False))
+    build_gpt2(tm, gc, batch=8)
+    cm = tm.compile(SGDOptimizer(lr=0.01),
+                    loss_type="sparse_categorical_crossentropy", metrics=[])
+    cm.init(seed=0)
+    return engines, gc, cm, str(tmp_path_factory.mktemp("rollout"))
+
+
+def _trace(gc, n, seed, max_new, priorities=(1,)):
+    from flexflow_tpu.serving import tracefmt
+
+    return tracefmt.records_to_requests(tracefmt.poisson_records(
+        np.random.default_rng(seed), n, 500.0, gc.vocab, 4, max_new,
+        priorities=priorities))
+
+
+def _fleet(engines, **kw):
+    from flexflow_tpu.serving import (ServingFleet, gpt2_prompt_inputs,
+                                      gpt2_step_inputs)
+
+    return ServingFleet(engines, gpt2_prompt_inputs, gpt2_step_inputs,
+                        eos_id=None, dispatch_ahead=4, **kw)
+
+
+def _tokens(done):
+    return {r.rid: list(r.tokens) for r in done}
+
+
+def test_single_replica_fleet_is_the_plain_scheduler(fleet_env):
+    """One replica behind the router serves the scheduler's own token
+    streams with the scheduler's own prefill / decode / sync counts."""
+    from flexflow_tpu.serving import (ContinuousBatchingScheduler,
+                                      gpt2_prompt_inputs, gpt2_step_inputs)
+
+    (eng, _), gc, _, _ = fleet_env
+    sched = ContinuousBatchingScheduler(eng, eng.params, gpt2_prompt_inputs,
+                                        gpt2_step_inputs, eos_id=None,
+                                        dispatch_ahead=4)
+    direct = sched.run(_trace(gc, 8, 1, eng.max_decode_len))
+    fleet = _fleet([eng])
+    assert _tokens(fleet.serve(_trace(gc, 8, 1, eng.max_decode_len))) \
+        == _tokens(direct)
+    fs = fleet.replicas[0].sched
+    for c in ("prefills", "decode_steps", "materializations"):
+        assert getattr(fs, c) == getattr(sched, c), c
+
+
+def test_disagg_hands_every_request_off_once(fleet_env):
+    """The same mixed-priority trace through two mixed replicas and through
+    one prefill + one decode replica: both serve every request in full,
+    the split hands each request's committed pages over exactly once, and
+    the greedy streams are bitwise the colocated ones."""
+    engines, gc, _, _ = fleet_env
+    n, max_new = 12, engines[0].max_decode_len
+    colo = _fleet(engines, topology="colocated")
+    dis = _fleet(engines, topology="disagg", prefill_replicas=1)
+    toks = []
+    for fleet in (colo, dis):
+        done = fleet.serve(_trace(gc, n, 4, max_new, (0, 1, 1, 2)))
+        assert len(done) == n and not fleet.shed and not fleet.failed
+        assert all(len(r.tokens) == max_new for r in done)
+        toks.append(_tokens(done))
+    assert dis.stats["handoffs"] == n
+    assert sum(h.engine.kv.tier_counters.get("kv_handoff_bytes", 0)
+               for h in dis.replicas) > 0
+    assert toks[1] == toks[0]
+
+
+def test_rolling_swap_under_load_drops_nothing(fleet_env):
+    """A snapshot in the watched root rolls across the fleet one replica
+    at a time, each at its own drained window, while the fleet serves:
+    every replica ends on the new version and no request is lost. The last
+    third of the trace is routed only once the rollout is through, so it
+    is served by the new weights however long the swaps take."""
+    from flexflow_tpu.runtime.resilience import save_durable
+
+    engines, gc, cm, root = fleet_env
+    cm.init(seed=1)
+    cm._iteration = 1
+    save_durable(cm, root, block=True)
+    fleet = _fleet(engines)
+    pick, routed = fleet.router.pick, []
+
+    def pick_after_rollout(pool):
+        routed.append(1)
+        deadline = time.monotonic() + 120.0
+        while len(routed) > 8 and len(fleet.rolling.swaps) < len(engines) \
+                and time.monotonic() < deadline:
+            time.sleep(0.01)
+        return pick(pool)
+    fleet.router.pick = pick_after_rollout
+    try:
+        done = fleet.serve(_trace(gc, 12, 5, engines[0].max_decode_len),
+                           watch_root=root, poll_interval_s=0.01)
+    finally:
+        for e in engines:
+            e._watch_root = None
+    assert len(done) == 12 and not fleet.shed and not fleet.failed
+    assert sorted(i for i, _ in fleet.rolling.swaps) == [0, 1]
+    assert [e.active_version for e in engines] == [1, 1]

@@ -91,3 +91,31 @@ def test_fused_update_none_on_foreign_state():
     params = _params()
     foreign = optax.sgd(0.1).init(params)
     assert fused_update(plan, _grads(0), foreign, params) is None
+
+
+def test_fused_suite_trains_gpt2_to_the_optax_loss(devices):
+    """Through `fit`, not the kernel alone: a one-block GPT-2 under Adam
+    with the fused optimizer update and the fused cross-entropy forced on
+    ("on" raises where a kernel is not taken) ends two epochs on the loss
+    of the optax update and the optax loss, within 1e-5."""
+    from flexflow_tpu import FFConfig, FFModel
+    from flexflow_tpu.losses import LossType
+    from flexflow_tpu.models import GPT2Config, build_gpt2
+
+    rng = np.random.default_rng(0)
+    ids = rng.integers(0, 512, size=(128, 16)).astype(np.int32)
+    pos = np.broadcast_to(np.arange(16, dtype=np.int32), (128, 16)).copy()
+    y = rng.integers(0, 512, size=(128, 16)).astype(np.int32)
+
+    def final_loss(mode):
+        m = FFModel(FFConfig(batch_size=8, only_data_parallel=True, seed=3,
+                             fused_loss=mode, fused_optimizer=mode,
+                             log_level="warning"))
+        build_gpt2(m, GPT2Config(vocab=512, seq=16, d_model=64, heads=2,
+                                 layers=1, dropout=0.0), batch=8)
+        cm = m.compile(AdamOptimizer(alpha=1e-3),
+                       LossType.SPARSE_CATEGORICAL_CROSSENTROPY, metrics=[])
+        cm.init(seed=0)
+        return cm.fit([ids, pos], y, epochs=2, verbose=False)[-1]["loss"]
+
+    assert final_loss("on") == pytest.approx(final_loss("off"), abs=1e-5)

@@ -2,11 +2,9 @@
 path, bubble-aware search): schedule numerics vs the sequential accum loop
 (SGD + Adam, dropout rng parity, steps_per_dispatch fusion parity),
 stage-sharded memory, cross-mesh checkpoint restore, the memory-capped DP
-selection (MULTICHIP-style assertion), schedule-grid invariants, and the
-bench_pipeline CI smoke."""
+selection (MULTICHIP-style assertion), and schedule-grid invariants."""
 
 import os
-import sys
 
 import numpy as np
 import pytest
@@ -112,24 +110,29 @@ def test_parity_with_fused_dispatch_baseline(devices):
     assert h_p[-1]["loss"] == pytest.approx(h_seq[-1]["loss"], rel=1e-5)
 
 
-def test_four_stages_and_weight_residency(devices):
-    """S=4: per-stage weights live ONLY on the owning group — summing one
+@pytest.mark.parametrize("kind,S", [("mlp", 4), ("gpt2", 2)])
+def test_stage_weight_residency(devices, kind, S):
+    """Per-stage weights live ONLY on the owning group — summing one
     representative device per stage reconstructs the model, and the max
-    per-device share shrinks vs the replicated S=1 twin."""
-    cm1, h1 = _train("mlp", 1, accum=8)
-    cm4, h4 = _train("mlp", 4, accum=8)
-    assert h4[-1]["loss"] == pytest.approx(h1[-1]["loss"], rel=1e-5)
-    m1, m4 = cm1.memory_stats(), cm4.memory_stats()
+    per-device share of parameters + optimizer state (live buffers)
+    shrinks by at least S/2 vs the replicated S=1 twin."""
+    cm1, h1 = _train(kind, 1, accum=8)
+    cmS, hS = _train(kind, S, accum=8)
+    assert hS[-1]["loss"] == pytest.approx(h1[-1]["loss"], rel=1e-5)
+    m1, mS = cm1.memory_stats(), cmS.memory_stats()
     full = m1["actual_param_bytes_per_device"]
     # stage shares reassemble the model (tiny drift allowed: a divisible
     # bias may shard over data=8 at S=1 but not over a stage's data=2)
-    assert sum(m4["per_stage_param_bytes"]) == pytest.approx(full,
+    assert sum(mS["per_stage_param_bytes"]) == pytest.approx(full,
                                                              rel=0.01)
-    assert m4["actual_param_bytes_per_device"] <= full / 2
+    assert mS["actual_param_bytes_per_device"] <= full / (S / 2)
+    assert (full + m1["actual_opt_state_bytes_per_device"]) >= (S / 2) * (
+        mS["actual_param_bytes_per_device"]
+        + mS["actual_opt_state_bytes_per_device"])
     # disjoint groups: every layer's weights on exactly one stage
-    names = [set(p) for p in cm4.stage_params]
-    for i in range(4):
-        for j in range(i + 1, 4):
+    names = [set(p) for p in cmS.stage_params]
+    for i in range(S):
+        for j in range(i + 1, S):
             assert not (names[i] & names[j])
 
 
@@ -330,19 +333,6 @@ def test_launcher_value_flags_derived_from_parser():
     # the new pipeline knobs ride along automatically
     assert "--pipeline-stages" in derived
     assert "--pipeline-schedule" in derived
-
-
-# ------------------------------------------------------------------ smoke
-def test_bench_pipeline_check_smoke(devices):
-    """tools/bench_pipeline.py --check (wired next to the bench_search /
-    bench_step / bench_zero smokes): >= S/2 per-device param+opt memory
-    reduction at S=2 (live buffers), measured-vs-predicted bubble within
-    25% for both schedules, 1f1b >= ~gpipe, 1e-5 loss parity."""
-    sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..",
-                                    "tools"))
-    import bench_pipeline
-
-    assert bench_pipeline.main(["--check"]) == 0
 
 
 # ------------------------------------------------- review-hardening cases

@@ -5,12 +5,13 @@ retry budget with telemetry `retry` events; permanent → clean escalation),
 corrupt-newest-snapshot fallback, SIGTERM drain + resume="auto" trajectory
 parity on the same AND a resized mesh, elastic pipeline stage-count
 restore, CheckpointMismatchError, wait_pending timeout / exit-drain
-reporting, and the bench_resilience kill-and-resume CI smoke."""
+reporting, and a real SIGKILL mid-epoch resumed in a fresh process."""
 
 import json
 import os
 import shutil
 import signal
+import subprocess
 import sys
 import time
 
@@ -156,6 +157,8 @@ def test_distributed_init_site_is_wired():
     "dataloader/transfer@2*2",   # transient transfer failures, step 2
     "fit/dispatch@3",            # one dispatch admission failure, step 3
     "checkpoint/write@1",        # first checkpoint write attempt fails
+    # all three armed in one run
+    "dataloader/transfer@2*2,fit/dispatch@3,checkpoint/write@1",
 ])
 def test_fit_recovers_injected_transient_faults(devices, tmp_path, plan):
     """Each instrumented fit-path site, armed transiently, must be
@@ -169,8 +172,9 @@ def test_fit_recovers_injected_transient_faults(devices, tmp_path, plan):
                 checkpoint_every_steps=3)
     hist = cm.fit(x, y, epochs=2, verbose=False)
     cm.wait_checkpoints()
-    site = plan.split("@")[0]
-    assert faults.fired().get(site, 0) >= 1, f"{site} never fired"
+    for spec in plan.split(","):
+        site = spec.split("@")[0]
+        assert faults.fired().get(site, 0) >= 1, f"{site} never fired"
     np.testing.assert_allclose(_losses(hist), ref, rtol=1e-7)
 
 
@@ -537,14 +541,72 @@ def test_exit_drain_reports_failed_writes(devices, tmp_path, capsys):
         ck._PENDING.clear()
 
 
-# ---------------------------------------------------------------- CI smoke
-def test_bench_resilience_check_smoke(devices):
-    """tools/bench_resilience.py --check: the REAL kill-and-resume
-    acceptance run (subprocess SIGKILL mid-epoch, relaunch on the same and
-    a resized mesh, injected-fault leg) — wired like bench_zero/
-    bench_pipeline."""
-    sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..",
-                                    "tools"))
-    import bench_resilience
+# ------------------------------------------- a process that dies unwarned
+def _worker(*args):
+    """Start tests/_resilience_worker.py as a process of its own."""
+    return subprocess.Popen(
+        [sys.executable, os.path.join(os.path.dirname(__file__),
+                                      "_resilience_worker.py"), *args],
+        stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True)
 
-    assert bench_resilience.main(["--check"]) == 0
+
+def _history(out):
+    for line in reversed(out.splitlines()):
+        if line.startswith("HISTORY "):
+            return json.loads(line[len("HISTORY "):])
+    return None
+
+
+@pytest.fixture(scope="module")
+def sigkilled(devices, tmp_path_factory):
+    """The uninterrupted trajectory (run here, no checkpoints), and a
+    checkpointing run of the same model in a process that is SIGKILLed
+    mid-epoch: no handler runs, no drain, no final snapshot. The child
+    parks itself after step 13 (epoch 1, step 5 of 8) and the kill waits
+    for that and for a committed snapshot: nothing is paced by a clock."""
+    import _resilience_worker as w
+
+    ref = w.fit(w.build())
+    work = tmp_path_factory.mktemp("sigkill")
+    root, parked = str(work / "ck"), str(work / "parked")
+    proc = _worker(f"ckpt_dir={root}", "park_after=13",
+                   f"park_file={parked}")
+    try:
+        deadline = time.monotonic() + 240.0
+        while not (os.path.exists(parked) and rz.committed_snapshots(root)) \
+                and proc.poll() is None and time.monotonic() < deadline:
+            time.sleep(0.02)
+        alive = proc.poll() is None
+    finally:
+        proc.kill()
+    out, _ = proc.communicate(timeout=60)
+    return {"ref": ref, "root": root, "alive_at_kill": alive,
+            "returncode": proc.returncode, "out": out}
+
+
+def test_sigkill_leaves_a_committed_snapshot(sigkilled):
+    """The killed process was mid-run (alive, no history printed, dead by
+    signal 9) and what it left behind is discoverable: a committed
+    snapshot from before the kill, mid-trajectory."""
+    k = sigkilled
+    assert k["alive_at_kill"], k["out"][-2000:]
+    assert k["returncode"] == -signal.SIGKILL
+    assert _history(k["out"]) is None
+    snaps = rz.committed_snapshots(k["root"])
+    assert snaps and all(m["committed"] for _, _, m in snaps)
+    assert 0 < snaps[-1][0] <= 13          # parked after 13 of 24 steps
+
+
+@pytest.mark.parametrize("mesh", ["", "data=4,model=2"],
+                         ids=["same_mesh", "resized_mesh"])
+def test_sigkill_resume_matches_uninterrupted(sigkilled, tmp_path, mesh):
+    """resume="auto" in a fresh process, on the mesh that died and on
+    another one (the snapshot re-shards), finishes on the trajectory of
+    the run that was never interrupted."""
+    root = str(tmp_path / "ck")
+    shutil.copytree(sigkilled["root"], root)
+    proc = _worker(f"ckpt_dir={root}", "resume=auto", f"mesh={mesh}")
+    out, _ = proc.communicate(timeout=240)
+    assert proc.returncode == 0, out[-2000:]
+    np.testing.assert_allclose(_history(out), sigkilled["ref"], rtol=1e-5,
+                               atol=1e-7)

@@ -9,8 +9,7 @@ construction (dropout hard-zeroed in the clones, fixed rng); both serving
 programs warm-hit the strategy cache under independent keys; KV-cache
 residency is accounted in memory_stats within the watermark envelope; and
 the continuous-batching scheduler admits/evicts correctly under EOS,
-max-len, and page backpressure. tools/bench_serve.py --check rides along
-as the CI smoke of the open-loop bench.
+max-len, and page backpressure.
 """
 
 import os
@@ -471,16 +470,6 @@ def test_scheduler_never_compiles_the_full_logits_program(gpt2_serve, rng):
         lengths[0] = len(r.prompt)
         logits, _ = eng.prefill(eng.params, gpt2_prompt_inputs(ids, lengths))
         assert r.tokens[0] == int(np.asarray(logits)[0, len(r.prompt) - 1].argmax())
-
-
-# ------------------------------------------------------------------ CI smoke
-def test_bench_serve_check_smoke(devices, capsys):
-    """tools/bench_serve.py --check wired into tier-1: the open-loop bench
-    completes, quantiles are ordered, KV memory is accounted."""
-    import bench_serve
-
-    assert bench_serve.main(["--check", "--requests", "6"]) == 0
-    assert "CHECK PASS" in capsys.readouterr().out
 
 
 def test_serve_profile_ops_emits_corpus_rows(gpt2_serve, rng, tmp_path):

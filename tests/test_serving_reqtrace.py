@@ -13,7 +13,7 @@ sheds and timeouts against the availability objective (and never against
 latency ones); the serve/hist + serve/slo events round-trip through
 telemetry -> monitor -> Prometheus as real histogram series and labeled
 budget gauges; and tools/trace_report.py --rid renders one request's
-stage timeline. tools/bench_reqtrace.py --check rides along as CI smoke.
+stage timeline.
 """
 
 import json
@@ -445,19 +445,6 @@ def test_trace_report_rid_timeline(rt_serve, tmp_path, capsys):
     out = capsys.readouterr().out
     assert "rid=1" in out and "queue" in out and "prefill" in out
     assert trace_report.main([tdir, "--rid", "999"]) == 1
-
-
-# ------------------------------------------------------------ CI smoke
-@pytest.mark.slow  # ~28s: two engines + a live snapshot swap mid-run
-def test_bench_reqtrace_check_smoke(devices, capsys):
-    """tools/bench_reqtrace.py --check wired into CI: tracing overhead
-    <=2% tokens/s/chip, >=95% stage accounting, a mid-trace swap inside
-    a request timeline, and the SLO scoreboard under overload (the full
-    twin's evidence lives in BENCH_reqtrace.json)."""
-    import bench_reqtrace
-
-    assert bench_reqtrace.main(["--check", "--requests", "8"]) == 0
-    assert "CHECK PASS" in capsys.readouterr().out
 
 
 # ----------------------------------------------- scheduler spans (PR 25)

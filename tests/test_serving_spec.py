@@ -12,7 +12,6 @@ to a pinned tolerance; the speculative engines warm-restore draft AND
 target strategies from the cache with zero DP expansions; admission grows
 its page `need` by the K-token lookahead and every page returns to the
 free list in both caches; and the spec/kv telemetry feeds the monitor.
-tools/bench_spec.py --check rides along as the CI smoke.
 """
 
 import os
@@ -362,7 +361,7 @@ def test_int8_searched_strategy_diverges(devices):
     pools for both."""
     # the pinned divergence window: d_model=64 heads=4 at 12 slots is where
     # bf16's page traffic beats the tp all-reduce but int8's halved pages
-    # don't (see tools/bench_spec.py)
+    # don't
     gc = GPT2Config(vocab=256, seq=16, d_model=64, heads=4, layers=1,
                     dropout=0.0)
     degs = {}
@@ -377,16 +376,3 @@ def test_int8_searched_strategy_diverges(devices):
         degs[dt] = ms["kv_shard_degree"]
     assert degs["bf16"] == 4, degs
     assert degs["int8"] == 1, degs
-
-
-# ------------------------------------------------------------------ CI smoke
-@pytest.mark.slow  # ~13s: the full bench smoke (5 searched engines + two
-# serve traces); tier-1 pins the same invariants piecewise above, and
-# BENCH_spec.json carries the full-run evidence.
-def test_bench_spec_check_smoke(devices, capsys):
-    """tools/bench_spec.py --check end to end: parity, strategy
-    divergence, and KV accounting all assert inside the bench."""
-    import bench_spec
-
-    assert bench_spec.main(["--check", "--requests", "4"]) == 0
-    assert "CHECK PASS" in capsys.readouterr().out

@@ -1,11 +1,7 @@
 """Async training-loop pipeline (compiler/compile.py _fit_epochs +
 runtime/dataloader.py): device-resident metrics (zero mid-epoch host syncs
 in the default config), K-step fused dispatch, prefetcher exception
-forwarding, the make_multi_step donation contract, and the bench_step CI
-smoke (the step-pipeline twin of test_bench_search_check_smoke)."""
-
-import os
-import sys
+forwarding, and the make_multi_step donation contract."""
 
 import jax
 import jax.numpy as jnp
@@ -123,17 +119,29 @@ def test_make_multi_step_donation_contract(devices):
 
 
 # ----------------------------------------------------------- async fit loop
-def _fit_run(sync_every, steps_per_dispatch, callbacks=None, epochs=2):
+def _fit_run(sync_every, steps_per_dispatch, callbacks=None, epochs=2,
+             model="mlp"):
+    """8 batches an epoch of a two-layer MLP, or of a one-block GPT-2
+    (embedding, attention and a vocabulary-wide loss under the same loop)."""
     rng = np.random.default_rng(0)
-    x = rng.normal(size=(256, 16)).astype(np.float32)
-    y = rng.integers(0, 4, size=(256,)).astype(np.int32)
     cfg = FFConfig(batch_size=32, only_data_parallel=True,
                    sync_every=sync_every,
                    steps_per_dispatch=steps_per_dispatch)
     m = FFModel(cfg)
-    t = m.create_tensor([32, 16], name="x")
-    h = m.dense(t, 32, activation="relu")
-    m.dense(h, 4)
+    if model == "gpt2":
+        from flexflow_tpu.models import GPT2Config, build_gpt2
+
+        build_gpt2(m, GPT2Config(vocab=512, seq=16, d_model=64, heads=2,
+                                 layers=1, dropout=0.0), batch=32)
+        x = [rng.integers(0, 512, size=(256, 16)).astype(np.int32),
+             np.broadcast_to(np.arange(16, dtype=np.int32), (256, 16)).copy()]
+        y = rng.integers(0, 512, size=(256, 16)).astype(np.int32)
+    else:
+        x = rng.normal(size=(256, 16)).astype(np.float32)
+        y = rng.integers(0, 4, size=(256,)).astype(np.int32)
+        t = m.create_tensor([32, 16], name="x")
+        h = m.dense(t, 32, activation="relu")
+        m.dense(h, 4)
     cm = m.compile(SGDOptimizer(lr=0.05),
                    LossType.SPARSE_CATEGORICAL_CROSSENTROPY,
                    [MetricsType.ACCURACY])
@@ -142,12 +150,13 @@ def _fit_run(sync_every, steps_per_dispatch, callbacks=None, epochs=2):
     return cm, hist
 
 
-def test_async_fit_zero_host_syncs_and_loss_parity(devices):
+@pytest.mark.parametrize("model", ["mlp", "gpt2"])
+def test_async_fit_zero_host_syncs_and_loss_parity(devices, model):
     """Default config (sync_every=0): zero mid-epoch host syncs, and the
     deferred float64 loss/metric accumulation is BIT-identical to the
     synchronous loop (same values, same summation order)."""
-    _, h_sync = _fit_run(sync_every=1, steps_per_dispatch=1)
-    cm, h_async = _fit_run(sync_every=0, steps_per_dispatch=1)
+    _, h_sync = _fit_run(sync_every=1, steps_per_dispatch=1, model=model)
+    cm, h_async = _fit_run(sync_every=0, steps_per_dispatch=1, model=model)
     assert cm.step_stats["host_syncs"] == 0
     assert cm.step_stats["dispatches"] == 16  # 8 batches x 2 epochs
     for es, ea in zip(h_sync, h_async):
@@ -156,11 +165,12 @@ def test_async_fit_zero_host_syncs_and_loss_parity(devices):
         assert ea["host_syncs"] == 0.0 and es["host_syncs"] > 0
 
 
-def test_fused_fit_amortizes_dispatches(devices):
+@pytest.mark.parametrize("model", ["mlp", "gpt2"])
+def test_fused_fit_amortizes_dispatches(devices, model):
     """K=4 over 8 batches/epoch: 2 dispatches per epoch, all steps fused,
     loss within float32 reassociation of the synchronous loop."""
-    _, h_sync = _fit_run(sync_every=1, steps_per_dispatch=1)
-    cm, h_fused = _fit_run(sync_every=0, steps_per_dispatch=4)
+    _, h_sync = _fit_run(sync_every=1, steps_per_dispatch=1, model=model)
+    cm, h_fused = _fit_run(sync_every=0, steps_per_dispatch=4, model=model)
     assert cm.step_stats == {"dispatches": 4, "host_syncs": 0,
                              "barriers": 0, "fused_steps": 16,
                              "epoch_end_syncs": 2}
@@ -244,14 +254,3 @@ def test_perf_metrics_deferred_fold_parity(devices):
         small_e.update(4, {"m": float(jnp.float32(v))})
         small_d.update_deferred(4, {"m": jnp.float32(v)})
     assert small_d.summary()["m"] == small_e.summary()["m"]
-
-
-# ------------------------------------------------------------------ CI smoke
-def test_bench_step_check_smoke(devices):
-    """tools/bench_step.py --check (wired next to bench_search's smoke):
-    fused dispatch count <= ceil(num_batches/K), zero mid-epoch host syncs
-    in the async modes, 1e-6 final-loss parity with the synchronous loop."""
-    sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "tools"))
-    import bench_step
-
-    assert bench_step.main(["--check"]) == 0

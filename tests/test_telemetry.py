@@ -163,8 +163,7 @@ def test_trace_report_chrome_export(tmp_path):
 
 
 def test_trace_report_check_smoke():
-    """tools/trace_report.py --check wired into CI (the telemetry twin of
-    bench_search/bench_step's smoke modes)."""
+    """tools/trace_report.py --check wired into CI."""
     assert trace_report.main(["--check"]) == 0
     assert not tel.enabled()  # --check cleans up the global sink
 

@@ -17,8 +17,8 @@ import pytest
 
 from flexflow_tpu.serving import tracefmt
 from flexflow_tpu.serving.tracefmt import (SCHEMA_VERSION, Trace,
-                                           TraceRecord, burst_records,
-                                           load_trace, poisson_records,
+                                           TraceRecord, load_trace,
+                                           poisson_records,
                                            save_trace, scale_rate)
 
 
@@ -133,24 +133,6 @@ def test_poisson_records_match_legacy_inline_generator():
                            plen, max_new)
     assert [(r.arrival_ts, r.prompt) for r in recs] == legacy
     assert all(r.rid == i for i, r in enumerate(recs))
-
-
-def test_burst_records_shape():
-    """burst_records = steady segment then a burst_factor x tail: the
-    burst rides after the steady window and arrives denser."""
-    rng = np.random.default_rng(1)
-    recs = burst_records(rng, 100, base_rate=2.0, burst_factor=10.0,
-                         burst_frac=0.25, vocab=64, prompt_len=4,
-                         max_new=4)
-    steady, burst = recs[:100], recs[100:]
-    assert len(burst) == 25
-    assert burst[0].arrival_ts > steady[-1].arrival_ts
-    ts = [r.arrival_ts for r in recs]
-    assert ts == sorted(ts)
-    gap_s = (steady[-1].arrival_ts - steady[0].arrival_ts) / 99
-    gap_b = (burst[-1].arrival_ts - burst[0].arrival_ts) / 24
-    assert gap_b < gap_s / 3  # ~10x the rate, generously bounded
-    assert [r.rid for r in recs] == list(range(125))
 
 
 def test_scale_rate_scales_offered_load():

@@ -4,10 +4,9 @@ capacity bisection, burn-driven scaling signals, and the CI smokes.
 Unit pins cover the pure-twin pieces (no engines, bit-deterministic):
 replay determinism, live-report schema parity, what-if monotonicity,
 the capacity curve, scaling_signal's action table, and the
-window-overhead calibration identity. tools/twin.py --check and
-tools/bench_twin.py --check ride along as tier-1 smokes — bench_twin
-builds the real 8-dev CPU engine and closes the twin-vs-live +
-residual->refit loop end to end.
+window-overhead calibration identity. One test builds the real 8-dev CPU
+engine and closes the record -> replay -> residual -> refit loop end to
+end; tools/twin.py --check rides along as a tier-1 smoke.
 """
 
 import dataclasses
@@ -185,18 +184,110 @@ def test_scale_out_fires_before_budget_exhausts():
     assert sig["budget_remaining"] > 0
 
 
+def _min_budget(res):
+    rep = res.slo.report(now_s=res.stats["wall_s"])
+    return min(o["budget_remaining"] for o in rep["objectives"].values())
+
+
+def test_burst_signals_scale_out_and_the_sized_fleet_holds_budget():
+    """The policy through the twin's own event loop: twenty minutes at 1
+    req/s, then 30 s at ten times that. One replica ends with its ttft
+    budget spent, but its scale_out signal fired while budget was left;
+    the capacity curve sizes the fleet for the burst's peak rate, and that
+    fleet replays the same burst with budget to spare and nothing shed."""
+    rng = np.random.default_rng(3)
+    steady = poisson_records(rng, 1200, 1.0, 256, 4, 8)
+    burst = poisson_records(rng, 300, 10.0, 256, 4, 8,
+                            t0=steady[-1].arrival_ts)
+    for i, r in enumerate(burst):
+        r.rid = len(steady) + i
+    recs = steady + burst
+    spec = _spec(slo="ttft_p95_ms=1000")
+    costs = TwinCosts.analytic(spec.kv_spec(), step_floor_s=0.1)
+
+    static = simulate(recs, spec, costs, signal_every_s=5.0)
+    assert _min_budget(static) <= 0.0
+    sig = next(s for s in static.signals if s["action"] == "scale_out")
+    assert sig["budget_remaining"] > 0
+
+    ts = [r.arrival_ts for r in recs]
+    peak = max(sum(1 for u in ts if t - 10.0 <= u <= t) for t in ts[-300:]) \
+        / 10.0
+    curve = capacity_curve(steady, spec, costs, replicas=(1, 2, 4, 8))
+    n = next(c["replicas"] for c in curve
+             if c["capacity_rps"] >= 1.15 * peak)
+    scaled = simulate(recs, dataclasses.replace(spec, replicas=n), costs)
+    assert n > 1 and scaled.stats["shed"] == 0
+    assert _min_budget(scaled) > 0.0
+
+
+def test_recorded_trace_replays_and_refits_the_twin(devices, tmp_path):
+    """The loop the twin exists for, on a live engine: --serve-trace-out
+    records the offered load as a trace file; the twin configured from
+    that engine, priced from the run's own histograms ("measured"),
+    replays the file to completion; the residual rows it emits refit the
+    cost model, and the next resolve prices the decode step from the refit
+    ("learned") within a tenth of what was measured."""
+    import refit_cost_model
+
+    from flexflow_tpu import FFConfig, FFModel
+    from flexflow_tpu import telemetry as tel
+    from flexflow_tpu.models import GPT2Config, build_gpt2
+    from flexflow_tpu.serving import (ContinuousBatchingScheduler,
+                                      compile_serving, gpt2_prompt_inputs,
+                                      gpt2_step_inputs, tracefmt)
+    from flexflow_tpu.serving.twin import emit_residual_rows
+
+    trace_path = str(tmp_path / "live.jsonl")
+    cfg = FFConfig(search_budget=16, mesh_shape={"data": 2, "model": 4},
+                   log_level="warning", max_batch_slots=4, kv_page_size=4,
+                   serve_trace_out=trace_path,
+                   cost_model_path=str(tmp_path / "model.json"))
+    m = FFModel(cfg)
+    build_gpt2(m, GPT2Config(vocab=256, seq=16, d_model=64, heads=2,
+                             layers=1, dropout=0.0), batch=8)
+    eng = compile_serving(m, max_decode_len=4)
+    eng.init(seed=0)
+    sched = ContinuousBatchingScheduler(eng, eng.params, gpt2_prompt_inputs,
+                                        gpt2_step_inputs, eos_id=None,
+                                        dispatch_ahead=4)
+    n = 24
+    done = sched.run(tracefmt.records_to_requests(_recs(n, rate=500.0,
+                                                        max_new=4)))
+    assert len(done) == n
+
+    trace = tracefmt.load_trace(trace_path)
+    assert len(trace) == n and trace.skipped == 0
+    assert trace.meta.get("source") == "scheduler"
+
+    spec = TwinSpec.from_engine(eng, replicas=1)
+    ks = spec.kv_spec()
+    live = {"hists": sched.tracer.hists}
+    costs = TwinCosts.resolve(ks, cfg=eng.cfg, live_report=live,
+                              slots=spec.slots)
+    assert costs.source == "measured"
+    sim = simulate(trace.records, spec, costs)
+    assert sim.stats["completed"] == n and sim.stats["shed"] == 0
+
+    tdir = str(tmp_path / "tel")
+    tel.configure(tdir)
+    try:
+        rows = emit_residual_rows(live, TwinCosts.analytic(ks), ks,
+                                  spec.slots)
+        tel.flush()
+    finally:
+        tel.shutdown()
+    refit = refit_cost_model.refit(tdir, model_path=eng.cfg.cost_model_path)
+    assert rows == 2 and refit["rows"] >= 2
+    relearned = TwinCosts.resolve(ks, cfg=eng.cfg, slots=spec.slots)
+    assert relearned.source == "learned"
+    assert relearned.decode_step_s == pytest.approx(
+        sched.tracer.hists["decode_step"].mean(), rel=0.10)
+
+
 # ------------------------------------------------------------- CI smokes
 def test_twin_cli_check_smoke(capsys):
     """tools/twin.py --check: generate -> save -> load -> replay ->
     report -> capacity curve, no engine, deterministic."""
     import twin as twin_cli
     assert twin_cli.main(["--check"]) == 0
-
-
-def test_bench_twin_check_smoke(devices, capsys):
-    """tools/bench_twin.py --check end to end on the 8-dev CPU twin:
-    live record -> trace export -> twin replay -> validation within the
-    relaxed check bound, plus the residual -> refit -> relearned-pricing
-    loop and the pure-twin capacity/autoscale legs."""
-    import bench_twin
-    assert bench_twin.main(["--check"]) == 0

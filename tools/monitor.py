@@ -249,8 +249,7 @@ def _serve_stats(serve: Dict[str, Any]) -> Optional[Dict[str, Any]]:
     ttfts = [float(d["ttft_s"]) for d in serve["done"]
              if d.get("ttft_s") is not None]
     # ISSUE 15: when the stream carries live histograms they are THE
-    # source of truth for latency quantiles (bench_serve reads the same
-    # histograms, so the two can never disagree); the done-event/span
+    # source of truth for latency quantiles; the done-event/span
     # recompute is only the fallback for pre-15 streams
     hists = _merged_hists(serve)
     th, sh = hists.get("ttft"), hists.get("decode_step")

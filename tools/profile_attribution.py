@@ -14,7 +14,7 @@ acceptance contract end to end:
     non-empty featurized corpus.
 
 Usage:
-    python tools/profile_attribution.py [--out BENCH_attribution.json]
+    python tools/profile_attribution.py [--out attribution.json]
                                         [--epochs N] [--blocks N]
     python tools/profile_attribution.py --check    # CI smoke (small twin)
 """
@@ -147,7 +147,7 @@ def main(argv: Optional[List[str]] = None) -> int:
     ap = argparse.ArgumentParser(
         "profile_attribution", description=__doc__,
         formatter_class=argparse.RawDescriptionHelpFormatter)
-    ap.add_argument("--out", default="BENCH_attribution.json")
+    ap.add_argument("--out", default="attribution.json")
     ap.add_argument("--epochs", type=int, default=3)
     ap.add_argument("--blocks", type=int, default=2)
     ap.add_argument("--telemetry-dir", default=None,
