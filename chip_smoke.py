@@ -8,7 +8,7 @@ and data made from --seed:
     python chip_smoke.py --chips 4  # four chips: ONLY the multi-chip phase
 
   train      FFModel + build_gpt2 -> model.compile (default config: fusion
-             on, fused loss / optimizer "auto") -> cm.fit: a few steps of the
+             on, fused loss "auto") -> cm.fit: a few steps of the
              async loop, loss finite and falling on a fixed seeded dataset.
   serve      the same trained graph through compile_serving +
              ContinuousBatchingScheduler.run: every request completes, and
@@ -36,7 +36,7 @@ import time
 
 ONE_CHIP_PHASES = ("train", "serve")
 MULTI_CHIP_PHASES = ("multichip",)
-KERNELS = ("flash_attention", "fused_ce", "fused_optim", "dequant_attention")
+KERNELS = ("flash_attention", "fused_ce", "dequant_attention")
 # token parity: a served token that is not the reference argmax must be
 # within this many bf16 ulps (at the logits' scale) of the reference max
 NEAR_TIE_ULPS = 8.0
@@ -174,9 +174,9 @@ def _build(gcfg, batch: int, seed: int, init: bool = True, **cfg_kw):
     from flexflow_tpu import AdamOptimizer, FFConfig, FFModel
     from flexflow_tpu.models import build_gpt2
 
-    # default config otherwise: enable_fusion=True, fused_loss /
-    # fused_optimizer "auto". strategy_cache=False: nothing outside the
-    # committed files may steer the run.
+    # default config otherwise: enable_fusion=True, fused_loss "auto".
+    # strategy_cache=False: nothing outside the committed files may steer
+    # the run.
     cfg = FFConfig(batch_size=batch, compute_dtype="bfloat16", seed=seed,
                    strategy_cache=False, log_level="warning", **cfg_kw)
     model = FFModel(cfg)
@@ -244,7 +244,6 @@ def run_train(gcfg, batch: int, seed: int, batches: int = 4, epochs: int = 3):
     assert losses[-1] < losses[0], f"loss did not fall: {losses}"
     # every kernel the config selected is in the compiled step
     assert kernels["flash_attention"] >= 3, kernels  # fwd + dq + dkv
-    assert kernels["fused_optim"] >= 1, kernels
     assert (kernels["fused_ce"] >= 2) == bool(ce_selected), kernels
     return model, cm
 

@@ -566,22 +566,6 @@ class CompiledModel:
         fusion_on = bool(self.cfg.enable_fusion)
         from flexflow_tpu.kernels import fused_ce as _fce
 
-        # fused optimizer update (kernels/fused_optim.py): one elementwise
-        # kernel per param block instead of the optax tree_map chain —
-        # recognized Adam/SGD configs only, silent tx.update fallback in
-        # "auto" mode, hard error in "on" mode
-        fused_opt_mode = str(getattr(self.cfg, "fused_optimizer", "auto"))
-        fopt_plan = None
-        if fused_opt_mode != "off" and (fusion_on or fused_opt_mode == "on"):
-            from flexflow_tpu.kernels import fused_optim as _fopt
-
-            fopt_plan = _fopt.plan_for(self.optimizer)
-            if fused_opt_mode == "on" and fopt_plan is None:
-                raise ValueError(
-                    f"--fused-optimizer=on but "
-                    f"{type(self.optimizer).__name__} is not a recognized "
-                    f"Adam/SGD configuration")
-
         # ZeRO machinery: the moment/opt-state sharding trees are fixed by
         # (strategy, mesh, optimizer), so build them once per compile and
         # share between the jitted tx.init (see init()) and the in-step
@@ -628,21 +612,7 @@ class CompiledModel:
             memory and update flops."""
             if zero != "off":
                 grads = wsc(grads, moment_sh)
-            done = None
-            if fopt_plan is not None:
-                from flexflow_tpu.kernels import fused_optim as _fopt
-
-                done = _fopt.fused_update(fopt_plan, grads, opt_state,
-                                          params, mesh=self.mesh,
-                                          shardings=moment_sh)
-                if done is None and fused_opt_mode == "on":
-                    raise ValueError(
-                        "--fused-optimizer=on but the live optax state does "
-                        "not match the recognized optimizer plan")
-            if done is not None:
-                updates, opt_state = done
-            else:
-                updates, opt_state = tx.update(grads, opt_state, params)
+            updates, opt_state = tx.update(grads, opt_state, params)
             if zero != "off":
                 updates = wsc(updates, pshards)      # all-gather
                 opt_state = wsc(opt_state, opt_sh)   # moments stay sharded

@@ -195,21 +195,14 @@ class FFConfig:
     remat: bool = False  # DEPRECATED alias: uniform "full" policy
     remat_search: bool = False
     remat_policies: str = "none,dots,full"
-    # Pallas fusion suite gates (flexflow_tpu/kernels): "auto" uses the
-    # fused kernel when the backend/shape supports it (TPU, or interpret
-    # mode where exercised explicitly) and falls back to the reference
-    # path otherwise; "on" forces the fused path (interpret mode on CPU —
-    # tests/benches); "off" never fuses.
-    #   fused_loss      — fused cross-entropy (kernels/fused_ce.py): the
-    #                     [B,S,vocab] logits' softmax stats are computed
-    #                     blockwise (online log-sum-exp) so the loss never
-    #                     materializes the f32 logits copy
-    #   fused_optimizer — fused Adam/SGD moment update
-    #                     (kernels/fused_optim.py): one elementwise kernel
-    #                     per param block, composing with ZeRO's scattered
-    #                     moments
+    # fused cross-entropy gate (kernels/fused_ce.py): the [B,S,vocab]
+    # logits' softmax stats are computed blockwise (online log-sum-exp) so
+    # the loss never materializes the f32 logits copy. "auto" uses the
+    # kernel when the backend/shape supports it (TPU, or interpret mode
+    # where exercised explicitly) and falls back to the optax loss
+    # otherwise; "on" forces it (interpret mode on CPU — tests); "off"
+    # never fuses.
     fused_loss: str = "auto"
-    fused_optimizer: str = "auto"
     donate_state: bool = True
     # observability
     # unified telemetry (flexflow_tpu/telemetry.py): span/counter JSONL
@@ -492,8 +485,6 @@ class FFConfig:
                        default="none,dots,full")
         p.add_argument("--fused-loss", type=str, default="auto",
                        choices=("auto", "on", "off"))
-        p.add_argument("--fused-optimizer", type=str, default="auto",
-                       choices=("auto", "on", "off"))
         p.add_argument("--compgraph", dest="export_dot", type=str, default="")
         p.add_argument("--include-costs-dot-graph", action="store_true")
         p.add_argument("--serve", action="store_true")
@@ -566,6 +557,12 @@ class FFConfig:
 
             env_args = shlex.split(os.environ.get("FF_LAUNCH_ARGS", ""))
             argv = env_args + list(sys.argv[1:])
+        # parse_known_args passes unknown flags by in silence (they are the
+        # user script's): a flag of ours that is gone is refused by name
+        if any(a.split("=")[0] == "--fused-optimizer" for a in argv):
+            raise SystemExit(
+                "--fused-optimizer is gone: the optimizer update is always "
+                "tx.update + optax.apply_updates, one XLA fusion per leaf")
         args, _unknown = FFConfig.build_parser().parse_known_args(argv)
 
         mesh: Dict[str, int] = {}
@@ -634,7 +631,6 @@ class FFConfig:
             remat_search=args.remat_search,
             remat_policies=args.remat_policies,
             fused_loss=args.fused_loss,
-            fused_optimizer=args.fused_optimizer,
             export_dot=args.export_dot,
             include_costs_dot_graph=args.include_costs_dot_graph,
             serve=args.serve,

@@ -4,8 +4,6 @@ flash_attention — block-wise online-softmax attention (fwd + custom VJP),
 the cuDNN-fused-attention replacement (reference src/ops/attention.cu:35).
 fused_ce — blockwise online-logsumexp sparse cross-entropy (fwd + custom
 VJP): the loss never materializes an f32 [N, vocab] array.
-fused_optim — single-pass Adam/SGD moment update, replacing the optax
-tree_map chain while keeping its exact state layout.
 dequant_attention — fused int8-dequant + decode attention over the
 quantized paged KV cache (serving --kv-cache-dtype int8).
 """
@@ -20,8 +18,4 @@ from flexflow_tpu.kernels.flash_attention import (  # noqa: F401
 from flexflow_tpu.kernels.fused_ce import (  # noqa: F401
     fused_ce_supported,
     fused_cross_entropy,
-)
-from flexflow_tpu.kernels.fused_optim import (  # noqa: F401
-    fused_update,
-    plan_for,
 )

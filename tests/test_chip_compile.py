@@ -31,8 +31,7 @@ from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)  # chip_smoke
 
-KERNEL_MODULES = ("flash_attention", "fused_ce", "fused_optim",
-                  "dequant_attention")
+KERNEL_MODULES = ("flash_attention", "fused_ce", "dequant_attention")
 
 # GPT-2 medium: batch 8, 16 heads of 64, seq 1024, vocab padded to 50304
 B, H, S, D = 8, 16, 1024, 64
@@ -145,29 +144,6 @@ def test_fused_ce_forward_backward(one_chip, mosaic):
                         ((B * S, VOCAB_PADDED), jnp.bfloat16),
                         ((B * S,), jnp.int32))
     assert compiled.as_text().count("tpu_custom_call") >= 2
-
-
-@pytest.mark.parametrize("state_dtype", ["float32", "bfloat16"])
-def test_fused_adam(one_chip, mosaic, state_dtype):
-    import optax
-
-    from flexflow_tpu.kernels.fused_optim import fused_update, plan_for
-    from flexflow_tpu.optimizers import AdamOptimizer
-
-    opt = AdamOptimizer(alpha=1e-4, state_dtype=state_dtype)
-    plan = plan_for(opt)
-    assert plan is not None
-    sd = jnp.dtype(state_dtype)
-    shape = (1024, 4096)
-
-    def step(g, mu, nu, p, count):
-        state = (optax.ScaleByAdamState(count=count, mu={"w": mu},
-                                        nu={"w": nu}),)
-        upd, new_state = fused_update(plan, {"w": g}, state, {"w": p})
-        return upd["w"], new_state[0].mu["w"], new_state[0].nu["w"]
-
-    _compile(step, one_chip, (shape, jnp.float32), (shape, sd), (shape, sd),
-             (shape, jnp.float32), ((), jnp.int32))
 
 
 @pytest.mark.parametrize("q_tokens", [1, 5])
@@ -288,6 +264,27 @@ def _train_step_shapes(cm, label_shape):
     return params, opt, {}, ins, label, key
 
 
+_SMOKE_STEPS = {}
+
+
+def _smoke_step(cs, described_devices, chips, **cfg_kw):
+    """chip_smoke's GPT-2 medium at depth 2, default config, compiled for
+    `chips` described devices: (cm, compiled step). One compile per
+    configuration for the whole file."""
+    key = (chips, repr(sorted(cfg_kw.items())))
+    if key not in _SMOKE_STEPS:
+        described_devices(chips)
+        gcfg = cs.gpt2_medium()
+        gcfg.layers = 2
+        _, cm, _, _ = cs._build(gcfg, B, 0, init=False, **cfg_kw)
+        _SMOKE_STEPS[key] = cm, cm.train_step.lower(
+            *_train_step_shapes(cm, (B, gcfg.seq))).compile()
+    return _SMOKE_STEPS[key]
+
+
+SEARCHED_2X2 = dict(search_budget=32, mesh_shape={"data": 2, "model": 2})
+
+
 def test_searched_train_step_on_the_mesh(described_devices, mosaic):
     """The whole sharded step through the normal entry points: chip_smoke's
     GPT-2 medium at depth 2, searched on {data:2, model:2} as its --chips 4
@@ -295,29 +292,111 @@ def test_searched_train_step_on_the_mesh(described_devices, mosaic):
     or the chip's compiler refuses the program here."""
     import chip_smoke as cs
 
-    described_devices(4)
-    gcfg = cs.gpt2_medium()
-    gcfg.layers = 2
-    _, cm, _, _ = cs._build(gcfg, B, 0, init=False, search_budget=32,
-                            mesh_shape={"data": 2, "model": 2})
+    cm, compiled = _smoke_step(cs, described_devices, 4, zero_sharding="off",
+                               **SEARCHED_2X2)
     assert cm.strategy.name.startswith("unity")
-    text = cm.train_step.lower(
-        *_train_step_shapes(cm, (B, gcfg.seq))).compile().as_text()
-    kernels = cs.kernels_in(text)
-    assert kernels["flash_attention"] >= 3 * gcfg.layers, kernels
-    assert kernels["fused_optim"] >= 1, kernels
+    text = compiled.as_text()
+    assert cs.kernels_in(text)["flash_attention"] >= 3 * 2
     assert sum(cs.collectives_in(text).values()) > 0
+
+
+def _elements(ty):
+    """Element count of the (first) array of an HLO result type, or None."""
+    shape = re.search(r"\[([\d,]+)\]", ty)
+    return None if shape is None else int(
+        np.prod([int(d) for d in shape.group(1).split(",")]))
+
+
+def _whole_weight_relayouts(text, cm):
+    """`copy` / `reshape` / `transpose` of the compiled step's entry
+    computation whose f32 result is as large as a whole weight matrix (the
+    smallest per-device shard of a parameter leaf of two or more dims, as
+    the parameters or the moments lie). The forward and backward hold their
+    weights in bf16, so an f32 array of that size is a parameter, a moment,
+    a gradient or an update: the optimizer's. `copy-start` is let through:
+    the compiler's own prefetch into its alternate memory, no relayout."""
+    pshapes, pshards = cm._param_templates()
+    sizes = [int(np.prod(sh.shard_shape(s.shape)))
+             for shards in (pshards, cm._moment_sh)
+             for s, sh in zip(jax.tree_util.tree_leaves(pshapes),
+                              jax.tree_util.tree_leaves(shards))
+             if len(s.shape) >= 2]
+    return [(op, ty) for op, ty in _entry_ops(text)
+            if op in ("copy", "reshape", "transpose")
+            and ty.startswith("f32[") and _elements(ty) >= min(sizes)]
+
+
+def test_the_update_passes_over_each_leaf_as_it_lies(described_devices,
+                                                     mosaic):
+    """One chip, the default training step: the optimizer update is the
+    optax chain fused by XLA, one pass per leaf in place. No Mosaic call of
+    an update kernel, and no whole-weight f32 `reshape` / `copy` /
+    `transpose` anywhere in the step (with the per-leaf Pallas kernel this
+    program held 108 of them at depth 2: seven around each call, the
+    `reshape` + `copy` 36 ms of GPT-2 medium's 244 ms step), and every
+    parameter and moment aliased onto an output."""
+    import chip_smoke as cs
+
+    cm, compiled = _smoke_step(cs, described_devices, 1)
+    text = compiled.as_text()
+    assert "ff_fused_optim" not in text
+    assert cs.kernels_in(text)["flash_attention"] >= 6
+    assert _whole_weight_relayouts(text, cm) == []
+    pshapes, _ = cm._param_templates()
+    held = 3 * sum(s.size * s.dtype.itemsize
+                   for s in jax.tree_util.tree_leaves(pshapes))
+    assert compiled.memory_analysis().alias_size_in_bytes >= held
+
+
+@pytest.mark.parametrize("zero,collectives", [
+    pytest.param("off", {"all-reduce": 28, "all-gather": 27}, id="searched"),
+    pytest.param("zero1", {"all-reduce": 37, "all-gather": 73,
+                           "collective-permute": 9}, id="searched-zero1"),
+])
+def test_the_update_on_the_mesh_adds_no_collective(described_devices, mosaic,
+                                                   zero, collectives):
+    """The same on {data:2, model:2}, searched, without and with ZeRO-1:
+    GSPMD partitions the fused update by the moments' own specs (the
+    per-leaf kernel ran under 37 `shard_map`s here). `collectives` is what
+    the step held WITH the kernel (PR 31's parent, this compile): the
+    optax path may not add one. Under ZeRO-1 the moments come back in
+    `moment_sh`, split over `data` where the parameters are not."""
+    import chip_smoke as cs
+
+    cm, compiled = _smoke_step(cs, described_devices, 4, zero_sharding=zero,
+                               **SEARCHED_2X2)
+    assert cm.strategy.name.startswith("unity")
+    text = compiled.as_text()
+    assert "ff_fused_optim" not in text
+    assert _whole_weight_relayouts(text, cm) == []
+    got = cs.collectives_in(text)
+    assert all(got[op] <= collectives.get(op, 0) for op in got), got
+    if zero == "off":       # the step pins its state's layout under ZeRO only
+        return
+    out_params, out_opt = compiled.output_shardings[:2]
+    mu = out_opt[0].mu                 # optax.adam: (ScaleByAdamState, Empty)
+    leaves = jax.tree_util.tree_leaves
+    split_more = 0
+    for got_sh, want_sh, param_sh, s in zip(
+            leaves(mu), leaves(cm._moment_sh), leaves(out_params),
+            leaves(cm._param_templates()[0])):
+        assert got_sh.is_equivalent_to(want_sh, len(s.shape)), (got_sh, want_sh)
+        split_more += (got_sh.shard_shape(s.shape)
+                       != param_sh.shard_shape(s.shape))
+    assert split_more > 0
 
 
 def test_remat_shrinks_the_compiled_steps_temp_memory(described_devices):
     """Per-layer jax.checkpoint shrinks the live temp buffers of the
     COMPILED train step of a chain of eight dense layers: asked of the
-    chip's compiler (XLA:CPU reports the same figure both ways)."""
+    chip's compiler (XLA:CPU reports the same figure both ways). At 32 MB
+    an activation: the compiler keeps a chain of 1 MB ones out of its
+    temporaries altogether, and reports 2 644 992 B with and without."""
     from flexflow_tpu import FFConfig, FFModel, SGDOptimizer
     from flexflow_tpu.losses import LossType
 
     described_devices(1)
-    batch, hidden, layers = 1024, 256, 8
+    batch, hidden, layers = 8192, 1024, 8
     temp = {}
     for remat in (False, True):
         m = FFModel(FFConfig(batch_size=batch, only_data_parallel=True,
@@ -332,7 +411,7 @@ def test_remat_shrinks_the_compiled_steps_temp_memory(described_devices):
         compiled = cm.train_step.lower(
             *_train_step_shapes(cm, (batch,))).compile()
         temp[remat] = compiled.memory_analysis().temp_size_in_bytes
-    assert 0 < temp[True] < temp[False], temp
+    assert 0 < temp[True] < 0.7 * temp[False], temp
 
 
 def _described_engine(cell_name, described_devices, monkeypatch, one_chip):
@@ -441,10 +520,8 @@ def _assert_appends_in_place(program, eng):
     size = pools[0].size         # slots * pages_per_slot + 1 pages: both
     moved, staged = [], 0
     for op, ty in _entry_ops(program.as_text()):
-        shape = re.search(r"\[([\d,]+)\]", ty)
         if op not in ("copy", "copy-start", "reshape", "transpose") \
-                or shape is None or not 0.9 * size <= np.prod(
-                    [int(d) for d in shape.group(1).split(",")]) <= size:
+                or not 0.9 * size <= (_elements(ty) or 0) <= size:
             continue
         layouts = re.findall(r"\{[^}]*\}", ty)     # copy-start: dest, source
         if op == "copy-start" and "S(1)" in layouts[1] \

@@ -410,24 +410,22 @@ def test_remat_and_fused_kernel_flags_wired():
     """The ISSUE-12 MFU knobs flow parse_args -> FFConfig via
     build_parser only (launcher value-flag coverage is derived):
     --remat-search/--remat-policies select the searched-remat dimension,
-    --fused-loss/--fused-optimizer gate the pallas fusion suite, and the
+    --fused-loss gates the fused cross-entropy, and the
     deprecated --remat alias survives but cannot combine with the
     search."""
     from flexflow_tpu.config import FFConfig as Cfg
 
     cfg = Cfg.parse_args(["--remat-search", "--remat-policies",
-                          "none,dots", "--fused-loss", "on",
-                          "--fused-optimizer", "off"])
+                          "none,dots", "--fused-loss", "on"])
     assert cfg.remat_search is True
     assert cfg.remat_policies == "none,dots"
     assert cfg.remat_policy_list() == ("none", "dots")
     assert cfg.fused_loss == "on"
-    assert cfg.fused_optimizer == "off"
-    # defaults: remat fully off, fused kernels in auto mode
+    # defaults: remat fully off, the fused loss in auto mode
     d = Cfg()
     assert (d.remat, d.remat_search) == (False, False)
     assert d.remat_policy_list() == ("none", "dots", "full")
-    assert (d.fused_loss, d.fused_optimizer) == ("auto", "auto")
+    assert d.fused_loss == "auto"
     # deprecated alias still parses on its own
     assert Cfg.parse_args(["--remat"]).remat is True
     # ...but contradicts the searched dimension, loudly
@@ -440,5 +438,5 @@ def test_remat_and_fused_kernel_flags_wired():
     with pytest.raises(SystemExit):
         Cfg.parse_args(["--fused-loss", "maybe"])
     vf = Cfg.launcher_value_flags()
-    for flag in ("--remat-policies", "--fused-loss", "--fused-optimizer"):
+    for flag in ("--remat-policies", "--fused-loss"):
         assert flag in vf, flag

@@ -150,3 +150,29 @@ def test_e2e_loss_parity_on_sharded_mesh(devices):
     base = _fit(devices, "off")
     fused = _fit(devices, "on")
     assert np.allclose(base, fused, atol=1e-5, rtol=1e-5)
+
+
+def test_fused_loss_trains_gpt2_to_the_optax_loss(devices):
+    """Through `fit`, not the kernel alone: a one-block GPT-2 under Adam
+    with the fused cross-entropy forced on ("on" raises where the kernel is
+    not taken) ends two epochs on the loss of the optax loss, within
+    1e-5."""
+    from flexflow_tpu import AdamOptimizer
+    from flexflow_tpu.models import GPT2Config, build_gpt2
+
+    rng = np.random.default_rng(0)
+    ids = rng.integers(0, 512, size=(128, 16)).astype(np.int32)
+    pos = np.broadcast_to(np.arange(16, dtype=np.int32), (128, 16)).copy()
+    y = rng.integers(0, 512, size=(128, 16)).astype(np.int32)
+
+    def final_loss(mode):
+        m = FFModel(FFConfig(batch_size=8, only_data_parallel=True, seed=3,
+                             fused_loss=mode, log_level="warning"))
+        build_gpt2(m, GPT2Config(vocab=512, seq=16, d_model=64, heads=2,
+                                 layers=1, dropout=0.0), batch=8)
+        cm = m.compile(AdamOptimizer(alpha=1e-3),
+                       LossType.SPARSE_CATEGORICAL_CROSSENTROPY, metrics=[])
+        cm.init(seed=0)
+        return cm.fit([ids, pos], y, epochs=2, verbose=False)[-1]["loss"]
+
+    assert final_loss("on") == pytest.approx(final_loss("off"), abs=1e-5)
