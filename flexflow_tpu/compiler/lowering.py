@@ -96,6 +96,8 @@ def build_forward(
     for _l in layers:
         if _l.op_type in _norm_types:
             cast_exempt[_l.name] = set(_l.weight_specs)
+        elif get_op_def(_l.op_type).uncast_weights:
+            cast_exempt[_l.name] = set(get_op_def(_l.op_type).uncast_weights)
         elif _l.op_type is _OT.FORK_JOIN:
             ex = set()
             for bi, (bls, _bx, _bo) in enumerate(_l.branches):

@@ -396,18 +396,70 @@ class FFModel:
                   expert_width: int, experts_held=None,
                   valid: Optional[Tensor] = None,
                   initializers: Optional[Dict[str, Any]] = None,
-                  name=None) -> Tensor:
+                  scoring: Optional[str] = None, n_group: int = 0,
+                  topk_group: int = 0, norm_topk_prob: bool = False,
+                  routed_scaling_factor: Optional[float] = None,
+                  score_bias: bool = False, name=None) -> Tensor:
         """Dropless top-k layer of gated-SiLU experts over `[batch, seq,
         d]`, routed over all `num_experts`, computing those in
-        `experts_held = (lo, hi)` (default: all); ops/moe_ops.py."""
+        `experts_held = (lo, hi)` (default: all); ops/moe_ops.py. How it
+        chooses and gates beyond top-k + softmax (`scoring` "sigmoid", the
+        choice limited to `topk_group` of `n_group` groups, gates
+        normalised and scaled, a `score_bias` weight for the selection;
+        `moe_ops._choose`) enters the params only where set, so graphs
+        without them keep their fingerprints."""
         lo, hi = experts_held if experts_held is not None else (0, num_experts)
         ins = [input] + ([valid] if valid is not None else [])
-        return self._add_layer(
-            OperatorType.MOE_LAYER,
-            {"num_experts": int(num_experts), "top_k": int(top_k),
-             "expert_width": int(expert_width),
-             "experts_held": (int(lo), int(hi))},
-            ins, name, initializers)[0]
+        params = {"num_experts": int(num_experts), "top_k": int(top_k),
+                  "expert_width": int(expert_width),
+                  "experts_held": (int(lo), int(hi))}
+        if scoring is not None:
+            params["scoring"] = str(scoring)
+        if n_group:
+            params.update(n_group=int(n_group), topk_group=int(topk_group))
+        if norm_topk_prob:
+            params["norm_topk_prob"] = True
+        if routed_scaling_factor is not None:
+            params["routed_scaling_factor"] = float(routed_scaling_factor)
+        if score_bias:
+            params["score_bias"] = True
+        return self._add_layer(OperatorType.MOE_LAYER, params, ins, name,
+                               initializers)[0]
+
+    def latent_attention(self, input: Tensor, positions: Tensor, heads: int,
+                         q_lora_rank: int, kv_lora_rank: int,
+                         qk_nope_head_dim: int, qk_rope_head_dim: int,
+                         v_head_dim: int, eps: float = 1e-6,
+                         rope_theta: float = 10000.0,
+                         rope_scaling: Optional[Dict[str, Any]] = None,
+                         valid: Optional[Tensor] = None, impl: str = "auto",
+                         initializers: Optional[Dict[str, Any]] = None,
+                         name=None) -> Tensor:
+        """Multi-head latent attention over `[batch, seq, d]` with rotary
+        `positions` `[batch, seq]` (ops/latent_attention_ops.py).
+        `rope_scaling`: a YaRN dict with Hugging Face's keys (factor,
+        original_max_position_embeddings, beta_fast, beta_slow, mscale,
+        mscale_all_dim), or None for plain rotary frequencies. `valid`
+        `[batch, seq]` int: which positions hold a token (counters only)."""
+        params = {"heads": int(heads), "q_lora_rank": int(q_lora_rank),
+                  "kv_lora_rank": int(kv_lora_rank),
+                  "qk_nope_head_dim": int(qk_nope_head_dim),
+                  "qk_rope_head_dim": int(qk_rope_head_dim),
+                  "v_head_dim": int(v_head_dim), "eps": float(eps),
+                  "rope_theta": float(rope_theta), "impl": impl}
+        if rope_scaling:
+            params.update(
+                rope_factor=float(rope_scaling["factor"]),
+                rope_original_len=int(
+                    rope_scaling["original_max_position_embeddings"]),
+                rope_beta_fast=float(rope_scaling.get("beta_fast", 32)),
+                rope_beta_slow=float(rope_scaling.get("beta_slow", 1)),
+                rope_mscale=float(rope_scaling.get("mscale", 1)),
+                rope_mscale_all_dim=float(
+                    rope_scaling.get("mscale_all_dim", 0)))
+        ins = [input, positions] + ([valid] if valid is not None else [])
+        return self._add_layer(OperatorType.LATENT_ATTENTION, params, ins,
+                               name, initializers)[0]
 
     def cache(self, input: Tensor, num_batches: int = 1, name=None) -> Tensor:
         return self._add_layer(OperatorType.CACHE, {"num_batches": num_batches}, [input], name)[0]

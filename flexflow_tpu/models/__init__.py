@@ -12,6 +12,8 @@ from flexflow_tpu.models.transformer import build_transformer
 from flexflow_tpu.models.gpt2 import build_gpt2, GPT2Config
 from flexflow_tpu.models.granite_hybrid import (GraniteHybridConfig,
                                                 build_granite_hybrid)
+from flexflow_tpu.models.deepseek_v3 import (DeepseekV3Config,
+                                             build_deepseek_v3)
 from flexflow_tpu.models.bert import build_bert
 from flexflow_tpu.models.moe import build_moe_mlp
 from flexflow_tpu.models.inception import build_inception_v3
@@ -25,4 +27,5 @@ __all__ = [
     "build_dlrm", "build_transformer", "build_gpt2", "GPT2Config",
     "build_bert", "build_moe_mlp", "build_inception_v3",
     "build_granite_hybrid", "GraniteHybridConfig",
+    "build_deepseek_v3", "DeepseekV3Config",
 ]

@@ -22,6 +22,7 @@ from flexflow_tpu.ops import (  # noqa: F401
     attention_ops,
     moe_ops,
     ssm_ops,
+    latent_attention_ops,
     parallel_ops,
     fork_join,
 )

@@ -417,8 +417,17 @@ def _mha_serving_params(params: dict, kind: str) -> dict:
     return dict(params, dropout=0.0, kv_out=True)
 
 
+def _mha_page_state(layer: Layer) -> dict:
+    """A token's rows in this layer's K and V pools: the K/V heads (fewer
+    than the query heads where they are grouped) of head_dim each."""
+    p = layer.params
+    return {"heads": _kv_heads(p),
+            "head_dim": int(p["embed_dim"]) // int(p["num_heads"])}
+
+
 register_op(OperatorType.MULTIHEAD_ATTENTION, _mha_infer, _mha_lower, _mha_flops,
-            serving_params=_mha_serving_params, state_kind="paged_kv")
+            serving_params=_mha_serving_params, state_kind="paged_kv",
+            page_state=_mha_page_state)
 
 
 def _sdpa_infer(layer: Layer):

@@ -146,6 +146,17 @@ def _moe_layer_infer(layer: Layer):
         "w_in": TensorSpec((hi - lo, d, 2 * width), x.dtype),
         "w_out": TensorSpec((hi - lo, width, d), x.dtype),
     }
+    if p.get("scoring", "softmax") not in ("softmax", "sigmoid"):
+        raise ValueError(f"moe_layer scoring {p['scoring']!r}")
+    groups = p.get("n_group", 0)
+    if groups and (p["num_experts"] % groups
+                   or not 0 < p.get("topk_group", 0) <= groups):
+        raise ValueError(f"moe_layer: {groups} groups over "
+                         f"{p['num_experts']} experts, topk_group "
+                         f"{p.get('topk_group')}")
+    if p.get("score_bias"):
+        layer.weight_specs["score_bias"] = TensorSpec((p["num_experts"],),
+                                                      DataType.FLOAT)
     return [x]
 
 
@@ -153,6 +164,49 @@ def _moe_layer_infer(layer: Layer):
 # many (lax.map), so that the `tokens * k` row buffers of the grouped
 # product stay a fraction of a prefill wave's
 MOE_TOKEN_BLOCK = 4096
+
+
+def _choose(scores, weights, p):
+    """(gates `[tokens, k]` f32, experts `[tokens, k]`) from the router's
+    scores of ALL experts `[tokens, E]` f32. As granite routes (no key of
+    the ones below set): the top k of the scores, a softmax over those k.
+    Where set (DeepSeek-V3's `noaux_tc`):
+    - `scoring` "sigmoid": an expert's score is sigmoid(x W_r);
+    - `score_bias`: a weight `[E]` added to the scores for the SELECTION
+      only (the gates use the scores without it);
+    - `n_group`, `topk_group`: experts lie in `n_group` groups of
+      consecutive ids; a group's score is the sum of its two largest
+      selection scores, and only the `topk_group` best groups' experts can
+      be chosen;
+    - `norm_topk_prob`: the k gates are divided by their sum;
+    - `routed_scaling_factor`: and multiplied by this."""
+    k = p["top_k"]
+    sigmoid = p.get("scoring") == "sigmoid"
+    groups = p.get("n_group", 0)
+    if not (sigmoid or groups or "score_bias" in weights):
+        top, experts = jax.lax.top_k(scores, k)
+        gate = jax.nn.softmax(top, axis=-1)
+    else:
+        own = jax.nn.sigmoid(scores) if sigmoid else scores
+        choice = own + weights["score_bias"].astype(jnp.float32) \
+            if "score_bias" in weights else own
+        if groups:
+            tokens, n = choice.shape
+            best2 = jax.lax.top_k(choice.reshape(tokens, groups, n // groups),
+                                  2)[0]
+            _, kept = jax.lax.top_k(jnp.sum(best2, axis=-1), p["topk_group"])
+            open_ = jnp.any(kept[:, :, None] == jnp.arange(groups)[None, None],
+                            axis=1)                            # [tokens, groups]
+            choice = jnp.where(jnp.repeat(open_, n // groups, axis=1), choice,
+                               -jnp.inf)
+        _, experts = jax.lax.top_k(choice, k)
+        picked = jnp.take_along_axis(own, experts, axis=-1)
+        gate = picked if sigmoid else jax.nn.softmax(picked, axis=-1)
+    if p.get("norm_topk_prob"):
+        gate = gate / (jnp.sum(gate, axis=-1, keepdims=True) + 1e-20)
+    if p.get("routed_scaling_factor"):
+        gate = gate * float(p["routed_scaling_factor"])
+    return gate, experts
 
 
 def _route_tokens(xt, exists, weights, p):
@@ -167,8 +221,7 @@ def _route_tokens(xt, exists, weights, p):
     scores = jnp.dot(xt.astype(jnp.float32),
                      weights["router"].astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
-    top, experts = jax.lax.top_k(scores, k)                    # [tokens, k]
-    gate = jax.nn.softmax(top, axis=-1)
+    gate, experts = _choose(scores, weights, p)                # [tokens, k]
     held = (experts >= lo) & (experts < hi) & exists
     local = jnp.where(held, experts - lo, held_n).reshape(-1)  # absent: last
     order = jnp.argsort(local, stable=True)
@@ -198,7 +251,8 @@ def _moe_layer_lower(layer: Layer, inputs, weights, ctx):
 
     The router scores ALL `num_experts` in f32 (the matmul at HIGHEST
     precision: a lower one moves the k-th and (k+1)-th scores past each
-    other), takes the top k and a softmax over those k in f32. Each (token,
+    other), takes the top k and a softmax over those k in f32, or chooses
+    and gates as the layer's params say (`_choose`). Each (token,
     choice) whose expert is held here is computed; the others contribute
     nothing, so the result is this holder's part of the layer's output
     (the parts of all holders add up to the whole layer). Rows are sorted
@@ -252,4 +306,4 @@ def _moe_layer_flops(layer: Layer):
 
 
 register_op(OperatorType.MOE_LAYER, _moe_layer_infer, _moe_layer_lower,
-            _moe_layer_flops)
+            _moe_layer_flops, uncast_weights=("score_bias",))

@@ -99,6 +99,9 @@ class OperatorType(enum.Enum):
     MOE_LAYER = "moe_layer"
     # state-space mixer (Mamba-2, ops/ssm_ops.py)
     MAMBA2 = "mamba2"
+    # multi-head latent attention (low-rank q and K/V, rotary positions on a
+    # slice, a latent cache; ops/latent_attention_ops.py)
+    LATENT_ATTENTION = "latent_attention"
     # fused compute op (reference: src/ops/fused.cc)
     FUSED = "fused"
     # inter-op placement composite (reference: nonsequence splits,
@@ -133,6 +136,7 @@ WEIGHTED_OPS = frozenset(
         OperatorType.EXPERTS,
         OperatorType.MOE_LAYER,
         OperatorType.MAMBA2,
+        OperatorType.LATENT_ATTENTION,
         OperatorType.FORK_JOIN,
     }
 )

@@ -79,11 +79,19 @@ class OpDef:
     # serving_params(params, kind) -> the params of its prefill / decode
     # twin (kind "prefill" | "decode"; None: the layer's own);
     # state_kind: the per-request state it carries ("paged_kv": K/V pages
-    # of the paged pool, "recurrent": fixed-size per-slot arrays);
+    # of the paged pool, "paged_latent": pages of ONE pool a layer whose
+    # rows are a token's latent, "recurrent": fixed-size per-slot arrays);
+    # page_state(layer) -> what a token's row holds, for the paged kinds
+    # ({"heads", "head_dim"} of the K/V pools, {"latent_dim"} of a latent
+    # pool): the cache's geometry comes from the layers' own declarations;
     # slot_state(layer) -> {name: (per-slot shape, dtype)} for "recurrent"
     serving_params: Optional[Callable[[Dict[str, Any], str], Dict[str, Any]]] = None
     state_kind: Optional[str] = None
+    page_state: Optional[Callable[[Layer], Dict[str, int]]] = None
     slot_state: Optional[Callable[[Layer], Dict[str, Any]]] = None
+    # weights that keep their own type under a compute_dtype (a selection
+    # bias whose size is that of the gaps it decides)
+    uncast_weights: tuple = ()
 
     def flop_count(self, layer: Layer) -> float:
         if self.flops is not None:
@@ -96,10 +104,12 @@ _REGISTRY: Dict[OperatorType, OpDef] = {}
 
 # where build_forward(collect_stats=True) puts LoweringCtx.stats
 STATS_KEY = "serve/stats"
+# the kinds of per-request state that live in pages of the paged pools
+PAGED_STATE_KINDS = ("paged_kv", "paged_latent")
 
 
-def register_op(op_type: OperatorType, infer, lower, flops=None, **serving) -> OpDef:
-    d = OpDef(infer=infer, lower=lower, flops=flops, **serving)
+def register_op(op_type: OperatorType, infer, lower, flops=None, **extra) -> OpDef:
+    d = OpDef(infer=infer, lower=lower, flops=flops, **extra)
     _REGISTRY[op_type] = d
     return d
 

@@ -102,6 +102,12 @@ class KVCacheSpec:
     # SSM state and conv tail, summed over those layers): resident for
     # every slot, read and written by every decode step, never paged
     state_bytes_per_slot: int = 0
+    # a LATENT cache (multi-head latent attention): a layer has ONE pool
+    # whose rows are a token's `latent_dim` values, with no heads axis and
+    # no V pool (0 = the K and V pools of `heads` x `head_dim` above, which
+    # then say nothing and are 0); at rest a row is padded to whole lanes
+    # (`row_widths`), and every byte count here is of what is stored
+    latent_dim: int = 0
 
     @property
     def padded_len(self) -> int:
@@ -114,9 +120,21 @@ class KVCacheSpec:
         host tier shrinks HBM, else every slot's worth) plus scratch."""
         return (self.device_pages or self.slots * self.pages_per_slot) + 1
 
+    def row_widths(self) -> dict:
+        """{leaf: values a token's row holds} of a layer's pools, as they
+        lie at rest (`[pool_pages, page_size, width]` each)."""
+        if self.latent_dim:
+            # whole lanes (576 -> 640), so that the pool is row-major by
+            # default and no step relays it: serving/kv_cache.py says why
+            return {"latent": -(-self.latent_dim // 128) * 128}
+        return {"k": self.heads * self.head_dim, "v": self.heads * self.head_dim}
+
     def page_bytes(self) -> int:
         """K + V bytes of ONE page of ONE layer (the unit the tier moves:
-        spill/prefetch copy whole pages, values plus quantized scales)."""
+        spill/prefetch copy whole pages, values plus quantized scales); of
+        a latent cache, the bytes of its one pool's page."""
+        if self.latent_dim:
+            return self.page_size * self.row_widths()["latent"] * self.itemsize
         return (2 * self.page_size * self.heads
                 * (self.head_dim * self.itemsize + self.scale_itemsize))
 
@@ -157,7 +175,8 @@ class KVCacheSpec:
         # only where there is such state: the keys of caches that hold
         # strategies of models without it stay what they were
         return fp + ((self.state_bytes_per_slot,)
-                     if self.state_bytes_per_slot else ())
+                     if self.state_bytes_per_slot else ()) \
+            + (("latent", self.latent_dim) if self.latent_dim else ())
 
 
 def zero_divisor(spec: TensorSpec, dims: Sequence[DimSharding],

@@ -21,6 +21,8 @@ from flexflow_tpu.serving.reqtrace import (RequestTracer, StreamingHistogram,
 from flexflow_tpu.serving.scheduler import (ContinuousBatchingScheduler,
                                             Request, gpt2_prompt_inputs,
                                             gpt2_step_inputs,
+                                            positions_valid_prompt_inputs,
+                                            positions_valid_step_inputs,
                                             valid_prompt_inputs,
                                             valid_step_inputs)
 from flexflow_tpu.serving.tracefmt import (Trace, TraceRecord, load_trace,
@@ -33,6 +35,7 @@ __all__ = [
     "ContinuousBatchingScheduler", "Request", "clone_for_serving",
     "serving_optimize", "gpt2_prompt_inputs", "gpt2_step_inputs",
     "valid_prompt_inputs", "valid_step_inputs",
+    "positions_valid_prompt_inputs", "positions_valid_step_inputs",
     "PAGE_TABLE_KEY", "POS_KEY", "ACTIVE_KEY",
     "RequestTracer", "StreamingHistogram", "TERMINAL_FIELDS",
     "terminal_record",

@@ -69,7 +69,8 @@ from flexflow_tpu.search import cost_model as cm
 from flexflow_tpu.serving.kv_cache import (ACTIVE_KEY, POS_KEY, PagedKVCache,
                                            _tree_bytes)
 from flexflow_tpu.serving.program import (attn_head_degree, clone_for_serving,
-                                          recurrent_layers, serving_optimize)
+                                          page_geometry, recurrent_layers,
+                                          serving_optimize)
 
 log = logging.getLogger("flexflow_tpu")
 
@@ -158,17 +159,11 @@ def compile_serving(model, max_batch_slots: Optional[int] = None,
                  else getattr(cfg, "serve_spec_tokens", 0) or 0)
     kv_dtype, kv_itemsize, kv_scale_itemsize, kv_quantized = \
         _resolve_kv_dtype(cfg, kv_cache_dtype)
-    attn_params = [l.params for l in model.layers
-                   if l.op_type is OperatorType.MULTIHEAD_ATTENTION]
-    if not attn_params:
-        raise ValueError("compile_serving needs a model with attention "
-                         "layers (nothing to cache)")
-    embed = int(attn_params[0]["embed_dim"])
-    head_dim = embed // int(attn_params[0]["num_heads"])
-    # the pools hold the K/V heads: fewer than the query heads where they
-    # are grouped
-    heads = int(attn_params[0].get("num_kv_heads")
-                or attn_params[0]["num_heads"])
+    # what a token's row holds in the pools, as the layers that page
+    # declare it: the K/V heads (fewer than the query heads where they are
+    # grouped) of head_dim each, or a latent
+    geometry = page_geometry(model)
+    latent = "latent_dim" in geometry
     seq = int(model.input_tensors[0].spec.shape[1])
     if draft is None and spec_k > 0 and getattr(cfg, "serve_draft_model", ""):
         draft = _draft_from_spec(cfg, cfg.serve_draft_model,
@@ -202,8 +197,24 @@ def compile_serving(model, max_batch_slots: Optional[int] = None,
                     f"compile_serving: the model has {len(recurrent)} "
                     f"{kind.value} layers with per-slot recurrent state, "
                     "which do not support " + "; ".join(unsupported))
+        if latent:
+            unsupported = [
+                what for what, asked in (
+                    ("the host KV tier (--kv-host-pages)",
+                     int(getattr(cfg, "kv_host_pages", 0) or 0) > 0),
+                    ("speculative decoding", draft is not None and spec_k > 0),
+                    ("a quantized cache (--kv-cache-dtype int8)",
+                     kv_quantized))
+                if asked]
+            if unsupported:
+                raise NotImplementedError(
+                    f"compile_serving: the model's {len(attn)} layers page "
+                    "paged_latent state (a token's latent, no heads axis), "
+                    "which does not support " + "; ".join(unsupported)
+                    + " yet")
         compile_span.set(kv_layers=len(attn), state_layers=len(recurrent),
-                         state_bytes_per_slot=state_bytes)
+                         state_bytes_per_slot=state_bytes,
+                         paged_state="paged_latent" if latent else "paged_kv")
         for l in model.layers:
             if l.op_type is OperatorType.MOE_LAYER:
                 lo, hi = l.params["experts_held"]
@@ -224,7 +235,9 @@ def compile_serving(model, max_batch_slots: Optional[int] = None,
         prefetch_ahead = max(1, int(getattr(cfg, "kv_prefetch_ahead", 2)
                                     or 2))
         kv_spec = cm.KVCacheSpec(
-            layers=len(attn), heads=heads, head_dim=head_dim,
+            layers=len(attn), heads=int(geometry.get("heads", 0)),
+            head_dim=int(geometry.get("head_dim", 0)),
+            latent_dim=int(geometry.get("latent_dim", 0)),
             slots=slots, pages_per_slot=pages_per_slot,
             page_size=page, itemsize=kv_itemsize,
             scale_itemsize=kv_scale_itemsize,
@@ -277,7 +290,8 @@ def compile_serving(model, max_batch_slots: Optional[int] = None,
         # the in-place append's engagement: the pool as it lies at rest and
         # the bytes of the state leaves a decode step is told to donate
         compile_span.set(
-            kv_pool_shape=list(engine.kv.state[attn[0]]["k"].shape),
+            kv_pool_shape=list(
+                next(iter(engine.kv.state[attn[0]].values())).shape),
             decode_state_donated_bytes=_tree_bytes(engine.kv.state))
         return engine
 

@@ -43,6 +43,25 @@ decode program as lowering state (`compile.build_forward`'s state →
 new_state channel): `state[layer_name] = {"k", "v"}`,
 `state["serve/page_table"]`, `state["serve/pos"]`, `state["serve/active"]`.
 
+A LATENT cache (multi-head latent attention, `spec.latent_dim` set) is the
+same paging with other rows: a layer has ONE pool `[pool_pages, page_size,
+latent_dim]`, `state[layer_name] = {"latent": ...}`, whose row is what a
+token leaves behind for later ones (its K/V latent and the shared rotary
+key: 576 values for DeepSeek-V3's widths, not 64 heads x 384). No heads
+axis, so nothing to shard the pool over (it is replicated), and no V pool.
+At rest the row is one axis as a K/V row is, padded with zeros to whole
+lanes (576 -> 640, `KVCacheSpec.row_widths`): 576 is no multiple of 128, and
+the chip's default layout of a `[pages, page, 576]` array puts the page
+index in the lanes, so that every step relaid every pool for its scatter
+(12 whole-pool copies a step in the described chip's compile), the case
+above; splitting it 512 + 64 would leave a 64-wide pool with the same
+fault. 11 % of a pool that is a hundredth of the weights buys row-major
+pools that scatter, gather and the in-place append find as they find K/V
+rows. Writers pad the rows they hand in (`pad_row`); the decode attention
+reads the gathered rows as they lie (its query side carries zeros there).
+What it does not support yet raises NotImplementedError: a quantized pool,
+the host tier, park/spill, the hand-off and speculative roll-back.
+
 Recurrent layers (a state-space mixer) keep the other kind of per-request
 state in the same manager: fixed-size arrays per slot, `state[layer_name] =
 {leaf: [slots, ...]}` (an SSM state and a conv tail), never paged.
@@ -119,6 +138,14 @@ class KVPoolExhausted(Exception):
         self.have = have
 
 
+def pad_row(rows, width: int):
+    """Token rows `[.., n]` as a pool `[pages, page, width]` holds them:
+    zeros up to `width` (a latent row's whole lanes)."""
+    short = width - rows.shape[-1]
+    return rows if short == 0 else jnp.pad(
+        rows, [(0, 0)] * (rows.ndim - 1) + [(0, short)])
+
+
 def merge_heads(x):
     """`[.., heads, head_dim]` token rows as the pools hold them:
     `[.., heads * head_dim]`, heads-major."""
@@ -127,18 +154,19 @@ def merge_heads(x):
 
 @functools.partial(jax.jit, donate_argnums=(0,))
 def _commit_prefill(cache_state, kv_state, slot_ids, lengths):
-    """Scatter prefilled per-head K/V (`[Bp, S, h, d]` per layer, from the
-    prefill program's kv_out state) into the pools of the slots in
-    `slot_ids`, in place: `cache_state` is donated. Positions >= lengths[r]
-    (right padding) and positions past the slot's allocated pages are
-    routed to the scratch page."""
+    """Scatter the prefill program's rows (per layer `{leaf: [Bp, S, h, d]}`
+    per-head K/V from kv_out, or `[Bp, S, width]` rows as the pool holds
+    them: a latent) into the pools of the slots in `slot_ids`, in place:
+    `cache_state` is donated. Positions >= lengths[r] (right padding) and
+    positions past the slot's allocated pages are routed to the scratch
+    page."""
     new = dict(cache_state)
     pt = cache_state[PAGE_TABLE_KEY]
     for name, kv in kv_state.items():
-        kh, vh = kv["k"], kv["v"]
-        pool_k = cache_state[name]["k"]
-        page = pool_k.shape[1]
-        s = kh.shape[1]
+        pools = cache_state[name]
+        first = next(iter(kv.values()))
+        page = next(iter(pools.values())).shape[1]
+        s = first.shape[1]
         pages = pt[slot_ids]                      # [Bp, pages_per_slot]
         t = jnp.arange(s)
         pg = t // page                            # [S]
@@ -148,24 +176,23 @@ def _commit_prefill(cache_state, kv_state, slot_ids, lengths):
         valid = t[None, :] < lengths[:, None]
         pageix = jnp.where(valid, pageix, 0)      # padding -> scratch
         off = jnp.broadcast_to(t % page, pageix.shape)
-        if "k_scale" in cache_state[name]:
+        if "k_scale" in pools:
             # quantized pools: scatter int8 values + per-(entry, head) scales
-            qk, ks = kv_quantize(kh)
-            qv, vs = kv_quantize(vh)
+            qk, ks = kv_quantize(kv["k"])
+            qv, vs = kv_quantize(kv["v"])
             new[name] = {
-                "k": pool_k.at[pageix, off].set(merge_heads(qk)),
-                "v": cache_state[name]["v"].at[pageix, off].set(
-                    merge_heads(qv)),
-                "k_scale": cache_state[name]["k_scale"].at[pageix, off].set(ks),
-                "v_scale": cache_state[name]["v_scale"].at[pageix, off].set(vs),
+                "k": pools["k"].at[pageix, off].set(merge_heads(qk)),
+                "v": pools["v"].at[pageix, off].set(merge_heads(qv)),
+                "k_scale": pools["k_scale"].at[pageix, off].set(ks),
+                "v_scale": pools["v_scale"].at[pageix, off].set(vs),
             }
         else:
             new[name] = {
-                "k": pool_k.at[pageix, off].set(
-                    merge_heads(kh).astype(pool_k.dtype)),
-                "v": cache_state[name]["v"].at[pageix, off].set(
-                    merge_heads(vh).astype(pool_k.dtype)),
-            }
+                leaf: pools[leaf].at[pageix, off].set(
+                    (merge_heads(rows) if rows.ndim == 4
+                     else pad_row(rows, pools[leaf].shape[-1]))
+                    .astype(pools[leaf].dtype))
+                for leaf, rows in kv.items()}
     return new
 
 
@@ -215,7 +242,8 @@ class PagedKVCache:
             deg = 1
             for a in axes:
                 deg *= mesh.shape.get(a, 1)
-            if all(a in mesh.shape for a in axes) and spec.heads % deg == 0:
+            if all(a in mesh.shape for a in axes) and spec.heads \
+                    and spec.heads % deg == 0:
                 self.heads_axis = heads_axis
                 # a shard of the merged axis holds whole heads, so the
                 # pools and the scales' heads dim split alike
@@ -224,22 +252,28 @@ class PagedKVCache:
                                if mesh is not None else None)
         self._repl = (NamedSharding(mesh, PartitionSpec())
                       if mesh is not None else None)
-        shape = (spec.pool_pages, spec.page_size, spec.heads * spec.head_dim)
+        if spec.latent_dim and self.quantized:
+            raise NotImplementedError(
+                "a quantized (int8) cache of paged_latent state: the "
+                "per-head scales have no heads to belong to")
+        shape = (spec.pool_pages, spec.page_size)
 
-        def pool():
-            z = jnp.zeros(shape, jnp.int8 if self.quantized else dtype)
+        def pool(width):
+            z = jnp.zeros(shape + (width,),
+                          jnp.int8 if self.quantized else dtype)
             return (jax.device_put(z, self._pool_sharding)
                     if self._pool_sharding is not None else z)
 
         def scales():
             # per-(page entry, head) f32 scales, sharded like the pools'
             # heads dim so the quantized cache needs no resharding either
-            z = jnp.zeros(shape[:2] + (spec.heads,), jnp.float32)
+            z = jnp.zeros(shape + (spec.heads,), jnp.float32)
             return (jax.device_put(z, self._pool_sharding)
                     if self._pool_sharding is not None else z)
 
         def layer_state():
-            st = {"k": pool(), "v": pool()}
+            st = {leaf: pool(width)
+                  for leaf, width in spec.row_widths().items()}
             if self.quantized:
                 st["k_scale"] = scales()
                 st["v_scale"] = scales()
@@ -369,7 +403,19 @@ class PagedKVCache:
         — the scheduler's rotation candidates."""
         return [s for s in self._cold if s not in self._inflight]
 
-    def _no_recurrent(self, what: str) -> None:
+    @property
+    def state_kinds(self) -> str:
+        """The kinds of per-request state this cache holds, as the ops'
+        `state_kind` names them (what the cache's spans say they moved)."""
+        paged = "paged_latent" if self.spec.latent_dim else "paged_kv"
+        return paged + ("+recurrent" if self.recurrent else "")
+
+    def _kv_pages_only(self, what: str) -> None:
+        if self.spec.latent_dim:
+            raise NotImplementedError(
+                f"{what}: the cache holds paged_latent state "
+                f"({self.attn_layers[0]}, ...), which this path does not "
+                "move yet")
         if self.recurrent:
             raise NotImplementedError(
                 f"{what}: a model with recurrent layers "
@@ -421,6 +467,7 @@ class PagedKVCache:
         caller (scheduler) batches `push()` after a rotation round."""
         import time as _time
         from flexflow_tpu import telemetry as tel
+        self._kv_pages_only("spill")
         if not self.can_spill(slot):
             raise ValueError(f"cannot spill slot {slot}")
         pages = self._slot_pages.pop(slot)
@@ -507,7 +554,7 @@ class PagedKVCache:
         the prefill replica spills the slot after commit, exports it here,
         evicts, and the fleet delivers the payload to a decode replica's
         `import_parked`. Non-destructive — the caller evicts afterwards."""
-        self._no_recurrent("export_parked")
+        self._kv_pages_only("export_parked")
         host_ids = self._cold.get(slot)
         if host_ids is None:
             raise ValueError(f"slot {slot} is not parked (spill it first)")
@@ -534,7 +581,7 @@ class PagedKVCache:
         other op. Raises `KVPoolExhausted` when the host free list is
         short — backpressure, the fleet retries the delivery."""
         import time as _time
-        self._no_recurrent("import_parked")
+        self._kv_pages_only("import_parked")
         if self._active[slot] or slot in self._cold:
             raise ValueError(f"slot {slot} is occupied")
         need = int(payload["pages"])
@@ -583,7 +630,7 @@ class PagedKVCache:
         # each commit consumes the leaves it is handed: what comes back is
         # adopted before the next one reads `self.state`
         with tel.span("serve/prefill/commit_kv", cat="serve",
-                      bytes=_tree_bytes(fresh)):
+                      bytes=_tree_bytes(fresh), state=self.state_kinds):
             self.state = {**self.state,
                           **_commit_prefill(paged, fresh, slot_ids, lengths)}
         if self.recurrent:

@@ -48,7 +48,7 @@ from flexflow_tpu.core.layer import Layer
 from flexflow_tpu.core.model import FFModel
 from flexflow_tpu.core.tensor import Tensor, TensorSpec
 from flexflow_tpu.ops import get_op_def
-from flexflow_tpu.ops.op_type import OperatorType
+from flexflow_tpu.ops.registry import PAGED_STATE_KINDS
 from flexflow_tpu.parallel.machine import MachineSpec
 from flexflow_tpu.search import cost_model as cm
 
@@ -61,6 +61,28 @@ def _serving_params(layer: Layer, kind: str) -> dict:
     hook = get_op_def(layer.op_type).serving_params
     p = dict(layer.params)
     return p if hook is None else hook(p, kind)
+
+
+def page_geometry(model) -> Dict[str, int]:
+    """What a token's row holds in the paged pools of `model`'s layers, from
+    the layers' own declarations (their op's `page_state`): `{"heads",
+    "head_dim"}` of K and V pools, or `{"latent_dim"}` of a latent pool. One
+    geometry a cache: layers that declare different ones raise."""
+    found: Dict[str, Dict[str, int]] = {}
+    for l in topo_order(model.layers):
+        d = get_op_def(l.op_type)
+        if d.state_kind in PAGED_STATE_KINDS:
+            found[l.name] = dict(d.page_state(l))
+    if not found:
+        raise ValueError("compile_serving needs a model with attention "
+                         "layers (nothing to cache)")
+    first = next(iter(found))
+    for name, geometry in found.items():
+        if geometry != found[first]:
+            raise NotImplementedError(
+                f"one cache geometry a model: {first} pages {found[first]}, "
+                f"{name} pages {geometry}")
+    return found[first]
 
 
 def recurrent_layers(model) -> Dict[str, Dict[str, tuple]]:
@@ -88,7 +110,8 @@ def clone_for_serving(model, kind: str, slots: int,
     params transfer by (layer name, weight name).
 
     Returns (serving_model, attention_layer_names) — the latter is the set
-    of layers whose KV the paged cache holds, in topo order.
+    of layers whose pages the paged cache holds (K/V, or a latent), in topo
+    order.
     """
     if kind not in ("prefill", "decode"):
         raise ValueError(f"unknown serving program kind {kind!r}")
@@ -123,7 +146,7 @@ def clone_for_serving(model, kind: str, slots: int,
             nt = nl.add_output(spec, idx=i, name=l.outputs[i].name)
             tmap[l.outputs[i].guid] = nt
         sm.layers.append(nl)
-        if get_op_def(l.op_type).state_kind == "paged_kv":
+        if get_op_def(l.op_type).state_kind in PAGED_STATE_KINDS:
             attn.append(l.name)
     return sm, attn
 
@@ -194,7 +217,8 @@ def _decode_cost_fn(machine: MachineSpec, kv_layer_bytes: int,
     def cost(layer, cand):
         rf = cm.op_roofline(layer, cand, machine)
         t = rf["t_mem_s"] / 2.0
-        if kv_layer_bytes and layer.op_type is OperatorType.MULTIHEAD_ATTENTION:
+        if kv_layer_bytes and get_op_def(layer.op_type).state_kind \
+                in PAGED_STATE_KINDS:
             wq = cand.weight_dims.get("wq")
             deg = cm.dims_degree([wq[1]], machine) if wq and len(wq) > 1 else 1
             t += kv_layer_bytes / max(1, deg) / machine.hbm_bw

@@ -153,6 +153,21 @@ def valid_step_inputs(tokens, state) -> List[Any]:
     return [tokens, jnp.broadcast_to(live, tokens.shape)]
 
 
+def positions_valid_prompt_inputs(ids: np.ndarray,
+                                 lengths: np.ndarray) -> List[np.ndarray]:
+    """Prefill inputs of a model with rotary positions whose layers are also
+    told which positions exist (`input_ids`, `positions`, `valid`)."""
+    ids, pos = gpt2_prompt_inputs(ids, lengths)
+    return [ids, pos, valid_prompt_inputs(ids, lengths)[1]]
+
+
+def positions_valid_step_inputs(tokens, state) -> List[Any]:
+    """Decode inputs of such a model: the next tokens, the device-side
+    position each is written at, and which slots are live."""
+    tokens, pos = gpt2_step_inputs(tokens, state)
+    return [tokens, pos, valid_step_inputs(tokens, state)[1]]
+
+
 def _stat_totals(stats: List[Any]) -> Dict[str, float]:
     """The programs' own counters (one dict of device scalars per decode
     step or prefill wave, as it came out under STATS_KEY), summed per name
@@ -270,6 +285,10 @@ class ContinuousBatchingScheduler:
         # (see fleet._SharedRuntimeEngine).
         self.exec_lock: Any = threading.RLock()
         self._exec_serialized = False
+        if self.handoff is not None and self.kv.spec.latent_dim:
+            raise NotImplementedError(
+                "prefill-only handoff: the cache holds paged_latent state, "
+                "which the hand-off does not move yet")
         if self.handoff is not None and getattr(self.kv, "recurrent", None):
             raise NotImplementedError(
                 "prefill-only handoff: the model has layers with per-slot "
@@ -418,7 +437,8 @@ class ContinuousBatchingScheduler:
         last first token; none when no batch formed."""
         with tel.span("serve/admit", cat="serve",
                       wave=self.prefills + 1) as wave:
-            with tel.span("serve/admit/place", cat="serve"):
+            with tel.span("serve/admit/place", cat="serve",
+                          state=self.kv.state_kinds):
                 batch = self._place(waiting, active, now_s)
                 if batch:
                     self.kv.push()
@@ -510,7 +530,8 @@ class ContinuousBatchingScheduler:
             if self._spec:
                 self.draft.kv.push()
             return False
-        with tel.span("serve/prefill/commit", cat="serve"):
+        with tel.span("serve/prefill/commit", cat="serve",
+                      state=self.kv.state_kinds):
             self.kv.commit_prefill(
                 kv_state, np.arange(self.slots, dtype=np.int32), lengths)
         if self._spec:

@@ -490,6 +490,62 @@ def test_granite_serving_programs_fit_one_chip(described_devices, mosaic,
     _assert_appends_in_place(decode, eng)
 
 
+def test_gigachat_serving_programs_fit_one_chip(described_devices, mosaic,
+                                                one_chip, monkeypatch):
+    """`GigaChat3.1-702B-A36B.serve-chat`'s two programs at the cell's own
+    sizes (16 slots, width 1024, 10.35 GB of bf16 weights, six latent pools
+    of `[1281, 16, 640]`: 576 values a row in whole lanes), through the normal entry points: the chip's
+    compiler must hold the prefill wave beside the weights and the cache,
+    the 192-wide heads must go through the flash kernel and the experts
+    through the grouped product, and the decode step must append to the
+    pools it was handed (no whole-pool copy, every pool aliased)
+    and attend in the latent space: no `[slots, context, heads, 320]`
+    decompression of the cache exists in it."""
+    from flexflow_tpu.serving import kv_cache
+
+    eng, g, params, state = _described_engine(
+        "GigaChat3.1-702B-A36B.serve-chat", described_devices, monkeypatch,
+        one_chip)
+    slots = eng.slots
+    spec = eng.kv_spec
+    assert (spec.latent_dim, spec.heads, spec.layers) == (576, 0, 6)
+    pool = eng.kv.state[eng.attn_layers[0]]["latent"]
+    assert pool.shape == (slots * 80 + 1, 16, 640) and pool.dtype == jnp.bfloat16
+    held = sum(x.size * x.dtype.itemsize
+               for x in jax.tree_util.tree_leaves(state))
+    assert held == pytest.approx(spec.total_bytes(), rel=1e-3)
+    assert 0.155e9 < held < 0.16e9
+    three = [_i32(one_chip, slots, 1)] * 3
+    decode = eng._decode_jit.lower(params, state, three).compile()
+    wave = [_i32(one_chip, slots, g.seq)] * 3
+    prefill = eng._prefill_first_tokens_jit.lower(
+        params, wave, _i32(one_chip, slots)).compile()
+    chip = 15.75e9          # what the compiler has of a v5e chip's 16 GB
+    for program, beside in ((decode, 0), (prefill, held)):
+        m = program.memory_analysis()
+        assert 10.3e9 < m.argument_size_in_bytes
+        need = (m.argument_size_in_bytes + m.output_size_in_bytes
+                + m.temp_size_in_bytes + beside)
+        assert need < 0.95 * chip, (need, m)
+    text = prefill.as_text()
+    assert "ragged-dot" in text and "tpu_custom_call" in text
+    text = decode.as_text()
+    assert "ragged-dot" in text
+    # the decompressed K/V of a slot's context: [.., 1280, 64, 320] or merged
+    assert not re.search(r"\[16,1280,(64,320|20480|64,128|64,192|8192|12288)\]",
+                         text)
+    # six pools of 26 MB: the compiler stages some of them (4 here) through
+    # its alternate memory, as it does granite's two: 52 MB read and written
+    # back a staged pool and step, beside the 4 GB the step streams
+    _assert_appends_in_place(decode, eng, staged_at_most=6)
+    fresh = {n: {"latent": jax.ShapeDtypeStruct(
+                     (slots, g.seq, 576), jnp.bfloat16, sharding=one_chip)}
+             for n in eng.attn_layers}
+    commit = kv_cache._commit_prefill.lower(
+        state, fresh, _i32(one_chip, slots), _i32(one_chip, slots)).compile()
+    _assert_appends_in_place(commit, eng, staged_at_most=6)
+
+
 def _entry_ops(text):
     """(op name, result type) of every instruction of optimized HLO's
     entry computation."""
@@ -505,7 +561,7 @@ def _entry_ops(text):
     return ops
 
 
-def _assert_appends_in_place(program, eng):
+def _assert_appends_in_place(program, eng, staged_at_most=2):
     """No op of the program's entry computation copies or relays an array
     of a pool's size (`copy`, `copy-start`, `reshape`, `transpose`: the
     pools themselves, and the gathered context, which is as large), and
@@ -515,8 +571,8 @@ def _assert_appends_in_place(program, eng):
     four slices in, the scatter and the gather there, one `copy-start` back
     onto the aliased buffer) — its own placement, as on the parent, no
     relayout and no fresh buffer."""
-    pools = [eng.kv.state[n][key] for n in eng.attn_layers
-             for key in ("k", "v")]
+    pools = [leaf for n in eng.attn_layers
+             for leaf in eng.kv.state[n].values()]
     size = pools[0].size         # slots * pages_per_slot + 1 pages: both
     moved, staged = [], 0
     for op, ty in _entry_ops(program.as_text()):
@@ -530,7 +586,7 @@ def _assert_appends_in_place(program, eng):
         else:
             moved.append((op, ty))
     assert not moved, (len(moved), moved[:4])
-    assert staged <= 2, staged
+    assert staged <= staged_at_most, staged
     held = sum(leaf.size * leaf.dtype.itemsize for n in eng.attn_layers
                for leaf in eng.kv.state[n].values())
     assert program.memory_analysis().alias_size_in_bytes >= held
