@@ -17,6 +17,7 @@ capacity = ceil(alpha * k * batch / n_experts) and overflow tokens are dropped
 
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
@@ -164,6 +165,24 @@ def _moe_layer_infer(layer: Layer):
 # many (lax.map), so that the `tokens * k` row buffers of the grouped
 # product stay a fraction of a prefill wave's
 MOE_TOKEN_BLOCK = 4096
+# a block's row buffers are sized at run time, by the smallest of these
+# parts of its `tokens * k` pairs that holds the pairs held here
+# (`_row_capacities`: ahead of them no row at all, behind them always the
+# whole block). Chosen on the chip (PERF.md, PR 33: the layer alone over a
+# `[16, 1024]` wave in which a few prompts of 16-512 tokens exist, ms a
+# layer, whole block -> ladder). 1/16: what a served block holds, 2048 rows
+# at 16 of 256 experts held (four 512-token prompts hold 918; three short
+# prompts 37.9 -> 19.7), 2560 rows at 36 of 72 (one 512-token prompt holds
+# 2540; three short prompts 24.1 -> 11.0). 1/4: four 512-token prompts in
+# one block at 36 of 72 hold 10 200 (20.6 -> 8.2).
+# The empty rung: a wave's other blocks (1.6 of 100 rows computed in both
+# serving cells; an all-empty wave 6.9 -> 5.3)
+MOE_ROW_RUNGS = (16, 4)
+# and no rung is smaller than this: the grouped product works on tiles of
+# 128 rows, so a smaller buffer computes no fewer. A decode step's 128 or
+# 160 pairs get no ladder; 80 tokens x top-8 get [0, 160, 640] and lose
+# nothing by it (2.45 -> 2.15 ms)
+MOE_MIN_RUNG_ROWS = 128
 
 
 def _choose(scores, weights, p):
@@ -209,29 +228,33 @@ def _choose(scores, weights, p):
     return gate, experts
 
 
-def _route_tokens(xt, exists, weights, p):
-    """One block of tokens `[tokens, d]` through the routed layer: (this
-    holder's part of the output `[tokens, d]`, rows on each held expert
-    `[held]`)."""
-    k = p["top_k"]
-    lo, hi = p["experts_held"]
-    held_n = hi - lo
-    tokens, _d = xt.shape
-    dt = xt.dtype
-    scores = jnp.dot(xt.astype(jnp.float32),
-                     weights["router"].astype(jnp.float32),
-                     precision=jax.lax.Precision.HIGHEST)
-    gate, experts = _choose(scores, weights, p)                # [tokens, k]
-    held = (experts >= lo) & (experts < hi) & exists
-    local = jnp.where(held, experts - lo, held_n).reshape(-1)  # absent: last
-    order = jnp.argsort(local, stable=True)
-    sizes = jnp.bincount(local, length=held_n + 1)[:held_n].astype(jnp.int32)
-    rows = xt[order // k]                                      # [tokens*k, d]
-    ab = jax.lax.ragged_dot(rows, weights["w_in"].astype(dt), sizes)
+def _row_capacities(pairs: int):
+    """The static ladder of row-buffer sizes for a block of `pairs`
+    (token, choice) pairs, smallest first: no row at all (a block none of
+    whose pairs is held here), the parts of `MOE_ROW_RUNGS`, and last the
+    whole block. The whole block alone where no part is worth a rung."""
+    parts = [pairs // part for part in MOE_ROW_RUNGS
+             if pairs // part >= MOE_MIN_RUNG_ROWS]
+    return ([0] + parts if parts else []) + [pairs]
+
+
+def _experts(rows, sizes, weights, p):
+    """The gated-SiLU experts over `rows` sorted by expert, `sizes[e]` of
+    them on held expert e: one grouped product in, the gate in f32, one
+    out. Rows past the last group are not multiplied."""
+    dt = rows.dtype
     width = p["expert_width"]
+    ab = jax.lax.ragged_dot(rows, weights["w_in"].astype(dt), sizes)
     mid = (jax.nn.silu(ab[:, :width].astype(jnp.float32))
            * ab[:, width:].astype(jnp.float32)).astype(dt)
-    out = jax.lax.ragged_dot(mid, weights["w_out"].astype(dt), sizes)
+    return jax.lax.ragged_dot(mid, weights["w_out"].astype(dt), sizes)
+
+
+def _all_rows(xt, gate, held, order, sizes, weights, p):
+    """Every (token, choice) pair of the block has a row: the whole
+    `tokens * k` buffer, whatever is held."""
+    tokens, k = gate.shape
+    out = _experts(xt[order // k], sizes, weights, p)          # [tokens*k, d]
     # back to (token, choice) order, one choice at a time (a [tokens, k, d]
     # buffer in f32 would be the largest of the program); rows past the
     # last group hold nothing that was computed, so they are selected
@@ -242,7 +265,61 @@ def _route_tokens(xt, exists, weights, p):
         y = y + jnp.where(held[:, j:j + 1],
                           gate[:, j:j + 1] * out[where[:, j]].astype(jnp.float32),
                           0.0)
-    return y.astype(dt), sizes
+    return y.astype(xt.dtype)
+
+
+def _no_rows(xt, *_):
+    """The same for a block that holds no pair."""
+    return jnp.zeros_like(xt)
+
+
+def _held_rows(cap, xt, gate, held, order, sizes, weights, p):
+    """The same for a block that holds at most `cap` pairs: they are the
+    first `cap` of `order` (absent pairs sort last), and gather, products,
+    gate and combine are over those rows alone. A token's rows lie apart
+    (one in each of its experts' groups): each is gated and added to its
+    token's row of the output; rows past the last group hold nothing that
+    was computed and add 0."""
+    k = gate.shape[1]
+    pair = order[:cap]
+    token = pair // k
+    out = _experts(xt[token], sizes, weights, p)               # [cap, d]
+    live = jnp.arange(cap) < jnp.sum(sizes)
+    part = jnp.where(live[:, None],
+                     gate.reshape(-1)[pair][:, None] * out.astype(jnp.float32),
+                     0.0)
+    return jnp.zeros(xt.shape, jnp.float32).at[token].add(part).astype(xt.dtype)
+
+
+def _route_tokens(xt, exists, weights, p):
+    """One block of tokens `[tokens, d]` through the routed layer: (this
+    holder's part of the output `[tokens, d]`, rows on each held expert
+    `[held]`, rows the grouped product was sized for)."""
+    k = p["top_k"]
+    lo, hi = p["experts_held"]
+    held_n = hi - lo
+    tokens, _d = xt.shape
+    scores = jnp.dot(xt.astype(jnp.float32),
+                     weights["router"].astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    gate, experts = _choose(scores, weights, p)                # [tokens, k]
+    held = (experts >= lo) & (experts < hi) & exists
+    local = jnp.where(held, experts - lo, held_n).reshape(-1)  # absent: last
+    order = jnp.argsort(local, stable=True)
+    sizes = jnp.bincount(local, length=held_n + 1)[:held_n].astype(jnp.int32)
+    # a holder of every expert has a row for every pair of an existing
+    # token, and a block of a few rows has nothing to save: no ladder
+    caps = _row_capacities(tokens * k) if held_n < p["num_experts"] \
+        else [tokens * k]
+    if len(caps) == 1:
+        return (_all_rows(xt, gate, held, order, sizes, weights, p), sizes,
+                jnp.int32(tokens * k))
+    rung = jnp.sum(jnp.sum(sizes) > jnp.asarray(caps[:-1], jnp.int32))
+    y = jax.lax.switch(
+        rung, [functools.partial(_held_rows, cap, p=p) if cap else _no_rows
+               for cap in caps[:-1]] + [functools.partial(_all_rows, p=p)],
+        xt, gate, held, order, sizes, weights)
+    return y, sizes, jnp.asarray(caps, jnp.int32)[rung]
 
 
 def _moe_layer_lower(layer: Layer, inputs, weights, ctx):
@@ -255,16 +332,25 @@ def _moe_layer_lower(layer: Layer, inputs, weights, ctx):
     and gates as the layer's params say (`_choose`). Each (token,
     choice) whose expert is held here is computed; the others contribute
     nothing, so the result is this holder's part of the layer's output
-    (the parts of all holders add up to the whole layer). Rows are sorted
-    by expert and multiplied as one grouped product (`jax.lax.ragged_dot`),
-    whose cost follows the rows that were routed here: the static
-    `tokens * k` rows, absent ones sorted behind the last group and never
-    multiplied. No capacity, no drops. The optional second input `valid`
+    (the parts of all holders add up to the whole layer). Pairs are sorted
+    by expert, absent ones behind the last group, and the held ones' rows
+    multiplied as one grouped product (`jax.lax.ragged_dot`). The row
+    buffers (gather, products, f32 gate, combine) are sized per block of
+    `MOE_TOKEN_BLOCK` tokens at run time: the smallest rung of
+    `_row_capacities(tokens * k)` that holds the block's held pairs, chosen
+    by a `lax.switch` on their count, so the layer's cost follows the rows
+    held here and not the static `tokens * k`. The last rung is the whole
+    block: no capacity factor, no drops, whatever the routing. A holder of
+    every expert, and a block too small for a smaller rung (a decode step),
+    lower with no conditional. The optional second input `valid`
     `[batch, seq]` names the tokens that exist; the others are not routed.
 
     Reports (ctx.add_stat): moe_routed_pairs, moe_held_pairs, moe_load_max
     (rows on the fullest held expert), moe_load_mean (held pairs over
-    experts held), moe_experts_hit (held experts with a row)."""
+    experts held), moe_experts_hit (held experts with a row),
+    moe_rows_static (`tokens * k`) and moe_rows_computed (the rungs taken,
+    summed over blocks: equal to moe_rows_static where there is no
+    ladder)."""
     x = inputs[0]
     p = layer.params
     b, s, d = x.shape
@@ -274,13 +360,13 @@ def _moe_layer_lower(layer: Layer, inputs, weights, ctx):
         else inputs[1].reshape(tokens, 1) > 0
     if tokens > MOE_TOKEN_BLOCK and tokens % MOE_TOKEN_BLOCK == 0:
         blocks = tokens // MOE_TOKEN_BLOCK
-        y, sizes = jax.lax.map(
+        y, sizes, computed = jax.lax.map(
             lambda block: _route_tokens(block[0], block[1], weights, p),
             (xt.reshape(blocks, MOE_TOKEN_BLOCK, d),
              exists.reshape(blocks, MOE_TOKEN_BLOCK, 1)))
-        sizes = jnp.sum(sizes, axis=0)
+        sizes, computed = jnp.sum(sizes, axis=0), jnp.sum(computed)
     else:
-        y, sizes = _route_tokens(xt, exists, weights, p)
+        y, sizes, computed = _route_tokens(xt, exists, weights, p)
     n_held = jnp.sum(sizes)
     ctx.add_stat("moe_routed_pairs",
                  jnp.sum(exists).astype(jnp.int32) * p["top_k"])
@@ -289,6 +375,8 @@ def _moe_layer_lower(layer: Layer, inputs, weights, ctx):
     ctx.add_stat("moe_load_mean",
                  n_held.astype(jnp.float32) / sizes.shape[0])
     ctx.add_stat("moe_experts_hit", jnp.sum(sizes > 0).astype(jnp.int32))
+    ctx.add_stat("moe_rows_static", jnp.int32(tokens * p["top_k"]))
+    ctx.add_stat("moe_rows_computed", computed)
     return [y.reshape(b, s, d)]
 
 

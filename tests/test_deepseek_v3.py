@@ -730,6 +730,14 @@ def test_scheduler_serves_it_and_reports_its_spans_and_counters():
     assert wave["moe_routed_pairs"] == 2 * g.experts_per_tok * sum(
         len(r.prompt) for r in first)
     assert 0 < wave["moe_held_pairs"] < wave["moe_routed_pairs"]
+    # 576 pairs a layer and wave, 183 of them routed and about half of
+    # those held: the rung of 144 rows; a decode step's 12 pairs are under
+    # every rung
+    assert wave["moe_rows_static"] == 2 * SLOTS * g.seq * g.experts_per_tok
+    assert wave["moe_held_pairs"] <= wave["moe_rows_computed"] == 2 * 144
+    assert all(a["moe_rows_computed"] == a["moe_rows_static"]
+               == a["steps"] * 2 * SLOTS * g.experts_per_tok
+               for a in spans["serve/decode/window_sync"])
 
 
 def test_what_a_latent_cache_does_not_support_fails_loudly():

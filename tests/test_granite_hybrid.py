@@ -426,10 +426,18 @@ def test_scheduler_serves_it_and_reports_its_spans_and_counters():
             <= a["steps"] * g.layers * SLOTS * g.experts_per_tok
         assert a["moe_load_max"] >= a["moe_load_mean"] > 0
         assert a["moe_experts_hit"] <= a["steps"] * g.layers * 4
+        # a step's 12 pairs a layer are under every rung: all rows computed
+        assert a["moe_rows_computed"] == a["moe_rows_static"] \
+            == a["steps"] * g.layers * SLOTS * g.experts_per_tok
     assert steps == sched.decode_steps
     wave = spans["serve/prefill/device_wait"][0]
     assert wave["moe_routed_pairs"] == g.layers * g.experts_per_tok * sum(
         len(r.prompt) for r in reqs[:SLOTS])
+    # 576 pairs a layer and wave, 183 of them routed and about half of
+    # those held: the rung of 144 rows in every layer
+    assert wave["moe_rows_static"] == g.layers * SLOTS * g.seq * 3
+    assert wave["moe_held_pairs"] <= wave["moe_rows_computed"] \
+        == g.layers * 144
 
 
 def test_what_recurrent_state_does_not_support_fails_loudly():

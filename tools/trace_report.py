@@ -247,6 +247,34 @@ def print_request_timeline(tl: Dict[str, Any]) -> None:
         print(f"  terminal {term.get('event', '?')}: {rec}")
 
 
+def expert_layer_lines(events: List[Dict[str, Any]]) -> List[str]:
+    """One line per serving span that carries the expert layers' counters
+    (serve/prefill/device_wait: the waves; serve/decode/window_sync: the
+    decode steps): the pairs held here of those routed, and beside it the
+    rows the grouped product's buffers were sized for of the static
+    `tokens * k` (100 % where no block took a smaller rung)."""
+    sums: Dict[str, Dict[str, float]] = {}
+    for ev in events:
+        args = ev.get("args") or {}
+        if ev.get("ph") == "X" and "moe_routed_pairs" in args:
+            into = sums.setdefault(ev["name"], {})
+            for k, v in args.items():
+                if k.startswith("moe_"):
+                    into[k] = into.get(k, 0) + v
+    lines = []
+    for name in sorted(sums):
+        a = sums[name]
+        line = (f"[serve] expert layers in {name}: held "
+                f"{100.0 * a.get('moe_held_pairs', 0) / max(a['moe_routed_pairs'], 1):.2f}% "
+                f"of {int(a['moe_routed_pairs'])} routed pairs")
+        if a.get("moe_rows_static"):
+            line += (f", rows computed "
+                     f"{100.0 * a.get('moe_rows_computed', 0) / a['moe_rows_static']:.2f}% "
+                     f"of {int(a['moe_rows_static'])} static")
+        lines.append(line)
+    return lines
+
+
 def render(path: str, out_path: Optional[str] = None, top: int = 0,
            quiet: bool = False) -> Dict[str, Any]:
     """The full report: summary rows + chrome doc + derived sections.
@@ -301,6 +329,8 @@ def render(path: str, out_path: Optional[str] = None, top: int = 0,
             if ev.get("name") == "serve/compile_serving" and ev.get("args"):
                 print("[serve] compile_serving: " + " ".join(
                     f"{k}={v}" for k, v in sorted(ev["args"].items())))
+        for line in expert_layer_lines(events):
+            print(line)
         for ev in errors:
             print(f"[error] {ev['name']}: {ev.get('args', {})}")
     return {"events": events, "summary": rows, "chrome": chrome,
