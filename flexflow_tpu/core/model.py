@@ -399,15 +399,20 @@ class FFModel:
                   scoring: Optional[str] = None, n_group: int = 0,
                   topk_group: int = 0, norm_topk_prob: bool = False,
                   routed_scaling_factor: Optional[float] = None,
-                  score_bias: bool = False, name=None) -> Tensor:
+                  score_bias: bool = False,
+                  expert_activation: Optional[str] = None,
+                  latent_size: int = 0, name=None) -> Tensor:
         """Dropless top-k layer of gated-SiLU experts over `[batch, seq,
         d]`, routed over all `num_experts`, computing those in
         `experts_held = (lo, hi)` (default: all); ops/moe_ops.py. How it
         chooses and gates beyond top-k + softmax (`scoring` "sigmoid", the
         choice limited to `topk_group` of `n_group` groups, gates
         normalised and scaled, a `score_bias` weight for the selection;
-        `moe_ops._choose`) enters the params only where set, so graphs
-        without them keep their fingerprints."""
+        `moe_ops._choose`) and what its experts are beyond gated SiLU at
+        the layer's own width (`expert_activation` "relu2": un-gated
+        squared ReLU; `latent_size`: the experts work in a latent of that
+        width, between two projections of the layer) enters the params
+        only where set, so graphs without them keep their fingerprints."""
         lo, hi = experts_held if experts_held is not None else (0, num_experts)
         ins = [input] + ([valid] if valid is not None else [])
         params = {"num_experts": int(num_experts), "top_k": int(top_k),
@@ -423,6 +428,10 @@ class FFModel:
             params["routed_scaling_factor"] = float(routed_scaling_factor)
         if score_bias:
             params["score_bias"] = True
+        if expert_activation is not None:
+            params["expert_activation"] = str(expert_activation)
+        if latent_size:
+            params["latent_size"] = int(latent_size)
         return self._add_layer(OperatorType.MOE_LAYER, params, ins, name,
                                initializers)[0]
 

@@ -14,6 +14,7 @@ from flexflow_tpu.models.granite_hybrid import (GraniteHybridConfig,
                                                 build_granite_hybrid)
 from flexflow_tpu.models.deepseek_v3 import (DeepseekV3Config,
                                              build_deepseek_v3)
+from flexflow_tpu.models.nemotron_h import NemotronHConfig, build_nemotron_h
 from flexflow_tpu.models.bert import build_bert
 from flexflow_tpu.models.moe import build_moe_mlp
 from flexflow_tpu.models.inception import build_inception_v3
@@ -28,4 +29,5 @@ __all__ = [
     "build_bert", "build_moe_mlp", "build_inception_v3",
     "build_granite_hybrid", "GraniteHybridConfig",
     "build_deepseek_v3", "DeepseekV3Config",
+    "build_nemotron_h", "NemotronHConfig",
 ]

@@ -140,13 +140,17 @@ class _ALog(Initializer):
 
 
 class _DtBias(Initializer):
-    """softplus(dt_bias) log-uniform in [1e-3, 1e-1]: the inverse softplus of
-    such a dt (the Mamba-2 convention), so that the state neither blows up
-    nor vanishes under random weights."""
+    """softplus(dt_bias) log-uniform in [lo, hi], no smaller than `floor`:
+    the inverse softplus of such a dt (the Mamba-2 convention), so that the
+    state neither blows up nor vanishes under random weights."""
+
+    def __init__(self, lo: float = 1e-3, hi: float = 1e-1, floor: float = 0.0):
+        self.lo, self.hi, self.floor = lo, hi, floor
 
     def __call__(self, key, spec):
         dt = jnp.exp(jax.random.uniform(key, spec.shape, jnp.float32,
-                                        math.log(1e-3), math.log(1e-1)))
+                                        math.log(self.lo), math.log(self.hi)))
+        dt = jnp.maximum(dt, self.floor)
         return dt + jnp.log(-jnp.expm1(-dt))
 
 

@@ -135,6 +135,12 @@ register_op(OperatorType.CACHE, _cache_infer, _cache_lower)
 
 
 # ---------------------------------------------------------------- moe_layer
+def _in_parts(p) -> int:
+    """Matrices an expert's `w_in` holds side by side: the gated-SiLU
+    expert's two, the un-gated squared-ReLU expert's one."""
+    return 1 if p.get("expert_activation") == "relu2" else 2
+
+
 def _moe_layer_infer(layer: Layer):
     x = layer.inputs[0].spec
     p = layer.params
@@ -142,11 +148,19 @@ def _moe_layer_infer(layer: Layer):
     if not 0 <= lo < hi <= p["num_experts"]:
         raise ValueError(f"experts_held {lo, hi} outside 0..{p['num_experts']}")
     d, width = x.shape[-1], p["expert_width"]
+    if p.get("expert_activation") not in (None, "relu2"):
+        raise ValueError(f"moe_layer expert_activation "
+                         f"{p['expert_activation']!r}")
+    # the experts' own width: the layer's d, or the latent they work in
+    d_e = p.get("latent_size", d)
     layer.weight_specs = {
         "router": TensorSpec((d, p["num_experts"]), x.dtype),
-        "w_in": TensorSpec((hi - lo, d, 2 * width), x.dtype),
-        "w_out": TensorSpec((hi - lo, width, d), x.dtype),
+        "w_in": TensorSpec((hi - lo, d_e, width * _in_parts(p)), x.dtype),
+        "w_out": TensorSpec((hi - lo, width, d_e), x.dtype),
     }
+    if "latent_size" in p:
+        layer.weight_specs["w_latent_in"] = TensorSpec((d, d_e), x.dtype)
+        layer.weight_specs["w_latent_out"] = TensorSpec((d_e, d), x.dtype)
     if p.get("scoring", "softmax") not in ("softmax", "sigmoid"):
         raise ValueError(f"moe_layer scoring {p['scoring']!r}")
     groups = p.get("n_group", 0)
@@ -239,14 +253,21 @@ def _row_capacities(pairs: int):
 
 
 def _experts(rows, sizes, weights, p):
-    """The gated-SiLU experts over `rows` sorted by expert, `sizes[e]` of
-    them on held expert e: one grouped product in, the gate in f32, one
-    out. Rows past the last group are not multiplied."""
+    """The experts over `rows` sorted by expert, `sizes[e]` of them on held
+    expert e: one grouped product in, the activation in f32, one out. Rows
+    are as wide as the experts work (the layer's d, or its latent), the
+    middle `expert_width`. Gated SiLU, `silu(a) * b` with `[a | b]` the
+    product in, unless the layer's `expert_activation` is "relu2":
+    `relu(a)^2`, no gate matrix. Rows past the last group are not
+    multiplied."""
     dt = rows.dtype
     width = p["expert_width"]
     ab = jax.lax.ragged_dot(rows, weights["w_in"].astype(dt), sizes)
-    mid = (jax.nn.silu(ab[:, :width].astype(jnp.float32))
-           * ab[:, width:].astype(jnp.float32)).astype(dt)
+    if p.get("expert_activation") == "relu2":
+        mid = jnp.square(jax.nn.relu(ab.astype(jnp.float32))).astype(dt)
+    else:
+        mid = (jax.nn.silu(ab[:, :width].astype(jnp.float32))
+               * ab[:, width:].astype(jnp.float32)).astype(dt)
     return jax.lax.ragged_dot(mid, weights["w_out"].astype(dt), sizes)
 
 
@@ -291,6 +312,21 @@ def _held_rows(cap, xt, gate, held, order, sizes, weights, p):
     return jnp.zeros(xt.shape, jnp.float32).at[token].add(part).astype(xt.dtype)
 
 
+def _through_latent(rows_fn):
+    """`rows_fn` (`_all_rows` or a `_held_rows`) for a layer whose experts
+    work in a latent: the block's tokens are projected into it, the row
+    buffers (gather, products, gate, combine) are latent-wide, and this
+    holder's combined part is projected back. Both projections are linear
+    and bias-free, so the holders' parts still add up to the whole layer."""
+    def rows(xt, gate, held, order, sizes, weights):
+        dt = xt.dtype
+        y = rows_fn(xt @ weights["w_latent_in"].astype(dt), gate, held,
+                    order, sizes, weights)
+        return y @ weights["w_latent_out"].astype(dt)
+
+    return rows
+
+
 def _route_tokens(xt, exists, weights, p):
     """One block of tokens `[tokens, d]` through the routed layer: (this
     holder's part of the output `[tokens, d]`, rows on each held expert
@@ -311,20 +347,25 @@ def _route_tokens(xt, exists, weights, p):
     # token, and a block of a few rows has nothing to save: no ladder
     caps = _row_capacities(tokens * k) if held_n < p["num_experts"] \
         else [tokens * k]
+    wrap = _through_latent if "latent_size" in p else (lambda rows_fn: rows_fn)
+    whole = wrap(functools.partial(_all_rows, p=p))
     if len(caps) == 1:
-        return (_all_rows(xt, gate, held, order, sizes, weights, p), sizes,
+        return (whole(xt, gate, held, order, sizes, weights), sizes,
                 jnp.int32(tokens * k))
     rung = jnp.sum(jnp.sum(sizes) > jnp.asarray(caps[:-1], jnp.int32))
     y = jax.lax.switch(
-        rung, [functools.partial(_held_rows, cap, p=p) if cap else _no_rows
-               for cap in caps[:-1]] + [functools.partial(_all_rows, p=p)],
+        rung, [wrap(functools.partial(_held_rows, cap, p=p)) if cap
+               else _no_rows for cap in caps[:-1]] + [whole],
         xt, gate, held, order, sizes, weights)
     return y, sizes, jnp.asarray(caps, jnp.int32)[rung]
 
 
 def _moe_layer_lower(layer: Layer, inputs, weights, ctx):
-    """Dropless top-k layer of gated-SiLU experts over `[batch, seq, d]`,
-    for a holder of the experts `experts_held = (lo, hi)`.
+    """Dropless top-k layer of experts (gated SiLU, or as `_experts` says)
+    over `[batch, seq, d]`, for a holder of the experts `experts_held =
+    (lo, hi)`. With `latent_size` in the layer's params the experts work
+    in a latent of that width (`_through_latent`): the router still reads
+    the d-wide row, and everything sized by rows below is latent-wide.
 
     The router scores ALL `num_experts` in f32 (the matmul at HIGHEST
     precision: a lower one moves the k-th and (k+1)-th scores past each
@@ -382,15 +423,19 @@ def _moe_layer_lower(layer: Layer, inputs, weights, ctx):
 
 def _moe_layer_flops(layer: Layer):
     """Forward, for the EXPECTED rows here: each token's k choices fall on
-    the held experts in the share held / num_experts."""
+    the held experts in the share held / num_experts; an expert's two or
+    three matrices at the width it works in, and the latent's two
+    projections on every token."""
     x = layer.inputs[0].spec
     p = layer.params
     lo, hi = p["experts_held"]
     d = x.shape[-1]
     tokens = x.num_elements // d
     pairs = tokens * p["top_k"] * (hi - lo) / p["num_experts"]
-    return 2.0 * tokens * d * p["num_experts"] \
-        + 2.0 * pairs * 3 * d * p["expert_width"]
+    d_e = p.get("latent_size", d)
+    latent = 2 * d * d_e if "latent_size" in p else 0
+    return 2.0 * tokens * (d * p["num_experts"] + latent) \
+        + 2.0 * pairs * (_in_parts(p) + 1) * d_e * p["expert_width"]
 
 
 register_op(OperatorType.MOE_LAYER, _moe_layer_infer, _moe_layer_lower,

@@ -1,13 +1,20 @@
 """State-space mixer: Mamba-2 (Dao & Gu 2024, "Transformers are SSMs"), in
 the layout of Hugging Face's Mamba2 / GraniteMoeHybrid mixers.
 
-    [z | xBC | dt] = x W_in                 sizes d_inner | d_inner + 2 N | H
+    [z | xBC | dt] = x W_in                 sizes d_inner | d_inner + 2 G N | H
     xBC = silu(causal depthwise conv1d(xBC, width d_conv) + b_conv)
     [u | B | C] = xBC                       u: [H heads, P = d_inner / H]
+                                            B, C: [G groups, N] each
     dt = softplus(dt + dt_bias);  A = -exp(A_log)        one scalar a head
     S_t = exp(dt_t A) S_{t-1} + dt_t u_t (x) B_t         S: [H, P, N], float32
-    y_t = S_t C_t + D u_t
-    out = RMS(y * silu(z); w_norm) W_out                 norm over all d_inner
+    y_t = S_t C_t + D u_t                   head h reads B, C of group h // (H / G)
+    out = RMS_G(y * silu(z); w_norm) W_out
+
+`n_groups` G (1 unless the layer's params say otherwise): the heads lie in G
+groups of H / G consecutive heads that share one B and one C, and the gated
+norm RMS_G normalises each group's d_inner / G values apart (one weight of
+d_inner); with G = 1 that is one B, one C and a norm over all d_inner. A
+layer with G = 1 lowers to the program it lowered to before groups existed.
 
 Three forms of one op, chosen by `params["mode"]`:
 
@@ -21,7 +28,9 @@ Three forms of one op, chosen by `params["mode"]`:
   `ctx.new_state[layer.name] = {"ssm": [b, H, P, N] f32, "conv": [b,
   d_conv - 1, conv_dim]}`.
 - "decode" (serving decode): one step of the recurrence on
-  `ctx.state[layer.name]`, written back to `ctx.new_state`.
+  `ctx.state[layer.name]`, written back to `ctx.new_state`. Reports
+  (ctx.add_stat) `ssm_state_bytes`: the state the step's live slots read
+  and wrote (both leaves, twice).
 
 The second input, `valid` `[b, s]` (int, 1 = a real token), says which
 positions exist: at the others `dt` is 0 (the state neither decays nor
@@ -52,15 +61,16 @@ from flexflow_tpu.ops.registry import LoweringCtx, register_op
 def _sizes(p):
     heads, hd, n = p["heads"], p["head_dim"], p["d_state"]
     d_inner = heads * hd
-    return heads, hd, n, d_inner, d_inner + 2 * n
+    return heads, hd, n, d_inner, d_inner + 2 * p.get("n_groups", 1) * n
 
 
 def _mamba_infer(layer: Layer):
     x = layer.inputs[0].spec
     p = layer.params
-    if p.get("n_groups", 1) != 1:
-        raise NotImplementedError("mamba2: n_groups other than 1")
     heads, _hd, _n, d_inner, conv_dim = _sizes(p)
+    groups = p.get("n_groups", 1)
+    if groups < 1 or heads % groups:
+        raise ValueError(f"mamba2: {groups} groups over {heads} heads")
     d = x.shape[-1]
     f32 = DataType.FLOAT
     layer.weight_specs = {
@@ -79,11 +89,24 @@ def _mamba_infer(layer: Layer):
 def ssd_scan(u, dt, a, bm, cm, chunk: int):
     """The recurrence S_t = exp(dt_t a) S_{t-1} + dt_t u_t (x) B_t, y_t =
     S_t C_t from S_0 = 0, by chunks. u [b, L, H, P]; dt [b, L, H] f32, >= 0;
-    a [H] f32, < 0; bm, cm [b, L, N]. Returns (y [b, L, H, P] f32, the
-    state after step L [b, H, P, N] f32). Products take their operands in
-    u's dtype and accumulate in f32; decays are f32."""
+    a [H] f32, < 0; bm, cm [b, L, N], or [b, L, G, N] where the heads read
+    them in G groups (head h those of group h // (H / G)). Returns (y [b, L,
+    H, P] f32, the state after step L [b, H, P, N] f32). Products take their
+    operands in u's dtype and accumulate in f32; decays are f32."""
     b, length, heads, hd = u.shape
     n = bm.shape[-1]
+    if bm.ndim == 4:    # the same scan, a group's heads with their B and C
+        g = bm.shape[2]
+
+        def split(t):   # [b, L, H, ...] -> [b, L, G, H / G, ...]
+            return t.reshape(t.shape[:2] + (g, heads // g) + t.shape[3:])
+
+        y, state = jax.vmap(
+            lambda *group: ssd_scan(*group, chunk),
+            in_axes=(2, 2, 0, 2, 2), out_axes=(2, 1))(
+                split(u), split(dt), a.reshape(g, heads // g), bm, cm)
+        return (y.reshape(b, length, heads, hd),
+                state.reshape(b, heads, hd, n))
     q = min(int(chunk), length)
     pad = -length % q
     if pad:     # steps with dt = 0 and u = 0: the state stays, y is unused
@@ -130,9 +153,32 @@ MAMBA_TOKEN_BLOCK = 4096
 
 
 def _gated(y, z, weights, p, dt):
-    """RMS(y * silu(z); w_norm), in the compute type."""
+    """RMS(y * silu(z); w_norm), in the compute type; over each of the
+    layer's groups apart where it has more than one."""
     g = y * jax.nn.silu(z.astype(jnp.float32))
-    return rms_norm(g, weights["norm"], p.get("eps", 1e-5)).astype(dt)
+    groups = p.get("n_groups", 1)
+    if groups == 1:
+        return rms_norm(g, weights["norm"], p.get("eps", 1e-5)).astype(dt)
+    split = g.shape[:-1] + (groups, g.shape[-1] // groups)
+    return rms_norm(g.reshape(split), weights["norm"].reshape(split[-2:]),
+                    p.get("eps", 1e-5)).reshape(g.shape).astype(dt)
+
+
+def _b_and_c(act, p, lead):
+    """B and C out of the activated xBC `[*lead, conv_dim]`: `[*lead, N]`
+    each, or `[*lead, G, N]` for a layer of G > 1 groups."""
+    _heads, _hd, n, d_inner, _conv_dim = _sizes(p)
+    groups = p.get("n_groups", 1)
+    shape = lead + ((groups, n) if groups > 1 else (n,))
+    return (act[..., d_inner:d_inner + groups * n].reshape(shape),
+            act[..., d_inner + groups * n:].reshape(shape))
+
+
+def _report_state_bytes(ctx: LoweringCtx, valid, st) -> None:
+    """`ssm_state_bytes`: both leaves of the live slots' state, read and
+    written by this step."""
+    ctx.add_stat("ssm_state_bytes", jnp.sum(valid).astype(jnp.float32)
+                 * (2.0 * sum(leaf[0].nbytes for leaf in st.values())))
 
 
 def _mamba_lower(layer: Layer, inputs, weights, ctx: LoweringCtx):
@@ -140,6 +186,7 @@ def _mamba_lower(layer: Layer, inputs, weights, ctx: LoweringCtx):
     p = layer.params
     heads, hd, n, d_inner, conv_dim = _sizes(p)
     k = p["d_conv"]
+    groups = p.get("n_groups", 1)
     dt_ = x.dtype
     b, s, _d = x.shape
     valid = (inputs[1] > 0) if len(inputs) > 1 else jnp.ones((b, s), bool)
@@ -166,14 +213,23 @@ def _mamba_lower(layer: Layer, inputs, weights, ctx: LoweringCtx):
         conv = jnp.einsum("bkc,kc->bc", window.astype(jnp.float32), conv_w)
         act = jax.nn.silu(conv + conv_b)
         u = act[:, :d_inner].reshape(b, heads, hd)
-        b_t, c_t = act[:, d_inner:d_inner + n], act[:, d_inner + n:]
+        b_t, c_t = _b_and_c(act, p, (b,))
         dt1 = dt[:, 0]                                          # [b, H]
-        ssm = st["ssm"] * jnp.exp(dt1 * a)[:, :, None, None] \
-            + (dt1[..., None] * u)[..., None] * b_t[:, None, None, :]
-        y = jnp.einsum("bhpn,bn->bhp", ssm, c_t) + d_skip[None, :, None] * u
+        if groups > 1:  # a head's B and C are its group's: [b, H, N]
+            b_t, c_t = (jnp.repeat(t, heads // groups, axis=1)
+                        for t in (b_t, c_t))
+            ssm = st["ssm"] * jnp.exp(dt1 * a)[:, :, None, None] \
+                + (dt1[..., None] * u)[..., None] * b_t[:, :, None, :]
+            y = jnp.einsum("bhpn,bhn->bhp", ssm, c_t)
+        else:
+            ssm = st["ssm"] * jnp.exp(dt1 * a)[:, :, None, None] \
+                + (dt1[..., None] * u)[..., None] * b_t[:, None, None, :]
+            y = jnp.einsum("bhpn,bn->bhp", ssm, c_t)
+        y = y + d_skip[None, :, None] * u
         ctx.new_state[layer.name] = {
             "ssm": ssm,
             "conv": jnp.where(valid[:, :1, None], window[:, 1:], st["conv"])}
+        _report_state_bytes(ctx, valid, st)
         g = _gated(y.reshape(b, 1, d_inner), z, weights, p, dt_)
         return [g @ weights["out_proj"].astype(dt_)]
 
@@ -187,8 +243,7 @@ def _mamba_lower(layer: Layer, inputs, weights, ctx: LoweringCtx):
         conv = sum(xp[:, j:j + s] * conv_w[j] for j in range(k))
         act = jax.nn.silu(conv + conv_b)
         u = act[..., :d_inner].reshape(r, s, heads, hd).astype(dt_)
-        b_m = act[..., d_inner:d_inner + n].astype(dt_)
-        c_m = act[..., d_inner + n:].astype(dt_)
+        b_m, c_m = (t.astype(dt_) for t in _b_and_c(act, p, (r, s)))
         y, ssm = ssd_scan(u, dt_r, a, b_m, c_m, p.get("chunk", 256))
         y = y + d_skip[None, None, :, None] * u.astype(jnp.float32)
         return _gated(y.reshape(r, s, d_inner), z_r, weights, p, dt_), ssm

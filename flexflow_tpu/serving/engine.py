@@ -215,12 +215,20 @@ def compile_serving(model, max_batch_slots: Optional[int] = None,
         compile_span.set(kv_layers=len(attn), state_layers=len(recurrent),
                          state_bytes_per_slot=state_bytes,
                          paged_state="paged_latent" if latent else "paged_kv")
-        for l in model.layers:
-            if l.op_type is OperatorType.MOE_LAYER:
-                lo, hi = l.params["experts_held"]
-                compile_span.set(experts_held=hi - lo,
-                                 experts_routed_over=l.params["num_experts"])
-                break
+        expert_layers = [l for l in model.layers
+                         if l.op_type is OperatorType.MOE_LAYER]
+        if expert_layers:
+            p = expert_layers[0].params
+            lo, hi = p["experts_held"]
+            # the width the experts' row buffers have: the layer's own, or
+            # the latent its experts work in
+            compile_span.set(
+                experts_held=hi - lo, experts_routed_over=p["num_experts"],
+                expert_layers=len(expert_layers),
+                experts_latent_dim=p.get("latent_size", 0))
+        if recurrent:
+            compile_span.set(ssm_groups=dec_model.get_layer_by_name(
+                next(iter(recurrent))).params.get("n_groups", 1))
         # tiered KV (--kv-host-pages H > 0): host pages SUBSTITUTE device
         # pages — the HBM pool shrinks to slots*pages_per_slot - H (floored
         # at one slot's worth, the minimum a decoding slot must keep hot),

@@ -551,6 +551,53 @@ def test_gigachat_serving_programs_fit_one_chip(described_devices, mosaic,
     _assert_appends_in_place(commit, eng, staged_at_most=6)
 
 
+def test_nemotron_serving_programs_fit_one_chip(described_devices, mosaic,
+                                                one_chip, monkeypatch):
+    """`NVIDIA-Nemotron-3-Super-120B-A12B-BF16.serve-chat`'s two programs at
+    the cell's own sizes (16 slots, width 1024, 9.30 GB of bf16 weights;
+    of 11 layers 5 keep a recurrent state of 4.26 MB a slot, 1 pages K/V
+    256 wide and 5 keep nothing), through the normal entry points: the
+    chip's compiler must hold the prefill wave beside the weights, the state
+    and the cache, the 8-group scans and the latent-wide grouped products
+    must be what it lowers to, and the decode step (352 pairs an expert
+    layer: the first to get a ladder of row rungs) appends to the pools it
+    was handed."""
+    eng, g, params, state = _described_engine(
+        "NVIDIA-Nemotron-3-Super-120B-A12B-BF16.serve-chat", described_devices,
+        monkeypatch, one_chip)
+    slots = eng.slots
+    spec = eng.kv_spec
+    assert (spec.layers, spec.heads, spec.head_dim) == (1, 2, 128)
+    assert spec.state_bytes_per_slot == 5 * (128 * 64 * 128 * 4
+                                             + 3 * 10240 * 2)
+    pool = eng.kv.state[eng.attn_layers[0]]["k"]
+    assert pool.shape == (slots * 80 + 1, 16, 256)
+    held = sum(x.size * x.dtype.itemsize
+               for x in jax.tree_util.tree_leaves(state))
+    assert 0.35e9 < held < 0.37e9
+    two = [_i32(one_chip, slots, 1)] * 2
+    decode = eng._decode_jit.lower(params, state, two).compile()
+    wave = [_i32(one_chip, slots, g.seq)] * 2
+    prefill = eng._prefill_first_tokens_jit.lower(
+        params, wave, _i32(one_chip, slots)).compile()
+    chip = 15.75e9          # what the compiler has of a v5e chip's 16 GB
+    for program, beside in ((decode, 0), (prefill, held)):
+        m = program.memory_analysis()
+        assert 9.29e9 < m.argument_size_in_bytes
+        need = (m.argument_size_in_bytes + m.output_size_in_bytes
+                + m.temp_size_in_bytes + beside)
+        assert need < 0.9 * chip, (need, m)
+    # the wave's temporaries: 1.97 GB when this was written
+    assert prefill.memory_analysis().temp_size_in_bytes < 2.5e9
+    assert decode.memory_analysis().temp_size_in_bytes < 0.1e9
+    text = prefill.as_text()
+    assert "ragged-dot" in text and "tpu_custom_call" in text
+    assert len(re.findall(r" conditional\(", text)) >= 5
+    text = decode.as_text()
+    assert "ragged-dot" in text
+    _assert_appends_in_place(decode, eng)
+
+
 def _entry_ops(text):
     """(op name, result type) of every instruction of optimized HLO's
     entry computation."""

@@ -252,14 +252,16 @@ def expert_layer_lines(events: List[Dict[str, Any]]) -> List[str]:
     (serve/prefill/device_wait: the waves; serve/decode/window_sync: the
     decode steps): the pairs held here of those routed, and beside it the
     rows the grouped product's buffers were sized for of the static
-    `tokens * k` (100 % where no block took a smaller rung)."""
+    `tokens * k` (100 % where no block took a smaller rung); for decode
+    steps of a model with state-space layers, a second line with the
+    recurrent state moved and the held experts hit, a step."""
     sums: Dict[str, Dict[str, float]] = {}
     for ev in events:
         args = ev.get("args") or {}
         if ev.get("ph") == "X" and "moe_routed_pairs" in args:
             into = sums.setdefault(ev["name"], {})
             for k, v in args.items():
-                if k.startswith("moe_"):
+                if k.startswith("moe_") or k in ("ssm_state_bytes", "steps"):
                     into[k] = into.get(k, 0) + v
     lines = []
     for name in sorted(sums):
@@ -272,6 +274,12 @@ def expert_layer_lines(events: List[Dict[str, Any]]) -> List[str]:
                      f"{100.0 * a.get('moe_rows_computed', 0) / a['moe_rows_static']:.2f}% "
                      f"of {int(a['moe_rows_static'])} static")
         lines.append(line)
+        if a.get("ssm_state_bytes") and a.get("steps"):
+            lines.append(
+                f"[serve] state-space layers in {name}: "
+                f"{a['ssm_state_bytes'] / a['steps'] / 1e6:.1f} MB of "
+                f"recurrent state read and written a step, "
+                f"{a['moe_experts_hit'] / a['steps']:.1f} held experts hit")
     return lines
 
 
