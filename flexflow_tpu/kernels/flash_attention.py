@@ -12,6 +12,22 @@ Forward saves the per-row logsumexp; the backward pass is two more pallas
 kernels (dq gridded over q blocks; dk/dv gridded over k blocks) recomputing
 the probabilities from the saved lse — the flash-attention v2 recipe.
 
+A VMEM budget bounds a block from above (`_blocks_for`). A non-causal call
+takes that bound as its tile and loops over the key blocks with the online
+softmax. A causal call holds a block of that size per grid step and tiles
+the score matrix under it (`_tiles`: never the whole causal square where a
+smaller tile divides the sequence): the key blocks before the step's own
+lie wholly under the diagonal and are met whole in a loop; inside the
+step's own block, which the diagonal crosses, every tile's place is static,
+so each row of tiles meets its tiles wholly under the diagonal in one
+unmasked product and the tiles the diagonal crosses in one masked product,
+and the tiles above the diagonal are not visited (`_k_tile_bounds`,
+`_q_tile_bounds`: exact for unequal q and k tiles). Where the block is the
+whole sequence (GPT-2's 1024 at head_dim 64) no statistic is carried from
+tile to tile at all. Measured on a v5e (PERF.md, Findings PR 36): a loop
+over small tiles with a trip count known only at run time costs more than
+the masked half it skips; the static schedule does not.
+
 All matmuls accumulate in float32 (preferred_element_type) regardless of the
 input dtype; bf16 inputs hit the MXU at full rate.
 """
@@ -25,6 +41,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from flexflow_tpu import telemetry as tel
+
 _BLOCK_CANDIDATES = (1024, 512, 256, 128)
 _NEG_INF = float("-inf")
 # k/v (fwd/dq) and q/do (dk/dv) are held fully in VMEM per (b, h) grid step;
@@ -32,22 +50,26 @@ _NEG_INF = float("-inf")
 # on shapes that pass the divisibility checks. Longer sequences belong to the
 # ring-attention path (kernels/ring_attention.py).
 _VMEM_SEQ_BYTES = 6 * 1024 * 1024
-# per-BLOCK VMEM budget: the block-shape ceiling was implicitly sized for
-# head_dim 64 (a 512 x 64 f32 block = 128KB). Wider heads scale the block
-# footprint linearly, so the block choice is parametrized by (depth,
-# itemsize): head_dim 128 f32 drops 512 -> 256 instead of handing Mosaic a
-# 256KB block per operand (q, do, dq accumulators all carry it); bf16 keeps
-# the full 512. 160KB leaves the d=64 behavior exactly as before.
+# per-BLOCK VMEM budget, the bound on a block from above: a grid step's
+# operands (q, do, dq accumulators) each carry rows x depth x itemsize, so
+# the bound is parametrized by (depth, itemsize): head_dim 128 f32 takes
+# 256 rows, bf16 512; head_dim 192 bf16 256.
 _VMEM_BLOCK_BYTES = 160 * 1024
-# narrow heads (d <= 64, GPT-2's case) get a larger
-# per-block budget: a 1024 x 64 f32 block is 256KB and three such operands
-# are still < 1MB of VMEM, while the doubled rows-per-grid-step halve the
-# k/v streaming overhead that starves the MXU at short blocks. Wider heads
-# keep the 160KB budget (d=128 behavior unchanged: f32 -> 256, bf16 -> 512).
+# narrow heads (d <= 64, GPT-2's case) may hold more rows a grid step: a
+# 1024 x 64 f32 block is 256KB and three such operands are still < 1MB of
+# VMEM. It is a bound and no longer the causal tile: a non-causal call takes
+# it, a causal call holds a block of it and tiles under it (`_tiles`).
 _VMEM_BLOCK_BYTES_NARROW = 256 * 1024
+# the causal tile of narrow heads, from a sweep of each kernel on a v5e at
+# [8, 16, 1024, 64] bf16 (PERF.md, Findings PR 36): 256 is within 4 % of
+# each kernel's best and 11-13 % ahead of 512 in the two backward kernels.
+# Wider heads keep their bound (512 at head_dim 128 bf16 measured equal to
+# 256 there).
+_CAUSAL_TILE_NARROW = 256
 
 
 def _blocks_for(depth: int, itemsize: int):
+    """The candidate blocks the VMEM budget admits, largest first."""
     budget = _VMEM_BLOCK_BYTES_NARROW if depth <= 64 else _VMEM_BLOCK_BYTES
     ok = tuple(b for b in _BLOCK_CANDIDATES
                if b * max(1, depth) * itemsize <= budget)
@@ -67,30 +89,117 @@ def flash_supported(seq: int, depth: int, itemsize: int = 4) -> bool:
     return False
 
 
-def _pick_block(s: int, depth: int = 64, itemsize: int = 4,
-                env: str = "FLEXFLOW_FLASH_BLOCK") -> int:
+def _forced_block(env: str, s: int, cands) -> int:
+    """The tuning override's block, 0 where unset or unusable: only
+    known-safe sizes count (the per-block VMEM budget was sized for
+    _blocks_for's output; arbitrary values could OOM Mosaic)."""
     import os
 
-    cands = _blocks_for(depth, itemsize)
     try:
         forced = int(os.environ.get(env, "0") or "0")
     except ValueError:
-        forced = 0
-    # tuning override: only known-safe block sizes (the per-block VMEM
-    # budget was sized for _blocks_for's output; arbitrary values could
-    # OOM Mosaic)
-    if forced in cands and s % forced == 0:
+        return 0
+    return forced if forced in cands and s % forced == 0 else 0
+
+
+def _bound(s: int, depth: int, itemsize: int) -> int:
+    """The largest tile the VMEM budget admits that divides `s`."""
+    cands = _blocks_for(depth, itemsize)
+    for b in cands:
+        if s % b == 0:
+            return b
+    raise ValueError(f"sequence length {s} not divisible by any of {cands} "
+                     f"(head_dim {depth}, itemsize {itemsize})")
+
+
+def _pick_block(s: int, depth: int = 64, itemsize: int = 4,
+                env: str = "FLEXFLOW_FLASH_BLOCK") -> int:
+    """`_bound`, or what the tuning override forces: what a non-causal
+    call takes, and what a causal call picks under (`_tiles`)."""
+    forced = _forced_block(env, s, _blocks_for(depth, itemsize))
+    if forced:
         return forced
     if env != "FLEXFLOW_FLASH_BLOCK":
         # bwd knob unset OR invalid: inherit the main block choice (so a
         # typo'd bwd value degrades to the fwd configuration, not to a
         # third configuration nobody asked for)
         return _pick_block(s, depth, itemsize)
-    for b in cands:
-        if s % b == 0:
-            return b
-    raise ValueError(f"sequence length {s} not divisible by any of {cands} "
-                     f"(head_dim {depth}, itemsize {itemsize})")
+    return _bound(s, depth, itemsize)
+
+
+KERNELS = ("fwd", "dq", "dkv")
+
+
+def _tiles(kernel: str, seq_q: int, seq_k: int, depth: int, itemsize: int,
+           causal: bool):
+    """(bq, bk) of one of the three kernels, from what it can see. A
+    non-causal call, and one a tuning override speaks for, takes
+    `_pick_block`. A causal call (seq_q == seq_k) picks under that bound:
+    narrow heads at most `_CAUSAL_TILE_NARROW`, and no call the whole
+    causal square where a smaller tile divides the sequence, so that the
+    tiles above the diagonal can be skipped."""
+    env = ("FLEXFLOW_FLASH_BLOCK" if kernel == "fwd"
+           else "FLEXFLOW_FLASH_BLOCK_BWD")
+    bq = _pick_block(seq_q, depth, itemsize, env)
+    bk = _pick_block(seq_k, depth, itemsize, env)
+    cands = _blocks_for(depth, itemsize)
+    if not causal or _forced_block(env, seq_q, cands) \
+            or _forced_block("FLEXFLOW_FLASH_BLOCK", seq_q, cands):
+        return bq, bk
+    tile = min(bq, _CAUSAL_TILE_NARROW) if depth <= 64 else bq
+    if tile == seq_q:
+        tile = next((b for b in _BLOCK_CANDIDATES
+                     if b < seq_q and seq_q % b == 0), tile)
+    return tile, tile
+
+
+# ------------------------------------------------------------ causal schedule
+def _k_tile_bounds(q_start, bq: int, bk: int):
+    """Of the k tiles of width `bk`, seen from the q rows [q_start, q_start
+    + bq) of a causal call: tiles [0, full) lie wholly at or under the
+    diagonal (every pair unmasked), [full, visit) are crossed by it, and
+    from `visit` on no pair is unmasked. Exact for any bq, bk; `q_start`
+    may be traced."""
+    return (q_start + 1) // bk, (q_start + bq - 1) // bk + 1
+
+
+def _q_tile_bounds(k_start, bq: int, bk: int):
+    """The same seen from the k columns [k_start, k_start + bk): q tiles
+    before `visit` hold no unmasked pair, [visit, full) are crossed by the
+    diagonal, from `full` on every pair is unmasked."""
+    return k_start // bq, (k_start + bk + bq - 2) // bq
+
+
+def _schedule(kernel: str, seq_q: int, seq_k: int, bq: int, bk: int,
+              causal: bool):
+    """(visited, masked, total) tiles of one (batch, head)'s score matrix
+    for a kernel at tiles (bq, bk), counted with the kernel's own bounds:
+    `visited` are computed, `masked` of them take the causal mask."""
+    nq, nk = seq_q // bq, seq_k // bk
+    if not causal:
+        return nq * nk, 0, nq * nk
+    if kernel == "dkv":         # a k tile a grid step, looping over q tiles
+        spans = [_q_tile_bounds(j * bk, bq, bk) for j in range(nk)]
+        return (sum(nq - first for first, _ in spans),
+                sum(full - first for first, full in spans), nq * nk)
+    spans = [_k_tile_bounds(i * bq, bq, bk) for i in range(nq)]
+    return (sum(visit for _, visit in spans),
+            sum(visit - full for full, visit in spans), nq * nk)
+
+
+def tile_plan(seq_q: int, seq_k: int, depth: int, itemsize: int,
+              causal: bool) -> dict:
+    """What each of the three kernels does at this shape, as the lowering
+    span reports it: its tile and the `_schedule` counts."""
+    plan = {}
+    for kernel in KERNELS:
+        bq, bk = _tiles(kernel, seq_q, seq_k, depth, itemsize, causal)
+        visited, masked, total = _schedule(kernel, seq_q, seq_k, bq, bk, causal)
+        plan[kernel] = {"flash_tile_q": bq, "flash_tile_k": bk,
+                        "flash_tiles_visited": visited,
+                        "flash_tiles_masked": masked,
+                        "flash_tiles_total": total}
+    return plan
 
 
 def _interpret() -> bool:
@@ -105,192 +214,309 @@ def _params():
         dimension_semantics=("parallel", "parallel", "arbitrary"))
 
 
+def _traced_once(arrays: int):
+    """`call(*arrays, causal, scale, bq, bk, interpret)` under `jax.jit`,
+    all but the arrays static: a model's layers call a kernel at one shape,
+    and a trace of the step then traces and lowers the kernel's body once
+    and not once a layer (the static schedule under the diagonal is
+    straight-line code, twice the parent's loop to trace). Interpret mode
+    is part of the key: tests switch it within a process."""
+    def wrap(call):
+        jitted = jax.jit(call, static_argnums=tuple(range(arrays, arrays + 5)))
+        return lambda *args: jitted(*args, _interpret())
+    return wrap
+
+
+def _under_diagonal(q_start, k_start, bq: int, bk: int):
+    """(bq, bk) bool: the pairs of a tile the diagonal crosses whose query
+    is at or after the key."""
+    ahead = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0) \
+        - jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+    return ahead >= k_start - q_start
+
+
+def _nt(a, b):
+    """a (m, d) . b (n, d)^T -> (m, n) f32."""
+    return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _nn(a, b):
+    """a (m, n) . b (n, d) -> (m, d) f32."""
+    return jax.lax.dot_general(a, b, (((1,), (0,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _tn(a, b):
+    """a (n, m)^T . b (n, d) -> (m, d) f32."""
+    return jax.lax.dot_general(a, b, (((0,), (0,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _online(carry, parts):
+    """One online-softmax step of some q rows over `parts`, a list of
+    (scores f32 (r, n), values (n, d)) that are met together: one max and
+    one sum over all of them, so only `carry` (None, or the rows' (m, l,
+    acc) so far) is rescaled."""
+    m = functools.reduce(
+        jnp.maximum, [jnp.max(s, axis=-1, keepdims=True) for s, _ in parts])
+    if carry is not None:
+        m = jnp.maximum(carry[0], m)
+    l = acc = 0.0
+    for s, v in parts:
+        p = jnp.exp(s - m)
+        l = l + jnp.sum(p, axis=-1, keepdims=True)
+        acc = acc + _nn(p.astype(v.dtype), v)
+    if carry is not None:
+        alpha = jnp.exp(carry[0] - m)
+        l, acc = l + carry[1] * alpha, acc + carry[2] * alpha
+    return m, l, acc
+
+
+def _at(base, offset: int, size: int, align: int):
+    """Rows [base + offset, base + offset + size) of a resident operand;
+    `base` is 0 or a traced multiple of `align`, as is `offset`."""
+    if isinstance(base, int):
+        return pl.ds(base + offset, size)
+    return pl.ds(pl.multiple_of(base + offset, align), size)
+
+
 # --------------------------------------------------------------------- forward
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal, block_k):
-    q = q_ref[0, 0]                                # (bq, d), input dtype (MXU bf16)
-    bq, d = q.shape
+# Each kernel: the whole key (or q) blocks in a loop first, `carry` None
+# where there are none; then, causal, the step's own block by static tiles.
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal, bq, bk):
+    gq, d = q_ref.shape[2:]
     sk = k_ref.shape[2]
     qi = pl.program_id(2)
-    q_start = qi * bq
+    block_k = gq if causal else bk
 
-    if causal:
-        nk_loop = (q_start + bq) // block_k        # blocks at/under the diagonal
-    else:
-        nk_loop = sk // block_k
-
-    def body(ki, carry):
+    def finish(carry, rows):
         m, l, acc = carry
-        k = k_ref[0, 0, pl.ds(ki * block_k, block_k), :]
-        v = v_ref[0, 0, pl.ds(ki * block_k, block_k), :]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        if causal:
-            row = q_start + jax.lax.broadcasted_iota(jnp.int32, (bq, block_k), 0)
-            col = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, (bq, block_k), 1)
-            s = jnp.where(row >= col, s, _NEG_INF)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m - m_new)
-        l = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc = acc * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        return m_new, l, acc
+        o_ref[0, 0, rows, :] = (acc / l).astype(o_ref.dtype)
+        lse_ref[0, 0, rows, :] = m + jnp.log(l)         # (rows, 1)
 
-    m0 = jnp.full((bq, 1), _NEG_INF, jnp.float32)
-    l0 = jnp.zeros((bq, 1), jnp.float32)
-    a0 = jnp.zeros((bq, d), jnp.float32)
-    m, l, acc = jax.lax.fori_loop(0, nk_loop, body, (m0, l0, a0))
-    o_ref[0, 0] = (acc / l).astype(o_ref.dtype)
-    lse_ref[0, 0] = m + jnp.log(l)                 # (bq, 1)
+    def whole_block(ki, carry):
+        keys = _at(ki * block_k, 0, block_k, block_k)
+        s = _nt(q_ref[0, 0], k_ref[0, 0, keys, :]) * scale
+        return _online(carry, [(s, v_ref[0, 0, keys, :])])
+
+    carry = None
+    if not causal or gq != sk:
+        carry = jax.lax.fori_loop(
+            0, qi if causal else sk // block_k, whole_block,
+            (jnp.full((gq, 1), _NEG_INF, jnp.float32),
+             jnp.zeros((gq, 1), jnp.float32), jnp.zeros((gq, d), jnp.float32)))
+    if not causal:
+        finish(carry, slice(None))
+        return
+    base = 0 if gq == sk else qi * gq
+    for q_at in range(0, gq, bq):
+        rows = slice(q_at, q_at + bq)
+        q = q_ref[0, 0, rows, :]
+        full, visit = _k_tile_bounds(q_at, bq, bk)
+        parts = []
+        if full:
+            keys = _at(base, 0, full * bk, bk)
+            parts.append((_nt(q, k_ref[0, 0, keys, :]) * scale,
+                          v_ref[0, 0, keys, :]))
+        keys = _at(base, full * bk, (visit - full) * bk, bk)
+        s = _nt(q, k_ref[0, 0, keys, :]) * scale
+        # every row meets its own position here: its max is finite
+        s = jnp.where(_under_diagonal(q_at, full * bk, *s.shape), s, _NEG_INF)
+        parts.append((s, v_ref[0, 0, keys, :]))
+        before = None if carry is None else tuple(x[rows] for x in carry)
+        finish(_online(before, parts), rows)
 
 
-def _fwd(q, k, v, causal, scale):
-    """q: (b, h, sq, d); k/v: (b, h, sk, d) -> (o, lse)."""
+def _grid_block(seq: int, depth: int, itemsize: int, causal: bool,
+                tile: int, other: int) -> int:
+    """Rows of q (fwd, dq) or k (dkv) a grid step holds: a non-causal
+    step its tile; a causal one the budget's bound, tiled inside."""
+    return max(_bound(seq, depth, itemsize), tile, other) if causal else tile
+
+
+@_traced_once(3)
+def _fwd_call(q, k, v, causal, scale, bq, bk, interpret):
     b, h, sq, d = q.shape
     sk = k.shape[2]
-    bq = _pick_block(sq, d, q.dtype.itemsize)
-    bk = _pick_block(sk, d, k.dtype.itemsize)
-    kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal, block_k=bk)
-    o, lse = pl.pallas_call(
+    gq = _grid_block(sq, d, q.dtype.itemsize, causal, bq, bk)
+    kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
+                               bq=bq, bk=bk)
+    return pl.pallas_call(
         kernel,
-        grid=(b, h, sq // bq),
+        grid=(b, h, sq // gq),
         in_specs=[
-            pl.BlockSpec((1, 1, bq, d), lambda b_, h_, i: (b_, h_, i, 0)),
+            pl.BlockSpec((1, 1, gq, d), lambda b_, h_, i: (b_, h_, i, 0)),
             pl.BlockSpec((1, 1, sk, d), lambda b_, h_, i: (b_, h_, 0, 0)),
             pl.BlockSpec((1, 1, sk, d), lambda b_, h_, i: (b_, h_, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, bq, d), lambda b_, h_, i: (b_, h_, i, 0)),
+            pl.BlockSpec((1, 1, gq, d), lambda b_, h_, i: (b_, h_, i, 0)),
             # lse is (b, h, sq, 1): the trailing singleton keeps the block's
-            # last-two dims TPU-tileable ((bq, 1) with 1 == full array dim)
-            pl.BlockSpec((1, 1, bq, 1), lambda b_, h_, i: (b_, h_, i, 0)),
+            # last-two dims TPU-tileable ((gq, 1) with 1 == full array dim)
+            pl.BlockSpec((1, 1, gq, 1), lambda b_, h_, i: (b_, h_, i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
             jax.ShapeDtypeStruct((b, h, sq, 1), jnp.float32),
         ],
         compiler_params=_params(),
-        interpret=_interpret(),
+        interpret=interpret,
         name="ff_flash_attention_fwd",
     )(q, k, v)
-    return o, lse
+
+
+def _fwd(q, k, v, causal, scale):
+    """q: (b, h, sq, d); k/v: (b, h, sk, d) -> (o, lse)."""
+    bq, bk = _tiles("fwd", q.shape[2], k.shape[2], q.shape[3],
+                    q.dtype.itemsize, causal)
+    return _fwd_call(q, k, v, causal, scale, bq, bk)
 
 
 # -------------------------------------------------------------------- backward
+def _ds(q, k, v, do, lse, delta, scale, mask):
+    """(p, ds) f32 (rows, keys) of some q rows against some keys, the
+    probabilities recomputed from the saved lse; `mask` where the diagonal
+    crosses."""
+    p = jnp.exp(_nt(q, k) * scale - lse)
+    if mask is not None:
+        p = jnp.where(mask, p, 0.0)
+    return p, p * (_nt(do, v) - delta) * scale
+
+
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-               *, scale, causal, block_k):
-    q = q_ref[0, 0]                                # input dtype: MXU-rate dots
-    do = do_ref[0, 0]
-    lse = lse_ref[0, 0]                            # (bq, 1) f32
-    delta = delta_ref[0, 0]
-    bq, d = q.shape
+               *, scale, causal, bq, bk):
+    gq, d = q_ref.shape[2:]
     sk = k_ref.shape[2]
     qi = pl.program_id(2)
-    q_start = qi * bq
-    nk_loop = (q_start + bq) // block_k if causal else sk // block_k
+    block_k = gq if causal else bk
 
-    def body(ki, dq_acc):
-        k = k_ref[0, 0, pl.ds(ki * block_k, block_k), :]
-        v = v_ref[0, 0, pl.ds(ki * block_k, block_k), :]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        p = jnp.exp(s - lse)
-        if causal:
-            row = q_start + jax.lax.broadcasted_iota(jnp.int32, (bq, block_k), 0)
-            col = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, (bq, block_k), 1)
-            p = jnp.where(row >= col, p, 0.0)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta) * scale).astype(k.dtype)
-        return dq_acc + jax.lax.dot_general(ds, k, (((1,), (0,)), ((), ())),
-                                            preferred_element_type=jnp.float32)
+    def dq_of(rows, keys, mask=None):
+        k = k_ref[0, 0, keys, :]
+        _, ds = _ds(q_ref[0, 0, rows, :], k, v_ref[0, 0, keys, :],
+                    do_ref[0, 0, rows, :], lse_ref[0, 0, rows, :],
+                    delta_ref[0, 0, rows, :], scale, mask)
+        return _nn(ds.astype(k.dtype), k)
 
-    dq = jax.lax.fori_loop(0, nk_loop, body, jnp.zeros((bq, d), jnp.float32))
-    dq_ref[0, 0] = dq.astype(dq_ref.dtype)
+    def whole_block(ki, dq):
+        return dq + dq_of(slice(None), _at(ki * block_k, 0, block_k, block_k))
+
+    dq = None
+    if not causal or gq != sk:
+        dq = jax.lax.fori_loop(0, qi if causal else sk // block_k, whole_block,
+                               jnp.zeros((gq, d), jnp.float32))
+    if not causal:
+        dq_ref[0, 0] = dq.astype(dq_ref.dtype)
+        return
+    base = 0 if gq == sk else qi * gq
+    for q_at in range(0, gq, bq):
+        rows = slice(q_at, q_at + bq)
+        full, visit = _k_tile_bounds(q_at, bq, bk)
+        crossed = (visit - full) * bk
+        acc = dq_of(rows, _at(base, full * bk, crossed, bk),
+                    _under_diagonal(q_at, full * bk, bq, crossed))
+        if full:
+            acc = acc + dq_of(rows, _at(base, 0, full * bk, bk))
+        if dq is not None:
+            acc = acc + dq[rows]
+        dq_ref[0, 0, rows, :] = acc.astype(dq_ref.dtype)
 
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
-                *, scale, causal, block_q):
-    k = k_ref[0, 0]                                # (bk, d), input dtype
-    v = v_ref[0, 0]
-    bk, d = k.shape
+                *, scale, causal, bq, bk):
+    gk, d = k_ref.shape[2:]
     sq = q_ref.shape[2]
-    ki = pl.program_id(2)
-    k_start = ki * bk
-    nq = sq // block_q
-    qi_start = k_start // block_q if causal else 0
+    kj = pl.program_id(2)
+    block_q = gk if causal else bq
 
-    def body(qi, carry):
-        dk_acc, dv_acc = carry
-        q = q_ref[0, 0, pl.ds(qi * block_q, block_q), :]
-        do = do_ref[0, 0, pl.ds(qi * block_q, block_q), :]
-        lse = lse_ref[0, 0, pl.ds(qi * block_q, block_q), :]
-        delta = delta_ref[0, 0, pl.ds(qi * block_q, block_q), :]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        p = jnp.exp(s - lse)                        # (bq, bk) f32
-        if causal:
-            row = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, bk), 0)
-            col = k_start + jax.lax.broadcasted_iota(jnp.int32, (block_q, bk), 1)
-            p = jnp.where(row >= col, p, 0.0)
-        pc = p.astype(do.dtype)
-        dv_acc = dv_acc + jax.lax.dot_general(pc, do, (((0,), (0,)), ((), ())),
-                                              preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta) * scale).astype(q.dtype)
-        dk_acc = dk_acc + jax.lax.dot_general(ds, q, (((0,), (0,)), ((), ())),
-                                              preferred_element_type=jnp.float32)
-        return dk_acc, dv_acc
+    def dkv_of(rows, keys, mask=None):
+        q, do = q_ref[0, 0, rows, :], do_ref[0, 0, rows, :]
+        p, ds = _ds(q, k_ref[0, 0, keys, :], v_ref[0, 0, keys, :], do,
+                    lse_ref[0, 0, rows, :], delta_ref[0, 0, rows, :], scale,
+                    mask)
+        return _tn(ds.astype(q.dtype), q), _tn(p.astype(do.dtype), do)
 
-    z = jnp.zeros((bk, d), jnp.float32)
-    dk, dv = jax.lax.fori_loop(qi_start, nq, body, (z, z))
-    dk_ref[0, 0] = dk.astype(dk_ref.dtype)
-    dv_ref[0, 0] = dv.astype(dv_ref.dtype)
+    def whole_block(qi, carry):
+        dk, dv = dkv_of(_at(qi * block_q, 0, block_q, block_q), slice(None))
+        return carry[0] + dk, carry[1] + dv
+
+    carry = None
+    if not causal or gk != sq:
+        z = jnp.zeros((gk, d), jnp.float32)
+        carry = jax.lax.fori_loop(kj + 1 if causal else 0, sq // block_q,
+                                  whole_block, (z, z))
+    if not causal:
+        dk_ref[0, 0] = carry[0].astype(dk_ref.dtype)
+        dv_ref[0, 0] = carry[1].astype(dv_ref.dtype)
+        return
+    base = 0 if gk == sq else kj * gk
+    for k_at in range(0, gk, bk):
+        keys = slice(k_at, k_at + bk)
+        first, full = _q_tile_bounds(k_at, bq, bk)
+        crossed = (full - first) * bq
+        dk, dv = dkv_of(_at(base, first * bq, crossed, bq), keys,
+                        _under_diagonal(first * bq, k_at, crossed, bk))
+        if full * bq < gk:
+            more = dkv_of(_at(base, full * bq, gk - full * bq, bq), keys)
+            dk, dv = dk + more[0], dv + more[1]
+        if carry is not None:
+            dk, dv = dk + carry[0][keys], dv + carry[1][keys]
+        dk_ref[0, 0, keys, :] = dk.astype(dk_ref.dtype)
+        dv_ref[0, 0, keys, :] = dv.astype(dv_ref.dtype)
 
 
-def _bwd(causal, scale, res, g):
-    q, k, v, o, lse = res
+@_traced_once(6)
+def _dq_call(q, k, v, g, lse, delta, causal, scale, bq, bk, interpret):
     b, h, sq, d = q.shape
     sk = k.shape[2]
-    # FLEXFLOW_FLASH_BLOCK_BWD tunes the backward independently (the dq /
-    # dkv kernels have different VMEM/recompute balance than the forward);
-    # unset = inherit FLEXFLOW_FLASH_BLOCK's choice
-    bq = _pick_block(sq, d, q.dtype.itemsize, env="FLEXFLOW_FLASH_BLOCK_BWD")
-    bk = _pick_block(sk, d, k.dtype.itemsize, env="FLEXFLOW_FLASH_BLOCK_BWD")
-    do = g.astype(jnp.float32)
-    delta = jnp.sum(do * o.astype(jnp.float32), axis=-1, keepdims=True)  # (b, h, sq, 1)
-
-    q_spec = pl.BlockSpec((1, 1, bq, d), lambda b_, h_, i: (b_, h_, i, 0))
+    gq = _grid_block(sq, d, q.dtype.itemsize, causal, bq, bk)
+    q_spec = pl.BlockSpec((1, 1, gq, d), lambda b_, h_, i: (b_, h_, i, 0))
     k_full = pl.BlockSpec((1, 1, sk, d), lambda b_, h_, i: (b_, h_, 0, 0))
-    q_full = pl.BlockSpec((1, 1, sq, d), lambda b_, h_, i: (b_, h_, 0, 0))
-    k_spec = pl.BlockSpec((1, 1, bk, d), lambda b_, h_, i: (b_, h_, i, 0))
-    vec_q = pl.BlockSpec((1, 1, bq, 1), lambda b_, h_, i: (b_, h_, i, 0))
-    vec_full = pl.BlockSpec((1, 1, sq, 1), lambda b_, h_, i: (b_, h_, 0, 0))
-
-    dq = pl.pallas_call(
-        functools.partial(_dq_kernel, scale=scale, causal=causal, block_k=bk),
-        grid=(b, h, sq // bq),
+    vec_q = pl.BlockSpec((1, 1, gq, 1), lambda b_, h_, i: (b_, h_, i, 0))
+    return pl.pallas_call(
+        functools.partial(_dq_kernel, scale=scale, causal=causal, bq=bq, bk=bk),
+        grid=(b, h, sq // gq),
         in_specs=[q_spec, k_full, k_full, q_spec, vec_q, vec_q],
         out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
         compiler_params=_params(),
-        interpret=_interpret(),
+        interpret=interpret,
         name="ff_flash_attention_dq",
     )(q, k, v, g, lse, delta)
 
-    dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, scale=scale, causal=causal, block_q=bq),
-        grid=(b, h, sk // bk),
+
+@_traced_once(6)
+def _dkv_call(q, k, v, g, lse, delta, causal, scale, bq, bk, interpret):
+    b, h, sq, d = q.shape
+    sk = k.shape[2]
+    gk = _grid_block(sk, d, k.dtype.itemsize, causal, bk, bq)
+    q_full = pl.BlockSpec((1, 1, sq, d), lambda b_, h_, i: (b_, h_, 0, 0))
+    k_spec = pl.BlockSpec((1, 1, gk, d), lambda b_, h_, i: (b_, h_, i, 0))
+    vec_full = pl.BlockSpec((1, 1, sq, 1), lambda b_, h_, i: (b_, h_, 0, 0))
+    return pl.pallas_call(
+        functools.partial(_dkv_kernel, scale=scale, causal=causal, bq=bq, bk=bk),
+        grid=(b, h, sk // gk),
         in_specs=[q_full, k_spec, k_spec, q_full, vec_full, vec_full],
         out_specs=[k_spec, k_spec],
         out_shape=[jax.ShapeDtypeStruct((b, h, sk, d), k.dtype),
                    jax.ShapeDtypeStruct((b, h, sk, d), v.dtype)],
         compiler_params=_params(),
-        interpret=_interpret(),
+        interpret=interpret,
         name="ff_flash_attention_dkv",
     )(q, k, v, g, lse, delta)
+
+
+def _bwd(causal, scale, res, g):
+    q, k, v, o, lse = res
+    # FLEXFLOW_FLASH_BLOCK_BWD tunes the backward independently (the dq /
+    # dkv kernels have different VMEM/recompute balance than the forward);
+    # unset = inherit FLEXFLOW_FLASH_BLOCK's choice
+    shape = (q.shape[2], k.shape[2], q.shape[3], q.dtype.itemsize, causal)
+    do = g.astype(jnp.float32)
+    delta = jnp.sum(do * o.astype(jnp.float32), axis=-1, keepdims=True)  # (b, h, sq, 1)
+    dq = _dq_call(q, k, v, g, lse, delta, causal, scale, *_tiles("dq", *shape))
+    dk, dv = _dkv_call(q, k, v, g, lse, delta, causal, scale,
+                       *_tiles("dkv", *shape))
     return dq, dk, dv
 
 
@@ -335,7 +561,14 @@ def flash_attention(q, k, v, causal: bool = False, scale: float | None = None):
                 f"({_VMEM_SEQ_BYTES} bytes); use the einsum or ring path")
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
-    return _flash(q, k, v, causal, float(scale))
+    # one span a lowered call (trace time): the schedule each of the three
+    # kernels takes at this shape, for tools/trace_report.py
+    with tel.span("lower/flash_attention", cat="compile",
+                  batch_heads=q.shape[0] * q.shape[1], seq_q=q.shape[2],
+                  seq_k=k.shape[2], depth=q.shape[3], causal=bool(causal),
+                  kernels=tile_plan(q.shape[2], k.shape[2], q.shape[3],
+                                    q.dtype.itemsize, causal)):
+        return _flash(q, k, v, causal, float(scale))
 
 
 def flash_attention_qkv(q, k, v, causal: bool = False, scale: float | None = None):

@@ -132,6 +132,33 @@ def test_flash_attention_backward(one_chip, mosaic):
     assert compiled.as_text().count("tpu_custom_call") >= 3
 
 
+@pytest.mark.parametrize("bq,bk", [(256, 256), (256, 128), (128, 256)])
+def test_flash_attention_causal_tiles(one_chip, mosaic, bq, bk):
+    """The three kernels at the tiles the causal rule takes at GPT-2
+    medium's shape, and at unequal q and k tiles both ways: the static
+    schedule under the diagonal (slices at tile multiples, a masked product
+    per row of tiles) has to pass Mosaic, not only interpret mode."""
+    import importlib
+
+    fa = importlib.import_module("flexflow_tpu.kernels.flash_attention")
+    if bq == bk:
+        plan = fa.tile_plan(S, S, D, 2, True)
+        for kern in fa.KERNELS:
+            assert (plan[kern]["flash_tile_q"], plan[kern]["flash_tile_k"]) \
+                == (bq, bk)
+            assert plan[kern]["flash_tiles_visited"] \
+                < plan[kern]["flash_tiles_total"]
+    qkv = ((B, H, S, D), jnp.bfloat16)
+    vec = ((B, H, S, 1), jnp.float32)
+    scale = 1.0 / np.sqrt(D)
+    _compile(lambda q, k, v: fa._fwd_call(q, k, v, True, scale, bq, bk),
+             one_chip, qkv, qkv, qkv)
+    _compile(lambda *a: fa._dq_call(*a, True, scale, bq, bk),
+             one_chip, qkv, qkv, qkv, qkv, vec, vec)
+    _compile(lambda *a: fa._dkv_call(*a, True, scale, bq, bk),
+             one_chip, qkv, qkv, qkv, qkv, vec, vec)
+
+
 def test_fused_ce_forward_backward(one_chip, mosaic):
     from flexflow_tpu.kernels.fused_ce import (fused_ce_supported,
                                                fused_cross_entropy)

@@ -145,20 +145,30 @@ def test_head_dim_128_parity(causal):
 
 
 @pytest.mark.parametrize("causal", [False, True])
-def test_head_dim_64_retuned_blocks_parity(causal):
-    """Satellite (ISSUE 12): narrow heads waste the depth-sized budget —
-    head_dim <= 64 gets its own VMEM budget so long sequences keep the
-    1024-row block (fewer grid steps, better MXU occupancy). The retune
-    must leave every depth>=128 pick and the d=64 short-seq picks alone,
-    and match the einsum reference in fwd AND grads at the new block."""
-    from flexflow_tpu.kernels.flash_attention import _pick_block
+def test_head_dim_64_tile_rule_parity(causal):
+    """ISSUE 36: the VMEM budget bounds a tile from above (narrow heads up
+    to 1024 rows) and a causal call picks under it: never the whole causal
+    square where a smaller tile divides the sequence, so the tiles above
+    the diagonal are skipped. A non-causal call keeps the bound. Forward
+    and gradients match the einsum reference at the tiles the rule takes."""
+    from flexflow_tpu.kernels.flash_attention import (KERNELS, _pick_block,
+                                                      _tiles)
 
-    # the retuned pick: d=64 f32 at seq 1024 now keeps the 1024 block
+    # the bound, as before: d=64 at seq 1024 may take the 1024 block
     assert _pick_block(1024, 64, 4) == 1024
     # the d=128 pins of the round-5 retune still hold
     assert _pick_block(512, 64, 4) == 512
     assert _pick_block(512, 128, 4) == 256
     assert _pick_block(512, 128, 2) == 512
+    for kern in KERNELS:
+        for itemsize in (2, 4):
+            bq, bk = _tiles(kern, 1024, 1024, 64, itemsize, causal)
+            if causal:
+                assert max(bq, bk) < 1024 and 1024 % bq == 0 == 1024 % bk
+            else:
+                assert (bq, bk) == (1024, 1024)
+        # a sequence of one candidate tile has no smaller tile to take
+        assert _tiles(kern, 128, 128, 64, 2, causal) == (128, 128)
 
     rng = np.random.default_rng(6)
     b, h, s, d = 1, 2, 1024, 64
@@ -182,6 +192,192 @@ def test_head_dim_64_retuned_blocks_parity(causal):
     for a, b_ in zip(g_flash, g_ref):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
                                    atol=2e-3, rtol=2e-3)
+
+
+TILES = (128, 256, 512, 1024)
+
+
+@pytest.mark.parametrize("kernel,seq,depth,itemsize,tile", [
+    ("fwd", 1024, 128, 2, 512), ("dq", 1024, 128, 2, 512),
+    ("dkv", 1024, 128, 2, 512),          # granite, Nemotron
+    ("fwd", 1024, 192, 2, 256),          # GigaChat
+    ("fwd", 1024, 128, 4, 256), ("dkv", 1024, 128, 4, 256)])
+def test_wide_heads_keep_the_parents_tiles(kernel, seq, depth, itemsize, tile):
+    """Head widths over 64 take the tile they took before ISSUE 36, causal
+    or not: the budget's bound, which at seq 1024 never spans the square."""
+    from flexflow_tpu.kernels.flash_attention import _pick_block, _tiles
+
+    assert _pick_block(seq, depth, itemsize) == tile
+    assert _tiles(kernel, seq, seq, depth, itemsize, True) == (tile, tile)
+    assert _tiles(kernel, seq, seq, depth, itemsize, False) == (tile, tile)
+
+
+def test_block_overrides_still_force_a_tile(monkeypatch):
+    """The two tuning overrides win over the causal rule (a sweep may ask
+    for the whole square), each for its own kernels."""
+    from flexflow_tpu.kernels.flash_attention import _tiles
+
+    monkeypatch.setenv("FLEXFLOW_FLASH_BLOCK", "1024")
+    assert _tiles("fwd", 1024, 1024, 64, 2, True) == (1024, 1024)
+    assert _tiles("dq", 1024, 1024, 64, 2, True) == (1024, 1024)
+    monkeypatch.setenv("FLEXFLOW_FLASH_BLOCK_BWD", "128")
+    assert _tiles("fwd", 1024, 1024, 64, 2, True) == (1024, 1024)
+    assert _tiles("dkv", 1024, 1024, 64, 2, True) == (128, 128)
+    monkeypatch.setenv("FLEXFLOW_FLASH_BLOCK_BWD", "100")     # unusable
+    assert _tiles("dkv", 1024, 1024, 64, 2, True) == (1024, 1024)
+
+
+@pytest.mark.parametrize("seq", [1024, 640, 384])
+def test_visited_tiles_are_those_with_an_unmasked_pair(seq):
+    """The loop bounds, for every pair of tiles that divide the sequence:
+    the tiles a kernel visits are exactly those holding a pair at or under
+    the diagonal, and the ones it masks exactly those the diagonal
+    crosses, whichever of q and k is tiled finer."""
+    from flexflow_tpu.kernels.flash_attention import (KERNELS, _k_tile_bounds,
+                                                      _q_tile_bounds,
+                                                      _schedule)
+
+    under = np.tril(np.ones((seq, seq), bool))
+    tiles = [t for t in TILES if seq % t == 0]
+    assert tiles
+    for bq in tiles:
+        for bk in tiles:
+            cells = under.reshape(seq // bq, bq, seq // bk, bk)
+            some, every = cells.any(axis=(1, 3)), cells.all(axis=(1, 3))
+            want = (int(some.sum()), int((some & ~every).sum()), some.size)
+            for kern in KERNELS:
+                assert _schedule(kern, seq, seq, bq, bk, True) == want
+                assert _schedule(kern, seq, seq, bq, bk, False) == \
+                    (some.size, 0, some.size)
+            for i in range(seq // bq):
+                full, visit = _k_tile_bounds(i * bq, bq, bk)
+                assert list(every[i]) == [j < full for j in range(seq // bk)]
+                assert list(some[i]) == [j < visit for j in range(seq // bk)]
+            for j in range(seq // bk):
+                first, full = _q_tile_bounds(j * bk, bq, bk)
+                assert list(some[:, j]) == [i >= first for i in range(seq // bq)]
+                assert list(every[:, j]) == [i >= full for i in range(seq // bq)]
+
+
+def _three_kernels(q, k, v, g, scale, bq, bk):
+    from flexflow_tpu.kernels.flash_attention import (_dkv_call, _dq_call,
+                                                      _fwd_call)
+
+    o, lse = _fwd_call(q, k, v, True, scale, bq, bk)
+    delta = jnp.sum(g * o, axis=-1, keepdims=True)
+    dq = _dq_call(q, k, v, g, lse, delta, True, scale, bq, bk)
+    dk, dv = _dkv_call(q, k, v, g, lse, delta, True, scale, bq, bk)
+    return o, lse, dq, dk, dv
+
+
+@pytest.mark.parametrize("seq,bq,bk", [
+    (1024, 128, 128), (1024, 256, 128), (1024, 128, 256), (1024, 512, 256),
+    (1024, 256, 512), (384, 128, 128)])
+def test_unequal_tiles_match_einsum_at_head_dim_64(seq, bq, bk):
+    """Each of the three kernels at its own (bq, bk), causal, d = 64:
+    forward and both gradients against einsum + mask + softmax, to the
+    tolerances of the d = 64 parity test above."""
+    rng = np.random.default_rng(7)
+    b, h, d = 1, 2, 64
+    q, k, v, g = (jnp.asarray(rng.normal(size=(b, h, seq, d)), jnp.float32)
+                  for _ in range(4))
+    scale = 1.0 / np.sqrt(d)
+    o, _lse, dq, dk, dv = _three_kernels(q, k, v, g, scale, bq, bk)
+    ref, vjp = jax.vjp(lambda q, k, v: _reference(q, k, v, True, scale),
+                       q, k, v)
+    np.testing.assert_allclose(np.asarray(o), np.asarray(ref),
+                               atol=5e-5, rtol=5e-5)
+    for a, b_ in zip((dq, dk, dv), vjp(g)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
+                                   atol=2e-3, rtol=2e-3)
+
+
+def test_a_sequence_of_several_blocks_loops_over_the_earlier_ones():
+    """A causal sequence longer than the block a grid step holds (384 = 3
+    x 128: only 128 divides it): a step meets the key blocks before its
+    own whole, in a loop, and carries the statistics into its own block.
+    Through the public entry, forward and gradients."""
+    rng = np.random.default_rng(9)
+    b, h, s, d = 1, 2, 384, 64
+    q, k, v = (jnp.asarray(rng.normal(size=(b, h, s, d)), jnp.float32)
+               for _ in range(3))
+    scale = 1.0 / np.sqrt(d)
+    np.testing.assert_allclose(
+        np.asarray(flash_attention(q, k, v, causal=True)),
+        np.asarray(_reference(q, k, v, True, scale)), atol=5e-5, rtol=5e-5)
+    g_flash = jax.grad(lambda *a: jnp.sum(flash_attention(*a, causal=True) ** 2),
+                       argnums=(0, 1, 2))(q, k, v)
+    g_ref = jax.grad(lambda *a: jnp.sum(_reference(*a, True, scale) ** 2),
+                     argnums=(0, 1, 2))(q, k, v)
+    for a, b_ in zip(g_flash, g_ref):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
+                                   atol=2e-3, rtol=2e-3)
+
+
+@pytest.mark.parametrize("bq,bk", [(256, 128), (128, 256), (256, 256)])
+def test_rows_that_meet_only_diagonal_tiles_normalise(bq, bk):
+    """The first q tile visits no tile wholly under the diagonal, and with
+    bk < bq its upper rows are wholly masked in the later tiles: every row
+    still divides by its own sum. Row 0 attends key 0 alone."""
+    rng = np.random.default_rng(8)
+    b, h, s, d = 1, 1, 512, 64
+    q, k, v = (jnp.asarray(rng.normal(size=(b, h, s, d)), jnp.float32)
+               for _ in range(3))
+    scale = 1.0 / np.sqrt(d)
+    from flexflow_tpu.kernels.flash_attention import _fwd_call
+
+    o, lse = _fwd_call(q, k, v, True, scale, bq, bk)
+    assert np.isfinite(np.asarray(o)).all() and np.isfinite(np.asarray(lse)).all()
+    logits = np.einsum("qd,kd->qk", np.asarray(q[0, 0]), np.asarray(k[0, 0])) * scale
+    logits = np.where(np.tril(np.ones((s, s), bool)), logits, -np.inf)
+    want = np.log(np.exp(logits - logits.max(-1, keepdims=True)).sum(-1)) \
+        + logits.max(-1)
+    np.testing.assert_allclose(np.asarray(lse[0, 0, :, 0]), want,
+                               atol=5e-5, rtol=5e-5)
+    np.testing.assert_allclose(np.asarray(o[0, 0, 0]), np.asarray(v[0, 0, 0]),
+                               atol=1e-6)
+
+
+def test_lowering_span_carries_the_tile_plan_and_trace_report_prints_it():
+    """ISSUE 36: a lowered call leaves one `lower/flash_attention` span
+    with, per kernel, its tile and the visited / total tile counts, and
+    tools/trace_report.py prints one line a shape."""
+    import os
+    import sys
+
+    from flexflow_tpu import telemetry as tel
+    from flexflow_tpu.kernels.flash_attention import KERNELS, _schedule
+
+    sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "tools"))
+    import trace_report
+
+    since = tel.ring_spans()[-1].end_ns if tel.ring_spans() else 0
+    q = jnp.zeros((2, 3, 512, 64), jnp.bfloat16)
+    jax.eval_shape(lambda q: flash_attention(q, q, q, causal=True), q)
+    spans = [s for s in tel.ring_spans("lower/flash_attention")
+             if s.start_ns >= since]
+    assert len(spans) == 1
+    args = spans[0].args
+    assert (args["batch_heads"], args["seq_q"], args["depth"],
+            args["causal"]) == (6, 512, 64, True)
+    for kern in KERNELS:
+        got = args["kernels"][kern]
+        bq, bk = got["flash_tile_q"], got["flash_tile_k"]
+        assert max(bq, bk) < 512
+        assert (got["flash_tiles_visited"], got["flash_tiles_masked"],
+                got["flash_tiles_total"]) == _schedule(kern, 512, 512, bq,
+                                                       bk, True)
+        assert got["flash_tiles_visited"] < got["flash_tiles_total"]
+    events = [{"ph": "X", "name": s.name, "args": s.args} for s in spans] * 2
+    lines = trace_report.flash_attention_lines(events)
+    assert len(lines) == 1
+    assert lines[0].startswith("[lower] flash attention x2 [6, 512x512, 64] "
+                               "causal: fwd ")
+    fwd = args["kernels"]["fwd"]
+    assert (f"{fwd['flash_tiles_visited']} of {fwd['flash_tiles_total']} "
+            f"visited ({fwd['flash_tiles_masked']} masked)") in lines[0]
+    assert trace_report.flash_attention_lines(
+        [{"ph": "X", "name": "serve/admit", "args": {"wave": 1}}]) == []
 
 
 def test_vmem_reject_falls_back_to_reference_path():

@@ -283,6 +283,29 @@ def expert_layer_lines(events: List[Dict[str, Any]]) -> List[str]:
     return lines
 
 
+def flash_attention_lines(events: List[Dict[str, Any]]) -> List[str]:
+    """One line per shape the flash-attention kernels were lowered at
+    (`lower/flash_attention` spans, one a lowered call): for each of the
+    three kernels its tile and how many tiles of the score matrix it
+    computes, with those of them that take the causal mask."""
+    calls: Dict[str, int] = {}
+    for ev in events:
+        a = ev.get("args") or {}
+        if ev.get("ph") != "X" or ev.get("name") != "lower/flash_attention" \
+                or "kernels" not in a:
+            continue
+        what = (f"[{a.get('batch_heads')}, {a.get('seq_q')}x{a.get('seq_k')}"
+                f", {a.get('depth')}]{' causal' if a.get('causal') else ''}: ")
+        what += "; ".join(
+            f"{kern} {k['flash_tile_q']}x{k['flash_tile_k']} tiles, "
+            f"{k['flash_tiles_visited']} of {k['flash_tiles_total']} visited"
+            f" ({k['flash_tiles_masked']} masked)"
+            for kern, k in a["kernels"].items())
+        calls[what] = calls.get(what, 0) + 1
+    return [f"[lower] flash attention x{n} {what}"
+            for what, n in sorted(calls.items())]
+
+
 def render(path: str, out_path: Optional[str] = None, top: int = 0,
            quiet: bool = False) -> Dict[str, Any]:
     """The full report: summary rows + chrome doc + derived sections.
@@ -337,7 +360,7 @@ def render(path: str, out_path: Optional[str] = None, top: int = 0,
             if ev.get("name") == "serve/compile_serving" and ev.get("args"):
                 print("[serve] compile_serving: " + " ".join(
                     f"{k}={v}" for k, v in sorted(ev["args"].items())))
-        for line in expert_layer_lines(events):
+        for line in expert_layer_lines(events) + flash_attention_lines(events):
             print(line)
         for ev in errors:
             print(f"[error] {ev['name']}: {ev.get('args', {})}")
