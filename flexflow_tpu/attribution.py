@@ -11,10 +11,14 @@ compiled placement) it lines up
     `Strategy._predicted_op_costs` — restored from the strategy cache on
     warm compiles; analytic fallback for imported/data-parallel
     strategies),
-  * the MEASURED time — primary path: the Chrome/perfetto trace
-    `jax.profiler` emits under `--profiling`, mapped back to graph layers
-    via the `jax.named_scope(layer.name)` HLO metadata the lowering stamps
-    (compiler/lowering.py); fallback path: a partitioned re-execution that
+  * the MEASURED time — primary path: the device events of the
+    `.xplane.pb` that `jax.profiler` writes under `--profiling`, joined BY
+    INSTRUCTION NAME with the compiled programs' own optimized HLO, whose
+    per-instruction `metadata.op_name` carries the `jax.named_scope(
+    layer.name)` the lowering stamps (compiler/lowering.py) and JAX's
+    jvp / transpose wrappers (`op_scope_map`, `register_program`,
+    `device_time_by_scope`: the same map the benchmark's
+    readers/scope_device.py reads); fallback path: a partitioned re-execution that
     times each layer's jitted fwd/bwd at shard-local shapes on the live
     machine (search/measure.MeasuredCost — works on CPU CI), rescaled so
     attributed times sum to the REAL measured step time,
@@ -38,12 +42,14 @@ op_attribution()` (both also feed `profile_report`), `--profile-ops`
 
 from __future__ import annotations
 
+import bisect
 import glob
-import gzip
 import hashlib
 import json
 import os
-from typing import Any, Dict, List, Optional, Sequence
+import re
+import weakref
+from typing import Any, Dict, List, NamedTuple, Optional, Sequence
 
 from flexflow_tpu import telemetry as tel
 from flexflow_tpu.search import cost_model as cmod
@@ -104,62 +110,490 @@ def feature_key(features: Dict[str, Any]) -> str:
     return hashlib.sha1(blob.encode()).hexdigest()[:16]
 
 
-# ----------------------------------------------------- xplane/Chrome trace
-def measured_from_trace(profile_dir: str, layer_names: Sequence[str]
-                        ) -> Optional[Dict[str, float]]:
-    """Primary measurement path: map the profiler's per-kernel timeline
-    back to graph layers. `jax.profiler.trace` (under --profiling) writes
-    `plugins/profile/<run>/*.trace.json[.gz]`; the lowering stamps
-    `jax.named_scope(layer.name)` so XLA op metadata — and therefore the
-    trace event names / `args` — carry "<layer>/..." source names. Returns
-    layer -> total device microseconds across the trace (fused ops whose
-    metadata names several layers credit the FIRST match), or None when no
-    parseable trace exists (the caller falls back to partitioned
-    re-execution). Totals are only meaningful as FRACTIONS of the step —
-    the caller normalizes against the measured step time."""
+# --------------------------------------------- compiled HLO -> (layer, phase)
+# What a compiled program's device time is made of. The profiler's device
+# events carry no source names: an event of the TPU plane's "XLA Ops" line is
+# named by the HLO instruction's own text ("%fusion.12 = bf16[...] fusion(
+# ...)"), a CPU thunk event by `hlo_op`. The optimized HLO text of the SAME
+# executable says, per instruction, `metadata={op_name="jit(train_step)/
+# transpose(jvp(h0_attn))/dot_general"}`: the name stack at trace time, with
+# the `jax.named_scope(layer.name)` of compiler/lowering.py and JAX's own
+# transform wrappers in it. The instruction's name is the join key.
+PHASES = ("forward", "backward", "update", "loss", "other")
+LOSS_SCOPE = "ff.loss"          # jax.named_scope names in the step function
+UPDATE_SCOPE = "ff.update"      # (compiler/compile.py: _build_steps)
+_STEP_SCOPES = {LOSS_SCOPE: "loss", UPDATE_SCOPE: "update"}
+# instructions that span the events of the computations they call
+CONTAINERS = ("while", "conditional", "call")
+UNATTRIBUTED = "unattributed"   # the name is in no registered program's map
+AMBIGUOUS = "ambiguous"         # two programs of the interval disagree on it
+SPAN = "compile/op_scopes"
+
+
+class OpScope(NamedTuple):
+    """Where one instruction of a compiled program belongs."""
+    layer: str      # graph layer name ("" outside every layer)
+    op_type: str    # the layer's op_type.value ("" outside every layer)
+    phase: str      # one of PHASES (AMBIGUOUS / UNATTRIBUTED after a join)
+    opcode: str     # HLO opcode; a named custom call by its (folded) name
+    has_dot: bool   # a dot, convolution, ragged-dot or ff_* kernel is inside
+    mixed: bool     # a fusion whose body spans more than one (layer, phase)
+    inferred: bool = False  # the compiler made it: scoped by what it serves
+
+
+_INSTR = re.compile(r"^\s+(ROOT )?%?([\w.\-]+) = (.*)$")
+_COMPUTATION = re.compile(r"^(ENTRY )?%?([\w.\-]+) \(.*\) -> .*\{$")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_CALLED = re.compile(
+    r"\b(calls|body|condition|to_apply|true_computation|false_computation)"
+    r"=%?([\w.\-]+)")
+_BRANCHES = re.compile(r"branch_computations=\{([^}]*)\}")
+_OPERAND = re.compile(r"%([\w.\-]+)")
+_WRAPPED = re.compile(r"^([A-Za-z_][\w.]*)\((.*)\)$")
+_NUMBERED = re.compile(r"[.\-_]?\d+$")
+_DOT_OPCODES = ("dot", "convolution", "ragged-dot")
+# custom calls that are a matrix product inside: a Pallas kernel by its
+# stable `name=`, and what the chip's compiler makes of a ragged-dot (a
+# Mosaic call named `ragged-dot-none.N`)
+_DOT_KERNELS = ("ff_", "ragged-dot")
+
+
+def fold_name(name: str) -> str:
+    """`fusion.12` -> `fusion`, `ff_flash_attention_fwd.30` ->
+    `ff_flash_attention_fwd` (the benchmark's top_ops folds alike)."""
+    return _NUMBERED.sub("", name)
+
+
+def _opcode_of(rest: str) -> str:
+    """The opcode of `<shape> <opcode>(<operands>), <attributes>`; a tuple
+    shape holds parentheses (and TPU layouts `T(8,128)` do), so the shape
+    is stepped over by bracket matching."""
+    if rest.startswith("("):
+        depth = 0
+        for i, c in enumerate(rest):
+            depth += (c == "(") - (c == ")")
+            if depth == 0:
+                rest = rest[i + 1:].lstrip()
+                break
+    else:
+        rest = rest.split(" ", 1)[1] if " " in rest else rest
+    return rest.split("(", 1)[0].strip()
+
+
+def _segments(op_name: str) -> List[str]:
+    """`op_name` split at the slashes outside parentheses."""
+    out, depth, cur = [], 0, []
+    for c in op_name:
+        if c == "/" and depth == 0:
+            out.append("".join(cur))
+            cur = []
+            continue
+        depth += (c == "(") - (c == ")")
+        cur.append(c)
+    out.append("".join(cur))
+    return out
+
+
+def scope_of_op_name(op_name: str, op_types: Dict[str, str]
+                     ) -> "tuple[str, str]":
+    """(layer, phase) of one `metadata.op_name`. A layer is matched as a
+    WHOLE path segment inside JAX's transform wrappers (`jvp(up)`,
+    `transpose(jvp(up))`), so a layer named "up" never absorbs "update" or
+    "ffn_up_2", and an op that merely mentions a layer mid-word matches
+    nothing. The first segment that names a layer or a step scope decides:
+    under `transpose(...)` a layer's work is `backward`, else `forward`;
+    everything under `ff.loss` (its own backward too) is `loss`, under
+    `ff.update` `update`. Recomputed forward under `jax.checkpoint` carries
+    the wrappers of the backward pass it is recomputed in, so it counts as
+    `backward`: it is time the backward pass costs."""
+    for seg in _segments(op_name):
+        wrappers = []
+        m = _WRAPPED.match(seg)
+        while m is not None:
+            wrappers.append(m.group(1))
+            seg = m.group(2)
+            m = _WRAPPED.match(seg)
+        if seg in _STEP_SCOPES:
+            return "", _STEP_SCOPES[seg]
+        if seg in op_types:
+            return seg, "backward" if "transpose" in wrappers else "forward"
+    return "", "other"
+
+
+class _Instr(NamedTuple):
+    name: str
+    opcode: str
+    op_name: str        # metadata.op_name, "" where the compiler made it
+    rest: str           # the text after " = "
+    root: bool
+    operands: tuple
+
+
+def _operands(rest: str, opcode: str) -> tuple:
+    """Names of the instructions between the opcode's parentheses."""
+    at = rest.find(opcode + "(")
+    if at < 0:
+        return ()
+    depth, start = 0, at + len(opcode)
+    for i in range(start, len(rest)):
+        depth += (rest[i] == "(") - (rest[i] == ")")
+        if depth == 0:
+            return tuple(_OPERAND.findall(rest[start:i]))
+    return ()
+
+
+def _parse_computations(hlo_text: str):
+    """{computation: [_Instr]} and the entry computation's name."""
+    comps: Dict[str, list] = {}
+    entry, cur = None, None
+    for line in hlo_text.splitlines():
+        if cur is None:
+            m = _COMPUTATION.match(line)
+            if m is not None:
+                cur = comps.setdefault(m.group(2), [])
+                if m.group(1):
+                    entry = m.group(2)
+            continue
+        if line.startswith("}"):
+            cur = None
+            continue
+        m = _INSTR.match(line)
+        if m is None:
+            continue
+        rest = m.group(3)
+        meta = _OP_NAME.search(rest)
+        opcode = _opcode_of(rest)
+        # a name stack starts "jit(f)/..."; an `op_name` with no path is the
+        # compiler's own ("ragged-dot-none" on the call it rewrote a
+        # ragged-dot into): like none at all
+        op_name = meta.group(1) if meta and "/" in meta.group(1) else ""
+        cur.append(_Instr(m.group(2), opcode, op_name, rest,
+                          bool(m.group(1)), _operands(rest, opcode)))
+    return comps, entry
+
+
+def _called(rest: str) -> List[str]:
+    names = [m.group(2) for m in _CALLED.finditer(rest)]
+    b = _BRANCHES.search(rest)
+    if b is not None:
+        names += [n.strip().lstrip("%") for n in b.group(1).split(",")]
+    return names
+
+
+_NO_EVENT = ("parameter", "constant", "tuple", "get-tuple-element", "bitcast")
+_UNDECIDED = ("", "")       # no name stack anywhere on the instruction
+
+
+def op_scope_map(hlo_text: str, layers) -> Dict[str, OpScope]:
+    """{instruction name: OpScope} of one compiled program's optimized HLO
+    text (`jitted.lower(...).compile().as_text()`): every instruction of
+    the entry computation and of every computation that runs as events of
+    its own (while bodies and conditions, conditional branches, called and
+    asynchronous computations), and for a fusion what its fused computation
+    holds. `layers` are the graph's layers (anything with `.name` and
+    `.op_type.value`).
+
+    A fusion is credited to the (layer, phase) of its dot-like instruction
+    where its body has exactly one such scope, else to its root's (the
+    fusion's own metadata where the root has none; where that names no
+    layer, the scope most of its body carries); `mixed` says the body held
+    more than one: a weight-gradient product with the optimizer update in
+    its epilogue is `backward`, `mixed`.
+
+    An instruction the compiler made (no `op_name` on it or in its body: a
+    relayout copy, a broadcast constant, a loop-carried copy) belongs to
+    what it was made for: inside a layer's loop or branch to that layer
+    (the scope of the `while` / `conditional` that calls its computation),
+    else to the scope of the instruction that uses it, else of the one it
+    reads; `other` where none of them has one."""
+    op_types = layers if isinstance(layers, dict) else \
+        {l.name: l.op_type.value for l in layers}
+    comps, entry = _parse_computations(hlo_text)
+    if entry is None:
+        return {}
+    scope_cache: Dict[str, tuple] = {"": _UNDECIDED}
+
+    def scope(op_name):
+        got = scope_cache.get(op_name)
+        if got is None:
+            got = scope_cache[op_name] = scope_of_op_name(op_name, op_types)
+        return got
+
+    def dot_like(i):
+        return i.opcode in _DOT_OPCODES or (
+            i.opcode == "custom-call" and i.name.startswith(_DOT_KERNELS))
+
+    def own_scope(i):
+        """((layer, phase) or _UNDECIDED, has_dot, mixed)."""
+        if i.opcode != "fusion":
+            return scope(i.op_name), dot_like(i), False
+        called = _called(i.rest)
+        body = comps.get(called[0], []) if called else []
+        inner = [(scope(b.op_name), b) for b in body if b.op_name]
+        dots = {s for s, b in inner if dot_like(b)}
+        named = [s for s, _b in inner if s[1] != "other"]
+        if len(dots) == 1:
+            got = next(iter(dots))
+        else:
+            got = next((s for s, b in inner if b.root), _UNDECIDED)
+            if got[1] in ("", "other"):
+                got = scope(i.op_name) if i.op_name else got
+            if got[1] in ("", "other") and named:
+                got = max(set(named), key=named.count)
+        return got, any(dot_like(b) for b in body), len(set(named)) > 1
+
+    out: Dict[str, OpScope] = {}
+    seen, todo = set(), [(entry, _UNDECIDED)]
+    while todo:
+        comp, inherited = todo.pop()
+        if comp in seen or comp not in comps:
+            continue
+        seen.add(comp)
+        instrs = comps[comp]
+        found = {i.name: own_scope(i) for i in instrs}
+        made = {n for n, f in found.items() if f[0] == _UNDECIDED}
+        users: Dict[str, list] = {}
+        for i in instrs:
+            for o in i.operands:
+                users.setdefault(o, []).append(i.name)
+        if inherited != _UNDECIDED:
+            for name, (s, dot, mixed) in found.items():
+                if s == _UNDECIDED:
+                    found[name] = (inherited, dot, mixed)
+        for order in (instrs[::-1], instrs) * 2:
+            # through chains of copies: a pass against program order takes
+            # a chain from its last user, a pass along it from its source
+            changed = False
+            for i in order:
+                if found[i.name][0] != _UNDECIDED:
+                    continue
+                near = [found[n][0] for n in
+                        users.get(i.name, []) + list(i.operands)
+                        if n in found]
+                got = next((s for s in near if s[1] not in ("", "other")),
+                           None)
+                if got is not None:
+                    found[i.name] = (got,) + found[i.name][1:]
+                    changed = True
+            if not changed:
+                break
+        for i in instrs:
+            (layer, phase), has_dot, mixed = found[i.name]
+            if i.opcode in CONTAINERS or i.opcode.endswith("-start"):
+                todo.extend((c, (layer, phase)) for c in _called(i.rest))
+            if i.opcode in _NO_EVENT:
+                continue
+            # a custom call that was given a name goes by it
+            # (`ff_flash_attention_fwd`, `ragged-dot-none`)
+            kernel = i.opcode == "custom-call" and \
+                not i.name.startswith("custom-call")
+            out[i.name] = OpScope(layer, op_types.get(layer, ""),
+                                  phase or "other",
+                                  fold_name(i.name) if kernel else i.opcode,
+                                  has_dot, mixed, i.name in made)
+    return out
+
+
+# ------------------------------------------------------------- the registry
+class Program:
+    """One jitted program that puts work on the device: a weak reference to
+    it, the graph layers' op types, and from its first run on the executable
+    that run used (`jax.stages.Compiled`: the handle the jit's own cache
+    holds, no arrays). The HLO text is rendered and parsed only when
+    `op_scopes` asks."""
+
+    def __init__(self, name: str, jitted, layers, owner=None):
+        self.name = name
+        self._jitted = weakref.ref(jitted)
+        self._owner = weakref.ref(jitted if owner is None else owner)
+        self.op_types = {l.name: l.op_type.value for l in layers}
+        self.compiled = None
+        self.scopes: Optional[Dict[str, OpScope]] = None
+        self.module = ""        # "jit_train_step": the profile's module name
+
+    def first_run(self, *args, **kwargs) -> None:
+        """The owner calls this once, just before the program's first call
+        (`if prog.compiled is None`), with that call's arguments: lowering
+        and compiling here is what the call itself would do next, and the
+        call then finds both done."""
+        self.compiled = self._jitted().lower(*args, **kwargs).compile()
+
+    def render(self) -> Optional[Dict[str, OpScope]]:
+        if self.scopes is not None or self.compiled is None:
+            return self.scopes
+        with tel.span(SPAN, cat="compile", program=self.name) as sp:
+            text = self.compiled.as_text()
+            self.module = text[:text.find(",")].replace("HloModule ", "").strip()
+            self.scopes = op_scope_map(text, self.op_types)
+            fusions = [s for s in self.scopes.values() if s.opcode == "fusion"]
+            # `layers_named`: how many of the registered layers the text
+            # names (pass-through layers have no instruction of their own).
+            # Near 0: the executable came from a persistent compile cache
+            # that another graph filled (the cache's key leaves metadata
+            # out, so the name stacks are whoever compiled it first)
+            sp.set(instructions=len(self.scopes), fusions=len(fusions),
+                   mixed=sum(s.mixed for s in fusions),
+                   inferred=sum(s.inferred for s in self.scopes.values()),
+                   text_bytes=len(text), module=self.module,
+                   layers=len(self.op_types),
+                   layers_named=len({s.layer for s in self.scopes.values()
+                                     if s.layer}))
+        return self.scopes
+
+
+# name -> the programs registered under it. A program's owner (a
+# CompiledModel, an engine, a cache) keeps the handle too; the registry
+# lets go of a dead owner's programs when the next program registers under
+# the name, so a reader that comes after the owner is gone (the benchmark's,
+# once its cell has returned) still finds the last programs that ran.
+_PROGRAMS: Dict[str, List[Program]] = {}
+
+
+def register_program(name: str, jitted, layers, owner=None) -> Program:
+    """Register a jitted program under `name` ("train_step", "serve/prefill",
+    "serve/decode", "serve/commit") and return its handle, which the caller
+    keeps; nothing is lowered or rendered. `layers`: the graph layers it
+    runs (anything with `.name` and `.op_type.value`). `owner`: whose life
+    the registration shares where that is not the jitted function's own (a
+    cache that runs a module's jit at its own shapes)."""
+    prog = Program(name, jitted, layers, owner)
+    held = _PROGRAMS.setdefault(name, [])
+    held[:] = [p for p in held if p._owner() is not None]
+    held.append(prog)
+    return prog
+
+
+def op_scopes(name: str) -> List[Dict[str, OpScope]]:
+    """The instruction -> OpScope maps of the programs registered under
+    `name` that have run, rendered on first demand and kept. This is the
+    only place HLO text is rendered: with nobody asking (tracing off),
+    a program costs its handle."""
+    maps = [p.render() for p in _PROGRAMS.get(name, ())]
+    return [m for m in maps if m]
+
+
+def merge_scopes(maps: Sequence[Dict[str, OpScope]]) -> Dict[str, OpScope]:
+    """The union of several programs' maps: a name that two of them give
+    different scopes maps to an AMBIGUOUS scope, not to a guess."""
+    out: Dict[str, OpScope] = {}
+    for m in maps:
+        for name, s in m.items():
+            had = out.setdefault(name, s)
+            if had != s:
+                out[name] = OpScope("", "", AMBIGUOUS, fold_name(name),
+                                    False, False)
+    return out
+
+
+def device_time_by_scope(events, scopes, by_name: bool = False
+                         ) -> Dict[Any, float]:
+    """Device time by OpScope. `events`: [(instruction name, start ns,
+    end ns)] of one device line; `scopes`: one map or several (the programs
+    that may run inside the interval the events come from). Containers
+    (`while`, `conditional`, `call`) are left out and their bodies counted.
+    A name in no map goes to an UNATTRIBUTED scope that keeps the
+    instruction's name as its opcode. `by_name`: keyed by (OpScope, the
+    instruction's folded name), which parts `convert_reduce_fusion` from
+    `fusion` as the benchmark's `device_ops` does."""
+    merged = scopes if isinstance(scopes, dict) else merge_scopes(scopes)
+    out: Dict[Any, float] = {}
+    for name, start, end in events:
+        s = merged.get(name)
+        if s is None:
+            if fold_name(name) in CONTAINERS:
+                continue
+            s = OpScope("", "", UNATTRIBUTED, name, False, False)
+        elif s.opcode in CONTAINERS:
+            continue
+        key = (s, fold_name(name)) if by_name else s
+        out[key] = out.get(key, 0.0) + (end - start)
+    return out
+
+
+# ------------------------------------------------------- the profile's events
+_TPU_PLANE = re.compile(r"^/device:TPU:\d+$")
+
+
+def profile_events(profile_dir: str):
+    """{hlo module: [(instruction name, start ns, end ns)]} of the newest
+    `.xplane.pb` under `profile_dir`, read with jax.profiler.ProfileData.
+    On a TPU the first chip's plane: an event of its "XLA Ops" line is
+    named by the instruction's text and belongs to the module whose event
+    on the "XLA Modules" line ("jit_train_step(<fingerprint>)") holds it;
+    on a CPU the host plane's thunk events (`hlo_op`, `hlo_module`).
+    Instruction names repeat from program to program (`fusion.7`), so an
+    event counts only for the program whose module ran it. None where
+    there is no profile."""
     if not profile_dir or not os.path.isdir(profile_dir):
         return None
-    paths = sorted(
-        glob.glob(os.path.join(profile_dir, "**", "*.trace.json"),
-                  recursive=True)
-        + glob.glob(os.path.join(profile_dir, "**", "*.trace.json.gz"),
-                    recursive=True),
-        key=lambda p: os.path.getmtime(p))
+    paths = sorted(glob.glob(os.path.join(profile_dir, "**", "*.xplane.pb"),
+                             recursive=True), key=os.path.getmtime)
     if not paths:
         return None
-    try:
-        opener = gzip.open if paths[-1].endswith(".gz") else open
-        with opener(paths[-1], "rt") as f:
-            doc = json.load(f)
-    except (OSError, ValueError):
-        return None
-    events = doc.get("traceEvents", doc if isinstance(doc, list) else [])
-    # boundary-safe matching: a layer is credited only for "<name>/" path
-    # segments (the exact shape named_scope produces in HLO op_name /
-    # source strings) at a segment start — "up" must not absorb "update",
-    # and an event merely MENTIONING a layer mid-word never matches.
-    # Longest-first alternation so "ffn_up_2" wins over a "ffn_up" prefix.
-    import re
+    from jax.profiler import ProfileData
 
-    names = sorted(set(layer_names), key=len, reverse=True)
-    if not names:
+    try:
+        data = ProfileData.from_file(paths[-1])
+    except Exception:
         return None
-    pat = re.compile("(?:^|[/ ;,(])("
-                     + "|".join(re.escape(n) for n in names) + ")/")
-    totals: Dict[str, float] = {}
-    for ev in events:
-        if not isinstance(ev, dict) or ev.get("ph") != "X":
+    by_module: Dict[str, list] = {}
+    planes = list(data.planes)
+    tpu = sorted((p for p in planes if _TPU_PLANE.match(p.name)),
+                 key=lambda p: p.name)
+    if tpu:
+        lines = {line.name: line for line in tpu[0].lines}
+        if "XLA Ops" not in lines or "XLA Modules" not in lines:
+            return None
+        modules = sorted((e.start_ns, e.start_ns + e.duration_ns,
+                          e.name.split("(", 1)[0])
+                         for e in lines["XLA Modules"].events)
+        starts = [m[0] for m in modules]
+        for e in lines["XLA Ops"].events:
+            i = bisect.bisect_right(starts, e.start_ns) - 1
+            if i < 0 or e.start_ns >= modules[i][1]:
+                continue            # outside every module's run
+            by_module.setdefault(modules[i][2], []).append(
+                (e.name.split(" = ", 1)[0].lstrip("%"), e.start_ns,
+                 e.start_ns + e.duration_ns))
+        return by_module or None
+    for plane in planes:
+        if not plane.name.startswith("/host:"):
             continue
-        dur = ev.get("dur")
-        if not isinstance(dur, (int, float)) or dur <= 0:
-            continue
-        hay = str(ev.get("name", ""))
-        args = ev.get("args")
-        if isinstance(args, dict):
-            hay += " " + " ".join(str(v) for v in args.values())
-        m = pat.search(hay)
-        if m is not None:
-            totals[m.group(1)] = totals.get(m.group(1), 0.0) + float(dur)
+        for line in plane.lines:
+            for e in line.events:
+                stats = dict(e.stats)
+                if "hlo_op" in stats:
+                    by_module.setdefault(str(stats.get("hlo_module", "")),
+                                         []).append(
+                        (str(stats["hlo_op"]), e.start_ns,
+                         e.start_ns + e.duration_ns))
+    return by_module or None
+
+
+def measured_from_trace(profile_dir: str, programs: Sequence[Program]
+                        ) -> Optional[Dict[str, Dict[str, float]]]:
+    """Primary measurement path: the profiler's device events under
+    `profile_dir` (`jax.profiler.trace` under --profiling writes
+    `plugins/profile/<run>/*.xplane.pb`) joined with the registered
+    programs' instruction -> scope maps. Returns layer -> {phase: device
+    microseconds} across the profile (work outside every layer under the
+    layer ""; UNATTRIBUTED / AMBIGUOUS time under those phases), or None
+    when there is no profile or no program that has run (the caller falls
+    back to partitioned re-execution). Totals are only meaningful as
+    FRACTIONS of the step — the caller normalizes against the measured
+    step time."""
+    rendered = [p for p in programs if p.render()]
+    events = profile_events(profile_dir) if rendered else None
+    if not events:
+        return None
+    totals: Dict[str, Dict[str, float]] = {}
+    for module, evs in events.items():
+        maps = [p.scopes for p in rendered if p.module == module]
+        if not maps:
+            continue        # another program's run (eval_step, a commit)
+        for s, ns in device_time_by_scope(evs, maps).items():
+            by_phase = totals.setdefault(s.layer, {})
+            by_phase[s.phase] = by_phase.get(s.phase, 0.0) + ns / 1e3
     return totals or None
 
 
@@ -173,7 +607,8 @@ def build_report(items: List[Dict[str, Any]],
                  measure_warmup: int = 1,
                  emit: Optional[bool] = None,
                  inference: bool = False,
-                 tag: Optional[str] = None) -> Dict[str, Any]:
+                 tag: Optional[str] = None,
+                 programs: Sequence["Program"] = ()) -> Dict[str, Any]:
     """Assemble the attribution report.
 
     items: one dict per placed op — {"layer", "cand", "machine",
@@ -189,6 +624,10 @@ def build_report(items: List[Dict[str, Any]],
     normalization). When None, attributed == measured and scale == 1.
     source: "auto" (trace when available, else measure), "trace",
     "measure".
+    programs: the `register_program` handles of the jitted programs the
+    profile under `profile_dir` ran (the trace path joins their compiled
+    instructions with the profile's events; without them there is no
+    trace path).
     emit: write op/attr + op/drift_topk telemetry events (default: when
     the telemetry sink is enabled) — this is what grows the span corpus.
     inference: forward-pass-only regime (serving prefill/decode — ISSUE
@@ -209,8 +648,7 @@ def build_report(items: List[Dict[str, Any]],
         # path requires a measured step time to normalize against; "auto"
         # without one falls back to the per-update re-execution path
         if step_time_s:
-            trace_totals = measured_from_trace(
-                profile_dir or "", [it["layer"].name for it in items])
+            trace_totals = measured_from_trace(profile_dir or "", programs)
         if source == "trace":
             if not step_time_s:
                 raise ValueError("source='trace' needs a measured step "
@@ -248,7 +686,7 @@ def build_report(items: List[Dict[str, Any]],
                 if roof["roofline_s"] > 0 else 0.0)
         if trace_totals is not None:
             # whole-run device us; normalized to per-update seconds below
-            measured = trace_totals.get(layer.name, 0.0) * 1e-6
+            measured = sum(trace_totals.get(layer.name, {}).values()) * 1e-6
         elif inference:
             measured = mc_for(machine).op_time_fwd(layer, cand) * mult
         else:
@@ -274,16 +712,28 @@ def build_report(items: List[Dict[str, Any]],
             "key": feature_key(feats),
             "features": feats,
         })
+        if trace_totals is not None:
+            rows[-1]["phases_s"] = {
+                ph: us * 1e-6
+                for ph, us in trace_totals.get(layer.name, {}).items()}
 
+    outside: Dict[str, float] = {}
     if used_source == "trace":
         # per-update measured time = the op's share of the profiled stream
         # x the real step time (trace totals span every profiled step, so
-        # only the proportions carry over)
-        raw = sum(r["measured_s"] for r in rows)
+        # only the proportions carry over). The stream is ALL the device
+        # time the join saw: the layers' rows, the layers that have no row
+        # (pass-through placements), and what runs outside every layer
+        # (update, loss, other, unattributed), reported as `outside_s`
+        raw = sum(us for by_phase in trace_totals.values()
+                  for us in by_phase.values()) * 1e-6
         if raw > 0:
             f = float(step_time_s) / raw
             for r in rows:
                 r["measured_s"] *= f
+                r["phases_s"] = {ph: v * f for ph, v in r["phases_s"].items()}
+            outside = {ph: us * 1e-6 * f
+                       for ph, us in trace_totals.get("", {}).items()}
     total_meas = sum(r["measured_s"] for r in rows)
     scale = 1.0
     if step_time_s and total_meas > 0:
@@ -305,6 +755,9 @@ def build_report(items: List[Dict[str, Any]],
         "scale": scale,
         "mult": mult,
         "source": used_source,
+        # trace source only: per-update device seconds outside every layer,
+        # by phase (update / loss / other / unattributed / ambiguous)
+        "outside_s": outside,
     }
     report["top_drift"] = drift_top_k(rows)
     if emit:
@@ -316,6 +769,8 @@ def build_report(items: List[Dict[str, Any]],
             if r["stage"] is not None:
                 args["stage"] = r["stage"]
             args["source"] = tag or used_source
+            if "phases_s" in r:
+                args["phases_s"] = r["phases_s"]
             args["features"] = r["features"]
             tel.event(OP_EVENT, cat="op", **args)
         td = report["top_drift"]
@@ -363,19 +818,29 @@ def format_report(report: Dict[str, Any], top: int = 0) -> List[str]:
     tools/profile_attribution.py share this formatting)."""
     rows = report["rows"][:top] if top else report["rows"]
     has_stage = any(r["stage"] is not None for r in rows)
+    traced = report["source"] == "trace"
     lines = []
     head = ("st " if has_stage else "") + \
         f"{'layer':24} {'op':14} {'pred':>9} {'attr':>9} {'roof':>9} " \
-        f"{'mfu':>5} {'bound':>9} {'%':>5}"
+        f"{'mfu':>5} {'bound':>9} {'%':>5}" + \
+        (f" {'fwd':>9} {'bwd':>9}" if traced else "")
     lines.append(head)
     total = report["attributed_total_s"] or 1.0
     for r in rows:
         st = f"{r['stage']:2d} " if has_stage else ""
+        ph = r.get("phases_s", {})
         lines.append(
             f"{st}{r['layer'][:24]:24} {r['op'][:14]:14} "
             f"{r['predicted_s'] * 1e6:8.1f}u {r['attributed_s'] * 1e6:8.1f}u "
             f"{r['roofline_s'] * 1e6:8.1f}u {r['mfu']:5.2f} "
-            f"{r['bound']:>9} {100 * r['attributed_s'] / total:4.1f}%")
+            f"{r['bound']:>9} {100 * r['attributed_s'] / total:4.1f}%"
+            + (f" {ph.get('forward', 0.0) * 1e6:8.1f}u"
+               f" {ph.get('backward', 0.0) * 1e6:8.1f}u" if traced else ""))
+    if traced and report.get("outside_s"):
+        lines.append("[ops] device time outside every layer, per update: "
+                     + ", ".join(f"{ph}={v * 1e6:.1f}us" for ph, v in
+                                 sorted(report["outside_s"].items(),
+                                        key=lambda kv: -kv[1])))
     st_ = report.get("step_time_s")
     lines.append(
         f"[ops] source={report['source']} "

@@ -26,7 +26,7 @@ import numpy as np
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
-from flexflow_tpu import health
+from flexflow_tpu import attribution, health
 from flexflow_tpu import telemetry as tel
 from flexflow_tpu.config import ensure_compile_cache
 from flexflow_tpu.core.graph import topo_order
@@ -584,20 +584,26 @@ class CompiledModel:
                 # inside build_forward); --remat aliases to all-layers "full"
                 outs, new_state = forward_fn(p, state, inputs, True, rng)
                 logits = outs[0]
-                if _fce.use_fused_ce(loss_type, logits, fused_loss_mode,
-                                     fusion_on, self.mesh, logits_pspec):
-                    # native-dtype logits: the f32 copy the reference path
-                    # takes below is exactly the materialization we avoid
-                    loss = _fce.fused_cross_entropy(logits, label, self.mesh,
-                                                    logits_pspec)
-                else:
-                    loss = compute_loss(loss_type,
-                                        logits.astype(jnp.float32), label)
-                for (ln, wn), terms in regularizers.items():
-                    w = p[ln][wn].astype(jnp.float32)
-                    for mode, lam in terms:
-                        loss = loss + lam * (jnp.sum(jnp.abs(w)) if mode == "l1"
-                                             else jnp.sum(w * w))
+                # the name stack is the phase key of attribution.op_scope_map
+                # (layers carry their own name from build_forward): what is
+                # computed here, and its backward, is the step's `loss`
+                with jax.named_scope(attribution.LOSS_SCOPE):
+                    if _fce.use_fused_ce(loss_type, logits, fused_loss_mode,
+                                         fusion_on, self.mesh, logits_pspec):
+                        # native-dtype logits: the f32 copy the reference
+                        # path takes below is exactly the materialization
+                        # we avoid
+                        loss = _fce.fused_cross_entropy(
+                            logits, label, self.mesh, logits_pspec)
+                    else:
+                        loss = compute_loss(loss_type,
+                                            logits.astype(jnp.float32), label)
+                    for (ln, wn), terms in regularizers.items():
+                        w = p[ln][wn].astype(jnp.float32)
+                        for mode, lam in terms:
+                            loss = loss + lam * (
+                                jnp.sum(jnp.abs(w)) if mode == "l1"
+                                else jnp.sum(w * w))
                 return loss, (logits, new_state)
 
             return jax.value_and_grad(loss_fn, has_aux=True)(params)
@@ -610,23 +616,33 @@ class CompiledModel:
             back — same ring volume as the fused all-reduce
             (cost_model.grad_sync_time zero=True), 1/degree the moment
             memory and update flops."""
-            if zero != "off":
-                grads = wsc(grads, moment_sh)
-            updates, opt_state = tx.update(grads, opt_state, params)
-            if zero != "off":
-                updates = wsc(updates, pshards)      # all-gather
-                opt_state = wsc(opt_state, opt_sh)   # moments stay sharded
-            return optax.apply_updates(params, updates), opt_state
+            with jax.named_scope(attribution.UPDATE_SCOPE):
+                if zero != "off":
+                    grads = wsc(grads, moment_sh)
+                updates, opt_state = tx.update(grads, opt_state, params)
+                if zero != "off":
+                    updates = wsc(updates, pshards)      # all-gather
+                    opt_state = wsc(opt_state, opt_sh)   # moments stay sharded
+                return optax.apply_updates(params, updates), opt_state
+
+        def grad_sentinels(mvals, loss, grads):
+            """The health sentinel's sums over the gradients: the step's
+            `update` phase, beside the update they watch."""
+            if not sentinels:
+                return mvals
+            with jax.named_scope(attribution.UPDATE_SCOPE):
+                return dict(mvals, **health.sentinel_metrics(
+                    loss, optax.global_norm(grads)))
 
         def train_step(params, opt_state, state, inputs, label, rng):
             (loss, (logits, new_state)), grads = value_and_grads(
                 params, state, inputs, label, rng)
             params, opt_state = apply_update(params, opt_state, grads)
-            mvals = compute_metrics(metric_types, logits.astype(jnp.float32), label)
-            if sentinels:
-                mvals = dict(mvals, **health.sentinel_metrics(
-                    loss, optax.global_norm(grads)))
-            return params, opt_state, new_state, loss, mvals
+            with jax.named_scope(attribution.LOSS_SCOPE):
+                mvals = compute_metrics(metric_types,
+                                        logits.astype(jnp.float32), label)
+            return (params, opt_state, new_state, loss,
+                    grad_sentinels(mvals, loss, grads))
 
         def accum_step(params, opt_state, state, inputs, label, rng):
             """accum_steps=N microbatching: inputs/label carry a leading
@@ -648,8 +664,9 @@ class CompiledModel:
                     params, state, ins, lab, jax.random.fold_in(rng, j))
                 if zero == "zero2":
                     grads = wsc(grads, moment_sh)
-                mvals = compute_metrics(metric_types,
-                                        logits.astype(jnp.float32), lab)
+                with jax.named_scope(attribution.LOSS_SCOPE):
+                    mvals = compute_metrics(metric_types,
+                                            logits.astype(jnp.float32), lab)
                 return new_state, grads, loss, mvals
 
             def body(j, carry):
@@ -665,14 +682,12 @@ class CompiledModel:
             s, g, lsum, msum = jax.lax.fori_loop(1, accum, body,
                                                  (s, g, lsum, msum))
             inv = 1.0 / accum
-            g = jax.tree_util.tree_map(lambda t: t * inv, g)
+            with jax.named_scope(attribution.UPDATE_SCOPE):
+                g = jax.tree_util.tree_map(lambda t: t * inv, g)
             params, opt_state = apply_update(params, opt_state, g)
             loss = lsum * inv
             mvals = jax.tree_util.tree_map(lambda x: x * inv, msum)
-            if sentinels:
-                mvals = dict(mvals, **health.sentinel_metrics(
-                    loss, optax.global_norm(g)))
-            return params, opt_state, s, loss, mvals
+            return params, opt_state, s, loss, grad_sentinels(mvals, loss, g)
 
         step_fn = accum_step if accum > 1 else train_step
 
@@ -700,6 +715,11 @@ class CompiledModel:
         # alive after each step (debugging / external references)
         donate = (0, 1, 2) if self.cfg.donate_state else ()
         self.train_step = jax.jit(_wrap(step_fn), donate_argnums=donate)
+        # what the step's device time is made of, for whoever asks
+        # (attribution.op_scopes): a weak reference now, the executable at
+        # the first dispatch, its HLO text only on demand
+        self._programs = {1: attribution.register_program(
+            "train_step", self.train_step, self.model.layers)}
         self.eval_step = jax.jit(_wrap(eval_step))
         self.infer_step = jax.jit(_wrap(infer))
         self._train_step_fn = step_fn  # unjitted body for make_multi_step
@@ -712,6 +732,10 @@ class CompiledModel:
         fn = self._multi_cache.get(k)
         if fn is None:
             fn = self._multi_cache[k] = self.make_multi_step(k)
+            # the fused loop runs the same step body: same scopes, a
+            # program of its own (its steps lie inside one `while`)
+            self._programs[k] = attribution.register_program(
+                "train_step", fn, self.model.layers)
         return fn
 
     def make_multi_step(self, n: int, donate: "Optional[bool]" = None):
@@ -1104,14 +1128,22 @@ class CompiledModel:
                   with tel.span("fit/dispatch", cat="fit",
                                 step_num=self._iteration, kind=kind) as sp:
                       if kind == "k":
+                          i0 = jnp.int32(self._iteration)
+                          if self._programs[k].compiled is None:
+                              self._programs[k].first_run(
+                                  self.params, self.opt_state, self.state,
+                                  dx, dy, base_rng, i0)
                           (self.params, self.opt_state, self.state, loss,
                            mvals) = multi(self.params, self.opt_state,
-                                          self.state, dx, dy, base_rng,
-                                          jnp.int32(self._iteration))
+                                          self.state, dx, dy, base_rng, i0)
                           steps = k
                           stats["fused_steps"] += k
                       else:  # single step (k==1, or the fused-epoch tail)
                           rng = jax.random.fold_in(base_rng, self._iteration)
+                          if self._programs[1].compiled is None:
+                              self._programs[1].first_run(
+                                  self.params, self.opt_state, self.state,
+                                  dx, dy, rng)
                           (self.params, self.opt_state, self.state, loss,
                            mvals) = self.train_step(self.params,
                                                     self.opt_state,
@@ -1401,7 +1433,8 @@ class CompiledModel:
         report = attribution.build_report(
             items, step_time_s=step_time_s,
             mult=max(1, int(self._accum_steps)),
-            profile_dir=profile_dir, source=source)
+            profile_dir=profile_dir, source=source,
+            programs=list(self._programs.values()))
         if print_table:
             for line in attribution.format_report(report, top=top):
                 print(line)

@@ -126,11 +126,13 @@ def build_forward(
         for layer in order:
             ins = [env[t.guid] for t in layer.inputs]
             w = params.get(layer.name, {})
-            # stamp the graph-layer name into the XLA op metadata
-            # (name_stack -> HLO metadata.op_name): profiler traces emitted
-            # under --profiling carry "<layer.name>/..." source names, which
-            # is how attribution.measured_from_trace maps fused XLA ops back
-            # to graph layers (ISSUE 7 primary measurement path)
+            # stamp the graph-layer name into the name stack: the compiled
+            # program's optimized HLO then says, per instruction,
+            # metadata.op_name "jit(train_step)/transpose(jvp(<layer.name>))
+            # /dot_general". The profile itself carries no such names (a
+            # device event is named by its HLO instruction), so
+            # attribution.op_scope_map reads them from the executable's
+            # text and joins by instruction name.
             scope = jax.named_scope(layer.name)
             if cast_to is not None:
                 # uniform mixed-precision policy: master weights stay f32 in
@@ -140,10 +142,12 @@ def build_forward(
                 # affine in f32 (standard AMP keeps norm params full
                 # precision) — including norms inside fork_join branches.
                 ex = cast_exempt.get(layer.name, ())
-                w = {k: (v.astype(cast_to)
-                         if k not in ex and jnp.issubdtype(v.dtype, jnp.floating)
-                         else v)
-                     for k, v in w.items()}
+                with jax.named_scope(layer.name):   # the cast is its work too
+                    w = {k: (v.astype(cast_to)
+                             if k not in ex
+                             and jnp.issubdtype(v.dtype, jnp.floating)
+                             else v)
+                         for k, v in w.items()}
             pol = remat_map.get(layer.name)
             if pol in _ckpt_policies:
                 # run the layer inside jax.checkpoint as a pure function of

@@ -174,7 +174,8 @@ class FFConfig:
     profiling: bool = False
     profile_dir: str = ""  # xplane trace output dir ("" = ./ff_profile)
     # per-op attribution (flexflow_tpu/attribution.py): at fit end, join
-    # per-op measured times (profiler trace under --profiling, else
+    # per-op measured times (under --profiling the profile's device events
+    # joined by instruction name with the compiled step's HLO, else
     # partitioned re-execution) against the search's stamped per-op
     # predicted costs and the roofline bound — per-op MFU, compute-vs-
     # bandwidth classification and the per-op drift top-K, printed via

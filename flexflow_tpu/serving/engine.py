@@ -51,7 +51,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec
 
-from flexflow_tpu import health
+from flexflow_tpu import attribution, health
 from flexflow_tpu import telemetry as tel
 from flexflow_tpu.runtime.checkpoint import (CheckpointMismatchError,
                                              _graph_fingerprint)
@@ -421,6 +421,17 @@ class ServingCompiled:
         # handed), the params never (hot-swap: in-flight work holds them)
         self._decode_jit = jax.jit(_decode, donate_argnums=(1,))
         self._decode_fn = _decode
+        # what each program's device time is made of, for whoever asks
+        # (attribution.op_scopes): weak references here, the executable
+        # at a program's first run, its HLO text only on demand
+        self._programs = {
+            "prefill": attribution.register_program(
+                "serve/prefill", self._prefill_jit, prefill_model.layers),
+            "prefill_first_tokens": attribution.register_program(
+                "serve/prefill", self._prefill_first_tokens_jit,
+                prefill_model.layers),
+            "decode": attribution.register_program(
+                "serve/decode", self._decode_jit, decode_model.layers)}
         self._verify_jit = None
         self._verify_fn = None
         self._spec_jit = None
@@ -712,6 +723,10 @@ class ServingCompiled:
 
     # ------------------------------------------------------------ programs
     def _run_prefill(self, jitted, *args):
+        prog = self._programs["prefill" if jitted is self._prefill_jit
+                              else "prefill_first_tokens"]
+        if prog.compiled is None:
+            prog.first_run(*args)
         if not tel.enabled():
             return jitted(*args)
         t0 = tel.now_us()
@@ -749,7 +764,10 @@ class ServingCompiled:
         prefill's do in its kv_state: whoever reads them pops them, so what
         goes into the next step has the shape of what came into this one."""
         t0 = tel.now_us() if tel.enabled() else None
-        logits, new_state = self._decode_jit(params, state, list(input_arrays))
+        inputs = list(input_arrays)
+        if self._programs["decode"].compiled is None:
+            self._programs["decode"].first_run(params, state, inputs)
+        logits, new_state = self._decode_jit(params, state, inputs)
         if t0 is not None:
             tel.record("serve/decode_step", t0, cat="serve")
         return logits, new_state
@@ -896,7 +914,8 @@ class ServingCompiled:
     def op_attribution(self, kind: str = "both",
                        step_time_s: Optional[float] = None,
                        prefill_step_time_s: Optional[float] = None,
-                       print_table: bool = False, top: int = 0
+                       print_table: bool = False, top: int = 0,
+                       profile_dir: Optional[str] = None
                        ) -> Dict[str, Any]:
         """Serving-regime per-op attribution (ISSUE 14 satellite): the
         serving face of CompiledModel.op_attribution. One report per
@@ -907,8 +926,12 @@ class ServingCompiled:
         learned cost model) the bandwidth-bound seq=1 decode regime that
         training fits never exercise. step_time_s normalizes decode rows
         (the scheduler passes its median per-token wall), prefill_step_
-        time_s the prefill rows (the shed estimator's EMA)."""
-        from flexflow_tpu import attribution
+        time_s the prefill rows (the shed estimator's EMA). With
+        `profile_dir` (a `jax.profiler.trace` of a serving run of this
+        engine) and a step time, a program's rows are measured from the
+        profile: its device events joined by instruction name with the
+        program's own compiled HLO (`source == "trace"`); else each op is
+        re-executed alone."""
         from flexflow_tpu.search.candidates import compiled_candidate
         from flexflow_tpu.serving.program import (_decode_cost_fn,
                                                   _prefill_cost_fn)
@@ -918,15 +941,17 @@ class ServingCompiled:
             programs.append(("serve_prefill", self.prefill_model,
                              self.prefill_strategy,
                              _prefill_cost_fn(self.machine),
-                             prefill_step_time_s))
+                             prefill_step_time_s,
+                             [self._programs["prefill"],
+                              self._programs["prefill_first_tokens"]]))
         if kind in ("both", "decode"):
             programs.append(("serve_decode", self.decode_model,
                              self.decode_strategy,
                              _decode_cost_fn(self.machine,
                                              self.kv_spec.layer_bytes()),
-                             step_time_s))
+                             step_time_s, [self._programs["decode"]]))
         reports: Dict[str, Any] = {}
-        for tag, smodel, strategy, cost, t_step in programs:
+        for tag, smodel, strategy, cost, t_step, compiled in programs:
             batch_sizes = {t.spec.shape[0] for t in smodel.input_tensors
                            if t.spec.ndim > 0}
             items = []
@@ -943,7 +968,9 @@ class ServingCompiled:
                               "machine": self.machine,
                               "predicted_s": predicted, "stage": None})
             report = attribution.build_report(
-                items, step_time_s=t_step, mult=1, source="measure",
+                items, step_time_s=t_step, mult=1,
+                source="auto" if profile_dir else "measure",
+                profile_dir=profile_dir, programs=compiled,
                 inference=True, tag=tag)
             if print_table:
                 print(f"[{tag}]")

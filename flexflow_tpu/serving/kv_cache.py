@@ -95,6 +95,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
+from flexflow_tpu import attribution
 from flexflow_tpu.search.cost_model import KVCacheSpec
 
 PAGE_TABLE_KEY = "serve/page_table"
@@ -228,6 +229,13 @@ class PagedKVCache:
                  recurrent: Optional[Dict[str, Dict[str, tuple]]] = None):
         self.spec = spec
         self.machine = machine  # host_bw source for transfer pricing rows
+        # the commit programs as this cache runs them (attribution.op_scopes
+        # "serve/commit"): they hold no graph layer, so their device time
+        # is a wave's `other`: found, because they are registered
+        self._commit_kv = attribution.register_program(
+            "serve/commit", _commit_prefill, (), owner=self)
+        self._commit_state = attribution.register_program(
+            "serve/commit", _commit_state, (), owner=self)
         self.attn_layers = list(attn_layers)
         # {layer: {leaf: (per-slot shape, dtype)}} (program.recurrent_layers)
         # (compile_serving refuses a host tier beside them)
@@ -631,15 +639,20 @@ class PagedKVCache:
         # adopted before the next one reads `self.state`
         with tel.span("serve/prefill/commit_kv", cat="serve",
                       bytes=_tree_bytes(fresh), state=self.state_kinds):
+            if self._commit_kv.compiled is None:
+                self._commit_kv.first_run(paged, fresh, slot_ids, lengths)
             self.state = {**self.state,
                           **_commit_prefill(paged, fresh, slot_ids, lengths)}
         if self.recurrent:
             fresh = {n: kv_state[n] for n in self.recurrent}
             with tel.span("serve/prefill/commit_state", cat="serve",
                           bytes=_tree_bytes(fresh)):
-                self.state.update(_commit_state(
-                    {n: self.state[n] for n in self.recurrent}, fresh,
-                    slot_ids, lengths))
+                had = {n: self.state[n] for n in self.recurrent}
+                if self._commit_state.compiled is None:
+                    self._commit_state.first_run(had, fresh, slot_ids,
+                                                 lengths)
+                self.state.update(_commit_state(had, fresh, slot_ids,
+                                                lengths))
 
     def adopt(self, new_state) -> None:
         """Take ownership of the state returned by a decode step (a pointer

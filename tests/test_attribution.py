@@ -9,7 +9,6 @@ span_dataset with stable feature keys, and the drift top-K is populated
 after a fit with telemetry on.
 """
 
-import json
 import os
 import sys
 
@@ -211,63 +210,324 @@ def test_feature_key_dedups_structural_twins(devices):
 
 
 # --------------------------------------------------------- trace primary path
-def test_measured_from_trace_boundary_and_normalization(devices, tmp_path):
-    """The --profiling trace path: events map to layers only on exact
-    "<name>/" path segments (no prefix/substring bleed — "up" must not
-    absorb "update"), and build_report normalizes the WHOLE-RUN trace
-    totals onto the measured per-update step time."""
-    pdir = tmp_path / "prof" / "plugins" / "profile" / "run1"
-    pdir.mkdir(parents=True)
-    events = [
-        # 3 steps of the same two ops (whole-run totals 300us and 600us)
-        *[{"ph": "X", "ts": i * 1000.0, "dur": 100.0,
-           "name": f"jit(train_step)/up/dot_general.{i}"}
-          for i in range(3)],
-        *[{"ph": "X", "ts": i * 1000.0 + 500, "dur": 200.0,
-           "name": f"jit(train_step)/down/dot_general.{i}"}
-          for i in range(3)],
-        # must NOT be credited to layer "up": not a "<name>/" segment
-        {"ph": "X", "ts": 9000.0, "dur": 5000.0, "name": "update/adam"},
-        {"ph": "X", "ts": 9500.0, "dur": 5000.0, "name": "warmup/copy"},
-        {"ph": "i", "ts": 0.0, "name": "up/instant_without_dur"},
-    ]
-    with open(pdir / "host.trace.json", "w") as f:
-        json.dump({"traceEvents": events}, f)
-
-    totals = attribution.measured_from_trace(
-        str(tmp_path / "prof"), ["up", "down"])
-    assert totals == {"up": 300.0, "down": 600.0}
+def _twin(profile_dir=None, layers=2, **cfg_kw):
+    """The tiny GPT-2 twin, compiled with Adam on one device, and a batch."""
+    from flexflow_tpu import AdamOptimizer
 
     cfg = FFConfig(batch_size=8, only_data_parallel=True,
-                   log_level="warning")
+                   mesh_shape={"data": 1}, log_level="warning",
+                   profiling=profile_dir is not None,
+                   profile_dir=profile_dir or "", **cfg_kw)
     m = FFModel(cfg)
-    x = m.create_tensor([8, 16], name="x")
-    m.dense(m.dense(x, 32, activation="relu", name="up"), 4, name="down")
-    cm = m.compile(SGDOptimizer(),
-                   LossType.SPARSE_CATEGORICAL_CROSSENTROPY)
-    items = [{"layer": m.get_layer_by_name(n),
-              "cand": cm._candidate_for(m.get_layer_by_name(n)),
-              "machine": cm.machine, "predicted_s": None, "stage": None}
-             for n in ("up", "down")]
-    report = attribution.build_report(
-        items, step_time_s=0.009, profile_dir=str(tmp_path / "prof"),
-        source="trace", emit=False)
+    gcfg = GPT2Config(vocab=128, seq=8, d_model=32, heads=2, layers=layers,
+                      dropout=0.0)
+    build_gpt2(m, gcfg, batch=8)
+    cm = m.compile(AdamOptimizer(alpha=0.01),
+                   LossType.SPARSE_CATEGORICAL_CROSSENTROPY, metrics=[])
+    cm.init(seed=0)
+    rng = np.random.default_rng(0)
+    ids = rng.integers(0, 128, size=(32, 8)).astype(np.int32)
+    pos = np.broadcast_to(np.arange(8, dtype=np.int32), (32, 8)).copy()
+    y = rng.integers(0, 128, size=(32, 8)).astype(np.int32)
+    return cm, [ids, pos], y
+
+
+class _Layer:
+    def __init__(self, name, op="linear"):
+        self.name = name
+        self.op_type = type("T", (), {"value": op})
+
+
+@pytest.mark.parametrize("op_name, want", [
+    ("jit(train_step)/jvp(up)/dot_general", ("up", "forward")),
+    ("jit(train_step)/transpose(jvp(up))/dot_general", ("up", "backward")),
+    ("jit(prefill)/up/dot_general", ("up", "forward")),
+    # a layer named "up" absorbs neither the update scope nor a longer name
+    ("jit(train_step)/ff.update/mul", ("", "update")),
+    ("jit(train_step)/update/up_cast/mul", ("", "other")),
+    ("jit(train_step)/jvp(ffn_up_2)/mul", ("ffn_up_2", "forward")),
+    ("jit(train_step)/jvp(ff.loss)/reduce_sum", ("", "loss")),
+    ("jit(train_step)/transpose(jvp(ff.loss))/mul", ("", "loss")),
+    # the slash inside an einsum's parentheses splits nothing
+    ("jit(f)/while/body/transpose(jvp(down))/a,b->(a/b)/mul",
+     ("down", "backward")),
+    ("jit(train_step)/mul", ("", "other")),
+    # forward recomputed under jax.checkpoint runs in the backward pass
+    ("jit(train_step)/transpose(jvp(up))/jvp(up)/checkpoint/"
+     "rematted_computation/tanh", ("up", "backward")),
+])
+def test_scope_of_op_name_matches_whole_segments(op_name, want):
+    op_types = {"up": "linear", "down": "linear", "ffn_up_2": "linear"}
+    assert attribution.scope_of_op_name(op_name, op_types) == want
+
+
+def test_op_scope_map_on_the_twins_train_step(devices):
+    """The real optimized HLO of the twin's train_step: every graph layer
+    appears, all four phases are there, and `mixed` fusions are flagged."""
+    cm, x, y = _twin()
+    cm.fit(x, y, epochs=1, verbose=False)
+    maps = attribution.op_scopes("train_step")
+    prog = cm._programs[1]
+    assert prog.scopes is not None and prog.scopes in maps
+    scopes = prog.scopes
+    span = tel.ring_spans(attribution.SPAN)[-1]
+    assert span.args["program"] == "train_step"
+    assert span.args["instructions"] == len(scopes) > 100
+    # the name stacks are this tree's own (no stale compile cache)
+    assert span.args["layers_named"] >= 0.7 * span.args["layers"] > 0
+    # every layer that holds weights has instructions of its own (a
+    # residual add may live wholly inside a neighbour's fusion)
+    layers = {s.layer for s in scopes.values()}
+    assert {l.name for l in cm.model.layers if l.weight_specs} <= layers
+    by_phase = {}
+    for s in scopes.values():
+        by_phase.setdefault(s.phase, []).append(s)
+    assert {"forward", "backward", "update", "loss"} <= set(by_phase)
+    assert len(by_phase.get("other", ())) < 0.05 * len(scopes)
+    assert all(not s.layer for s in by_phase["update"] + by_phase["loss"])
+    ops = {l.name: l.op_type.value for l in cm.model.layers}
+    assert all(s.op_type == ops.get(s.layer, "") for s in scopes.values())
+    # a weight-gradient product is the backward of its layer
+    assert any(s.has_dot and s.phase == "backward"
+               and s.op_type == "linear" for s in scopes.values())
+    assert any(s.mixed for s in scopes.values())
+    # asked again: the kept map, no second span
+    n = len(tel.ring_spans(attribution.SPAN))
+    assert attribution.op_scopes("train_step") == maps
+    assert len(tel.ring_spans(attribution.SPAN)) == n
+
+
+HLO_WITH_A_FUSED_DOT = """HloModule jit_step, is_scheduled=true
+
+%fused_computation.1 (p0: f32[8,8], p1: f32[8,8], p2: f32[8,8]) -> f32[8,8] {
+  %p0 = f32[8,8]{1,0} parameter(0)
+  %p1 = f32[8,8]{1,0} parameter(1)
+  %p2 = f32[8,8]{1,0} parameter(2)
+  %dot.5 = f32[8,8]{1,0} dot(%p0, %p1), lhs_contracting_dims={1}, rhs_contracting_dims={0}, metadata={op_name="jit(step)/transpose(jvp(up))/dot_general"}
+  ROOT %sub.7 = f32[8,8]{1,0} subtract(%p2, %dot.5), metadata={op_name="jit(step)/ff.update/sub"}
+}
+
+%fused_computation.2 (p0.1: f32[8,8]) -> f32[8,8] {
+  %p0.1 = f32[8,8]{1,0} parameter(0)
+  ROOT %tanh.3 = f32[8,8]{1,0} tanh(%p0.1), metadata={op_name="jit(step)/jvp(up)/tanh"}
+}
+
+%body (c: (s32[], f32[8,8])) -> (s32[], f32[8,8]) {
+  %c = (s32[], f32[8,8]{1,0}) parameter(0)
+  %i = s32[] get-tuple-element(%c), index=0
+  %x = f32[8,8]{1,0} get-tuple-element(%c), index=1
+  %fusion.2 = f32[8,8]{1,0} fusion(%x), kind=kLoop, calls=%fused_computation.2, metadata={op_name="jit(step)/jvp(up)/tanh"}
+  ROOT %t = (s32[], f32[8,8]{1,0}) tuple(%i, %fusion.2)
+}
+
+%cond (c.1: (s32[], f32[8,8])) -> pred[] {
+  %c.1 = (s32[], f32[8,8]{1,0}) parameter(0)
+  ROOT %lt = pred[] constant(true)
+}
+
+ENTRY %main.9 (a: f32[8,8], b: f32[8,8], w: f32[8,8]) -> f32[8,8] {
+  %a = f32[8,8]{1,0:T(8,128)} parameter(0)
+  %b = f32[8,8]{1,0} parameter(1)
+  %w = f32[8,8]{1,0} parameter(2)
+  %fusion.1 = f32[8,8]{1,0:T(8,128)} fusion(%a, %b, %w), kind=kOutput, calls=%fused_computation.1, metadata={op_name="jit(step)/ff.update/sub"}
+  %ff_flash_attention_fwd.4 = (f32[8,8]{1,0}, f32[8]{0}) custom-call(%a), custom_call_target="tpu_custom_call", metadata={op_name="jit(step)/jvp(attn)/pallas_call"}
+  %ragged-dot-none.6 = f32[8,8]{1,0} custom-call(%a, %w), custom_call_target="tpu_custom_call", metadata={op_name="ragged-dot-none"}
+  %convert.7 = f32[8,8]{1,0} convert(%ragged-dot-none.6), metadata={op_name="jit(step)/jvp(moe)/convert_element_type"}
+  %zero = s32[] constant(0)
+  %init = (s32[], f32[8,8]{1,0}) tuple(%zero, %fusion.1)
+  %while.3 = (s32[], f32[8,8]{1,0}) while(%init), condition=%cond, body=%body
+  ROOT %out = f32[8,8]{1,0} get-tuple-element(%while.3), index=1
+}
+"""
+
+
+def test_op_scope_map_credits_a_fusion_to_its_one_dot():
+    scopes = attribution.op_scope_map(
+        HLO_WITH_A_FUSED_DOT,
+        [_Layer("up"), _Layer("attn", "multihead_attention"),
+         _Layer("moe", "moe_layer")])
+    # the weight-gradient product with the update in its epilogue: the
+    # dot's scope, not the root's, and flagged
+    assert scopes["fusion.1"] == attribution.OpScope(
+        "up", "linear", "backward", "fusion", True, True)
+    # an instruction inside a while body is an event of its own
+    assert scopes["fusion.2"] == attribution.OpScope(
+        "up", "linear", "forward", "fusion", False, False)
+    assert scopes["while.3"].opcode == "while"
+    # a named kernel keeps its ff_ name as its opcode
+    assert scopes["ff_flash_attention_fwd.4"] == attribution.OpScope(
+        "attn", "multihead_attention", "forward", "ff_flash_attention_fwd",
+        True, False)
+    # what the chip's compiler makes of a ragged-dot carries its own name
+    # for a name stack: it belongs to the layer that uses its result
+    assert scopes["ragged-dot-none.6"] == attribution.OpScope(
+        "moe", "moe_layer", "forward", "ragged-dot-none", True, False, True)
+    # what has no event of its own is not in the map
+    assert not {"a", "zero", "init", "out", "dot.5", "lt"} & set(scopes)
+
+
+def test_join_ambiguous_unattributed_and_containers():
+    S = attribution.OpScope
+    prefill = {"fusion.1": S("attn", "multihead_attention", "forward",
+                             "fusion", True, False),
+               "copy.2": S("attn", "multihead_attention", "forward", "copy",
+                           False, False),
+               "while.3": S("", "", "other", "while", False, False)}
+    commit = {"fusion.1": S("", "", "other", "fusion", False, False),
+              "copy.2": prefill["copy.2"],
+              "scatter.4": S("", "", "other", "scatter", False, False)}
+    events = [("while.3", 0, 100),          # a container: its body counts
+              ("fusion.1", 10, 40), ("copy.2", 40, 50), ("copy.2", 50, 55),
+              ("scatter.4", 100, 120), ("fusion.99", 120, 127),
+              ("while.8", 130, 140)]        # unmapped, a container by name
+    by = attribution.device_time_by_scope(events, [prefill, commit])
+    assert by[prefill["copy.2"]] == 15            # same scope in both: kept
+    assert by[S("", "", attribution.AMBIGUOUS, "fusion", False, False)] == 30
+    assert by[commit["scatter.4"]] == 20
+    assert by[S("", "", attribution.UNATTRIBUTED, "fusion.99", False,
+                False)] == 7
+    assert sum(by.values()) == 30 + 15 + 20 + 7
+    # one map alone: nothing is ambiguous
+    alone = attribution.device_time_by_scope(events, prefill)
+    assert alone[prefill["fusion.1"]] == 30
+    assert attribution.AMBIGUOUS not in {s.phase for s in alone}
+
+
+def test_measured_from_trace_on_a_real_profile(devices, tmp_path, capsys):
+    """One profiled fit of the twin through jax.profiler.trace
+    (--profiling): the written .xplane.pb joined with the step's own HLO
+    gives most layers a time, by phase, and the report says `trace`."""
+    pdir = str(tmp_path / "prof")
+    cm, x, y = _twin(profile_dir=pdir)
+    cm.fit(x, y, epochs=2, verbose=False)
+    totals = attribution.measured_from_trace(pdir, cm._programs.values())
+    named = [l.name for l in cm.model.layers]
+    timed = [n for n in named if sum(totals.get(n, {}).values()) > 0]
+    assert len(timed) >= 0.7 * len(named), (timed, named)
+    assert totals["h0_attn"]["forward"] > 0 < totals["h0_attn"]["backward"]
+    assert totals[""]["update"] > 0 and totals[""]["loss"] > 0
+    assert not totals[""].get(attribution.UNATTRIBUTED)
+    report = cm.op_attribution(source="trace", print_table=True)
     assert report["source"] == "trace"
+    _assert_report_shape(report)
     by = {r["layer"]: r for r in report["rows"]}
-    # per-update measured = stream share x step time (1/3 and 2/3 of 9ms)
-    assert by["up"]["measured_s"] == pytest.approx(0.003)
-    assert by["down"]["measured_s"] == pytest.approx(0.006)
-    assert report["attributed_total_s"] == pytest.approx(0.009)
-    # trace source without a measured step time is an explicit error;
-    # "auto" silently falls back to the re-execution path
+    assert by["h0_attn"]["phases_s"]["backward"] > 0
+    assert report["outside_s"]["update"] > 0
+    # the layers' rows and what runs outside every layer make up the step
+    # (pass-through placements have no row)
+    whole = report["measured_total_s"] + sum(report["outside_s"].values())
+    assert whole <= report["step_time_s"] * 1.0001
+    assert report["coverage"] > 0.5
+    out = capsys.readouterr().out
+    assert "source=trace" in out and "fwd" in out and "update=" in out
+    # no profile, no trace path: explicit where asked for, silent in auto
+    with pytest.raises(ValueError, match="no parseable profiler trace"):
+        attribution.build_report([], step_time_s=0.01, source="trace",
+                                 profile_dir=str(tmp_path / "none"),
+                                 programs=cm._programs.values())
     with pytest.raises(ValueError, match="step"):
-        attribution.build_report(items, step_time_s=None,
-                                 profile_dir=str(tmp_path / "prof"),
-                                 source="trace", emit=False)
-    rep2 = attribution.build_report(items, step_time_s=None,
-                                    profile_dir=str(tmp_path / "prof"),
-                                    source="auto", emit=False)
-    assert rep2["source"] == "measure"
+        attribution.build_report([], step_time_s=None, source="trace",
+                                 profile_dir=pdir,
+                                 programs=cm._programs.values())
+
+
+def test_a_chips_events_count_for_the_module_that_ran_them(tmp_path):
+    """A TPU profile names an event by its instruction and says on the
+    "XLA Modules" line which program ran when: `fusion.7` of the rng's
+    little programs is not the step's `fusion.7`. The chip's own recorded
+    profile of a fused fit (tests/benchmark/recorded_scope_fit.*)."""
+    import gzip
+    import json
+    import shutil
+
+    here = os.path.join(os.path.dirname(__file__), "benchmark")
+    shutil.copy(os.path.join(here, "recorded_scope_fit.xplane.pb"),
+                tmp_path / "host.xplane.pb")
+    events = attribution.profile_events(str(tmp_path))
+    assert {"jit_multi", "jit__threefry_seed"} <= set(events)
+    n = {m: len(evs) for m, evs in events.items()}
+    assert n["jit_multi"] > 0.9 * sum(n.values())
+    with gzip.open(os.path.join(here, "recorded_scope_fit.json.gz"), "rt") as f:
+        facts = json.load(f)
+
+    def program(text):
+        prog = attribution.Program("train_step", lambda: None,
+                                   [_Layer(n, t) for n, t in facts["layers"]])
+        prog.compiled = type("C", (), {"as_text": lambda self: text})()
+        return prog
+
+    step = program(facts["hlo_text"])
+    totals = attribution.measured_from_trace(str(tmp_path), [step])
+    assert step.module == "jit_multi"
+    busy = sum(e - s for _n, s, e in events["jit_multi"]
+               if attribution.fold_name(_n) not in attribution.CONTAINERS)
+    assert sum(us for ph in totals.values() for us in ph.values()) == \
+        pytest.approx(busy / 1e3)
+    assert totals[""]["update"] > 0 and totals[""]["loss"] > 0
+    # the same text under another module's name: none of the profile's
+    # events are that program's
+    other = program(facts["hlo_text"].replace("HloModule jit_multi",
+                                              "HloModule jit_eval_step", 1))
+    assert attribution.measured_from_trace(str(tmp_path), [other]) is None
+
+
+def test_registration_renders_nothing(devices):
+    """compile + fit with nobody asking: no compile/op_scopes span, no HLO
+    text, and the loop's counters are the baseline's
+    (tests/test_telemetry.py pins the same numbers)."""
+    tel.ring_clear()
+    cm, x, y = _twin(layers=1)
+    cm.fit(x, y, epochs=2, verbose=False)
+    assert not tel.enabled()
+    assert tel.ring_spans(attribution.SPAN) == []
+    prog = cm._programs[1]
+    assert prog.scopes is None and prog.compiled is not None
+    assert cm.step_stats == {"dispatches": 8, "host_syncs": 0, "barriers": 0,
+                             "fused_steps": 0, "epoch_end_syncs": 2}
+    # the fused loop registers its own program under the same name
+    cm.fit(x, y, epochs=1, verbose=False, steps_per_dispatch=2)
+    assert cm._programs[2].compiled is not None
+    assert cm._programs[2].scopes is None
+    assert tel.ring_spans(attribution.SPAN) == []
+    # the last owner's programs outlive it, for whoever reads after the
+    # model is gone (the benchmark's reader); they go when the next
+    # program registers under the name
+    import gc
+    mine = {id(p) for p in cm._programs.values()}
+    del cm, prog
+    gc.collect()
+    held = [p for p in attribution._PROGRAMS["train_step"] if id(p) in mine]
+    assert len(held) == 2 and all(p._owner() is None for p in held)
+    maps = attribution.op_scopes("train_step")
+    assert all(p.scopes and p.scopes in maps for p in held)
+    del held
+    cm2, _x, _y = _twin(layers=1)
+    assert cm2._programs[1] in attribution._PROGRAMS["train_step"]
+    assert all(p._owner() is not None
+               for p in attribution._PROGRAMS["train_step"])
+
+
+def test_a_registration_shares_its_owners_life():
+    """A cache registers a module's jit (which never dies) at its own
+    shapes: the registration lives as long as the cache, stays readable
+    after it, and goes when the next one registers under the name."""
+    import gc
+
+    class Owner:
+        pass
+
+    def jitted():
+        pass
+
+    first = Owner()
+    p1 = attribution.register_program("test/owner", jitted, (), owner=first)
+    del first
+    gc.collect()
+    assert attribution._PROGRAMS["test/owner"] == [p1]
+    second = Owner()
+    p2 = attribution.register_program("test/owner", jitted, (), owner=second)
+    p3 = attribution.register_program("test/owner", jitted, (), owner=second)
+    assert attribution._PROGRAMS["test/owner"] == [p2, p3]
 
 
 # ------------------------------------------------------ probe -> telemetry

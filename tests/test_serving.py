@@ -506,6 +506,43 @@ def test_serve_profile_ops_emits_corpus_rows(gpt2_serve, rng, tmp_path):
     assert any((a.get("predicted_s") or 0) > 0 for a in dec)
 
 
+def test_a_profiled_run_gives_each_program_its_own_events(gpt2_serve, rng,
+                                                           tmp_path):
+    """engine.op_attribution(profile_dir=...): rows measured from a real
+    profile (`source == "trace"`), and each program's report takes only
+    the events of its own module: `fusion.3` exists in the prefill, the
+    commit and the decode program alike."""
+    from flexflow_tpu import attribution
+
+    eng, gc = gpt2_serve
+    pdir = str(tmp_path / "prof")
+    reqs = [Request(rid=i, prompt=list(rng.integers(1, gc.vocab, size=3)),
+                    max_new_tokens=4, arrival_s=0.0) for i in range(3)]
+    sched = ContinuousBatchingScheduler(eng, eng.params, gpt2_prompt_inputs,
+                                        gpt2_step_inputs)
+    with jax.profiler.trace(pdir):
+        sched.run(reqs)
+        jax.block_until_ready(eng.kv.state)
+    events = attribution.profile_events(pdir)
+    pre, dec = (eng._programs["prefill_first_tokens"],
+                eng._programs["decode"])
+    reports = eng.op_attribution(step_time_s=1e-3, prefill_step_time_s=1e-2,
+                                 profile_dir=pdir)
+    assert {pre.module, dec.module} <= set(events)
+    assert pre.module != dec.module
+    assert all(r["source"] == "trace" for r in reports.values())
+    for prog, tag in ((pre, "serve_prefill"), (dec, "serve_decode")):
+        own = attribution.measured_from_trace(pdir, [prog])
+        busy = sum(e - s for n, s, e in events[prog.module]
+                   if prog.scopes[n].opcode not in attribution.CONTAINERS)
+        assert sum(us for ph in own.values() for us in ph.values()) \
+            == pytest.approx(busy / 1e3)
+        assert not any(attribution.UNATTRIBUTED in ph for ph in own.values())
+        rows = reports[tag]["rows"]
+        assert sum(r["measured_s"] for r in rows) > 0
+        assert all(set(r["phases_s"]) <= {"forward"} for r in rows)
+
+
 def test_serve_telemetry_stream(gpt2_serve, rng, tmp_path):
     """serve/prefill + serve/decode_step spans, queue/slot counters and
     per-request lifecycle events flow through the PR 5 sink and feed the

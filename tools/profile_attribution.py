@@ -1,10 +1,13 @@
 #!/usr/bin/env python
 """Per-op attribution evidence run (ISSUE 7 acceptance).
 
-Fits the gpt2 CPU twin with telemetry + `--profile-ops` semantics, runs the
-per-op attribution join (flexflow_tpu/attribution.py) and verifies the
-acceptance contract end to end:
+Fits the gpt2 CPU twin with telemetry + `--profiling` + `--profile-ops`
+semantics, runs the per-op attribution join (flexflow_tpu/attribution.py) on
+the profile the fit wrote and verifies the acceptance contract end to end:
 
+  * the rows are measured from the real profile (`source == "trace"`: the
+    `.xplane.pb`'s per-instruction events joined with the compiled step's
+    own HLO, forward and backward apart),
   * per-op attributed times sum to the MEASURED per-update step time
     within attribution.SUM_TOLERANCE (15%),
   * every op row carries predicted cost, measured time, roofline bound
@@ -33,12 +36,14 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 def _build_twin(tdir: str, blocks: int, batch: int = 8):
     """The gpt2 CPU twin (the bench family's standard subject): a scaled
-    GPT-2 on the virtual data mesh, compiled with telemetry on."""
+    GPT-2 on the virtual data mesh, compiled with telemetry on and the
+    fit profiled (jax.profiler.trace into <tdir>/profile)."""
     from flexflow_tpu import FFConfig, FFModel, SGDOptimizer
     from flexflow_tpu.models import GPT2Config, build_gpt2
 
     cfg = FFConfig(batch_size=batch, only_data_parallel=True,
-                   telemetry_dir=tdir, log_level="warning")
+                   telemetry_dir=tdir, log_level="warning", profiling=True,
+                   profile_dir=os.path.join(tdir, "profile"))
     m = FFModel(cfg)
     gcfg = GPT2Config(vocab=256, seq=16, d_model=64, heads=4,
                       layers=blocks, dropout=0.0)
@@ -99,9 +104,10 @@ def run(epochs: int = 3, blocks: int = 2, batch: int = 8,
             "compute_bound_ops": sum(1 for r in rows
                                      if r["bound"] == "compute"),
             "corpus_rows": len(corpus),
-            "top_ops": [{k: r[k] for k in
+            "outside_layers_s": report["outside_s"],
+            "top_ops": [{k: r.get(k) for k in
                          ("layer", "op", "predicted_s", "attributed_s",
-                          "roofline_s", "mfu", "bound")}
+                          "roofline_s", "mfu", "bound", "phases_s")}
                         for r in rows[:8]],
         }
         return result
@@ -118,6 +124,8 @@ def verify(result: Dict[str, Any], report_rows_checked: bool = True) -> None:
     from flexflow_tpu import attribution
 
     assert result["rows"] > 0, "no op rows attributed"
+    assert result["source"] == "trace", \
+        f"rows are {result['source']!r}, not measured from the fit's profile"
     step, att = result["step_time_s"], result["attributed_total_s"]
     assert step and step > 0, "no measured step time (fit didn't record " \
                               "drift windows)"

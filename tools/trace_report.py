@@ -341,15 +341,22 @@ def render(path: str, out_path: Optional[str] = None, top: int = 0,
                       + (" DRIFT-WARNING" if d.get("warn") else ""))
         if ops:
             show = ops[:top] if top else ops[:12]
+            sources = sorted({str(r.get("source")) for r in ops})
             print(f"[ops] {len(ops)} attributed ops "
-                  "(attributed / predicted / roofline, per update):")
+                  "(attributed / predicted / roofline, per update; "
+                  f"source={','.join(sources)}):")
             for r in show:
                 st = f" s{r['stage']}" if r.get("stage") is not None else ""
+                # rows measured from a profile carry the layer's device
+                # time by phase
+                ph = r.get("phases_s") or {}
                 print(f"[ops]   {str(r.get('layer'))[:28]:28}{st} "
                       f"{(r.get('attributed_s') or 0) * 1e6:9.1f}u / "
                       f"{(r.get('predicted_s') or 0) * 1e6:9.1f}u / "
                       f"{(r.get('roofline_s') or 0) * 1e6:9.1f}u  "
-                      f"mfu={r.get('mfu', 0):.2f} {r.get('bound', '?')}")
+                      f"mfu={r.get('mfu', 0):.2f} {r.get('bound', '?')}"
+                      + "".join(f" {p}={v * 1e6:.1f}u"
+                                for p, v in sorted(ph.items())))
         for d in op_drifts:
             print(f"[ops] drift top-K: worst={d.get('worst')} "
                   f"explains(top-k)={100 * (d.get('explained') or 0):.0f}% "
