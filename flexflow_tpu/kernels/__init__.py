@@ -6,6 +6,8 @@ fused_ce — blockwise online-logsumexp sparse cross-entropy (fwd + custom
 VJP): the loss never materializes an f32 [N, vocab] array.
 dequant_attention — fused int8-dequant + decode attention over the
 quantized paged KV cache (serving --kv-cache-dtype int8).
+ssd_scan — the Mamba-2 chunked scan with the mixer's skip, gate and norm on
+the same tile (forward; ops/ssm_ops.py gives it the XLA form's gradient).
 """
 
 from flexflow_tpu.kernels.dequant_attention import (  # noqa: F401
@@ -18,4 +20,9 @@ from flexflow_tpu.kernels.flash_attention import (  # noqa: F401
 from flexflow_tpu.kernels.fused_ce import (  # noqa: F401
     fused_ce_supported,
     fused_cross_entropy,
+)
+from flexflow_tpu.kernels.ssd_scan import (  # noqa: F401
+    scan_tiles,
+    ssd_chunk_scan,
+    ssd_chunk_scan_gated,
 )

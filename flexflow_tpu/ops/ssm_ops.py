@@ -14,16 +14,18 @@ the layout of Hugging Face's Mamba2 / GraniteMoeHybrid mixers.
 groups of H / G consecutive heads that share one B and one C, and the gated
 norm RMS_G normalises each group's d_inner / G values apart (one weight of
 d_inner); with G = 1 that is one B, one C and a norm over all d_inner. A
-layer with G = 1 lowers to the program it lowered to before groups existed.
+decode step with G = 1 lowers to the program it lowered to before groups
+existed.
 
 Three forms of one op, chosen by `params["mode"]`:
 
-- None (training, evaluation): the whole sequence by chunks of
-  `params["chunk"]` (the SSD form: inside a chunk the recurrence is one
-  masked matrix product, between chunks the state is carried by a
-  `lax.scan`, so the `[chunk, chunk]` intermediates exist for one chunk at
-  a time). Equal to the recurrence at any length; a length that is no
-  multiple of the chunk is padded with steps that change nothing.
+- None (training, evaluation): the whole sequence by chunks (the SSD form:
+  inside a chunk the recurrence is one masked matrix product a head,
+  between chunks the state is carried). Equal to the recurrence at any
+  length and at any chunk; a length that is no multiple of the chunk is
+  padded with steps that change nothing. `params["chunk"]` is the
+  configuration's published chunk size: it bounds the tile the scan takes
+  and is not mathematics.
 - "state_out" (serving prefill): the same, and the state is handed out in
   `ctx.new_state[layer.name] = {"ssm": [b, H, P, N] f32, "conv": [b,
   d_conv - 1, conv_dim]}`.
@@ -32,6 +34,23 @@ Three forms of one op, chosen by `params["mode"]`:
   (ctx.add_stat) `ssm_state_bytes`: the state the step's live slots read
   and wrote (both leaves, twice).
 
+The sequence forms are one algorithm at every size, its form chosen from the
+shapes alone (`scan_path`, reported a lowered layer by the `ssm/scan_path`
+span):
+
+- the kernel (`kernels/ssd_scan.py`, `ff_ssd_chunk_scan`) where a group's
+  heads and the state fill whole lanes (the served widths: heads of 64, a
+  state of 128): the `[chunk, chunk]` decay mask, `C B^T`, their product
+  with `dt` and the product with `u` live on a tile in VMEM, the state is
+  carried in VMEM over the tiles of a row, and the skip, the gate and the
+  group's norm are applied to the tile before it is written, so of the scan
+  only its result in the compute type and the last state reach HBM. Its
+  gradient is the XLA form's (`custom_vjp`, recomputed).
+- the XLA form (`_ssd_xla`, jax.numpy; gradients from JAX) elsewhere: the
+  same products batched over the chunks with the heads leading, the
+  `[rows, heads, chunk, chunk]` intermediates streamed through HBM for
+  `MAMBA_TOKEN_BLOCK` tokens at a time.
+
 The second input, `valid` `[b, s]` (int, 1 = a real token), says which
 positions exist: at the others `dt` is 0 (the state neither decays nor
 takes anything in) and nothing enters the conv tail. A right-padded prompt
@@ -39,7 +58,7 @@ wave therefore hands out each row's state after its LAST REAL token, and a
 decode step advances only the slots that `valid` names. Without the input
 every position is real.
 
-Plain XLA (jax.numpy); gradients come from JAX.
+The projections, the conv and the decode step are plain XLA (jax.numpy).
 """
 
 from __future__ import annotations
@@ -51,8 +70,11 @@ import jax.numpy as jnp
 
 if TYPE_CHECKING:
     from flexflow_tpu.core.layer import Layer
+from flexflow_tpu import telemetry as tel
 from flexflow_tpu.core.tensor import TensorSpec
 from flexflow_tpu.dtype import DataType
+from flexflow_tpu.kernels.ssd_scan import (scan_tiles, ssd_chunk_scan,
+                                           ssd_chunk_scan_gated)
 from flexflow_tpu.ops.norm_ops import rms_norm
 from flexflow_tpu.ops.op_type import OperatorType
 from flexflow_tpu.ops.registry import LoweringCtx, register_op
@@ -86,27 +108,32 @@ def _mamba_infer(layer: Layer):
     return [x]
 
 
-def ssd_scan(u, dt, a, bm, cm, chunk: int):
-    """The recurrence S_t = exp(dt_t a) S_{t-1} + dt_t u_t (x) B_t, y_t =
-    S_t C_t from S_0 = 0, by chunks. u [b, L, H, P]; dt [b, L, H] f32, >= 0;
-    a [H] f32, < 0; bm, cm [b, L, N], or [b, L, G, N] where the heads read
-    them in G groups (head h those of group h // (H / G)). Returns (y [b, L,
-    H, P] f32, the state after step L [b, H, P, N] f32). Products take their
-    operands in u's dtype and accumulate in f32; decays are f32."""
+# the XLA form takes a long input through its chunks in blocks of about this
+# many tokens (lax.map over groups of rows), so that the f32 `[rows, heads,
+# chunk, chunk]` intermediates it streams through HBM stay a fraction of a
+# prefill wave's. The kernel holds them on the chip and takes the wave whole.
+MAMBA_TOKEN_BLOCK = 4096
+
+
+def _ssd_xla(u, dt, a, bm, cm, chunk: int):
+    """`ssd_scan` in plain XLA (jax.numpy), differentiable by JAX; bm, cm
+    `[b, L, G, N]`. Head-major from the start (the decay mask is born `[..,
+    H, l, s]` from a `[.., H, q]` cumulative sum: the batch of its product
+    with `u` leads and nothing is relaid), the groups folded into the batch,
+    and everything that depends on no other chunk (`C B^T`, the mask, the
+    masked product, each chunk's own contribution to the state) batched over
+    the chunks; only the `[chunks]`-long recurrence of the states is
+    sequential."""
     b, length, heads, hd = u.shape
-    n = bm.shape[-1]
-    if bm.ndim == 4:    # the same scan, a group's heads with their B and C
-        g = bm.shape[2]
-
-        def split(t):   # [b, L, H, ...] -> [b, L, G, H / G, ...]
-            return t.reshape(t.shape[:2] + (g, heads // g) + t.shape[3:])
-
-        y, state = jax.vmap(
-            lambda *group: ssd_scan(*group, chunk),
-            in_axes=(2, 2, 0, 2, 2), out_axes=(2, 1))(
-                split(u), split(dt), a.reshape(g, heads // g), bm, cm)
-        return (y.reshape(b, length, heads, hd),
-                state.reshape(b, heads, hd, n))
+    g, n = bm.shape[2:]
+    per = heads // g
+    rows = max(1, MAMBA_TOKEN_BLOCK // length)
+    if b > rows and b % rows == 0:
+        y, state = jax.lax.map(
+            lambda xs: _ssd_xla(xs[0], xs[1], a, xs[2], xs[3], chunk),
+            tuple(t.reshape((b // rows, rows) + t.shape[1:])
+                  for t in (u, dt, bm, cm)))
+        return y.reshape((b,) + y.shape[2:]), state.reshape((b,) + state.shape[2:])
     q = min(int(chunk), length)
     pad = -length % q
     if pad:     # steps with dt = 0 and u = 0: the state stays, y is unused
@@ -114,54 +141,144 @@ def ssd_scan(u, dt, a, bm, cm, chunk: int):
                          for t in (u, dt, bm, cm))
     nc = (length + pad) // q
     dot = u.dtype
+    u = u.reshape(b, nc, q, g, per, hd)
+    bm, cm = (t.reshape(b, nc, q, g, n) for t in (bm, cm))
 
-    def chunks(t):      # [b, nc * q, ...] -> [nc, b, q, ...]
-        return jnp.moveaxis(t.reshape((b, nc, q) + t.shape[2:]), 1, 0)
+    def by_position(t):     # [b, nc, G, H / G, q] -> [b, nc, q, G, H / G, 1]
+        return jnp.transpose(t, (0, 1, 4, 2, 3))[..., None]
 
-    causal = jnp.tril(jnp.ones((q, q), bool))
+    dt = jnp.transpose(dt.reshape(b, nc, q, g, per), (0, 1, 3, 4, 2))
+    cs = jnp.cumsum(dt * a.reshape(g, per, 1), axis=-1)     # [b, nc, G, H/G, q]
+    seg = cs[..., :, None] - cs[..., None, :]               # [.., l, s]
+    decay = jnp.exp(jnp.where(jnp.tril(jnp.ones((q, q), bool)), seg, -jnp.inf))
+    cb = jnp.einsum("bclgn,bcsgn->bcgls", cm, bm,
+                    preferred_element_type=jnp.float32)
+    m = cb[:, :, :, None] * decay * dt[..., None, :]        # [b, nc, G, H/G, l, s]
+    y = jnp.einsum("bcghls,bcsghp->bclghp", m.astype(dot), u,
+                   preferred_element_type=jnp.float32)
+    w = jnp.exp(cs[..., -1:] - cs) * dt                     # [b, nc, G, H/G, q]
+    own = jnp.einsum("bcsghp,bcsgn->bcghpn", (by_position(w) * u).astype(dot),
+                     bm, preferred_element_type=jnp.float32)
 
-    def body(state, xs):
-        u_c, dt_c, b_c, c_c = xs
-        cs = jnp.cumsum(dt_c * a, axis=1)                       # [b, q, H]
-        seg = cs[:, :, None, :] - cs[:, None, :, :]             # [b, l, s, H]
-        decay = jnp.exp(jnp.where(causal[None, :, :, None], seg, -jnp.inf))
-        cb = jnp.einsum("bln,bsn->bls", c_c, b_c,
-                        preferred_element_type=jnp.float32)
-        m = cb[..., None] * decay * dt_c[:, None, :, :]         # [b, l, s, H]
-        y = jnp.einsum("blsh,bshp->blhp", m.astype(dot), u_c,
-                       preferred_element_type=jnp.float32)
-        y = y + jnp.einsum("bln,bhpn->blhp", c_c.astype(jnp.float32), state) \
-            * jnp.exp(cs)[..., None]
-        w = jnp.exp(cs[:, -1:, :] - cs) * dt_c                  # [b, q, H]
-        state = state * jnp.exp(cs[:, -1, :])[:, :, None, None] + jnp.einsum(
-            "bshp,bsn->bhpn", (w[..., None] * u_c).astype(dot), b_c,
-            preferred_element_type=jnp.float32)
-        return state, y
+    def carry(state, xs):   # -> the state after the chunk; emits the one before
+        own_c, decay_c = xs
+        return state * decay_c[..., None, None] + own_c, state
 
-    state0 = jnp.zeros((b, heads, hd, n), jnp.float32)
-    state, ys = jax.lax.scan(body, state0,
-                             (chunks(u), chunks(dt), chunks(bm), chunks(cm)))
-    y = jnp.moveaxis(ys, 0, 1).reshape(b, nc * q, heads, hd)
-    return y[:, :length], state
-
-
-# rows of a long input go through the conv, the scan and the gate in blocks
-# of about this many tokens (lax.map over groups of rows), so that their f32
-# intermediates stay a fraction of a prefill wave's; the two projections
-# stay whole
-MAMBA_TOKEN_BLOCK = 4096
+    state, before = jax.lax.scan(
+        carry, jnp.zeros((b, g, per, hd, n), jnp.float32),
+        (jnp.moveaxis(own, 1, 0), jnp.moveaxis(jnp.exp(cs[..., -1]), 1, 0)))
+    y = y + jnp.einsum("bclgn,cbghpn->bclghp", cm.astype(jnp.float32), before) \
+        * by_position(jnp.exp(cs))
+    return (y.reshape(b, nc * q, heads, hd)[:, :length],
+            state.reshape(b, heads, hd, n))
 
 
-def _gated(y, z, weights, p, dt):
-    """RMS(y * silu(z); w_norm), in the compute type; over each of the
-    layer's groups apart where it has more than one."""
+def _mixer_xla(u, z, dt, a, bm, cm, d_skip, norm, chunk, eps, z_column):
+    """`mixer_scan` in plain XLA: the scan, the skip `y + D u`, the gate and
+    the norm; z is columns `z_column` on of the array handed in."""
+    b, s, heads, hd = u.shape
+    d_inner = heads * hd
+    y, state = _ssd_xla(u, dt, a, bm, cm, chunk)
+    y = y.reshape(b, s, d_inner) + jnp.repeat(d_skip, hd) \
+        * u.reshape(b, s, d_inner).astype(jnp.float32)
+    return _gated(y, z[..., z_column:z_column + d_inner], norm, bm.shape[2],
+                  eps, u.dtype), state
+
+
+# The kernel forward, the XLA form's gradient (recomputed: no cell trains a
+# Mamba layer yet, so the backward's speed is nobody's). Static: the chunk,
+# the tile `(q, hs)` and, gated, eps and z's first column.
+def _ssd_forward(u, dt, a, bm, cm, chunk, tiles):
+    return ssd_chunk_scan(u, dt, a, bm, cm, *tiles)
+
+
+def _ssd_backward(chunk, tiles, operands, g):
+    return jax.vjp(lambda *t: _ssd_xla(*t, chunk), *operands)[1](g)
+
+
+_ssd_kernel = jax.custom_vjp(_ssd_forward, nondiff_argnums=(5, 6))
+_ssd_kernel.defvjp(lambda *args: (_ssd_forward(*args), args[:5]), _ssd_backward)
+
+
+def _mixer_forward(u, z, dt, a, bm, cm, d_skip, norm, chunk, eps, z_column,
+                   tiles):
+    return ssd_chunk_scan_gated(u, dt, a, bm, cm, z, d_skip, norm, *tiles,
+                                eps=eps, z_column=z_column)
+
+
+def _mixer_backward(chunk, eps, z_column, tiles, operands, g):
+    return jax.vjp(lambda *t: _mixer_xla(*t, chunk, eps, z_column),
+                   *operands)[1](g)
+
+
+_mixer_kernel = jax.custom_vjp(_mixer_forward, nondiff_argnums=(8, 9, 10, 11))
+_mixer_kernel.defvjp(lambda *args: (_mixer_forward(*args), args[:8]),
+                     _mixer_backward)
+
+
+def _tiles(u, bm, chunk):
+    _b, length, heads, hd = u.shape
+    groups, n = bm.shape[2:]
+    return scan_tiles(length, heads, hd, n, groups, u.dtype.itemsize, chunk)
+
+
+def scan_path(u, bm, chunk: int) -> dict:
+    """Which form the scan of u `[b, L, H, P]` and B `[b, L, G, N]` (arrays
+    or their shapes and types) takes: `{"path": "kernel", "tile": q,
+    "head_block": hs}` or `{"path": "xla", "tile": chunk}`."""
+    tiles = _tiles(u, bm, chunk)
+    if tiles is None:
+        return {"path": "xla", "tile": min(int(chunk), u.shape[1])}
+    return {"path": "kernel", "tile": tiles[0], "head_block": tiles[1]}
+
+
+def ssd_scan(u, dt, a, bm, cm, chunk: int):
+    """The recurrence S_t = exp(dt_t a) S_{t-1} + dt_t u_t (x) B_t, y_t =
+    S_t C_t from S_0 = 0, by chunks. u [b, L, H, P]; dt [b, L, H] f32, >= 0;
+    a [H] f32, < 0; bm, cm [b, L, N], or [b, L, G, N] where the heads read
+    them in G groups (head h those of group h // (H / G)). Returns (y [b, L,
+    H, P] f32, the state after step L [b, H, P, N] f32). Products take their
+    operands in u's dtype and accumulate in f32; decays are f32.
+
+    One algorithm for every caller, the form chosen from the shapes
+    (`scan_path`): the kernel where `scan_tiles` takes them (whole lanes of
+    heads and state), the XLA form elsewhere and for the kernel's backward.
+    The result is the recurrence's at any chunk, so `chunk` bounds a tile
+    and is not mathematics."""
+    if bm.ndim == 3:
+        bm, cm = bm[:, :, None], cm[:, :, None]
+    tiles = _tiles(u, bm, chunk)
+    if tiles is None:
+        return _ssd_xla(u, dt, a, bm, cm, int(chunk))
+    return _ssd_kernel(u, dt, a, bm, cm, int(chunk), tiles)
+
+
+def mixer_scan(u, z, dt, a, bm, cm, d_skip, norm, chunk: int, eps: float,
+               z_column: int = 0):
+    """`ssd_scan`, then the skip, the gate and the norm, RMS_G((y + D u) *
+    silu(z); norm): (`[b, L, H P]` in u's type, the last state). z is read
+    from column `z_column` on of the array handed in (the whole `[z | xBC |
+    dt]` will do). The same choice of form as `ssd_scan`; the kernel does
+    all of it on the tile it holds, so no f32 `[b, L, H P]` value exists."""
+    if bm.ndim == 3:
+        bm, cm = bm[:, :, None], cm[:, :, None]
+    tiles = _tiles(u, bm, chunk)
+    if tiles is None:
+        return _mixer_xla(u, z, dt, a, bm, cm, d_skip, norm, int(chunk), eps,
+                          z_column)
+    return _mixer_kernel(u, z, dt, a, bm, cm, d_skip, norm, int(chunk),
+                         float(eps), int(z_column), tiles)
+
+
+def _gated(y, z, norm, groups: int, eps: float, dt):
+    """RMS(y * silu(z); norm), in the compute type `dt`; over each of the
+    `groups` groups apart where there is more than one."""
     g = y * jax.nn.silu(z.astype(jnp.float32))
-    groups = p.get("n_groups", 1)
     if groups == 1:
-        return rms_norm(g, weights["norm"], p.get("eps", 1e-5)).astype(dt)
+        return rms_norm(g, norm, eps).astype(dt)
     split = g.shape[:-1] + (groups, g.shape[-1] // groups)
-    return rms_norm(g.reshape(split), weights["norm"].reshape(split[-2:]),
-                    p.get("eps", 1e-5)).reshape(g.shape).astype(dt)
+    return rms_norm(g.reshape(split), norm.reshape(split[-2:]),
+                    eps).reshape(g.shape).astype(dt)
 
 
 def _b_and_c(act, p, lead):
@@ -230,33 +347,23 @@ def _mamba_lower(layer: Layer, inputs, weights, ctx: LoweringCtx):
             "ssm": ssm,
             "conv": jnp.where(valid[:, :1, None], window[:, 1:], st["conv"])}
         _report_state_bytes(ctx, valid, st)
-        g = _gated(y.reshape(b, 1, d_inner), z, weights, p, dt_)
+        g = _gated(y.reshape(b, 1, d_inner), z, weights["norm"], groups,
+                   p.get("eps", 1e-5), dt_)
         return [g @ weights["out_proj"].astype(dt_)]
 
-    def core(rows):
-        """Conv, scan, skip and gate of some rows: (gated [r, s, d_inner],
-        the state after each row's last step [r, H, P, N])."""
-        z_r, xbc_r, dt_r = rows
-        r = z_r.shape[0]
-        # causal depthwise conv: out[t] = sum_j w[j] x[t - k + 1 + j]
-        xp = jnp.pad(xbc_r, [(0, 0), (k - 1, 0), (0, 0)]).astype(jnp.float32)
-        conv = sum(xp[:, j:j + s] * conv_w[j] for j in range(k))
-        act = jax.nn.silu(conv + conv_b)
-        u = act[..., :d_inner].reshape(r, s, heads, hd).astype(dt_)
-        b_m, c_m = (t.astype(dt_) for t in _b_and_c(act, p, (r, s)))
-        y, ssm = ssd_scan(u, dt_r, a, b_m, c_m, p.get("chunk", 256))
-        y = y + d_skip[None, None, :, None] * u.astype(jnp.float32)
-        return _gated(y.reshape(r, s, d_inner), z_r, weights, p, dt_), ssm
-
-    rows = max(1, MAMBA_TOKEN_BLOCK // s)
-    if b > rows and b % rows == 0:
-        def blocks(t):
-            return t.reshape((b // rows, rows) + t.shape[1:])
-
-        g, ssm = jax.lax.map(core, (blocks(z), blocks(xbc), blocks(dt)))
-        g, ssm = g.reshape(b, s, d_inner), ssm.reshape((b,) + ssm.shape[2:])
-    else:
-        g, ssm = core((z, xbc, dt))
+    # causal depthwise conv: out[t] = sum_j w[j] x[t - k + 1 + j]
+    xp = jnp.pad(xbc, [(0, 0), (k - 1, 0), (0, 0)])
+    conv = sum(xp[:, j:j + s].astype(jnp.float32) * conv_w[j] for j in range(k))
+    act = jax.nn.silu(conv + conv_b)
+    u = act[..., :d_inner].reshape(b, s, heads, hd).astype(dt_)
+    b_m, c_m = (t.astype(dt_).reshape(b, s, groups, n)
+                for t in _b_and_c(act, p, (b, s)))
+    chunk = p.get("chunk", 256)
+    # one span a lowered layer (trace time): the form its scan took
+    with tel.span("ssm/scan_path", cat="compile", layer=layer.name,
+                  **scan_path(u, b_m, chunk)):
+        g, ssm = mixer_scan(u, zxbcdt, dt, a, b_m, c_m, d_skip,
+                            weights["norm"], chunk, p.get("eps", 1e-5))
     if p.get("mode") == "state_out":
         # the conv tail: each row's last k-1 REAL xBC rows (zeros before the
         # sequence's start); `valid` is a right-padded prefix of ones
@@ -277,8 +384,11 @@ def _mamba_lower(layer: Layer, inputs, weights, ctx: LoweringCtx):
 
 def _mamba_flops(layer: Layer):
     """Forward: the two projections, and the recurrence's own products (the
-    state update and the read-out, 2 * 2 * P * N a head and token; the
-    chunked form does more, which is not counted)."""
+    state update and the read-out, 2 * 2 * P * N a head and token). What
+    the chunked form multiplies besides, in either of its forms (the masked
+    product, 2 * tile * P a head and token, as much again at tile 256 and P
+    64; `C B^T`, 2 * tile * N a group and token), is not counted: it is the
+    algorithm's price, not the layer's need."""
     x = layer.inputs[0].spec
     heads, hd, n, d_inner, conv_dim = _sizes(layer.params)
     tokens = x.num_elements // x.shape[-1]

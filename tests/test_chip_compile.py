@@ -31,7 +31,7 @@ from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)  # chip_smoke
 
-KERNEL_MODULES = ("flash_attention", "fused_ce", "dequant_attention")
+KERNEL_MODULES = ("flash_attention", "fused_ce", "dequant_attention", "ssd_scan")
 
 # GPT-2 medium: batch 8, 16 heads of 64, seq 1024, vocab padded to 50304
 B, H, S, D = 8, 16, 1024, 64
@@ -130,6 +130,37 @@ def test_flash_attention_backward(one_chip, mosaic):
                         one_chip, qkv, qkv, qkv)
     # forward (residuals) + dq + dkv
     assert compiled.as_text().count("tpu_custom_call") >= 3
+
+
+@pytest.mark.parametrize("groups, chunk", [(1, 256), (8, 128)],
+                         ids=["granite", "nemotron"])
+def test_mamba_scan_at_the_served_widths(one_chip, mosaic, groups, chunk):
+    """`ff_ssd_chunk_scan` as the two hybrid cells' prefill waves call it
+    (16 rows of 1024, 128 heads of 64, a state of 128; one B/C group at
+    chunk 256, eight at 128), the gated entry reading z out of the whole `[z
+    | xBC | dt]`, and the plain scan: 16 heads a grid step, a group's state
+    resident over its tiles (8192 lanes of it in f32 for one group, in
+    eight sub-blocks), has to pass Mosaic and its VMEM, not only interpret
+    mode, and nothing the size of the wave in f32 may be left around the
+    gated kernel."""
+    from flexflow_tpu.ops import ssm_ops
+
+    rows, seq, heads, hd, n = 16, 1024, 128, 64, 128
+    width = 2 * heads * hd + 2 * groups * n + heads
+    f32 = jnp.float32
+    scan = [((rows, seq, heads, hd), jnp.bfloat16), ((rows, seq, heads), f32),
+            ((heads,), f32), ((rows, seq, groups, n), jnp.bfloat16),
+            ((rows, seq, groups, n), jnp.bfloat16)]
+    assert ssm_ops.scan_path(*(jax.ShapeDtypeStruct(*scan[i]) for i in (0, 3)),
+                             chunk) \
+        == {"path": "kernel", "tile": chunk, "head_block": 16}
+    _compile(lambda *t: ssm_ops.ssd_scan(*t, chunk), one_chip, *scan)
+    u, dt, a, bm, cm = scan
+    gated = _compile(
+        lambda u, z, *t: ssm_ops.mixer_scan(u, z, *t, chunk, 1e-5), one_chip,
+        u, ((rows, seq, width), jnp.bfloat16), dt, a, bm, cm,
+        ((heads,), f32), ((heads * hd,), jnp.bfloat16))
+    assert gated.memory_analysis().temp_size_in_bytes < rows * seq * heads * hd * 4
 
 
 @pytest.mark.parametrize("bq,bk", [(256, 256), (256, 128), (128, 256)])

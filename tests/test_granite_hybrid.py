@@ -157,47 +157,125 @@ def test_fit_first_loss_and_gradients_against_the_reference():
 # --------------------------------------------------------------------- scan
 def recurrence(u, dt, a, bm, cm):
     """S_t = exp(dt_t a) S_{t-1} + dt_t u_t (x) B_t; y_t = S_t C_t, one
-    position at a time, in float64."""
+    position at a time, in float64; bm, cm [b, L, N], or [b, L, G, N] with
+    head h reading group h // (H / G)."""
     b, length, heads, hd = u.shape
+    if bm.ndim == 3:
+        bm, cm = bm[:, :, None], cm[:, :, None]
+    bm, cm = (np.repeat(t, heads // t.shape[2], axis=2) for t in (bm, cm))
     state = np.zeros((b, heads, hd, bm.shape[-1]))
     ys = []
     for t in range(length):
         state = state * np.exp(dt[:, t] * a)[:, :, None, None] \
-            + (dt[:, t, :, None] * u[:, t])[..., None] * bm[:, t, None, None, :]
-        ys.append(np.einsum("bhpn,bn->bhp", state, cm[:, t]))
+            + (dt[:, t, :, None] * u[:, t])[..., None] * bm[:, t, :, None, :]
+        ys.append(np.einsum("bhpn,bhn->bhp", state, cm[:, t]))
     return np.stack(ys, axis=1), state
 
 
-@pytest.mark.parametrize("length", [32, 16, 37, 5])
-def test_scan_by_chunks_against_the_literal_recurrence(length):
-    """Lengths that are, and are not, multiples of the chunk (16), and one
-    shorter than a chunk; some steps masked (dt = 0)."""
+def recurrence_in_jax(u, dt, a, bm, cm):
+    """`recurrence` as a `lax.scan`, in the arrays' own type, for its
+    gradient."""
+    heads = u.shape[2]
+    if bm.ndim == 3:
+        bm, cm = bm[:, :, None], cm[:, :, None]
+    bm, cm = (jnp.repeat(t, heads // t.shape[2], axis=2) for t in (bm, cm))
+
+    def step(state, xs):
+        u_t, dt_t, b_t, c_t = xs
+        state = state * jnp.exp(dt_t * a)[:, :, None, None] \
+            + (dt_t[..., None] * u_t)[..., None] * b_t[:, :, None, :]
+        return state, jnp.einsum("bhpn,bhn->bhp", state, c_t)
+
+    state, ys = jax.lax.scan(
+        step, jnp.zeros(u.shape[:1] + u.shape[2:] + bm.shape[-1:], u.dtype),
+        tuple(jnp.moveaxis(t, 1, 0) for t in (u, dt, bm, cm)))
+    return jnp.moveaxis(ys, 0, 1), state
+
+
+# (length, heads, head_dim, d_state, groups, chunk, operands' type, path):
+# the tiny geometry of this file's model, which the XLA form takes, at
+# lengths that are, and are not, multiples of the chunk, and one shorter than
+# a chunk; and the served cells' tile shape in small (whole lanes of heads
+# and state, P 64, N 128: the kernel, interpreted here) at tile 128 and 256,
+# one group and eight, a length no tile divides, a group of more heads than a
+# grid step computes (32: two sub-blocks of 16), bf16 operands
+SCANS = {
+    "32": (32, 3, 4, 5, 1, 16, np.float32, "xla"),
+    "16": (16, 3, 4, 5, 1, 16, np.float32, "xla"),
+    "37": (37, 3, 4, 5, 1, 16, np.float32, "xla"),
+    "5": (5, 3, 4, 5, 1, 16, np.float32, "xla"),
+    "tile128": (256, 4, 64, 128, 1, 128, np.float32, "kernel"),
+    "tile256_ragged": (300, 4, 64, 128, 1, 256, np.float32, "kernel"),
+    "tile128_groups8_ragged": (150, 16, 64, 128, 8, 128, np.float32, "kernel"),
+    "tile128_two_sub_blocks": (256, 32, 64, 128, 1, 128, np.float32, "kernel"),
+    "tile256_bf16": (256, 4, 64, 128, 1, 256, jnp.bfloat16, "kernel"),
+    "tile128_groups8_bf16": (128, 16, 64, 128, 8, 128, jnp.bfloat16, "kernel"),
+}
+# bf16 operands: the masked matrix and the weighted inputs are rounded to 8
+# bits before their products (2^-9 an element, sums of a few hundred): 1e-2
+# of the result's scale holds them and no fault (a wrong mask is off by 1)
+RTOL_BF16 = 1e-2
+
+
+@pytest.mark.parametrize("case", list(SCANS))
+def test_scan_by_chunks_against_the_literal_recurrence(case):
+    """`y`, the last state, the state of a row that ends early (dt = 0
+    after its last real step) and, where the operands are f32, the gradient
+    of every operand, against the literal recurrence in float64."""
+    length, heads, hd, n, groups, chunk, dtype, path = SCANS[case]
     rng = np.random.default_rng(length)
-    b, heads, hd, n = 2, 3, 4, 5
+    b = 2
+    lead = (b, length) + ((groups,) if groups > 1 else ())
     u = rng.normal(size=(b, length, heads, hd)).astype(np.float32)
     dt = rng.uniform(0.01, 0.5, (b, length, heads)).astype(np.float32)
     dt[1, length // 2:] = 0.0       # a row that ends early
     a = -rng.uniform(1.0, 8.0, heads).astype(np.float32)
-    bm = rng.normal(size=(b, length, n)).astype(np.float32)
-    cm = rng.normal(size=(b, length, n)).astype(np.float32)
-    y, state = ssm_ops.ssd_scan(jnp.asarray(u), jnp.asarray(dt),
-                                jnp.asarray(a), jnp.asarray(bm),
-                                jnp.asarray(cm), chunk=16)
-    want_y, want_state = recurrence(*(t.astype(np.float64)
-                                      for t in (u, dt, a, bm, cm)))
-    assert close(y, want_y) and close(state, want_state)
+    bm = rng.normal(size=lead + (n,)).astype(np.float32)
+    cm = rng.normal(size=lead + (n,)).astype(np.float32)
+    u, bm, cm = (np.asarray(jnp.asarray(t, dtype), np.float32) for t in (u, bm, cm))
+    operands = (jnp.asarray(u, dtype), jnp.asarray(dt), jnp.asarray(a),
+                jnp.asarray(bm, dtype), jnp.asarray(cm, dtype))
+    assert ssm_ops.scan_path(operands[0], operands[3].reshape(b, length, groups, n),
+                             chunk)["path"] == path
+    y, state = ssm_ops.ssd_scan(*operands, chunk=chunk)
+    assert y.dtype == state.dtype == jnp.float32
+    f64 = [t.astype(np.float64) for t in (u, dt, a, bm, cm)]
+    want_y, want_state = recurrence(*f64)
+    rtol = RTOL if dtype == np.float32 else RTOL_BF16
+    assert close(y, want_y, rtol) and close(state, want_state, rtol)
     # the masked row's state is the state after its last real step
     cut = length // 2
-    _, half = recurrence(*(t[1:, :cut].astype(np.float64) for t in (u, dt)),
-                         a.astype(np.float64),
-                         *(t[1:, :cut].astype(np.float64) for t in (bm, cm)))
-    assert close(state[1:], half)
+    _, half = recurrence(*(t[1:, :cut] for t in f64[:2]), f64[2],
+                         *(t[1:, :cut] for t in f64[3:]))
+    assert close(state[1:], half, rtol)
+    if dtype != np.float32:
+        return
+    # the gradient (the kernel's is the XLA form's, by its custom_vjp)
+    gy = rng.normal(size=y.shape).astype(np.float32)
+    gs = rng.normal(size=state.shape).astype(np.float32)
+
+    def loss(scan, gy, gs):
+        def of(*operands):
+            y, state = scan(*operands)
+            return (y * gy).sum() + (state * gs).sum()
+        return of
+
+    got = jax.jit(jax.grad(
+        loss(lambda *t: ssm_ops.ssd_scan(*t, chunk=chunk), gy, gs),
+        argnums=(0, 1, 2, 3, 4)))(*operands)
+    with jax.enable_x64(True):
+        want = jax.jit(jax.grad(
+            loss(recurrence_in_jax, gy.astype(np.float64), gs.astype(np.float64)),
+            argnums=(0, 1, 2, 3, 4)))(*(jnp.asarray(t) for t in f64))
+        want = [np.asarray(t) for t in want]
+    for name, g, w in zip("u dt a B C".split(), got, want):
+        assert close(g, w, 10 * RTOL), name
 
 
 def test_rows_and_tokens_in_blocks_equal_the_whole(monkeypatch):
-    """A long input goes through the mixer's core by groups of rows and
-    through the expert layer by blocks of tokens (lax.map): the same
-    result as in one piece."""
+    """A long input goes through the scan's XLA form (this size's) by groups
+    of rows and through the expert layer by blocks of tokens (lax.map): the
+    same result as in one piece."""
     g = GraniteHybridConfig.tiny(seq=32)
     ids = tokens(g, 4)
     valid = np.ones_like(ids)

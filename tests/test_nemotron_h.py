@@ -191,19 +191,58 @@ def reference_mamba(x, w, groups, heads=8, hd=8, n=8):
         return reference.mamba2(x, ref, hp)
 
 
-@pytest.mark.parametrize("groups", (1, 2, 8))
-def test_the_scan_in_groups_against_the_literal_recurrence(groups):
+# the served cells' tile shape in small: heads of 64, a state of 128, a
+# group's heads in whole lanes, so the scan, the skip, the gate and the norm
+# are the kernel's (interpreted here); 200 positions are two tiles of 128,
+# the second padded
+SERVED = dict(s=200, heads=16, hd=64, n=128, chunk=128)
+
+
+@pytest.mark.parametrize("groups, geometry", [
+    (1, {}), (2, {}), (8, {}), (1, SERVED), (8, SERVED),
+    (1, dict(SERVED, heads=32))],
+    ids=["1", "2", "8", "kernel_1", "kernel_8", "kernel_1_two_sub_blocks"])
+def test_the_scan_in_groups_against_the_literal_recurrence(groups, geometry):
     """B and C in `groups` groups, head h reading group h // (H / G), and
     the gated norm over each group apart: the chunked form (40 positions in
-    chunks of 16) against the reference's recurrence a position at a time."""
-    layer = mamba_layer(groups)
+    chunks of 16 through the XLA form; the served tile shape through the
+    kernel, a group of 16 heads a grid step, of 2, and of 32 in two
+    sub-blocks whose norm is one) against the reference's recurrence a position at a time, and
+    the gradient of the input and of every weight against the
+    reference's."""
+    layer = mamba_layer(groups, **geometry)
+    heads, hd, n = (layer.params[k] for k in ("heads", "head_dim", "d_state"))
+    b, s, d = layer.inputs[0].spec.shape
     w = mamba_weights(layer)
-    assert layer.weight_specs["in_proj"].shape == (32, 64 + 64 + 2 * groups * 8 + 8)
-    assert layer.weight_specs["conv_w"].shape == (4, 64 + 2 * groups * 8)
-    x = jnp.asarray(np.random.default_rng(1).normal(size=(2, 40, 32)), jnp.float32)
-    got = get_op_def(OperatorType.MAMBA2).lower(
-        layer, [x, jnp.ones((2, 40), jnp.int32)], w, LoweringCtx())[0]
-    assert close(got, reference_mamba(x, w, groups))
+    d_inner = heads * hd
+    assert layer.weight_specs["in_proj"].shape \
+        == (d, d_inner + d_inner + 2 * groups * n + heads)
+    assert layer.weight_specs["conv_w"].shape == (4, d_inner + 2 * groups * n)
+    x = jnp.asarray(np.random.default_rng(1).normal(size=(b, s, d)), jnp.float32)
+    op = get_op_def(OperatorType.MAMBA2)
+    tel.ring_clear()
+
+    def program(x, w):
+        return op.lower(layer, [x, jnp.ones((b, s), jnp.int32)], w,
+                        LoweringCtx())[0]
+
+    def reference_of(x, w):
+        return reference_mamba(x, w, groups, heads, hd, n)
+
+    assert close(program(x, w), reference_of(x, w))
+    path, = tel.ring_spans("ssm/scan_path")
+    assert path.args["layer"] == "mix"
+    assert path.args["path"] == ("kernel" if geometry else "xla")
+    if geometry:
+        assert (path.args["tile"], path.args["head_block"]) \
+            == (128, min(16, heads // groups))
+    g = jnp.asarray(np.random.default_rng(2).normal(size=(b, s, d)), jnp.float32)
+    got = jax.grad(lambda x, w: (program(x, w) * g).sum(), argnums=(0, 1))(x, w)
+    want = jax.grad(lambda x, w: (reference_of(x, w) * g).sum(),
+                    argnums=(0, 1))(x, w)
+    assert close(got[0], want[0], 10 * RTOL)
+    for name in w:
+        assert close(got[1][name], want[1][name], 10 * RTOL), name
 
 
 def test_a_groups_heads_read_their_own_b_and_c():
@@ -236,7 +275,7 @@ def test_the_grouped_norm_is_not_the_whole_width_norm():
     y = jnp.asarray(rng.normal(size=(2, 5, 64)) * np.repeat([0.1, 1, 3, 10], 16),
                     jnp.float32)
     z = jnp.asarray(rng.normal(size=(2, 5, 64)), jnp.float32)
-    got = np.asarray(ssm_ops._gated(y, z, w, layer.params, jnp.float32))
+    got = np.asarray(ssm_ops._gated(y, z, w["norm"], 4, 1e-5, jnp.float32))
     g = np.asarray(y, np.float64) * np.asarray(jax.nn.silu(z), np.float64)
     grouped = g.reshape(2, 5, 4, 16)
     grouped = grouped / np.sqrt((grouped ** 2).mean(-1, keepdims=True) + 1e-5)
@@ -244,44 +283,93 @@ def test_the_grouped_norm_is_not_the_whole_width_norm():
     whole = g / np.sqrt((g ** 2).mean(-1, keepdims=True) + 1e-5) \
         * np.asarray(w["norm"], np.float64)
     assert close(got, want, 1e-5) and not close(got, whole, 0.1)
-    one = np.asarray(ssm_ops._gated(y, z, w, dict(layer.params, n_groups=1),
-                                    jnp.float32))
+    one = np.asarray(ssm_ops._gated(y, z, w["norm"], 1, 1e-5, jnp.float32))
     assert close(one, whole, 1e-5)
 
 
-@pytest.mark.parametrize("groups", (1, 4))
-def test_prefill_state_then_one_step_equals_the_sequence(groups):
+@pytest.mark.parametrize("groups, geometry", [
+    (1, {}), (4, {}), (4, dict(heads=8, hd=64, n=128, chunk=128))],
+    ids=["1", "4", "kernel_4"])
+def test_prefill_state_then_one_step_equals_the_sequence(groups, geometry):
     """The prefill twin hands out each row's state after its LAST REAL token
-    (rows of unequal length), and the decode twin's one step from it gives
-    the sequence form's output at the next position; the step reports the
-    state it read and wrote for the live slots alone."""
+    (rows of unequal length: a right-padded `valid`), and the decode twin's
+    one step from it gives the sequence form's output at the next position;
+    the step reports the state it read and wrote for the live slots alone.
+    At the served tile shape the prefill twin's scan is the kernel's."""
     b, s = 3, 24
     op = get_op_def(OperatorType.MAMBA2)
-    whole = mamba_layer(groups, b=b, s=s)
+    whole = mamba_layer(groups, b=b, s=s, **geometry)
+    heads, hd, n = (whole.params[k] for k in ("heads", "head_dim", "d_state"))
     w = mamba_weights(whole)
     x = jnp.asarray(np.random.default_rng(4).normal(size=(b, s, 32)), jnp.float32)
     lengths = np.array([23, 9, 1])
     want = op.lower(whole, [x, jnp.ones((b, s), jnp.int32)], w, LoweringCtx())[0]
     valid = jnp.asarray(np.arange(s)[None] < lengths[:, None], jnp.int32)
     ctx = LoweringCtx()
-    op.lower(mamba_layer(groups, "state_out", b=b, s=s), [x, valid], w, ctx)
+    tel.ring_clear()
+    op.lower(mamba_layer(groups, "state_out", b=b, s=s, **geometry), [x, valid],
+             w, ctx)
+    assert tel.ring_spans("ssm/scan_path")[0].args["path"] \
+        == ("kernel" if geometry else "xla")
     st = ctx.new_state["mix"]
-    conv_dim = 64 + 2 * groups * 8
-    assert st["ssm"].shape == (b, 8, 8, 8) and st["conv"].shape == (b, 3, conv_dim)
+    conv_dim = heads * hd + 2 * groups * n
+    assert st["ssm"].shape == (b, heads, hd, n)
+    assert st["conv"].shape == (b, 3, conv_dim)
     assert ssm_ops._mamba_slot_state(whole) == {
-        "ssm": ((8, 8, 8), jnp.float32), "conv": ((3, conv_dim), jnp.float32)}
-    nxt = jnp.stack([x[r, n] for r, n in enumerate(lengths)])[:, None]
+        "ssm": ((heads, hd, n), jnp.float32), "conv": ((3, conv_dim), jnp.float32)}
+    nxt = jnp.stack([x[r, n_] for r, n_ in enumerate(lengths)])[:, None]
     live = jnp.asarray([[1], [1], [0]], jnp.int32)
     dctx = LoweringCtx(state={"mix": st}, stats={})
-    got = op.lower(mamba_layer(groups, "decode", b=b, s=1), [nxt, live], w,
-                   dctx)[0]
-    for r, n in enumerate(lengths[:2]):
-        assert close(got[r, 0], want[r, n])
+    got = op.lower(mamba_layer(groups, "decode", b=b, s=1, **geometry),
+                   [nxt, live], w, dctx)[0]
+    for r, n_ in enumerate(lengths[:2]):
+        assert close(got[r, 0], want[r, n_])
     # a slot that is not live keeps its state
     assert np.array_equal(dctx.new_state["mix"]["ssm"][2], st["ssm"][2])
     assert np.array_equal(dctx.new_state["mix"]["conv"][2], st["conv"][2])
     assert float(dctx.stats["ssm_state_bytes"]) \
-        == 2 * 2 * (8 * 8 * 8 * 4 + 3 * conv_dim * 4)
+        == 2 * 2 * (heads * hd * n * 4 + 3 * conv_dim * 4)
+
+
+def test_a_served_prefill_layer_holds_no_chunk_by_chunk_value_outside_the_kernel(
+        monkeypatch):
+    """The program of a `state_out` layer at the served tile shape, lowered
+    for the TPU (the kernel is then one `tpu_custom_call` and the text
+    around it is all XLA's): no f32 value of three axes or more holds the
+    tile twice, i.e. no `[.., heads, tile, tile]` decay mask or masked
+    matrix crosses HBM. With the kernel refused the same text holds them,
+    so the check sees what it is for."""
+    from flexflow_tpu.kernels import ssd_scan as kernel
+
+    tile = 128
+    layer = mamba_layer(1, "state_out", b=2, s=3 * tile, heads=4, hd=64, n=128,
+                        chunk=tile)
+    weights = {k: jax.ShapeDtypeStruct(spec.shape, jnp.float32)
+               for k, spec in layer.weight_specs.items()}
+
+    def chunk_by_chunk():
+        def program(x, valid, w):
+            ctx = LoweringCtx()
+            out = get_op_def(OperatorType.MAMBA2).lower(layer, [x, valid], w, ctx)
+            return out[0], ctx.new_state["mix"]
+
+        text = jax.jit(program).trace(
+            jax.ShapeDtypeStruct((2, 3 * tile, 32), jnp.float32),
+            jax.ShapeDtypeStruct((2, 3 * tile), jnp.int32),
+            weights).lower(lowering_platforms=("tpu",)).as_text()
+        shapes = {tuple(int(n) for n in dims.split("x"))
+                  for dims in re.findall(r"tensor<([0-9x]+)xf32>", text)}
+        return text, sorted(shape for shape in shapes
+                            if len(shape) >= 3 and shape.count(tile) >= 2)
+
+    monkeypatch.setattr(kernel, "_interpret", lambda: False)
+    text, found = chunk_by_chunk()
+    assert "tpu_custom_call" in text and "ff_ssd_chunk_scan" in text
+    assert found == []
+    monkeypatch.setattr(ssm_ops, "scan_tiles", lambda *a: None)
+    text, found = chunk_by_chunk()
+    assert "tpu_custom_call" not in text
+    assert (2, 3, 1, 4, tile, tile) in found
 
 
 def test_mamba_refuses_groups_that_do_not_divide_the_heads():
@@ -748,7 +836,7 @@ def test_the_other_models_graphs_keep_their_fingerprints(name, want):
 
 
 @pytest.mark.parametrize("name, want", [
-    ("granite", ("f213278812e9df6161d431df", "0bda3c11ca01d9853ea14ab2")),
+    ("granite", ("51a714e712928d03a37fb7d2", "0bda3c11ca01d9853ea14ab2")),
     ("gigachat", ("36f4009cc3f4d59289b6ea26", "24ba0154583078d90c426c1c")),
     ("gpt2", ("c0d11d3ba4f3bd2442699251", "8cb59350cdb130454afef717"))])
 def test_the_other_models_serving_programs_lower_to_the_parents(name, want,
@@ -761,7 +849,13 @@ def test_the_other_models_serving_programs_lower_to_the_parents(name, want,
     out the step lowers to the parent's text. GPT-2's prefill hash is PR
     36's: its causal attention goes through the flash kernel (interpreted
     here), whose schedule under the diagonal that PR rewrote; the decode
-    step, which does not run the kernel, kept PR 33's."""
+    step, which does not run the kernel, kept PR 33's. Granite's prefill
+    hash is PR 39's, on purpose: that PR rewrote the Mamba-2 op's sequence
+    form (this size takes its XLA form: the scan batched over the chunks
+    with the heads leading, the conv and the gate over the wave whole); its
+    decode hash is still PR 33's (but for the counter above), as are
+    GigaChat's pair and GPT-2's decode step: the decode form and the other
+    models took the code they took."""
     monkeypatch.setattr(ssm_ops, "_report_state_bytes", lambda *a: None)
     build, inputs = BUILDERS[name]
     model = FFModel(FFConfig(batch_size=4, seed=3, strategy_cache=False,
