@@ -472,6 +472,51 @@ def op_scopes(name: str) -> List[Dict[str, OpScope]]:
     return [m for m in maps if m]
 
 
+def instructions_in_scope(hlo_text: str, scope: str) -> "set[str]":
+    """Names of the instructions of one compiled program's optimized HLO
+    text that run as events of their own under the `jax.named_scope`
+    `scope` (a whole segment of the name stack): an instruction whose own
+    `op_name` holds it, a fusion most of whose named body does, and what
+    the compiler made (no name stack) inside a loop or branch that does.
+    Containers themselves are left out (their bodies are counted)."""
+    comps, entry = _parse_computations(hlo_text)
+    if entry is None:
+        return set()
+
+    def named(op_name):
+        return any(seg == scope for seg in _segments(op_name))
+
+    def under(i, inherited):
+        if i.opcode == "fusion":
+            called = _called(i.rest)
+            inner = [named(b.op_name) for b in
+                     (comps.get(called[0], []) if called else []) if b.op_name]
+            if inner:
+                return 2 * sum(inner) > len(inner)
+        return named(i.op_name) if i.op_name else inherited
+
+    out, seen, todo = set(), set(), [(entry, False)]
+    while todo:
+        comp, inherited = todo.pop()
+        if comp in seen or comp not in comps:
+            continue
+        seen.add(comp)
+        for i in comps[comp]:
+            inside = under(i, inherited)
+            if i.opcode in CONTAINERS or i.opcode.endswith("-start"):
+                todo.extend((c, inside) for c in _called(i.rest))
+            elif inside and i.opcode not in _NO_EVENT:
+                out.add(i.name)
+    return out
+
+
+def instructions_under(name: str, scope: str) -> List["set[str]"]:
+    """`instructions_in_scope` of every program registered under `name` that
+    has run, one set a program (empty where the scope is not in it)."""
+    return [instructions_in_scope(p.compiled.as_text(), scope)
+            for p in _PROGRAMS.get(name, ()) if p.compiled is not None]
+
+
 def merge_scopes(maps: Sequence[Dict[str, OpScope]]) -> Dict[str, OpScope]:
     """The union of several programs' maps: a name that two of them give
     different scopes maps to an AMBIGUOUS scope, not to a guess."""

@@ -270,6 +270,23 @@ class FFModel:
              "chunk": int(chunk), "n_groups": int(n_groups), "eps": eps},
             ins, name, initializers)[0]
 
+    def kda(self, input: Tensor, heads: int, head_dim: int, d_conv: int = 4,
+            lower_bound: float = -5.0, eps: float = 1e-6,
+            valid: Optional[Tensor] = None,
+            initializers: Optional[Dict[str, Any]] = None,
+            name=None) -> Tensor:
+        """Kimi-Delta-Attention mixer over `[batch, seq, d]`: linear
+        attention over a `[head_dim, head_dim]` state a head, decayed a
+        channel and corrected by the delta rule (ops/kda_ops.py). `valid`
+        `[batch, seq]` int: which positions hold a token."""
+        ins = [input] + ([valid] if valid is not None else [])
+        return self._add_layer(
+            OperatorType.KDA,
+            {"heads": int(heads), "head_dim": int(head_dim),
+             "d_conv": int(d_conv), "lower_bound": float(lower_bound),
+             "eps": eps},
+            ins, name, initializers)[0]
+
     def softmax(self, input, axis: int = -1, name=None):
         return self._add_layer(OperatorType.SOFTMAX, {"axis": axis}, [input], name)[0]
 
@@ -436,21 +453,23 @@ class FFModel:
                                initializers)[0]
 
     def latent_attention(self, input: Tensor, positions: Tensor, heads: int,
-                         q_lora_rank: int, kv_lora_rank: int,
+                         q_lora_rank: Optional[int], kv_lora_rank: int,
                          qk_nope_head_dim: int, qk_rope_head_dim: int,
                          v_head_dim: int, eps: float = 1e-6,
                          rope_theta: float = 10000.0,
                          rope_scaling: Optional[Dict[str, Any]] = None,
                          valid: Optional[Tensor] = None, impl: str = "auto",
                          initializers: Optional[Dict[str, Any]] = None,
-                         name=None) -> Tensor:
+                         head_gate: bool = False, name=None) -> Tensor:
         """Multi-head latent attention over `[batch, seq, d]` with rotary
         `positions` `[batch, seq]` (ops/latent_attention_ops.py).
         `rope_scaling`: a YaRN dict with Hugging Face's keys (factor,
         original_max_position_embeddings, beta_fast, beta_slow, mscale,
         mscale_all_dim), or None for plain rotary frequencies. `valid`
-        `[batch, seq]` int: which positions hold a token (counters only)."""
-        params = {"heads": int(heads), "q_lora_rank": int(q_lora_rank),
+        `[batch, seq]` int: which positions hold a token (counters only).
+        `q_lora_rank` None or 0: queries from one matrix, no latent;
+        `head_gate`: a sigmoid gate a head on the attention output."""
+        params = {"heads": int(heads), "q_lora_rank": int(q_lora_rank or 0),
                   "kv_lora_rank": int(kv_lora_rank),
                   "qk_nope_head_dim": int(qk_nope_head_dim),
                   "qk_rope_head_dim": int(qk_rope_head_dim),
@@ -466,6 +485,8 @@ class FFModel:
                 rope_mscale=float(rope_scaling.get("mscale", 1)),
                 rope_mscale_all_dim=float(
                     rope_scaling.get("mscale_all_dim", 0)))
+        if head_gate:
+            params["head_gate"] = True
         ins = [input, positions] + ([valid] if valid is not None else [])
         return self._add_layer(OperatorType.LATENT_ATTENTION, params, ins,
                                name, initializers)[0]

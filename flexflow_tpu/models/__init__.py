@@ -15,6 +15,8 @@ from flexflow_tpu.models.granite_hybrid import (GraniteHybridConfig,
 from flexflow_tpu.models.deepseek_v3 import (DeepseekV3Config,
                                              build_deepseek_v3)
 from flexflow_tpu.models.nemotron_h import NemotronHConfig, build_nemotron_h
+from flexflow_tpu.models.bailing_hybrid import (BailingHybridConfig,
+                                                build_bailing_hybrid)
 from flexflow_tpu.models.bert import build_bert
 from flexflow_tpu.models.moe import build_moe_mlp
 from flexflow_tpu.models.inception import build_inception_v3
@@ -30,4 +32,5 @@ __all__ = [
     "build_granite_hybrid", "GraniteHybridConfig",
     "build_deepseek_v3", "DeepseekV3Config",
     "build_nemotron_h", "NemotronHConfig",
+    "build_bailing_hybrid", "BailingHybridConfig",
 ]

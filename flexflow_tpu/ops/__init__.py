@@ -23,6 +23,7 @@ from flexflow_tpu.ops import (  # noqa: F401
     moe_ops,
     ssm_ops,
     latent_attention_ops,
+    kda_ops,
     parallel_ops,
     fork_join,
 )

@@ -18,6 +18,13 @@ RMS(x; w) = x / sqrt(mean(x^2) + eps) * w:
 
 `scale` = (dn + dr)^-1/2 m^2, m = yarn_mscale(factor, mscale_all_dim).
 
+Two variants by the layer's params. `q_lora_rank` 0 (a model whose config
+says null): no query latent, `[q_n | q_r] = x W_q` with one `[d, H (dn +
+dr)]` matrix and no `q_norm`. `head_gate`: a sigmoid gate a head on the
+attention output, `o^j <- o^j sigmoid(x w_gate)_j` with `w_gate` `[d, H]`,
+before W_o, in every form. Without rotary scaling (`rope_factor` absent) the
+frequencies are the plain `base^(-2i/dr)` and m = 1.
+
 Three forms of one op, chosen by `params["mode"]`:
 
 - None (training, evaluation): the whole sequence, K and V decompressed
@@ -150,15 +157,20 @@ def _latent_infer(layer: Layer):
     if dr % 2:
         raise ValueError("qk_rope_head_dim must be even (rotary pairs)")
     d = x.shape[-1]
-    layer.weight_specs = {
+    queries = {
         "wq_a": TensorSpec((d, r_q), x.dtype),
         "q_norm": TensorSpec((r_q,), x.dtype),
         "wq_b": TensorSpec((r_q, heads * (dn + dr)), x.dtype),
+    } if r_q else {"wq": TensorSpec((d, heads * (dn + dr)), x.dtype)}
+    layer.weight_specs = {
+        **queries,
         "wkv_a": TensorSpec((d, r + dr), x.dtype),
         "kv_norm": TensorSpec((r,), x.dtype),
         "wkv_b": TensorSpec((r, heads * (dn + dv)), x.dtype),
         "wo": TensorSpec((heads * dv, d), x.dtype),
     }
+    if layer.params.get("head_gate"):
+        layer.weight_specs["w_gate"] = TensorSpec((d, heads), x.dtype)
     return [x]
 
 
@@ -237,8 +249,12 @@ def _latent_lower(layer: Layer, inputs, weights, ctx: LoweringCtx):
     b, s, _d = x.shape
     exists = (inputs[2] > 0) if len(inputs) > 2 else None
 
-    c_q = rms_norm(x @ weights["wq_a"].astype(dt), weights["q_norm"], eps)
-    q = (c_q @ weights["wq_b"].astype(dt)).reshape(b, s, heads, dn + dr)
+    if "wq" in weights:
+        q = x @ weights["wq"].astype(dt)
+    else:
+        c_q = rms_norm(x @ weights["wq_a"].astype(dt), weights["q_norm"], eps)
+        q = c_q @ weights["wq_b"].astype(dt)
+    q = q.reshape(b, s, heads, dn + dr)
     ckr = x @ weights["wkv_a"].astype(dt)              # [b, s, r + dr]
     c_kv = rms_norm(ckr[..., :r], weights["kv_norm"], eps)
     cos, sin = rope_tables(positions, p)               # [b, s, dr] f32
@@ -247,10 +263,18 @@ def _latent_lower(layer: Layer, inputs, weights, ctx: LoweringCtx):
     q_r = apply_rope(q[..., dn:], cos[:, :, None], sin[:, :, None])
     latent = jnp.concatenate([c_kv, k_r], axis=-1)     # what is cached
 
+    def projected(out):     # [b, s, H dv] -> the layer's output
+        if "w_gate" in weights:
+            gate = jax.nn.sigmoid(
+                (x @ weights["w_gate"].astype(dt)).astype(jnp.float32))
+            out = (out.reshape(b, s, heads, dv) * gate[..., None].astype(dt)
+                   ).reshape(b, s, heads * dv)
+        return [out @ weights["wo"].astype(dt)]
+
     mode = p.get("mode")
     if mode == "decode":
-        out = _absorbed_decode(layer, q_n, q_r, latent, exists, weights, ctx)
-        return [out @ weights["wo"].astype(dt)]
+        return projected(_absorbed_decode(layer, q_n, q_r, latent, exists,
+                                          weights, ctx))
     if mode == "latent_out":
         ctx.new_state[layer.name] = {"latent": latent}
         ctx.add_stat("latent_tokens_committed",
@@ -263,14 +287,16 @@ def _latent_lower(layer: Layer, inputs, weights, ctx: LoweringCtx):
     out = _whole_sequence_attention(
         jnp.concatenate([q_n, q_r], axis=-1), k, kv[..., dn:],
         softmax_scale(p), ctx, p.get("impl", "auto"))
-    return [out.reshape(b, s, heads * dv) @ weights["wo"].astype(dt)]
+    return projected(out.reshape(b, s, heads * dv))
 
 
 def projection_params(p, d: int) -> int:
     """The op's matrices, as multiplied with every token."""
     heads, r_q, r, dn, dr, dv = _sizes(p)
-    return d * r_q + r_q * heads * (dn + dr) + d * (r + dr) \
-        + r * heads * (dn + dv) + heads * dv * d
+    queries = d * r_q + r_q * heads * (dn + dr) if r_q \
+        else d * heads * (dn + dr)
+    return queries + d * (r + dr) + r * heads * (dn + dv) + heads * dv * d \
+        + (d * heads if p.get("head_gate") else 0)
 
 
 def _latent_flops(layer: Layer):

@@ -237,7 +237,7 @@ def _choose(scores, weights, p):
         gate = picked if sigmoid else jax.nn.softmax(picked, axis=-1)
     if p.get("norm_topk_prob"):
         gate = gate / (jnp.sum(gate, axis=-1, keepdims=True) + 1e-20)
-    if p.get("routed_scaling_factor"):
+    if p.get("routed_scaling_factor") is not None:     # 0 is a factor too
         gate = gate * float(p["routed_scaling_factor"])
     return gate, experts
 

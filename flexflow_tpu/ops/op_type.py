@@ -102,6 +102,9 @@ class OperatorType(enum.Enum):
     # multi-head latent attention (low-rank q and K/V, rotary positions on a
     # slice, a latent cache; ops/latent_attention_ops.py)
     LATENT_ATTENTION = "latent_attention"
+    # linear attention over a per-head matrix state (the gated delta rule
+    # with a decay a channel: Kimi Delta Attention; ops/kda_ops.py)
+    KDA = "kda"
     # fused compute op (reference: src/ops/fused.cc)
     FUSED = "fused"
     # inter-op placement composite (reference: nonsequence splits,
@@ -137,6 +140,7 @@ WEIGHTED_OPS = frozenset(
         OperatorType.MOE_LAYER,
         OperatorType.MAMBA2,
         OperatorType.LATENT_ATTENTION,
+        OperatorType.KDA,
         OperatorType.FORK_JOIN,
     }
 )

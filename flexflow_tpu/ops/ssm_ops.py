@@ -291,6 +291,17 @@ def _b_and_c(act, p, lead):
             act[..., d_inner + groups * n:].reshape(shape))
 
 
+def conv_tail(x, valid, k: int):
+    """Each row's last k-1 REAL rows of x `[b, s, C]` (zeros before the
+    sequence's start): what a decode step's causal conv of width k needs of
+    the prompt. `valid` `[b, s]` bool is a right-padded prefix of ones."""
+    s = x.shape[1]
+    lengths = jnp.sum(valid, axis=1).astype(jnp.int32)
+    idx = lengths[:, None] - (k - 1) + jnp.arange(k - 1)[None, :]
+    tail = jnp.take_along_axis(x, jnp.clip(idx, 0, s - 1)[..., None], axis=1)
+    return jnp.where(idx[..., None] >= 0, tail, 0)
+
+
 def _report_state_bytes(ctx: LoweringCtx, valid, st) -> None:
     """`ssm_state_bytes`: both leaves of the live slots' state, read and
     written by this step."""
@@ -365,16 +376,10 @@ def _mamba_lower(layer: Layer, inputs, weights, ctx: LoweringCtx):
         g, ssm = mixer_scan(u, zxbcdt, dt, a, b_m, c_m, d_skip,
                             weights["norm"], chunk, p.get("eps", 1e-5))
     if p.get("mode") == "state_out":
-        # the conv tail: each row's last k-1 REAL xBC rows (zeros before the
-        # sequence's start); `valid` is a right-padded prefix of ones
-        lengths = jnp.sum(valid, axis=1).astype(jnp.int32)
-        idx = lengths[:, None] - (k - 1) + jnp.arange(k - 1)[None, :]
-        tail = jnp.take_along_axis(xbc, jnp.clip(idx, 0, s - 1)[..., None],
-                                   axis=1)
-        tail = jnp.where(idx[..., None] >= 0, tail, 0)
         # the tail is taken now, with the layer's output: left to the
         # scheduler it is taken at the program's end, and every layer's xBC
         # (a quarter GB each in a prefill wave) stays live until then
+        tail = conv_tail(xbc, valid, k)
         out, tail = jax.lax.optimization_barrier(
             (g @ weights["out_proj"].astype(dt_), tail))
         ctx.new_state[layer.name] = {"ssm": ssm, "conv": tail}
@@ -409,4 +414,6 @@ def _mamba_slot_state(layer: Layer) -> dict:
 
 register_op(OperatorType.MAMBA2, _mamba_infer, _mamba_lower, _mamba_flops,
             serving_params=_mamba_serving_params, state_kind="recurrent",
-            slot_state=_mamba_slot_state)
+            slot_state=_mamba_slot_state,
+            span_facts=lambda layer: {
+                "ssm_groups": layer.params.get("n_groups", 1)})

@@ -63,6 +63,7 @@ from flexflow_tpu.config import ensure_compile_cache
 from flexflow_tpu.compiler.lowering import build_forward, constrainable
 from flexflow_tpu.core.graph import topo_order
 from flexflow_tpu.ops.op_type import OperatorType
+from flexflow_tpu.ops.registry import get_op_def
 from flexflow_tpu.parallel.default_strategy import data_parallel_strategy
 from flexflow_tpu.parallel.machine import MachineSpec, build_mesh
 from flexflow_tpu.search import cost_model as cm
@@ -227,8 +228,11 @@ def compile_serving(model, max_batch_slots: Optional[int] = None,
                 expert_layers=len(expert_layers),
                 experts_latent_dim=p.get("latent_size", 0))
         if recurrent:
-            compile_span.set(ssm_groups=dec_model.get_layer_by_name(
-                next(iter(recurrent))).params.get("n_groups", 1))
+            # what the first recurrent layer's op says of its state's layout
+            first = dec_model.get_layer_by_name(next(iter(recurrent)))
+            facts = get_op_def(first.op_type).span_facts
+            if facts is not None:
+                compile_span.set(**facts(first))
         # tiered KV (--kv-host-pages H > 0): host pages SUBSTITUTE device
         # pages — the HBM pool shrinks to slots*pages_per_slot - H (floored
         # at one slot's worth, the minimum a decoding slot must keep hot),

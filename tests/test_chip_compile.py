@@ -656,6 +656,54 @@ def test_nemotron_serving_programs_fit_one_chip(described_devices, mosaic,
     _assert_appends_in_place(decode, eng)
 
 
+def test_ling_serving_programs_fit_one_chip(described_devices, mosaic,
+                                            one_chip, monkeypatch):
+    """`Ling-3.0-flash.serve-chat`'s two programs at the cell's own sizes
+    (16 slots, width 1024, 10.47 GB of bf16 weights; of 7 layers 6 keep a
+    `[32, 128, 128]` f32 matrix state and a convolution tail a slot, 12.6 MB
+    a slot, and 1 pages latents `[1281, 16, 640]`), through the normal entry
+    points: the chip's compiler must hold the prefill wave beside the
+    weights, the state and the cache, the six chunked delta-rule scans must
+    compile (no Mosaic kernel: plain XLA under `ff_kda_chunk_scan`), and the
+    decode step appends to the one latent pool it was handed."""
+    eng, g, params, state = _described_engine(
+        "Ling-3.0-flash.serve-chat", described_devices, monkeypatch, one_chip)
+    slots = eng.slots
+    spec = eng.kv_spec
+    assert eng.kv.state_kinds == "paged_latent+recurrent"
+    assert (spec.latent_dim, spec.heads, spec.layers) == (576, 0, 1)
+    assert spec.state_bytes_per_slot == 6 * (32 * 128 * 128 * 4
+                                             + 3 * 12288 * 2)
+    pool = eng.kv.state[eng.attn_layers[0]]["latent"]
+    assert pool.shape == (slots * 80 + 1, 16, 640)
+    held = sum(x.size * x.dtype.itemsize
+               for x in jax.tree_util.tree_leaves(state))
+    assert 0.22e9 < held < 0.24e9
+    three = [_i32(one_chip, slots, 1)] * 3
+    decode = eng._decode_jit.lower(params, state, three).compile()
+    wave = [_i32(one_chip, slots, g.seq)] * 3
+    prefill = eng._prefill_first_tokens_jit.lower(
+        params, wave, _i32(one_chip, slots)).compile()
+    chip = 15.75e9          # what the compiler has of a v5e chip's 16 GB
+    for program, beside in ((decode, 0), (prefill, held)):
+        m = program.memory_analysis()
+        assert 10.4e9 < m.argument_size_in_bytes
+        need = (m.argument_size_in_bytes + m.output_size_in_bytes
+                + m.temp_size_in_bytes + beside)
+        assert need < 0.95 * chip, (need, m)
+    # the wave's temporaries: 3.37 GB when this was written (the expert
+    # layers' row buffers, as GigaChat's 3.27; the scans go through their
+    # chunks 2048 tokens at a time), the step's 24 MB
+    assert prefill.memory_analysis().temp_size_in_bytes < 3.6e9
+    assert decode.memory_analysis().temp_size_in_bytes < 0.1e9
+    text = prefill.as_text()
+    assert "ragged-dot" in text and "ff_kda_chunk_scan" in text
+    assert len(re.findall(r" conditional\(", text)) >= 6
+    text = decode.as_text()
+    assert "ragged-dot" in text
+    _assert_appends_in_place(decode, eng)
+
+
 def _entry_ops(text):
     """(op name, result type) of every instruction of optimized HLO's
     entry computation."""

@@ -247,6 +247,12 @@ def print_request_timeline(tl: Dict[str, Any]) -> None:
         print(f"  terminal {term.get('event', '?')}: {rec}")
 
 
+# what the recurrent layers' decode twins report of the state they moved
+# (ops/ssm_ops.py, ops/kda_ops.py)
+STATE_COUNTERS = {"ssm_state_bytes": "state-space",
+                  "linear_state_bytes": "linear-attention"}
+
+
 def expert_layer_lines(events: List[Dict[str, Any]]) -> List[str]:
     """One line per serving span that carries the expert layers' counters
     (serve/prefill/device_wait: the waves; serve/decode/window_sync: the
@@ -261,7 +267,7 @@ def expert_layer_lines(events: List[Dict[str, Any]]) -> List[str]:
         if ev.get("ph") == "X" and "moe_routed_pairs" in args:
             into = sums.setdefault(ev["name"], {})
             for k, v in args.items():
-                if k.startswith("moe_") or k in ("ssm_state_bytes", "steps"):
+                if k.startswith("moe_") or k in STATE_COUNTERS or k == "steps":
                     into[k] = into.get(k, 0) + v
     lines = []
     for name in sorted(sums):
@@ -274,12 +280,13 @@ def expert_layer_lines(events: List[Dict[str, Any]]) -> List[str]:
                      f"{100.0 * a.get('moe_rows_computed', 0) / a['moe_rows_static']:.2f}% "
                      f"of {int(a['moe_rows_static'])} static")
         lines.append(line)
-        if a.get("ssm_state_bytes") and a.get("steps"):
-            lines.append(
-                f"[serve] state-space layers in {name}: "
-                f"{a['ssm_state_bytes'] / a['steps'] / 1e6:.1f} MB of "
-                f"recurrent state read and written a step, "
-                f"{a['moe_experts_hit'] / a['steps']:.1f} held experts hit")
+        for counter, kind in STATE_COUNTERS.items():
+            if a.get(counter) and a.get("steps"):
+                lines.append(
+                    f"[serve] {kind} layers in {name}: "
+                    f"{a[counter] / a['steps'] / 1e6:.1f} MB of "
+                    f"recurrent state read and written a step, "
+                    f"{a['moe_experts_hit'] / a['steps']:.1f} held experts hit")
     return lines
 
 
