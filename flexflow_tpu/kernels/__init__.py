@@ -8,6 +8,9 @@ dequant_attention — fused int8-dequant + decode attention over the
 quantized paged KV cache (serving --kv-cache-dtype int8).
 ssd_scan — the Mamba-2 chunked scan with the mixer's skip, gate and norm on
 the same tile (forward; ops/ssm_ops.py gives it the XLA form's gradient).
+kda_scan — the chunked delta-rule scan of Kimi Delta Attention with the unit
+vectors and the gated head norm on the same tile (forward; ops/kda_ops.py
+gives it the XLA form's gradient).
 """
 
 from flexflow_tpu.kernels.dequant_attention import (  # noqa: F401
@@ -20,6 +23,10 @@ from flexflow_tpu.kernels.flash_attention import (  # noqa: F401
 from flexflow_tpu.kernels.fused_ce import (  # noqa: F401
     fused_ce_supported,
     fused_cross_entropy,
+)
+from flexflow_tpu.kernels.kda_scan import (  # noqa: F401
+    kda_chunk_scan,
+    kda_chunk_scan_gated,
 )
 from flexflow_tpu.kernels.ssd_scan import (  # noqa: F401
     scan_tiles,

@@ -22,8 +22,10 @@ that its exponents stay in float32's range.
 Three forms of one op, chosen by `params["mode"]`:
 
 - None (training, evaluation): the whole sequence by chunks
-  (`kda_chunk_scan`). Equal to the recurrence at any length; a length that
-  is no multiple of the chunk is padded with steps that change nothing.
+  (`kda_mixer_scan`: `kda_chunk_scan` with the unit vectors before it and
+  the gated head norm after). Equal to the recurrence at any length; a
+  length that is no multiple of the chunk (or of the kernel's tile) is
+  padded with steps that change nothing.
 - "state_out" (serving prefill): the same, and the state is handed out in
   `ctx.new_state[layer.name] = {"state": [b, H, D, D] f32, "conv": [b,
   d_conv - 1, 3 H D]}`. Reports (ctx.add_stat) `kda_layers`, 1 a layer.
@@ -60,10 +62,19 @@ float32's smallest normal, which e^-80 times a small entry, with the chunk's
 first row as reference, is not). T comes from block forward substitution by
 doubling (exact, log2 C products, no series).
 
-Plain XLA (jax.numpy) under the named scope `ff_kda_chunk_scan`; products
-take their operands in the compute type and accumulate in float32; decays and
-the state are float32; T is solved in float32 and enters the products for W
-and U in the compute type, like their other operand. Gradients come from JAX.
+Two forms of the chunked scan under the named scope `ff_kda_chunk_scan`,
+chosen from the shapes (`scan_path`; a lowered layer reports the choice in a
+`kda/scan_path` span): the Pallas kernel `kernels/kda_scan.py` where a head is
+whole 128-lane slabs and a chunk whole sublane tiles (everything above on a
+tile in VMEM, the operands read where they lie as `[b, L, H D]`; the layer's
+entry `kda_mixer_scan` also makes q and k unit vectors and applies the gated
+head norm there, so that no `[b, L, H, D]` value exists around it), plain XLA
+(jax.numpy, `_chunk_scan`) elsewhere. In both, products take their operands in
+the compute type and accumulate in float32; decays and the state are float32;
+T is solved in float32 (in the kernel under bfloat16: products of three bf16
+passes, 2^-16) and enters the products for W and U in the compute type, like
+their other operand. Gradients: JAX's through the XLA form, which is also the
+kernel's backward (a `custom_vjp` that recomputes it).
 """
 
 from __future__ import annotations
@@ -76,8 +87,10 @@ import jax.numpy as jnp
 
 if TYPE_CHECKING:
     from flexflow_tpu.core.layer import Layer
+from flexflow_tpu import telemetry as tel
 from flexflow_tpu.core.tensor import TensorSpec
 from flexflow_tpu.dtype import DataType
+from flexflow_tpu.kernels import kda_scan
 from flexflow_tpu.ops.norm_ops import rms_norm
 from flexflow_tpu.ops.op_type import OperatorType
 from flexflow_tpu.ops.registry import LoweringCtx, register_op
@@ -91,10 +104,11 @@ MAX_EXPONENT = 80.0
 # and the most steps a chunk has however slow the decay: its `[C, C]` pairs
 MAX_CHUNK = 64
 L2_EPS = 1e-6
-# a long input goes through the scan in blocks of about this many tokens
-# (lax.map over groups of rows), so that the scan's per-chunk operands (the
-# decayed copies of q and k, the `[chunk, chunk]` pair products, W and U)
-# stay a fraction of a prefill wave's
+# the XLA form takes a long input through the scan in blocks of about this
+# many tokens (lax.map over groups of rows), so that the per-chunk operands it
+# streams through HBM (the decayed copies of q and k, the `[chunk, chunk]`
+# pair products, W and U) stay a fraction of a prefill wave's. The kernel
+# holds them on the chip and takes the wave whole.
 KDA_TOKEN_BLOCK = 2048
 
 
@@ -156,14 +170,103 @@ def _unit_lower_inverse(a):
     return inv.reshape(lead + (c, c))
 
 
+def _head_block(q, chunk: int):
+    _b, _length, heads, hd = q.shape
+    return kda_scan.heads_a_step(heads, hd, q.dtype.itemsize, chunk)
+
+
+def scan_path(q, lower_bound: float) -> dict:
+    """Which form the scan of q `[b, L, H, D]` (an array or its shape and
+    type) takes: `{"path": "kernel", "tile": positions a grid step,
+    "head_block": heads a grid step}` or `{"path": "xla", "tile": chunk}`."""
+    chunk = chunk_steps(lower_bound)
+    hs = _head_block(q, chunk)
+    if hs is None:
+        return {"path": "xla", "tile": chunk}
+    return {"path": "kernel", "tile": kda_scan.TILE, "head_block": hs}
+
+
+# The kernel forward, the XLA form's gradient (recomputed: no cell trains a
+# KDA layer, so the backward's speed is nobody's). Static: the chunk, the
+# heads of a grid step and, gated, the layer's heads and eps.
+def _scan_forward(q, k, v, g, beta, chunk, hs):
+    return kda_scan.kda_chunk_scan(q, k, v, g, beta, chunk, hs)
+
+
+def _scan_backward(chunk, hs, operands, ct):
+    return jax.vjp(lambda *t: _chunk_scan(*t, chunk), *operands)[1](ct)
+
+
+_scan_kernel = jax.custom_vjp(_scan_forward, nondiff_argnums=(5, 6))
+_scan_kernel.defvjp(lambda *args: (_scan_forward(*args), args[:5]),
+                    _scan_backward)
+
+
+def _mixer_xla(act, z, g, beta, norm, heads, chunk, eps):
+    """`kda_mixer_scan` in plain XLA: the unit vectors, the scan and the
+    gated head norm."""
+    b, s, inner = g.shape
+    hd = inner // heads
+    dt = act.dtype
+    q, k, v = _heads_of(act.astype(jnp.float32), (b, s), heads, hd)
+    o, state = _chunk_scan(q.astype(dt), k.astype(dt), v.astype(dt),
+                           g.reshape(b, s, heads, hd), beta, chunk)
+    return _gated(o, z, norm, eps, dt), state
+
+
+def _mixer_forward(act, z, g, beta, norm, heads, chunk, eps, hs):
+    return kda_scan.kda_chunk_scan_gated(act, g, beta, z, norm, heads, chunk,
+                                         hs, l2_eps=L2_EPS, eps=eps)
+
+
+def _mixer_backward(heads, chunk, eps, hs, operands, ct):
+    return jax.vjp(lambda *t: _mixer_xla(*t, heads, chunk, eps),
+                   *operands)[1](ct)
+
+
+_mixer_kernel = jax.custom_vjp(_mixer_forward, nondiff_argnums=(5, 6, 7, 8))
+_mixer_kernel.defvjp(lambda *args: (_mixer_forward(*args), args[:5]),
+                     _mixer_backward)
+
+
 def kda_chunk_scan(q, k, v, g, beta, lower_bound: float):
     """The recurrence S' = diag(e^{g_t}) S_{t-1}, S_t = S' + beta_t k_t (v_t
     - S'^T k_t)^T, o_t = S_t^T q_t from S_0 = 0, by chunks of
     `chunk_steps(lower_bound)`. q, k, v `[b, L, H, D]`; g `[b, L, H, D]` f32
     in `[lower_bound, 0]`; beta `[b, L, H]` f32. Returns (o `[b, L, H, D]`
-    f32, the state after step L `[b, H, D, D]` f32)."""
+    f32, the state after step L `[b, H, D, D]` f32).
+
+    One algorithm for every caller, the form chosen from the shapes
+    (`scan_path`): the kernel where `kda_scan.heads_a_step` takes them
+    (heads of whole 128-lane slabs), the XLA form elsewhere and for the
+    kernel's backward."""
+    chunk = chunk_steps(lower_bound)
+    hs = _head_block(q, chunk)
     with jax.named_scope(SCAN_SCOPE):
-        return _chunk_scan(q, k, v, g, beta, chunk_steps(lower_bound))
+        if hs is None:
+            return _chunk_scan(q, k, v, g, beta, chunk)
+        return _scan_kernel(q, k, v, g.astype(jnp.float32),
+                            beta.astype(jnp.float32), chunk, hs)
+
+
+def kda_mixer_scan(act, z, g, beta, norm, heads: int, lower_bound: float,
+                   eps: float):
+    """`kda_chunk_scan` with its neighbours: `act` `[b, L, 3 H D]` is `[q' |
+    k' | v']` as they leave the convolution, q = q' / |q'| / sqrt(D) and k =
+    k' / |k'| a head; z and g `[b, L, H D]`, g f32; beta `[b, L, H]` f32.
+    Returns (`RMS(o; norm) * sigmoid(z)` a head, `[b, L, H D]` in act's
+    type, the state after step L). The same choice of form as
+    `kda_chunk_scan`; the kernel does all of it on the tile it holds, so no
+    `[b, L, H, D]` value exists, in f32 or relaid."""
+    chunk = chunk_steps(lower_bound)
+    hs = kda_scan.heads_a_step(heads, g.shape[-1] // heads,
+                               act.dtype.itemsize, chunk)
+    with jax.named_scope(SCAN_SCOPE):
+        if hs is None:
+            return _mixer_xla(act, z, g, beta, norm, heads, chunk, eps)
+        return _mixer_kernel(act, z, g.astype(jnp.float32),
+                             beta.astype(jnp.float32), norm, heads, chunk,
+                             float(eps), hs)
 
 
 def _chunk_scan(q, k, v, g, beta, chunk):
@@ -261,13 +364,12 @@ def _heads_of(act, lead, heads, hd):
     return _unit(q) * hd ** -0.5, _unit(k), v
 
 
-def _gated_out(o, z, weights, p, dt):
-    """(RMS(o; norm) * sigmoid(z)) W_out with the norm over each head."""
-    heads, hd, inner = _sizes(p)
-    lead = o.shape[:-2]
-    y = rms_norm(o, weights["norm"], p.get("eps", 1e-6)) \
-        * jax.nn.sigmoid(z.astype(jnp.float32)).reshape(lead + (heads, hd))
-    return y.reshape(lead + (inner,)).astype(dt) @ weights["out_proj"].astype(dt)
+def _gated(o, z, norm, eps, dt):
+    """RMS(o; norm) * sigmoid(z) with the norm over each head: o `[*lead, H,
+    D]` f32, z `[*lead, H D]` -> `[*lead, H D]` in `dt`."""
+    y = rms_norm(o, norm, eps) \
+        * jax.nn.sigmoid(z.astype(jnp.float32)).reshape(o.shape)
+    return y.reshape(z.shape).astype(dt)
 
 
 def _kda_lower(layer: Layer, inputs, weights, ctx: LoweringCtx):
@@ -281,18 +383,26 @@ def _kda_lower(layer: Layer, inputs, weights, ctx: LoweringCtx):
     f32 = jnp.float32
     conv_w = weights["conv_w"].astype(f32)
 
-    proj = x @ weights["in_proj"].astype(dt)
-    qkv = jnp.where(valid[..., None], proj[..., :3 * inner], 0)
-    z = proj[..., 4 * inner:5 * inner]
+    decode = p.get("mode") == "decode"
+    w_in = weights["in_proj"].astype(dt)
+    # [q' | k' | v'], f, z, b
+    parts = ((0, 3 * inner), (3 * inner, 4 * inner), (4 * inner, 5 * inner),
+             (5 * inner, 5 * inner + heads))
+    if decode:      # one product: the step reads the weights once
+        proj = x @ w_in
+        pq, pf, z, pb = (proj[..., a:b] for a, b in parts)
+    else:   # one a consumer, each born in the layout its reader takes: the
+        # scan kernel reads `[b, s, H D]` row-major, and a whole
+        # in-projection that something reads so is relaid, all 5 H D + H of it
+        pq, pf, z, pb = (x @ w_in[:, a:b] for a, b in parts)
+    qkv = jnp.where(valid[..., None], pq, 0)
     rate = jnp.repeat(jnp.exp(weights["A_log"].astype(f32)), hd)
     g = p["lower_bound"] * jax.nn.sigmoid(
-        rate * (proj[..., 3 * inner:4 * inner].astype(f32)
-                + weights["dt_bias"].astype(f32)))
+        rate * (pf.astype(f32) + weights["dt_bias"].astype(f32)))
     g = jnp.where(valid[..., None], g, 0.0)                 # [b, s, H D]
-    beta = jnp.where(valid[..., None],
-                     jax.nn.sigmoid(proj[..., 5 * inner:].astype(f32)), 0.0)
+    beta = jnp.where(valid[..., None], jax.nn.sigmoid(pb.astype(f32)), 0.0)
 
-    if p.get("mode") == "decode":
+    if decode:
         if s != 1:
             raise NotImplementedError(
                 "kda decode takes one token a step (a verify pass over "
@@ -309,17 +419,20 @@ def _kda_lower(layer: Layer, inputs, weights, ctx: LoweringCtx):
             "conv": jnp.where(valid[:, :1, None], window[:, 1:], st["conv"])}
         ctx.add_stat("linear_state_bytes", jnp.sum(valid).astype(f32)
                      * (2.0 * sum(leaf[0].nbytes for leaf in st.values())))
-        return [_gated_out(o[:, None], z, weights, p, dt)]
+        return [_gated(o[:, None], z, weights["norm"], p.get("eps", 1e-6), dt)
+                @ weights["out_proj"].astype(dt)]
 
     # causal depthwise conv: out[t] = sum_j w[j] x[t - k + 1 + j]
     xp = jnp.pad(qkv, [(0, 0), (kw - 1, 0), (0, 0)])
     act = jax.nn.silu(sum(xp[:, j:j + s].astype(f32) * conv_w[j]
-                          for j in range(kw)))
-    q, k, v = _heads_of(act, (b, s), heads, hd)
-    o, state = kda_chunk_scan(q.astype(dt), k.astype(dt), v.astype(dt),
-                              g.reshape(b, s, heads, hd), beta,
-                              p["lower_bound"])
-    out = _gated_out(o, z, weights, p, dt)
+                          for j in range(kw))).astype(dt)
+    # one span a lowered layer (trace time): the form its scan took
+    with tel.span("kda/scan_path", cat="compile", layer=layer.name,
+                  **scan_path(jax.ShapeDtypeStruct((b, s, heads, hd), dt),
+                              p["lower_bound"])):
+        y, state = kda_mixer_scan(act, z, g, beta, weights["norm"], heads,
+                                  p["lower_bound"], p.get("eps", 1e-6))
+    out = y @ weights["out_proj"].astype(dt)
     if p.get("mode") == "state_out":
         # the conv tail of [q' | k' | v'], taken now, with the layer's
         # output, so that no layer's projection stays live to the program's

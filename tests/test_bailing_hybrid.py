@@ -134,7 +134,8 @@ def scan_inputs(length, regime, seed=0, b=2, heads=3, hd=16):
     """Unit keys with a common direction (as SiLU's positive mean gives
     them: `k_t . k_s` about a half, the triangular solve's hard case),
     decays by `regime`: every channel near 1, every channel near e^-5, or
-    each channel at one of the two ends and switching."""
+    each channel at one of the two ends and switching, or `lower_bound` on
+    every step and channel."""
     rng = np.random.default_rng(seed)
     shape = (b, length, heads, hd)
 
@@ -146,6 +147,7 @@ def scan_inputs(length, regime, seed=0, b=2, heads=3, hd=16):
     v = rng.normal(size=shape)
     share = {"near_one": rng.uniform(0.0, 0.01, shape),
              "near_e-5": rng.uniform(0.99, 1.0, shape),
+             "at_the_bound": np.ones(shape),
              "both_ends": (rng.uniform(size=shape) > 0.5) * 0.998 + 0.001}[regime]
     beta = rng.uniform(0.0, 1.0, shape[:3])
     return tuple(jnp.asarray(t, jnp.float32)
@@ -170,19 +172,113 @@ def recurrence(q, k, v, g, beta, store=lambda s: s, step=kda_ops.kda_step):
 
 @pytest.mark.parametrize("regime", ("near_one", "near_e-5", "both_ends"))
 @pytest.mark.parametrize("length", (64, 100, 37, 128))
-def test_the_chunked_scan_against_the_literal_recurrence(regime, length):
+@pytest.mark.parametrize("hd", (16, 128))
+def test_the_chunked_scan_against_the_literal_recurrence(regime, length, hd):
     """Values at every position and the state handed on, at lengths that
     the chunk of 16 does and does not divide (the rest is padded with steps
     that change nothing), with decays at both ends of (e^-5, 1): near 1 the
     chunk's pairs all count and the solve is dense, near e^-5 G falls by 5 a
-    step and every exponent must be formed as a bounded difference."""
-    q, k, v, g, beta = scan_inputs(length, regime)
+    step and every exponent must be formed as a bounded difference. Heads
+    of 16 take the XLA form, heads of 128 the kernel (interpreted here), in
+    f32: lengths that its tile of 128 does and does not divide."""
+    q, k, v, g, beta = scan_inputs(length, regime, b=1 if hd == 128 else 2,
+                                   heads=2 if hd == 128 else 3, hd=hd)
+    assert kda_ops.scan_path(q, -5.0)["path"] == ("kernel" if hd == 128
+                                                  else "xla")
     want_o, want_s = recurrence(q, k, v, g, beta)
     with jax.default_matmul_precision("highest"):
         got_o, got_s = jax.jit(
             lambda *t: kda_ops.kda_chunk_scan(*t, -5.0))(q, k, v, g, beta)
     assert bool(jnp.isfinite(got_o).all()) and bool(jnp.isfinite(got_s).all())
     assert off_by(got_o, want_o) < 2e-5 and off_by(got_s, want_s) < 2e-5
+
+
+@pytest.mark.parametrize("dtype, limit", [("float32", 2e-5), ("bfloat16", 2e-2)])
+@pytest.mark.parametrize("length", (256, 300))
+def test_the_kernel_over_whole_tiles_at_the_decay_bound(length, dtype, limit):
+    """`g = lower_bound` on every step and channel of whole tiles of 128
+    (eight chunks a grid step, the state carried from tile to tile; 300 is
+    padded): every exponent the kernel forms is a chunk's own, so nothing
+    leaves float32, in f32 to the recurrence's rounding and in bfloat16
+    (where the solve's products are three bf16 passes) to bfloat16's."""
+    q, k, v, g, beta = scan_inputs(length, "at_the_bound", b=1, heads=2, hd=128)
+    assert kda_ops.scan_path(q, -5.0) == {"path": "kernel", "tile": 128,
+                                          "head_block": 2}
+    want_o, want_s = recurrence(q, k, v, g, beta)
+    dt = jnp.dtype(dtype)
+    got_o, got_s = jax.jit(lambda *t: kda_ops.kda_chunk_scan(*t, -5.0))(
+        q.astype(dt), k.astype(dt), v.astype(dt), g, beta)
+    assert bool(jnp.isfinite(got_o).all()) and bool(jnp.isfinite(got_s).all())
+    assert off_by(got_o, want_o) < limit and off_by(got_s, want_s) < limit
+
+
+def test_the_kernel_at_heads_of_two_lane_slabs():
+    """The tile rule takes any head of whole 128-lane slabs: at 256 the
+    state is `[256, 256]` a head and the pair products contract two slabs."""
+    q, k, v, g, beta = scan_inputs(40, "both_ends", b=1, heads=2, hd=256)
+    assert kda_ops.scan_path(q, -5.0)["path"] == "kernel"
+    want_o, want_s = recurrence(q, k, v, g, beta)
+    with jax.default_matmul_precision("highest"):
+        got_o, got_s = kda_ops.kda_chunk_scan(q, k, v, g, beta, -5.0)
+    assert off_by(got_o, want_o) < 2e-5 and off_by(got_s, want_s) < 2e-5
+
+
+def test_the_kernel_in_bfloat16_is_as_close_as_the_xla_form():
+    """bfloat16 operands, both forms against the f32 recurrence of the same
+    rounded operands: the kernel (interpreted) is no further off than the
+    XLA form is, and the two lie closer to each other than to it."""
+    q, k, v, g, beta = scan_inputs(200, "both_ends", b=1, heads=2, hd=128)
+    q, k, v = (t.astype(jnp.bfloat16) for t in (q, k, v))
+    want_o, want_s = recurrence(*(t.astype(jnp.float32) for t in (q, k, v)),
+                                g, beta)
+    got_o, got_s = kda_ops.kda_chunk_scan(q, k, v, g, beta, -5.0)
+    xla_o, xla_s = kda_ops._chunk_scan(q, k, v, g, beta, 16)
+    assert off_by(got_o, want_o) < 1.5 * off_by(xla_o, want_o) < 2e-2
+    assert off_by(got_s, want_s) < 1.5 * off_by(xla_s, want_s) < 2e-2
+    assert off_by(got_o, xla_o) < 1e-2 and off_by(got_s, xla_s) < 1e-2
+
+
+def test_gradients_through_the_kernel_are_the_xla_forms():
+    """The kernel is forward only: its `custom_vjp` recomputes the XLA form,
+    so the gradients of a loss over both results are that form's own."""
+    q, k, v, g, beta = scan_inputs(40, "both_ends", b=1, heads=2, hd=128)
+
+    def loss(form):
+        def f(*t):
+            o, s = form(*t)
+            return jnp.sum(jnp.sin(o)) + jnp.sum(s * s)
+        return f
+
+    with jax.default_matmul_precision("highest"):
+        got = jax.grad(loss(lambda *t: kda_ops.kda_chunk_scan(*t, -5.0)),
+                       argnums=(0, 1, 2, 3, 4))(q, k, v, g, beta)
+        want = jax.grad(loss(lambda *t: kda_ops._chunk_scan(*t, 16)),
+                        argnums=(0, 1, 2, 3, 4))(q, k, v, g, beta)
+    for a, b in zip(got, want):
+        assert off_by(a, b) < 1e-5
+
+
+def test_the_scan_path_by_shape_and_its_span():
+    """The form is chosen from what the scan sees: the kernel at the served
+    wave (heads of one 128-lane slab), the XLA form at the tiny cell's
+    widths; a lowered layer says which in a `kda/scan_path` span."""
+    from flexflow_tpu import telemetry
+
+    wave = jax.ShapeDtypeStruct((16, 1024, 32, 128), jnp.bfloat16)
+    assert kda_ops.scan_path(wave, -5.0) == {"path": "kernel", "tile": 128,
+                                             "head_block": 8}
+    tiny = jax.ShapeDtypeStruct((4, 64, 4, 16), jnp.float32)
+    assert kda_ops.scan_path(tiny, -5.0) == {"path": "xla", "tile": 16}
+    # a chunk under a sublane tile of bfloat16 (lower_bound -10: 8 steps)
+    assert kda_ops.scan_path(wave, -10.0) == {"path": "xla", "tile": 8}
+    for hd, path in ((8, "xla"), (128, "kernel")):
+        layer = kda_layer(b=1, s=24, heads=2, hd=hd)
+        x = jnp.asarray(np.random.default_rng(7).normal(size=(1, 24, 32)),
+                        jnp.float32)
+        lower_kda(layer, x, kda_weights(layer), jnp.ones((1, 24), jnp.int32))
+        span = telemetry.ring_spans("kda/scan_path")[-1]
+        assert span.args["layer"] == "kda" and span.args["path"] == path
+        assert ("head_block" in span.args) == (path == "kernel")
 
 
 def test_a_form_that_multiplies_by_e_to_the_minus_g_overflows():
@@ -291,11 +387,37 @@ def test_the_layer_against_the_reference_and_its_weights():
                   layer.inputs, name="k"))
 
 
-def test_a_padded_wave_hands_out_each_rows_state_at_its_last_real_token():
+def test_the_layer_through_the_kernel_against_the_reference(monkeypatch):
+    """Heads of 128 take the kernel (interpreted here) with the unit vectors
+    in its prologue and the gated head norm in its epilogue: the layer's
+    output is the reference's, and its gradients are those of the XLA form
+    (the `custom_vjp` recomputes it)."""
+    layer = kda_layer(b=1, s=40, heads=2, hd=128)
+    w = kda_weights(layer, seed=6)
+    x = jnp.asarray(np.random.default_rng(8).normal(size=(1, 40, 32)), jnp.float32)
+    valid = jnp.ones((1, 40), jnp.int32)
+    out, _ = lower_kda(layer, x, w, valid)
+    assert close(out, reference_kda(x, w, heads=2, hd=128))
+
+    def loss(x, in_proj):
+        return jnp.sum(jnp.sin(lower_kda(layer, x, dict(w, in_proj=in_proj),
+                                         valid)[0]))
+
+    got = jax.grad(loss, argnums=(0, 1))(x, w["in_proj"])
+    # the same layer through the XLA form
+    monkeypatch.setattr(kda_ops.kda_scan, "heads_a_step", lambda *a: None)
+    want = jax.grad(loss, argnums=(0, 1))(x, w["in_proj"])
+    assert close(got[0], want[0]) and close(got[1], want[1])
+
+
+@pytest.mark.parametrize("heads, hd", [(4, 8), (2, 128)])
+def test_a_padded_wave_hands_out_each_rows_state_at_its_last_real_token(
+        heads, hd):
     """Rows of 7, 23 and 40 real tokens in one wave of 40: the state and the
     convolution tail handed out are those of each row alone at its own
-    length, and the outputs at the real positions are unchanged."""
-    layer = kda_layer("state_out", b=3)
+    length, and the outputs at the real positions are unchanged; through
+    the XLA form (heads of 8) and through the kernel (heads of 128)."""
+    layer = kda_layer("state_out", b=3, heads=heads, hd=hd)
     w = kda_weights(layer, seed=2)
     rng = np.random.default_rng(3)
     x = jnp.asarray(rng.normal(size=(3, 40, 32)), jnp.float32)
@@ -305,9 +427,10 @@ def test_a_padded_wave_hands_out_each_rows_state_at_its_last_real_token():
     stats = {}
     out, handed = lower_kda(layer, x, w, valid, stats=stats)
     assert int(stats["kda_layers"]) == 1
-    assert handed["state"].shape == (3, 4, 8, 8) and handed["conv"].shape == (3, 3, 96)
+    assert handed["state"].shape == (3, heads, hd, hd)
+    assert handed["conv"].shape == (3, 3, 3 * heads * hd)
     for row, n in enumerate(lengths):
-        alone = kda_layer("state_out", b=1, s=n)
+        alone = kda_layer("state_out", b=1, s=n, heads=heads, hd=hd)
         o1, h1 = lower_kda(alone, x[row:row + 1, :n], w,
                            jnp.ones((1, n), jnp.int32))
         assert close(out[row, :n], o1[0])
@@ -843,18 +966,24 @@ def test_flop_and_byte_functions_against_hand_counts_and_the_program():
 
 
 # -------------------------------------------------------------- attribution
-def test_instructions_under_a_named_scope_of_a_compiled_program():
+@pytest.mark.parametrize("hd", (16, 128))
+def test_instructions_under_a_named_scope_of_a_compiled_program(hd):
     """What `kda_scan_roofline` joins the device trace with: the names of
     the compiled program's instructions under `ff_kda_chunk_scan`, loop
-    bodies included, and none of the work outside the scope."""
-    q, k, v, g, beta = scan_inputs(64, "both_ends")
+    bodies included, and none of the work outside the scope; of the XLA
+    form (heads of 16) and of the kernel (heads of 128; interpreted here,
+    its grid a loop: `tests/test_chip_compile.py` finds the Mosaic call
+    under the scope)."""
+    q, k, v, g, beta = scan_inputs(64, "both_ends", b=1, hd=hd)
+    assert kda_ops.scan_path(q, -5.0)["path"] == ("kernel" if hd == 128
+                                                  else "xla")
 
     def program(q, k, v, g, beta, w):
         out, _state = kda_ops.kda_chunk_scan(q, k, v, g, beta, -5.0)
         with jax.named_scope("after"):
             return jnp.tanh(out.reshape(out.shape[:2] + (-1,)) @ w)
 
-    w = jnp.ones((48, 8), jnp.float32)
+    w = jnp.ones((3 * hd, 8), jnp.float32)
     text = jax.jit(program).lower(q, k, v, g, beta, w).compile().as_text()
     inside = attribution.instructions_in_scope(text, kda_ops.SCAN_SCOPE)
     after = attribution.instructions_in_scope(text, "after")

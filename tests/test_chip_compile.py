@@ -31,7 +31,8 @@ from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)  # chip_smoke
 
-KERNEL_MODULES = ("flash_attention", "fused_ce", "dequant_attention", "ssd_scan")
+KERNEL_MODULES = ("flash_attention", "fused_ce", "dequant_attention", "ssd_scan",
+                  "kda_scan")
 
 # GPT-2 medium: batch 8, 16 heads of 64, seq 1024, vocab padded to 50304
 B, H, S, D = 8, 16, 1024, 64
@@ -161,6 +162,37 @@ def test_mamba_scan_at_the_served_widths(one_chip, mosaic, groups, chunk):
         u, ((rows, seq, width), jnp.bfloat16), dt, a, bm, cm,
         ((heads,), f32), ((heads * hd,), jnp.bfloat16))
     assert gated.memory_analysis().temp_size_in_bytes < rows * seq * heads * hd * 4
+
+
+def test_kda_scan_at_the_served_widths(one_chip, mosaic):
+    """`ff_kda_chunk_scan` as Ling's prefill wave calls it (16 rows of 1024,
+    32 heads of 128, bfloat16; eight heads of straight-line code a grid
+    step, their `[8, 128, 128]` f32 state resident over a row's tiles), the
+    layer's entry with the unit vectors and the gated head norm on the tile
+    and the plain scan: it has to pass Mosaic and its VMEM, not only
+    interpret mode; nothing the size of the wave's `[b, L, H, D]` in f32 may
+    be left around the layer's call, and the call is an instruction of its
+    own under the scope that `kda_scan_roofline` asks for."""
+    from flexflow_tpu import attribution
+    from flexflow_tpu.ops import kda_ops
+
+    rows, seq, heads, hd = 16, 1024, 32, 128
+    bf, f32 = jnp.bfloat16, jnp.float32
+    wave = ((rows, seq, heads, hd), bf)
+    assert kda_ops.scan_path(jax.ShapeDtypeStruct(*wave), -5.0) \
+        == {"path": "kernel", "tile": 128, "head_block": 8}
+    _compile(lambda *t: kda_ops.kda_chunk_scan(*t, -5.0), one_chip,
+             wave, wave, wave, ((rows, seq, heads, hd), f32),
+             ((rows, seq, heads), f32))
+    inner = heads * hd
+    mixer = _compile(
+        lambda *t: kda_ops.kda_mixer_scan(*t, heads, -5.0, 1e-6), one_chip,
+        ((rows, seq, 3 * inner), bf), ((rows, seq, inner), bf),
+        ((rows, seq, inner), f32), ((rows, seq, heads), f32), ((hd,), bf))
+    assert mixer.memory_analysis().temp_size_in_bytes < rows * seq * inner * 4
+    inside = attribution.instructions_in_scope(mixer.as_text(),
+                                               kda_ops.SCAN_SCOPE)
+    assert any(name.startswith("ff_kda_chunk_scan") for name in inside)
 
 
 @pytest.mark.parametrize("bq,bk", [(256, 256), (256, 128), (128, 256)])
@@ -664,8 +696,11 @@ def test_ling_serving_programs_fit_one_chip(described_devices, mosaic,
     a slot, and 1 pages latents `[1281, 16, 640]`), through the normal entry
     points: the chip's compiler must hold the prefill wave beside the
     weights, the state and the cache, the six chunked delta-rule scans must
-    compile (no Mosaic kernel: plain XLA under `ff_kda_chunk_scan`), and the
-    decode step appends to the one latent pool it was handed."""
+    compile (the Mosaic kernel `ff_kda_chunk_scan`, once a layer, under the
+    scope of the same name), and the decode step appends to the one latent
+    pool it was handed."""
+    from flexflow_tpu import telemetry as tel
+
     eng, g, params, state = _described_engine(
         "Ling-3.0-flash.serve-chat", described_devices, monkeypatch, one_chip)
     slots = eng.slots
@@ -682,8 +717,12 @@ def test_ling_serving_programs_fit_one_chip(described_devices, mosaic,
     three = [_i32(one_chip, slots, 1)] * 3
     decode = eng._decode_jit.lower(params, state, three).compile()
     wave = [_i32(one_chip, slots, g.seq)] * 3
+    tel.ring_clear()
     prefill = eng._prefill_first_tokens_jit.lower(
         params, wave, _i32(one_chip, slots)).compile()
+    # every KDA layer of the wave said which form its scan took
+    assert [(s.args["path"], s.args["tile"], s.args["head_block"])
+            for s in tel.ring_spans("kda/scan_path")] == [("kernel", 128, 8)] * 6
     chip = 15.75e9          # what the compiler has of a v5e chip's 16 GB
     for program, beside in ((decode, 0), (prefill, held)):
         m = program.memory_analysis()
@@ -691,13 +730,15 @@ def test_ling_serving_programs_fit_one_chip(described_devices, mosaic,
         need = (m.argument_size_in_bytes + m.output_size_in_bytes
                 + m.temp_size_in_bytes + beside)
         assert need < 0.95 * chip, (need, m)
-    # the wave's temporaries: 3.37 GB when this was written (the expert
-    # layers' row buffers, as GigaChat's 3.27; the scans go through their
-    # chunks 2048 tokens at a time), the step's 24 MB
+    # the wave's temporaries: 2.89 GB since PR 42 (3.37 when the scans
+    # were plain XLA, 2048 tokens at a time), the step's 24 MB
     assert prefill.memory_analysis().temp_size_in_bytes < 3.6e9
     assert decode.memory_analysis().temp_size_in_bytes < 0.1e9
     text = prefill.as_text()
     assert "ragged-dot" in text and "ff_kda_chunk_scan" in text
+    assert len(re.findall(r' custom-call\([^\n]*custom_call_target='
+                          r'"tpu_custom_call"[^\n]*ff_kda_chunk_scan',
+                          text)) == 6
     assert len(re.findall(r" conditional\(", text)) >= 6
     text = decode.as_text()
     assert "ragged-dot" in text
