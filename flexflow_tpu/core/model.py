@@ -287,6 +287,26 @@ class FFModel:
              "eps": eps},
             ins, name, initializers)[0]
 
+    def power_retention(self, input: Tensor, positions: Tensor, heads: int,
+                        kv_heads: int, head_dim: int,
+                        rope_theta: float = 10000.0, eps: float = 1e-6,
+                        valid: Optional[Tensor] = None,
+                        initializers: Optional[Dict[str, Any]] = None,
+                        name=None) -> Tensor:
+        """Power-retention mixer over `[batch, seq, d]`: linear attention
+        with the kernel (q . k)^2 over a gated `[head_dim (head_dim + 1) / 2,
+        head_dim]` state a K/V head, `heads / kv_heads` query heads reading
+        each, RMS-normed and rotated q and k (ops/power_retention_ops.py).
+        `positions` `[batch, seq]` int: the rotary positions; `valid`
+        `[batch, seq]` int: which positions hold a token."""
+        ins = [input, positions] + ([valid] if valid is not None else [])
+        return self._add_layer(
+            OperatorType.POWER_RETENTION,
+            {"heads": int(heads), "kv_heads": int(kv_heads),
+             "head_dim": int(head_dim), "rope_theta": float(rope_theta),
+             "eps": eps},
+            ins, name, initializers)[0]
+
     def softmax(self, input, axis: int = -1, name=None):
         return self._add_layer(OperatorType.SOFTMAX, {"axis": axis}, [input], name)[0]
 

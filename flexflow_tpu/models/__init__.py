@@ -17,6 +17,7 @@ from flexflow_tpu.models.deepseek_v3 import (DeepseekV3Config,
 from flexflow_tpu.models.nemotron_h import NemotronHConfig, build_nemotron_h
 from flexflow_tpu.models.bailing_hybrid import (BailingHybridConfig,
                                                 build_bailing_hybrid)
+from flexflow_tpu.models.brumby import BrumbyConfig, build_brumby
 from flexflow_tpu.models.bert import build_bert
 from flexflow_tpu.models.moe import build_moe_mlp
 from flexflow_tpu.models.inception import build_inception_v3
@@ -33,4 +34,5 @@ __all__ = [
     "build_deepseek_v3", "DeepseekV3Config",
     "build_nemotron_h", "NemotronHConfig",
     "build_bailing_hybrid", "BailingHybridConfig",
+    "build_brumby", "BrumbyConfig",
 ]

@@ -24,6 +24,7 @@ from flexflow_tpu.ops import (  # noqa: F401
     ssm_ops,
     latent_attention_ops,
     kda_ops,
+    power_retention_ops,
     parallel_ops,
     fork_join,
 )

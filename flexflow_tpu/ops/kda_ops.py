@@ -439,7 +439,8 @@ def _kda_lower(layer: Layer, inputs, weights, ctx: LoweringCtx):
         # end (ssm_ops.py)
         out, tail = jax.lax.optimization_barrier(
             (out, conv_tail(qkv, valid, kw)))
-        ctx.new_state[layer.name] = {"state": state, "conv": tail}
+        ctx.hand_out_slot_state(layer.name, {"state": state, "conv": tail},
+                                valid)
         ctx.add_stat("kda_layers", jnp.asarray(1, jnp.int32))
     return [out]
 

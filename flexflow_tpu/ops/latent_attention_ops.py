@@ -71,6 +71,7 @@ from flexflow_tpu.kernels.partition import multi_device
 from flexflow_tpu.ops.norm_ops import rms_norm
 from flexflow_tpu.ops.op_type import OperatorType
 from flexflow_tpu.ops.registry import LoweringCtx, register_op
+from flexflow_tpu.ops.rotary import apply_rope, inv_freq  # noqa: F401 (apply_rope: this module's name for it too)
 
 
 def yarn_mscale(factor: float, mscale: float) -> float:
@@ -85,10 +86,10 @@ def yarn_inv_freq(dim: int, base: float, factor: float = 1.0,
     than `beta_slow` times over the original length are slowed by `factor`,
     pairs that turn more than `beta_fast` times are kept, and those between
     are blended along a linear ramp over the pair index."""
-    i = np.arange(dim // 2, dtype=np.float64)
-    f = base ** (-2.0 * i / dim)
+    f = inv_freq(dim, base)
     if factor <= 1:
         return f
+    i = np.arange(dim // 2, dtype=np.float64)
 
     def pair_turning(n):    # the (real) pair index that turns n times
         return dim * math.log(original_len / (2 * math.pi * n)) \
@@ -128,22 +129,6 @@ def rope_tables(positions, p):
         / yarn_mscale(factor, p.get("rope_mscale_all_dim") or 1.0) \
         if factor > 1 else 1.0
     return jnp.cos(angles) * m, jnp.sin(angles) * m
-
-
-def apply_rope(x, cos, sin):
-    """Rotates the pairs (2i, 2i+1) of x's last axis by their angles:
-    (a, b) -> (a cos - b sin, a sin + b cos), in f32, result in x's dtype.
-    The pairs' partners come from one product with a signed permutation
-    (exact: each output is one input, times +-1), so the interleaved axis is
-    never split into pairs, which the chip would relay."""
-    dr = x.shape[-1]
-    swap = np.zeros((dr, dr), np.float32)
-    swap[np.arange(1, dr, 2), np.arange(0, dr, 2)] = -1.0   # out[2i] = -x[2i+1]
-    swap[np.arange(0, dr, 2), np.arange(1, dr, 2)] = 1.0    # out[2i+1] = x[2i]
-    xf = x.astype(jnp.float32)
-    partner = jnp.einsum("...d,de->...e", xf, jnp.asarray(swap),
-                         precision=jax.lax.Precision.HIGHEST)
-    return (xf * cos + partner * sin).astype(x.dtype)
 
 
 def _sizes(p):

@@ -105,6 +105,9 @@ class OperatorType(enum.Enum):
     # linear attention over a per-head matrix state (the gated delta rule
     # with a decay a channel: Kimi Delta Attention; ops/kda_ops.py)
     KDA = "kda"
+    # linear attention with a degree-2 power kernel over a gated recurrent
+    # state a K/V head (power retention; ops/power_retention_ops.py)
+    POWER_RETENTION = "power_retention"
     # fused compute op (reference: src/ops/fused.cc)
     FUSED = "fused"
     # inter-op placement composite (reference: nonsequence splits,
@@ -141,6 +144,7 @@ WEIGHTED_OPS = frozenset(
         OperatorType.MAMBA2,
         OperatorType.LATENT_ATTENTION,
         OperatorType.KDA,
+        OperatorType.POWER_RETENTION,
         OperatorType.FORK_JOIN,
     }
 )

@@ -29,6 +29,13 @@ from flexflow_tpu.core.tensor import TensorSpec
 from flexflow_tpu.ops.op_type import OperatorType
 
 
+def rows_taken(fresh, old, took):
+    """`[rows, ...]` with the rows `took` `[rows]` bool names from `fresh`
+    (in `old`'s type) and the others from `old`."""
+    return jnp.where(took.reshape((-1,) + (1,) * (old.ndim - 1)),
+                     fresh.astype(old.dtype), old)
+
+
 @dataclasses.dataclass
 class LoweringCtx:
     """Per-trace context threaded through op lowerings."""
@@ -63,6 +70,23 @@ class LoweringCtx:
     def add_stat(self, name: str, value) -> None:
         if self.stats is not None:
             self.stats[name] = self.stats.get(name, 0) + value
+
+    def hand_out_slot_state(self, name: str, fresh: Dict[str, Any],
+                            valid) -> None:
+        """A recurrent layer's prefill form ("state_out") hands out the
+        state its wave left, `{leaf: [rows, ...]}`, through this. Where the
+        program was handed the layer's slot arrays (`state[name]`: the
+        serving engine does so for a state too large to exist twice), a row
+        that holds a request (`valid` `[rows, seq]` bool names a token in
+        it) goes into them and a row that sat the wave out keeps what the
+        slot had; else the fresh rows go out as they are, for the cache's
+        commit program."""
+        old = self.state.get(name)
+        if old is not None:
+            took = jnp.any(valid, axis=1)
+            fresh = {key: rows_taken(rows, old[key], took)
+                     for key, rows in fresh.items()}
+        self.new_state[name] = fresh
 
     def rng_for(self, layer: Layer) -> jax.Array:
         if self.rng is None:

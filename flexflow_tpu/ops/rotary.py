@@ -1,0 +1,59 @@
+"""Rotary position embeddings, for whichever op rotates something.
+
+Two layouts of a rotated axis of even width `dim`, `dim // 2` pairs each
+turned by its own angle `position * f_i`, `f_i = base^(-2i/dim)`:
+
+- interleaved (DeepSeek's latent attention): the pairs are (2i, 2i + 1):
+  `apply_rope`, with tables that hold each pair's angle twice in a row;
+- rotate-half (Hugging Face's Llama / Qwen layout): the pairs are (i, i +
+  dim // 2): `apply_rope_half`, with tables that hold the `dim // 2` angles
+  once and then again (`half_tables`).
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+
+def inv_freq(dim: int, base: float) -> np.ndarray:
+    """The frequencies of the `dim // 2` pairs, `[dim // 2]` float64."""
+    return base ** (-2.0 * np.arange(dim // 2, dtype=np.float64) / dim)
+
+
+def apply_rope(x, cos, sin):
+    """Rotates the pairs (2i, 2i+1) of x's last axis by their angles:
+    (a, b) -> (a cos - b sin, a sin + b cos), in f32, result in x's dtype.
+    The pairs' partners come from one product with a signed permutation
+    (exact: each output is one input, times +-1), so the interleaved axis is
+    never split into pairs, which the chip would relay."""
+    dr = x.shape[-1]
+    swap = np.zeros((dr, dr), np.float32)
+    swap[np.arange(1, dr, 2), np.arange(0, dr, 2)] = -1.0   # out[2i] = -x[2i+1]
+    swap[np.arange(0, dr, 2), np.arange(1, dr, 2)] = 1.0    # out[2i+1] = x[2i]
+    xf = x.astype(jnp.float32)
+    partner = jnp.einsum("...d,de->...e", xf, jnp.asarray(swap),
+                         precision=jax.lax.Precision.HIGHEST)
+    return (xf * cos + partner * sin).astype(x.dtype)
+
+
+def half_tables(positions, dim: int, base: float):
+    """(cos, sin) `positions.shape + [dim]` float32 for `apply_rope_half`:
+    the `dim // 2` angles, then the same again."""
+    inv = jnp.asarray(np.tile(inv_freq(dim, base), 2), jnp.float32)
+    angles = positions.astype(jnp.float32)[..., None] * inv
+    return jnp.cos(angles), jnp.sin(angles)
+
+
+def apply_rope_half(x, cos, sin):
+    """Rotates the pairs (i, i + dim // 2) of x's last axis: x cos +
+    rotate_half(x) sin with rotate_half(x) = [-x_2 | x_1], in f32, result in
+    x's dtype. The partner is the axis rolled by half its width (whole
+    lanes for a head of 128), signed."""
+    dim = x.shape[-1]
+    sign = jnp.asarray(np.where(np.arange(dim) < dim // 2, -1.0, 1.0),
+                       jnp.float32)
+    xf = x.astype(jnp.float32)
+    return (xf * cos + jnp.roll(xf, dim // 2, axis=-1) * sign * sin
+            ).astype(x.dtype)

@@ -382,7 +382,8 @@ def _mamba_lower(layer: Layer, inputs, weights, ctx: LoweringCtx):
         tail = conv_tail(xbc, valid, k)
         out, tail = jax.lax.optimization_barrier(
             (g @ weights["out_proj"].astype(dt_), tail))
-        ctx.new_state[layer.name] = {"ssm": ssm, "conv": tail}
+        ctx.hand_out_slot_state(layer.name, {"ssm": ssm, "conv": tail},
+                                valid)
         return [out]
     return [g @ weights["out_proj"].astype(dt_)]
 

@@ -63,7 +63,7 @@ from flexflow_tpu.config import ensure_compile_cache
 from flexflow_tpu.compiler.lowering import build_forward, constrainable
 from flexflow_tpu.core.graph import topo_order
 from flexflow_tpu.ops.op_type import OperatorType
-from flexflow_tpu.ops.registry import get_op_def
+from flexflow_tpu.ops.registry import STATS_KEY, get_op_def
 from flexflow_tpu.parallel.default_strategy import data_parallel_strategy
 from flexflow_tpu.parallel.machine import MachineSpec, build_mesh
 from flexflow_tpu.search import cost_model as cm
@@ -71,7 +71,7 @@ from flexflow_tpu.serving.kv_cache import (ACTIVE_KEY, POS_KEY, PagedKVCache,
                                            _tree_bytes)
 from flexflow_tpu.serving.program import (attn_head_degree, clone_for_serving,
                                           page_geometry, recurrent_layers,
-                                          serving_optimize)
+                                          serving_optimize, slot_state_bytes)
 
 log = logging.getLogger("flexflow_tpu")
 
@@ -162,7 +162,7 @@ def compile_serving(model, max_batch_slots: Optional[int] = None,
         _resolve_kv_dtype(cfg, kv_cache_dtype)
     # what a token's row holds in the pools, as the layers that page
     # declare it: the K/V heads (fewer than the query heads where they are
-    # grouped) of head_dim each, or a latent
+    # grouped) of head_dim each, or a latent; nothing where no layer pages
     geometry = page_geometry(model)
     latent = "latent_dim" in geometry
     seq = int(model.input_tensors[0].spec.shape[1])
@@ -178,9 +178,13 @@ def compile_serving(model, max_batch_slots: Optional[int] = None,
         pre_model, attn = clone_for_serving(model, "prefill", slots)
         dec_model, _ = clone_for_serving(model, "decode", slots)
         recurrent = recurrent_layers(dec_model)
-        state_bytes = sum(
-            int(np.prod(shape)) * jnp.dtype(dt).itemsize
-            for leaves in recurrent.values() for shape, dt in leaves.values())
+        if not attn and not recurrent:
+            raise ValueError("compile_serving needs a model with layers that "
+                             "carry per-request state: none pages K/V or a "
+                             "latent, none keeps a state a slot (nothing to "
+                             "cache)")
+        state_bytes = sum(slot_state_bytes(leaves)
+                          for leaves in recurrent.values())
         if recurrent:
             # what a layer with per-slot recurrent state does not support
             # yet fails here, not silently later
@@ -215,7 +219,8 @@ def compile_serving(model, max_batch_slots: Optional[int] = None,
                     + " yet")
         compile_span.set(kv_layers=len(attn), state_layers=len(recurrent),
                          state_bytes_per_slot=state_bytes,
-                         paged_state="paged_latent" if latent else "paged_kv")
+                         paged_state="paged_latent" if latent
+                         else "paged_kv" if attn else "none")
         expert_layers = [l for l in model.layers
                          if l.op_type is OperatorType.MOE_LAYER]
         if expert_layers:
@@ -303,7 +308,9 @@ def compile_serving(model, max_batch_slots: Optional[int] = None,
         # the bytes of the state leaves a decode step is told to donate
         compile_span.set(
             kv_pool_shape=list(
-                next(iter(engine.kv.state[attn[0]].values())).shape),
+                next(iter(engine.kv.state[attn[0]].values())).shape)
+            if attn else [],
+            state_in_place=engine.kv.writes_state_in_place,
             decode_state_donated_bytes=_tree_bytes(engine.kv.state))
         return engine
 
@@ -400,14 +407,22 @@ class ServingCompiled:
             head_fwd = build_forward([head], head.inputs, pre_out, mesh,
                                      prefill_strategy, **fwd_kw)
 
-        def _prefill_first_tokens(params, inputs, lengths):
+        def _prefill_first_tokens(params, inputs, lengths, slot_state=None):
             last = jnp.maximum(lengths.astype(jnp.int32) - 1, 0)
-            outs, kv_state = body_fwd(params, {}, inputs, False, rng0)
+            # with `slot_state` the wave writes its own slots: each recurrent
+            # layer is handed its slot arrays and hands back what they hold
+            # after the wave (LoweringCtx.hand_out_slot_state)
+            outs, kv_state = body_fwd(params, slot_state or {}, inputs, False,
+                                      rng0)
             rows = jnp.take_along_axis(outs[0], last[:, None, None], axis=1,
                                        mode="clip")
             if head_fwd is not None:
                 rows = head_fwd(params, {}, [rows], False, rng0)[0][0]
             tokens = jnp.argmax(rows[:, 0, :], axis=-1).astype(jnp.int32)
+            if slot_state is not None:
+                kv_state.setdefault(STATS_KEY, {})["state_written_bytes"] = \
+                    jnp.sum(lengths > 0).astype(jnp.float32) \
+                    * float(kv_spec.state_bytes_per_slot)
             return tokens, kv_state
 
         def _decode(params, state, inputs):
@@ -420,7 +435,12 @@ class ServingCompiled:
             return outs[0], ns
 
         self._prefill_jit = jax.jit(_prefill)
-        self._prefill_first_tokens_jit = jax.jit(_prefill_first_tokens)
+        # where the cache says so (`writes_state_in_place`) the program is
+        # handed the recurrent layers' slot arrays, donated, as a fourth
+        # argument; else it takes three and lowers to what it lowered to
+        self._prefill_first_tokens_jit = jax.jit(
+            _prefill_first_tokens,
+            donate_argnums=(3,) if self.kv.writes_state_in_place else ())
         # the state is donated (the step appends to the pools it was
         # handed), the params never (hot-swap: in-flight work holds them)
         self._decode_jit = jax.jit(_decode, donate_argnums=(1,))
@@ -753,8 +773,17 @@ class ServingCompiled:
         `prefill`'s: per layer that carries state, what `commit_prefill`
         takes, and under STATS_KEY the wave's counters where ops report
         any."""
-        return self._run_prefill(self._prefill_first_tokens_jit, params,
-                                 list(input_arrays), lengths)
+        if not self.kv.writes_state_in_place:
+            return self._run_prefill(self._prefill_first_tokens_jit, params,
+                                     list(input_arrays), lengths)
+        # the program writes the recurrent state into the slot arrays it is
+        # handed (donated: dead after the call); the cache adopts them at
+        # once and `commit_prefill` finds nothing fresh for those layers
+        tokens, kv_state = self._run_prefill(
+            self._prefill_first_tokens_jit, params, list(input_arrays),
+            jnp.asarray(lengths), self.kv.slot_state())
+        self.kv.state.update({n: kv_state.pop(n) for n in self.kv.recurrent})
+        return tokens, kv_state
 
     def decode_step(self, params, state, input_arrays):
         """One single-token step over all slots: returns (logits

@@ -71,6 +71,20 @@ for the slots its inputs name as live. What they do not support yet raises
 NotImplementedError: the host tier, park/spill and the hand-off, which
 would all have to move this state with the pages.
 
+A model NONE of whose layers pages anything (every layer recurrent) has no
+pools and no page accounting: `state_kinds` is "recurrent", a request needs
+0 pages, and admission is by free slots alone.
+
+A wave's recurrent state reaches its slots one of two ways, by the state's
+size (`writes_state_in_place`): the commit program `_commit_state` takes the
+prefill program's fresh `[slots, ...]` tree, gathers the old rows, selects
+and scatters (three whole copies live at once: fine for a state that is a
+few percent of the device), or, from IN_PLACE_STATE_BYTES up, the prefill
+program itself is handed the slot arrays DONATED and writes each layer's
+rows of the slots the wave prefilled into them (serving/engine.py), so that
+no second whole copy exists; `commit_prefill` then finds no fresh state and
+moves nothing.
+
 Host cold tier (--kv-host-pages > 0): causal decode streams a slot's whole
 committed working set every step, so pages cannot go cold while their slot
 decodes — the tier works at SLOT granularity. `spill` parks an active slot:
@@ -97,6 +111,13 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from flexflow_tpu import attribution
 from flexflow_tpu.search.cost_model import KVCacheSpec
+
+# a wave's fresh recurrent state (slots x the state a slot, every layer) at
+# or above this is written in place by the prefill program; under it the
+# commit program writes it (the module docstring says what each costs). A
+# sixteenth of a v5e's memory: granite's, Nemotron's and Ling's states are
+# 0.2-0.6 GB a wave, a power-retention model's 3.3
+IN_PLACE_STATE_BYTES = 1 << 30
 
 PAGE_TABLE_KEY = "serve/page_table"
 POS_KEY = "serve/pos"
@@ -337,6 +358,8 @@ class PagedKVCache:
                 if not self._active[i] and i not in self._cold]
 
     def pages_needed(self, total_tokens: int) -> int:
+        if not self.attn_layers:    # nothing pages: a slot is all it takes
+            return 0
         cap = min(int(total_tokens), self.spec.padded_len)
         return -(-cap // self.spec.page_size)
 
@@ -415,15 +438,29 @@ class PagedKVCache:
     def state_kinds(self) -> str:
         """The kinds of per-request state this cache holds, as the ops'
         `state_kind` names them (what the cache's spans say they moved)."""
-        paged = "paged_latent" if self.spec.latent_dim else "paged_kv"
-        return paged + ("+recurrent" if self.recurrent else "")
+        kinds = [] if not self.attn_layers else \
+            ["paged_latent" if self.spec.latent_dim else "paged_kv"]
+        return "+".join(kinds + (["recurrent"] if self.recurrent else []))
+
+    @property
+    def writes_state_in_place(self) -> bool:
+        """Whether the prefill program writes the recurrent state into the
+        slot arrays itself (IN_PLACE_STATE_BYTES)."""
+        return self.spec.slots * self.spec.state_bytes_per_slot \
+            >= IN_PLACE_STATE_BYTES
+
+    def slot_state(self) -> Dict:
+        """The recurrent layers' slot arrays, `{layer: {leaf: [slots,
+        ...]}}`: what a program that writes them is handed (donated: what
+        it returns takes their place in `state`)."""
+        return {n: self.state[n] for n in self.recurrent}
 
     def _kv_pages_only(self, what: str) -> None:
         if self.spec.latent_dim:
             raise NotImplementedError(
                 f"{what}: the cache holds paged_latent state "
-                f"({self.attn_layers[0]}, ...), which this path does not "
-                "move yet")
+                f"({len(self.attn_layers)} layers), which this path does "
+                "not move yet")
         if self.recurrent:
             raise NotImplementedError(
                 f"{what}: a model with recurrent layers "
@@ -632,27 +669,32 @@ class PagedKVCache:
 
         slot_ids = self._put_repl(np.asarray(slot_ids, np.int32))
         lengths = self._put_repl(np.asarray(lengths, np.int32))
-        paged = {k: v for k, v in self.state.items()
-                 if k not in self.recurrent}
-        fresh = {n: kv_state[n] for n in self.attn_layers}
-        # each commit consumes the leaves it is handed: what comes back is
-        # adopted before the next one reads `self.state`
-        with tel.span("serve/prefill/commit_kv", cat="serve",
-                      bytes=_tree_bytes(fresh), state=self.state_kinds):
-            if self._commit_kv.compiled is None:
-                self._commit_kv.first_run(paged, fresh, slot_ids, lengths)
-            self.state = {**self.state,
-                          **_commit_prefill(paged, fresh, slot_ids, lengths)}
+        if self.attn_layers:
+            paged = {k: v for k, v in self.state.items()
+                     if k not in self.recurrent}
+            fresh = {n: kv_state[n] for n in self.attn_layers}
+            # each commit consumes the leaves it is handed: what comes back
+            # is adopted before the next one reads `self.state`
+            with tel.span("serve/prefill/commit_kv", cat="serve",
+                          bytes=_tree_bytes(fresh), state=self.state_kinds):
+                if self._commit_kv.compiled is None:
+                    self._commit_kv.first_run(paged, fresh, slot_ids, lengths)
+                self.state = {**self.state,
+                              **_commit_prefill(paged, fresh, slot_ids,
+                                                lengths)}
         if self.recurrent:
-            fresh = {n: kv_state[n] for n in self.recurrent}
+            # a prefill program that wrote the slots itself hands no fresh
+            # state on: the span stays (bytes 0), nothing is dispatched
+            fresh = {n: kv_state[n] for n in self.recurrent if n in kv_state}
             with tel.span("serve/prefill/commit_state", cat="serve",
                           bytes=_tree_bytes(fresh)):
-                had = {n: self.state[n] for n in self.recurrent}
-                if self._commit_state.compiled is None:
-                    self._commit_state.first_run(had, fresh, slot_ids,
-                                                 lengths)
-                self.state.update(_commit_state(had, fresh, slot_ids,
-                                                lengths))
+                if fresh:
+                    had = {n: self.state[n] for n in fresh}
+                    if self._commit_state.compiled is None:
+                        self._commit_state.first_run(had, fresh, slot_ids,
+                                                     lengths)
+                    self.state.update(_commit_state(had, fresh, slot_ids,
+                                                    lengths))
 
     def adopt(self, new_state) -> None:
         """Take ownership of the state returned by a decode step (a pointer

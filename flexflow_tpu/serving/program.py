@@ -40,8 +40,11 @@ DP expansions.
 
 from __future__ import annotations
 
+import math
 import time
 from typing import Dict, List, Optional, Tuple
+
+import numpy as np
 
 from flexflow_tpu.core.graph import topo_order
 from flexflow_tpu.core.layer import Layer
@@ -66,16 +69,17 @@ def _serving_params(layer: Layer, kind: str) -> dict:
 def page_geometry(model) -> Dict[str, int]:
     """What a token's row holds in the paged pools of `model`'s layers, from
     the layers' own declarations (their op's `page_state`): `{"heads",
-    "head_dim"}` of K and V pools, or `{"latent_dim"}` of a latent pool. One
-    geometry a cache: layers that declare different ones raise."""
+    "head_dim"}` of K and V pools, `{"latent_dim"}` of a latent pool, or `{}`
+    where no layer pages anything (every layer that carries state keeps it
+    a slot: `recurrent_layers`). One geometry a cache: layers that declare
+    different ones raise."""
     found: Dict[str, Dict[str, int]] = {}
     for l in topo_order(model.layers):
         d = get_op_def(l.op_type)
         if d.state_kind in PAGED_STATE_KINDS:
             found[l.name] = dict(d.page_state(l))
     if not found:
-        raise ValueError("compile_serving needs a model with attention "
-                         "layers (nothing to cache)")
+        return {}
     first = next(iter(found))
     for name, geometry in found.items():
         if geometry != found[first]:
@@ -197,6 +201,20 @@ def _prefill_cost_fn(machine: MachineSpec):
     return cost
 
 
+def slot_state_bytes(leaves: Dict[str, tuple]) -> int:
+    """Bytes of one slot's recurrent state in a layer, from its leaves
+    `{leaf: (per-slot shape, dtype)}` (`recurrent_layers`' values)."""
+    return sum(math.prod(shape) * np.dtype(dt).itemsize
+               for shape, dt in leaves.values())
+
+
+def _layer_state_bytes(layer: Layer) -> int:
+    """The same of `layer` itself (0: it keeps no state a slot)."""
+    d = get_op_def(layer.op_type)
+    return slot_state_bytes(d.slot_state(layer)) \
+        if d.state_kind == "recurrent" else 0
+
+
 def _decode_cost_fn(machine: MachineSpec, kv_layer_bytes: int,
                     kv_spec: Optional["cm.KVCacheSpec"] = None,
                     prefetch_ahead: int = 1):
@@ -212,11 +230,17 @@ def _decode_cost_fn(machine: MachineSpec, kv_layer_bytes: int,
     scheduler issues it early — traffic hidden behind more decode steps
     costs less per step, which is exactly the knob --kv-prefetch-ahead
     turns. The learned cost model refits this term from the kv_transfer
-    telemetry rows like any other op."""
+    telemetry rows like any other op.
+
+    A layer with per-slot recurrent state reads and writes it every step
+    (every slot's, at worst): the cache term of a model that pages nothing
+    is this alone. It is never sharded, so it moves no ranking."""
+    slots = kv_spec.slots if kv_spec is not None else 0
 
     def cost(layer, cand):
         rf = cm.op_roofline(layer, cand, machine)
-        t = rf["t_mem_s"] / 2.0
+        t = rf["t_mem_s"] / 2.0 \
+            + 2.0 * slots * _layer_state_bytes(layer) / machine.hbm_bw
         if kv_layer_bytes and get_op_def(layer.op_type).state_kind \
                 in PAGED_STATE_KINDS:
             wq = cand.weight_dims.get("wq")

@@ -745,6 +745,68 @@ def test_ling_serving_programs_fit_one_chip(described_devices, mosaic,
     _assert_appends_in_place(decode, eng)
 
 
+def test_brumby_serving_programs_fit_one_chip(described_devices, one_chip,
+                                              monkeypatch):
+    """`Brumby-14B-Base.serve-longanswer`'s two programs at the cell's own
+    sizes (16 slots, width 1024, 7.08 GB of bf16 weights; every one of the 6
+    layers keeps an `[8, 8256, 128]` f32 state and its `[8, 8256]`
+    normaliser a slot, 34.08 MB a layer, 3.27 GB in all; nothing pages),
+    through the normal entry points: no pools and no page accounting; the
+    prefill wave is handed the slot arrays donated and writes them itself,
+    so that the chip holds arguments + temporaries (no second `[16, 6, ...]`
+    copy of the state among them: under two layers' worth), and the decode
+    step updates the state it was handed in place."""
+    from flexflow_tpu import telemetry as tel
+
+    eng, g, params, state = _described_engine(
+        "Brumby-14B-Base.serve-longanswer", described_devices, monkeypatch,
+        one_chip)
+    slots = eng.slots
+    spec = eng.kv_spec
+    assert eng.kv.state_kinds == "recurrent" and eng.attn_layers == []
+    assert (spec.layers, spec.heads, spec.latent_dim) == (0, 0, 0)
+    layer_state = 8 * 8256 * (128 + 1) * 4
+    assert spec.state_bytes_per_slot == 6 * layer_state == 6 * 34080768
+    assert eng.kv.writes_state_in_place
+    assert eng.kv.pages_needed(1024) == 0 and eng.kv.can_admit(10 ** 6)
+    held = sum(x.size * x.dtype.itemsize
+               for x in jax.tree_util.tree_leaves(state))
+    assert 3.27e9 < held < 3.28e9
+    weights = sum(x.size * x.dtype.itemsize
+                  for x in jax.tree_util.tree_leaves(params))
+    assert weights == 2 * 3537947136
+    three = [_i32(one_chip, slots, 1)] * 3
+    decode = eng._decode_jit.lower(params, state, three).compile()
+    wave = [_i32(one_chip, slots, g.seq)] * 3
+    slot_state = {n: state[n] for n in eng.kv.recurrent}
+    tel.ring_clear()
+    prefill = eng._prefill_first_tokens_jit.lower(
+        params, wave, _i32(one_chip, slots), slot_state).compile()
+    # every layer of the wave said which regime its sequence took
+    assert [(s.args["path"], s.args["chunk"])
+            for s in tel.ring_spans("retention/path")] == [("pair", 1024)] * 6
+    chip = 15.75e9          # what the compiler has of a v5e chip's 16 GB
+    for program in (decode, prefill):
+        m = program.memory_analysis()
+        # the weights and the whole state are arguments, the state aliased
+        # to the outputs: arguments + temporaries is what the chip holds
+        assert 10.3e9 < m.argument_size_in_bytes < 10.4e9
+        assert m.alias_size_in_bytes > 3.27e9
+        need = m.argument_size_in_bytes + m.temp_size_in_bytes \
+            + m.output_size_in_bytes - m.alias_size_in_bytes
+        assert need < 15e9 < chip, (need, m)
+    # the wave's temporaries hold no second copy of the state (3.27 GB;
+    # the MLPs' two `[16, 1024, 34816]` intermediates are 2.3 of the 3.24)
+    assert prefill.memory_analysis().temp_size_in_bytes < 3.6e9
+    assert decode.memory_analysis().temp_size_in_bytes < 0.2e9
+    for program in (decode, prefill):
+        # no whole-state copy in either entry computation
+        big = [(op, t) for op, t in _entry_ops(program.as_text())
+               if op in ("copy", "copy-start", "transpose")
+               and "f32[16,8,8256,128]" in t]
+        assert not big, big
+
+
 def _entry_ops(text):
     """(op name, result type) of every instruction of optimized HLO's
     entry computation."""

@@ -248,7 +248,7 @@ def print_request_timeline(tl: Dict[str, Any]) -> None:
 
 
 # what the recurrent layers' decode twins report of the state they moved
-# (ops/ssm_ops.py, ops/kda_ops.py)
+# (ops/ssm_ops.py, ops/kda_ops.py, ops/power_retention_ops.py)
 STATE_COUNTERS = {"ssm_state_bytes": "state-space",
                   "linear_state_bytes": "linear-attention"}
 
@@ -288,6 +288,27 @@ def expert_layer_lines(events: List[Dict[str, Any]]) -> List[str]:
                     f"recurrent state read and written a step, "
                     f"{a['moe_experts_hit'] / a['steps']:.1f} held experts hit")
     return lines
+
+
+def recurrent_only_lines(events: List[Dict[str, Any]]) -> List[str]:
+    """One line for a model none of whose layers pages anything and none
+    routes (its decode windows carry a state counter and no `moe_*`): the
+    recurrent state read and written a decode step, and what the prefill
+    waves wrote into their slots themselves."""
+    moved = steps = written = 0.0
+    for ev in events:
+        args = ev.get("args") or {}
+        if ev.get("ph") != "X" or "moe_routed_pairs" in args:
+            continue
+        if ev.get("name") == "serve/decode/window_sync":
+            moved += sum(args.get(c, 0) for c in STATE_COUNTERS)
+            steps += args.get("steps", 0)
+        written += args.get("state_written_bytes", 0)
+    if not moved or not steps:
+        return []
+    return [f"[serve] recurrent state alone (no paged layer): "
+            f"{moved / steps / 1e6:.1f} MB of it read and written a decode "
+            f"step, {written / 1e6:.1f} MB written in place by prefill waves"]
 
 
 def flash_attention_lines(events: List[Dict[str, Any]]) -> List[str]:
@@ -374,7 +395,8 @@ def render(path: str, out_path: Optional[str] = None, top: int = 0,
             if ev.get("name") == "serve/compile_serving" and ev.get("args"):
                 print("[serve] compile_serving: " + " ".join(
                     f"{k}={v}" for k, v in sorted(ev["args"].items())))
-        for line in expert_layer_lines(events) + flash_attention_lines(events):
+        for line in expert_layer_lines(events) + recurrent_only_lines(events) \
+                + flash_attention_lines(events):
             print(line)
         for ev in errors:
             print(f"[error] {ev['name']}: {ev.get('args', {})}")
