@@ -11,6 +11,10 @@ the same tile (forward; ops/ssm_ops.py gives it the XLA form's gradient).
 kda_scan — the chunked delta-rule scan of Kimi Delta Attention with the unit
 vectors and the gated head norm on the same tile (forward; ops/kda_ops.py
 gives it the XLA form's gradient).
+retention_step — one decode step of power retention for the live slots: the
+update of a slot's state and the float32 read-out of the new state on one
+tile, the state read from HBM once and written once in place
+(ops/power_retention_ops.py chooses it where a head is whole 128-lane slabs).
 """
 
 from flexflow_tpu.kernels.dequant_attention import (  # noqa: F401

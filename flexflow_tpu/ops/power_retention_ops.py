@@ -19,9 +19,21 @@ head and RMS_D the RMS norm over a head:
 
 phi(w) is the symmetric power embedding of degree 2: the P = D (D + 1) / 2
 products w_a w_b with a <= b, those with a < b times sqrt 2, so that phi(q) .
-phi(k) = (q . k)^2 and the two forms are the same numbers. The rows lie in
-`np.triu_indices(D)` order, the symmetric half exactly (P = 8256 at D = 128:
-a multiple of the 8 sublanes of an f32 tile; no padding).
+phi(k) = (q . k)^2 and the two forms are the same numbers.
+
+How the state lies at rest has ONE definition, `_row_pairs` (the one-hot
+picks of `_embedding_tables`, which every form builds and reads the state
+through): row r holds the pair (a_r, b_r) and is read with the weight c_r. In
+a head that is not whole 128-lane slabs the rows are the symmetric half
+exactly, in `np.triu_indices(D)` order, and z is `[P]` in the same order.
+Where a head is whole slabs (D = 128, the published width) the values a lie
+in blocks of 8 and every a of block A has the run b in [8 A, D)
+(`kernels/retention_step.row_block`): `laid_rows` = 8704 rows for P = 8256,
+every run whole f32 tiles; the rows with b < a that this adds inside a
+diagonal block hold the mirror products and are read with weight 0; z lies as
+the `[D, D]` square of k_a k_b. `state_rows` stays P, the rows the
+recurrence needs: what `linear_state_bytes` and the models' byte counts
+report is the need, not the allocation.
 
 Three forms of one op, chosen by `params["mode"]`:
 
@@ -34,7 +46,8 @@ Three forms of one op, chosen by `params["mode"]`:
 - "decode" (serving decode): one step of the recurrence on
   `ctx.state[layer.name]` for the slots `valid` names, written back to
   `ctx.new_state` (`retention_step`). Reports `linear_state_bytes`: the
-  state those slots read and wrote.
+  state those slots must read and write (`state_rows`, not the rows laid),
+  and in a `retention/step_path` span which form the step took.
 
 The inputs: x, `positions` `[b, s]` (the rotary angle's position) and
 `valid` `[b, s]` (int, 1 = a real token). At a position that does not exist
@@ -52,21 +65,30 @@ inside a chunk and the state between chunks. One code path: the first
 regime is the second with one chunk. A lowered layer says which in a
 `retention/path` span.
 
-Plain XLA (jax.numpy) throughout, under the named scopes
-`ff_power_retention_scan` (everything between the rotated q, k, v and y, the
-state build among it) and `ff_power_retention_step`. Products take their
+Two named scopes: `ff_power_retention_scan` (everything between the rotated
+q, k, v and y, the state build among it; plain XLA) and
+`ff_power_retention_step`. Products take their
 operands in the compute type and accumulate in float32; gates, cumulative
 sums, decays, S and z are float32. phi of a `[.., D]` operand is two
-products with constant one-hot `[D, P]` matrices (exact: each output is one
+products with constant one-hot `[D, R]` matrices (exact: each output is one
 input) and their product a row, so that no gather and no `[.., D, D]` value
-is formed. The decode step loops over the LIVE slots only (a `fori_loop`
-over a compaction of `valid`, each turn reading one slot's `[J, P, D]` state
-where it lies and writing it back in place): a slot that is not live is
-neither read nor written.
+is formed.
+
+The decode step has two forms, chosen from the shapes (`step_path`), and in
+both a slot that is not live is neither read nor written. Where a head is
+whole 128-lane slabs: the Pallas kernel `kernels/retention_step.py`, a grid
+over (live slot, K/V head) whose first bound is the live count, the update
+and the float32 read-out of the new state on one tile in VMEM, the slot's
+state read from HBM once and written once in place. Elsewhere, and as the
+form the kernel is tested against: plain XLA, a `fori_loop` over a
+compaction of `valid`, each turn taking one slot's state out of the array
+(a copy), updating it in place and reading it out in float32 sums: five
+passes over the slot's state where the kernel makes two.
 """
 
 from __future__ import annotations
 
+import functools
 from typing import TYPE_CHECKING
 
 import jax
@@ -77,6 +99,7 @@ if TYPE_CHECKING:
     from flexflow_tpu.core.layer import Layer
 from flexflow_tpu import telemetry as tel
 from flexflow_tpu.core.tensor import TensorSpec
+from flexflow_tpu.kernels import retention_step as step_kernel
 from flexflow_tpu.ops.norm_ops import rms_norm
 from flexflow_tpu.ops.op_type import OperatorType
 from flexflow_tpu.ops.registry import LoweringCtx, register_op, rows_taken
@@ -101,8 +124,56 @@ def _sizes(p):
 
 
 def state_rows(head_dim: int) -> int:
-    """P: the rows of a K/V head's state, the symmetric half of D x D."""
+    """P: the rows of a K/V head's state that the recurrence needs, the
+    symmetric half of D x D."""
     return head_dim * (head_dim + 1) // 2
+
+
+def laid_rows(head_dim: int) -> int:
+    """The rows of a K/V head's state as it lies at rest (`_row_pairs`):
+    P, or 8704 for 8256 where a head is whole 128-lane slabs."""
+    return sum(step_kernel.block_rows(head_dim))
+
+
+@functools.lru_cache(maxsize=None)
+def _row_pairs(head_dim: int):
+    """(a [R], b [R], weight [R]): row r of the state holds the products of
+    k_a and k_b and is read with `weight` (1 where a = b, 2 where a < b:
+    the square of the symmetric embedding's sqrt 2; 0 where a > b, the
+    mirror rows that whole tiles add). The values a in blocks of
+    `row_block(D)`, each a of a block with the run b from the block's first
+    a to D: with blocks of 1, `np.triu_indices(D)`."""
+    blk = step_kernel.row_block(head_dim)      # 1, or 8 dividing D
+    firsts = range(0, head_dim, blk)
+    a = np.concatenate([np.repeat(np.arange(lo, lo + blk), head_dim - lo)
+                        for lo in firsts])
+    b = np.concatenate([np.tile(np.arange(lo, head_dim), blk)
+                        for lo in firsts])
+    weight = np.where(a == b, 1.0, np.where(a < b, 2.0, 0.0))
+    return a, b, weight.astype(np.float32)
+
+
+def _square_normaliser(head_dim: int) -> bool:
+    """Whether z lies as the `[D, D]` square of k_a k_b (where the rows
+    are laid in whole tiles) and not as `[P]` in the rows' order."""
+    return step_kernel.row_block(head_dim) > 1
+
+
+def _rows_of_square(square):
+    """`[.., D, D]` -> `[.., R]`: entry (a_r, b_r) a row of `_row_pairs`."""
+    a, b, _weight = _row_pairs(square.shape[-1])
+    return square[..., a, b]
+
+
+def _normaliser_at_rest(pairs):
+    """The `[.., D, D]` sums of decayed k_a k_b as z lies at rest."""
+    return pairs if _square_normaliser(pairs.shape[-1]) \
+        else _rows_of_square(pairs)
+
+
+def _normaliser_rows(total, head_dim: int):
+    """z at rest -> `[.., R]` in the order of the state's rows."""
+    return _rows_of_square(total) if _square_normaliser(head_dim) else total
 
 
 def chunk_steps(head_dim: int) -> int:
@@ -126,23 +197,31 @@ def sequence_path(length: int, head_dim: int) -> dict:
             "chunk": chunk}
 
 
+def _state_shapes(kv_heads: int, head_dim: int):
+    """(S, z) of one slot as they lie at rest."""
+    z = (head_dim, head_dim) if _square_normaliser(head_dim) \
+        else (state_rows(head_dim),)
+    return (kv_heads, laid_rows(head_dim), head_dim), (kv_heads,) + z
+
+
 def _embedding_tables(head_dim: int):
-    """(left [D, P], right [D, P], twice [P]): row r = (a, b) of
-    `np.triu_indices(D)` picks w_a and w_b; `twice` is 1 where a = b, else
-    2 (the square of the symmetric embedding's sqrt 2)."""
-    a, b = np.triu_indices(head_dim)
+    """(left [D, R], right [D, R], twice [R]): row r = (a, b) of
+    `_row_pairs` picks w_a and w_b; `twice` is the row's weight on the
+    query side."""
+    a, b, weight = _row_pairs(head_dim)
     rows = np.arange(a.size)
     left = np.zeros((head_dim, a.size), np.float32)
     right = np.zeros((head_dim, a.size), np.float32)
     left[a, rows] = 1.0
     right[b, rows] = 1.0
-    return left, right, np.where(a == b, 1.0, 2.0).astype(np.float32)
+    return left, right, weight
 
 
 def key_rows(k):
     """The key side of the embedding over k's last axis, `[.., D] -> [..,
-    P]` float32: k_a k_b for a <= b, EXACT (the two picks copy one input
-    each, and a product of two bfloat16 numbers has 16 significant bits)."""
+    R]` float32: k_a k_b a row of `_row_pairs`, EXACT (the two picks copy
+    one input each, and a product of two bfloat16 numbers has 16
+    significant bits)."""
     left, right, _twice = _embedding_tables(k.shape[-1])
     exact = jax.lax.Precision.HIGHEST if k.dtype == jnp.float32 else None
     pick = lambda m: jnp.einsum(                         # noqa: E731
@@ -152,7 +231,7 @@ def key_rows(k):
 
 
 def query_rows(q):
-    """The query side, float32: q_a q_b, twice where a < b, so that
+    """The query side, float32: q_a q_b times the row's weight, so that
     `query_rows(q) . key_rows(k) = (q . k)^2`."""
     return key_rows(q) * _embedding_tables(q.shape[-1])[2]
 
@@ -214,21 +293,20 @@ def _chunk_pairs(q, k, v, cum):
 
 def _chunk_state(k, v, cum):
     """What a chunk adds to the state by its end: k, v `[b, c, J, D]`, cum
-    `[b, c, J]` -> (`sum_s key_rows(k_s) (e^{G_c - G_s} v_s)^T` `[b, J, P,
-    D]`, `sum_s e^{G_c - G_s} key_rows(k_s)` `[b, J, P]`), float32. The key
-    rows enter exactly (`_rows_product`); the decay rides on v, whose
+    `[b, c, J]` -> (`sum_s key_rows(k_s) (e^{G_c - G_s} v_s)^T` `[b, J, R,
+    D]`, `sum_s e^{G_c - G_s} k_s (x) k_s` as z lies at rest), float32. The
+    key rows enter exactly (`_rows_product`); the decay rides on v, whose
     rounding is a relative error of the token's own term."""
     f32 = jnp.float32
     left = jnp.exp(cum[:, -1:] - cum)                       # [b, c, J]
     vd = (v.astype(f32) * left[..., None]).astype(v.dtype)
-    # the normaliser's sum as the `[D, D]` product it is, its upper half
-    # taken: no pass over the `[.., c, J, P]` rows
+    # the normaliser's sum as the `[D, D]` product it is: no pass over the
+    # `[.., c, J, R]` rows
     kf = k.astype(f32)
     pairs = jnp.einsum("rcjx,rcjy->rjxy", kf * left[..., None], kf,
                        precision=jax.lax.Precision.HIGHEST)
-    upper = np.triu_indices(k.shape[-1])
     return (_rows_product("bcjp,bcjd->bjpd", key_rows(k), vd),
-            pairs[..., upper[0], upper[1]])
+            _normaliser_at_rest(pairs))
 
 
 def _sequence(q, k, v, log_g, eps, chunk):
@@ -259,17 +337,18 @@ def _sequence(q, k, v, log_g, eps, chunk):
             hi = jax.lax.Precision.HIGHEST
             num_c += decayed[..., None] * jnp.einsum(
                 "bcjgp,bjpd->bcjgd", pq, state, precision=hi)
-            den_c += decayed * jnp.einsum("bcjgp,bjp->bcjg", pq, total,
-                                          precision=hi)
+            den_c += decayed * jnp.einsum(
+                "bcjgp,bjp->bcjg", pq, _normaliser_rows(total, hd),
+                precision=hi)
             add_s, add_z = _chunk_state(k_c, v_c, cum_c)
             fall = jnp.exp(cum_c[:, -1])                    # [b, J]
             return ((state * fall[..., None, None] + add_s,
-                     total * fall[..., None] + add_z), (num_c, den_c))
+                     total * fall.reshape(fall.shape + (1,) * (total.ndim - 2))
+                     + add_z), (num_c, den_c))
 
-        rows = state_rows(hd)
         (state, total), (num, den) = jax.lax.scan(
-            carry, (jnp.zeros((b, kv, rows, hd), f32),
-                    jnp.zeros((b, kv, rows), f32)),
+            carry, tuple(jnp.zeros((b,) + shape, f32)
+                         for shape in _state_shapes(kv, hd)),
             tuple(jnp.moveaxis(t, 1, 0) for t in (q, k, v, cum)))
         num, den = jnp.moveaxis(num, 0, 1), jnp.moveaxis(den, 0, 1)
     y = (num / (den[..., None] + eps)).reshape(b, n * chunk, heads, hd)
@@ -280,7 +359,8 @@ def retention_sequence(q, k, v, log_g, eps: float, valid=None, into=None):
     """The whole sequence from an empty state: q `[b, L, H, D]`, k and v
     `[b, L, J, D]` (the compute type), log_g `[b, L, J]` f32 (<= 0; 0 and k
     = 0 where no token is). Returns (y `[b, L, H, D]` in q's type, S `[b, J,
-    P, D]` f32, z `[b, J, P]` f32: the state after step L).
+    R, D]` f32, z `[b, J, P]` or `[b, J, D, D]` f32: the state after step L
+    as it lies at rest).
 
     One algorithm for every caller, the regime from the shapes
     (`sequence_chunk`), rows in blocks of RETENTION_TOKEN_BLOCK tokens, one
@@ -313,8 +393,8 @@ def retention_sequence(q, k, v, log_g, eps: float, valid=None, into=None):
             order = jnp.argsort(jnp.logical_not(held), stable=True)
             count = jnp.sum(held.astype(jnp.int32))
         if into is None:
-            p = state_rows(hd)
-            into = (jnp.zeros((b, kv, p, hd), f32), jnp.zeros((b, kv, p), f32))
+            into = tuple(jnp.zeros((b,) + shape, f32)
+                         for shape in _state_shapes(kv, hd))
 
         def turn(i, held_so_far):
             y_all, s_all, z_all = held_so_far
@@ -346,52 +426,80 @@ def rows_computed(valid):
                           .astype(jnp.int32))
 
 
-def retention_step(state, total, q, k, v, log_g, live, eps: float):
-    """One step of the recurrence for the slots `live` names: state `[b, J,
-    P, D]` and total `[b, J, P]` f32, q `[b, H, D]`, k and v `[b, J, D]`
-    (the compute type), log_g `[b, J]` f32, live `[b]` bool -> (y `[b, H,
-    D]` f32, 0 for a slot that is not live; the new state and total). A
-    loop over the live slots alone: each turn takes one slot's state where
-    it lies (a dynamic slice of the donated array), and puts it back."""
+def step_path(heads: int, kv_heads: int, head_dim: int) -> dict:
+    """Which form a decode step takes, from the shapes: what a lowered layer
+    reports in its `retention/step_path` span."""
+    kernel = step_kernel.step_supported(head_dim, heads // kv_heads)
+    return {"path": "kernel" if kernel else "xla",
+            "laid_rows": laid_rows(head_dim)}
+
+
+def _step_xla(state, total, q, k, v, log_g, live, eps):
+    """`retention_step` in plain XLA: a loop over the live slots alone, each
+    turn taking one slot's state where it lies (a dynamic slice of the
+    donated array: a copy), and putting it back."""
     b, heads, hd = q.shape
     kv = k.shape[1]
     f32 = jnp.float32
-    with jax.named_scope(STEP_SCOPE):
-        pq = query_rows(q).reshape(b, kv, heads // kv, -1)
-        pk = key_rows(k)
-        order = jnp.argsort(jnp.logical_not(live), stable=True)
+    pq = query_rows(q).reshape(b, kv, heads // kv, -1)
+    pk = key_rows(k)
+    # phi(k) as z lies at rest
+    kf = k.astype(f32)
+    pk_z = kf[..., :, None] * kf[..., None, :] if _square_normaliser(hd) \
+        else pk
+    order = jnp.argsort(jnp.logical_not(live), stable=True)
 
-        def turn(i, held):
-            state, total, y = held
-            slot = order[i]
-            take = lambda t: jax.lax.dynamic_index_in_dim(      # noqa: E731
-                t, slot, keepdims=False)
-            gate = jnp.exp(take(log_g))                          # [J]
-            pk_i, pq_i = take(pk), take(pq)
-            v_i = take(v).astype(f32)
-            old, sums = take(state), take(total)
-            # the read-out of the NEW state from the old one and the step's
-            # own term, so that the slot's state is read by two independent
-            # passes (this sum, and the update below) and no `[J, P, D]`
-            # value lies between them. float32 throughout, as sums and not
-            # as products on the MXU: the rows of a read-out cancel to a
-            # fortieth of their size
-            own = jnp.sum(pq_i * pk_i[:, None], axis=2)          # (q . k)^2
-            num = gate[:, None, None] \
-                * jnp.sum(pq_i[..., None] * old[:, None], axis=2) \
-                + own[..., None] * v_i[:, None]
-            den = gate[:, None] * jnp.sum(pq_i * sums[:, None], axis=2) + own
-            new = old * gate[:, None, None] + pk_i[..., None] * v_i[:, None, :]
-            sums = sums * gate[:, None] + pk_i
-            put = lambda t, x: jax.lax.dynamic_update_index_in_dim(  # noqa: E731
-                t, x, slot, 0)
-            return (put(state, new), put(total, sums),
-                    put(y, num / (den[..., None] + eps)))
+    def turn(i, held):
+        state, total, y = held
+        slot = order[i]
+        take = lambda t: jax.lax.dynamic_index_in_dim(      # noqa: E731
+            t, slot, keepdims=False)
+        gate = jnp.exp(take(log_g))                          # [J]
+        pk_i, pq_i = take(pk), take(pq)
+        v_i = take(v).astype(f32)
+        old, sums = take(state), take(total)
+        # the read-out of the NEW state from the old one and the step's
+        # own term, so that the slot's state is read by two independent
+        # passes (this sum, and the update below) and no `[J, R, D]`
+        # value lies between them. float32 throughout, as sums and not
+        # as products on the MXU: the rows of a read-out cancel to a
+        # fortieth of their size
+        own = jnp.sum(pq_i * pk_i[:, None], axis=2)          # (q . k)^2
+        num = gate[:, None, None] \
+            * jnp.sum(pq_i[..., None] * old[:, None], axis=2) \
+            + own[..., None] * v_i[:, None]
+        den = gate[:, None] * jnp.sum(
+            pq_i * _normaliser_rows(sums, hd)[:, None], axis=2) + own
+        new = old * gate[:, None, None] + pk_i[..., None] * v_i[:, None, :]
+        sums = sums * gate.reshape((kv,) + (1,) * (sums.ndim - 1)) \
+            + take(pk_z)
+        put = lambda t, x: jax.lax.dynamic_update_index_in_dim(  # noqa: E731
+            t, x, slot, 0)
+        return (put(state, new), put(total, sums),
+                put(y, num / (den[..., None] + eps)))
 
-        state, total, y = jax.lax.fori_loop(
-            0, jnp.sum(live.astype(jnp.int32)), turn,
-            (state, total, jnp.zeros((b, kv, heads // kv, hd), f32)))
+    state, total, y = jax.lax.fori_loop(
+        0, jnp.sum(live.astype(jnp.int32)), turn,
+        (state, total, jnp.zeros((b, kv, heads // kv, hd), f32)))
     return y.reshape(b, heads, hd), state, total
+
+
+def retention_step(state, total, q, k, v, log_g, live, eps: float):
+    """One step of the recurrence for the slots `live` names: state `[b, J,
+    R, D]` and total (`[b, J, P]` or `[b, J, D, D]`) f32 as they lie at
+    rest, q `[b, H, D]`, k and v `[b, J, D]` (the compute type), log_g `[b,
+    J]` f32, live `[b]` bool -> (y `[b, H, D]` f32, 0 for a slot that is not
+    live; the new state and total; a slot that is not live keeps its bytes).
+
+    One result for every caller, the form from the shapes (`step_path`): the
+    kernel where a head is whole 128-lane slabs (the state passes through
+    the chip once), the XLA loop elsewhere."""
+    heads, hd = q.shape[1:]
+    with jax.named_scope(STEP_SCOPE):
+        if step_path(heads, k.shape[1], hd)["path"] == "kernel":
+            return step_kernel.retention_step(
+                state, total, q, k, v, jnp.exp(log_g), live, eps)
+        return _step_xla(state, total, q, k, v, log_g, live, eps)
 
 
 def _retention_lower(layer: Layer, inputs, weights, ctx: LoweringCtx):
@@ -423,12 +531,17 @@ def _retention_lower(layer: Layer, inputs, weights, ctx: LoweringCtx):
                 "power_retention decode takes one token a step (a verify "
                 "pass over several would have to roll the state back)")
         st = ctx.state[layer.name]
-        y, state, total = retention_step(
-            st["S"], st["z"], q[:, 0], k[:, 0], v[:, 0], log_g[:, 0],
-            valid[:, 0], eps)
+        # one span a lowered layer (trace time): the form its step took
+        with tel.span("retention/step_path", cat="compile", layer=layer.name,
+                      **step_path(heads, kv, hd)):
+            y, state, total = retention_step(
+                st["S"], st["z"], q[:, 0], k[:, 0], v[:, 0], log_g[:, 0],
+                valid[:, 0], eps)
         ctx.new_state[layer.name] = {"S": state, "z": total}
+        # what the live slots MUST move, read and written once: the rows
+        # the recurrence needs, whatever the layout allocates
         ctx.add_stat("linear_state_bytes", jnp.sum(valid).astype(f32)
-                     * (2.0 * sum(leaf[0].nbytes for leaf in st.values())))
+                     * (2.0 * kv * state_rows(hd) * (hd + 1) * 4))
         y = y[:, None]
     else:
         # one span a lowered layer (trace time): the regime its sequence took
@@ -484,9 +597,8 @@ def _retention_serving_params(params: dict, kind: str) -> dict:
 
 def _retention_slot_state(layer: Layer) -> dict:
     _heads, kv, hd = _sizes(layer.params)
-    rows = state_rows(hd)
-    return {"S": ((kv, rows, hd), jnp.float32),
-            "z": ((kv, rows), jnp.float32)}
+    state, total = _state_shapes(kv, hd)
+    return {"S": (state, jnp.float32), "z": (total, jnp.float32)}
 
 
 def _retention_span_facts(layer: Layer) -> dict:

@@ -126,11 +126,17 @@ def scan_inputs(length, seed=0, b=2, heads=4, kv=2, hd=16, gates=(0.3, 0.999)):
 
 def symmetric_half(full):
     """The reference's plain `[.., D, D, ...]` state (axes 1 and 2 of a
-    row's) as the program lays it: its `np.triu_indices` rows as they are
-    (the factor 2 of the rows off the diagonal is the query side's)."""
+    row's) as the program lays it: the rows of `pr._row_pairs` as they are
+    (the weights of the rows are the query side's)."""
     full = np.asarray(full)
-    a, b = np.triu_indices(full.shape[1])
+    a, b, _weight = pr._row_pairs(full.shape[1])
     return full[:, a, b]
+
+
+def normaliser_at_rest(full):
+    """The reference's `[J, D, D]` normaliser as the program lays it: the
+    square itself where a head is whole 128-lane slabs, else the rows."""
+    return np.asarray(pr._normaliser_at_rest(jnp.asarray(full)))
 
 
 def test_the_embedding_squares_the_dot_product():
@@ -142,16 +148,30 @@ def test_the_embedding_squares_the_dot_product():
         # phi itself: the rows off the diagonal times sqrt 2
         root = np.sqrt(pr._embedding_tables(hd)[2])
         pq, pk = pr.key_rows(q) * root, pr.key_rows(k) * root
-        assert pq.shape == (5, pr.state_rows(hd)) == (5, hd * (hd + 1) // 2)
+        assert pr.state_rows(hd) == hd * (hd + 1) // 2
+        assert pq.shape == (5, pr.laid_rows(hd))
         assert close(jnp.sum(pq * pk, -1), square, 1e-5)
         # as the program holds it: the sqrt 2, squared, on the query side
         assert close(jnp.sum(pr.query_rows(q) * pr.key_rows(k), -1), square,
                      1e-5)
-    assert pr.state_rows(128) == 8256
+    # the rows as they lie: the symmetric half exactly where a head is not
+    # whole 128-lane slabs; at 128 each a of a block of 8 with the run b
+    # from the block's first a, every run whole tiles, the mirror rows that
+    # adds read with weight 0
+    assert (pr.state_rows(16), pr.laid_rows(16)) == (136, 136)
+    for got, want in zip(pr._row_pairs(16), np.triu_indices(16)):
+        assert (got == want).all()
+    assert (pr.state_rows(128), pr.laid_rows(128)) == (8256, 8704)
+    a, b, weight = pr._row_pairs(128)
+    assert (a // 8 * 8 <= b).all() and (weight == np.where(
+        a == b, 1, np.where(a < b, 2, 0))).all()
+    assert int((weight > 0).sum()) == 8256
+    starts = np.flatnonzero(np.diff(a, prepend=-1))
+    assert len(starts) == 128 and (starts % 8 == 0).all() \
+        and (np.diff(starts) % 8 == 0).all()
     # from bfloat16 the key rows are EXACT in float32, and two bfloat16
     # terms hold them: the state's weights are the pair form's
     kb = k.astype(jnp.bfloat16)
-    a, b = np.triu_indices(128)
     want = np.asarray(kb, np.float32)[:, a] * np.asarray(kb, np.float32)[:, b]
     got = pr.key_rows(kb)
     assert got.dtype == jnp.float32 and (np.asarray(got) == want).all()
@@ -256,19 +276,140 @@ def test_one_step_on_the_live_slots_alone():
     assert (np.asarray(new_total[1]) == np.asarray(total[1])).all()
 
 
+def wide_step_inputs(steps, seed, dtype=jnp.float32, b=4, kv=2, group=5,
+                     hd=128):
+    """`steps` tokens a slot at the published head width, and which slots
+    are live at each step: a strict subset that changes between steps."""
+    q, k, v, log_g = scan_inputs(steps, seed=seed, b=b, heads=kv * group,
+                                 kv=kv, hd=hd)
+    q, k, v = (t.astype(dtype) for t in (q, k, v))
+    rng = np.random.default_rng(seed)
+    live = np.zeros((steps, b), bool)
+    for t in range(steps):
+        live[t, rng.permutation(b)[:1 + (t + seed) % (b - 1)]] = True
+    assert not live.all(axis=1).any() and live.any(axis=1).all()
+    assert len({tuple(row) for row in live}) > 1
+    return q, k, v, log_g, live
+
+
+@pytest.mark.parametrize("dtype", ("float32", "bfloat16"))
+def test_the_step_kernel_against_the_recurrence_and_the_xla_loop(dtype):
+    """The decode step's kernel (interpreted here) at the published head
+    width, 2 K/V heads x 5 query heads, four steps from an empty state with
+    a live set that changes: each live slot's y against the token-by-token
+    float32 recurrence over the tokens that slot has taken, and y, S and z
+    against the XLA loop on the same state; a slot that is not live keeps
+    its bytes, through both forms. From bfloat16 q, k and v too: the kernel
+    computes in float32 from numbers float32 holds exactly."""
+    steps, b = 4, 4
+    q, k, v, log_g, live = wide_step_inputs(steps, seed=6,
+                                            dtype=jnp.dtype(dtype))
+    assert pr.step_path(10, 2, 128) == {"path": "kernel", "laid_rows": 8704}
+    shapes = pr._state_shapes(2, 128)
+    assert shapes == ((2, 8704, 128), (2, 128, 128))
+    held = tuple(jnp.zeros((b,) + shape, jnp.float32) for shape in shapes)
+    step = jax.jit(pr.retention_step, static_argnums=7)
+    loop = jax.jit(pr._step_xla, static_argnums=7)
+    assert "pallas_call" in str(jax.make_jaxpr(
+        lambda *t: pr.retention_step(*t, 1e-6))(*held, q[:, 0], k[:, 0],
+                                                v[:, 0], log_g[:, 0], live[0]))
+    taken = [[] for _ in range(b)]
+    f32 = lambda t: jnp.asarray(t, jnp.float32)             # noqa: E731
+    for t in range(steps):
+        args = (q[:, t], k[:, t], v[:, t], log_g[:, t], jnp.asarray(live[t]))
+        y, state, total = step(*held, *args, 1e-6)
+        y_loop, state_loop, total_loop = loop(*held, *args, 1e-6)
+        for slot in range(b):
+            if not live[t, slot]:
+                assert not np.asarray(y[slot]).any()
+                for new, old in ((state, held[0]), (total, held[1]),
+                                 (state_loop, held[0]), (total_loop, held[1])):
+                    assert (np.asarray(new[slot])
+                            == np.asarray(old[slot])).all()
+                continue
+            taken[slot].append(t)
+            at = np.asarray(taken[slot])
+            want, (s_want, z_want) = reference.retention_recurrence(
+                f32(q[slot, at]), f32(k[slot, at]), f32(v[slot, at]),
+                log_g[slot, at], HP)
+            assert close(y[slot], want[-1]), (
+                t, slot, off_by(y[slot], want[-1]))
+            assert close(y[slot], y_loop[slot], 1e-5)
+            assert close(state[slot], symmetric_half(s_want), 1e-5)
+            assert close(total[slot], normaliser_at_rest(z_want), 1e-5)
+            assert close(state[slot], state_loop[slot], 1e-6)
+            assert close(total[slot], total_loop[slot], 1e-6)
+        held = (state, total)
+    assert all(len(t) >= 1 for t in taken) and max(map(len, taken)) >= 2
+
+
+def test_the_step_path_by_shape_and_its_span():
+    """The kernel where a head is whole 128-lane slabs, the XLA loop at the
+    tiny width (no knob: the shapes say); a lowered decode layer says which
+    in its `retention/step_path` span."""
+    assert pr.step_path(40, 8, 128) == {"path": "kernel", "laid_rows": 8704}
+    assert pr.step_path(4, 2, 16) == {"path": "xla", "laid_rows": 136}
+    assert pr.step_path(4, 2, 64) == {"path": "xla", "laid_rows": 2080}
+    q, k, v, log_g = scan_inputs(1, seed=2, b=3)
+    held = tuple(jnp.zeros((3,) + shape, jnp.float32)
+                 for shape in pr._state_shapes(2, 16))
+    assert "pallas_call" not in str(jax.make_jaxpr(
+        lambda *t: pr.retention_step(*t, 1e-6))(
+            *held, q[:, 0], k[:, 0], v[:, 0], log_g[:, 0],
+            jnp.asarray([True, False, True])))
+    for g, path, rows in ((BrumbyConfig.tiny(seq=48), "xla", 136),
+                          (wide_config(), "kernel", 8704)):
+        tel.ring_clear()
+        eng = engine_for(g)
+        state = eng.kv.state
+        eng.decode_step(eng.params, state, positions_valid_step_inputs(
+            jnp.ones((SLOTS, 1), jnp.int32), state))
+        said = tel.ring_spans("retention/step_path")
+        assert [(s.args["layer"], s.args["path"], s.args["laid_rows"])
+                for s in said] == [(f"l{i}_ret", path, rows)
+                                   for i in range(g.layers)]
+
+
+def recurrence_read_out_in_bfloat16(q, k, v, log_g, hp):
+    """The literal recurrence with a float32 state whose READ-OUT takes
+    bfloat16 operands (the state and phi(q) rounded, products summed in
+    float32): what a decode step on the matrix unit would compute."""
+    def rounded(x):
+        return x.astype(jnp.bfloat16).astype(jnp.float32)
+
+    ys, held = [], None
+    for t in range(q.shape[0]):
+        _y, held = reference.retention_recurrence(
+            q[t:t + 1], k[t:t + 1], v[t:t + 1], log_g[t:t + 1], hp, state=held)
+        s, z = held
+        qg = q[t].reshape(k.shape[1], -1, q.shape[-1])
+        qq = rounded(qg[..., :, None] * qg[..., None, :])
+        num = jnp.einsum("jgab,jabd->jgd", qq, rounded(s))
+        den = jnp.einsum("jgab,jab->jg", qq, rounded(z))
+        ys.append((num / (den[..., None] + hp["eps"])).reshape(q.shape[1:]))
+    return jnp.stack(ys), held
+
+
 @pytest.mark.parametrize("fault, least", [
     ({"state_dtype": jnp.bfloat16}, 5e-4), ({"normaliser": False}, 1e-1),
-    ({"gate_factor": 0.9}, 1e-2)])
+    ({"gate_factor": 0.9}, 1e-2), ({"read_out": "bfloat16"}, 5e-4)])
 def test_a_wrong_layer_fails_the_tolerance(fault, least):
     """What RTOL must catch, through the reference's own switches: a state
     rounded to bfloat16 after every step, the numerator without its
-    normaliser, a gate a tenth off."""
+    normaliser, a gate a tenth off; and a read-out whose operands are
+    bfloat16, the state itself float32."""
     q, k, v, log_g = scan_inputs(64, seed=8, b=1, gates=(0.9, 0.999))
     y, _s, _z = pr.retention_sequence(q, k, v, log_g, 1e-6)
-    hp = dict(HP, **{n: x for n, x in fault.items() if n != "state_dtype"})
+    hp = dict(HP, **{n: x for n, x in fault.items()
+                     if n not in ("state_dtype", "read_out")})
     log_wrong = log_g[0] + jnp.log(hp.pop("gate_factor", 1.0))
-    wrong, _ = reference.retention_recurrence(
-        q[0], k[0], v[0], log_wrong, hp, state_dtype=fault.get("state_dtype"))
+    if "read_out" in fault:
+        wrong, _ = recurrence_read_out_in_bfloat16(q[0], k[0], v[0],
+                                                   log_wrong, hp)
+    else:
+        wrong, _ = reference.retention_recurrence(
+            q[0], k[0], v[0], log_wrong, hp,
+            state_dtype=fault.get("state_dtype"))
     assert off_by(y[0], wrong) > least >= 5 * RTOL
 
 
@@ -333,6 +474,13 @@ def engine_for(g, **compile_kw):
     return eng
 
 
+def wide_config(seq=48):
+    """One layer at the published head width, 2 K/V heads x 5 query heads:
+    the state in whole tiles, the decode step the kernel."""
+    return dataclass_replace(BrumbyConfig.tiny(seq=seq), layers=1, heads=10,
+                             kv_heads=2, head_dim=128)
+
+
 class Served:
     """Drives the engine's prefill and decode programs and the cache by
     hand, keeps each slot's tokens, and holds every logit row that comes out
@@ -343,6 +491,10 @@ class Served:
 
     def __init__(self, g, scheduler_path=False):
         self.g, self.eng = g, engine_for(g)
+        # what a live slot's step must move: the rows the recurrence needs
+        self.need = flops.state_bytes_per_slot(file_config(g))
+        assert self.need == g.state_bytes_per_slot() \
+            <= self.eng.kv_spec.state_bytes_per_slot
         self.scheduler_path = scheduler_path
         self.seqs = {}
         self.checked = 0
@@ -401,8 +553,8 @@ class Served:
                 self.eng.params, state,
                 positions_valid_step_inputs(jnp.asarray(nxt), state))
             stats = state.pop(STATS_KEY)
-            assert float(stats["linear_state_bytes"]) == 2 * len(self.seqs) \
-                * self.eng.kv_spec.state_bytes_per_slot
+            assert float(stats["linear_state_bytes"]) \
+                == 2 * len(self.seqs) * self.need
             kv.adopt(state)
             kv.sync_after(1)
             logits = np.asarray(logits)
@@ -416,19 +568,22 @@ class Served:
         del self.seqs[slot]
 
 
-@pytest.mark.parametrize("in_place", (False, True))
+@pytest.mark.parametrize("in_place, wide", [(False, False), (True, False),
+                                            (False, True), (True, True)])
 def test_prefill_then_decode_through_the_state_equals_the_full_forward(
-        in_place, monkeypatch):
+        in_place, wide, monkeypatch):
     """Logits, not tokens. Prompts of different lengths in one padded wave
     (one of 2 tokens): the state is handed out at each row's last real
     token; a slot that sits out the second wave keeps its state and decodes
     correctly; a second wave into a freed slot and into one never used.
     Both ways a wave's state reaches its slots: the commit program, and (the
     threshold forced to 0) the prefill program writing the donated slot
-    arrays itself."""
+    arrays itself. `wide`: one layer at the published head width, where the
+    wave builds the state in whole tiles (8704 rows for 8256) and the step
+    is the kernel."""
     if in_place:
         monkeypatch.setattr(kv_cache, "IN_PLACE_STATE_BYTES", 0)
-    g = BrumbyConfig.tiny(seq=48)
+    g = wide_config() if wide else BrumbyConfig.tiny(seq=48)
     rng = np.random.default_rng(7)
     s = Served(g, scheduler_path=in_place)
     assert s.eng.kv.state_kinds == "recurrent"
@@ -444,6 +599,9 @@ def test_prefill_then_decode_through_the_state_equals_the_full_forward(
     s.decode(3)
     assert s.checked == (0 if in_place else 3 + 2) + 3 * 3 + 4 * 3
     assert len(s.seqs[0]) == 2 + 1 + 6 and len(s.seqs[1]) == 9 + 1 + 3
+    if wide:
+        assert s.eng.kv_spec.state_bytes_per_slot \
+            == 2 * (8704 * 128 + 128 * 128) * 4 > s.need == 2 * 8256 * 129 * 4
 
 
 def test_a_padded_wave_hands_out_each_rows_state_at_its_last_real_token():
@@ -743,6 +901,26 @@ def test_flop_and_byte_functions_against_hand_counts_and_the_program():
     scan = flops.retention_scan_need(cfg, system, {}, {"retention_rows": 18})
     assert scan["flops"] == 18 * 1024 * least
     assert scan["bytes"] == 18 * (1024 * ((80 + 16) * 128 * 2 + 32) + 34080768)
+    # the program's own counter at the published head width: a step reports
+    # 2 x live x what `state_bytes_per_slot` counts (the 8256 rows the
+    # recurrence needs), whatever the layout allocates (8704 rows and a
+    # square normaliser), so that live slots derived from it are whole
+    wide = wide_config()
+    eng = engine_for(wide)
+    for slot in (0, 2, 3):
+        eng.kv.admit(slot, 4, 16)
+    eng.kv.push()
+    state = eng.kv.state
+    _logits, state = eng.decode_step(
+        eng.params, state,
+        positions_valid_step_inputs(jnp.ones((SLOTS, 1), jnp.int32), state))
+    need = flops.state_bytes_per_slot(file_config(wide))
+    assert need == 2 * 8256 * 129 * 4 < eng.kv_spec.state_bytes_per_slot
+    assert float(state[STATS_KEY]["linear_state_bytes"]) == 2 * 3 * need
+    ret = flops.retention_step_need(
+        file_config(wide), system, {},
+        {"linear_state_bytes": 2.0 * 3 * need, "steps": 1})
+    assert ret["bytes"] == 2.0 * 3 * need
 
 
 def dataclass_replace(g, **kw):
@@ -751,11 +929,15 @@ def dataclass_replace(g, **kw):
     return dataclasses.replace(g, **kw)
 
 
-def test_instructions_under_the_named_scopes_of_the_compiled_programs():
+@pytest.mark.parametrize("wide", (False, True))
+def test_instructions_under_the_named_scopes_of_the_compiled_programs(wide):
     """What `retention_scan_roofline.brumby` and `retention_step_roofline
     .brumby` read: the operations the compiled programs put under the two
-    named scopes."""
-    g = BrumbyConfig.tiny(seq=48)
+    named scopes. `wide`: at the published head width the step under
+    `ff_power_retention_step` is the kernel's call (interpreted here: the
+    loop over its grid; `tests/test_chip_compile.py` finds the Mosaic call
+    under the scope in the chip's own program)."""
+    g = wide_config() if wide else BrumbyConfig.tiny(seq=48)
     eng = engine_for(g)
     ids = np.ones((SLOTS, g.seq), np.int32)
     lengths = np.full(SLOTS, 7, np.int32)
@@ -776,3 +958,10 @@ def test_instructions_under_the_named_scopes_of_the_compiled_programs():
                            ("serve/decode", pr.STEP_SCOPE)):
         found = attribution.instructions_under(program, scope)
         assert found and all(found), (program, scope)
+    lowered = eng._decode_jit.lower(
+        eng.params, eng.kv.state, positions_valid_step_inputs(
+            jnp.ones((SLOTS, 1), jnp.int32), eng.kv.state)).as_text(
+                debug_info=True)
+    calls = [line for line in lowered.splitlines()
+             if "ff_power_retention_step/" in line and "pallas_call" in line]
+    assert bool(calls) == wide

@@ -32,7 +32,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)  # chip_smoke
 
 KERNEL_MODULES = ("flash_attention", "fused_ce", "dequant_attention", "ssd_scan",
-                  "kda_scan")
+                  "kda_scan", "retention_step")
 
 # GPT-2 medium: batch 8, 16 heads of 64, seq 1024, vocab padded to 50304
 B, H, S, D = 8, 16, 1024, 64
@@ -745,17 +745,20 @@ def test_ling_serving_programs_fit_one_chip(described_devices, mosaic,
     _assert_appends_in_place(decode, eng)
 
 
-def test_brumby_serving_programs_fit_one_chip(described_devices, one_chip,
-                                              monkeypatch):
+def test_brumby_serving_programs_fit_one_chip(described_devices, mosaic,
+                                              one_chip, monkeypatch):
     """`Brumby-14B-Base.serve-longanswer`'s two programs at the cell's own
     sizes (16 slots, width 1024, 7.08 GB of bf16 weights; every one of the 6
-    layers keeps an `[8, 8256, 128]` f32 state and its `[8, 8256]`
-    normaliser a slot, 34.08 MB a layer, 3.27 GB in all; nothing pages),
+    layers keeps an `[8, 8704, 128]` f32 state (the 8256 rows the
+    recurrence needs, laid in whole tiles) and its `[8, 128, 128]`
+    normaliser a slot, 36.18 MB a layer for 34.08 of need, 3.47 GB in all;
+    nothing pages),
     through the normal entry points: no pools and no page accounting; the
     prefill wave is handed the slot arrays donated and writes them itself,
     so that the chip holds arguments + temporaries (no second `[16, 6, ...]`
     copy of the state among them: under two layers' worth), and the decode
-    step updates the state it was handed in place."""
+    step updates the state it was handed in place: one Mosaic call a layer
+    under `ff_power_retention_step`, the slot arrays aliased through it."""
     from flexflow_tpu import telemetry as tel
 
     eng, g, params, state = _described_engine(
@@ -765,21 +768,22 @@ def test_brumby_serving_programs_fit_one_chip(described_devices, one_chip,
     spec = eng.kv_spec
     assert eng.kv.state_kinds == "recurrent" and eng.attn_layers == []
     assert (spec.layers, spec.heads, spec.latent_dim) == (0, 0, 0)
-    layer_state = 8 * 8256 * (128 + 1) * 4
-    assert spec.state_bytes_per_slot == 6 * layer_state == 6 * 34080768
+    layer_state = 8 * (8704 * 128 + 128 * 128) * 4
+    assert spec.state_bytes_per_slot == 6 * layer_state == 6 * 36175872
+    assert g.state_bytes_per_slot() == 6 * 34080768     # the need
     assert eng.kv.writes_state_in_place
     assert eng.kv.pages_needed(1024) == 0 and eng.kv.can_admit(10 ** 6)
     held = sum(x.size * x.dtype.itemsize
                for x in jax.tree_util.tree_leaves(state))
-    assert 3.27e9 < held < 3.28e9
+    assert 3.47e9 < held < 3.48e9
     weights = sum(x.size * x.dtype.itemsize
                   for x in jax.tree_util.tree_leaves(params))
     assert weights == 2 * 3537947136
     three = [_i32(one_chip, slots, 1)] * 3
+    tel.ring_clear()
     decode = eng._decode_jit.lower(params, state, three).compile()
     wave = [_i32(one_chip, slots, g.seq)] * 3
     slot_state = {n: state[n] for n in eng.kv.recurrent}
-    tel.ring_clear()
     prefill = eng._prefill_first_tokens_jit.lower(
         params, wave, _i32(one_chip, slots), slot_state).compile()
     # every layer of the wave said which regime its sequence took
@@ -790,12 +794,12 @@ def test_brumby_serving_programs_fit_one_chip(described_devices, one_chip,
         m = program.memory_analysis()
         # the weights and the whole state are arguments, the state aliased
         # to the outputs: arguments + temporaries is what the chip holds
-        assert 10.3e9 < m.argument_size_in_bytes < 10.4e9
-        assert m.alias_size_in_bytes > 3.27e9
+        assert 10.5e9 < m.argument_size_in_bytes < 10.6e9
+        assert m.alias_size_in_bytes > 3.47e9
         need = m.argument_size_in_bytes + m.temp_size_in_bytes \
             + m.output_size_in_bytes - m.alias_size_in_bytes
         assert need < 15e9 < chip, (need, m)
-    # the wave's temporaries hold no second copy of the state (3.27 GB;
+    # the wave's temporaries hold no second copy of the state (3.47 GB;
     # the MLPs' two `[16, 1024, 34816]` intermediates are 2.3 of the 3.24)
     assert prefill.memory_analysis().temp_size_in_bytes < 3.6e9
     assert decode.memory_analysis().temp_size_in_bytes < 0.2e9
@@ -803,8 +807,15 @@ def test_brumby_serving_programs_fit_one_chip(described_devices, one_chip,
         # no whole-state copy in either entry computation
         big = [(op, t) for op, t in _entry_ops(program.as_text())
                if op in ("copy", "copy-start", "transpose")
-               and "f32[16,8,8256,128]" in t]
+               and "f32[16,8,8704,128]" in t]
         assert not big, big
+    # the step: the kernel, once a layer, and every layer said so
+    assert [(s.args["path"], s.args["laid_rows"])
+            for s in tel.ring_spans("retention/step_path")] \
+        == [("kernel", 8704)] * 6
+    assert len(re.findall(r' custom-call\([^\n]*custom_call_target='
+                          r'"tpu_custom_call"[^\n]*ff_power_retention_step',
+                          decode.as_text())) == 6
 
 
 def _entry_ops(text):
