@@ -11,16 +11,30 @@ Policy (the vLLM-style loop, on PR 2's async-dispatch discipline):
   time-to-first-token. The program takes it itself (argmax at each slot's
   last real position, `engine.prefill_first_tokens`): `slots` int32 reach
   the host, never the `[slots, S, vocab]` logits.
-- DECODE: between sync points the host dispatches up to `dispatch_ahead`
-  single-token steps without materializing anything — each step's argmax
-  feeds the next step as a device array, the device-resident loop of the
-  async runtime (`prefetch_multi`-style overlap: the host is preparing
-  admissions while the device chews the dispatched window). The window
-  is additionally capped at the smallest remaining token budget across
-  active slots, so the loop never speculates past a max-len finish; an
-  EOS finish inside a window is masked out of the committed KV advance
-  (`sync_after(advances=...)`) and counted as `overdecode_tokens`.
-- EVICTION: at sync points, slots whose sequence hit EOS or max-new are
+- DECODE: in steady decode the chip always has a dispatched step queued.
+  The loop keeps `OVERLAP_DEPTH` single-token steps in flight (two: one
+  running, one queued; never more than `dispatch_ahead`) — each step's
+  argmax feeds the next step as a device array, so the token chain never
+  leaves the device — and once that many are out it materializes the
+  OLDEST one (one `jax.device_get` of its tokens and its counters) while
+  the newer run, dispatches the replacement at once, and only then
+  commits the tokens it pulled on the host. The steps in
+  flight are ALL materialized (a drain: the pipeline runs empty) only where
+  the host mirrors are about to be published to the device or a decision
+  needs every token: a finish, an admission that can be placed, tier
+  rotation, a hand-off, a fault. The steps in flight count against the
+  smallest remaining token budget across active slots, so the pipeline
+  runs empty exactly at a max-len finish and never decodes past it; an
+  EOS finish is seen one sync late, masked out of the committed KV
+  advance (`sync_after(advances=...)`), counted as `overdecode_tokens`,
+  and evicted after the drain that follows. A scheduler built with a
+  feature that needs a safe point every window (hot swap or a fleet's
+  swap control, a fleet's feed and serialized execution, the host tier,
+  the prefill-only hand-off, the decode watchdog) drains whenever the
+  window is full, as every scheduler did before. `sched.stats` counts
+  `overlapped_syncs` (a step or more stayed in flight behind the sync),
+  `drains` and `drains_by_reason`.
+- EVICTION: at drains, slots whose sequence hit EOS or max-new are
   evicted (pages freed). The decode attention routes any out-of-range
   write to the scratch page, so over-decode can never corrupt a
   neighbour.
@@ -169,25 +183,45 @@ def positions_valid_step_inputs(tokens, state) -> List[Any]:
 
 
 def _stat_totals(stats: List[Any]) -> Dict[str, float]:
-    """The programs' own counters (one dict of device scalars per decode
-    step or prefill wave, as it came out under STATS_KEY), summed per name
-    on the host. Called where the step's tokens are materialized anyway, so it
-    waits for nothing new."""
+    """The programs' own counters (one dict of scalars per decode step or
+    prefill wave, as it came out under STATS_KEY and was brought to the
+    host with the tokens it rode beside), summed per name."""
     totals: Dict[str, float] = {}
-    for step in jax.device_get([s for s in stats if s]):
-        for name, value in step.items():
+    for step in stats:
+        for name, value in (step or {}).items():
             totals[name] = totals.get(name, 0) + value.item()
     return totals
+
+
+@jax.jit
+def _greedy_tokens(logits):
+    """Each slot's next token `[slots, 1]` from a step's logits, as ONE
+    launch. Written eagerly (`logits[:, -1, :]`, argmax, cast, new axis)
+    this was four or five programs a step and 3.7 ms of the host's time
+    on the chip's machine, where the whole decode launch is 1.0 (PERF.md,
+    Findings PR 45): more than the device needs for some models' step, so
+    no ordering of the loop could keep the chip fed."""
+    return jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32)[:, None]
 
 
 def _urgency(r: Request):
     return (r.priority, r.arrival_s, r.rid)
 
 
+# Steps the overlapped decode loop keeps in flight: one running and one
+# queued behind it, which feeds the chip as long as the host turns a step
+# round faster than the device runs one (2.1-2.4 ms against 4-12 in the
+# benchmark's cells). Measured, not derived (PERF.md, Findings PR 45): with
+# four in flight every cell idled as much as with two, `ttft_p95_ms` rose
+# 3-12 % (an arriving request waits out every step in flight before its
+# wave) and 3 of 20 runs paused 1.2-3 s for a cause not yet found.
+OVERLAP_DEPTH = 2
+
+
 class ContinuousBatchingScheduler:
     def __init__(self, engine, params, prompt_inputs_fn: Callable,
                  step_inputs_fn: Callable, eos_id: Optional[int] = None,
-                 dispatch_ahead: int = 4,
+                 dispatch_ahead: int = 4,  # most steps in flight: `_depth`
                  ttft_budget_ms: Optional[float] = None,
                  queue_cap: Optional[int] = None,
                  decode_timeout_ms: Optional[float] = None,
@@ -302,22 +336,30 @@ class ContinuousBatchingScheduler:
         self.queue_depth = 0                # live router signals (ints,
         self.active_count = 0               # safe to read cross-thread)
         self.parked: Dict[int, Request] = {}
-        self.stats: Dict[str, int] = {
+        self.stats: Dict[str, Any] = {
             "shed_queue_full": 0, "shed_ttft_budget": 0, "shed_deadline": 0,
             "shed_prompt_too_long": 0, "shed_over_max_context": 0,
             "failed": 0, "evicted_wedged": 0,
             "decode_timeouts": 0, "overdecode_tokens": 0, "swaps": 0,
             "spec_rounds": 0, "spec_drafted_tokens": 0,
-            "spec_accepted_tokens": 0}
+            "spec_accepted_tokens": 0,
+            # how often the decode loop hid a sync behind device work: of
+            # `materializations`, those with a step or more still in
+            # flight, and those that emptied the pipeline, by what for
+            "overlapped_syncs": 0, "drains": 0, "drains_by_reason": {}}
         self._ema_serve_ms = 0.0  # EMA of prefill wall (the shed estimator)
         # per-decode-step wall seconds at materialization granularity —
         # the per-token latency samples the bench quantiles
         self.step_times: List[float] = []
         self.decode_steps = 0
         self.prefills = 0
-        self.materializations = 0  # host syncs that drained a window
-        # the counters of the dispatched, unmaterialized steps
-        self._window_stats: List[Any] = []
+        self.materializations = 0  # host syncs of dispatched steps
+        # dispatched, unmaterialized steps, oldest first: (tokens
+        # `[slots, 1]` on the device, the step's counters or None)
+        self._in_flight: deque = deque()
+        # slots whose request an overlapped sync saw finish (EOS) with
+        # steps still in flight: evicted after the drain that follows
+        self._finishing: Dict[int, Request] = {}
         # request-level tracing (ISSUE 15): zero-sync by construction —
         # the tracer only ever sees timestamps the loop already took at
         # its sync points. With reqtrace off there is NO tracer and the
@@ -457,6 +499,24 @@ class ContinuousBatchingScheduler:
                      padded_tokens=self.slots * self.seq)
             return self._prefill_wave(batch, ids, lengths, active, next_host)
 
+    def _reserved_tokens(self, req: Request) -> int:
+        """Positions a request's admission reserves. Speculation slack: a
+        verify pass caches up to K entries past the committed extent, so
+        the page reservation grows by K — rollback must never need pages
+        the admit didn't grant."""
+        return (len(req.prompt) + req.max_new_tokens
+                + self.dispatch_ahead + self.spec_tokens)
+
+    def _admissible(self, waiting: List[Request]) -> bool:
+        """Whether `_place` would place anybody now: a slot is free and the
+        most urgent waiter's pages are (or the host tier can make room).
+        A waiter held back by a short free list is no reason to empty the
+        pipeline: pages come back at a finish, which drains it anyway."""
+        if not waiting or not self.kv.free_slots():
+            return False
+        return self.tiered or self.kv.can_admit(
+            self._reserved_tokens(min(waiting, key=_urgency)))
+
     def _place(self, waiting: List[Request], active: Dict[int, Request],
                now_s: float) -> List[Request]:
         """The placement loop: requests that got a slot and their pages,
@@ -471,11 +531,7 @@ class ContinuousBatchingScheduler:
             if self.prefill_chunk_tokens and batch and \
                     chunk_used + len(req.prompt) > self.prefill_chunk_tokens:
                 break  # chunked admission: the rest joins the next wave
-            # speculation slack: a verify pass caches up to K entries past
-            # the committed extent, so the page reservation grows by K —
-            # rollback must never need pages the admit didn't grant
-            need = (len(req.prompt) + req.max_new_tokens
-                    + self.dispatch_ahead + self.spec_tokens)
+            need = self._reserved_tokens(req)
             if not self.kv.can_admit(need):
                 # tiered: spill an active slot's pages to the host tier to
                 # make HBM room before conceding backpressure
@@ -562,7 +618,8 @@ class ContinuousBatchingScheduler:
         with tel.span("serve/prefill/device_wait", cat="serve") as sp:
             jax.block_until_ready(first_tokens)
             if STATS_KEY in kv_state:
-                sp.set(**_stat_totals([kv_state[STATS_KEY]]))
+                sp.set(**_stat_totals(
+                    jax.device_get([kv_state[STATS_KEY]])))
         with tel.span("serve/prefill/logits_to_host", cat="serve") as sp:
             tok = np.asarray(first_tokens)
             sp.set(bytes=int(tok.nbytes))
@@ -775,82 +832,163 @@ class ContinuousBatchingScheduler:
             return True
         return False
 
-    def _window_cap(self, active: Dict[int, Request]) -> int:
-        """Dispatch-window length: bounded by `dispatch_ahead` AND the
-        smallest remaining token budget across active slots, so the loop
-        never speculates past a max-len finish (the `scheduler.py`
-        over-decode waste fix of ISSUE 11)."""
+    def _budget(self, active: Dict[int, Request]) -> int:
+        """Steps until the nearest max-len finish, counted from the tokens
+        COMMITTED: the steps in flight count against it."""
         if not active:
             return self.dispatch_ahead
-        rem = min(r.max_new_tokens - len(r.tokens) for r in active.values())
-        return max(1, min(self.dispatch_ahead, rem))
+        return max(1, min(r.max_new_tokens - len(r.tokens)
+                          for r in active.values()))
 
-    def _materialize(self, window_toks: List[Any],
-                     active: Dict[int, Request], window_t0: float
-                     ) -> np.ndarray:
-        """Drain a dispatched window: one host sync pulls every step's
-        tokens, advances the host KV mirrors (per-slot — an EOS finish
-        inside the window is masked out of the committed advance), evicts
-        finished slots, and applies the decode watchdog. Returns the last
-        step's tokens (the next window's seed)."""
-        steps = len(window_toks)
-        window = self.materializations + 1
+    def _window_cap(self, active: Dict[int, Request], pulled: int = 0) -> int:
+        """The most steps that may be in flight now: `_depth`, and no more
+        than the smallest remaining token budget across active slots, less
+        the `pulled` steps an overlapped sync brought and the turn has not
+        committed yet. Once as many are out, every token the nearest finish
+        needs is on its way, the loop stops dispatching, and the pipeline
+        runs empty exactly at a max-len finish (the `scheduler.py`
+        over-decode waste fix of ISSUE 11)."""
+        return min(self._depth, self._budget(active) - pulled)
+
+    @property
+    def _drains_every_window(self) -> bool:
+        """Whether this scheduler was built with a feature that needs the
+        pipeline empty between windows: a swap poll (the engine watches a
+        checkpoint root, or a fleet's controller decides), a fleet's feed
+        (hand-offs arrive through it) and its run-to-completion barriers,
+        tier rotation, the prefill-only hand-off, the decode watchdog
+        (which evicts on a window's own wall time); or with room for one
+        step in flight, which is a window."""
+        return bool(self.control is not None or self.feed is not None
+                    or self._exec_serialized or self.engine.watching
+                    or self.tiered or self.handoff is not None
+                    or self.decode_timeout_ms or self.dispatch_ahead == 1)
+
+    @property
+    def _depth(self) -> int:
+        """The most steps in flight, at which the loop materializes: a
+        window of `dispatch_ahead` where every window is drained, as
+        before; in the overlapped loop `OVERLAP_DEPTH` of them, and never
+        more than `dispatch_ahead`."""
+        if self._drains_every_window:
+            return self.dispatch_ahead
+        return min(self.dispatch_ahead, OVERLAP_DEPTH)
+
+    def _drain_reason(self, waiting: List[Request],
+                      active: Dict[int, Request]) -> Optional[str]:
+        """Why every step in flight has to be materialized before the loop
+        goes on, or None where the oldest will do (or nothing yet).
+        `kv.push()` after an admission or an eviction writes the host's
+        positions and tables over the device's, which is only right when no
+        step is in flight; a finish is decided on the request's whole token
+        list. The run's end is a finish too: the last slot's."""
+        if self._finishing or len(self._in_flight) >= self._budget(active):
+            # an EOS was seen, or a budget's last step is out: a sync of
+            # the oldest alone could not be followed by a dispatch
+            return "finish"
+        if self._admissible(waiting):
+            return "admit"
+        if self._drains_every_window and (
+                len(self._in_flight) >= self.dispatch_ahead
+                # under the host tier a parked request or a hand-off is
+                # taken up at the next turn, not at the window's end
+                or self.parked or self._pending_handoffs):
+            return "safe_point"
+        return None
+
+    def _sync(self, waiting: List[Request], active: Dict[int, Request],
+              drain: Optional[str] = None):
+        """A turn's materialization, under `serve/decode/window_sync`: decide
+        what has to come to the host (every step in flight where
+        `_drain_reason` names a reason, or the caller does; the oldest one
+        where `_depth` are out; nothing while the pipeline is still
+        filling) and bring it, tokens and counters in ONE transfer. The
+        span's `in_flight` is what stays dispatched behind the sync (0 at a
+        drain, whose reason it names): the device work that hides this sync
+        and the commit after it. The decision and the release of the pulled
+        device arrays lie inside the span, so the loop's spans account for
+        its turn. Returns (the steps' tokens, or None where nothing was
+        materialized; the drain's reason, or None)."""
         with tel.span("serve/decode/window_sync", cat="serve",
-                      window=window, steps=steps) as sp:
-            mats = [np.asarray(t) for t in window_toks]
-            if self._window_stats:
-                sp.set(**_stat_totals(self._window_stats))
-                self._window_stats = []
-        t_now = time.perf_counter()
-        self.materializations += 1
-        with tel.span("serve/decode/commit", cat="serve",
-                      window=window) as sp:
-            seed, committed = self._commit_window(mats, active,
-                                                  window_t0, t_now)
-            sp.set(tokens_committed=committed)
-        return seed
+                      window=self.materializations + 1) as sp:
+            drain = drain or self._drain_reason(waiting, active)
+            if not drain and len(self._in_flight) < self._depth:
+                sp.cancel()
+                return None, None
+            steps = len(self._in_flight) if drain else 1
+            taken = [self._in_flight.popleft() for _ in range(steps)]
+            left = len(self._in_flight)
+            sp.set(steps=steps, in_flight=left)
+            mats, stats = jax.device_get(([t for t, _ in taken],
+                                          [c for _, c in taken]))
+            sp.set(**_stat_totals(stats))
+            del taken
+            self.materializations += 1
+            if left:
+                self.stats["overlapped_syncs"] += 1
+            else:
+                sp.set(drain=drain)
+                self.stats["drains"] += 1
+                by = self.stats["drains_by_reason"]
+                by[drain] = by.get(drain, 0) + 1
+        return mats, drain
 
     def _commit_window(self, mats: List[np.ndarray],
                        active: Dict[int, Request], window_t0: float,
-                       t_now: float):
-        """The host side of a drained window: extend token lists, advance
-        the KV mirrors, finish and evict. The cache state needs no hand-over
+                       t_now: float) -> np.ndarray:
+        """The host side of materialized steps, under `serve/decode/commit`:
+        extend token lists, advance the KV mirrors (per-slot — an EOS
+        finish is masked out of the committed advance), and, once nothing
+        is in flight, finish and evict. The cache state needs no hand-over
         here: `self.kv.state` is the newest tree after every dispatch.
-        Returns (next seed, tokens committed)."""
-        steps = len(mats)
-        per_step = (t_now - window_t0) / steps
-        self.step_times.extend([per_step] * steps)
-        self._maybe_autotune(per_step)
-        adv = np.zeros((self.slots,), np.int32)
-        finished: List[int] = []
-        for slot, req in active.items():
-            prev = len(req.tokens)
-            req.tokens.extend(int(m[slot, 0]) for m in mats)
-            if self._truncate(req):
-                kept = max(0, len(req.tokens) - prev)
-                adv[slot] = kept
-                self.stats["overdecode_tokens"] += steps - kept
-                finished.append(slot)
-            else:
-                adv[slot] = steps
-        if self.tracer is not None:
-            # attribute the drained window to every slot that decoded in
-            # it, using the t_now this sync already produced
-            self.tracer.on_decode_window(
-                list(active.values()), t_now - self._t0, steps, per_step,
-                {slot: int(adv[slot]) for slot in active},
-                window=self.materializations)
-        self.kv.sync_after(steps, advances=adv)
-        for slot in finished:
-            self._finish(active.pop(slot), self._now())
-        if self.decode_timeout_ms and active and \
-                per_step * 1e3 > self.decode_timeout_ms:
-            # bounded-step watchdog: the window came back slower than the
-            # per-step budget — evict the longest-resident slot instead
-            # of letting one wedged sequence stall every neighbour
-            self.stats["decode_timeouts"] += 1
-            self._evict_wedged(active, "timeout", self._now(), None)
-        return mats[-1].copy(), int(adv.sum())
+        `window_t0` is when the previous sync ended (or the wave that
+        seeded these steps), so `step_times` is wall time between syncs
+        over the steps materialized. Returns the last step's tokens (the
+        next dispatch's seed once nothing is in flight)."""
+        with tel.span("serve/decode/commit", cat="serve",
+                      window=self.materializations) as sp:
+            steps = len(mats)
+            per_step = (t_now - window_t0) / steps
+            self.step_times.extend([per_step] * steps)
+            self._maybe_autotune(per_step)
+            adv = np.zeros((self.slots,), np.int32)
+            # a slot that finished at an earlier sync decoded these too
+            self.stats["overdecode_tokens"] += steps * len(self._finishing)
+            for slot, req in active.items():
+                prev = len(req.tokens)
+                req.tokens.extend(int(m[slot, 0]) for m in mats)
+                if self._truncate(req):
+                    kept = max(0, len(req.tokens) - prev)
+                    adv[slot] = kept
+                    self.stats["overdecode_tokens"] += steps - kept
+                    self._finishing[slot] = req
+                else:
+                    adv[slot] = steps
+            if self.tracer is not None:
+                # attribute the materialized steps to every slot that
+                # decoded in them, using the t_now this sync already took
+                self.tracer.on_decode_window(
+                    list(active.values()), t_now - self._t0, steps, per_step,
+                    {slot: int(adv[slot]) for slot in active},
+                    window=self.materializations)
+            self.kv.sync_after(steps, advances=adv)
+            for slot in self._finishing:
+                active.pop(slot, None)
+            if not self._in_flight:
+                # drained: the evictions' mirrors may be published
+                for req in self._finishing.values():
+                    self._finish(req, self._now())
+                self._finishing.clear()
+            if self.decode_timeout_ms and active and \
+                    per_step * 1e3 > self.decode_timeout_ms:
+                # bounded-step watchdog: the window came back slower than
+                # the per-step budget — evict the longest-resident slot
+                # instead of letting one wedged sequence stall every
+                # neighbour
+                self.stats["decode_timeouts"] += 1
+                self._evict_wedged(active, "timeout", self._now(), None)
+            sp.set(tokens_committed=int(adv.sum()))
+        return mats[-1].copy()
 
     # --------------------------------------------------------- speculation
     def _spec_round(self, active: Dict[int, Request],
@@ -987,6 +1125,46 @@ class ContinuousBatchingScheduler:
             self._evict_wedged(active, "timeout", self._now(), None)
         return out
 
+    def _dispatch(self, next_dev):
+        """Launch one decode step on the newest cache state and queue its
+        tokens for materialization; returns them as the next step's input
+        (a device array: the token chain stays on the device). Raises what
+        a permanent fault of the launch raises."""
+        with tel.span("serve/decode/dispatch", cat="serve",
+                      window=self.materializations + 1):
+            state = self.kv.state
+            inputs = self.step_inputs_fn(next_dev, state)
+            # the step DONATES `s` and a retry re-calls with the same `s`.
+            # What a retry may assume: a dispatch that raised before it was
+            # enqueued (every injected fault fires ahead of fn; a refused
+            # launch) has not consumed its input, and replays identical
+            # work. One that consumed `s` and then raised cannot be retried
+            # with it: the replay fails on the deleted buffers, the budget
+            # runs out and the fault is permanent.
+            launch = (lambda s=state, ins=inputs:
+                      self.engine.decode_step(self.params, s, ins))
+            logits, state = run_resilient("serve/decode_step", launch,
+                                          policy=self.retry_policy)
+            stats = state.pop(STATS_KEY, None)
+            # the tree that went in is dead (donated): the pool holds the
+            # newest one after every dispatch, steps in flight or not, so
+            # pushes, admissions, rotations and the fault path never touch
+            # a consumed buffer
+            self.kv.adopt(state)
+            with self.exec_lock:
+                # the argmax over model-sharded logits is its own
+                # collective program; under a fleet it must not interleave
+                # with a sibling replica's collectives (the engine call
+                # above serializes inside the proxy — this is the one
+                # launch the scheduler itself owns)
+                next_dev = _greedy_tokens(logits)
+                if self._exec_serialized:
+                    jax.block_until_ready(next_dev)
+            self._in_flight.append((next_dev, stats))
+            self.decode_steps += 1
+            del logits, inputs, state, launch
+        return next_dev
+
     def _now(self) -> float:
         return time.perf_counter() - self._t0
 
@@ -1011,11 +1189,11 @@ class ContinuousBatchingScheduler:
         # pools donates the tree it is handed, and `self.kv.state` is the
         # newest one after every dispatch and every commit
         next_dev = jnp.asarray(next_host)
-        window_toks: List[Any] = []  # dispatched, unmaterialized [slots,1]
+        # when the previous sync ended, or the wave that seeded the steps
         window_t0 = time.perf_counter()
 
         while (queue or waiting or active or self.parked
-               or self._pending_handoffs
+               or self._finishing or self._pending_handoffs
                or (self.feed is not None and not self.feed.exhausted)):
             now = self._now()
             if self.feed is not None:
@@ -1032,19 +1210,24 @@ class ContinuousBatchingScheduler:
             self.active_count = len(active)
             tel.counter("serve/queue_depth", len(waiting), cat="serve")
             tel.counter("serve/active_slots", len(active), cat="serve")
-            want_sync = (len(window_toks) >= self._window_cap(active)
-                         or (waiting and self.kv.free_slots())
-                         or bool(self.parked)
-                         or bool(self._pending_handoffs)
-                         or not active)
-            if want_sync and window_toks:
-                # materialize the dispatched window: one host sync drains
-                # every step's tokens (tiny [slots,1] arrays)
-                next_host = self._materialize(window_toks, active, window_t0)
-                window_toks = []
-                window_t0 = time.perf_counter()
-            if not window_toks and (self.control is not None
-                                    or self.engine.watching):
+            # a turn with steps in flight materializes all of them where
+            # something needs the pipeline empty, the oldest one where as
+            # many are out as may be, none while it is still filling. What
+            # an overlapped sync pulled is committed AFTER the turn's
+            # dispatch, so the chip has the replacement queued while the
+            # host walks the slots
+            pulled, t_sync, drain = None, window_t0, None
+            if self._in_flight:
+                pulled, drain = self._sync(waiting, active)
+                t_sync = time.perf_counter()
+                if drain:
+                    next_host = self._commit_window(
+                        pulled, active, window_t0, t_sync)
+                    pulled = None
+                    window_t0 = time.perf_counter()
+            drained = not self._in_flight and pulled is None
+            if drained and (self.control is not None
+                            or self.engine.watching):
                 # safe swap point: nothing dispatched references params.
                 # Under a fleet, the rolling controller decides whether
                 # THIS replica may advance (or must roll back) here.
@@ -1062,31 +1245,30 @@ class ContinuousBatchingScheduler:
                             getattr(self.engine, "active_version", None))
             if waiting:
                 self._shed_stale(waiting, self._now())
-            if self._pending_handoffs and not window_toks:
+            if self._pending_handoffs and drained:
                 # disaggregated decode side: adopt handed-off prefills into
                 # the host tier; the rotation below carries them to HBM
                 self._ingest_handoffs(self._now())
-            if self.parked and not window_toks:
+            if self.parked and drained:
                 # tier rotation at this sync point: prefetch-ahead issues +
                 # ready/forced rejoins (forced = active drained, a counted
                 # stall); runs before admission so rejoining slots claim
                 # device pages ahead of new arrivals (they are older)
                 self._rotate(active, next_host, self._now())
-            if waiting and self.kv.free_slots():
+            if drained and (drain == "admit" or self._admissible(waiting)):
                 if self._admit(waiting, active, next_host, self._now()):
                     next_dev = jnp.asarray(next_host)
                     window_t0 = time.perf_counter()
-            if self.handoff is not None and active and not window_toks:
+            if self.handoff is not None and active and drained:
                 # prefill replica: everything admitted leaves for the
                 # decode pool right after its TTFT materialization
                 self._handoff_all(active)
-            if self.tiered and not window_toks:
+            if self.tiered and drained:
                 # rotation/spill change which slots decode outside _admit's
-                # refresh; re-seed at drained-window points only — with
-                # steps in flight `next_host` is BEHIND the device, and
-                # resetting to it would re-dispatch the last materialized
-                # token (untiered runs keep the exact pre-PR dispatch
-                # sequence)
+                # refresh; re-seed at drained points only — with steps in
+                # flight `next_host` is BEHIND the device, and resetting to
+                # it would re-dispatch the last materialized token
+                # (untiered runs keep the exact pre-PR dispatch sequence)
                 next_dev = jnp.asarray(next_host)
             if not active:
                 if queue and not waiting:
@@ -1107,7 +1289,7 @@ class ContinuousBatchingScheduler:
                 continue
             if self._spec:
                 # speculative rounds are self-contained (draft chain +
-                # verify + host commit) — no dispatch-ahead window, every
+                # verify + host commit) — nothing stays in flight, every
                 # round is a sync point, so poll_swap stays safe above
                 try:
                     next_host = run_resilient(
@@ -1119,58 +1301,29 @@ class ContinuousBatchingScheduler:
                         self._evict_wedged(active, "failed", self._now(), e)
                 next_dev = jnp.asarray(next_host)
                 continue
-            with tel.span("serve/decode/dispatch", cat="serve",
-                          window=self.materializations + 1):
-                state = self.kv.state
-                inputs = self.step_inputs_fn(next_dev, state)
+            if len(self._in_flight) < self._window_cap(
+                    active, len(pulled or ())):
                 try:
-                    # the step DONATES `s` and a retry re-calls with the
-                    # same `s`. What a retry may assume: a dispatch that
-                    # raised before it was enqueued (every injected fault
-                    # fires ahead of fn; a refused launch) has not consumed
-                    # its input, and replays identical work. One that
-                    # consumed `s` and then raised cannot be retried with
-                    # it: the replay fails on the deleted buffers, the
-                    # budget runs out and the fault is permanent.
-                    logits, state = run_resilient(
-                        "serve/decode_step",
-                        lambda s=state, ins=inputs:
-                            self.engine.decode_step(self.params, s, ins),
-                        policy=self.retry_policy)
+                    next_dev = self._dispatch(next_dev)
                 except Exception as e:  # noqa: BLE001 — permanent fault
-                    # drain what WAS dispatched successfully, then evict
-                    # the wedged slot; every other slot keeps serving.
-                    # Both go through `self.kv.state`, the tree the last
-                    # successful dispatch returned
-                    if window_toks:
-                        next_host = self._materialize(
-                            window_toks, active, window_t0)
-                        window_toks = []
+                    # materialize what WAS dispatched successfully, then
+                    # evict the wedged slot; every other slot keeps
+                    # serving. Both go through `self.kv.state`, the tree
+                    # the last successful dispatch returned
+                    mats = pulled or []
+                    if self._in_flight:
+                        mats += self._sync(waiting, active, "fault")[0]
+                    if mats:
+                        next_host = self._commit_window(
+                            mats, active, window_t0, time.perf_counter())
                     if active:
                         self._evict_wedged(active, "failed", self._now(), e)
                     next_dev = jnp.asarray(next_host)
                     window_t0 = time.perf_counter()
                     continue
-                stats = state.pop(STATS_KEY, None)
-                if stats:
-                    self._window_stats.append(stats)
-                # the tree that went in is dead (donated): the pool holds
-                # the newest one after every dispatch, window in flight or
-                # not, so pushes, admissions, rotations and the fault path
-                # never touch a consumed buffer
-                self.kv.adopt(state)
-                with self.exec_lock:
-                    # the argmax over model-sharded logits is its own
-                    # collective program; under a fleet it must not
-                    # interleave with a sibling replica's collectives (the
-                    # engine call above serializes inside the proxy — this
-                    # is the one launch the scheduler itself owns)
-                    next_dev = jnp.argmax(
-                        logits[:, -1, :], axis=-1).astype(jnp.int32)[:, None]
-                    if self._exec_serialized:
-                        jax.block_until_ready(next_dev)
-                window_toks.append(next_dev)
-                self.decode_steps += 1
+            if pulled is not None:
+                self._commit_window(pulled, active, window_t0, t_sync)
+                window_t0 = t_sync
         if self.tiered:
             # final tier ledger: counters into telemetry (monitor/prom) and
             # into stats (the bench + tests read them from here)

@@ -199,11 +199,15 @@ class TwinCosts:
     prefill_per_token_s: float = 0.0  # + per prompt token in the batch
     kv_transfer_page_s: float = 1e-5  # host<->HBM, one page, all layers
     spec_round_factor: float = 1.3    # spec verify round vs plain step
-    window_overhead_s: float = 0.0    # host work per dispatch window that
-    #   no per-op histogram sees (admission, sampling, materialization
-    #   sync) — throughput-limiting under overload; calibrate it as
-    #   (wall - histogram-accounted busy) / materializations off a
-    #   saturated live run
+    window_overhead_s: float = 0.0    # host work per DRAIN of the decode
+    #   pipeline that no per-op histogram sees (the sync that empties it,
+    #   the commit, eviction, admission, the first dispatch after it: the
+    #   chip has nothing queued meanwhile) — throughput-limiting under
+    #   overload. The live loop hides a sync behind the steps still in
+    #   flight and drains only for a finish or an admission, so the twin
+    #   charges this per turn that admits or finishes somebody, not per
+    #   window of `dispatch_ahead`; calibrate it as (wall -
+    #   histogram-accounted busy) / drains off a saturated live run
     source: str = "analytic"
 
     def prefill_s(self, batch_tokens: int) -> float:
@@ -436,7 +440,7 @@ def simulate(records: Sequence[TraceRecord], spec: TwinSpec,
     completed: List[Dict[str, Any]] = []
     shed: List[Dict[str, Any]] = []
     counters = {"kv_spilled_pages": 0, "prefetch_stall_s": 0.0,
-                "handoffs": 0, "tokens_out": 0, "windows": 0}
+                "handoffs": 0, "tokens_out": 0, "windows": 0, "drains": 0}
 
     def terminal(req: _SimReq, now_s: float, outcome: str,
                  reason: str) -> None:
@@ -543,6 +547,7 @@ def simulate(records: Sequence[TraceRecord], spec: TwinSpec,
             rep.active.append(req)
         # 3) decode window
         worked = bool(fresh or joins or rep.active)
+        drained = bool(fresh or joins)
         if rep.active:
             steps = min(spec.dispatch_ahead,
                         max(int(math.ceil(
@@ -557,6 +562,7 @@ def simulate(records: Sequence[TraceRecord], spec: TwinSpec,
                 counters["prefetch_stall_s"] += stall
                 dt += stall
             hists["decode_step"].add(dt / steps, n=steps)
+            before = rep.done
             for req in list(rep.active):
                 take = min(req.max_new_tokens - len(req.tokens),
                            int(math.ceil(steps * cps)))
@@ -569,10 +575,14 @@ def simulate(records: Sequence[TraceRecord], spec: TwinSpec,
                              "done", "completed")
                     release(rep, req)
             rep.t += dt
+            drained = drained or rep.done > before
         if worked:
-            # one outer-loop window's worth of host overhead
-            rep.t += costs.window_overhead_s
             counters["windows"] += 1
+        if drained:
+            # the pipeline ran empty for an admission or a finish: the
+            # host's work there is not hidden behind a step in flight
+            rep.t += costs.window_overhead_s
+            counters["drains"] += 1
         rep.busy_s += rep.t - t0
         if rep.active or rep.waiting:
             push(rep.t, "step", rep)
@@ -618,6 +628,7 @@ def simulate(records: Sequence[TraceRecord], spec: TwinSpec,
         "tokens_per_s": counters["tokens_out"] / wall,
         "handoffs": counters["handoffs"],
         "windows": counters["windows"],
+        "drains": counters["drains"],
         "kv_spilled_pages": counters["kv_spilled_pages"],
         "prefetch_stall_s": counters["prefetch_stall_s"],
         "utilization": [r.busy_s / wall for r in replicas],
@@ -725,15 +736,16 @@ def calibrate_window_overhead(probe_records: Sequence[TraceRecord],
                               live_wall_s: float) -> float:
     """Solve for `TwinCosts.window_overhead_s` from a SATURATED live
     probe: replay the probe trace at zero overhead, and spread the wall
-    time the live run took beyond the twin's over the windows the twin
-    dispatched. Per-op histograms can't see this cost (admission,
-    sampling, host-sync bookkeeping between materializations), but under
-    overload it limits throughput, so an uncalibrated twin is
+    time the live run took beyond the twin's over the DRAINS of the twin's
+    decode pipeline (the turns that admitted or finished a request: the
+    live scheduler's `stats["drains"]`). Per-op histograms can't see this
+    cost (the sync that empties the pipeline, commit, eviction, admission),
+    but under overload it limits throughput, so an uncalibrated twin is
     systematically optimistic."""
     base = dataclasses.replace(costs, window_overhead_s=0.0)
     res = simulate(probe_records, spec, base)
-    windows = max(1, res.stats["windows"])
-    return max(0.0, (live_wall_s - res.stats["wall_s"]) / windows)
+    drains = max(1, res.stats["drains"])
+    return max(0.0, (live_wall_s - res.stats["wall_s"]) / drains)
 
 
 # ------------------------------------------------------------- validation

@@ -395,7 +395,12 @@ def test_dense_donated_pools_serve_the_parents_tokens(gpt2_serve):
     done = sched.run(reqs)
     assert {r.rid: list(r.tokens) for r in done} == PARENT_TOKENS
     assert len(handed) == sched.decode_steps and all(handed)
-    assert sched.materializations < sched.decode_steps   # windows of > 1 step
+    # steps stay in flight behind the syncs: the pipeline runs empty less
+    # often than a step is dispatched, and some syncs leave it running
+    assert sched.stats["drains"] < sched.decode_steps
+    assert sched.stats["overlapped_syncs"] > 0
+    assert sched.stats["overlapped_syncs"] + sched.stats["drains"] \
+        == sched.materializations
     assert not any(x.is_deleted()
                    for x in jax.tree_util.tree_leaves(eng.kv.state))
 

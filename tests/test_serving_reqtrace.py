@@ -460,12 +460,24 @@ def _ring_run(eng, gc, n=6, **kw):
     return sched, reqs, spans, run
 
 
-def test_serve_run_children_cover_it(rt_serve):
+def test_serve_run_children_cover_it(devices):
     """The scheduler's own spans account for its loop: the direct children
-    of serve/run cover at least 95 % of it."""
-    eng, gc = rt_serve
-    _sched_, reqs, spans, run = _ring_run(eng, gc, n=8, max_new=5)
+    of serve/run cover at least 95 % of it. The run is sized like a served
+    one in what matters here: a decode step of some milliseconds (two
+    layers of width 128, not one of 32) and 46 of them, inside the
+    engine's decode length, so that the Python between a turn's three
+    spans (some 60 us a turn) and the fixed millisecond around the waves
+    are no larger a share than they are of a served run."""
+    gc = GPT2Config(vocab=256, seq=64, d_model=128, heads=4, layers=2,
+                    dropout=0.0)
+    m = FFModel(_serve_cfg(max_decode_len=32))
+    build_gpt2(m, gc, batch=8)
+    eng = compile_serving(m)
+    eng.init(seed=0)
+    _ring_run(eng, gc, n=4, max_new=4)      # compiled: the run below is warm
+    _sched_, reqs, spans, run = _ring_run(eng, gc, n=8, max_new=24)
     assert run.args == {"requests": 8}
+    assert all(len(r.tokens) == 24 for r in reqs)
     kids = sorted((s for s in spans if s.parent == run.id),
                   key=lambda s: s.start_ns)
     assert {s.name for s in kids} >= {"serve/admit", "serve/decode/dispatch",

@@ -129,6 +129,23 @@ def test_window_overhead_calibration_identity():
                                      base_wall * 0.5) == 0.0
 
 
+def test_overhead_is_charged_a_drain_not_a_window():
+    """The live loop hides a sync behind the steps still in flight and
+    empties its pipeline for an admission or a finish alone: one long
+    answer is many windows of `dispatch_ahead` and two drains, and the
+    twin's wall grows by the overhead once a drain."""
+    recs = _recs(n=1, max_new=32)
+    spec = _spec(slo="", max_decode_len=32)
+    costs = TwinCosts.analytic(spec.kv_spec(), step_floor_s=0.01)
+    base = simulate(recs, spec, costs).stats
+    assert base["windows"] >= 32 // spec.dispatch_ahead
+    assert base["drains"] == 2      # the admission's turn, the finish's
+    walled = simulate(recs, spec, dataclasses.replace(
+        costs, window_overhead_s=0.5)).stats
+    assert walled["drains"] == 2
+    assert walled["wall_s"] == pytest.approx(base["wall_s"] + 2 * 0.5)
+
+
 def test_validate_gates_on_worst_metric():
     live = {"tokens_per_s_per_cpu_device": 100.0, "ttft_p99_s": 0.10}
     twin = {"tokens_per_s_per_cpu_device": 110.0, "ttft_p99_s": 0.13}

@@ -311,6 +311,33 @@ def recurrent_only_lines(events: List[Dict[str, Any]]) -> List[str]:
             f"step, {written / 1e6:.1f} MB written in place by prefill waves"]
 
 
+def decode_loop_lines(events: List[Dict[str, Any]]) -> List[str]:
+    """One line for the serving decode loop, from its
+    `serve/decode/window_sync` spans: how many syncs, the share of them
+    that left a step or more in flight behind them (the chip kept working
+    while the host synced and committed), the drains (syncs that emptied
+    the pipeline) by their reason, and the median steps in flight behind a
+    sync."""
+    behind: List[int] = []
+    drains: Dict[str, int] = {}
+    for ev in events:
+        args = ev.get("args") or {}
+        if ev.get("ph") != "X" or "in_flight" not in args \
+                or ev.get("name") != "serve/decode/window_sync":
+            continue
+        behind.append(args["in_flight"])
+        if "drain" in args:
+            drains[args["drain"]] = drains.get(args["drain"], 0) + 1
+    if not behind:
+        return []
+    overlapped = sum(1 for n in behind if n)
+    by_reason = ", ".join(f"{r} {n}" for r, n in sorted(drains.items()))
+    return [f"[serve] decode loop: {len(behind)} syncs, "
+            f"{100.0 * overlapped / len(behind):.1f}% overlapped, "
+            f"{sum(drains.values())} drains ({by_reason}), "
+            f"median in_flight {statistics.median_low(behind)}"]
+
+
 def flash_attention_lines(events: List[Dict[str, Any]]) -> List[str]:
     """One line per shape the flash-attention kernels were lowered at
     (`lower/flash_attention` spans, one a lowered call): for each of the
@@ -395,8 +422,8 @@ def render(path: str, out_path: Optional[str] = None, top: int = 0,
             if ev.get("name") == "serve/compile_serving" and ev.get("args"):
                 print("[serve] compile_serving: " + " ".join(
                     f"{k}={v}" for k, v in sorted(ev["args"].items())))
-        for line in expert_layer_lines(events) + recurrent_only_lines(events) \
-                + flash_attention_lines(events):
+        for line in decode_loop_lines(events) + expert_layer_lines(events) \
+                + recurrent_only_lines(events) + flash_attention_lines(events):
             print(line)
         for ev in errors:
             print(f"[error] {ev['name']}: {ev.get('args', {})}")
