@@ -36,7 +36,7 @@ import time
 
 ONE_CHIP_PHASES = ("train", "serve")
 MULTI_CHIP_PHASES = ("multichip",)
-KERNELS = ("flash_attention", "fused_ce", "dequant_attention")
+KERNELS = ("flash_attention", "dequant_attention")
 # token parity: a served token that is not the reference argmax must be
 # within this many bf16 ulps (at the logits' scale) of the reference max
 NEAR_TIE_ULPS = 8.0
@@ -174,9 +174,8 @@ def _build(gcfg, batch: int, seed: int, init: bool = True, **cfg_kw):
     from flexflow_tpu import AdamOptimizer, FFConfig, FFModel
     from flexflow_tpu.models import build_gpt2
 
-    # default config otherwise: enable_fusion=True, fused_loss "auto".
-    # strategy_cache=False: nothing outside the committed files may steer
-    # the run.
+    # default config otherwise. strategy_cache=False: nothing outside the
+    # committed files may steer the run.
     cfg = FFConfig(batch_size=batch, compute_dtype="bfloat16", seed=seed,
                    strategy_cache=False, log_level="warning", **cfg_kw)
     model = FFModel(cfg)
@@ -209,8 +208,6 @@ def run_train(gcfg, batch: int, seed: int, batches: int = 4, epochs: int = 3):
     """Train phase. Returns (model, cm) for the serve phase."""
     import numpy as np
 
-    from flexflow_tpu.kernels.fused_ce import fused_ce_supported
-
     # one chip, whatever the host shows: the default mesh would be
     # {data: len(jax.devices())}
     model, cm, t_search, t_init = _build(gcfg, batch, seed,
@@ -222,9 +219,6 @@ def run_train(gcfg, batch: int, seed: int, batches: int = 4, epochs: int = 3):
     losses = [float(h["loss"]) for h in hist]
     text = _step_text(cm, x, y)
     kernels = kernels_in(text)
-    vocab_out = int(model.layers[-1].outputs[0].spec.shape[-1])
-    ce_selected = fused_ce_supported((batch * gcfg.seq, vocab_out),
-                                     "bfloat16")
     emit(phase="train", model="gpt2_medium" if gcfg.layers == 24 else "gpt2",
          layers=gcfg.layers, d_model=gcfg.d_model, heads=gcfg.heads,
          seq=gcfg.seq, vocab=gcfg.vocab, batch=batch,
@@ -235,16 +229,11 @@ def run_train(gcfg, batch: int, seed: int, batches: int = 4, epochs: int = 3):
          fit_s_incl_xla_compile=round(t_fit, 3),
          fit_dispatches=cm.step_stats.get("dispatches"),
          fit_host_syncs=cm.step_stats.get("host_syncs"),
-         kernels_in_train_step=kernels,
-         fused_ce_selected=bool(ce_selected),
-         fused_ce_note=("vocab %d is not a multiple of 128 and vocab_pad_to "
-                        "is 0: the default path takes the optax loss"
-                        % vocab_out) if not ce_selected else "")
+         kernels_in_train_step=kernels)
     assert all(np.isfinite(losses)), f"non-finite loss: {losses}"
     assert losses[-1] < losses[0], f"loss did not fall: {losses}"
     # every kernel the config selected is in the compiled step
     assert kernels["flash_attention"] >= 3, kernels  # fwd + dq + dkv
-    assert (kernels["fused_ce"] >= 2) == bool(ce_selected), kernels
     return model, cm
 
 

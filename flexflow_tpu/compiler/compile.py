@@ -552,20 +552,6 @@ class CompiledModel:
         # before user-facing metric accounting
         sentinels = bool(getattr(self.cfg, "health_sentinels", False))
 
-        # fused cross-entropy (kernels/fused_ce.py): the sparse-CE loss
-        # computed blockwise over the vocab axis, so the training step never
-        # holds an f32 copy of the [B, S, vocab] logits
-        fused_loss_mode = str(getattr(self.cfg, "fused_loss", "auto"))
-        # the layout the strategy gives the logits: on a multi-device mesh
-        # the kernel runs per shard of it (kernels/partition.py)
-        out0 = self.outputs[0] if self.outputs else None
-        logits_pspec = PartitionSpec()
-        if out0 is not None and out0.owner is not None:
-            logits_pspec = self.strategy.sharding_for(
-                out0.owner.name).output_pspec(out0.owner_idx)
-        fusion_on = bool(self.cfg.enable_fusion)
-        from flexflow_tpu.kernels import fused_ce as _fce
-
         # ZeRO machinery: the moment/opt-state sharding trees are fixed by
         # (strategy, mesh, optimizer), so build them once per compile and
         # share between the jitted tx.init (see init()) and the in-step
@@ -588,16 +574,8 @@ class CompiledModel:
                 # (layers carry their own name from build_forward): what is
                 # computed here, and its backward, is the step's `loss`
                 with jax.named_scope(attribution.LOSS_SCOPE):
-                    if _fce.use_fused_ce(loss_type, logits, fused_loss_mode,
-                                         fusion_on, self.mesh, logits_pspec):
-                        # native-dtype logits: the f32 copy the reference
-                        # path takes below is exactly the materialization
-                        # we avoid
-                        loss = _fce.fused_cross_entropy(
-                            logits, label, self.mesh, logits_pspec)
-                    else:
-                        loss = compute_loss(loss_type,
-                                            logits.astype(jnp.float32), label)
+                    loss = compute_loss(loss_type,
+                                        logits.astype(jnp.float32), label)
                     for (ln, wn), terms in regularizers.items():
                         w = p[ln][wn].astype(jnp.float32)
                         for mode, lam in terms:

@@ -196,14 +196,6 @@ class FFConfig:
     remat: bool = False  # DEPRECATED alias: uniform "full" policy
     remat_search: bool = False
     remat_policies: str = "none,dots,full"
-    # fused cross-entropy gate (kernels/fused_ce.py): the [B,S,vocab]
-    # logits' softmax stats are computed blockwise (online log-sum-exp) so
-    # the loss never materializes the f32 logits copy. "auto" uses the
-    # kernel when the backend/shape supports it (TPU, or interpret mode
-    # where exercised explicitly) and falls back to the optax loss
-    # otherwise; "on" forces it (interpret mode on CPU — tests); "off"
-    # never fuses.
-    fused_loss: str = "auto"
     donate_state: bool = True
     # observability
     # unified telemetry (flexflow_tpu/telemetry.py): span/counter JSONL
@@ -484,8 +476,6 @@ class FFConfig:
         p.add_argument("--remat-search", action="store_true")
         p.add_argument("--remat-policies", type=str,
                        default="none,dots,full")
-        p.add_argument("--fused-loss", type=str, default="auto",
-                       choices=("auto", "on", "off"))
         p.add_argument("--compgraph", dest="export_dot", type=str, default="")
         p.add_argument("--include-costs-dot-graph", action="store_true")
         p.add_argument("--serve", action="store_true")
@@ -550,7 +540,7 @@ class FFConfig:
         # (flexflow_tpu/jupyter — the reference custom-kernel analog) or a
         # launcher wrapper. Honored ONLY for real CLI invocations
         # (argv=None): a kernelspec-installed env var must not silently
-        # alter explicit programmatic configs in tests/scripts (ADVICE r5).
+        # alter explicit programmatic configs in tests/scripts.
         # CLI flags still override the environment.
         if argv is None:
             import shlex
@@ -560,10 +550,16 @@ class FFConfig:
             argv = env_args + list(sys.argv[1:])
         # parse_known_args passes unknown flags by in silence (they are the
         # user script's): a flag of ours that is gone is refused by name
-        if any(a.split("=")[0] == "--fused-optimizer" for a in argv):
-            raise SystemExit(
-                "--fused-optimizer is gone: the optimizer update is always "
-                "tx.update + optax.apply_updates, one XLA fusion per leaf")
+        gone = {
+            "--fused-optimizer": "the optimizer update is always tx.update "
+                                 "+ optax.apply_updates, one XLA fusion per "
+                                 "leaf",
+            "--fused-loss": "the loss is always the optax form "
+                            "(losses.compute_loss on float32 logits)",
+        }
+        for flag in (a.split("=")[0] for a in argv):
+            if flag in gone:
+                raise SystemExit(f"{flag} is gone: {gone[flag]}")
         args, _unknown = FFConfig.build_parser().parse_known_args(argv)
 
         mesh: Dict[str, int] = {}
@@ -631,7 +627,6 @@ class FFConfig:
             remat=args.remat,
             remat_search=args.remat_search,
             remat_policies=args.remat_policies,
-            fused_loss=args.fused_loss,
             export_dot=args.export_dot,
             include_costs_dot_graph=args.include_costs_dot_graph,
             serve=args.serve,

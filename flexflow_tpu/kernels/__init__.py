@@ -2,8 +2,6 @@
 
 flash_attention — block-wise online-softmax attention (fwd + custom VJP),
 the cuDNN-fused-attention replacement (reference src/ops/attention.cu:35).
-fused_ce — blockwise online-logsumexp sparse cross-entropy (fwd + custom
-VJP): the loss never materializes an f32 [N, vocab] array.
 dequant_attention — fused int8-dequant + decode attention over the
 quantized paged KV cache (serving --kv-cache-dtype int8).
 ssd_scan — the Mamba-2 chunked scan with the mixer's skip, gate and norm on
@@ -23,10 +21,6 @@ from flexflow_tpu.kernels.dequant_attention import (  # noqa: F401
 from flexflow_tpu.kernels.flash_attention import (  # noqa: F401
     flash_attention,
     flash_attention_qkv,
-)
-from flexflow_tpu.kernels.fused_ce import (  # noqa: F401
-    fused_ce_supported,
-    fused_cross_entropy,
 )
 from flexflow_tpu.kernels.kda_scan import (  # noqa: F401
     kda_chunk_scan,

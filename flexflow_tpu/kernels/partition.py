@@ -46,12 +46,3 @@ def dividing(dim, size: int, mesh, taken=()):
     if degree < 2 or size % degree or set(used_axes([dim])) & set(taken):
         return None
     return dim
-
-
-def local_shape(shape, dims, mesh):
-    """Per-shard shape of `shape` under the per-dim shardings `dims`, or
-    None when a sharded dim does not divide evenly."""
-    degrees = [_degree(d, mesh) for d in dims] + [1] * (len(shape) - len(dims))
-    if any(g == 0 or s % g for s, g in zip(shape, degrees)):
-        return None
-    return tuple(s // g for s, g in zip(shape, degrees))

@@ -23,14 +23,6 @@ class GPT2Config:
     layers: int = 12
     d_ff: int = 0  # 0 -> 4*d_model
     dropout: float = 0.1
-    # pad the lm_head output dim up to a multiple of this (0 = off): GPT-2's
-    # 50257 vocab is 113 lanes short of a 128-lane boundary, so the biggest
-    # matmul in the model (and the CE reduction over it) runs misaligned on
-    # the MXU/VPU. Padding columns are real trained params whose logits the
-    # softmax drives to -inf; labels never index them. FLOP accounting
-    # (flops_per_token) stays on the TRUE vocab, so reported MFU counts only
-    # useful model FLOPs.
-    vocab_pad_to: int = 0
 
     @staticmethod
     def small():
@@ -99,8 +91,5 @@ def build_gpt2(model: FFModel, cfg: GPT2Config, batch: int = 8,
     for i in range(cfg.layers):
         t = gpt2_block(model, t, cfg, f"h{i}", decode=decode)
     t = model.layer_norm(t, name="ln_f")
-    out_v = cfg.vocab
-    if cfg.vocab_pad_to:
-        out_v = -(-cfg.vocab // cfg.vocab_pad_to) * cfg.vocab_pad_to
-    logits = model.dense(t, out_v, use_bias=False, name="lm_head")
+    logits = model.dense(t, cfg.vocab, use_bias=False, name="lm_head")
     return (ids, pos), logits

@@ -550,7 +550,7 @@ def _layer_candidates(layer: "Layer", machine: MachineSpec, batch_sizes,
         stacked = congruent_branches(layer)
         b_local = (ispecs[0].shape[0] // max(1, _ddeg([dp_in[0][0]], machine))
                    if ispecs and ispecs[0].ndim else 1)
-        # ADVICE r5 crash gate: when the batch cannot shard over the batch
+        # crash gate: when the batch cannot shard over the batch
         # axes (_dp_dims fell back to replicated — e.g. batch 6 on data=4),
         # place_branches' backward fails at trace time (g_l varies over the
         # batch axes while the replicated primals do not) and the grouped

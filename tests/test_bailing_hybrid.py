@@ -43,7 +43,7 @@ from flexflow_tpu.models import (BailingHybridConfig,  # noqa: E402
                                  build_bailing_hybrid)
 from flexflow_tpu.ops import get_op_def, kda_ops  # noqa: E402
 from flexflow_tpu.ops.op_type import OperatorType  # noqa: E402
-from flexflow_tpu.ops.registry import STATS_KEY, LoweringCtx  # noqa: E402
+from flexflow_tpu.ops.registry import LoweringCtx  # noqa: E402
 from flexflow_tpu.serving import (ContinuousBatchingScheduler, Request,  # noqa: E402
                                   compile_serving,
                                   positions_valid_prompt_inputs,
@@ -54,6 +54,7 @@ from families import bailing_hybrid as family  # noqa: E402
 from harness import flops_bailing_hybrid as flops  # noqa: E402
 from harness import manifest as mf  # noqa: E402
 from harness import reference_bailing_hybrid as reference  # noqa: E402
+from served import Served, off_by  # noqa: E402
 
 RTOL = 1e-4
 SLOTS = 4
@@ -102,12 +103,6 @@ def compiled(g, batch=2, **kw):
                        loss_type="sparse_categorical_crossentropy", metrics=[])
     cm.init(seed=3)
     return cm
-
-
-def off_by(got, want):
-    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
-    assert got.shape == want.shape
-    return float(np.abs(got - want).max()) / float(np.abs(want).max())
 
 
 def close(got, want, rtol=RTOL):
@@ -593,69 +588,23 @@ def engine_for(g, **compile_kw):
     return eng
 
 
-class Served:
-    """Drives engine.prefill / engine.decode_step and the cache by hand,
-    keeps each slot's tokens, and holds every logit row that comes out
-    against the reference's full forward over the slot's tokens."""
+def served(g):
+    """The shared harness on this family's engine, input builders and
+    reference."""
+    eng = engine_for(g)
 
-    def __init__(self, g):
-        self.g, self.eng = g, engine_for(g)
-        self.seqs = {}
-        self.checked = 0
+    def wave_stats(s, stats, prompts):
+        assert int(stats["kda_layers"]) == g.kinds.count("kda")
 
-    def check(self, slot, logits_row):
-        ids = np.asarray([self.seqs[slot]], np.int32)
-        want = np.asarray(reference_logits(self.eng.params, self.g, ids))[0, -1]
-        assert close(logits_row, want), (slot, len(self.seqs[slot]),
-                                         off_by(logits_row, want))
-        self.checked += 1
+    def step_stats(s, stats):
+        per_slot = eng.kv_spec.state_bytes_per_slot
+        assert float(stats["linear_state_bytes"]) \
+            == 2 * len(s.seqs) * per_slot
+        assert "ssm_state_bytes" not in stats
 
-    def wave(self, prompts):
-        kv = self.eng.kv
-        ids = np.zeros((SLOTS, self.g.seq), np.int32)
-        lengths = np.zeros(SLOTS, np.int32)
-        for slot, prompt in prompts.items():
-            kv.admit(slot, len(prompt), len(prompt) + 16)
-            ids[slot, :len(prompt)] = prompt
-            lengths[slot] = len(prompt)
-            self.seqs[slot] = list(prompt)
-        kv.push()
-        logits, kv_state = self.eng.prefill(
-            self.eng.params, positions_valid_prompt_inputs(ids, lengths))
-        stats = kv_state.pop(STATS_KEY)
-        assert int(stats["kda_layers"]) == self.g.kinds.count("kda")
-        kv.commit_prefill(kv_state, np.arange(SLOTS, dtype=np.int32), lengths)
-        logits = np.asarray(logits)
-        for slot, prompt in prompts.items():
-            self.check(slot, logits[slot, len(prompt) - 1])
-            self.seqs[slot].append(int(logits[slot, len(prompt) - 1].argmax()))
-
-    def decode(self, steps):
-        kv = self.eng.kv
-        for _ in range(steps):
-            nxt = np.zeros((SLOTS, 1), np.int32)
-            for slot, seq in self.seqs.items():
-                nxt[slot, 0] = seq[-1]
-            state = kv.state
-            logits, state = self.eng.decode_step(
-                self.eng.params, state,
-                positions_valid_step_inputs(jnp.asarray(nxt), state))
-            stats = state.pop(STATS_KEY)
-            per_slot = self.eng.kv_spec.state_bytes_per_slot
-            assert float(stats["linear_state_bytes"]) \
-                == 2 * len(self.seqs) * per_slot
-            assert "ssm_state_bytes" not in stats
-            kv.adopt(state)
-            kv.sync_after(1)
-            logits = np.asarray(logits)
-            for slot in self.seqs:
-                self.check(slot, logits[slot, 0])
-                self.seqs[slot].append(int(logits[slot, 0].argmax()))
-
-    def evict(self, slot):
-        self.eng.kv.evict(slot)
-        self.eng.kv.push()
-        del self.seqs[slot]
+    return Served(eng, lambda ids: reference_logits(eng.params, g, ids),
+                  positions_valid_prompt_inputs, positions_valid_step_inputs,
+                  RTOL, wave_stats=wave_stats, step_stats=step_stats)
 
 
 def test_prefill_then_decode_through_cache_and_state_equals_the_full_forward():
@@ -667,7 +616,7 @@ def test_prefill_then_decode_through_cache_and_state_equals_the_full_forward():
     query latent, the head gate) and the delta rule's single step."""
     g = BailingHybridConfig.tiny(seq=48)
     rng = np.random.default_rng(7)
-    s = Served(g)
+    s = served(g)
     assert s.eng.kv.state_kinds == "paged_latent+recurrent"
 
     def prompt(n):

@@ -51,6 +51,7 @@ from families import brumby as family  # noqa: E402
 from harness import flops_brumby as flops  # noqa: E402
 from harness import manifest as mf  # noqa: E402
 from harness import reference_brumby as reference  # noqa: E402
+from served import Served, off_by  # noqa: E402
 
 RTOL = 1e-4
 SLOTS = 4
@@ -83,12 +84,6 @@ def compiled(g, batch=2, **kw):
                        loss_type="sparse_categorical_crossentropy", metrics=[])
     cm.init(seed=3)
     return cm
-
-
-def off_by(got, want):
-    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
-    assert got.shape == want.shape
-    return float(np.abs(got - want).max()) / float(np.abs(want).max())
 
 
 def close(got, want, rtol=RTOL):
@@ -481,91 +476,32 @@ def wide_config(seq=48):
                              kv_heads=2, head_dim=128)
 
 
-class Served:
-    """Drives the engine's prefill and decode programs and the cache by
-    hand, keeps each slot's tokens, and holds every logit row that comes out
-    against the reference's full forward over the slot's tokens. `scheduler
-    _path`: the prefill the scheduler runs (first tokens on the device; the
-    wave writes its own slots where the cache says so) instead of the
-    full-logits one."""
+def served(g, scheduler_path=False):
+    """The shared harness on this family's engine, input builders and
+    reference. `scheduler_path`: the prefill the scheduler runs instead of
+    the full-logits one."""
+    eng = engine_for(g)
+    # what a live slot's step must move: the rows the recurrence needs
+    need = flops.state_bytes_per_slot(file_config(g))
+    assert need == g.state_bytes_per_slot() \
+        <= eng.kv_spec.state_bytes_per_slot
 
-    def __init__(self, g, scheduler_path=False):
-        self.g, self.eng = g, engine_for(g)
-        # what a live slot's step must move: the rows the recurrence needs
-        self.need = flops.state_bytes_per_slot(file_config(g))
-        assert self.need == g.state_bytes_per_slot() \
-            <= self.eng.kv_spec.state_bytes_per_slot
-        self.scheduler_path = scheduler_path
-        self.seqs = {}
-        self.checked = 0
-
-    def check(self, slot, logits_row):
-        ids = np.asarray([self.seqs[slot]], np.int32)
-        want = np.asarray(reference_logits(self.eng.params, self.g, ids))[0, -1]
-        assert close(logits_row, want), (slot, len(self.seqs[slot]),
-                                         off_by(logits_row, want))
-        self.checked += 1
-
-    def wave(self, prompts):
-        kv = self.eng.kv
-        ids = np.zeros((SLOTS, self.g.seq), np.int32)
-        lengths = np.zeros(SLOTS, np.int32)
-        for slot, prompt in prompts.items():
-            kv.admit(slot, len(prompt), len(prompt) + 16)
-            ids[slot, :len(prompt)] = prompt
-            lengths[slot] = len(prompt)
-            self.seqs[slot] = list(prompt)
-        kv.push()
-        inputs = positions_valid_prompt_inputs(ids, lengths)
-        if self.scheduler_path:
-            first, kv_state = self.eng.prefill_first_tokens(
-                self.eng.params, inputs, lengths)
-            stats = kv_state.pop(STATS_KEY)
-            if kv.writes_state_in_place:
-                assert not set(kv_state) & set(kv.recurrent)
-                assert float(stats["state_written_bytes"]) == len(prompts) \
-                    * self.eng.kv_spec.state_bytes_per_slot
-        else:
-            logits, kv_state = self.eng.prefill(self.eng.params, inputs)
-            stats = kv_state.pop(STATS_KEY)
-        assert int(stats["retention_layers"]) == self.g.layers
+    def wave_stats(s, stats, prompts):
+        if scheduler_path and eng.kv.writes_state_in_place:
+            assert float(stats["state_written_bytes"]) == len(prompts) \
+                * eng.kv_spec.state_bytes_per_slot
+        assert int(stats["retention_layers"]) == g.layers
         # (at 48 positions a block of 1024 tokens holds all four rows: no
         # row is skipped; a `[16, 1024]` wave goes row by row)
-        assert int(stats["retention_rows"]) == self.g.layers * SLOTS
-        kv.commit_prefill(kv_state, np.arange(SLOTS, dtype=np.int32), lengths)
-        for slot, prompt in prompts.items():
-            if self.scheduler_path:
-                nxt = int(np.asarray(first)[slot])
-            else:
-                row = np.asarray(logits)[slot, len(prompt) - 1]
-                self.check(slot, row)
-                nxt = int(row.argmax())
-            self.seqs[slot].append(nxt)
+        assert int(stats["retention_rows"]) == g.layers * SLOTS
 
-    def decode(self, steps):
-        kv = self.eng.kv
-        for _ in range(steps):
-            nxt = np.zeros((SLOTS, 1), np.int32)
-            for slot, seq in self.seqs.items():
-                nxt[slot, 0] = seq[-1]
-            state = kv.state
-            logits, state = self.eng.decode_step(
-                self.eng.params, state,
-                positions_valid_step_inputs(jnp.asarray(nxt), state))
-            stats = state.pop(STATS_KEY)
-            assert float(stats["linear_state_bytes"]) \
-                == 2 * len(self.seqs) * self.need
-            kv.adopt(state)
-            kv.sync_after(1)
-            logits = np.asarray(logits)
-            for slot in self.seqs:
-                self.check(slot, logits[slot, 0])
-                self.seqs[slot].append(int(logits[slot, 0].argmax()))
+    def step_stats(s, stats):
+        assert float(stats["linear_state_bytes"]) == 2 * len(s.seqs) * need
 
-    def evict(self, slot):
-        self.eng.kv.evict(slot)
-        self.eng.kv.push()
-        del self.seqs[slot]
+    return Served(eng, lambda ids: reference_logits(eng.params, g, ids),
+                  positions_valid_prompt_inputs, positions_valid_step_inputs,
+                  RTOL, wave_stats=wave_stats, step_stats=step_stats,
+                  scheduler_path=scheduler_path)
 
 
 @pytest.mark.parametrize("in_place, wide", [(False, False), (True, False),
@@ -585,7 +521,7 @@ def test_prefill_then_decode_through_the_state_equals_the_full_forward(
         monkeypatch.setattr(kv_cache, "IN_PLACE_STATE_BYTES", 0)
     g = wide_config() if wide else BrumbyConfig.tiny(seq=48)
     rng = np.random.default_rng(7)
-    s = Served(g, scheduler_path=in_place)
+    s = served(g, scheduler_path=in_place)
     assert s.eng.kv.state_kinds == "recurrent"
     assert s.eng.kv.writes_state_in_place == in_place
 
@@ -601,7 +537,7 @@ def test_prefill_then_decode_through_the_state_equals_the_full_forward(
     assert len(s.seqs[0]) == 2 + 1 + 6 and len(s.seqs[1]) == 9 + 1 + 3
     if wide:
         assert s.eng.kv_spec.state_bytes_per_slot \
-            == 2 * (8704 * 128 + 128 * 128) * 4 > s.need == 2 * 8256 * 129 * 4
+            == 2 * (8704 * 128 + 128 * 128) * 4 > g.state_bytes_per_slot() == 2 * 8256 * 129 * 4
 
 
 def test_a_padded_wave_hands_out_each_rows_state_at_its_last_real_token():

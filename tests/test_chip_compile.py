@@ -31,12 +31,11 @@ from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)  # chip_smoke
 
-KERNEL_MODULES = ("flash_attention", "fused_ce", "dequant_attention", "ssd_scan",
+KERNEL_MODULES = ("flash_attention", "dequant_attention", "ssd_scan",
                   "kda_scan", "retention_step")
 
-# GPT-2 medium: batch 8, 16 heads of 64, seq 1024, vocab padded to 50304
+# GPT-2 medium: batch 8, 16 heads of 64, seq 1024
 B, H, S, D = 8, 16, 1024, 64
-VOCAB_PADDED = 50304
 
 
 @pytest.fixture(scope="module")
@@ -222,20 +221,6 @@ def test_flash_attention_causal_tiles(one_chip, mosaic, bq, bk):
              one_chip, qkv, qkv, qkv, qkv, vec, vec)
 
 
-def test_fused_ce_forward_backward(one_chip, mosaic):
-    from flexflow_tpu.kernels.fused_ce import (fused_ce_supported,
-                                               fused_cross_entropy)
-
-    # GPT-2's published vocab is not lane-aligned: the default (unpadded)
-    # model takes the optax loss, and only the padded vocab selects the kernel
-    assert not fused_ce_supported((B, S, 50257), jnp.bfloat16)
-    assert fused_ce_supported((B * S, VOCAB_PADDED), jnp.bfloat16)
-    compiled = _compile(jax.value_and_grad(fused_cross_entropy), one_chip,
-                        ((B * S, VOCAB_PADDED), jnp.bfloat16),
-                        ((B * S,), jnp.int32))
-    assert compiled.as_text().count("tpu_custom_call") >= 2
-
-
 @pytest.mark.parametrize("q_tokens", [1, 5])
 def test_dequant_decode_attention(one_chip, mosaic, q_tokens):
     """The serving engine's int8 geometry: [slots, L, 16, 64] gathered
@@ -314,26 +299,6 @@ def test_dequant_decode_attention_per_shard_on_the_mesh(mesh2x2, mosaic):
     assert "tpu_custom_call" in text
 
 
-def test_fused_ce_per_shard_on_the_mesh(mesh2x2, mosaic):
-    """Rows split over data, vocab whole on every device (a vocab-sharded
-    layout is not selected: use_fused_ce says no and the optax loss runs)."""
-    from flexflow_tpu.kernels.fused_ce import fused_cross_entropy, use_fused_ce
-
-    pspec = P("data", None, None)
-    logits = jax.ShapeDtypeStruct((B, S, VOCAB_PADDED), jnp.bfloat16)
-    assert use_fused_ce("sparse_categorical_crossentropy", logits, "auto",
-                        True, mesh2x2, pspec)
-    assert not use_fused_ce("sparse_categorical_crossentropy", logits, "auto",
-                            True, mesh2x2, P("data", None, "model"))
-    text = _compile_on(
-        mesh2x2,
-        jax.value_and_grad(
-            lambda x, y: fused_cross_entropy(x, y, mesh2x2, pspec)),
-        ((B, S, VOCAB_PADDED), jnp.bfloat16, pspec),
-        ((B, S), jnp.int32, P("data", None))).as_text()
-    assert text.count("tpu_custom_call") >= 2
-
-
 def _train_step_shapes(cm, label_shape):
     """The jitted train step's arguments as shapes with cm's own shardings
     (a described device holds no array)."""
@@ -388,6 +353,32 @@ def test_searched_train_step_on_the_mesh(described_devices, mosaic):
     text = compiled.as_text()
     assert cs.kernels_in(text)["flash_attention"] >= 3 * 2
     assert sum(cs.collectives_in(text).values()) > 0
+
+
+def test_the_loss_has_one_path_at_a_lane_aligned_vocab(described_devices,
+                                                       mosaic):
+    """GPT-2's step at a vocabulary of 50304, a multiple of 128 (rows a
+    multiple of 8, bf16): the shapes at which a fused cross-entropy kernel
+    used to be chosen. The loss is the optax form whatever the shapes are:
+    the log-softmax's own operations under `ff.loss`, the scope
+    `step_loss_device_ms.train` joins on, and no kernel call there."""
+    import chip_smoke as cs
+    from flexflow_tpu.attribution import LOSS_SCOPE
+
+    described_devices(1)
+    gcfg = cs.gpt2_medium()
+    gcfg.layers, gcfg.vocab = 1, 50304
+    _, cm, _, _ = cs._build(gcfg, B, 0, init=False, mesh_shape={"data": 1})
+    text = cm.train_step.lower(
+        *_train_step_shapes(cm, (B, gcfg.seq))).as_text(debug_info=True)
+    # the name stacks of the lowered step's operations
+    names = re.findall(r'loc\("([^"]+)"', text)
+    assert sum("ff_flash_attention" in n for n in names) == 3  # fwd, dq, dkv
+    loss = [n for n in names if f"({LOSS_SCOPE})" in n]
+    for op in ("reduce_max", "exp", "log"):
+        assert f"jit(train_step)/jvp({LOSS_SCOPE})/{op}" in loss, op
+    assert not any("pallas_call" in n for n in loss), loss
+    assert "ff_fused_ce" not in text
 
 
 def _elements(ty):

@@ -91,7 +91,7 @@ def test_no_tpu_exits_nonzero_and_prints_no_result(capsys):
 def _chip_only_kernels(text):
     # interpret mode lowers the kernels to plain ops: which Mosaic custom
     # calls the compiled text holds is a fact only the chip run can show
-    return {"flash_attention": 3, "fused_ce": 2, "dequant_attention": 0}
+    return {"flash_attention": 3, "dequant_attention": 0}
 
 
 def test_train_and_serve_phases_tiny(devices, monkeypatch, capsys):
@@ -126,14 +126,14 @@ def test_kernels_and_collectives_are_read_from_the_compiled_text():
         '%a = bf16[8] custom-call(%x), custom_call_target="tpu_custom_call", '
         'metadata={op_name="jit(step)/h0_attn/ff_flash_attention_fwd/pallas_call"}',
         '%b = f32[8] custom-call(%y), custom_call_target="tpu_custom_call", '
-        'metadata={op_name="jit(step)/ff_fused_ce_bwd/pallas_call"}',
-        '%c = f32[8] fusion(%z), metadata={op_name="ff_fused_ce_fwd"}',
+        'metadata={op_name="jit(step)/ff_dequant_attention/pallas_call"}',
+        '%c = f32[8] fusion(%z), metadata={op_name="ff_dequant_attention"}',
         "%d = f32[8] all-reduce(%c), replica_groups={}",
         "%e = f32[8] all-gather-start(%d)",
     ])
     # %c names the kernel but is no Mosaic call: it does not count
-    assert cs.kernels_in(text) == {"flash_attention": 1, "fused_ce": 1,
-                                   "dequant_attention": 0}
+    assert cs.kernels_in(text) == {"flash_attention": 1,
+                                   "dequant_attention": 1}
     got = cs.collectives_in(text)
     assert got["all-reduce"] == 1 and got["all-gather"] == 1
 

@@ -410,22 +410,19 @@ def test_remat_and_fused_kernel_flags_wired():
     """The ISSUE-12 MFU knobs flow parse_args -> FFConfig via
     build_parser only (launcher value-flag coverage is derived):
     --remat-search/--remat-policies select the searched-remat dimension,
-    --fused-loss gates the fused cross-entropy, and the
-    deprecated --remat alias survives but cannot combine with the
+    and the deprecated --remat alias survives but cannot combine with the
     search."""
     from flexflow_tpu.config import FFConfig as Cfg
 
     cfg = Cfg.parse_args(["--remat-search", "--remat-policies",
-                          "none,dots", "--fused-loss", "on"])
+                          "none,dots"])
     assert cfg.remat_search is True
     assert cfg.remat_policies == "none,dots"
     assert cfg.remat_policy_list() == ("none", "dots")
-    assert cfg.fused_loss == "on"
-    # defaults: remat fully off, the fused loss in auto mode
+    # defaults: remat fully off
     d = Cfg()
     assert (d.remat, d.remat_search) == (False, False)
     assert d.remat_policy_list() == ("none", "dots", "full")
-    assert d.fused_loss == "auto"
     # deprecated alias still parses on its own
     assert Cfg.parse_args(["--remat"]).remat is True
     # ...but contradicts the searched dimension, loudly
@@ -434,9 +431,4 @@ def test_remat_and_fused_kernel_flags_wired():
     # unknown policy names fail at construction, not deep in the DP
     with pytest.raises(ValueError, match="unknown remat policies"):
         Cfg.parse_args(["--remat-policies", "none,sometimes"])
-    # mode flags are choice-constrained
-    with pytest.raises(SystemExit):
-        Cfg.parse_args(["--fused-loss", "maybe"])
-    vf = Cfg.launcher_value_flags()
-    for flag in ("--remat-policies", "--fused-loss"):
-        assert flag in vf, flag
+    assert "--remat-policies" in Cfg.launcher_value_flags()

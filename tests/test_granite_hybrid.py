@@ -33,12 +33,13 @@ from flexflow_tpu.dtype import DataType  # noqa: E402
 from flexflow_tpu.models import GraniteHybridConfig, build_granite_hybrid  # noqa: E402
 from flexflow_tpu.ops import get_op_def, moe_ops, ssm_ops  # noqa: E402
 from flexflow_tpu.ops.op_type import OperatorType  # noqa: E402
-from flexflow_tpu.ops.registry import STATS_KEY, LoweringCtx  # noqa: E402
+from flexflow_tpu.ops.registry import LoweringCtx  # noqa: E402
 from flexflow_tpu.serving import (ContinuousBatchingScheduler, Request,  # noqa: E402
                                   compile_serving, valid_prompt_inputs,
                                   valid_step_inputs)
 from families import granitemoehybrid as family  # noqa: E402
 from harness import reference_granitemoehybrid as reference  # noqa: E402
+from served import Served  # noqa: E402
 
 RTOL = 1e-4
 SLOTS = 4
@@ -365,67 +366,19 @@ def engine_for(g, **compile_kw):
     return eng
 
 
-class Served:
-    """Drives engine.prefill / engine.decode_step and the state manager by
-    hand, keeps each slot's tokens, and holds every logit row that comes
-    out against the reference's full forward over the slot's tokens."""
+def served(g):
+    """The shared harness on this family's engine, input builders and
+    reference; a step reports the routed pairs as one number."""
+    eng = engine_for(g)
+    cfg = file_config(g)
+    ref, hp = family.reference_params(eng.params, cfg), family.hyper(cfg)
 
-    def __init__(self, g):
-        self.g, self.eng = g, engine_for(g)
-        cfg = file_config(g)
-        self.ref = family.reference_params(self.eng.params, cfg)
-        self.hp = family.hyper(cfg)
-        self.seqs = {}
-        self.checked = 0
+    def step_stats(s, stats):
+        assert stats["moe_routed_pairs"].shape == ()
 
-    def check(self, slot, logits_row):
-        ids = np.asarray([self.seqs[slot]], np.int32)
-        want = np.asarray(reference.forward(self.ref, ids, self.hp))[0, -1]
-        assert close(logits_row, want), (slot, len(self.seqs[slot]))
-        self.checked += 1
-
-    def wave(self, prompts):
-        """Prefill {slot: prompt} as one padded wave; the other slots sit
-        it out (length 0)."""
-        kv = self.eng.kv
-        ids = np.zeros((SLOTS, self.g.seq), np.int32)
-        lengths = np.zeros(SLOTS, np.int32)
-        for slot, prompt in prompts.items():
-            kv.admit(slot, len(prompt), len(prompt) + 16)
-            ids[slot, :len(prompt)] = prompt
-            lengths[slot] = len(prompt)
-            self.seqs[slot] = list(prompt)
-        kv.push()
-        logits, kv_state = self.eng.prefill(
-            self.eng.params, valid_prompt_inputs(ids, lengths))
-        kv.commit_prefill(kv_state, np.arange(SLOTS, dtype=np.int32), lengths)
-        logits = np.asarray(logits)
-        for slot, prompt in prompts.items():
-            self.check(slot, logits[slot, len(prompt) - 1])
-            self.seqs[slot].append(int(logits[slot, len(prompt) - 1].argmax()))
-
-    def decode(self, steps):
-        kv = self.eng.kv
-        for _ in range(steps):
-            nxt = np.zeros((SLOTS, 1), np.int32)
-            for slot, seq in self.seqs.items():
-                nxt[slot, 0] = seq[-1]
-            state = kv.state
-            logits, state = self.eng.decode_step(
-                self.eng.params, state,
-                valid_step_inputs(jnp.asarray(nxt), state))
-            assert state.pop(STATS_KEY)["moe_routed_pairs"].shape == ()
-            kv.adopt(state)
-            kv.sync_after(1)
-            logits = np.asarray(logits)
-            for slot in self.seqs:
-                self.check(slot, logits[slot, 0])
-                self.seqs[slot].append(int(logits[slot, 0].argmax()))
-
-    def evict(self, slot):
-        self.eng.kv.evict(slot)
-        self.eng.kv.push()
-        del self.seqs[slot]
+    return Served(eng, lambda ids: reference.forward(ref, ids, hp),
+                  valid_prompt_inputs, valid_step_inputs, RTOL,
+                  step_stats=step_stats)
 
 
 @pytest.mark.parametrize("layer_types", [
@@ -443,7 +396,7 @@ def test_prefill_then_decode_through_the_cache_equals_the_full_forward(layer_typ
     g = dataclasses.replace(GraniteHybridConfig.tiny(seq=48),
                             layer_types=layer_types)
     rng = np.random.default_rng(7)
-    s = Served(g)
+    s = served(g)
 
     def prompt(n):
         return [int(t) for t in rng.integers(0, g.vocab, n)]

@@ -63,7 +63,7 @@ def test_ff_launch_args_env(monkeypatch):
     """FFConfig.parse_args absorbs the kernel's FF_LAUNCH_ARGS only on real
     CLI invocations (argv=None); CLI flags override the environment, and an
     explicit programmatic argv is never silently altered by the env
-    (ADVICE r5: a kernelspec-installed env var must not leak into
+    (a kernelspec-installed env var must not leak into
     tests/scripts that pass their own argv)."""
     import sys
 

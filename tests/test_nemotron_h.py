@@ -43,7 +43,7 @@ from flexflow_tpu.models import (DeepseekV3Config, GPT2Config,  # noqa: E402
                                  build_granite_hybrid, build_nemotron_h)
 from flexflow_tpu.ops import get_op_def, moe_ops, ssm_ops  # noqa: E402
 from flexflow_tpu.ops.op_type import OperatorType  # noqa: E402
-from flexflow_tpu.ops.registry import STATS_KEY, LoweringCtx  # noqa: E402
+from flexflow_tpu.ops.registry import LoweringCtx  # noqa: E402
 from flexflow_tpu.search.cost_model import KVCacheSpec  # noqa: E402
 from flexflow_tpu.search.strategy_cache import graph_fingerprint  # noqa: E402
 from flexflow_tpu.serving import (ContinuousBatchingScheduler, Request,  # noqa: E402
@@ -55,6 +55,7 @@ from families import nemotron_h as family  # noqa: E402
 from harness import flops_nemotron_h as flops  # noqa: E402
 from harness import manifest as mf  # noqa: E402
 from harness import reference_nemotron_h as reference  # noqa: E402
+from served import Served  # noqa: E402
 
 RTOL = 1e-4
 SLOTS = 4
@@ -587,68 +588,19 @@ def engine_for(g, **compile_kw):
     return eng
 
 
-class Served:
-    """Drives engine.prefill / engine.decode_step and the cache by hand,
-    keeps each slot's tokens, and holds every logit row that comes out
-    against the reference's full forward over the slot's tokens."""
+def served(g):
+    """The shared harness on this family's engine, input builders and
+    reference."""
+    eng = engine_for(g)
 
-    def __init__(self, g):
-        self.g, self.eng = g, engine_for(g)
-        self.seqs = {}
-        self.checked = 0
+    def step_stats(s, stats):
+        # two state-holding layers, the live slots' state read and written
+        per_slot = eng.kv_spec.state_bytes_per_slot
+        assert float(stats["ssm_state_bytes"]) == 2 * len(s.seqs) * per_slot
 
-    def check(self, slot, logits_row):
-        ids = np.asarray([self.seqs[slot]], np.int32)
-        want = np.asarray(reference_logits(self.eng.params, self.g, ids))[0, -1]
-        assert close(logits_row, want), (slot, len(self.seqs[slot]))
-        self.checked += 1
-
-    def wave(self, prompts):
-        """Prefill {slot: prompt} as one padded wave; the other slots sit
-        it out (length 0)."""
-        kv = self.eng.kv
-        ids = np.zeros((SLOTS, self.g.seq), np.int32)
-        lengths = np.zeros(SLOTS, np.int32)
-        for slot, prompt in prompts.items():
-            kv.admit(slot, len(prompt), len(prompt) + 16)
-            ids[slot, :len(prompt)] = prompt
-            lengths[slot] = len(prompt)
-            self.seqs[slot] = list(prompt)
-        kv.push()
-        logits, kv_state = self.eng.prefill(
-            self.eng.params, valid_prompt_inputs(ids, lengths))
-        kv_state.pop(STATS_KEY)
-        kv.commit_prefill(kv_state, np.arange(SLOTS, dtype=np.int32), lengths)
-        logits = np.asarray(logits)
-        for slot, prompt in prompts.items():
-            self.check(slot, logits[slot, len(prompt) - 1])
-            self.seqs[slot].append(int(logits[slot, len(prompt) - 1].argmax()))
-
-    def decode(self, steps):
-        kv = self.eng.kv
-        for _ in range(steps):
-            nxt = np.zeros((SLOTS, 1), np.int32)
-            for slot, seq in self.seqs.items():
-                nxt[slot, 0] = seq[-1]
-            state = kv.state
-            logits, state = self.eng.decode_step(
-                self.eng.params, state,
-                valid_step_inputs(jnp.asarray(nxt), state))
-            stats = state.pop(STATS_KEY)
-            # two state-holding layers, the live slots' state read and written
-            per_slot = self.eng.kv_spec.state_bytes_per_slot
-            assert float(stats["ssm_state_bytes"]) == 2 * len(self.seqs) * per_slot
-            kv.adopt(state)
-            kv.sync_after(1)
-            logits = np.asarray(logits)
-            for slot in self.seqs:
-                self.check(slot, logits[slot, 0])
-                self.seqs[slot].append(int(logits[slot, 0].argmax()))
-
-    def evict(self, slot):
-        self.eng.kv.evict(slot)
-        self.eng.kv.push()
-        del self.seqs[slot]
+    return Served(eng, lambda ids: reference_logits(eng.params, g, ids),
+                  valid_prompt_inputs, valid_step_inputs, RTOL,
+                  step_stats=step_stats)
 
 
 @pytest.mark.parametrize("heads", (8, 32), ids=("4_a_kv_head", "16_a_kv_head"))
@@ -663,7 +615,7 @@ def test_prefill_then_decode_through_cache_and_state_equals_the_full_forward(
     g = NemotronHConfig.tiny(seq=48)
     g.heads = heads
     rng = np.random.default_rng(7)
-    s = Served(g)
+    s = served(g)
 
     def prompt(n):
         return [int(t) for t in rng.integers(0, g.vocab, n)]

@@ -162,16 +162,34 @@ def test_optimizer_update_matches_a_plain_reference(opt):
                                    atol=2e-5 if bf16 else 2e-6)
 
 
-def test_the_fused_optimizer_option_is_refused_by_name():
-    """The Pallas update kernel and its option are gone (PR 31). A command
-    line that still carries the flag must not pass it by as one of the
+@pytest.mark.parametrize("gone", [
+    "--fused-optimizer", "--fused-loss", "FFConfig(fused_loss='on')",
+    "GPT2Config(vocab_pad_to=128)"])
+def test_the_fused_optimizer_option_is_refused_by_name(gone):
+    """The Pallas update kernel (PR 31), the fused cross-entropy kernel and
+    the padded vocabulary it needed (PR 46) are gone with their options. A
+    command line that still carries a flag must not pass it by as one of the
     user script's (`parse_known_args` would, and the launcher would then
-    take its value for the script's path)."""
-    with pytest.raises(SystemExit, match="--fused-optimizer"):
-        FFConfig.parse_args(["--fused-optimizer", "off"])
-    with pytest.raises(SystemExit, match="--fused-optimizer"):
-        FFConfig.parse_args(["-b", "8", "--fused-optimizer=on"])
-    with pytest.raises(TypeError, match="fused_optimizer"):
-        FFConfig(fused_optimizer="off")
-    assert "--fused-optimizer" not in FFConfig.launcher_value_flags()
-    assert FFConfig.parse_args(["--fused-loss", "on"]).fused_loss == "on"
+    take its value for the script's path); a field that is gone is a
+    TypeError by construction."""
+    from flexflow_tpu.models.gpt2 import GPT2Config
+
+    fields = {"FFConfig(fused_loss='on')": (FFConfig, "fused_loss", "on"),
+              "GPT2Config(vocab_pad_to=128)": (GPT2Config, "vocab_pad_to", 128)}
+    if gone in fields:
+        cls, field, value = fields[gone]
+        with pytest.raises(TypeError, match=field):
+            cls(**{field: value})
+        return
+    with pytest.raises(SystemExit, match=f"{gone} is gone"):
+        FFConfig.parse_args([gone, "off"])
+    with pytest.raises(SystemExit, match=f"{gone} is gone"):
+        FFConfig.parse_args(["-b", "8", f"{gone}=on"])
+    assert gone not in FFConfig.launcher_value_flags()
+    if gone == "--fused-optimizer":
+        with pytest.raises(TypeError, match="fused_optimizer"):
+            FFConfig(fused_optimizer="off")
+    else:
+        # what takes the flag's place is said in one sentence
+        with pytest.raises(SystemExit, match="always the optax form"):
+            FFConfig.parse_args([gone, "on"])

@@ -258,7 +258,7 @@ def _fork_join_model(batch):
 
 
 def test_inter_candidates_gated_on_batch_sharding():
-    """ADVICE r5: batch 6 on data=4 cannot shard the batch, and inter:
+    """Batch 6 on data=4 cannot shard the batch, and inter:
     placement's backward fails at trace time under a replicated batch — the
     search must not offer what compile cannot run."""
     fj6 = next(l for l in _fork_join_model(6).layers
