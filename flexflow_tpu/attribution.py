@@ -476,9 +476,13 @@ def instructions_in_scope(hlo_text: str, scope: str) -> "set[str]":
     """Names of the instructions of one compiled program's optimized HLO
     text that run as events of their own under the `jax.named_scope`
     `scope` (a whole segment of the name stack): an instruction whose own
-    `op_name` holds it, a fusion most of whose named body does, and what
-    the compiler made (no name stack) inside a loop or branch that does.
-    Containers themselves are left out (their bodies are counted)."""
+    `op_name` holds it, a fusion most of whose named body does, what the
+    compiler made (no name stack) inside a loop or branch that does, and
+    what the chip's compiler made OF a matrix product that does (a
+    ragged-dot becomes a Mosaic call named `ragged-dot-none.N` with no name
+    stack: it is under the scope where an instruction it reads, or one that
+    reads it, is). Containers themselves are left out (their bodies are
+    counted)."""
     comps, entry = _parse_computations(hlo_text)
     if entry is None:
         return set()
@@ -501,8 +505,17 @@ def instructions_in_scope(hlo_text: str, scope: str) -> "set[str]":
         if comp in seen or comp not in comps:
             continue
         seen.add(comp)
+        found = {i.name: under(i, inherited) for i in comps[comp]}
+        users: Dict[str, list] = {}
         for i in comps[comp]:
-            inside = under(i, inherited)
+            for o in i.operands:
+                users.setdefault(o, []).append(i.name)
+        for i in comps[comp]:
+            inside = found[i.name]
+            if not inside and not i.op_name and i.opcode == "custom-call" \
+                    and i.name.startswith(_DOT_KERNELS):
+                inside = any(found.get(n) for n in
+                             users.get(i.name, []) + list(i.operands))
             if i.opcode in CONTAINERS or i.opcode.endswith("-start"):
                 todo.extend((c, inside) for c in _called(i.rest))
             elif inside and i.opcode not in _NO_EVENT:

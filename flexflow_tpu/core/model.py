@@ -132,14 +132,22 @@ class FFModel:
                             decode: bool = False, kv_out: bool = False,
                             num_kv_heads: int = 0,
                             scale: Optional[float] = None,
+                            positions: Optional[Tensor] = None,
+                            rope_theta: Optional[float] = None,
+                            qk_norm: Optional[float] = None,
+                            initializers: Optional[Dict[str, Any]] = None,
                             name=None) -> Tensor:
         # decode: single-token serving step reading/writing the paged KV
         # cache via lowering state; kv_out: prefill variant that exposes
         # per-head K/V for cache commit (flexflow_tpu/serving).
         # num_kv_heads: grouped-query attention (0 = one K/V head a query
-        # head); scale: the factor on q k^T (None = 1/sqrt(head_dim)). Both
-        # enter the params only where set, so graphs without them keep
-        # their fingerprints.
+        # head); scale: the factor on q k^T (None = 1/sqrt(head_dim));
+        # positions `[batch, seq]` int: rotary positions, q and k turned
+        # over the whole head (rotate-half) at rope_theta; qk_norm: an RMS
+        # norm a head on q and on k before the rotation, the value its eps
+        # (weights q_norm, k_norm [head_dim]). All enter the params (and
+        # the inputs) only where set, so graphs without them keep their
+        # fingerprints.
         params = {"embed_dim": int(embed_dim), "num_heads": int(num_heads), "kdim": kdim,
                   "vdim": vdim, "dropout": dropout, "bias": bias, "add_bias_kv": add_bias_kv,
                   "add_zero_attn": add_zero_attn, "causal": causal, "impl": impl,
@@ -148,11 +156,18 @@ class FFModel:
             params["num_kv_heads"] = int(num_kv_heads)
         if scale is not None:
             params["scale"] = float(scale)
+        if positions is not None:
+            params["rope_theta"] = float(10000.0 if rope_theta is None
+                                         else rope_theta)
+        if qk_norm is not None:
+            params.update(qk_norm=True, qk_norm_eps=float(qk_norm))
         return self._add_layer(
             OperatorType.MULTIHEAD_ATTENTION, params,
-            [query, key, value], name,
-            {"wq": kernel_initializer, "wk": kernel_initializer, "wv": kernel_initializer,
-             "wo": kernel_initializer})[0]
+            [query, key, value] + ([positions] if positions is not None else []),
+            name,
+            dict({"wq": kernel_initializer, "wk": kernel_initializer,
+                  "wv": kernel_initializer, "wo": kernel_initializer},
+                 **(initializers or {})))[0]
 
     # elementwise ---------------------------------------------------------
     def _unary(self, op, input, name=None, **params) -> Tensor:
@@ -286,6 +301,19 @@ class FFModel:
              "d_conv": int(d_conv), "lower_bound": float(lower_bound),
              "eps": eps},
             ins, name, initializers)[0]
+
+    def short_conv(self, input: Tensor, kernel: int = 3,
+                   valid: Optional[Tensor] = None,
+                   initializers: Optional[Dict[str, Any]] = None,
+                   name=None) -> Tensor:
+        """Gated short convolution over `[batch, seq, d]`: a depthwise
+        causal convolution of `kernel` taps between two elementwise gates
+        and two projections (ops/short_conv_ops.py). `valid` `[batch, seq]`
+        int: which positions hold a token."""
+        ins = [input] + ([valid] if valid is not None else [])
+        return self._add_layer(OperatorType.SHORT_CONV,
+                               {"kernel": int(kernel)}, ins, name,
+                               initializers)[0]
 
     def power_retention(self, input: Tensor, positions: Tensor, heads: int,
                         kv_heads: int, head_dim: int,
@@ -438,14 +466,17 @@ class FFModel:
                   routed_scaling_factor: Optional[float] = None,
                   score_bias: bool = False,
                   expert_activation: Optional[str] = None,
-                  latent_size: int = 0, name=None) -> Tensor:
+                  latent_size: int = 0,
+                  gate_norm_eps: Optional[float] = None,
+                  name=None) -> Tensor:
         """Dropless top-k layer of gated-SiLU experts over `[batch, seq,
         d]`, routed over all `num_experts`, computing those in
         `experts_held = (lo, hi)` (default: all); ops/moe_ops.py. How it
         chooses and gates beyond top-k + softmax (`scoring` "sigmoid", the
         choice limited to `topk_group` of `n_group` groups, gates
-        normalised and scaled, a `score_bias` weight for the selection;
-        `moe_ops._choose`) and what its experts are beyond gated SiLU at
+        normalised (over their sum + `gate_norm_eps`) and scaled, a
+        `score_bias` weight for the selection; `moe_ops._choose`) and what
+        its experts are beyond gated SiLU at
         the layer's own width (`expert_activation` "relu2": un-gated
         squared ReLU; `latent_size`: the experts work in a latent of that
         width, between two projections of the layer) enters the params
@@ -469,6 +500,8 @@ class FFModel:
             params["expert_activation"] = str(expert_activation)
         if latent_size:
             params["latent_size"] = int(latent_size)
+        if gate_norm_eps is not None:
+            params["gate_norm_eps"] = float(gate_norm_eps)
         return self._add_layer(OperatorType.MOE_LAYER, params, ins, name,
                                initializers)[0]
 

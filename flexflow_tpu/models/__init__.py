@@ -18,6 +18,7 @@ from flexflow_tpu.models.nemotron_h import NemotronHConfig, build_nemotron_h
 from flexflow_tpu.models.bailing_hybrid import (BailingHybridConfig,
                                                 build_bailing_hybrid)
 from flexflow_tpu.models.brumby import BrumbyConfig, build_brumby
+from flexflow_tpu.models.lfm2_moe import Lfm2MoeConfig, build_lfm2_moe
 from flexflow_tpu.models.bert import build_bert
 from flexflow_tpu.models.moe import build_moe_mlp
 from flexflow_tpu.models.inception import build_inception_v3
@@ -35,4 +36,5 @@ __all__ = [
     "build_nemotron_h", "NemotronHConfig",
     "build_bailing_hybrid", "BailingHybridConfig",
     "build_brumby", "BrumbyConfig",
+    "build_lfm2_moe", "Lfm2MoeConfig",
 ]

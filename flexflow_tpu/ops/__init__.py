@@ -25,6 +25,7 @@ from flexflow_tpu.ops import (  # noqa: F401
     latent_attention_ops,
     kda_ops,
     power_retention_ops,
+    short_conv_ops,
     parallel_ops,
     fork_join,
 )

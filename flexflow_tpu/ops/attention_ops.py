@@ -10,6 +10,19 @@ the MXU; a fused pallas flash-attention kernel
 Head-parallel tensor parallelism (reference substitutions
 create_partition_attention_combine, src/runtime/substitution.cc:1763-1770) is
 expressed by sharding the per-head projection weights on a model axis.
+
+Inputs: query, key, value `[batch, seq, features]`, and optionally a fourth,
+`positions` `[batch, seq]` int: rotary positions, q and k turned over the
+whole head in the rotate-half layout (ops/rotary.py) at the layer's
+`rope_theta`. With `qk_norm` in the params each head of q and of k is
+RMS-normed over its head_dim first, with one learned weight each (`q_norm`,
+`k_norm` `[head_dim]`, eps `qk_norm_eps`): norm, then rotation, then the
+scores. All three forms do it (the whole sequence; the prefill twin, whose
+`kv_out` hands out k AFTER norm and rotation, so that is what the pool
+holds; the paged decode twin, which norms and rotates the step's q and k at
+the slot's position before the append). Both enter a layer's params only
+where a model sets them: a layer without them lowers to the program it
+lowered to before they existed.
 """
 
 from __future__ import annotations
@@ -26,8 +39,10 @@ if TYPE_CHECKING:
     from flexflow_tpu.core.layer import Layer
 from flexflow_tpu.core.tensor import TensorSpec
 from flexflow_tpu.kernels.partition import dividing, multi_device, per_shard
+from flexflow_tpu.ops.norm_ops import rms_norm
 from flexflow_tpu.ops.op_type import OperatorType
 from flexflow_tpu.ops.registry import register_op, LoweringCtx
+from flexflow_tpu.ops.rotary import apply_rope_half, half_tables
 
 
 def _mha_infer(layer: Layer):
@@ -62,7 +77,37 @@ def _mha_infer(layer: Layer):
     if p.get("add_bias_kv", False):
         layer.weight_specs["bias_k"] = TensorSpec((embed,), q.dtype)
         layer.weight_specs["bias_v"] = TensorSpec((embed,), q.dtype)
+    if _positioned(layer) and (p.get("add_bias_kv") or p.get("add_zero_attn")):
+        raise NotImplementedError("rotary positions or a q/k norm with "
+                                  "add_bias_kv/add_zero_attn")
+    if p.get("qk_norm"):
+        layer.weight_specs["q_norm"] = TensorSpec((embed // heads,), q.dtype)
+        layer.weight_specs["k_norm"] = TensorSpec((embed // heads,), q.dtype)
     return [q.with_shape(q.shape[:-1] + (embed,))]
+
+
+def _positioned(layer: Layer) -> bool:
+    """Whether q and k are normed or rotated before the scores."""
+    return len(layer.inputs) > 3 or bool(layer.params.get("qk_norm"))
+
+
+def _turner(layer: Layer, inputs, weights):
+    """`turn(heads [b, s, h, d], norm weight's name)`: the per-head RMS norm
+    where the layer has one, then the rotation at `positions` (the fourth
+    input) where it has them."""
+    p = layer.params
+    tables = None
+    if len(inputs) > 3:
+        hd = p["embed_dim"] // p["num_heads"]
+        cos, sin = half_tables(inputs[3], hd, p.get("rope_theta", 10000.0))
+        tables = cos[:, :, None], sin[:, :, None]           # [b, s, 1, d]
+
+    def turn(heads, norm):
+        if p.get("qk_norm"):
+            heads = rms_norm(heads, weights[norm], p.get("qk_norm_eps", 1e-6))
+        return heads if tables is None else apply_rope_half(heads, *tables)
+
+    return turn
 
 
 def _kv_heads(p) -> int:
@@ -137,6 +182,9 @@ def _mha_decode_lower(layer: Layer, inputs, weights, ctx: LoweringCtx):
     qh = _split_heads(proj(inputs[0], "wq", "bq"), heads)  # (slots, s, h, d)
     kh = _split_heads(proj(inputs[1], "wk", "bk"), kvh)    # (slots, s, kvh, d)
     vh = _split_heads(proj(inputs[2], "wv", "bv"), kvh)
+    if _positioned(layer):      # at the slot's position, before the append
+        turn = _turner(layer, inputs, weights)
+        qh, kh = turn(qh, "q_norm"), turn(kh, "k_norm")
     if kvh != heads and "k_scale" in ctx.state[layer.name]:
         raise NotImplementedError("grouped K/V heads with a quantized cache")
 
@@ -304,12 +352,16 @@ def _mha_lower(layer: Layer, inputs, weights, ctx: LoweringCtx):
 
     kp = proj(k, "wk", "bk")
     vp = proj(v, "wv", "bv")
+    turn = _turner(layer, inputs, weights) if _positioned(layer) else None
     if p.get("kv_out", False):
         # serving prefill: expose the per-head K/V of the prompt tokens so
         # the engine can commit them into the paged cache (captured BEFORE
-        # any bias_kv/zero_attn positions could pollute the cache)
-        ctx.new_state[layer.name] = {"k": _split_heads(kp, kvh),
-                                     "v": _split_heads(vp, kvh)}
+        # any bias_kv/zero_attn positions could pollute the cache; k as the
+        # scores see it: after its norm and rotation)
+        k_out = _split_heads(kp, kvh)
+        if turn is not None:
+            k_out = turn(k_out, "k_norm")
+        ctx.new_state[layer.name] = {"k": k_out, "v": _split_heads(vp, kvh)}
     if "bias_k" in weights:  # add_bias_kv: learned extra kv position
         b_ = k.shape[0]
         kp = jnp.concatenate([kp, jnp.broadcast_to(weights["bias_k"].astype(dt), (b_, 1, embed))], axis=1)
@@ -321,6 +373,9 @@ def _mha_lower(layer: Layer, inputs, weights, ctx: LoweringCtx):
     qh = _split_heads(proj(q, "wq", "bq"), heads)  # (b, sq, h, d)
     kh = _split_heads(kp, kvh)
     vh = _split_heads(vp, kvh)
+    if turn is not None:    # k once: what the prefill twin handed out
+        qh = turn(qh, "q_norm")
+        kh = k_out if p.get("kv_out", False) else turn(kh, "k_norm")
     if kvh != heads:
         # each K/V head once per query head of its group: the kernels and
         # the einsum below then see one K/V head a query head
@@ -425,9 +480,21 @@ def _mha_page_state(layer: Layer) -> dict:
             "head_dim": int(p["embed_dim"]) // int(p["num_heads"])}
 
 
+def _mha_span_facts(layer: Layer) -> dict:
+    """What a layer with positions or a q/k norm says of them on the serving
+    compile span; nothing for a layer without."""
+    p = layer.params
+    facts = {}
+    if len(layer.inputs) > 3:
+        facts["rope_theta"] = float(p.get("rope_theta", 10000.0))
+    if p.get("qk_norm"):
+        facts["qk_norm"] = True
+    return facts
+
+
 register_op(OperatorType.MULTIHEAD_ATTENTION, _mha_infer, _mha_lower, _mha_flops,
             serving_params=_mha_serving_params, state_kind="paged_kv",
-            page_state=_mha_page_state)
+            page_state=_mha_page_state, span_facts=_mha_span_facts)
 
 
 def _sdpa_infer(layer: Layer):

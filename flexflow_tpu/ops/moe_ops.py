@@ -2,7 +2,9 @@
 
 Reference analog: src/ops/{group_by.cc (534), aggregate.cc (569),
 aggregate_spec.cc (519), cache.cc (291)} — dynamic CUDA scatter/gather kernels.
-XLA needs static shapes, so the TPU-native design uses **capacity-factor
+XLA needs static shapes, so the reference framework's three ops (`GROUP_BY` /
+`EXPERTS` / `AGGREGATE`; NOT `MOE_LAYER` further down, which is dropless and
+has no capacity factor) use **capacity-factor
 routing** (the standard TPU MoE recipe): group_by emits a dense
 (n_experts, capacity, d) dispatch buffer + per-(token, choice) positions with
 overflow drops; `experts` is a batched per-expert dense (einsum over the expert
@@ -211,7 +213,8 @@ def _choose(scores, weights, p):
       consecutive ids; a group's score is the sum of its two largest
       selection scores, and only the `topk_group` best groups' experts can
       be chosen;
-    - `norm_topk_prob`: the k gates are divided by their sum;
+    - `norm_topk_prob`: the k gates are divided by their sum plus
+      `gate_norm_eps` (1e-20 where the layer's params name none);
     - `routed_scaling_factor`: and multiplied by this."""
     k = p["top_k"]
     sigmoid = p.get("scoring") == "sigmoid"
@@ -236,7 +239,8 @@ def _choose(scores, weights, p):
         picked = jnp.take_along_axis(own, experts, axis=-1)
         gate = picked if sigmoid else jax.nn.softmax(picked, axis=-1)
     if p.get("norm_topk_prob"):
-        gate = gate / (jnp.sum(gate, axis=-1, keepdims=True) + 1e-20)
+        gate = gate / (jnp.sum(gate, axis=-1, keepdims=True)
+                       + p.get("gate_norm_eps", 1e-20))
     if p.get("routed_scaling_factor") is not None:     # 0 is a factor too
         gate = gate * float(p["routed_scaling_factor"])
     return gate, experts
@@ -252,6 +256,13 @@ def _row_capacities(pairs: int):
     return ([0] + parts if parts else []) + [pairs]
 
 
+# the `jax.named_scope` around the experts' own work (the two grouped
+# products and the activation between them), in every expert layer of a
+# program: one name selects them all (`attribution.instructions_under`),
+# where a layer's own scope is its name
+EXPERTS_SCOPE = "ff_moe_experts"
+
+
 def _experts(rows, sizes, weights, p):
     """The experts over `rows` sorted by expert, `sizes[e]` of them on held
     expert e: one grouped product in, the activation in f32, one out. Rows
@@ -262,13 +273,14 @@ def _experts(rows, sizes, weights, p):
     multiplied."""
     dt = rows.dtype
     width = p["expert_width"]
-    ab = jax.lax.ragged_dot(rows, weights["w_in"].astype(dt), sizes)
-    if p.get("expert_activation") == "relu2":
-        mid = jnp.square(jax.nn.relu(ab.astype(jnp.float32))).astype(dt)
-    else:
-        mid = (jax.nn.silu(ab[:, :width].astype(jnp.float32))
-               * ab[:, width:].astype(jnp.float32)).astype(dt)
-    return jax.lax.ragged_dot(mid, weights["w_out"].astype(dt), sizes)
+    with jax.named_scope(EXPERTS_SCOPE):
+        ab = jax.lax.ragged_dot(rows, weights["w_in"].astype(dt), sizes)
+        if p.get("expert_activation") == "relu2":
+            mid = jnp.square(jax.nn.relu(ab.astype(jnp.float32))).astype(dt)
+        else:
+            mid = (jax.nn.silu(ab[:, :width].astype(jnp.float32))
+                   * ab[:, width:].astype(jnp.float32)).astype(dt)
+        return jax.lax.ragged_dot(mid, weights["w_out"].astype(dt), sizes)
 
 
 def _all_rows(xt, gate, held, order, sizes, weights, p):
@@ -327,10 +339,11 @@ def _through_latent(rows_fn):
     return rows
 
 
-def _route_tokens(xt, exists, weights, p):
+def _route_tokens(xt, exists, weights, p, told_what_exists: bool):
     """One block of tokens `[tokens, d]` through the routed layer: (this
     holder's part of the output `[tokens, d]`, rows on each held expert
-    `[held]`, rows the grouped product was sized for)."""
+    `[held]`, rows the grouped product was sized for). `told_what_exists`:
+    the layer has its `valid` input, so `exists` may name fewer than all."""
     k = p["top_k"]
     lo, hi = p["experts_held"]
     held_n = hi - lo
@@ -343,10 +356,12 @@ def _route_tokens(xt, exists, weights, p):
     local = jnp.where(held, experts - lo, held_n).reshape(-1)  # absent: last
     order = jnp.argsort(local, stable=True)
     sizes = jnp.bincount(local, length=held_n + 1)[:held_n].astype(jnp.int32)
-    # a holder of every expert has a row for every pair of an existing
-    # token, and a block of a few rows has nothing to save: no ladder
-    caps = _row_capacities(tokens * k) if held_n < p["num_experts"] \
-        else [tokens * k]
+    # a block's rows are the pairs that are held here AND exist: the ladder
+    # is worth its conditional where either can leave some out. A holder of
+    # every expert that is told of no absent token has a row for every
+    # pair, and a block of a few rows has nothing to save: no ladder
+    caps = _row_capacities(tokens * k) \
+        if held_n < p["num_experts"] or told_what_exists else [tokens * k]
     wrap = _through_latent if "latent_size" in p else (lambda rows_fn: rows_fn)
     whole = wrap(functools.partial(_all_rows, p=p))
     if len(caps) == 1:
@@ -358,6 +373,11 @@ def _route_tokens(xt, exists, weights, p):
                else _no_rows for cap in caps[:-1]] + [whole],
         xt, gate, held, order, sizes, weights)
     return y, sizes, jnp.asarray(caps, jnp.int32)[rung]
+
+
+def _report_experts_held(ctx, held_n: int) -> None:
+    """`moe_experts_held`: what `moe_experts_hit` is a share of."""
+    ctx.add_stat("moe_experts_held", jnp.int32(held_n))
 
 
 def _moe_layer_lower(layer: Layer, inputs, weights, ctx):
@@ -381,14 +401,19 @@ def _moe_layer_lower(layer: Layer, inputs, weights, ctx):
     `_row_capacities(tokens * k)` that holds the block's held pairs, chosen
     by a `lax.switch` on their count, so the layer's cost follows the rows
     held here and not the static `tokens * k`. The last rung is the whole
-    block: no capacity factor, no drops, whatever the routing. A holder of
-    every expert, and a block too small for a smaller rung (a decode step),
-    lower with no conditional. The optional second input `valid`
-    `[batch, seq]` names the tokens that exist; the others are not routed.
+    block: no capacity factor, no drops, whatever the routing. The rows a
+    block needs are the pairs that are held here AND exist, so the ladder
+    is there wherever either can leave pairs out: for a holder of a part
+    of the experts, and for any holder that has the optional second input
+    `valid` `[batch, seq]`, which names the tokens that exist (the others
+    are not routed; a padded prefill wave is mostly such). A holder of
+    every expert without `valid`, and a block too small for a smaller rung
+    (a decode step), lower with no conditional.
 
     Reports (ctx.add_stat): moe_routed_pairs, moe_held_pairs, moe_load_max
     (rows on the fullest held expert), moe_load_mean (held pairs over
     experts held), moe_experts_hit (held experts with a row),
+    moe_experts_held (experts held: what moe_experts_hit could reach),
     moe_rows_static (`tokens * k`) and moe_rows_computed (the rungs taken,
     summed over blocks: equal to moe_rows_static where there is no
     ladder)."""
@@ -397,17 +422,18 @@ def _moe_layer_lower(layer: Layer, inputs, weights, ctx):
     b, s, d = x.shape
     tokens = b * s
     xt = x.reshape(tokens, d)
-    exists = jnp.ones((tokens, 1), bool) if len(inputs) < 2 \
+    told = len(inputs) > 1
+    exists = jnp.ones((tokens, 1), bool) if not told \
         else inputs[1].reshape(tokens, 1) > 0
     if tokens > MOE_TOKEN_BLOCK and tokens % MOE_TOKEN_BLOCK == 0:
         blocks = tokens // MOE_TOKEN_BLOCK
         y, sizes, computed = jax.lax.map(
-            lambda block: _route_tokens(block[0], block[1], weights, p),
+            lambda block: _route_tokens(block[0], block[1], weights, p, told),
             (xt.reshape(blocks, MOE_TOKEN_BLOCK, d),
              exists.reshape(blocks, MOE_TOKEN_BLOCK, 1)))
         sizes, computed = jnp.sum(sizes, axis=0), jnp.sum(computed)
     else:
-        y, sizes, computed = _route_tokens(xt, exists, weights, p)
+        y, sizes, computed = _route_tokens(xt, exists, weights, p, told)
     n_held = jnp.sum(sizes)
     ctx.add_stat("moe_routed_pairs",
                  jnp.sum(exists).astype(jnp.int32) * p["top_k"])
@@ -416,6 +442,7 @@ def _moe_layer_lower(layer: Layer, inputs, weights, ctx):
     ctx.add_stat("moe_load_mean",
                  n_held.astype(jnp.float32) / sizes.shape[0])
     ctx.add_stat("moe_experts_hit", jnp.sum(sizes > 0).astype(jnp.int32))
+    _report_experts_held(ctx, sizes.shape[0])
     ctx.add_stat("moe_rows_static", jnp.int32(tokens * p["top_k"]))
     ctx.add_stat("moe_rows_computed", computed)
     return [y.reshape(b, s, d)]

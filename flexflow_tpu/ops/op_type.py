@@ -108,6 +108,9 @@ class OperatorType(enum.Enum):
     # linear attention with a degree-2 power kernel over a gated recurrent
     # state a K/V head (power retention; ops/power_retention_ops.py)
     POWER_RETENTION = "power_retention"
+    # gated short convolution (a depthwise causal convolution of a few
+    # taps between two gates and two projections; ops/short_conv_ops.py)
+    SHORT_CONV = "short_conv"
     # fused compute op (reference: src/ops/fused.cc)
     FUSED = "fused"
     # inter-op placement composite (reference: nonsequence splits,
@@ -145,6 +148,7 @@ WEIGHTED_OPS = frozenset(
         OperatorType.LATENT_ATTENTION,
         OperatorType.KDA,
         OperatorType.POWER_RETENTION,
+        OperatorType.SHORT_CONV,
         OperatorType.FORK_JOIN,
     }
 )

@@ -113,8 +113,10 @@ class OpDef:
     state_kind: Optional[str] = None
     page_state: Optional[Callable[[Layer], Dict[str, int]]] = None
     slot_state: Optional[Callable[[Layer], Dict[str, Any]]] = None
-    # span_facts(layer) -> what a "recurrent" layer says of its state's
-    # layout on the serving compile span ({"ssm_groups": n}); None: nothing
+    # span_facts(layer) -> what a layer that carries state says of it on the
+    # serving compile span (a "recurrent" layer of its state's layout,
+    # {"ssm_groups": n}; a paged one of what the pool's rows went through,
+    # {"rope_theta": t}); None, or an empty dict: nothing
     span_facts: Optional[Callable[[Layer], Dict[str, Any]]] = None
     # weights that keep their own type under a compute_dtype (a selection
     # bias whose size is that of the gaps it decides)

@@ -404,8 +404,9 @@ def _layer_candidates(layer: "Layer", machine: MachineSpec, batch_sizes,
             for b in ("bq", "bk", "bv"):
                 if b in repl_w:
                     wd[b] = [m]
-            if "bo" in repl_w:
-                wd["bo"] = [None]
+            for w in ("bo", "q_norm", "k_norm"):    # one a layer, or a head
+                if w in repl_w:
+                    wd[w] = [None]
             out_bytes = cm.shard_bytes(ospecs[0], dp_out[0], machine)
             embed = layer.params["embed_dim"]
             cands.append(Candidate(
@@ -425,6 +426,7 @@ def _layer_candidates(layer: "Layer", machine: MachineSpec, batch_sizes,
                 not layer.params.get("add_zero_attn") and \
                 not layer.params.get("dropout") and \
                 layer.params.get("impl", "auto") != "xla" and \
+                len(ispecs) == 3 and \
                 seq == seq_k == ispecs[2].shape[1]:
             for m in maxes:
                 dm = machine.mesh_axes[m]

@@ -232,9 +232,10 @@ def compile_serving(model, max_batch_slots: Optional[int] = None,
                 experts_held=hi - lo, experts_routed_over=p["num_experts"],
                 expert_layers=len(expert_layers),
                 experts_latent_dim=p.get("latent_size", 0))
-        if recurrent:
-            # what the first recurrent layer's op says of its state's layout
-            first = dec_model.get_layer_by_name(next(iter(recurrent)))
+        # what the first paged and the first recurrent layer's ops say of
+        # their state (its layout, what a pool's rows went through)
+        for name in attn[:1] + list(recurrent)[:1]:
+            first = dec_model.get_layer_by_name(name)
             facts = get_op_def(first.op_type).span_facts
             if facts is not None:
                 compile_span.set(**facts(first))

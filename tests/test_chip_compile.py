@@ -855,6 +855,65 @@ def _assert_appends_in_place(program, eng, staged_at_most=2):
     assert program.memory_analysis().alias_size_in_bytes >= held
 
 
+def test_lfm2_moe_serving_programs_fit_one_chip(described_devices, mosaic,
+                                                one_chip, monkeypatch):
+    """`LFM2-24B-A2B.serve-longanswer`'s two programs at the cell's own sizes
+    (16 slots, width 1024, 10.62 GB of bf16 weights: every one of the 64
+    experts of all eight expert layers; of 9 layers 2 page K/V 512 wide and
+    7 keep a convolution state of 8 KB a slot), through the normal entry
+    points: the chip's compiler must hold the prefill wave and the decode
+    step beside the weights and the cache (arguments + temporaries under 15
+    GB), attention must go through the flash kernel and the experts through
+    the grouped product, the wave's expert layers must carry the ladder's
+    conditional (a whole-holder with `valid`), the decode step none, and the
+    decode step appends to the pools it was handed."""
+    eng, g, params, state = _described_engine(
+        "LFM2-24B-A2B.serve-longanswer", described_devices, monkeypatch,
+        one_chip)
+    slots = eng.slots
+    spec = eng.kv_spec
+    assert (spec.layers, spec.heads, spec.head_dim) == (2, 8, 64)
+    assert spec.state_bytes_per_slot == 7 * 2 * 2048 * 2
+    assert len(eng.attn_layers) == 2 and len(eng.kv.recurrent) == 7
+    assert eng.kv.state_kinds == "paged_kv+recurrent"
+    moe = [l for l in eng.decode_model.layers if l.op_type.value == "moe_layer"]
+    assert len(moe) == 8
+    assert all(l.params["experts_held"] == (0, 64) for l in moe)
+    pool = eng.kv.state[eng.attn_layers[0]]["k"]
+    assert pool.shape == (slots * 96 + 1, 16, 512)
+    weights = sum(x.size * x.dtype.itemsize
+                  for x in jax.tree_util.tree_leaves(params))
+    assert weights == 2 * 5312168704 + 2 * 8 * 64    # the f32 selection biases
+    three = [_i32(one_chip, slots, 1)] * 3
+    decode = eng._decode_jit.lower(params, state, three).compile()
+    wave = [_i32(one_chip, slots, g.seq)] * 3
+    prefill = eng._prefill_first_tokens_jit.lower(
+        params, wave, _i32(one_chip, slots)).compile()
+    chip = 15.75e9          # what the compiler has of a v5e chip's 16 GB
+    held = sum(x.size * x.dtype.itemsize
+               for x in jax.tree_util.tree_leaves(state))
+    assert 0.10e9 < held < 0.11e9
+    for program, beside in ((decode, 0), (prefill, held)):
+        m = program.memory_analysis()
+        assert 10.6e9 < m.argument_size_in_bytes
+        need = (m.argument_size_in_bytes + m.output_size_in_bytes
+                - m.alias_size_in_bytes + m.temp_size_in_bytes + beside)
+        assert need < 15e9 < chip, (need, m)
+    text = prefill.as_text()
+    assert "ragged-dot" in text and "tpu_custom_call" in text
+    assert " conditional(" in text
+    assert "ragged-dot" in decode.as_text()
+    assert " conditional(" not in decode.as_text()
+    # what the chip's compiler makes of the ragged products has no name
+    # stack: the scope takes it by its neighbours (two calls a layer)
+    from flexflow_tpu import attribution
+    from flexflow_tpu.ops.moe_ops import EXPERTS_SCOPE
+    under = attribution.instructions_in_scope(decode.as_text(), EXPERTS_SCOPE)
+    assert sum(n.startswith("ragged-dot-none") for n in under) == 16
+    # two layers' K and V pools: the compiler stages all four (as GigaChat's)
+    _assert_appends_in_place(decode, eng, staged_at_most=4)
+
+
 def test_gpt2_medium_decode_and_commit_append_in_place(described_devices,
                                                        one_chip, monkeypatch):
     """`gpt2-medium.serve-chat`'s decode step and prefill commit at the

@@ -123,7 +123,7 @@ def route_blocks(routing, weights, x, valid, blocks):
     for b in range(blocks):
         xt = jnp.asarray(x[b * ROWS:(b + 1) * ROWS].reshape(BLOCK, D))
         ex = jnp.asarray(valid[b * ROWS:(b + 1) * ROWS].reshape(BLOCK, 1) > 0)
-        _y, sizes, rows = moe_ops._route_tokens(xt, ex, w, p)
+        _y, sizes, rows = moe_ops._route_tokens(xt, ex, w, p, True)
         out.append((np.asarray(sizes), int(rows)))
     return out
 
@@ -271,15 +271,15 @@ def test_trace_report_prints_the_rows_computed_beside_the_held_share():
 
 
 # ------------------------------------------------------------------ lowering
-def lowered(routing, held, tokens_shape):
+def lowered(routing, held, tokens_shape, told=True):
     """StableHLO of the layer alone over `[batch, seq, D]`, as a training
-    graph lowers it (no counters)."""
+    graph lowers it (no counters); `told`: with its `valid` input."""
     layer = layer_of(routing, held)
     weights = held_weights(make_weights(routing, rigged=False), held)
 
     def f(x, valid, w):
         return get_op_def(OperatorType.MOE_LAYER).lower(
-            layer, [x, valid], w, LoweringCtx())[0]
+            layer, [x, valid] if told else [x], w, LoweringCtx())[0]
 
     b, s = tokens_shape
     return jax.jit(f).lower(jnp.zeros((b, s, D), jnp.float32),
@@ -294,16 +294,20 @@ def conditionals(text):
 @pytest.mark.parametrize("case", ("every_expert_held", "a_decode_step"))
 def test_where_the_ladder_cannot_help_the_program_is_the_one_without_it(
         routing, case, monkeypatch):
-    """A holder of every expert (every training graph of the repo) and a
-    block of a few rows (the decode step: 16 tokens) lower with no
-    conditional, to the very text the layer lowers to with no ladder."""
+    """A holder of every expert that is told of no absent token (no `valid`
+    input: `build_moe_mlp`'s graphs) and a block of a few rows (the decode
+    step: 16 tokens) lower with no conditional, to the very text the layer
+    lowers to with no ladder. Since PR 47 a holder of every expert WITH
+    `valid` gets the ladder: its rows follow the tokens that exist."""
     r = ROUTINGS[routing]
-    held, shape = ((0, EXPERTS), (4, 512)) if case == "every_expert_held" \
-        else (HELD, (16, 1))
-    text = lowered(r, held, shape)
+    whole = case == "every_expert_held"
+    held, shape = ((0, EXPERTS), (4, 512)) if whole else (HELD, (16, 1))
+    text = lowered(r, held, shape, told=not whole)
     assert conditionals(text) == 0
+    if whole:
+        assert conditionals(lowered(r, held, shape)) == 1
     monkeypatch.setattr(moe_ops, "MOE_ROW_RUNGS", ())
-    assert text == lowered(r, held, shape)
+    assert text == lowered(r, held, shape, told=not whole)
 
 
 def test_a_partial_holder_of_a_long_input_gets_one_conditional():

@@ -260,7 +260,9 @@ def expert_layer_lines(events: List[Dict[str, Any]]) -> List[str]:
     rows the grouped product's buffers were sized for of the static
     `tokens * k` (100 % where no block took a smaller rung); for decode
     steps of a model with state-space layers, a second line with the
-    recurrent state moved and the held experts hit, a step."""
+    recurrent state moved and the held experts hit, a step; where the layers
+    report `moe_experts_held`, a line with the share of the held experts
+    that received a row."""
     sums: Dict[str, Dict[str, float]] = {}
     for ev in events:
         args = ev.get("args") or {}
@@ -280,6 +282,12 @@ def expert_layer_lines(events: List[Dict[str, Any]]) -> List[str]:
                      f"{100.0 * a.get('moe_rows_computed', 0) / a['moe_rows_static']:.2f}% "
                      f"of {int(a['moe_rows_static'])} static")
         lines.append(line)
+        if a.get("moe_experts_held"):
+            lines.append(
+                f"[serve] expert layers in {name}: "
+                f"{100.0 * a.get('moe_experts_hit', 0) / a['moe_experts_held']:.1f}% "
+                f"of the held experts hit of {int(a['moe_experts_held'])} "
+                "held (layers and steps summed)")
         for counter, kind in STATE_COUNTERS.items():
             if a.get(counter) and a.get("steps"):
                 lines.append(
