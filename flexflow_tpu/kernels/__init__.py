@@ -13,6 +13,10 @@ retention_step — one decode step of power retention for the live slots: the
 update of a slot's state and the float32 read-out of the new state on one
 tile, the state read from HBM once and written once in place
 (ops/power_retention_ops.py chooses it where a head is whole 128-lane slabs).
+moe_step — one decode step's routed experts: each hit expert's two matrices
+streamed once over all of the step's (at most 16) rows, the gated sum kept on
+the chip (forward; ops/moe_ops.py chooses it from the block's shapes and
+gives it the grouped product's gradient).
 """
 
 from flexflow_tpu.kernels.dequant_attention import (  # noqa: F401

@@ -30,6 +30,7 @@ if TYPE_CHECKING:
     from flexflow_tpu.core.layer import Layer
 from flexflow_tpu.core.tensor import TensorSpec
 from flexflow_tpu.dtype import DataType
+from flexflow_tpu.kernels.partition import multi_device
 from flexflow_tpu.ops.op_type import OperatorType
 from flexflow_tpu.ops.registry import register_op
 from flexflow_tpu.ops.activations import apply_activation
@@ -195,8 +196,12 @@ MOE_TOKEN_BLOCK = 4096
 # serving cells; an all-empty wave 6.9 -> 5.3)
 MOE_ROW_RUNGS = (16, 4)
 # and no rung is smaller than this: the grouped product works on tiles of
-# 128 rows, so a smaller buffer computes no fewer. A decode step's 128 or
-# 160 pairs get no ladder; 80 tokens x top-8 get [0, 160, 640] and lose
+# 128 rows, so a smaller buffer computes no fewer. A decode step's 64-352
+# pairs get no ladder, and where its widths allow no grouped product
+# either: top-k picks distinct experts, so none of its groups holds more
+# rows than the step has tokens (16, one bf16 sublane tile), and the hit
+# experts are streamed once over all of them (`_step_tile`, `_route_step`).
+# 80 tokens x top-8 (a verifier's block) get [0, 160, 640] and lose
 # nothing by it (2.45 -> 2.15 ms)
 MOE_MIN_RUNG_ROWS = 128
 
@@ -270,7 +275,10 @@ def _experts(rows, sizes, weights, p):
     middle `expert_width`. Gated SiLU, `silu(a) * b` with `[a | b]` the
     product in, unless the layer's `expert_activation` is "relu2":
     `relu(a)^2`, no gate matrix. Rows past the last group are not
-    multiplied."""
+    multiplied. The form of every block but a decode step's, of a decode
+    step's where a width is not whole 128-lane slabs (`_step_tile`), and of
+    the step kernel's backward; `kernels/moe_step.py` makes the same
+    products with the same roundings of `ab` and `mid`."""
     dt = rows.dtype
     width = p["expert_width"]
     with jax.named_scope(EXPERTS_SCOPE):
@@ -325,18 +333,41 @@ def _held_rows(cap, xt, gate, held, order, sizes, weights, p):
 
 
 def _through_latent(rows_fn):
-    """`rows_fn` (`_all_rows` or a `_held_rows`) for a layer whose experts
-    work in a latent: the block's tokens are projected into it, the row
-    buffers (gather, products, gate, combine) are latent-wide, and this
-    holder's combined part is projected back. Both projections are linear
-    and bias-free, so the holders' parts still add up to the whole layer."""
-    def rows(xt, gate, held, order, sizes, weights):
-        dt = xt.dtype
-        y = rows_fn(xt @ weights["w_latent_in"].astype(dt), gate, held,
-                    order, sizes, weights)
+    """`rows_fn` (`_all_rows`, a `_held_rows` or `_step_rows`: the block's
+    rows, what routing says of them, last the weights) for a layer whose
+    experts work in a latent: the block's tokens are projected into it,
+    the row buffers (gather, products, gate, combine) are latent-wide, and
+    this holder's combined part is projected back. Both projections are
+    linear and bias-free, so the holders' parts still add up to the whole
+    layer."""
+    def rows(xt, *routing_and_weights):
+        dt, weights = xt.dtype, routing_and_weights[-1]
+        y = rows_fn(xt @ weights["w_latent_in"].astype(dt),
+                    *routing_and_weights)
         return y @ weights["w_latent_out"].astype(dt)
 
     return rows
+
+
+def _in_experts_width(rows_fn, p):
+    """`rows_fn` as the layer's experts work: through the latent where the
+    layer has one, as it is elsewhere."""
+    return _through_latent(rows_fn) if "latent_size" in p else rows_fn
+
+
+def _routing(xt, exists, weights, p):
+    """What the router says of a block's (token, choice) pairs: (gates
+    `[tokens, k]` f32, which pairs are held here and exist `[tokens, k]`,
+    each pair's held expert `[tokens * k]`, counted from this holder's
+    first; an absent pair's is `held`, one past the last)."""
+    lo, hi = p["experts_held"]
+    scores = jnp.dot(xt.astype(jnp.float32),
+                     weights["router"].astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    gate, experts = _choose(scores, weights, p)                # [tokens, k]
+    held = (experts >= lo) & (experts < hi) & exists
+    local = jnp.where(held, experts - lo, hi - lo).reshape(-1)  # absent: last
+    return gate, held, local
 
 
 def _route_tokens(xt, exists, weights, p, told_what_exists: bool):
@@ -348,12 +379,7 @@ def _route_tokens(xt, exists, weights, p, told_what_exists: bool):
     lo, hi = p["experts_held"]
     held_n = hi - lo
     tokens, _d = xt.shape
-    scores = jnp.dot(xt.astype(jnp.float32),
-                     weights["router"].astype(jnp.float32),
-                     precision=jax.lax.Precision.HIGHEST)
-    gate, experts = _choose(scores, weights, p)                # [tokens, k]
-    held = (experts >= lo) & (experts < hi) & exists
-    local = jnp.where(held, experts - lo, held_n).reshape(-1)  # absent: last
+    gate, held, local = _routing(xt, exists, weights, p)
     order = jnp.argsort(local, stable=True)
     sizes = jnp.bincount(local, length=held_n + 1)[:held_n].astype(jnp.int32)
     # a block's rows are the pairs that are held here AND exist: the ladder
@@ -362,7 +388,7 @@ def _route_tokens(xt, exists, weights, p, told_what_exists: bool):
     # pair, and a block of a few rows has nothing to save: no ladder
     caps = _row_capacities(tokens * k) \
         if held_n < p["num_experts"] or told_what_exists else [tokens * k]
-    wrap = _through_latent if "latent_size" in p else (lambda rows_fn: rows_fn)
+    wrap = functools.partial(_in_experts_width, p=p)
     whole = wrap(functools.partial(_all_rows, p=p))
     if len(caps) == 1:
         return (whole(xt, gate, held, order, sizes, weights), sizes,
@@ -375,9 +401,98 @@ def _route_tokens(xt, exists, weights, p, told_what_exists: bool):
     return y, sizes, jnp.asarray(caps, jnp.int32)[rung]
 
 
+def _step_forward(xt, gate, local, ids, count, w_in, w_out, relu2, tn):
+    from flexflow_tpu.kernels import moe_step
+
+    held = jnp.arange(w_in.shape[0], dtype=local.dtype)[:, None, None]
+    # an expert's gate of each token, 0 where the token did not choose it
+    # (or is not live, or the expert is not held): compare and sum
+    gates = jnp.sum(jnp.where(local.reshape((1,) + gate.shape) == held,
+                              gate[None], 0.0), axis=2)      # [held, tokens]
+    return moe_step.moe_step(ids, count, xt, gates, w_in, w_out, relu2, tn)
+
+
+def _step_backward(relu2, tn, operands, ct):
+    """The grouped-product form's: `_all_rows` over the same pairs,
+    recomputed."""
+    xt, gate, local, _ids, _count, w_in, w_out = operands
+    held_n = w_in.shape[0]
+    p = {"expert_width": w_out.shape[1],
+         "expert_activation": "relu2" if relu2 else None}
+
+    held = (local < held_n).reshape(gate.shape)
+    order = jnp.argsort(local, stable=True)
+    sizes = jnp.bincount(local, length=held_n + 1)[:held_n].astype(jnp.int32)
+
+    def grouped(xt, gate, w_in, w_out):
+        return _all_rows(xt, gate, held, order, sizes,
+                         {"w_in": w_in, "w_out": w_out},
+                         p).astype(jnp.float32)
+
+    d_xt, d_gate, d_in, d_out = jax.vjp(grouped, xt, gate, w_in, w_out)[1](ct)
+    return d_xt, d_gate, None, None, None, d_in, d_out
+
+
+_step_kernel = jax.custom_vjp(_step_forward, nondiff_argnums=(7, 8))
+_step_kernel.defvjp(lambda *args: (_step_forward(*args), args[:7]),
+                    _step_backward)
+
+
+def _step_rows(tn, xt, gate, local, ids, count, weights, p):
+    """A decode step's block through the held experts it hits, as one
+    kernel (`kernels/moe_step.py`): no pair is sorted, no row gathered, and
+    a token's experts are summed in f32 on the chip, in expert order."""
+    dt = xt.dtype
+    with jax.named_scope(EXPERTS_SCOPE):
+        y = _step_kernel(xt, gate, local, ids, count,
+                         weights["w_in"].astype(dt),
+                         weights["w_out"].astype(dt),
+                         p.get("expert_activation") == "relu2", tn)
+    return y.astype(dt)
+
+
+def _step_tile(tokens: int, d: int, itemsize: int, p):
+    """The kernel's tile for a block that is a decode step, None for every
+    other block. From what the block's shapes say: no ladder and at most a
+    sublane tile's 16 tokens (top-k picks distinct experts, so no expert's
+    group then holds more than 16 rows: the speculative verifier's `[slots,
+    1 + draft]` blocks and a wave's do), and widths in whole 128-lane slabs
+    (`moe_step.tile_width`; the tiny test configurations' are not)."""
+    from flexflow_tpu.kernels import moe_step
+
+    if len(_row_capacities(tokens * p["top_k"])) > 1:
+        return None
+    return moe_step.tile_width(tokens, p.get("latent_size", d),
+                               p["expert_width"], _in_parts(p), itemsize)
+
+
+def _route_step(xt, exists, weights, p, tn: int):
+    """`_route_tokens` for a block that `_step_tile` gave a tile: (this
+    holder's part of the output, rows on each held expert, the hit experts:
+    the kernel's grid steps on its first axis)."""
+    from flexflow_tpu.kernels import moe_step
+
+    held_n = weights["w_in"].shape[0]
+    gate, _held, local = _routing(xt, exists, weights, p)
+    # by compare and sum over the block's few pairs: no scatter
+    sizes = jnp.sum(
+        local[None] == jnp.arange(held_n, dtype=local.dtype)[:, None],
+        axis=1, dtype=jnp.int32)
+    ids, count = moe_step.hit_experts(sizes, local.shape[0])
+    rows = _in_experts_width(functools.partial(_step_rows, tn, p=p), p)
+    return rows(xt, gate, local, ids, count, weights), sizes, count
+
+
 def _report_experts_held(ctx, held_n: int) -> None:
     """`moe_experts_held`: what `moe_experts_hit` is a share of."""
     ctx.add_stat("moe_experts_held", jnp.int32(held_n))
+
+
+def _report_step_kernel(ctx, experts) -> None:
+    """`moe_step_kernel_experts`: the kernel's grid steps on its first axis
+    (equal to `moe_experts_hit` where it ran), 0 where the block took the
+    grouped product."""
+    ctx.add_stat("moe_step_kernel_experts", experts)
 
 
 def _moe_layer_lower(layer: Layer, inputs, weights, ctx):
@@ -393,9 +508,22 @@ def _moe_layer_lower(layer: Layer, inputs, weights, ctx):
     and gates as the layer's params say (`_choose`). Each (token,
     choice) whose expert is held here is computed; the others contribute
     nothing, so the result is this holder's part of the layer's output
-    (the parts of all holders add up to the whole layer). Pairs are sorted
-    by expert, absent ones behind the last group, and the held ones' rows
-    multiplied as one grouped product (`jax.lax.ragged_dot`). The row
+    (the parts of all holders add up to the whole layer).
+
+    Two forms, chosen from the block's shapes (`_step_tile`; no flag). A
+    DECODE STEP (no ladder, at most 16 tokens, the experts' K and width in
+    whole 128-lane slabs, one device): top-k picks distinct experts, so an
+    expert's group holds at most the step's 16 rows, one bf16 sublane tile,
+    and nothing is sorted, gathered or combined: `kernels/moe_step.py` streams
+    each hit expert's two matrices once over ALL the step's rows and adds
+    `gate * out` into one f32 result on the chip, the gate 0 where a token
+    did not choose the expert (`_route_step`; per-expert rows and gates by
+    compare-and-sum over the `tokens * k` pairs; the backward is the
+    grouped form's, a `custom_vjp`). EVERY OTHER BLOCK (a wave's 4096
+    tokens, a verifier's `[slots, 1 + draft]`, a tiny model's widths):
+    pairs are sorted by expert, absent ones behind the last group, and the
+    held ones' rows multiplied as one grouped product
+    (`jax.lax.ragged_dot`). The row
     buffers (gather, products, f32 gate, combine) are sized per block of
     `MOE_TOKEN_BLOCK` tokens at run time: the smallest rung of
     `_row_capacities(tokens * k)` that holds the block's held pairs, chosen
@@ -408,7 +536,7 @@ def _moe_layer_lower(layer: Layer, inputs, weights, ctx):
     `valid` `[batch, seq]`, which names the tokens that exist (the others
     are not routed; a padded prefill wave is mostly such). A holder of
     every expert without `valid`, and a block too small for a smaller rung
-    (a decode step), lower with no conditional.
+    (a decode step in either form), lower with no conditional.
 
     Reports (ctx.add_stat): moe_routed_pairs, moe_held_pairs, moe_load_max
     (rows on the fullest held expert), moe_load_mean (held pairs over
@@ -416,7 +544,9 @@ def _moe_layer_lower(layer: Layer, inputs, weights, ctx):
     moe_experts_held (experts held: what moe_experts_hit could reach),
     moe_rows_static (`tokens * k`) and moe_rows_computed (the rungs taken,
     summed over blocks: equal to moe_rows_static where there is no
-    ladder)."""
+    ladder), moe_step_kernel_experts (the step kernel's grid steps on its
+    first axis: moe_experts_hit where it ran, 0 where the block took the
+    grouped product)."""
     x = inputs[0]
     p = layer.params
     b, s, d = x.shape
@@ -425,7 +555,15 @@ def _moe_layer_lower(layer: Layer, inputs, weights, ctx):
     told = len(inputs) > 1
     exists = jnp.ones((tokens, 1), bool) if not told \
         else inputs[1].reshape(tokens, 1) > 0
-    if tokens > MOE_TOKEN_BLOCK and tokens % MOE_TOKEN_BLOCK == 0:
+    # on a mesh of several devices the grouped product stays: GSPMD
+    # partitions it, and cannot partition a Mosaic call
+    tn = None if multi_device(ctx.mesh) \
+        else _step_tile(tokens, d, x.dtype.itemsize, p)
+    kernel_experts = jnp.int32(0)
+    if tn is not None:
+        y, sizes, kernel_experts = _route_step(xt, exists, weights, p, tn)
+        computed = jnp.int32(tokens * p["top_k"])
+    elif tokens > MOE_TOKEN_BLOCK and tokens % MOE_TOKEN_BLOCK == 0:
         blocks = tokens // MOE_TOKEN_BLOCK
         y, sizes, computed = jax.lax.map(
             lambda block: _route_tokens(block[0], block[1], weights, p, told),
@@ -445,6 +583,7 @@ def _moe_layer_lower(layer: Layer, inputs, weights, ctx):
     _report_experts_held(ctx, sizes.shape[0])
     ctx.add_stat("moe_rows_static", jnp.int32(tokens * p["top_k"]))
     ctx.add_stat("moe_rows_computed", computed)
+    _report_step_kernel(ctx, kernel_experts)
     return [y.reshape(b, s, d)]
 
 

@@ -32,7 +32,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)  # chip_smoke
 
 KERNEL_MODULES = ("flash_attention", "dequant_attention", "ssd_scan",
-                  "kda_scan", "retention_step")
+                  "kda_scan", "retention_step", "moe_step")
 
 # GPT-2 medium: batch 8, 16 heads of 64, seq 1024
 B, H, S, D = 8, 16, 1024, 64
@@ -567,7 +567,6 @@ def test_granite_serving_programs_fit_one_chip(described_devices, mosaic,
     assert prefill.memory_analysis().temp_size_in_bytes < 2.5e9
     text = prefill.as_text()
     assert "ragged-dot" in text and "tpu_custom_call" in text
-    assert "ragged-dot" in decode.as_text()
     _assert_appends_in_place(decode, eng)
 
 
@@ -616,7 +615,7 @@ def test_gigachat_serving_programs_fit_one_chip(described_devices, mosaic,
     assert len(re.findall(r" conditional\(", text)) >= 5
     assert prefill.memory_analysis().temp_size_in_bytes <= 3.27e9
     text = decode.as_text()
-    assert "ragged-dot" in text and " conditional(" not in text
+    assert " conditional(" not in text
     # the decompressed K/V of a slot's context: [.., 1280, 64, 320] or merged
     assert not re.search(r"\[16,1280,(64,320|20480|64,128|64,192|8192|12288)\]",
                          text)
@@ -674,8 +673,6 @@ def test_nemotron_serving_programs_fit_one_chip(described_devices, mosaic,
     text = prefill.as_text()
     assert "ragged-dot" in text and "tpu_custom_call" in text
     assert len(re.findall(r" conditional\(", text)) >= 5
-    text = decode.as_text()
-    assert "ragged-dot" in text
     _assert_appends_in_place(decode, eng)
 
 
@@ -731,8 +728,6 @@ def test_ling_serving_programs_fit_one_chip(described_devices, mosaic,
                           r'"tpu_custom_call"[^\n]*ff_kda_chunk_scan',
                           text)) == 6
     assert len(re.findall(r" conditional\(", text)) >= 6
-    text = decode.as_text()
-    assert "ragged-dot" in text
     _assert_appends_in_place(decode, eng)
 
 
@@ -863,9 +858,10 @@ def test_lfm2_moe_serving_programs_fit_one_chip(described_devices, mosaic,
     7 keep a convolution state of 8 KB a slot), through the normal entry
     points: the chip's compiler must hold the prefill wave and the decode
     step beside the weights and the cache (arguments + temporaries under 15
-    GB), attention must go through the flash kernel and the experts through
-    the grouped product, the wave's expert layers must carry the ladder's
-    conditional (a whole-holder with `valid`), the decode step none, and the
+    GB), attention must go through the flash kernel and the wave's experts
+    through the grouped product, the wave's expert layers must carry the
+    ladder's conditional (a whole-holder with `valid`), the decode step none
+    (its experts are the step kernel: the test after this one), and the
     decode step appends to the pools it was handed."""
     eng, g, params, state = _described_engine(
         "LFM2-24B-A2B.serve-longanswer", described_devices, monkeypatch,
@@ -902,16 +898,71 @@ def test_lfm2_moe_serving_programs_fit_one_chip(described_devices, mosaic,
     text = prefill.as_text()
     assert "ragged-dot" in text and "tpu_custom_call" in text
     assert " conditional(" in text
-    assert "ragged-dot" in decode.as_text()
     assert " conditional(" not in decode.as_text()
-    # what the chip's compiler makes of the ragged products has no name
-    # stack: the scope takes it by its neighbours (two calls a layer)
+    # the step's experts are the kernel, once a layer, under the scope that
+    # `moe_experts_roofline.decode.lfm2` reads
     from flexflow_tpu import attribution
     from flexflow_tpu.ops.moe_ops import EXPERTS_SCOPE
     under = attribution.instructions_in_scope(decode.as_text(), EXPERTS_SCOPE)
-    assert sum(n.startswith("ragged-dot-none") for n in under) == 16
+    assert sum(n.startswith("ff_moe_step") for n in under) == 8
     # two layers' K and V pools: the compiler stages all four (as GigaChat's)
     _assert_appends_in_place(decode, eng, staged_at_most=4)
+
+
+MOE_CELLS = {   # cell: (inputs of its programs, expert layers, a tile's tn)
+    "granite-4.0-h-small.serve-chat": (2, 10, 768),
+    "GigaChat3.1-702B-A36B.serve-chat": (3, 5, 512),
+    "NVIDIA-Nemotron-3-Super-120B-A12B-BF16.serve-chat": (2, 5, 2688),
+    "Ling-3.0-flash.serve-chat": (3, 6, 768),
+    "LFM2-24B-A2B.serve-longanswer": (3, 8, 1536)}
+
+
+@pytest.mark.parametrize("cell", list(MOE_CELLS))
+def test_a_decode_steps_experts_are_the_step_kernel(cell, described_devices,
+                                                    mosaic, one_chip,
+                                                    monkeypatch):
+    """Each expert family's decode program at its cell's sizes, through the
+    chip's compiler: every expert layer's held experts are one Mosaic call
+    `ff_moe_step` under `ff_moe_experts` (the tile as
+    `moe_step.tile_width` sizes it for the served widths), no grouped
+    product is left in the step, and none of the pair sort, the row gather
+    and the combine's gathers under an expert layer's scope (the router's
+    `top_k` is the one sort there). The prefill program, whose blocks are
+    4096 tokens, lowers to the grouped product as it did (asked of its
+    StableHLO: the family's own test compiles it)."""
+    from flexflow_tpu import attribution
+    from flexflow_tpu.kernels import moe_step
+    from flexflow_tpu.ops import moe_ops
+
+    inputs, layers, tn = MOE_CELLS[cell]
+    eng, g, params, state = _described_engine(cell, described_devices,
+                                              monkeypatch, one_chip)
+    slots = eng.slots
+    moe = [l for l in eng.decode_model.layers
+           if l.op_type.value == "moe_layer"]
+    assert len(moe) == layers
+    p = moe[0].params
+    assert moe_ops._step_tile(slots, moe[0].inputs[0].spec.shape[-1], 2,
+                              p) == tn
+    text = eng._decode_jit.lower(
+        params, state, [_i32(one_chip, slots, 1)] * inputs).compile().as_text()
+    calls = re.findall(r' custom-call\([^\n]*custom_call_target='
+                       r'"tpu_custom_call"[^\n]*ff_moe_step', text)
+    assert len(calls) == layers
+    assert "ragged-dot" not in text
+    under = attribution.instructions_in_scope(text, moe_ops.EXPERTS_SCOPE)
+    assert sum(n.startswith("ff_moe_step") for n in under) == layers
+    # what sorts, gathers or scatters under an expert layer's own scope is
+    # the router's choice (`_choose`: `top_k`, the take of the chosen scores)
+    layer_scopes = "|".join(re.escape(l.name) for l in moe)
+    for _op, what in re.findall(
+            r' (sort|gather|scatter)\([^\n]*op_name="[^"\n]*/(?:%s)/([^"\n]*)"'
+            % layer_scopes, text):
+        assert what in ("top_k", "jit(take_along_axis)/gather"), what
+    wave = eng._prefill_first_tokens_jit.lower(
+        params, [_i32(one_chip, slots, g.seq)] * inputs,
+        _i32(one_chip, slots)).as_text()
+    assert "ragged_dot" in wave and "ff_moe_step" not in wave
 
 
 def test_gpt2_medium_decode_and_commit_append_in_place(described_devices,

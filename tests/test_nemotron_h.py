@@ -812,6 +812,8 @@ def test_the_other_models_serving_programs_lower_to_the_parents(name, want,
     # and PR 47's `moe_experts_held`, a constant a layer beside the seven:
     # with the report taken out the programs lower to the pinned text
     monkeypatch.setattr(moe_ops, "_report_experts_held", lambda *a: None)
+    # and PR 48's `moe_step_kernel_experts`, 0 in every block of these
+    monkeypatch.setattr(moe_ops, "_report_step_kernel", lambda *a: None)
     build, inputs = BUILDERS[name]
     model = FFModel(FFConfig(batch_size=4, seed=3, strategy_cache=False,
                              log_level="warning", mesh_shape={"data": 1}))
