@@ -17,6 +17,11 @@ moe_step — one decode step's routed experts: each hit expert's two matrices
 streamed once over all of the step's (at most 16) rows, the gated sum kept on
 the chip (forward; ops/moe_ops.py chooses it from the block's shapes and
 gives it the grouped product's gradient).
+mamba2_step — one decode step of the Mamba-2 recurrence for the live slots:
+the update of a slot's state and the float32 read-out of the new state on one
+tile, the state read from HBM once and written once in place, a slot that is
+not live never touched (ops/ssm_ops.py chooses it where a head's state is
+whole f32 tiles and a B/C group's heads whole sublane tiles).
 """
 
 from flexflow_tpu.kernels.dequant_attention import (  # noqa: F401

@@ -32,7 +32,8 @@ Three forms of one op, chosen by `params["mode"]`:
 - "decode" (serving decode): one step of the recurrence on
   `ctx.state[layer.name]`, written back to `ctx.new_state`. Reports
   (ctx.add_stat) `ssm_state_bytes`: the state the step's live slots read
-  and wrote (both leaves, twice).
+  and wrote (both leaves, twice), and `ssm_step_kernel_slots`: the slots
+  the step kernel took (below; 0 where the step took the XLA form).
 
 The sequence forms are one algorithm at every size, its form chosen from the
 shapes alone (`scan_path`, reported a lowered layer by the `ssm/scan_path`
@@ -58,7 +59,26 @@ wave therefore hands out each row's state after its LAST REAL token, and a
 decode step advances only the slots that `valid` names. Without the input
 every position is real.
 
-The projections, the conv and the decode step are plain XLA (jax.numpy).
+The decode step's recurrence (`ssm_step`: the update of the state and the
+read-out `S C` of the new one) has two forms too, chosen from the shapes
+alone (`step_path`, reported a lowered layer by the `ssm/step_path` span):
+
+- the kernel (`kernels/mamba2_step.py`, `ff_mamba2_step`) where a head's
+  `[P, N]` f32 state is whole tiles (N whole 128-lane slabs, P whole sublane
+  tiles: the served widths), a B/C group's heads are whole sublane tiles (a
+  turn of the kernel's loop takes eight heads with one B and one C) and the
+  program runs on one device (GSPMD cannot partition a Mosaic call): a
+  grid over (live slot, block of heads) whose first bound is the live count,
+  a live slot's state read from HBM once and written once, in place in the
+  donated slot array, the read-out made from the tile while it is in VMEM.
+  A slot that is not live costs no grid step, keeps its bytes, and reads
+  y = 0.
+- the XLA form (`_step_xla`, jax.numpy) elsewhere (every tiny model, d_state
+  16): one pass over ALL the slots' state, a slot that is not live
+  multiplied by 1 (`dt` = 0) and written back.
+
+The projections, the conv window and its SiLU, `dt`'s softplus, the skip,
+the gate and the norm are plain XLA (jax.numpy) in every form of the step.
 """
 
 from __future__ import annotations
@@ -73,6 +93,8 @@ if TYPE_CHECKING:
 from flexflow_tpu import telemetry as tel
 from flexflow_tpu.core.tensor import TensorSpec
 from flexflow_tpu.dtype import DataType
+from flexflow_tpu.kernels import mamba2_step as step_kernel
+from flexflow_tpu.kernels.partition import multi_device
 from flexflow_tpu.kernels.ssd_scan import (scan_tiles, ssd_chunk_scan,
                                            ssd_chunk_scan_gated)
 from flexflow_tpu.ops.norm_ops import rms_norm
@@ -309,6 +331,63 @@ def _report_state_bytes(ctx: LoweringCtx, valid, st) -> None:
                  * (2.0 * sum(leaf[0].nbytes for leaf in st.values())))
 
 
+def _report_step_kernel(ctx: LoweringCtx, slots) -> None:
+    """`ssm_step_kernel_slots`: the step kernel's grid steps on its first
+    axis (where it ran: the live slots `ssm_state_bytes` counts), 0 where
+    the step took the XLA form."""
+    ctx.add_stat("ssm_step_kernel_slots", slots)
+
+
+def step_path(heads: int, head_dim: int, d_state: int, groups: int,
+              mesh=None) -> dict:
+    """Which form a decode step's recurrence takes, from the shapes and the
+    mesh the program is lowered for: what a lowered layer reports in its
+    `ssm/step_path` span. `{"path": "kernel", "head_block": hs, "groups":
+    G}` or `{"path": "xla", "groups": G}`. On a mesh of several devices the
+    XLA form stays: GSPMD partitions it, and cannot partition a Mosaic
+    call."""
+    hs = None if multi_device(mesh) \
+        else step_kernel.head_block(heads, head_dim, d_state, groups)
+    if hs is None:
+        return {"path": "xla", "groups": groups}
+    return {"path": "kernel", "head_block": hs, "groups": groups}
+
+
+def _step_xla(state, dt1, a, u, b_t, c_t):
+    """`ssm_step` in plain XLA: one pass over all the slots' state."""
+    heads = state.shape[1]
+    if b_t.ndim == 3:   # a head's B and C are its group's: [b, H, N]
+        b_t, c_t = (jnp.repeat(t, heads // t.shape[1], axis=1)
+                    for t in (b_t, c_t))
+        ssm = state * jnp.exp(dt1 * a)[:, :, None, None] \
+            + (dt1[..., None] * u)[..., None] * b_t[:, :, None, :]
+        return jnp.einsum("bhpn,bhn->bhp", ssm, c_t), ssm
+    ssm = state * jnp.exp(dt1 * a)[:, :, None, None] \
+        + (dt1[..., None] * u)[..., None] * b_t[:, None, None, :]
+    return jnp.einsum("bhpn,bn->bhp", ssm, c_t), ssm
+
+
+def ssm_step(state, dt1, a, u, b_t, c_t, live, path: dict):
+    """One step of the recurrence: state `[b, H, P, N]` f32 as it lies at
+    rest (donated), dt1 `[b, H]` f32 (0 for a slot that is not live), a `[H]`
+    f32, u `[b, H, P]` f32, b_t and c_t `[b, N]` or `[b, G, N]` f32, live
+    `[b]` bool, `path` as `step_path` says -> (y = S C of the NEW state `[b,
+    H, P]` f32, the new state, the slots the kernel took).
+
+    One result for every live slot in either form: the kernel (a live
+    slot's state passes through the chip once, in place; a slot that is not
+    live is not touched and reads y = 0) or the XLA form (a slot that is not
+    live is rewritten as it was; its y is a read-out nobody reads)."""
+    if path["path"] == "kernel":
+        b, _heads, _hd, n = state.shape
+        y, state = step_kernel.mamba2_step(
+            state, jnp.exp(dt1 * a), dt1[..., None] * u,
+            b_t.reshape(b, -1, n), c_t.reshape(b, -1, n), live,
+            path["head_block"])
+        return y, state, jnp.sum(live.astype(jnp.int32))
+    return _step_xla(state, dt1, a, u, b_t, c_t) + (jnp.int32(0),)
+
+
 def _mamba_lower(layer: Layer, inputs, weights, ctx: LoweringCtx):
     x = inputs[0]
     p = layer.params
@@ -342,22 +421,18 @@ def _mamba_lower(layer: Layer, inputs, weights, ctx: LoweringCtx):
         act = jax.nn.silu(conv + conv_b)
         u = act[:, :d_inner].reshape(b, heads, hd)
         b_t, c_t = _b_and_c(act, p, (b,))
-        dt1 = dt[:, 0]                                          # [b, H]
-        if groups > 1:  # a head's B and C are its group's: [b, H, N]
-            b_t, c_t = (jnp.repeat(t, heads // groups, axis=1)
-                        for t in (b_t, c_t))
-            ssm = st["ssm"] * jnp.exp(dt1 * a)[:, :, None, None] \
-                + (dt1[..., None] * u)[..., None] * b_t[:, :, None, :]
-            y = jnp.einsum("bhpn,bhn->bhp", ssm, c_t)
-        else:
-            ssm = st["ssm"] * jnp.exp(dt1 * a)[:, :, None, None] \
-                + (dt1[..., None] * u)[..., None] * b_t[:, None, None, :]
-            y = jnp.einsum("bhpn,bn->bhp", ssm, c_t)
+        path = step_path(heads, hd, n, groups, ctx.mesh)
+        # one span a lowered layer (trace time): the form its step took
+        with tel.span("ssm/step_path", cat="compile", layer=layer.name,
+                      **path):
+            y, ssm, kernel_slots = ssm_step(st["ssm"], dt[:, 0], a, u, b_t,
+                                            c_t, valid[:, 0], path)
         y = y + d_skip[None, :, None] * u
         ctx.new_state[layer.name] = {
             "ssm": ssm,
             "conv": jnp.where(valid[:, :1, None], window[:, 1:], st["conv"])}
         _report_state_bytes(ctx, valid, st)
+        _report_step_kernel(ctx, kernel_slots)
         g = _gated(y.reshape(b, 1, d_inner), z, weights["norm"], groups,
                    p.get("eps", 1e-5), dt_)
         return [g @ weights["out_proj"].astype(dt_)]

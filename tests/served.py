@@ -13,6 +13,7 @@ for `tests/benchmark/`, which may import nothing from here.
 import jax.numpy as jnp
 import numpy as np
 
+from flexflow_tpu import telemetry as tel
 from flexflow_tpu.ops.registry import STATS_KEY
 
 
@@ -127,3 +128,50 @@ class Served:
         self.eng.kv.evict(slot)
         self.eng.kv.push()
         del self.seqs[slot]
+
+
+def scheduler_reports_the_step_path(eng, reference, prompt_inputs,
+                                    step_inputs, vocab, want: dict,
+                                    mixers: set):
+    """Five requests through `ContinuousBatchingScheduler` on a model with
+    Mamba-2 layers: every served token is `reference`'s argmax over the
+    request's own tokens, every decode mixer (`mixers`: their names) reports
+    its form once in an `ssm/step_path` span whose facts are `want`, and
+    `ssm_step_kernel_slots` on the decode spans counts the (layer, live
+    slot) pairs that `ssm_state_bytes` counts where the kernel ran, 0 where
+    the XLA lines did; a wave reports neither."""
+    from flexflow_tpu.serving import ContinuousBatchingScheduler, Request
+
+    tel.ring_clear()
+    rng = np.random.default_rng(1)
+    reqs = [Request(rid=i, prompt=[int(t) for t in rng.integers(0, vocab, n)],
+                    max_new_tokens=new, arrival_s=0.0)
+            for i, (n, new) in enumerate([(5, 6), (17, 4), (30, 8), (9, 5),
+                                          (12, 7)])]
+    sched = ContinuousBatchingScheduler(eng, eng.params, prompt_inputs,
+                                        step_inputs, eos_id=None)
+    sched.run(reqs)
+    assert len(sched.completed) == len(reqs)
+    for r in reqs:
+        logits = np.asarray(reference(
+            np.asarray([r.prompt + r.tokens], np.int32)))[0]
+        rows = logits[len(r.prompt) - 1:len(r.prompt) - 1 + len(r.tokens)]
+        assert (rows.argmax(-1) == np.asarray(r.tokens)).all(), r.rid
+    spans = {}
+    for sp in tel.ring_spans():
+        spans.setdefault(sp.name, []).append(sp.args or {})
+    forms = spans["ssm/step_path"]
+    assert {a["layer"] for a in forms} == mixers
+    assert all({k: v for k, v in a.items() if k != "layer"} == want
+               for a in forms)
+    # one layer's state a slot: both leaves
+    a_slot = eng.kv_spec.state_bytes_per_slot // len(mixers)
+    syncs = spans["serve/decode/window_sync"]
+    assert syncs and sum(a["steps"] for a in syncs) == sched.decode_steps
+    for a in syncs:
+        pairs = a["ssm_state_bytes"] / (2 * a_slot)
+        assert 0 < pairs <= a["steps"] * len(mixers) * eng.slots \
+            and pairs == int(pairs)
+        assert a["ssm_step_kernel_slots"] \
+            == (pairs if want["path"] == "kernel" else 0)
+    assert "ssm_step_kernel_slots" not in spans["serve/prefill/device_wait"][0]

@@ -32,7 +32,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)  # chip_smoke
 
 KERNEL_MODULES = ("flash_attention", "dequant_attention", "ssd_scan",
-                  "kda_scan", "retention_step", "moe_step")
+                  "kda_scan", "retention_step", "moe_step", "mamba2_step")
 
 # GPT-2 medium: batch 8, 16 heads of 64, seq 1024
 B, H, S, D = 8, 16, 1024, 64
@@ -161,6 +161,33 @@ def test_mamba_scan_at_the_served_widths(one_chip, mosaic, groups, chunk):
         u, ((rows, seq, width), jnp.bfloat16), dt, a, bm, cm,
         ((heads,), f32), ((heads * hd,), jnp.bfloat16))
     assert gated.memory_analysis().temp_size_in_bytes < rows * seq * heads * hd * 4
+
+
+@pytest.mark.parametrize("groups", [1, 8], ids=["granite", "nemotron"])
+def test_mamba_step_at_the_served_widths(one_chip, mosaic, groups):
+    """`ff_mamba2_step` as the two hybrid cells' decode steps call it (16
+    slots of 128 heads of 64, a state of 128; one B/C group or eight): 64
+    heads' 2.1 MB a grid step, in and out one buffer, has to pass
+    Mosaic and the VMEM it states, not only interpret mode, and the slot
+    array must be updated where it lies: no second one among the
+    temporaries."""
+    from flexflow_tpu.ops import ssm_ops
+
+    slots, heads, hd, n = 16, 128, 64, 128
+    f32 = jnp.float32
+    path = ssm_ops.step_path(heads, hd, n, groups)
+    assert path == {"path": "kernel", "head_block": 64, "groups": groups}
+    bc = (slots, n) if groups == 1 else (slots, groups, n)
+    shapes = [((slots, heads, hd, n), f32), ((slots, heads), f32),
+              ((heads,), f32), ((slots, heads, hd), f32), (bc, f32),
+              (bc, f32), ((slots,), jnp.bool_)]
+    args = [jax.ShapeDtypeStruct(sh, dt, sharding=one_chip)
+            for sh, dt in shapes]
+    compiled = jax.jit(lambda *t: ssm_ops.ssm_step(*t, path),
+                       donate_argnums=(0,)).lower(*args).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "ff_mamba2_step" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * 1024 * 1024
 
 
 def test_kda_scan_at_the_served_widths(one_chip, mosaic):
@@ -534,6 +561,27 @@ def _i32(one_chip, *shape):
     return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
 
 
+def _assert_mixers_step_in_place(decode, mixers: int):
+    """Every Mamba-2 layer's recurrence is one `ff_mamba2_step` call, and a
+    slot array `f32[16,128,64,128]` is nowhere in the step but as a
+    parameter, that call's result and the program's own: no copy, slice or
+    fusion of that size (the XLA form's `multiply_reduce_fusion` a layer,
+    and what the compiler staged around it, are gone)."""
+    text = decode.as_text()
+    lines = text.splitlines()
+    entry = lines[next(i for i, l in enumerate(lines)
+                       if l.startswith("ENTRY ")):]
+    assert len(re.findall(r' custom-call\([^\n]*custom_call_target='
+                          r'"tpu_custom_call"[^\n]*ff_mamba2_step',
+                          text)) == mixers
+    made = [m.group(1) for l in entry
+            if (m := re.match(r"\s*%?[\w.\-]+ = f32\[16,128,64,128\]\S* "
+                              r"([\w\-]+)\(", l))]
+    assert sorted(set(made)) == ["get-tuple-element", "parameter"], made
+    assert made.count("parameter") == made.count("get-tuple-element") \
+        == mixers
+
+
 def test_granite_serving_programs_fit_one_chip(described_devices, mosaic,
                                                one_chip, monkeypatch):
     """`granite-4.0-h-small.serve-chat`'s two programs at the cell's own
@@ -568,6 +616,7 @@ def test_granite_serving_programs_fit_one_chip(described_devices, mosaic,
     text = prefill.as_text()
     assert "ragged-dot" in text and "tpu_custom_call" in text
     _assert_appends_in_place(decode, eng)
+    _assert_mixers_step_in_place(decode, 9)
 
 
 def test_gigachat_serving_programs_fit_one_chip(described_devices, mosaic,
@@ -674,6 +723,7 @@ def test_nemotron_serving_programs_fit_one_chip(described_devices, mosaic,
     assert "ragged-dot" in text and "tpu_custom_call" in text
     assert len(re.findall(r" conditional\(", text)) >= 5
     _assert_appends_in_place(decode, eng)
+    _assert_mixers_step_in_place(decode, 5)
 
 
 def test_ling_serving_programs_fit_one_chip(described_devices, mosaic,

@@ -39,7 +39,7 @@ from flexflow_tpu.serving import (ContinuousBatchingScheduler, Request,  # noqa:
                                   valid_step_inputs)
 from families import granitemoehybrid as family  # noqa: E402
 from harness import reference_granitemoehybrid as reference  # noqa: E402
-from served import Served  # noqa: E402
+from served import Served, scheduler_reports_the_step_path  # noqa: E402
 
 RTOL = 1e-4
 SLOTS = 4
@@ -381,20 +381,27 @@ def served(g):
                   step_stats=step_stats)
 
 
-@pytest.mark.parametrize("layer_types", [
-    ("mamba", "mamba", "attention", "mamba"),
-    ("attention", "attention")], ids=["hybrid", "attention-only"])
-def test_prefill_then_decode_through_the_cache_equals_the_full_forward(layer_types):
+@pytest.mark.parametrize("layer_types, d_state", [
+    (("mamba", "mamba", "attention", "mamba"), 16),
+    (("attention", "attention"), 16),
+    (("mamba", "mamba", "attention", "mamba"), 128)],
+    ids=["hybrid", "attention-only", "hybrid-step-kernel"])
+def test_prefill_then_decode_through_the_cache_equals_the_full_forward(
+        layer_types, d_state):
     """Logits, not tokens. Prompts of different lengths in one padded wave
     (one shorter than the conv's width, one past a page and a chunk); a
     slot that sits out the second wave and keeps decoding correctly; a
     second wave into a freed slot and into one never used. The
     attention-only model holds grouped K/V heads and `scale` alone, on the
-    prefill (kv_out) and the paged decode paths."""
+    prefill (kv_out) and the paged decode paths. With a state of 128 a head
+    the decode step's recurrence is the step kernel (interpreted), over the
+    slots that are live alone."""
     import dataclasses
 
     g = dataclasses.replace(GraniteHybridConfig.tiny(seq=48),
-                            layer_types=layer_types)
+                            layer_types=layer_types, mamba_d_state=d_state)
+    assert ssm_ops.step_path(g.mamba_heads, g.mamba_head_dim, d_state, 1)[
+        "path"] == ("kernel" if d_state == 128 else "xla")
     rng = np.random.default_rng(7)
     s = served(g)
 
@@ -469,6 +476,26 @@ def test_scheduler_serves_it_and_reports_its_spans_and_counters():
     assert wave["moe_rows_static"] == g.layers * SLOTS * g.seq * 3
     assert wave["moe_held_pairs"] <= wave["moe_rows_computed"] \
         == g.layers * 144
+
+
+@pytest.mark.parametrize("d_state, want", [
+    (16, {"path": "xla", "groups": 1}),
+    (128, {"path": "kernel", "head_block": 8, "groups": 1})],
+    ids=["tiny", "whole-lanes"])
+def test_the_decode_step_on_either_path_through_the_scheduler(d_state, want):
+    """The form of the decode step's recurrence is chosen from the state's
+    width (`ssm_ops.step_path`): through the scheduler either form serves
+    the reference's argmax and reports itself and its counter."""
+    import dataclasses
+
+    g = dataclasses.replace(GraniteHybridConfig.tiny(seq=48),
+                            mamba_d_state=d_state)
+    eng = engine_for(g)
+    cfg = file_config(g)
+    ref, hp = family.reference_params(eng.params, cfg), family.hyper(cfg)
+    scheduler_reports_the_step_path(
+        eng, lambda ids: reference.forward(ref, ids, hp), valid_prompt_inputs,
+        valid_step_inputs, g.vocab, want, {"l0_mamba", "l1_mamba", "l3_mamba"})
 
 
 def test_what_recurrent_state_does_not_support_fails_loudly():

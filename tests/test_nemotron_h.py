@@ -55,7 +55,7 @@ from families import nemotron_h as family  # noqa: E402
 from harness import flops_nemotron_h as flops  # noqa: E402
 from harness import manifest as mf  # noqa: E402
 from harness import reference_nemotron_h as reference  # noqa: E402
-from served import Served  # noqa: E402
+from served import Served, scheduler_reports_the_step_path  # noqa: E402
 
 RTOL = 1e-4
 SLOTS = 4
@@ -593,27 +593,41 @@ def served(g):
     reference."""
     eng = engine_for(g)
 
+    kernel = ssm_ops.step_path(g.mamba_heads, g.mamba_head_dim,
+                               g.mamba_d_state, g.mamba_n_groups)["path"] \
+        == "kernel"
+
     def step_stats(s, stats):
         # two state-holding layers, the live slots' state read and written
         per_slot = eng.kv_spec.state_bytes_per_slot
         assert float(stats["ssm_state_bytes"]) == 2 * len(s.seqs) * per_slot
+        # the step kernel's grid: a (layer, live slot) pair a step, or none
+        assert int(stats["ssm_step_kernel_slots"]) \
+            == (2 * len(s.seqs) if kernel else 0)
 
     return Served(eng, lambda ids: reference_logits(eng.params, g, ids),
                   valid_prompt_inputs, valid_step_inputs, RTOL,
                   step_stats=step_stats)
 
 
-@pytest.mark.parametrize("heads", (8, 32), ids=("4_a_kv_head", "16_a_kv_head"))
+@pytest.mark.parametrize("heads, d_state", ((8, 16), (32, 16), (8, 128)),
+                         ids=("4_a_kv_head", "16_a_kv_head", "step_kernel"))
 def test_prefill_then_decode_through_cache_and_state_equals_the_full_forward(
-        heads):
+        heads, d_state):
     """Logits, not tokens. Prompts of different lengths in one padded wave
     (one of 2 tokens, one past four pages): K/V pages and recurrent state
     are committed at each row's last real token; a slot that sits out the
     second wave keeps its state and decodes correctly; a second wave into a
     freed slot and into one never used. 2 K/V heads under 8 or 32 query
-    heads (16 a K/V head, as published) in the prefill and the paged decode."""
+    heads (16 a K/V head, as published) in the prefill and the paged decode.
+    With a state of 128 a head the decode step's recurrence is the step
+    kernel (interpreted; four B/C groups of eight heads), over the live slots
+    alone: `served` holds its counter to the live slots a step."""
     g = NemotronHConfig.tiny(seq=48)
     g.heads = heads
+    g.mamba_d_state = d_state
+    if d_state == 128:      # a B/C group of eight heads: whole sublane tiles
+        g.mamba_heads = 32
     rng = np.random.default_rng(7)
     s = served(g)
 
@@ -627,6 +641,26 @@ def test_prefill_then_decode_through_cache_and_state_equals_the_full_forward(
     s.decode(3)
     assert s.checked == 3 + 3 * 3 + 2 + 4 * 3
     assert len(s.seqs[0]) == 2 + 1 + 6 and len(s.seqs[1]) == 9 + 1 + 3
+
+
+@pytest.mark.parametrize("d_state, want", [
+    (16, {"path": "xla", "groups": 4}),
+    (128, {"path": "kernel", "head_block": 32, "groups": 4})],
+    ids=["tiny", "whole-lanes"])
+def test_the_decode_step_on_either_path_through_the_scheduler(d_state, want):
+    """The form of the decode step's recurrence is chosen from the state's
+    width and the heads of a B/C group (`ssm_ops.step_path`): through the
+    scheduler either form serves the reference's argmax and reports itself
+    and its counter."""
+    g = NemotronHConfig.tiny(seq=48)
+    g.mamba_d_state = d_state
+    if d_state == 128:      # a B/C group of eight heads: whole sublane tiles
+        g.mamba_heads = 32
+    eng = engine_for(g)
+    scheduler_reports_the_step_path(
+        eng, lambda ids: reference_logits(eng.params, g, ids),
+        valid_prompt_inputs, valid_step_inputs, g.vocab, want,
+        {"l0_mamba", "l2_mamba"})
 
 
 def test_the_cache_comes_from_the_layers_own_declarations():
@@ -814,6 +848,8 @@ def test_the_other_models_serving_programs_lower_to_the_parents(name, want,
     monkeypatch.setattr(moe_ops, "_report_experts_held", lambda *a: None)
     # and PR 48's `moe_step_kernel_experts`, 0 in every block of these
     monkeypatch.setattr(moe_ops, "_report_step_kernel", lambda *a: None)
+    # and PR 50's `ssm_step_kernel_slots`, 0 at d_state 16 (the XLA form)
+    monkeypatch.setattr(ssm_ops, "_report_step_kernel", lambda *a: None)
     build, inputs = BUILDERS[name]
     model = FFModel(FFConfig(batch_size=4, seed=3, strategy_cache=False,
                              log_level="warning", mesh_shape={"data": 1}))
