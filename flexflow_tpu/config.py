@@ -309,6 +309,14 @@ class FFConfig:
     kv_host_pages: int = 0
     kv_prefetch_ahead: int = 2
     serve_max_context: int = 0
+    # chunked prefill (ISSUE 52): a prompt goes into its slot's pages in
+    # chunks of ONE compiled shape, each attending over what the slot has
+    # cached and over itself, decode steps of the live slots between them.
+    #   serve_prefill_chunk      — tokens a chunk (0 = off: one padded
+    #                              `[slots, seq]` wave, as before). With it
+    #                              the model's `seq` is a slot's whole
+    #                              context: prompt + answer.
+    serve_prefill_chunk: int = 0
     # fleet serving (ISSUE 18): replica pools behind one control plane.
     #   serve_replicas         — in-process engine replicas behind the
     #                            fleet router. 1 = the plain pre-fleet
@@ -500,6 +508,7 @@ class FFConfig:
         p.add_argument("--kv-host-pages", type=int, default=0)
         p.add_argument("--kv-prefetch-ahead", type=int, default=2)
         p.add_argument("--serve-max-context", type=int, default=0)
+        p.add_argument("--serve-prefill-chunk", type=int, default=0)
         p.add_argument("--serve-replicas", type=int, default=1)
         p.add_argument("--serve-fleet-topology", type=str,
                        default="colocated", choices=("colocated", "disagg"))
@@ -646,6 +655,7 @@ class FFConfig:
             kv_host_pages=args.kv_host_pages,
             kv_prefetch_ahead=args.kv_prefetch_ahead,
             serve_max_context=args.serve_max_context,
+            serve_prefill_chunk=args.serve_prefill_chunk,
             serve_replicas=args.serve_replicas,
             serve_fleet_topology=args.serve_fleet_topology,
             serve_prefill_replicas=args.serve_prefill_replicas,

@@ -136,6 +136,9 @@ class FFModel:
                             rope_theta: Optional[float] = None,
                             qk_norm: Optional[float] = None,
                             initializers: Optional[Dict[str, Any]] = None,
+                            mrope_section: Optional[Sequence[int]] = None,
+                            selected: Optional[Tensor] = None,
+                            out_dim: int = 0,
                             name=None) -> Tensor:
         # decode: single-token serving step reading/writing the paged KV
         # cache via lowering state; kv_out: prefill variant that exposes
@@ -145,9 +148,12 @@ class FFModel:
         # positions `[batch, seq]` int: rotary positions, q and k turned
         # over the whole head (rotate-half) at rope_theta; qk_norm: an RMS
         # norm a head on q and on k before the rotation, the value its eps
-        # (weights q_norm, k_norm [head_dim]). All enter the params (and
-        # the inputs) only where set, so graphs without them keep their
-        # fingerprints.
+        # (weights q_norm, k_norm [head_dim]); mrope_section: positions are
+        # `[batch, seq, axes]` and the head's pairs follow them by these
+        # sections; selected: the key set a `sparse_indexer` chose for each
+        # query, the LAST input; out_dim: the output's width where it is not
+        # embed_dim (= num_heads * head_dim). All enter the params (and the inputs) only
+        # where set, so graphs without them keep their fingerprints.
         params = {"embed_dim": int(embed_dim), "num_heads": int(num_heads), "kdim": kdim,
                   "vdim": vdim, "dropout": dropout, "bias": bias, "add_bias_kv": add_bias_kv,
                   "add_zero_attn": add_zero_attn, "causal": causal, "impl": impl,
@@ -161,9 +167,16 @@ class FFModel:
                                          else rope_theta)
         if qk_norm is not None:
             params.update(qk_norm=True, qk_norm_eps=float(qk_norm))
+        if mrope_section is not None:
+            params["mrope_section"] = [int(n) for n in mrope_section]
+        if selected is not None:
+            params["selected"] = True
+        if out_dim and int(out_dim) != int(embed_dim):
+            params["out_dim"] = int(out_dim)
         return self._add_layer(
             OperatorType.MULTIHEAD_ATTENTION, params,
-            [query, key, value] + ([positions] if positions is not None else []),
+            [query, key, value] + ([positions] if positions is not None else [])
+            + ([selected] if selected is not None else []),
             name,
             dict({"wq": kernel_initializer, "wk": kernel_initializer,
                   "wv": kernel_initializer, "wo": kernel_initializer},
@@ -314,6 +327,27 @@ class FFModel:
         return self._add_layer(OperatorType.SHORT_CONV,
                                {"kernel": int(kernel)}, ins, name,
                                initializers)[0]
+
+    def sparse_indexer(self, input: Tensor, positions: Tensor, heads: int,
+                       head_dim: int, topk: int, rope_theta: float = 10000.0,
+                       mrope_section: Optional[Sequence[int]] = None,
+                       eps: float = 1e-6, valid: Optional[Tensor] = None,
+                       initializers: Optional[Dict[str, Any]] = None,
+                       name=None) -> Tensor:
+        """The learned indexer of sparse attention over `[batch, seq, d]`:
+        for each token the `topk` earlier tokens of largest score, one key
+        head of `head_dim` scored by `heads` query heads
+        (ops/sparse_attention_ops.py); what `multihead_attention(selected=)`
+        reads. `positions` as attention's; `valid` `[batch, seq]` int: which
+        positions hold a token (the counters' alone)."""
+        params = {"heads": int(heads), "head_dim": int(head_dim),
+                  "topk": int(topk), "rope_theta": float(rope_theta),
+                  "eps": float(eps)}
+        if mrope_section is not None:
+            params["mrope_section"] = [int(n) for n in mrope_section]
+        ins = [input, positions] + ([valid] if valid is not None else [])
+        return self._add_layer(OperatorType.SPARSE_INDEXER, params, ins,
+                               name, initializers)[0]
 
     def power_retention(self, input: Tensor, positions: Tensor, heads: int,
                         kv_heads: int, head_dim: int,

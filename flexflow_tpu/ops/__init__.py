@@ -19,6 +19,7 @@ from flexflow_tpu.ops import (  # noqa: F401
     shape_ops,
     reduce_ops,
     embed_ops,
+    sparse_attention_ops,
     attention_ops,
     moe_ops,
     ssm_ops,

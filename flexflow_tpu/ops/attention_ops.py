@@ -22,7 +22,18 @@ scores. All three forms do it (the whole sequence; the prefill twin, whose
 holds; the paged decode twin, which norms and rotates the step's q and k at
 the slot's position before the append). Both enter a layer's params only
 where a model sets them: a layer without them lowers to the program it
-lowered to before they existed.
+lowered to before they existed. With `mrope_section` in the params the
+positions are `[batch, seq, axes]` and the head's pairs follow them by
+sections (ops/rotary.py).
+
+With `selected` in the params the LAST input is the key set a learned indexer
+chose for each query (ops/sparse_attention_ops.py): one set a token for all
+heads. The whole sequence and a block of `s > 1` tokens over a slot's cache
+(a prefill chunk) take it as a membership mask `[batch, seq, keys]` and run
+dense under it, queries in blocks; a decode step takes the kept positions
+`[slots, 1, k]` and gathers their K and V rows from the pages, so that a step
+reads `k` rows a slot and not the slot's context. The work lies under the
+named scope `ff_sparse_attend`.
 """
 
 from __future__ import annotations
@@ -43,6 +54,8 @@ from flexflow_tpu.ops.norm_ops import rms_norm
 from flexflow_tpu.ops.op_type import OperatorType
 from flexflow_tpu.ops.registry import register_op, LoweringCtx
 from flexflow_tpu.ops.rotary import apply_rope_half, half_tables
+from flexflow_tpu.ops.sparse_attention_ops import (ATTEND_SCOPE, over_context,
+                                                   query_blocks)
 
 
 def _mha_infer(layer: Layer):
@@ -53,6 +66,9 @@ def _mha_infer(layer: Layer):
     if embed % heads:
         raise ValueError("num_heads must divide embed_dim")
     kv_embed = _kv_heads(p) * (embed // heads)
+    # the output's width where the heads do not add up to it (a model whose
+    # hidden size is not heads * head_dim): wo is [embed, out_dim]
+    out_dim = int(p.get("out_dim") or embed)
     # kdim/vdim are the key/value input feature dims (torch/reference
     # semantics); they must match the actual inputs if given.
     if p.get("kdim") and p["kdim"] != k.shape[-1]:
@@ -63,7 +79,7 @@ def _mha_infer(layer: Layer):
         "wq": TensorSpec((q.shape[-1], embed), q.dtype),
         "wk": TensorSpec((k.shape[-1], kv_embed), q.dtype),
         "wv": TensorSpec((v.shape[-1], kv_embed), q.dtype),
-        "wo": TensorSpec((embed, embed), q.dtype),
+        "wo": TensorSpec((embed, out_dim), q.dtype),
     }
     if p.get("bias", True):
         layer.weight_specs.update(
@@ -71,7 +87,7 @@ def _mha_infer(layer: Layer):
                 "bq": TensorSpec((embed,), q.dtype),
                 "bk": TensorSpec((kv_embed,), q.dtype),
                 "bv": TensorSpec((kv_embed,), q.dtype),
-                "bo": TensorSpec((embed,), q.dtype),
+                "bo": TensorSpec((out_dim,), q.dtype),
             }
         )
     if p.get("add_bias_kv", False):
@@ -83,12 +99,18 @@ def _mha_infer(layer: Layer):
     if p.get("qk_norm"):
         layer.weight_specs["q_norm"] = TensorSpec((embed // heads,), q.dtype)
         layer.weight_specs["k_norm"] = TensorSpec((embed // heads,), q.dtype)
-    return [q.with_shape(q.shape[:-1] + (embed,))]
+    return [q.with_shape(q.shape[:-1] + (out_dim,))]
+
+
+def _has_positions(layer: Layer) -> bool:
+    """Whether the fourth input is the rotary positions (the last one may
+    be an indexer's key set instead)."""
+    return len(layer.inputs) - bool(layer.params.get("selected")) > 3
 
 
 def _positioned(layer: Layer) -> bool:
     """Whether q and k are normed or rotated before the scores."""
-    return len(layer.inputs) > 3 or bool(layer.params.get("qk_norm"))
+    return _has_positions(layer) or bool(layer.params.get("qk_norm"))
 
 
 def _turner(layer: Layer, inputs, weights):
@@ -97,9 +119,10 @@ def _turner(layer: Layer, inputs, weights):
     input) where it has them."""
     p = layer.params
     tables = None
-    if len(inputs) > 3:
+    if _has_positions(layer):
         hd = p["embed_dim"] // p["num_heads"]
-        cos, sin = half_tables(inputs[3], hd, p.get("rope_theta", 10000.0))
+        cos, sin = half_tables(inputs[3], hd, p.get("rope_theta", 10000.0),
+                               p.get("mrope_section"))
         tables = cos[:, :, None], sin[:, :, None]           # [b, s, 1, d]
 
     def turn(heads, norm):
@@ -187,6 +210,9 @@ def _mha_decode_lower(layer: Layer, inputs, weights, ctx: LoweringCtx):
         qh, kh = turn(qh, "q_norm"), turn(kh, "k_norm")
     if kvh != heads and "k_scale" in ctx.state[layer.name]:
         raise NotImplementedError("grouped K/V heads with a quantized cache")
+    selected = inputs[-1] if p.get("selected") else None
+    if selected is not None and "k_scale" in ctx.state[layer.name]:
+        raise NotImplementedError("a selected key set with a quantized cache")
 
     cache = ctx.state[layer.name]
     k_pool, v_pool = cache["k"], cache["v"]
@@ -195,14 +221,10 @@ def _mha_decode_lower(layer: Layer, inputs, weights, ctx: LoweringCtx):
     pos = ctx.state["serve/pos"]
     page = k_pool.shape[1]
     b, s = q.shape[0], q.shape[1]
-    rows = jnp.arange(b)
-    t = pos[:, None] + jnp.arange(s)[None, :]      # (slots, s) write positions
-    pg = t // page
-    in_range = pg < pt.shape[1]
-    pageix = jnp.where(in_range,
-                       pt[rows[:, None], jnp.minimum(pg, pt.shape[1] - 1)], 0)
-    off = t % page
-    from flexflow_tpu.serving.kv_cache import kv_quantize, merge_heads
+    from flexflow_tpu.serving.kv_cache import (append_slots, kv_quantize,
+                                               merge_heads)
+
+    t, pageix, off = append_slots(pt, pos, s, page)
 
     if quantized:
         qk, ks = kv_quantize(kh)
@@ -222,7 +244,11 @@ def _mha_decode_lower(layer: Layer, inputs, weights, ctx: LoweringCtx):
 
     scale = _scale(p, hd)
     out = None
-    if quantized:
+    if selected is not None:
+        out = _selected_cache_attention(
+            qh.reshape(b, s, kvh, heads // kvh, hd), k_pool, v_pool, pt, t,
+            selected, scale, ctx)
+    elif quantized:
         # gather the int8 context + scales: [slots, L, h, (d)]
         Kq = k_pool[pt].reshape(b, -1, heads, hd)
         Vq = v_pool[pt].reshape(b, -1, heads, hd)
@@ -246,7 +272,7 @@ def _mha_decode_lower(layer: Layer, inputs, weights, ctx: LoweringCtx):
         else:
             K = merge_heads((Kq.astype(jnp.float32) * Ks[..., None]).astype(dt))
             V = merge_heads((Vq.astype(jnp.float32) * Vs[..., None]).astype(dt))
-    else:
+    elif selected is None:
         # gather each slot's pages, heads still merged: [slots, L, kvh * d]
         K = k_pool[pt].reshape(b, -1, kvh * hd).astype(dt)
         V = v_pool[pt].reshape(b, -1, kvh * hd).astype(dt)
@@ -271,10 +297,69 @@ def _mha_decode_lower(layer: Layer, inputs, weights, ctx: LoweringCtx):
     return [y]
 
 
-def _merged_axis_attention(qg, K, V, t, *, scale):
+def _selected_cache_attention(qg, k_pool, v_pool, pt, t, selected, scale,
+                              ctx: LoweringCtx):
+    """Attention of `qg` `[b, s, g, r, d]` at positions `t` `[b, s]` over the
+    keys an indexer chose among the slot's pages. A decode step (`selected`
+    `[b, 1, k]` int: the kept positions, `L` where fewer are kept) gathers
+    those rows of K and V from the pools, `k` a slot, and masks the places
+    that hold none. A block (`selected` `[b, s, L]` bool over the slot's
+    padded context) gathers the pages its context reaches once (the rung of
+    `sparse_attention_ops.context_rungs` that holds it) and runs dense under
+    the mask, queries in blocks, each K/V head against its group's r x block
+    query rows as one product. Reports `kv_bytes_gathered`: the K and V rows
+    a live slot's attention had to read."""
+    b, s, g, r, d = qg.shape
+    dt = qg.dtype
+    page = k_pool.shape[1]
+    row_bytes = 2.0 * g * d * k_pool.dtype.itemsize
+    live = ctx.state["serve/active"] > 0
+    with jax.named_scope(ATTEND_SCOPE):
+        if jnp.issubdtype(selected.dtype, jnp.integer):
+            idx = jnp.minimum(selected[:, 0], pt.shape[1] * page - 1)  # [b, k]
+            rows = pt[jnp.arange(b)[:, None], idx // page] * page + idx % page
+            K = k_pool.reshape(-1, g * d)[rows].astype(dt)         # [b, k, g*d]
+            V = v_pool.reshape(-1, g * d)[rows].astype(dt)
+            keep = selected <= t[:, :, None]
+            ctx.add_stat("kv_bytes_gathered", row_bytes * jnp.sum(
+                jnp.where(live[:, None], jnp.sum(keep, axis=-1), 0)
+            ).astype(jnp.float32))
+            return _merged_axis_attention(qg, K, V, t, scale=scale, keep=keep)
+        ctx.add_stat("kv_bytes_gathered", row_bytes * jnp.sum(
+            jnp.where(live, jnp.minimum(t[:, -1] + 1, pt.shape[1] * page), 0)
+        ).astype(jnp.float32))
+
+        def over(pages):
+            n = pages * page
+            # [b, g, n, d]: a K/V head's keys in a row, once a block
+            K = k_pool[pt[:, :pages]].reshape(b, n, g, d).astype(dt)
+            V = v_pool[pt[:, :pages]].reshape(b, n, g, d).astype(dt)
+            K, V = K.transpose(0, 2, 1, 3), V.transpose(0, 2, 1, 3)
+
+            def block(q, keep):
+                qb = q.shape[1]
+                rows = q.transpose(0, 2, 3, 1, 4).reshape(b, g, r * qb, d)
+                logits = jnp.einsum("bgmd,bgkd->bgmk", rows, K,
+                                    preferred_element_type=jnp.float32)
+                logits = jnp.where(
+                    keep[:, None, None, :, :n],
+                    logits.reshape(b, g, r, qb, n) * scale,
+                    jnp.finfo(jnp.float32).min).reshape(b, g, r * qb, n)
+                probs = jax.nn.softmax(logits, axis=-1).astype(dt)
+                out = jnp.einsum("bgmk,bgkd->bgmd", probs, V)
+                return out.reshape(b, g, r, qb, d).transpose(0, 3, 1, 2, 4)
+
+            return query_blocks(block, s, qg, selected)
+
+        return over_context(over, jnp.max(t) + 1, pt.shape[1], page)
+
+
+def _merged_axis_attention(qg, K, V, t, *, scale, keep=None):
     """Decode attention against a gathered context that stays as the pools
     hold it: `qg` `[b, s, g, r, d]` query rows (g K/V heads, r query heads
-    a group), `K`/`V` `[b, L, g * d]`, `t` `[b, s]` the rows' positions;
+    a group), `K`/`V` `[b, L, g * d]`, `t` `[b, s]` the rows' positions
+    (or `keep` `[b, s, L]` bool: which of the gathered rows a query may
+    read, where they are not the context in order);
     returns `[b, s, g, r, d]`. The context is as large as a pool, and
     splitting its merged axis into heads of under 128 would relay all of
     it. So the query side, a few rows, is laid over the merged axis
@@ -288,7 +373,8 @@ def _merged_axis_attention(qg, K, V, t, *, scale):
     # causal-by-construction: query token i (at position pos+i, just
     # written) attends cached positions 0..pos+i inclusive
     keep = (jnp.arange(K.shape[1])[None, None, None, None, :]
-            <= t[:, None, None, :, None])
+            <= t[:, None, None, :, None]) if keep is None \
+        else keep[:, None, None]
     logits = jnp.where(keep, logits, jnp.finfo(logits.dtype).min)
     probs = jax.nn.softmax(logits, axis=-1)
     spans = jnp.einsum("bgrqk,bke->bqgre", probs, V)
@@ -386,13 +472,20 @@ def _mha_lower(layer: Layer, inputs, weights, ctx: LoweringCtx):
     causal = p.get("causal", False)
     scale = _scale(p, embed // heads)
     out = None
+    if p.get("selected"):
+        if not causal or "bias_k" in weights or p.get("add_zero_attn") \
+                or q.shape[1] != k.shape[1]:
+            raise NotImplementedError("a selected key set on attention that "
+                                      "is not causal self-attention")
+        out = _selected_sequence_attention(qh, kh, vh, inputs[-1], scale)
     # flash kernel has no probs-dropout path: fall back (or fail under
     # impl="flash") rather than silently dropping the dropout mask
     needs_dropout = ctx.training and p.get("dropout", 0.0) > 0.0
     # sequence parallelism: the searched strategy may place this attention
     # on the ring path (sp_ring candidate -> {"seq_parallel": axis} attr)
     sp_axis = ctx.op_attrs.get(layer.name, {}).get("seq_parallel")
-    if sp_axis and ctx.mesh is not None and sp_axis in ctx.mesh.shape \
+    if out is None and sp_axis and ctx.mesh is not None \
+            and sp_axis in ctx.mesh.shape \
             and impl != "xla" and qh.shape[1] == kh.shape[1] == vh.shape[1] \
             and qh.shape[1] % ctx.mesh.shape[sp_axis] == 0 \
             and not needs_dropout and "bias_k" not in weights \
@@ -451,6 +544,23 @@ def _mha_lower(layer: Layer, inputs, weights, ctx: LoweringCtx):
     return [y]
 
 
+def _selected_sequence_attention(qh, kh, vh, keep, scale):
+    """Causal attention of a whole sequence under an indexer's membership
+    mask `keep` `[b, s, s]` (already causal): dense, queries in blocks;
+    q, k, v `[b, s, h, d]`, one K/V head a query head."""
+    dt = qh.dtype
+    with jax.named_scope(ATTEND_SCOPE):
+        def block(q, m):
+            logits = jnp.einsum("bqhd,bkhd->bhqk", q, kh,
+                                preferred_element_type=jnp.float32) * scale
+            logits = jnp.where(m[:, None], logits,
+                               jnp.finfo(jnp.float32).min)
+            probs = jax.nn.softmax(logits, axis=-1).astype(dt)
+            return jnp.einsum("bhqk,bkhd->bqhd", probs, vh)
+
+        return query_blocks(block, qh.shape[1], qh, keep)
+
+
 def _mha_flops(layer: Layer):
     q, k = layer.inputs[0].spec, layer.inputs[1].spec
     b, sq, e = q.shape
@@ -485,8 +595,10 @@ def _mha_span_facts(layer: Layer) -> dict:
     compile span; nothing for a layer without."""
     p = layer.params
     facts = {}
-    if len(layer.inputs) > 3:
+    if _has_positions(layer):
         facts["rope_theta"] = float(p.get("rope_theta", 10000.0))
+    if p.get("mrope_section"):
+        facts["mrope_section"] = list(p["mrope_section"])
     if p.get("qk_norm"):
         facts["qk_norm"] = True
     return facts

@@ -111,6 +111,9 @@ class OperatorType(enum.Enum):
     # gated short convolution (a depthwise causal convolution of a few
     # taps between two gates and two projections; ops/short_conv_ops.py)
     SHORT_CONV = "short_conv"
+    # the learned indexer of sparse attention: for each query the top-k
+    # earlier tokens by a cheap score (ops/sparse_attention_ops.py)
+    SPARSE_INDEXER = "sparse_indexer"
     # fused compute op (reference: src/ops/fused.cc)
     FUSED = "fused"
     # inter-op placement composite (reference: nonsequence splits,
@@ -149,6 +152,7 @@ WEIGHTED_OPS = frozenset(
         OperatorType.KDA,
         OperatorType.POWER_RETENTION,
         OperatorType.SHORT_CONV,
+        OperatorType.SPARSE_INDEXER,
         OperatorType.FORK_JOIN,
     }
 )

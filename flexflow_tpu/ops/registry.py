@@ -104,7 +104,10 @@ class OpDef:
     # twin (kind "prefill" | "decode"; None: the layer's own);
     # state_kind: the per-request state it carries ("paged_kv": K/V pages
     # of the paged pool, "paged_latent": pages of ONE pool a layer whose
-    # rows are a token's latent, "recurrent": fixed-size per-slot arrays);
+    # rows are a token's latent, "paged_index": pages of one pool a layer
+    # whose rows are a sparse-attention indexer's key, beside the K/V pages
+    # of the attention it selects for, "recurrent": fixed-size per-slot
+    # arrays);
     # page_state(layer) -> what a token's row holds, for the paged kinds
     # ({"heads", "head_dim"} of the K/V pools, {"latent_dim"} of a latent
     # pool): the cache's geometry comes from the layers' own declarations;
@@ -134,7 +137,7 @@ _REGISTRY: Dict[OperatorType, OpDef] = {}
 # where build_forward(collect_stats=True) puts LoweringCtx.stats
 STATS_KEY = "serve/stats"
 # the kinds of per-request state that live in pages of the paged pools
-PAGED_STATE_KINDS = ("paged_kv", "paged_latent")
+PAGED_STATE_KINDS = ("paged_kv", "paged_latent", "paged_index")
 
 
 def register_op(op_type: OperatorType, infer, lower, flops=None, **extra) -> OpDef:

@@ -8,6 +8,12 @@ turned by its own angle `position * f_i`, `f_i = base^(-2i/dim)`:
 - rotate-half (Hugging Face's Llama / Qwen layout): the pairs are (i, i +
   dim // 2): `apply_rope_half`, with tables that hold the `dim // 2` angles
   once and then again (`half_tables`).
+
+Positions of several axes (a vision-language model's time, height, width;
+text gives all three the same number): `half_tables(.., sections=)` turns
+the first `sections[0]` pairs by axis 0's position, the next `sections[1]`
+by axis 1's, and so on (Qwen2-VL's `mrope_section`, the sections laid in a
+row: no interleaving).
 """
 
 from __future__ import annotations
@@ -38,11 +44,25 @@ def apply_rope(x, cos, sin):
     return (xf * cos + partner * sin).astype(x.dtype)
 
 
-def half_tables(positions, dim: int, base: float):
-    """(cos, sin) `positions.shape + [dim]` float32 for `apply_rope_half`:
-    the `dim // 2` angles, then the same again."""
+def half_tables(positions, dim: int, base: float, sections=None):
+    """(cos, sin) `[..., dim]` float32 for `apply_rope_half`: the `dim // 2`
+    angles, then the same again. `positions` `[...]`, or with `sections`
+    (whole numbers that sum to `dim // 2`) `[..., len(sections)]`: pair i
+    is turned by the position of the axis whose section holds i."""
     inv = jnp.asarray(np.tile(inv_freq(dim, base), 2), jnp.float32)
-    angles = positions.astype(jnp.float32)[..., None] * inv
+    pos = positions.astype(jnp.float32)[..., None]
+    if sections is not None:
+        if sum(sections) != dim // 2 or \
+                positions.shape[-1] != len(sections):
+            raise ValueError(f"rotary sections {list(sections)} over "
+                             f"{dim // 2} pairs and positions "
+                             f"{positions.shape}")
+        # each pair's own axis, by selection (exact: no product)
+        half = jnp.concatenate(
+            [jnp.broadcast_to(pos[..., a, :], pos.shape[:-2] + (n,))
+             for a, n in enumerate(sections)], axis=-1)
+        pos = jnp.concatenate([half, half], axis=-1)
+    angles = pos * inv
     return jnp.cos(angles), jnp.sin(angles)
 
 

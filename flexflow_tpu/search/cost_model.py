@@ -108,6 +108,11 @@ class KVCacheSpec:
     # then say nothing and are 0); at rest a row is padded to whole lanes
     # (`row_widths`), and every byte count here is of what is stored
     latent_dim: int = 0
+    # a sparse-attention indexer's key beside K and V: `index_layers` more
+    # pools (one an indexer layer) whose rows are a token's `index_dim`
+    # values, in whole lanes at rest as a latent's are (0 = none)
+    index_dim: int = 0
+    index_layers: int = 0
 
     @property
     def padded_len(self) -> int:
@@ -129,6 +134,16 @@ class KVCacheSpec:
             return {"latent": -(-self.latent_dim // 128) * 128}
         return {"k": self.heads * self.head_dim, "v": self.heads * self.head_dim}
 
+    def index_row_width(self) -> int:
+        """Values a token's row holds in an indexer layer's pool at rest:
+        whole lanes (64 -> 128), as a latent's row and for its reason."""
+        return -(-self.index_dim // 128) * 128
+
+    def index_bytes(self) -> int:
+        """The indexer layers' pools, all of them."""
+        return self.index_layers * self.pool_pages * self.page_size \
+            * self.index_row_width() * self.itemsize
+
     def page_bytes(self) -> int:
         """K + V bytes of ONE page of ONE layer (the unit the tier moves:
         spill/prefetch copy whole pages, values plus quantized scales); of
@@ -144,7 +159,7 @@ class KVCacheSpec:
         return self.pool_pages * self.page_bytes()
 
     def total_bytes(self) -> int:
-        return self.layers * self.layer_bytes() \
+        return self.layers * self.layer_bytes() + self.index_bytes() \
             + self.slots * self.state_bytes_per_slot
 
     def per_device_bytes(self, model_degree: int = 1) -> int:
@@ -176,7 +191,9 @@ class KVCacheSpec:
         # strategies of models without it stay what they were
         return fp + ((self.state_bytes_per_slot,)
                      if self.state_bytes_per_slot else ()) \
-            + (("latent", self.latent_dim) if self.latent_dim else ())
+            + (("latent", self.latent_dim) if self.latent_dim else ()) \
+            + (("index", self.index_dim, self.index_layers)
+               if self.index_dim else ())
 
 
 def zero_divisor(spec: TensorSpec, dims: Sequence[DimSharding],

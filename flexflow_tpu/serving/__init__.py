@@ -21,6 +21,8 @@ from flexflow_tpu.serving.reqtrace import (RequestTracer, StreamingHistogram,
 from flexflow_tpu.serving.scheduler import (ContinuousBatchingScheduler,
                                             Request, gpt2_prompt_inputs,
                                             gpt2_step_inputs,
+                                            positions3_valid_prompt_inputs,
+                                            positions3_valid_step_inputs,
                                             positions_valid_prompt_inputs,
                                             positions_valid_step_inputs,
                                             valid_prompt_inputs,
@@ -36,6 +38,7 @@ __all__ = [
     "serving_optimize", "gpt2_prompt_inputs", "gpt2_step_inputs",
     "valid_prompt_inputs", "valid_step_inputs",
     "positions_valid_prompt_inputs", "positions_valid_step_inputs",
+    "positions3_valid_prompt_inputs", "positions3_valid_step_inputs",
     "PAGE_TABLE_KEY", "POS_KEY", "ACTIVE_KEY",
     "RequestTracer", "StreamingHistogram", "TERMINAL_FIELDS",
     "terminal_record",

@@ -67,14 +67,13 @@ from flexflow_tpu.ops.registry import STATS_KEY, get_op_def
 from flexflow_tpu.parallel.default_strategy import data_parallel_strategy
 from flexflow_tpu.parallel.machine import MachineSpec, build_mesh
 from flexflow_tpu.search import cost_model as cm
-from flexflow_tpu.serving.kv_cache import (ACTIVE_KEY, POS_KEY, PagedKVCache,
-                                           _tree_bytes)
+from flexflow_tpu.serving.kv_cache import (ACTIVE_KEY, PAGE_TABLE_KEY, POS_KEY,
+                                           PagedKVCache, _tree_bytes)
 from flexflow_tpu.serving.program import (attn_head_degree, clone_for_serving,
                                           page_geometry, recurrent_layers,
                                           serving_optimize, slot_state_bytes)
 
 log = logging.getLogger("flexflow_tpu")
-
 
 def _wq_heads_axis(strategy, attn_layers):
     """The mesh axis (or axis tuple) the decode strategy put on the
@@ -144,7 +143,13 @@ def compile_serving(model, max_batch_slots: Optional[int] = None,
     own searched strategies, its own paged cache with the TARGET's slot/
     page geometry — and a third VERIFY program (`[slots, K+1]` decode-mode
     clone lowered with the searched decode strategy) batch-verifies the K
-    drafted tokens in one pass."""
+    drafted tokens in one pass.
+
+    Chunked prefill: with `FFConfig.serve_prefill_chunk`
+    (--serve-prefill-chunk) tokens a chunk the prompt program is a `[1, prefill_chunk]` block over one
+    slot's own pages (`ServingCompiled.prefill_chunk`), the
+    model's `seq` is a slot's whole context (prompt + answer), and the
+    `[slots, seq]` wave is never compiled."""
     cfg = model.config
     ensure_compile_cache()
     # --telemetry-dir arms the process-global span stream for serving-only
@@ -166,6 +171,7 @@ def compile_serving(model, max_batch_slots: Optional[int] = None,
     geometry = page_geometry(model)
     latent = "latent_dim" in geometry
     seq = int(model.input_tensors[0].spec.shape[1])
+    chunk = int(getattr(cfg, "serve_prefill_chunk", 0) or 0)
     if draft is None and spec_k > 0 and getattr(cfg, "serve_draft_model", ""):
         draft = _draft_from_spec(cfg, cfg.serve_draft_model,
                                  int(model.input_tensors[0].spec.shape[0]))
@@ -202,6 +208,33 @@ def compile_serving(model, max_batch_slots: Optional[int] = None,
                     f"compile_serving: the model has {len(recurrent)} "
                     f"{kind.value} layers with per-slot recurrent state, "
                     "which do not support " + "; ".join(unsupported))
+        index_layers = [n for n in attn if get_op_def(
+            dec_model.get_layer_by_name(n).op_type).state_kind
+            == "paged_index"]
+        beside = [w for w, a in (
+            ("the host KV tier (--kv-host-pages)",
+             int(getattr(cfg, "kv_host_pages", 0) or 0) > 0),
+            ("speculative decoding", draft is not None and spec_k > 0),
+            ("a quantized cache (--kv-cache-dtype int8)", kv_quantized)) if a]
+        for what, asked in (
+                (f"{len(index_layers)} layers page a sparse-attention "
+                 "indexer's key beside K and V", bool(index_layers)),
+                (f"the prompt goes in by chunks of {chunk}", chunk > 0)):
+            if asked and beside:
+                raise NotImplementedError(
+                    f"compile_serving: {what}, which does not support "
+                    + "; ".join(beside) + " yet")
+        if chunk and (latent or recurrent or not attn):
+            # a chunk attends over what its slot has cached: K/V pages can be
+            # read back as they were written; a latent's chunk form (absorbed
+            # or decompressed over the cache) and a recurrent layer's (the
+            # sequence form started from a slot's state) do not exist yet
+            raise NotImplementedError(
+                "compile_serving: chunked prefill (--serve-prefill-chunk) "
+                "needs every stateful layer to page K/V; this model has "
+                + ("paged_latent layers" if latent else
+                   f"{len(recurrent)} recurrent layers" if recurrent else
+                   "none that pages"))
         if latent:
             unsupported = [
                 what for what, asked in (
@@ -234,7 +267,8 @@ def compile_serving(model, max_batch_slots: Optional[int] = None,
                 experts_latent_dim=p.get("latent_size", 0))
         # what the first paged and the first recurrent layer's ops say of
         # their state (its layout, what a pool's rows went through)
-        for name in attn[:1] + list(recurrent)[:1]:
+        kv_layers = [n for n in attn if n not in index_layers]
+        for name in kv_layers[:1] + index_layers[:1] + list(recurrent)[:1]:
             first = dec_model.get_layer_by_name(name)
             facts = get_op_def(first.op_type).span_facts
             if facts is not None:
@@ -244,7 +278,8 @@ def compile_serving(model, max_batch_slots: Optional[int] = None,
         # at one slot's worth, the minimum a decoding slot must keep hot),
         # so total two-tier capacity stays slots*pages_per_slot while the
         # HBM-page budget drops. H = 0 keeps the exact untiered geometry.
-        pages_per_slot = -(-(seq + max_new) // page)
+        # chunked: `seq` is the slot's whole context, the answer included
+        pages_per_slot = -(-(seq + (0 if chunk else max_new)) // page)
         host_pages = max(0, int(getattr(cfg, "kv_host_pages", 0) or 0))
         device_pages = 0
         if host_pages:
@@ -253,9 +288,11 @@ def compile_serving(model, max_batch_slots: Optional[int] = None,
         prefetch_ahead = max(1, int(getattr(cfg, "kv_prefetch_ahead", 2)
                                     or 2))
         kv_spec = cm.KVCacheSpec(
-            layers=len(attn), heads=int(geometry.get("heads", 0)),
+            layers=len(kv_layers), heads=int(geometry.get("heads", 0)),
             head_dim=int(geometry.get("head_dim", 0)),
             latent_dim=int(geometry.get("latent_dim", 0)),
+            index_dim=int(geometry.get("index_dim", 0)),
+            index_layers=len(index_layers),
             slots=slots, pages_per_slot=pages_per_slot,
             page_size=page, itemsize=kv_itemsize,
             scale_itemsize=kv_scale_itemsize,
@@ -273,6 +310,18 @@ def compile_serving(model, max_batch_slots: Optional[int] = None,
             dec_st = data_parallel_strategy(dec_model, machine)
         _overlay_parallel_ops(pre_model, pre_st)
         _overlay_parallel_ops(dec_model, dec_st)
+        chunk_model = None
+        if chunk:
+            # the decode twin at ONE row of `chunk` tokens: it appends the
+            # block to the slot's pages and attends over them, under the
+            # decode strategy (layer names are preserved), as the verifier
+            # does. One request a call: at the rates a chip sustains a
+            # second row would be empty in most chunks, and an empty row is
+            # half the call's positions (one row reads 92 % useful
+            # positions: PERF.md, Findings PR 52)
+            chunk_model, _ = clone_for_serving(model, "decode", 1,
+                                               decode_seq=chunk)
+            _overlay_parallel_ops(chunk_model, dec_st)
         ver_model = None
         draft_engine = None
         if draft is not None and spec_k > 0:
@@ -304,13 +353,16 @@ def compile_serving(model, max_batch_slots: Optional[int] = None,
                                  kv_dtype=kv_dtype, kv_quantized=kv_quantized,
                                  verify_model=ver_model,
                                  spec_tokens=spec_k if draft_engine else 0,
-                                 draft=draft_engine, recurrent=recurrent)
+                                 draft=draft_engine, recurrent=recurrent,
+                                 index_layers=index_layers,
+                                 chunk_model=chunk_model)
         # the in-place append's engagement: the pool as it lies at rest and
         # the bytes of the state leaves a decode step is told to donate
         compile_span.set(
             kv_pool_shape=list(
-                next(iter(engine.kv.state[attn[0]].values())).shape)
-            if attn else [],
+                next(iter(engine.kv.state[kv_layers[0]].values())).shape)
+            if kv_layers else [],
+            prefill_chunk=chunk,
             state_in_place=engine.kv.writes_state_in_place,
             decode_state_donated_bytes=_tree_bytes(engine.kv.state))
         return engine
@@ -338,7 +390,9 @@ class ServingCompiled:
                  attn_layers: List[str], kv_spec: "cm.KVCacheSpec",
                  max_decode_len: int, kv_dtype=None, kv_quantized: bool = False,
                  verify_model=None, spec_tokens: int = 0, draft=None,
-                 recurrent: Optional[Dict[str, Dict[str, tuple]]] = None):
+                 recurrent: Optional[Dict[str, Dict[str, tuple]]] = None,
+                 index_layers: Optional[List[str]] = None,
+                 chunk_model=None):
         self.model = model
         self.cfg = model.config
         self.machine = machine
@@ -366,7 +420,7 @@ class ServingCompiled:
         self.kv = PagedKVCache(kv_spec, self.attn_layers, mesh,
                                heads_axis=heads_axis, dtype=self.kv_dtype,
                                quantized=self.kv_quantized, machine=machine,
-                               recurrent=recurrent)
+                               recurrent=recurrent, index_layers=index_layers)
         deg = 1
         if self.kv.heads_axis is not None:
             axes = (self.kv.heads_axis,) if isinstance(self.kv.heads_axis, str) \
@@ -398,15 +452,31 @@ class ServingCompiled:
         # axis, as the graph shows it) is applied to the gathered
         # `[slots, 1, d]` rows alone, so the `[slots, S, vocab]` logits are
         # never formed; any other last layer is gathered on its output.
-        head = _positionwise_head(prefill_model)
-        body_fwd, head_fwd = pre_fwd, None
-        if head is not None:
-            body_fwd = build_forward(prefill_model.layers[:-1],
-                                     prefill_model.input_tensors,
-                                     head.inputs, mesh, prefill_strategy,
-                                     **fwd_kw)
-            head_fwd = build_forward([head], head.inputs, pre_out, mesh,
-                                     prefill_strategy, **fwd_kw)
+        def split_head(graph, strategy, whole=None):
+            """(body, head) forwards of `graph`: the head alone where its
+            last layer is position-wise, else (the whole graph's, None)."""
+            head = _positionwise_head(graph)
+            if head is None:
+                return whole or build_forward(
+                    graph.layers, graph.input_tensors,
+                    graph.layers[-1].outputs[:1], mesh, strategy,
+                    **fwd_kw), None
+            return (build_forward(graph.layers[:-1], graph.input_tensors,
+                                  head.inputs, mesh, strategy, **fwd_kw),
+                    build_forward([head], head.inputs,
+                                  graph.layers[-1].outputs[:1], mesh,
+                                  strategy, **fwd_kw))
+
+        def last_row_tokens(params, hidden, last, head):
+            """Each row's greedy token after position `last`."""
+            rows = jnp.take_along_axis(hidden, last[:, None, None], axis=1,
+                                       mode="clip")
+            if head is not None:
+                rows = head(params, {}, [rows], False, rng0)[0][0]
+            return jnp.argmax(rows[:, 0, :], axis=-1).astype(jnp.int32)
+
+        body_fwd, head_fwd = split_head(prefill_model, prefill_strategy,
+                                        whole=pre_fwd)
 
         def _prefill_first_tokens(params, inputs, lengths, slot_state=None):
             last = jnp.maximum(lengths.astype(jnp.int32) - 1, 0)
@@ -415,11 +485,7 @@ class ServingCompiled:
             # after the wave (LoweringCtx.hand_out_slot_state)
             outs, kv_state = body_fwd(params, slot_state or {}, inputs, False,
                                       rng0)
-            rows = jnp.take_along_axis(outs[0], last[:, None, None], axis=1,
-                                       mode="clip")
-            if head_fwd is not None:
-                rows = head_fwd(params, {}, [rows], False, rng0)[0][0]
-            tokens = jnp.argmax(rows[:, 0, :], axis=-1).astype(jnp.int32)
+            tokens = last_row_tokens(params, outs[0], last, head_fwd)
             if slot_state is not None:
                 kv_state.setdefault(STATS_KEY, {})["state_written_bytes"] = \
                     jnp.sum(lengths > 0).astype(jnp.float32) \
@@ -457,6 +523,40 @@ class ServingCompiled:
                 prefill_model.layers),
             "decode": attribution.register_program(
                 "serve/decode", self._decode_jit, decode_model.layers)}
+        # chunked prefill: the prompt program is a `[1, chunk]` block over
+        # one slot's own pages (registered as this engine's serve/prefill)
+        self.chunk_model = chunk_model
+        self.chunk_tokens = 0
+        self._chunk_jit = None
+        if chunk_model is not None:
+            self.chunk_tokens = int(
+                chunk_model.input_tensors[0].spec.shape[1])
+            c_body, c_head = split_head(chunk_model, decode_strategy)
+            paged = list(self.attn_layers)
+
+            def _prefill_chunk(params, state, inputs, page_rows, context,
+                               lengths):
+                # the block sees its slot's own pages and position; the
+                # cache's table, positions and live set pass through as they
+                # are (a prefilling slot is not live until its last chunk)
+                view = {n: state[n] for n in paged}
+                view[PAGE_TABLE_KEY] = page_rows
+                view[POS_KEY] = context
+                view[ACTIVE_KEY] = (lengths > 0).astype(
+                    state[ACTIVE_KEY].dtype)
+                outs, ns = c_body(params, view, inputs, False, rng0)
+                tokens = last_row_tokens(
+                    params, outs[0],
+                    jnp.maximum(lengths.astype(jnp.int32) - 1, 0), c_head)
+                new = dict(state)
+                new.update({n: ns[n] for n in paged})
+                if STATS_KEY in ns:
+                    new[STATS_KEY] = ns[STATS_KEY]
+                return tokens, new
+
+            self._chunk_jit = jax.jit(_prefill_chunk, donate_argnums=(1,))
+            self._programs["prefill_chunk"] = attribution.register_program(
+                "serve/prefill", self._chunk_jit, chunk_model.layers)
         self._verify_jit = None
         self._verify_fn = None
         self._spec_jit = None
@@ -785,6 +885,34 @@ class ServingCompiled:
             jnp.asarray(lengths), self.kv.slot_state())
         self.kv.state.update({n: kv_state.pop(n) for n in self.kv.recurrent})
         return tokens, kv_state
+
+    def prefill_chunk(self, params, state, input_arrays, page_rows, context,
+                      lengths):
+        """One chunk of one prompt, `chunk_tokens` tokens (every array has
+        a leading axis of 1): the tokens sit at positions `context[0] ..` of
+        the slot whose table row is `page_rows[0]` (`kv.prefill_row`),
+        `lengths[0]` of them real. The block's K, V and indexer keys are
+        appended to those pages and it attends over what they hold and over
+        itself. Returns (tokens `[1]` int32: the greedy token after the last
+        real position, which is the request's first token where the chunk
+        is its prompt's last; the new cache state, the step's counters
+        under STATS_KEY). `state` is DONATED, as in `decode_step`."""
+        if self._chunk_jit is None:
+            raise RuntimeError("prefill_chunk: engine compiled without "
+                               "--serve-prefill-chunk")
+        args = (params, state, list(input_arrays),
+                jnp.asarray(page_rows, jnp.int32),
+                jnp.asarray(context, jnp.int32),
+                jnp.asarray(lengths, jnp.int32))
+        prog = self._programs["prefill_chunk"]
+        if prog.compiled is None:
+            prog.first_run(*args)
+        t0 = tel.now_us() if tel.enabled() else None
+        out = self._chunk_jit(*args)
+        if t0 is not None:
+            tel.record("serve/prefill", t0, cat="serve", slots=1,
+                       chunk=self.chunk_tokens)
+        return out
 
     def decode_step(self, params, state, input_arrays):
         """One single-token step over all slots: returns (logits

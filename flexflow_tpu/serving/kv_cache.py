@@ -62,6 +62,25 @@ reads the gathered rows as they lie (its query side carries zeros there).
 What it does not support yet raises NotImplementedError: a quantized pool,
 the host tier, park/spill, the hand-off and speculative roll-back.
 
+A sparse-attention indexer's key (`spec.index_dim` set) is a third kind of
+row beside K and V: each INDEXER layer (ops/sparse_attention_ops.py, a layer
+of its own ahead of the attention it selects for) has one pool `[pool_pages,
+page_size, index_row_width]`, `state[layer_name] = {"ik": ...}`, under the
+same page table: a token's K, V and indexer key lie at the same page and
+offset of their layers' pools, are written by the same block and freed by
+the same eviction, and the position mask that hides a freed page's stale K/V
+hides its stale key. 64 values lie in 128 lanes (the latent's reason). The
+host tier, park/spill, the hand-off and a quantized pool raise
+NotImplementedError beside it.
+
+A prompt longer than one program's window is prefilled in CHUNKS over the
+slot's own pages (serving/engine.py: `prefill_chunk`; scheduler.py). Such a
+slot is admitted `prefilling`: it owns its pages on the host, but the device's
+table row stays at the scratch page and the slot inactive, so that a decode
+step between two chunks writes nothing into them; the chunk program is handed
+the slot's real row (`prefill_row`), and `activate` publishes it with the
+prompt's length once the last chunk is in.
+
 Recurrent layers (a state-space mixer) keep the other kind of per-request
 state in the same manager: fixed-size arrays per slot, `state[layer_name] =
 {leaf: [slots, ...]}` (an SSM state and a conv tail), never paged.
@@ -168,6 +187,21 @@ def pad_row(rows, width: int):
         rows, [(0, 0)] * (rows.ndim - 1) + [(0, short)])
 
 
+def append_slots(pt, pos, s: int, page: int):
+    """Where a block of `s` tokens a slot lies in the pools: (`t` `[slots,
+    s]` the tokens' positions `pos + i`, the page of each, its offset in the
+    page). A position past the table's last page goes to the scratch page
+    (as `_commit_prefill` routes padding), so the scatter that follows has
+    one shape whatever a slot holds."""
+    rows = jnp.arange(pt.shape[0])
+    t = pos[:, None] + jnp.arange(s)[None, :]
+    pg = t // page
+    in_range = pg < pt.shape[1]
+    pageix = jnp.where(in_range,
+                       pt[rows[:, None], jnp.minimum(pg, pt.shape[1] - 1)], 0)
+    return t, pageix, t % page
+
+
 def merge_heads(x):
     """`[.., heads, head_dim]` token rows as the pools hold them:
     `[.., heads * head_dim]`, heads-major."""
@@ -247,7 +281,8 @@ class PagedKVCache:
     def __init__(self, spec: KVCacheSpec, attn_layers: List[str],
                  mesh: Optional[Mesh] = None, heads_axis=None,
                  dtype=jnp.float32, quantized: bool = False, machine=None,
-                 recurrent: Optional[Dict[str, Dict[str, tuple]]] = None):
+                 recurrent: Optional[Dict[str, Dict[str, tuple]]] = None,
+                 index_layers: Optional[List[str]] = None):
         self.spec = spec
         self.machine = machine  # host_bw source for transfer pricing rows
         # the commit programs as this cache runs them (attribution.op_scopes
@@ -257,7 +292,10 @@ class PagedKVCache:
             "serve/commit", _commit_prefill, (), owner=self)
         self._commit_state = attribution.register_program(
             "serve/commit", _commit_state, (), owner=self)
+        # every layer that pages: attention's K/V (or latent) pools and, among
+        # them in graph order, the indexer layers' key pools
         self.attn_layers = list(attn_layers)
+        self.index_layers = list(index_layers or [])
         # {layer: {leaf: (per-slot shape, dtype)}} (program.recurrent_layers)
         # (compile_serving refuses a host tier beside them)
         self.recurrent = dict(recurrent or {})
@@ -300,7 +338,9 @@ class PagedKVCache:
             return (jax.device_put(z, self._pool_sharding)
                     if self._pool_sharding is not None else z)
 
-        def layer_state():
+        def layer_state(name):
+            if name in self.index_layers:
+                return {"ik": pool(spec.index_row_width())}
             st = {leaf: pool(width)
                   for leaf, width in spec.row_widths().items()}
             if self.quantized:
@@ -308,7 +348,7 @@ class PagedKVCache:
                 st["v_scale"] = scales()
             return st
 
-        self.state: Dict = {n: layer_state() for n in self.attn_layers}
+        self.state: Dict = {n: layer_state(n) for n in self.attn_layers}
         for n, leaves in self.recurrent.items():
             self.state[n] = {
                 key: (jax.device_put(z, self._repl) if self._repl is not None
@@ -321,6 +361,9 @@ class PagedKVCache:
         self._active = np.zeros((spec.slots,), np.int32)
         self.free_pages: List[int] = list(range(1, spec.pool_pages))
         self._slot_pages: Dict[int, List[int]] = {}
+        # slots whose prompt is being prefilled in chunks -> their table row,
+        # which the device does not see until `activate`
+        self._prefilling: Dict[int, np.ndarray] = {}
         # host cold tier: per-layer pinned buffers shaped like the pools
         # minus the page dim ([host_pages, page_size, heads * head_dim] for
         # values, [host_pages, page_size, heads] for quantized scales)
@@ -343,7 +386,11 @@ class PagedKVCache:
 
     # ------------------------------------------------------------ host ops
     def _put_repl(self, arr):
-        x = jnp.asarray(arr)
+        # a COPY of the host mirror: on the CPU backend `jnp.asarray` may
+        # alias an aligned numpy buffer, the serving programs donate the
+        # state they are handed, and a donated alias of `_pos` is then
+        # advanced in place by the program AND by `sync_after`
+        x = jnp.array(arr)
         return jax.device_put(x, self._repl) if self._repl is not None else x
 
     def _push_tables(self) -> None:
@@ -355,7 +402,8 @@ class PagedKVCache:
         # parked (cold/inflight) slots are inactive on device but occupied:
         # their KV lives in the host tier under the same slot id
         return [i for i in range(self.spec.slots)
-                if not self._active[i] and i not in self._cold]
+                if not self._active[i] and i not in self._cold
+                and i not in self._prefilling]
 
     def pages_needed(self, total_tokens: int) -> int:
         if not self.attn_layers:    # nothing pages: a slot is all it takes
@@ -375,15 +423,19 @@ class PagedKVCache:
     def total_free_pages(self) -> int:
         return len(self.free_pages) + len(self.free_host_pages)
 
-    def admit(self, slot: int, prompt_len: int, total_tokens: int) -> bool:
+    def admit(self, slot: int, prompt_len: int, total_tokens: int,
+              prefilling: bool = False) -> bool:
         """Assign pages for a sequence that will hold up to `total_tokens`
         positions (prompt + decode budget + dispatch-ahead headroom); the
         slot's position starts at `prompt_len` (the index the first decode
-        step writes). Raises `KVPoolExhausted` when the free list is short
+        step writes). `prefilling`: the prompt goes in by chunks; the slot
+        owns its pages but stays inactive, its device row at scratch, until
+        `activate`. Raises `KVPoolExhausted` when the free list is short
         — the scheduler's shed-or-queue path decides whether the request
         waits (backpressure) or is shed, instead of a bare free-list
         IndexError mid-drain."""
-        if self._active[slot] or slot in self._cold:
+        if self._active[slot] or slot in self._cold \
+                or slot in self._prefilling:
             raise ValueError(f"slot {slot} is occupied")
         need = self.pages_needed(total_tokens)
         if len(self.free_pages) < need:
@@ -392,10 +444,26 @@ class PagedKVCache:
         self._slot_pages[slot] = pages
         row = np.zeros(self.spec.pages_per_slot, np.int32)
         row[:need] = pages
+        if prefilling:
+            self._prefilling[slot] = row
+            return True
         self._table[slot] = row
         self._pos[slot] = prompt_len
         self._active[slot] = 1
         return True
+
+    def prefill_row(self, slot: int) -> np.ndarray:
+        """The table row `[pages_per_slot]` of a slot admitted `prefilling`,
+        for the chunk program."""
+        return self._prefilling[slot]
+
+    def activate(self, slot: int, prompt_len: int) -> None:
+        """The last chunk of a `prefilling` slot's prompt is in its pages:
+        the slot joins the decode steps at position `prompt_len` (the
+        caller pushes)."""
+        self._table[slot] = self._prefilling.pop(slot)
+        self._pos[slot] = prompt_len
+        self._active[slot] = 1
 
     def evict(self, slot: int) -> None:
         """Return the slot's pages to the free list(s); stale pool contents
@@ -405,6 +473,7 @@ class PagedKVCache:
         self.free_pages.extend(self._slot_pages.pop(slot, []))
         self.free_host_pages.extend(self._cold.pop(slot, []))
         self._inflight.pop(slot, None)
+        self._prefilling.pop(slot, None)
         self._table[slot] = 0
         self._pos[slot] = 0
         self._active[slot] = 0
@@ -440,7 +509,8 @@ class PagedKVCache:
         `state_kind` names them (what the cache's spans say they moved)."""
         kinds = [] if not self.attn_layers else \
             ["paged_latent" if self.spec.latent_dim else "paged_kv"]
-        return "+".join(kinds + (["recurrent"] if self.recurrent else []))
+        return "+".join(kinds + (["paged_index"] if self.index_layers else [])
+                        + (["recurrent"] if self.recurrent else []))
 
     @property
     def writes_state_in_place(self) -> bool:
@@ -460,6 +530,11 @@ class PagedKVCache:
             raise NotImplementedError(
                 f"{what}: the cache holds paged_latent state "
                 f"({len(self.attn_layers)} layers), which this path does "
+                "not move yet")
+        if self.index_layers:
+            raise NotImplementedError(
+                f"{what}: the cache holds an indexer's key beside K and V "
+                f"({len(self.index_layers)} layers), which this path does "
                 "not move yet")
         if self.recurrent:
             raise NotImplementedError(

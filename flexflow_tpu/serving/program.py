@@ -69,24 +69,30 @@ def _serving_params(layer: Layer, kind: str) -> dict:
 def page_geometry(model) -> Dict[str, int]:
     """What a token's row holds in the paged pools of `model`'s layers, from
     the layers' own declarations (their op's `page_state`): `{"heads",
-    "head_dim"}` of K and V pools, `{"latent_dim"}` of a latent pool, or `{}`
+    "head_dim"}` of K and V pools, `{"latent_dim"}` of a latent pool, with
+    `{"index_dim"}` where indexer layers page their key beside them, or `{}`
     where no layer pages anything (every layer that carries state keeps it
-    a slot: `recurrent_layers`). One geometry a cache: layers that declare
-    different ones raise."""
-    found: Dict[str, Dict[str, int]] = {}
+    a slot: `recurrent_layers`). One geometry a kind of state: layers of a
+    kind that declare different ones raise, and so do K/V and a latent in
+    one model."""
+    found: Dict[str, Dict[str, Dict[str, int]]] = {}
     for l in topo_order(model.layers):
         d = get_op_def(l.op_type)
         if d.state_kind in PAGED_STATE_KINDS:
-            found[l.name] = dict(d.page_state(l))
-    if not found:
-        return {}
-    first = next(iter(found))
-    for name, geometry in found.items():
-        if geometry != found[first]:
-            raise NotImplementedError(
-                f"one cache geometry a model: {first} pages {found[first]}, "
-                f"{name} pages {geometry}")
-    return found[first]
+            found.setdefault(d.state_kind, {})[l.name] = dict(d.page_state(l))
+    if len(set(found) - {"paged_index"}) > 1:
+        raise NotImplementedError(
+            f"one cache geometry a model: layers page {sorted(found)}")
+    out: Dict[str, int] = {}
+    for layers in found.values():
+        first = next(iter(layers))
+        for name, geometry in layers.items():
+            if geometry != layers[first]:
+                raise NotImplementedError(
+                    f"one cache geometry a model: {first} pages "
+                    f"{layers[first]}, {name} pages {geometry}")
+        out.update(layers[first])
+    return out
 
 
 def recurrent_layers(model) -> Dict[str, Dict[str, tuple]]:

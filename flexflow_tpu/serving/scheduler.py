@@ -57,6 +57,21 @@ SLO-aware admission & graceful degradation (ISSUE 11):
   prompt length of one admission wave, so a burst of long prompts
   spreads over several prefill batches instead of monopolizing the
   engine while decode slots starve.
+- CHUNKED PREFILL (an engine compiled with `--serve-prefill-chunk`): a
+  placed request's prompt goes into its slot's pages a chunk at a time
+  (`engine.prefill_chunk`: one request a call, the oldest first, the
+  chunk attending over what its slot has cached and over itself), one chunk a turn at a drained point, and a decode step of the
+  live slots between two chunks. A slot is `prefilling` (it owns its pages,
+  the device does not see them: kv_cache.py) until its last chunk yields the
+  request's first token and `kv.activate` joins it to the decode steps. Each
+  chunk is one `serve/admit` span (`requests`, `prompt_tokens` the chunk's
+  real tokens, `padded_tokens` the program's, `requests_started` those
+  of them at their first chunk, `chunk_index`, `chunks_of_request`,
+  `context_before`) with `serve/prefill/dispatch`,
+  `/commit` (nothing to move: the chunk wrote its pages) and `/device_wait`
+  inside; `admit_s` is the first chunk's dispatch, `ttft_s` the last
+  chunk's token. A prompt longer than `seq - max_decode_len` is shed as
+  `prompt_too_long`.
 - WATCHDOG: with `--serve-decode-timeout-ms` set, a dispatched window
   whose per-step materialization exceeds the budget evicts the longest-
   resident slot (outcome "timeout") instead of stalling the whole batch.
@@ -182,6 +197,25 @@ def positions_valid_step_inputs(tokens, state) -> List[Any]:
     return [tokens, pos, valid_step_inputs(tokens, state)[1]]
 
 
+def positions3_valid_prompt_inputs(ids: np.ndarray, lengths: np.ndarray,
+                                  offsets=None) -> List[np.ndarray]:
+    """Prompt inputs of a model with three-axis rotary positions
+    (`input_ids`, `positions` `[rows, s, 3]`, `valid`): text, whose time,
+    height and width positions are one number. `offsets` `[rows]`: where
+    each row's block starts in its sequence (a prefill chunk's context)."""
+    ids, pos, valid = positions_valid_prompt_inputs(ids, lengths)
+    if offsets is not None:
+        pos = pos + np.asarray(offsets, np.int32)[:, None]
+    return [ids, np.ascontiguousarray(np.repeat(pos[..., None], 3, axis=-1)),
+            valid]
+
+
+def positions3_valid_step_inputs(tokens, state) -> List[Any]:
+    """Decode inputs of such a model: a generated token is text."""
+    tokens, pos, valid = positions_valid_step_inputs(tokens, state)
+    return [tokens, jnp.repeat(pos[..., None], 3, axis=-1), valid]
+
+
 def _stat_totals(stats: List[Any]) -> Dict[str, float]:
     """The programs' own counters (one dict of scalars per decode step or
     prefill wave, as it came out under STATS_KEY and was brought to the
@@ -298,11 +332,18 @@ class ContinuousBatchingScheduler:
                 cfg, self.kv.spec, quantized=self.kv.quantized,
                 machine=self.kv.machine)
         self.max_context = int(getattr(cfg, "serve_max_context", 0) or 0)
+        # chunked prefill: tokens a chunk (0: one padded wave);
+        # slot -> [request, prompt tokens cached], oldest first
+        self.chunk = int(getattr(engine, "chunk_tokens", 0) or 0)
+        self._prefilling: Dict[int, List[Any]] = {}
         # the admission policy brain is the fleet-level class (ISSUE 18
         # control-plane split); a standalone scheduler owns one instance
         from flexflow_tpu.serving.fleet import AdmissionControl
         self.admission = AdmissionControl(
-            seq=self.seq, max_context=self.max_context,
+            # chunked, `seq` is a slot's whole context: the longest prompt
+            # leaves room for the longest answer
+            seq=self.seq - (engine.max_decode_len if self.chunk else 0),
+            max_context=self.max_context,
             queue_cap=self.queue_cap, ttft_budget_ms=self.ttft_budget_ms,
             overhead_tokens=self.dispatch_ahead + self.spec_tokens,
             pages_needed=self.kv.pages_needed,
@@ -477,6 +518,8 @@ class ContinuousBatchingScheduler:
         are pushed BEFORE the commit so the scatter sees the new pages.
         One `serve/admit` span per wave, from the first placement to the
         last first token; none when no batch formed."""
+        if self.chunk:
+            return self._admit_chunk(waiting, active, next_host, now_s)
         with tel.span("serve/admit", cat="serve",
                       wave=self.prefills + 1) as wave:
             with tel.span("serve/admit/place", cat="serve",
@@ -499,6 +542,120 @@ class ContinuousBatchingScheduler:
                      padded_tokens=self.slots * self.seq)
             return self._prefill_wave(batch, ids, lengths, active, next_host)
 
+    def _admit_chunk(self, waiting: List[Request],
+                     active: Dict[int, Request], next_host: np.ndarray,
+                     now_s: float) -> bool:
+        """Chunked prefill's turn: place whoever fits (their slots start
+        `prefilling`), then run ONE chunk of the oldest prefilling request.
+        Returns True if it got its first token and joined `active`."""
+        with tel.span("serve/admit", cat="serve",
+                      wave=self.prefills + 1) as wave:
+            with tel.span("serve/admit/place", cat="serve",
+                          state=self.kv.state_kinds):
+                placed = self._place(waiting, active, now_s)
+                for req in placed:
+                    self._prefilling[req.slot] = [req, 0]
+                if placed:
+                    # the pages may be an evicted slot's: the device must
+                    # stop seeing them under that slot before a chunk, and
+                    # the decode steps between chunks, write anything
+                    self.kv.push()
+                row = next(iter(self._prefilling.values()), None)
+                if row is not None:
+                    req, done = row
+                    part = req.prompt[done:done + self.chunk]
+                    ids = np.zeros((1, self.chunk), np.int32)
+                    ids[0, :len(part)] = part
+            if row is None:
+                wave.cancel()
+                return False
+            wave.set(requests=1, requests_started=int(done == 0),
+                     prompt_tokens=len(part), padded_tokens=self.chunk,
+                     chunk_index=done // self.chunk,
+                     chunks_of_request=-(-len(req.prompt) // self.chunk),
+                     context_before=done)
+            return self._prefill_chunk(row, ids, len(part), active, next_host)
+
+    def _prefill_chunk(self, row: List[Any], ids: np.ndarray, length: int,
+                       active: Dict[int, Request],
+                       next_host: np.ndarray) -> bool:
+        """Run one chunk of `row`'s request (`length` real tokens in `ids`)
+        and take up what it brought: once the prompt is whole in its pages
+        the request gets its first token and joins the decode steps."""
+        req, done = row
+        lengths = np.array([length], np.int32)
+        context = np.array([done], np.int32)
+        page_rows = self.kv.prefill_row(req.slot)[None]
+        t_pre = time.perf_counter()
+        try:
+            with tel.span("serve/prefill/dispatch", cat="serve"):
+                # the chunk DONATES the cache state, as a decode step does
+                # (`_dispatch` says what a retry may assume)
+                tokens, state = run_resilient(
+                    "serve/prefill",
+                    lambda s=self.kv.state: self.engine.prefill_chunk(
+                        self.params, s,
+                        self.prompt_inputs_fn(ids, lengths, context),
+                        page_rows, context, lengths),
+                    policy=self.retry_policy)
+                stats = state.pop(STATS_KEY, None)
+                self.kv.adopt(state)
+        except Exception as e:  # noqa: BLE001 — permanent prefill fault:
+            self._prefilling.pop(req.slot, None)    # fail ONLY this request
+            self.kv.evict(req.slot)
+            self._fail(req, "failed", self._now(), e)
+            self.kv.push()
+            return False
+        with tel.span("serve/prefill/commit", cat="serve",
+                      state=self.kv.state_kinds, bytes=0):
+            pass    # the chunk appended to its slot's pages itself
+        self.prefills += 1
+        tok, t_first = self._first_tokens_to_host(tokens, stats, t_pre)
+        t_pre_off, t_first_off = t_pre - self._t0, t_first - self._t0
+        with tel.span("serve/prefill/first_tokens", cat="serve"):
+            if req.admit_s is None:
+                req.admit_s = t_pre_off     # its first chunk's dispatch
+            row[1] += length
+            if row[1] < len(req.prompt):
+                return False
+            del self._prefilling[req.slot]
+            self.kv.activate(req.slot, len(req.prompt))
+            first = int(tok[0])
+            req.tokens.append(first)
+            req.ttft_s = t_first_off - req.arrival_s
+            next_host[req.slot, 0] = first
+            active[req.slot] = req
+            if self.tracer is not None:
+                self.tracer.on_admit(req, req.admit_s, t_first_off,
+                                     wave=self.prefills)
+            tel.event("serve/request_admitted", cat="serve",
+                      rid=req.rid, slot=req.slot,
+                      prompt_len=len(req.prompt),
+                      priority=req.priority, ttft_s=req.ttft_s,
+                      queue_wait_s=max(0.0, req.admit_s - req.arrival_s))
+        self.kv.push()
+        return True
+
+    def _first_tokens_to_host(self, first_tokens, stats, t_pre: float):
+        """A wave's or a chunk's sync point, in two parts: waiting for the
+        device (the program's counters ride on that span), then moving the
+        bytes (TTFT is a real materialization). The second span keeps the
+        name it had when whole logits crossed here: its `bytes` say which it
+        was, 4 a row now. Returns (the tokens on the host, when they
+        were)."""
+        with tel.span("serve/prefill/device_wait", cat="serve") as sp:
+            jax.block_until_ready(first_tokens)
+            if stats is not None:
+                sp.set(**_stat_totals(jax.device_get([stats])))
+        with tel.span("serve/prefill/logits_to_host", cat="serve") as sp:
+            tok = np.asarray(first_tokens)
+            sp.set(bytes=int(tok.nbytes))
+        t_first = time.perf_counter()
+        serve_ms = 1e3 * (t_first - t_pre)
+        self._ema_serve_ms = (serve_ms if not self._ema_serve_ms
+                              else 0.5 * self._ema_serve_ms + 0.5 * serve_ms)
+        return tok, t_first
+
     def _reserved_tokens(self, req: Request) -> int:
         """Positions a request's admission reserves. Speculation slack: a
         verify pass caches up to K entries past the committed extent, so
@@ -511,7 +668,11 @@ class ContinuousBatchingScheduler:
         """Whether `_place` would place anybody now: a slot is free and the
         most urgent waiter's pages are (or the host tier can make room).
         A waiter held back by a short free list is no reason to empty the
-        pipeline: pages come back at a finish, which drains it anyway."""
+        pipeline: pages come back at a finish, which drains it anyway. A
+        prompt part-way through its chunks always has its next chunk to
+        run."""
+        if self._prefilling:
+            return True
         if not waiting or not self.kv.free_slots():
             return False
         return self.tiered or self.kv.can_admit(
@@ -542,7 +703,8 @@ class ContinuousBatchingScheduler:
                 run_resilient(
                     "serve/kv_admit",
                     lambda s=slot, r=req, n=need:
-                        self.kv.admit(s, len(r.prompt), n),
+                        self.kv.admit(s, len(r.prompt), n, prefilling=True)
+                        if self.chunk else self.kv.admit(s, len(r.prompt), n),
                     policy=self.retry_policy)
             except KVPoolExhausted:
                 break  # lost a race below can_admit: keep queued
@@ -611,22 +773,8 @@ class ContinuousBatchingScheduler:
             self.draft.kv.commit_prefill(
                 dkv_state, np.arange(self.slots, dtype=np.int32), lengths)
         self.prefills += 1
-        # one sync point in two parts: waiting for the device, then moving
-        # the bytes (TTFT is a real materialization). The span keeps the
-        # name it had when whole logits crossed here: its `bytes` say
-        # which it was, `slots * 4` now
-        with tel.span("serve/prefill/device_wait", cat="serve") as sp:
-            jax.block_until_ready(first_tokens)
-            if STATS_KEY in kv_state:
-                sp.set(**_stat_totals(
-                    jax.device_get([kv_state[STATS_KEY]])))
-        with tel.span("serve/prefill/logits_to_host", cat="serve") as sp:
-            tok = np.asarray(first_tokens)
-            sp.set(bytes=int(tok.nbytes))
-        t_first = time.perf_counter()
-        serve_ms = 1e3 * (t_first - t_pre)
-        self._ema_serve_ms = (serve_ms if not self._ema_serve_ms
-                              else 0.5 * self._ema_serve_ms + 0.5 * serve_ms)
+        tok, t_first = self._first_tokens_to_host(
+            first_tokens, kv_state.get(STATS_KEY), t_pre)
         t_pre_off = t_pre - self._t0
         t_first_off = t_first - self._t0
         with tel.span("serve/prefill/first_tokens", cat="serve"):
@@ -1194,6 +1342,7 @@ class ContinuousBatchingScheduler:
 
         while (queue or waiting or active or self.parked
                or self._finishing or self._pending_handoffs
+               or self._prefilling
                or (self.feed is not None and not self.feed.exhausted)):
             now = self._now()
             if self.feed is not None:
@@ -1271,6 +1420,8 @@ class ContinuousBatchingScheduler:
                 # (untiered runs keep the exact pre-PR dispatch sequence)
                 next_dev = jnp.asarray(next_host)
             if not active:
+                if self._prefilling:
+                    continue        # the next chunk, at once
                 if queue and not waiting:
                     # open loop: idle until the next arrival (short naps
                     # when watching, so snapshot polls keep happening)

@@ -959,6 +959,70 @@ def test_lfm2_moe_serving_programs_fit_one_chip(described_devices, mosaic,
     _assert_appends_in_place(decode, eng, staged_at_most=4)
 
 
+def test_keye_vl_serving_programs_fit_one_chip(described_devices, mosaic,
+                                               one_chip, monkeypatch):
+    """`Keye-VL-2.0-30B-A3B.serve-longprompt`'s two programs at the cell's
+    own sizes (16 slots of 16896 positions, 8.75 GB of bf16 weights: every
+    one of the 128 experts of all six layers; six layers page K/V 512 wide
+    and six indexers their key beside it), through the normal entry points:
+    the prompt program is the `[1, 2048]` chunk over the slots' own pages
+    (the `[16, 16896]` wave is never compiled), and the chip's compiler must
+    hold the chunk and the decode step beside the weights and the cache
+    (arguments + temporaries under 15.5 GB). The decode step's experts are
+    the step kernel at the width of 768, its indexer is `lax.top_k` and its
+    attention gathers the kept rows: no value of a whole slot context's K or
+    V exists in it. Both programs append to the pools they were handed."""
+    eng, g, params, state = _described_engine(
+        "Keye-VL-2.0-30B-A3B.serve-longprompt", described_devices,
+        monkeypatch, one_chip)
+    slots, spec = eng.slots, eng.kv_spec
+    assert (spec.layers, spec.heads, spec.head_dim) == (6, 4, 128)
+    assert (spec.index_layers, spec.index_dim) == (6, 64)
+    assert spec.pages_per_slot * spec.page_size == g.seq == 16896
+    assert len(eng.attn_layers) == 12 and len(eng.kv.index_layers) == 6
+    assert eng.kv.state_kinds == "paged_kv+paged_index"
+    assert eng.chunk_tokens == 2048
+    pages = slots * 1056 + 1
+    assert eng.kv.state["l0_attn"]["k"].shape == (pages, 16, 512)
+    assert eng.kv.state["l0_index"]["ik"].shape == (pages, 16, 128)
+    weights = sum(x.size * x.dtype.itemsize
+                  for x in jax.tree_util.tree_leaves(params))
+    assert weights == 2 * 4374622464
+    held = sum(x.size * x.dtype.itemsize
+               for x in jax.tree_util.tree_leaves(state))
+    assert 3.7e9 < held < 3.8e9
+    decode = eng._decode_jit.lower(
+        params, state, [_i32(one_chip, slots, 1), _i32(one_chip, slots, 1, 3),
+                        _i32(one_chip, slots, 1)]).compile()
+    chunk = eng._chunk_jit.lower(
+        params, state, [_i32(one_chip, 1, 2048), _i32(one_chip, 1, 2048, 3),
+                        _i32(one_chip, 1, 2048)],
+        _i32(one_chip, 1, 1056), _i32(one_chip, 1), _i32(one_chip, 1)
+    ).compile()
+    chip = 15.75e9          # what the compiler has of a v5e chip's 16 GB
+    for program in (decode, chunk):
+        m = program.memory_analysis()
+        assert 12.4e9 < m.argument_size_in_bytes
+        need = (m.argument_size_in_bytes + m.output_size_in_bytes
+                - m.alias_size_in_bytes + m.temp_size_in_bytes)
+        assert need < 15.5e9 < chip, (need, m)
+        assert m.alias_size_in_bytes >= held - 1e6
+    text = decode.as_text()
+    from flexflow_tpu import attribution
+    from flexflow_tpu.ops.moe_ops import EXPERTS_SCOPE
+    from flexflow_tpu.ops.sparse_attention_ops import (ATTEND_SCOPE,
+                                                       INDEX_SCOPE)
+    under = attribution.instructions_in_scope(text, EXPERTS_SCOPE)
+    assert sum(n.startswith("ff_moe_step") for n in under) == 6
+    assert "ragged-dot" not in text
+    assert attribution.instructions_in_scope(text, INDEX_SCOPE)
+    assert attribution.instructions_in_scope(text, ATTEND_SCOPE)
+    # a step gathers 2048 rows a slot of K and of V, never a slot's context
+    assert f"bf16[{slots},16896,512]" not in text
+    assert f"bf16[{slots},2048,512]" in text
+    assert attribution.instructions_in_scope(chunk.as_text(), INDEX_SCOPE)
+
+
 MOE_CELLS = {   # cell: (inputs of its programs, expert layers, a tile's tn)
     "granite-4.0-h-small.serve-chat": (2, 10, 768),
     "GigaChat3.1-702B-A36B.serve-chat": (3, 5, 512),
