@@ -22,6 +22,13 @@ the update of a slot's state and the float32 read-out of the new state on one
 tile, the state read from HBM once and written once in place, a slot that is
 not live never touched (ops/ssm_ops.py chooses it where a head's state is
 whole f32 tiles and a B/C group's heads whole sublane tiles).
+sparse_attend_step — one decode step's attention over the keys an indexer
+kept, for the live slots: a slot's pages under its position fetched by page
+from the K and V pools where they lie, online softmax under the indexer's
+mask (ops/attention_ops.py chooses it where a K/V head is whole 128-lane
+slabs and a page whole tiles).
+The three per-slot step kernels' grids walk `partition.live_order`: the live
+slots first, and their count.
 """
 
 from flexflow_tpu.kernels.dequant_attention import (  # noqa: F401

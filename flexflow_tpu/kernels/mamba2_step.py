@@ -13,12 +13,12 @@ with the op (a few KB a slot).
 
 The grid is (live slot, block of heads), as `kernels/retention_step.py`
 builds its own: the slot of a grid step is `order[i]`, a scalar-prefetched
-compaction of the live slots, and the first grid bound is their count, known
-at run time. A grid step holds `[hs, P, N]` of a slot's state as its block,
-input and output one buffer; Pallas's own double buffering brings the next
-block in and writes the last one back while this one is computed. On the
-tile, eight heads `[8, P, N]` (64 vregs at P 64, N 128) a turn of one short
-loop:
+compaction of the live slots (`partition.live_order`), and the first grid
+bound is their count, known at run time. A grid step holds `[hs, P, N]` of a
+slot's state as its block, input and output one buffer; Pallas's own double
+buffering brings the next block in and writes the last one back while this
+one is computed. On the tile, eight heads `[8, P, N]` (64 vregs at P 64, N
+128) a turn of one short loop:
 
 - a head's decay `exp(dt a)` is a scalar from SMEM (scalar-prefetched beside
   `order`); B and C of the turn's group are rows over the lanes (eight heads
@@ -42,6 +42,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from flexflow_tpu.kernels.flash_attention import _interpret
+from flexflow_tpu.kernels.partition import live_order
 
 LANES = 128
 SUBLANES = 8
@@ -107,8 +108,7 @@ def _call(state, decay, du, bm, cm, live, hs, interpret):
     b, heads, hd, n = state.shape
     groups = bm.shape[1]
     f32 = jnp.float32
-    order = jnp.argsort(jnp.logical_not(live), stable=True).astype(jnp.int32)
-    count = jnp.sum(live.astype(jnp.int32))
+    order, count = live_order(live)
 
     def of_slot(*block):        # a block of heads of the grid step's slot
         zeros = (0,) * (len(block) - 1)

@@ -12,9 +12,22 @@ On one device the kernel is called directly.
 
 from __future__ import annotations
 
+import jax.numpy as jnp
 from jax import shard_map
 
 from flexflow_tpu.parallel.sharding import used_axes
+
+
+def live_order(live):
+    """`live` `[b]` bool -> (`order` `[b]` int32: the live slots in rising
+    order, then the others; `count` int32: how many are live). What the grid
+    of a decode step's per-slot kernel walks on its one device
+    (`retention_step`, `mamba2_step`, `sparse_attend_step`): the slot of grid
+    step `i` is `order[i]`, scalar-prefetched, and the first grid bound is
+    `count`, known at run time, so a slot that is not live costs no grid
+    step and none of its bytes is touched."""
+    order = jnp.argsort(jnp.logical_not(live), stable=True).astype(jnp.int32)
+    return order, jnp.sum(live.astype(jnp.int32))
 
 
 def multi_device(mesh) -> bool:

@@ -22,11 +22,12 @@ decayed k_a k_b, a on the sublanes and b on the lanes, read with the same
 weights.
 
 The grid is (live slot, K/V head): the slot of a grid step is `order[i]`, a
-scalar-prefetched compaction of the live slots, and the first grid bound is
-their count, known at run time, so a slot that is not live costs nothing and
-its bytes are never touched. A grid step holds one head's whole `[R, D]`
-state (4.46 MB) as its block; Pallas's own double buffering brings the next
-head in and writes the last one back while this one is computed. On the tile:
+scalar-prefetched compaction of the live slots (`partition.live_order`), and
+the first grid bound is their count, known at run time, so a slot that is
+not live costs nothing and its bytes are never touched. A grid step holds one
+head's whole `[R, D]` state (4.46 MB) as its block; Pallas's own double
+buffering brings the next head in and writes the last one back while this one
+is computed. On the tile:
 
 - `k (x) v` once a head as a `[D, D]` slab (k_b on the sublanes: the
   transpose of k broadcast over a slab), and q_h likewise a query head;
@@ -57,6 +58,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from flexflow_tpu.kernels.flash_attention import _interpret
+from flexflow_tpu.kernels.partition import live_order
 
 LANES = 128
 SUBLANES = 8
@@ -170,8 +172,7 @@ def _call(state, total, q, k, v, gate, live, eps, interpret):
     group = q.shape[1] // kv
     padded = -(-group // SUBLANES) * SUBLANES
     f32 = jnp.float32
-    order = jnp.argsort(jnp.logical_not(live), stable=True).astype(jnp.int32)
-    count = jnp.sum(live.astype(jnp.int32))
+    order, count = live_order(live)
     q8 = jnp.pad(q.astype(f32).reshape(b, kv, group, d),
                  [(0, 0), (0, 0), (0, padded - group), (0, 0)])
     kvg = jnp.pad(jnp.stack(

@@ -30,10 +30,18 @@ With `selected` in the params the LAST input is the key set a learned indexer
 chose for each query (ops/sparse_attention_ops.py): one set a token for all
 heads. The whole sequence and a block of `s > 1` tokens over a slot's cache
 (a prefill chunk) take it as a membership mask `[batch, seq, keys]` and run
-dense under it, queries in blocks; a decode step takes the kept positions
-`[slots, 1, k]` and gathers their K and V rows from the pages, so that a step
-reads `k` rows a slot and not the slot's context. The work lies under the
-named scope `ff_sparse_attend`.
+dense under it, queries in blocks. A decode step takes the mask `[slots, 1,
+keys]` too, in one of two forms chosen from the cache's shapes and the mesh
+before tracing (`step_path`; a lowered layer says which in its
+`sparse_attend/step_path` span): ONE Pallas kernel a layer
+(kernels/sparse_attend_step.py, `ff_sparse_attend_step`: the live slots
+alone, each slot's pages under its position fetched by page from the pools
+where they lie, online softmax under the mask) where a K/V head is whole
+128-lane slabs, a page whole tiles and the program runs on one device; the
+XLA form everywhere else (every tiny model, a mesh): the mask compacted to
+the kept positions `[slots, 1, k]`, whose K and V rows it gathers from the
+pages, `k` a slot, every slot. The work lies under the named scope
+`ff_sparse_attend`.
 """
 
 from __future__ import annotations
@@ -48,14 +56,17 @@ from jax.sharding import PartitionSpec
 from typing import TYPE_CHECKING
 if TYPE_CHECKING:
     from flexflow_tpu.core.layer import Layer
+from flexflow_tpu import telemetry as tel
 from flexflow_tpu.core.tensor import TensorSpec
+from flexflow_tpu.kernels import sparse_attend_step
 from flexflow_tpu.kernels.partition import dividing, multi_device, per_shard
 from flexflow_tpu.ops.norm_ops import rms_norm
 from flexflow_tpu.ops.op_type import OperatorType
 from flexflow_tpu.ops.registry import register_op, LoweringCtx
 from flexflow_tpu.ops.rotary import apply_rope_half, half_tables
-from flexflow_tpu.ops.sparse_attention_ops import (ATTEND_SCOPE, over_context,
-                                                   query_blocks)
+from flexflow_tpu.ops.sparse_attention_ops import (ATTEND_SCOPE,
+                                                   kept_positions,
+                                                   over_context, query_blocks)
 
 
 def _mha_infer(layer: Layer):
@@ -246,8 +257,8 @@ def _mha_decode_lower(layer: Layer, inputs, weights, ctx: LoweringCtx):
     out = None
     if selected is not None:
         out = _selected_cache_attention(
-            qh.reshape(b, s, kvh, heads // kvh, hd), k_pool, v_pool, pt, t,
-            selected, scale, ctx)
+            layer, qh.reshape(b, s, kvh, heads // kvh, hd), k_pool, v_pool,
+            pt, t, selected, scale, ctx)
     elif quantized:
         # gather the int8 context + scales: [slots, L, h, (d)]
         Kq = k_pool[pt].reshape(b, -1, heads, hd)
@@ -297,37 +308,90 @@ def _mha_decode_lower(layer: Layer, inputs, weights, ctx: LoweringCtx):
     return [y]
 
 
-def _selected_cache_attention(qg, k_pool, v_pool, pt, t, selected, scale,
-                              ctx: LoweringCtx):
+def step_path(head_dim: int, page: int, pages_per_slot: int, pool_dtype,
+              mesh=None) -> dict:
+    """Which form a decode step's attention over the keys an indexer kept
+    takes, from the cache's shapes and the mesh the program is lowered for:
+    what a lowered layer reports in its `sparse_attend/step_path` span.
+    `{"path": "kernel", "block_pages": P}` (kernels/sparse_attend_step.py)
+    where a K/V head is whole 128-lane slabs, a page whole tiles of the
+    pools' type and the program runs on one device (GSPMD cannot partition a
+    Mosaic call); `{"path": "xla"}` everywhere else (every tiny model)."""
+    pages = None if multi_device(mesh) else sparse_attend_step.block_pages(
+        page, head_dim, jnp.dtype(pool_dtype).itemsize, pages_per_slot)
+    if pages is None:
+        return {"path": "xla"}
+    return {"path": "kernel", "block_pages": pages}
+
+
+def _selected_cache_attention(layer: Layer, qg, k_pool, v_pool, pt, t,
+                              selected, scale, ctx: LoweringCtx):
     """Attention of `qg` `[b, s, g, r, d]` at positions `t` `[b, s]` over the
-    keys an indexer chose among the slot's pages. A decode step (`selected`
-    `[b, 1, k]` int: the kept positions, `L` where fewer are kept) gathers
-    those rows of K and V from the pools, `k` a slot, and masks the places
-    that hold none. A block (`selected` `[b, s, L]` bool over the slot's
-    padded context) gathers the pages its context reaches once (the rung of
-    `sparse_attention_ops.context_rungs` that holds it) and runs dense under
-    the mask, queries in blocks, each K/V head against its group's r x block
-    query rows as one product. Reports `kv_bytes_gathered`: the K and V rows
-    a live slot's attention had to read."""
+    keys an indexer chose among the slot's pages: `selected` `[b, s, L]`
+    bool, its membership mask over the slot's padded context.
+
+    A decode step (`s == 1`) takes the form `step_path` says. The kernel
+    `ff_sparse_attend_step` (kernels/sparse_attend_step.py): the live slots
+    alone, each slot's pages under its position fetched by page where they
+    lie, online softmax under the mask; a slot that is not live costs nothing
+    and reads zeros. Or the XLA form: the mask compacted to the kept
+    positions (`kept_positions`: `k` a slot, `L` where fewer are kept), those
+    rows of K and V gathered from the pools, every slot's, and the places
+    that hold none masked. A block (`s > 1`) gathers the pages its context
+    reaches once (the rung of `sparse_attention_ops.context_rungs` that holds
+    it) and runs dense under the mask, queries in blocks, each K/V head
+    against its group's r x block query rows as one product.
+
+    Reports `kv_bytes_gathered`: the K and V rows a live slot's attention
+    had to read (the kept keys' in a step, in either form); a step also
+    `kv_bytes_streamed`, the pages' bytes the kernel fetched (the live slots'
+    pages under their positions, whole), and `sparse_attend_kernel_slots`,
+    its grid steps on the first axis: both 0 in the XLA form."""
     b, s, g, r, d = qg.shape
     dt = qg.dtype
     page = k_pool.shape[1]
+    padded = pt.shape[1] * page      # L: the slot's padded context
     row_bytes = 2.0 * g * d * k_pool.dtype.itemsize
     live = ctx.state["serve/active"] > 0
-    with jax.named_scope(ATTEND_SCOPE):
-        if jnp.issubdtype(selected.dtype, jnp.integer):
-            idx = jnp.minimum(selected[:, 0], pt.shape[1] * page - 1)  # [b, k]
-            rows = pt[jnp.arange(b)[:, None], idx // page] * page + idx % page
-            K = k_pool.reshape(-1, g * d)[rows].astype(dt)         # [b, k, g*d]
-            V = v_pool.reshape(-1, g * d)[rows].astype(dt)
-            keep = selected <= t[:, :, None]
-            ctx.add_stat("kv_bytes_gathered", row_bytes * jnp.sum(
-                jnp.where(live[:, None], jnp.sum(keep, axis=-1), 0)
-            ).astype(jnp.float32))
-            return _merged_axis_attention(qg, K, V, t, scale=scale, keep=keep)
+
+    def report_gathered(rows):
+        """`kv_bytes_gathered` from the K/V rows `[b]` a slot's attention
+        had to read."""
         ctx.add_stat("kv_bytes_gathered", row_bytes * jnp.sum(
-            jnp.where(live, jnp.minimum(t[:, -1] + 1, pt.shape[1] * page), 0)
-        ).astype(jnp.float32))
+            jnp.where(live, rows, 0)).astype(jnp.float32))
+
+    with jax.named_scope(ATTEND_SCOPE):
+        if s == 1:
+            report_gathered(jnp.sum(selected, axis=(1, 2)))
+            path = step_path(d, page, pt.shape[1], k_pool.dtype, ctx.mesh)
+            fetched = slots = jnp.int32(0)
+            # one span a lowered layer (trace time): the form its step took
+            with tel.span("sparse_attend/step_path", cat="compile",
+                          layer=layer.name, **path):
+                if path["path"] == "kernel":
+                    out = sparse_attend_step.sparse_attend_step(
+                        qg[:, 0], selected, k_pool, v_pool, pt, t[:, 0], live,
+                        scale, path["block_pages"])[:, None].astype(dt)
+                    reach = -(-jnp.minimum(t[:, 0] + 1, padded) // page)
+                    fetched = jnp.sum(jnp.where(live, reach, 0))
+                    slots = jnp.sum(live.astype(jnp.int32))
+                else:
+                    # the indexer's `topk`: the places the positions take
+                    topk = layer.inputs[-1].owner.params["topk"]
+                    # [b, 1, k]
+                    at = kept_positions(selected, min(topk, padded))
+                    idx = jnp.minimum(at[:, 0], padded - 1)
+                    rows = pt[jnp.arange(b)[:, None], idx // page] * page \
+                        + idx % page
+                    K = k_pool.reshape(-1, g * d)[rows].astype(dt)  # [b,k,g*d]
+                    V = v_pool.reshape(-1, g * d)[rows].astype(dt)
+                    out = _merged_axis_attention(qg, K, V, t, scale=scale,
+                                                 keep=at <= t[:, :, None])
+            ctx.add_stat("kv_bytes_streamed",
+                         row_bytes * page * fetched.astype(jnp.float32))
+            ctx.add_stat("sparse_attend_kernel_slots", slots)
+            return out
+        report_gathered(jnp.minimum(t[:, -1] + 1, padded))
 
         def over(pages):
             n = pages * page

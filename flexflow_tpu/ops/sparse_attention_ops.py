@@ -29,12 +29,12 @@ Three forms of one op, as attention has them:
   holds it: `context_rungs`, a `lax.switch`), and the output is the mask
   `[b, s, L]`;
 - a decode step (`s == 1`): the same append, the same threshold over the
-  slot's cached keys, and the output is the kept positions themselves `[b,
-  1, min(topk, L)]` int32 in rising order (`kept_positions`: the mask
-  compacted by counts and two small products, no sort, no scatter), of which
-  attention gathers the K/V rows. Where fewer than `topk` are cached the
-  places behind the last kept one hold `L`, which no query's `position <= t`
-  admits.
+  slot's cached keys, all `L` of them, and the output is the mask `[b, 1,
+  L]` as well. Attention's step reads it as it is (its kernel over the live
+  slots' pages) or compacts it first (`kept_positions`, below: the kept
+  positions `[.., min(topk, L)]` int32 in rising order by counts and two
+  small products, no sort, no scatter) and gathers those rows of K and V;
+  which, is attention's to choose (ops/attention_ops.py: `step_path`).
 
 The selection is exact. In the mask forms it is a threshold, not a sort: the
 scores as order-preserving integers, the `topk`-th largest of a row found
@@ -152,7 +152,8 @@ def keep_mask(scores, allowed, topk: int):
 
 def kept_positions(mask, k: int):
     """`[.., n]` bool with at most `k` set -> `[.., k]` int32: the set
-    positions in rising order, then `n` in the places that are left. No sort
+    positions in rising order, then `n` in the places that are left (what
+    the XLA form of attention's decode step gathers rows by). No sort
     and no scatter: the row in blocks of 128, a key's rank inside its block
     by one product with a triangle, the block of output place j from the
     blocks' running counts, that block's ranks by one product with a one-hot
@@ -213,11 +214,9 @@ def _infer(layer: Layer):
         "ww": TensorSpec((d, heads), x.dtype),
     }
     b, s = x.shape[:2]
-    if p.get("decode") and s == 1:
-        # the kept positions; over a cache shorter than topk, all of it
-        return [TensorSpec((b, s, p["topk"]), DataType.INT32)]
-    # the membership mask; in a block over a cache its key axis is the
-    # slot's padded context, which the cache knows and the graph does not
+    # the membership mask; over a cache (a block, a decode step) its key axis
+    # is the slot's padded context, which the cache knows and the graph does
+    # not
     return [TensorSpec((b, s, s), DataType.BOOL)]
 
 
@@ -290,7 +289,7 @@ def _lower_cached(layer: Layer, inputs, weights, ctx: LoweringCtx):
 
     with jax.named_scope(INDEX_SCOPE):
         if s == 1:
-            out = kept_positions(kept_over(pt.shape[1]), topk)
+            out = kept_over(pt.shape[1])
             kept = jnp.minimum(live, topk)
         else:
             out = over_context(kept_over, jnp.max(t) + 1, pt.shape[1], page)

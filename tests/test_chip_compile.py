@@ -32,7 +32,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)  # chip_smoke
 
 KERNEL_MODULES = ("flash_attention", "dequant_attention", "ssd_scan",
-                  "kda_scan", "retention_step", "moe_step", "mamba2_step")
+                  "kda_scan", "retention_step", "moe_step", "mamba2_step",
+                  "sparse_attend_step")
 
 # GPT-2 medium: batch 8, 16 heads of 64, seq 1024
 B, H, S, D = 8, 16, 1024, 64
@@ -969,9 +970,13 @@ def test_keye_vl_serving_programs_fit_one_chip(described_devices, mosaic,
     (the `[16, 16896]` wave is never compiled), and the chip's compiler must
     hold the chunk and the decode step beside the weights and the cache
     (arguments + temporaries under 15.5 GB). The decode step's experts are
-    the step kernel at the width of 768, its indexer is `lax.top_k` and its
-    attention gathers the kept rows: no value of a whole slot context's K or
-    V exists in it. Both programs append to the pools they were handed."""
+    the step kernel at the width of 768, its indexer finds the 2048th largest
+    score bit by bit (`keep_mask`: no sort, no `lax.top_k`) and hands the
+    membership mask on, and its attention is the kernel `ff_sparse_attend_step`
+    a layer, which fetches the live slots' pages from the pools where they
+    lie: no value of a slot's kept rows or of a whole slot context's K or V
+    exists in it, and no pool is copied. Both programs append to the pools
+    they were handed."""
     eng, g, params, state = _described_engine(
         "Keye-VL-2.0-30B-A3B.serve-longprompt", described_devices,
         monkeypatch, one_chip)
@@ -1016,10 +1021,17 @@ def test_keye_vl_serving_programs_fit_one_chip(described_devices, mosaic,
     assert sum(n.startswith("ff_moe_step") for n in under) == 6
     assert "ragged-dot" not in text
     assert attribution.instructions_in_scope(text, INDEX_SCOPE)
-    assert attribution.instructions_in_scope(text, ATTEND_SCOPE)
-    # a step gathers 2048 rows a slot of K and of V, never a slot's context
+    under = attribution.instructions_in_scope(text, ATTEND_SCOPE)
+    assert sum(n.startswith("ff_sparse_attend_step") for n in under) == 6
+    assert len(re.findall(r' custom-call\([^\n]*custom_call_target='
+                          r'"tpu_custom_call"[^\n]*ff_sparse_attend_step',
+                          text)) == 6
+    # a step holds neither a slot's kept rows of K or V nor its context's
     assert f"bf16[{slots},16896,512]" not in text
-    assert f"bf16[{slots},2048,512]" in text
+    assert f"bf16[{slots},2048,512]" not in text
+    # the pools go into the kernel where they lie: nothing copies one
+    pool = f"bf16[{pages},16,512]"
+    assert not re.search(rf"= {re.escape(pool)}\S* copy\(", text)
     assert attribution.instructions_in_scope(chunk.as_text(), INDEX_SCOPE)
 
 
