@@ -12,8 +12,13 @@ Where the reference uses Legion regions + FFMapper + NCCL
 GSPMD: a MachineView becomes an assignment of tensor dims to mesh axes, and the
 four parallel ops (Repartition/Combine/Replicate/Reduction) become reshardings.
 """
+from time import perf_counter_ns as _now
+
+_T0_NS = _now()     # before anything is imported: telemetry's epoch
 
 import jax as _jax
+
+_T_JAX_NS = _now()
 
 # Sharding-invariant RNG. With the legacy (non-partitionable) threefry,
 # jitting a random initializer with SHARDED out_shardings produces
@@ -34,6 +39,8 @@ from flexflow_tpu.optimizers import SGDOptimizer, AdamOptimizer
 from flexflow_tpu.losses import LossType
 from flexflow_tpu.metrics import MetricsType
 from flexflow_tpu.ops.op_type import OperatorType
+
+from flexflow_tpu import telemetry as _telemetry
 
 __version__ = "0.1.0"
 
@@ -71,3 +78,10 @@ __all__ = [
     "OperatorType",
     "compile_serving",
 ]
+
+# set-up's first span: what importing JAX and then this package's own
+# modules cost (jax.experimental.pallas lies behind ops -> kernels)
+_T_END_NS = _now()
+_telemetry.record("start/import", 0.0, (_T_END_NS - _T0_NS) / 1e3,
+                  cat="start", jax_s=(_T_JAX_NS - _T0_NS) / 1e9,
+                  package_s=(_T_END_NS - _T_JAX_NS) / 1e9)

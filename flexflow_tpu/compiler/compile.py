@@ -24,6 +24,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
+from jax._src import xla_bridge
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from flexflow_tpu import attribution, health
@@ -143,7 +144,14 @@ def compile_serving(model, **kwargs):
 def resolve_machine(cfg) -> MachineSpec:
     """The machine description every compile entry point shares (training
     `compile_model` and the serving `compile_serving`): an explicit machine
-    file wins, then the --nodes DCN description, then mesh-shape detection."""
+    file wins, then the --nodes DCN description, then mesh-shape detection.
+    A compile's first question to the backend is asked here, under
+    `start/backend`: it starts the backend (on a chip the TPU runtime,
+    seconds) unless the caller or an earlier compile has (`already_up`;
+    then microseconds)."""
+    with tel.span("start/backend", cat="start",
+                  already_up=bool(xla_bridge.backends_are_initialized())):
+        jax.devices()
     if cfg.machine_model_file:
         return MachineSpec.from_file(cfg.machine_model_file)
     if not cfg.mesh_shape and cfg.num_nodes > 1:
@@ -352,6 +360,15 @@ def build_init_fn(layers, overrides, topo_idx=None):
     return init_fn
 
 
+def weights_facts(params) -> Dict[str, int]:
+    """What an init span says of the weights it made (array metadata: no
+    wait for the device)."""
+    leaves = jax.tree_util.tree_leaves(params)
+    return {"parameters": sum(int(l.size) for l in leaves),
+            "bytes": sum(int(l.nbytes) for l in leaves),
+            "leaves": len(leaves)}
+
+
 @partial(jax.jit, donate_argnums=(0,))
 def _stacked_slice_set(stack, value, b):
     """Update slice b of a stacked (k, ...) weight in place, preserving its
@@ -506,7 +523,11 @@ class CompiledModel:
 
     # ---------------------------------------------------------------- init
     def init(self, seed: Optional[int] = None):
-        """Initialize weights sharded-at-birth (no host round trip)."""
+        """Initialize weights sharded-at-birth (no host round trip). The
+        span `compile/init` is the HOST's part of it: tracing, lowering,
+        compile (or cache read) and dispatch of the two init programs; both
+        are asynchronous, and the device's time to fill the weights is
+        waited for by whoever first needs them (warm-up's first step)."""
         seed = self.cfg.seed if seed is None else seed
         layers = topo_order(self.model.layers)
         overrides = self.model._initializer_overrides
@@ -520,14 +541,17 @@ class CompiledModel:
             }
 
         init_fn = build_init_fn(layers, overrides)
-        self.params = jax.jit(init_fn, out_shardings=shardings)(jax.random.PRNGKey(seed))
-        self.state = {}
-        # jitted with EXPLICIT out_shardings (vs the old eager tx.init):
-        # moments land directly in their target layout — sharded from the
-        # first byte under ZeRO, and never paying the transient
-        # fully-replicated allocation implicit propagation produced
-        self.opt_state = jax.jit(self.tx.init,
-                                 out_shardings=self._opt_sh)(self.params)
+        with tel.span("compile/init", cat="compile") as sp:
+            self.params = jax.jit(init_fn, out_shardings=shardings)(
+                jax.random.PRNGKey(seed))
+            self.state = {}
+            # jitted with EXPLICIT out_shardings (vs the old eager tx.init):
+            # moments land directly in their target layout — sharded from
+            # the first byte under ZeRO, and never paying the transient
+            # fully-replicated allocation implicit propagation produced
+            self.opt_state = jax.jit(self.tx.init,
+                                     out_shardings=self._opt_sh)(self.params)
+            sp.set(**weights_facts(self.params))
         self._iteration = 0
         # first HBM watermark: the persistent footprint right after init
         self._watermarks.sample("init", (self.params, self.opt_state))

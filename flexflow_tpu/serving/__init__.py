@@ -5,6 +5,9 @@ bandwidth-priced decode), a paged KV cache laid out by the winning decode
 strategy, and a continuous-batching scheduler driving a device-resident
 decode loop. Entry point: `compile_serving(model)`.
 """
+from flexflow_tpu import telemetry as _tel
+
+_T_START_US = _tel.now_us()
 
 from flexflow_tpu.serving import tracefmt
 from flexflow_tpu.serving.engine import ServingCompiled, compile_serving
@@ -48,3 +51,7 @@ __all__ = [
     "tracefmt", "Trace", "TraceRecord", "load_trace", "save_trace",
     "TwinSpec", "TwinCosts", "TwinResult", "simulate", "capacity_curve",
 ]
+
+# this package is imported lazily and pulls in the whole compiler stack:
+# its own stamp beside the package's `start/import`
+_tel.record("start/import_serving", _T_START_US, cat="start")

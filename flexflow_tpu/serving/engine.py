@@ -57,8 +57,9 @@ from flexflow_tpu.runtime.checkpoint import (CheckpointMismatchError,
                                              _graph_fingerprint)
 from flexflow_tpu.runtime.resilience import (RetryPolicy, committed_snapshots,
                                              run_resilient)
-from flexflow_tpu.compiler.compile import (build_init_fn, resolve_machine,
-                                           _overlay_parallel_ops)
+from flexflow_tpu.compiler.compile import (_overlay_parallel_ops,
+                                           build_init_fn, resolve_machine,
+                                           weights_facts)
 from flexflow_tpu.config import ensure_compile_cache
 from flexflow_tpu.compiler.lowering import build_forward, constrainable
 from flexflow_tpu.core.graph import topo_order
@@ -617,7 +618,10 @@ class ServingCompiled:
         """Weights sharded-at-birth in the DECODE strategy's layout (the
         steady-state program; prefill's jit reshards on entry via GSPMD).
         Identical names/specs/topo order to the training graph mean this is
-        bitwise-identical to CompiledModel.init of the same model."""
+        bitwise-identical to CompiledModel.init of the same model. The
+        span `serve/init` is the HOST's part: tracing, lowering, compile (or
+        cache read) and dispatch of the init program, which is asynchronous:
+        the device's time to fill the weights is waited for in warm-up."""
         seed = self.cfg.seed if seed is None else seed
         layers = topo_order(self.decode_model.layers)
         shardings = {
@@ -625,8 +629,10 @@ class ServingCompiled:
                          for w, s in layer.weight_specs.items()}
             for layer in layers if layer.weight_specs}
         init_fn = build_init_fn(layers, self.model._initializer_overrides)
-        self.params = jax.jit(init_fn, out_shardings=shardings)(
-            jax.random.PRNGKey(seed))
+        with tel.span("serve/init", cat="compile") as sp:
+            self.params = jax.jit(init_fn, out_shardings=shardings)(
+                jax.random.PRNGKey(seed))
+            sp.set(**weights_facts(self.params))
         self._watermarks.sample("serve_init", (self.params, self.kv.state))
         return self.params
 

@@ -111,6 +111,7 @@ and drives the engine without this scheduler).
 from __future__ import annotations
 
 import dataclasses
+import gc
 import threading
 import time
 from collections import deque
@@ -1323,7 +1324,17 @@ class ContinuousBatchingScheduler:
         fields filled. Shed and failed requests land in `self.shed` /
         `self.failed` with their outcome + reason stamped."""
         with tel.span("serve/run", cat="serve", requests=len(requests)):
-            return self._run(requests)
+            # what the process held before the run is out of the collector's
+            # sight while requests are in flight: a full collection that
+            # comes due inside the run walks what the run made, and not a
+            # set-up's quarter of a million objects with every slot waiting
+            # (70-85 ms, once in every window of Nemotron's cell: PERF.md
+            # Findings, PR 54). Two list splices; no collection is forced
+            gc.freeze()
+            try:
+                return self._run(requests)
+            finally:
+                gc.unfreeze()
 
     def _run(self, requests: List[Request]) -> List[Request]:
         self._t0 = time.perf_counter()

@@ -28,13 +28,25 @@ Design contract:
     Lines to `<dir>/telemetry-<pid>.jsonl`. `enabled()` says whether it
     is on; `event()` and `counter()` are sink-only.
   * The ring's clock is `time.perf_counter_ns()`. The sink's timestamps
-    are MICROSECONDS on the same clock since import (`now_us()`), so
+    are MICROSECONDS on the same clock since the first line of the
+    package's `__init__` (`now_us()`; `start/import` begins at 0), so
     events map 1:1 onto the Chrome trace-event format
     `tools/trace_report.py` renders (ph "X" complete span / "i" instant /
     "C" counter, ts/dur in us).
+  * A sink that opens is first given the ring's `cat="start"` records that
+    no sink has had (`start/import`, made before any `configure()` can
+    run): every compile entry point configures on its first line, so the
+    file then tells of set-up what the ring tells.
 
-Ring record: `Span(name, start_ns, end_ns, thread, parent, args, id)`;
+Ring record: `Span(name, start_ns, end_ns, thread, parent, args, id, cat)`;
 `parent` is the `id` of the span that was open on that thread (0: none).
+
+Set-up's anatomy (ISSUE 54), all before a benchmark's window: `start/import`
+and `start/import_serving` (the packages' own `__init__`), `start/backend`
+(`compile.resolve_machine`, a compile's first question to the backend),
+`compile/compile_model` / `serve/compile_serving` (the search),
+`compile/init` / `serve/init` (the weights), and JAX's compile phases under
+whichever span made the first call (`_on_jax_duration`).
 
 Sink record schema (one JSON object per line):
   {"name": str, "ph": "X"|"i"|"C", "ts": us, "dur": us (X only),
@@ -56,13 +68,20 @@ from typing import (Any, Dict, Iterable, List, NamedTuple, Optional,
 from jax import monitoring as _monitoring
 from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
+# the process epoch all sink ts are relative to: the package's first line
+# (this module is imported by it), so that `start/import` begins at 0
+from flexflow_tpu import _T0_NS
+
 _LOCK = threading.Lock()
 _SINK: Optional["_Sink"] = None
-_T0_NS = perf_counter_ns()  # process epoch all sink ts are relative to
+_UNSUNK_SINCE_NS = 0     # what ended since then has gone to no sink
 
 # the ring holds this many spans; a benchmark run must stay under it
-# (`ring_peak()`), a long-lived process keeps the newest
-RING_SIZE = 65536
+# (`ring_peak()`), a long-lived process keeps the newest. A traced run of
+# the busiest cell (LFM2's, 7 000 decode turns with their `serve/req/*`
+# stages) read a peak of 63 185 (my chip run, PR 54): 65 536 was one seed
+# from dropping the set-up's records, which are the oldest
+RING_SIZE = 131072
 ANNOTATION_PREFIX = "ff/"
 # a JAX compile phase shorter than this gets no ring record of its own
 JAX_SPAN_MIN_NS = 1_000_000
@@ -177,7 +196,15 @@ def configure(telemetry_dir: Optional[str],
             with _LOCK:
                 old.max_bytes = max_bytes
         return True
-    _SINK = _Sink(d, max_bytes=max_bytes)
+    new = _Sink(d, max_bytes=max_bytes)
+    # set-up's first spans (cat "start": the imports, the backend) are made
+    # before any configure() can run: a file begins with those that no
+    # file has had, and then tells of set-up what the ring tells
+    since = _UNSUNK_SINCE_NS if old is None else 0
+    for rec in list(_RING):
+        if rec.cat == "start" and rec.end_ns >= since:
+            new.emit(_sink_obj(rec))
+    _SINK = new
     if old is not None:
         old.close()
     _register_atexit()
@@ -186,10 +213,11 @@ def configure(telemetry_dir: Optional[str],
 
 def shutdown() -> None:
     """Disable telemetry and close the stream (flushes buffered lines)."""
-    global _SINK
+    global _SINK, _UNSUNK_SINCE_NS
     s, _SINK = _SINK, None
     if s is not None:
         s.close()
+        _UNSUNK_SINCE_NS = perf_counter_ns()
 
 
 def flush() -> None:
@@ -223,11 +251,12 @@ class Span(NamedTuple):
     parent: int                     # id of the enclosing span, 0 = none
     args: Optional[Dict[str, Any]]
     id: int
+    cat: Optional[str] = None
 
 
 class _ThreadState(threading.local):
     def __init__(self):
-        self.stack: List[int] = []      # ids of the spans open here
+        self.stack: List["_Span"] = []  # the spans open here, outermost first
         # (start ns, seconds) of this thread's compile phases that no
         # later phase has enclosed yet
         self.phases: "deque[Tuple[int, float]]" = deque(maxlen=4096)
@@ -247,23 +276,55 @@ _JAX_EVENTS = {
 # always on: name -> [count, seconds] since the process began (a phase's
 # seconds are its own: less the phases nested in it)
 totals: Dict[str, List[float]] = {n: [0, 0.0] for n in _JAX_EVENTS.values()}
-# phases under JAX_SPAN_MIN_NS since the last flush:
-# name -> [count, seconds, first start ns, last end ns]
-_SMALL: Dict[str, List[Any]] = {}
+# phases under JAX_SPAN_MIN_NS since the last flush, by what made them:
+# (phase, thread, anchor span id, direct parent's name, fun_name)
+#   -> [count, seconds, first start ns, last end ns]
+_SMALL: Dict[Tuple[Any, ...], List[Any]] = {}
 
 
 def _flush_small() -> None:
-    """One ring record per name for the short compile phases gathered
-    since the last flush (`args` holds their count and summed own seconds).
-    Flushed whenever a root span opens and before the ring is read, so
-    every phase that ended before a root span lies before it in the
-    ring, and 461 Mosaic lowerings are one record, not 461."""
+    """One ring record a key for the short compile phases gathered since
+    the last flush: `parent` is the key's anchor, `args` hold the function
+    that was traced or lowered (`fun`), how many phases (`count`), their
+    summed own `seconds` and, where the anchor is not the direct parent,
+    that parent's name (`under`). Flushed whenever a root span opens and
+    before the ring is read, so every phase that ended before a root span
+    lies before it in the ring. The keys are programs x function names:
+    the largest set-up (Keye-VL's chunk cell) is 785 ring records in all
+    before its window, spans and compile phases of their own included (my
+    chip run, PR 54), against a ring of 131 072."""
     with _LOCK:
         gathered = list(_SMALL.items())
         _SMALL.clear()
-    for name, (count, secs, start, end) in gathered:
-        _emit(name, start, end, "compile", "jax", 0,
-              {"count": count, "seconds": secs}, next(_IDS))
+    for (name, thread, anchor, under, fun), (count, secs, start, end) \
+            in gathered:
+        args = {"fun": fun, "count": count, "seconds": secs}
+        if under is not None:
+            args["under"] = under
+        _emit(name, start, end, "compile", thread, anchor, args, next(_IDS))
+
+
+def _small_key(name: str, fun: Optional[str]) -> Tuple[Any, ...]:
+    """What a short phase is gathered under: the span open on this thread
+    (its program) and the function. A trace-time span opens once a lowered
+    CALL (`lower/flash_attention`, `ssm/step_path`, every `cat="compile"`
+    span inside a first call), so a phase right under one is keyed by that
+    span's NAME and anchored at the nearest span above it that is not
+    `cat="compile"` (the dispatch, the admission), or at the outermost
+    where all are (the search): the records stay as few as the programs
+    times the functions, not as many as the calls."""
+    thread = threading.current_thread().name
+    stack = _TLS.stack
+    if not stack:
+        return (name, thread, 0, None, fun)
+    top = stack[-1]
+    anchor = top
+    for sp in reversed(stack):
+        anchor = sp
+        if sp._cat != "compile":
+            break
+    return (name, thread, anchor.id,
+            None if anchor is top else top.name, fun)
 
 
 def _on_jax_duration(event: str, secs: float, **kw: Any) -> None:
@@ -281,12 +342,15 @@ def _on_jax_duration(event: str, secs: float, **kw: Any) -> None:
         own -= recent.pop()[1]
     recent.append((start, secs))
     own = max(0.0, own)
+    fun = kw.get("fun_name")
+    short = end - start < JAX_SPAN_MIN_NS
+    key = _small_key(name, fun) if short else None
     with _LOCK:
         tot = totals[name]
         tot[0] += 1
         tot[1] += own
-        if end - start < JAX_SPAN_MIN_NS:
-            small = _SMALL.setdefault(name, [0, 0.0, start, end])
+        if short:
+            small = _SMALL.setdefault(key, [0, 0.0, start, end])
             small[0] += 1
             small[1] += own
             small[3] = end
@@ -294,8 +358,8 @@ def _on_jax_duration(event: str, secs: float, **kw: Any) -> None:
     # parent = the span open on this thread: a compile inside fit/dispatch
     # or serve/decode/dispatch names the step that recompiled
     stack = _TLS.stack
-    _emit(name, start, end, "compile", None, stack[-1] if stack else 0,
-          {"fun": kw.get("fun_name"), "seconds": own}, next(_IDS))
+    _emit(name, start, end, "compile", None, stack[-1].id if stack else 0,
+          {"fun": fun, "seconds": own}, next(_IDS))
 
 
 _monitoring.register_event_duration_secs_listener(_on_jax_duration)
@@ -314,6 +378,7 @@ def ring_spans(name: Optional[str] = None,
 
 def ring_clear() -> None:
     global _PEAK
+    _flush_small()      # what is gathered for the ring goes with it
     _PEAK = max(_PEAK, len(_RING))
     _RING.clear()
 
@@ -343,15 +408,20 @@ def _emit(name: str, start_ns: int, end_ns: int, cat: Optional[str],
           span_id: int) -> None:
     """One finished span: into the ring, and into the file sink if one is
     configured."""
-    _RING.append(Span(name, start_ns, end_ns,
-                      tid if tid is not None
-                      else threading.current_thread().name,
-                      parent, args, span_id))
+    rec = Span(name, start_ns, end_ns,
+               tid if tid is not None else threading.current_thread().name,
+               parent, args, span_id, cat)
+    _RING.append(rec)
     s = _SINK
     if s is not None:
-        obj = _base(name, "X", (start_ns - _T0_NS) / 1e3, cat, args, tid=tid)
-        obj["dur"] = max(0.0, (end_ns - start_ns) / 1e3)
-        s.emit(obj)
+        s.emit(_sink_obj(rec))
+
+
+def _sink_obj(rec: Span) -> Dict[str, Any]:
+    obj = _base(rec.name, "X", (rec.start_ns - _T0_NS) / 1e3, rec.cat,
+                rec.args, tid=rec.thread)
+    obj["dur"] = max(0.0, (rec.end_ns - rec.start_ns) / 1e3)
+    return obj
 
 
 def record(name: str, start_us: float, end_us: Optional[float] = None,
@@ -367,7 +437,7 @@ def record(name: str, start_us: float, end_us: Optional[float] = None,
     start_ns = _T0_NS + round(start_us * 1e3)
     stack = _TLS.stack
     _emit(name, start_ns, start_ns + round((end - start_us) * 1e3), cat,
-          tid, stack[-1] if stack else 0, args or None, next(_IDS))
+          tid, stack[-1].id if stack else 0, args or None, next(_IDS))
 
 
 def event(name: str, cat: Optional[str] = None, **args: Any) -> None:
@@ -430,13 +500,13 @@ class _Span:
     def __enter__(self) -> "_Span":
         stack = _TLS.stack
         if stack:
-            self.parent = stack[-1]
+            self.parent = stack[-1].id
         else:
             self.parent = 0
             if _SMALL:
                 _flush_small()
         self.id = next(_IDS)
-        stack.append(self.id)
+        stack.append(self)
         # the annotation outside the stamps: the ring's span lies inside
         # the profiler's, by the cost of one clock read at each end
         self._ann.__enter__()

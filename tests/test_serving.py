@@ -362,6 +362,39 @@ def test_scheduler_continuous_batching(gpt2_serve, rng):
     assert len(eng.kv.free_pages) == eng.kv_spec.pool_pages - 1
 
 
+def test_a_run_keeps_the_heap_it_found_from_the_collector(gpt2_serve, rng):
+    """While requests are in flight a full collection walks what the run
+    itself made: the objects held before it are frozen when the first token
+    arrives and still when the last does, and handed back when the run ends
+    (a failed run too)."""
+    import gc as collector
+
+    eng, gc = gpt2_serve
+    before = collector.get_freeze_count()
+    held = len(collector.get_objects())
+    seen = []
+
+    class Stream(list):
+        def append(self, token):
+            seen.append(collector.get_freeze_count())
+            super().append(token)
+
+        def extend(self, tokens):
+            seen.append(collector.get_freeze_count())
+            super().extend(tokens)
+
+    reqs = [Request(rid=i, prompt=list(rng.integers(1, gc.vocab, size=3)),
+                    max_new_tokens=4, tokens=Stream()) for i in range(2)]
+    sched = ContinuousBatchingScheduler(eng, eng.params, gpt2_prompt_inputs,
+                                        gpt2_step_inputs)
+    assert len(sched.run(reqs)) == 2
+    assert len(seen) >= 4 and min(seen) >= before + held // 2
+    assert collector.get_freeze_count() <= before
+    with pytest.raises(TypeError):
+        sched.run(None)
+    assert collector.get_freeze_count() <= before
+
+
 # what the parent of ISSUE 29 served for this request set, with 4-D pools
 # (`[pages, page, heads, head_dim]`) and nothing donated: pinned once
 PARENT_TOKENS = {

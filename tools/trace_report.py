@@ -369,6 +369,112 @@ def flash_attention_lines(events: List[Dict[str, Any]]) -> List[str]:
             for what, n in sorted(calls.items())]
 
 
+# set-up's programs, by the span that makes the first call (telemetry's
+# docstring; benchmarks/readers/setup_span.py reads the same from the ring)
+_SETUP_INIT = ("serve/init", "compile/init")
+_SETUP_SEARCH = ("serve/compile_serving", "compile/compile_model")
+_SETUP_PROGRAMS = (
+    ("wave", ("serve/admit",)),
+    ("step", ("serve/decode/dispatch", "fit/dispatch")),
+    ("init", _SETUP_INIT), ("search", _SETUP_SEARCH))
+_SETUP_ROOTS = ("serve/run", "fit/call")
+
+
+def _union_us(spans: List[Dict[str, Any]], lo: float, hi: float) -> float:
+    total, reach = 0.0, lo
+    for ev in sorted(spans, key=lambda e: e["ts"]):
+        a, b = max(ev["ts"], reach), min(ev["ts"] + ev.get("dur", 0.0), hi)
+        if b > a:
+            total, reach = total + b - a, b
+    return total
+
+
+def setup_lines(events: List[Dict[str, Any]]) -> List[str]:
+    """One `[setup]` block a process that recorded `start/import`: why it
+    took as long as it did to come up. Import (JAX, the package, the
+    serving package), the backend's start, the search, weight init, JAX's
+    tracing + lowering by the program whose first call paid for it (a
+    compile phase lies inside that call's span on its thread), the backend
+    compiles, and the caller: from the end of `start/import` to the first
+    `serve/run` / `fit/call`, what lies under no span at all."""
+    import bisect
+
+    spans = [e for e in events if e.get("ph") == "X"]
+    lines = []
+    for imp in [e for e in spans if e["name"] == "start/import"]:
+        mine = [e for e in spans if e.get("pid") == imp.get("pid")]
+        thread = [e for e in mine if e.get("tid") == imp.get("tid")
+                  and not e["name"].startswith("jax/")]
+        dur = {}
+        for ev in mine:
+            dur[ev["name"]] = dur.get(ev["name"], 0.0) + ev.get("dur", 0.0)
+
+        def s(*names):
+            return sum(dur.get(n, 0.0) for n in names) / 1e6
+
+        a = imp.get("args") or {}
+        lines.append(
+            f"[setup] pid {imp.get('pid')}: import {s('start/import'):.2f}s "
+            f"(jax {a.get('jax_s', 0.0):.2f}s, package "
+            f"{a.get('package_s', 0.0):.2f}s)"
+            + "".join(f" + {n[len('start/'):]} {s(n):.2f}s" for n in sorted(dur)
+                      if n.startswith("start/import_")))
+        for ev in mine:
+            if ev["name"] == "start/backend":
+                up = (ev.get("args") or {}).get("already_up")
+                lines.append(f"[setup]   backend {ev['dur'] / 1e6:.2f}s "
+                             f"(already_up={up})")
+        roots = [e["ts"] for e in thread if e["name"] in _SETUP_ROOTS]
+        end = min(roots) if roots else max(e["ts"] + e.get("dur", 0.0)
+                                           for e in mine)
+        lo = imp["ts"] + imp["dur"]
+        caller = end - lo - _union_us(
+            [e for e in thread if e is not imp], lo, end)
+        made = next(
+            (f" ({e['args'].get('bytes', 0) / 1e9:.2f} GB, "
+             f"{e['args'].get('leaves')} leaves)"
+             for e in mine if e["name"] in _SETUP_INIT and e.get("args")), "")
+        lines.append(
+            f"[setup]   search {s(*_SETUP_SEARCH):.2f}s"
+            f"  init {s(*_SETUP_INIT):.2f}s{made}"
+            f"  caller {caller / 1e6:.2f}s (from import's end to "
+            f"{'the first ' + '/'.join(_SETUP_ROOTS) if roots else 'the last event'}"
+            ", under no span)")
+        # a compile phase's program: the span of that name on its thread
+        # that holds it (spans of one name do not overlap on a thread)
+        holders = {}
+        for label, names in _SETUP_PROGRAMS:
+            for ev in mine:
+                if ev["name"] in names:
+                    holders.setdefault((label, ev.get("tid")), []).append(
+                        (ev["ts"], ev["ts"] + ev.get("dur", 0.0)))
+        for found in holders.values():
+            found.sort()
+        by_program: Dict[str, float] = {}
+        compiled = 0.0
+        for ev in mine:
+            secs = (ev.get("args") or {}).get("seconds")
+            if secs is None or not ev["name"].startswith("jax/"):
+                continue
+            if ev["name"] == "jax/backend_compile":
+                compiled += secs
+                continue
+            program = "other"
+            for label, _names in _SETUP_PROGRAMS:
+                found = holders.get((label, ev.get("tid")), [])
+                i = bisect.bisect_right(found, (ev["ts"], float("inf"))) - 1
+                if i >= 0 and found[i][1] >= ev["ts"] + ev.get("dur", 0.0):
+                    program = label
+                    break
+            by_program[program] = by_program.get(program, 0.0) + secs
+        lines.append(
+            f"[setup]   trace+lower {sum(by_program.values()):.2f}s: "
+            + ", ".join(f"{p} {v:.2f}s" for p, v in sorted(
+                by_program.items(), key=lambda kv: -kv[1]))
+            + f"; backend compile {compiled:.2f}s")
+    return lines
+
+
 def render(path: str, out_path: Optional[str] = None, top: int = 0,
            quiet: bool = False) -> Dict[str, Any]:
     """The full report: summary rows + chrome doc + derived sections.
@@ -430,8 +536,9 @@ def render(path: str, out_path: Optional[str] = None, top: int = 0,
             if ev.get("name") == "serve/compile_serving" and ev.get("args"):
                 print("[serve] compile_serving: " + " ".join(
                     f"{k}={v}" for k, v in sorted(ev["args"].items())))
-        for line in decode_loop_lines(events) + expert_layer_lines(events) \
-                + recurrent_only_lines(events) + flash_attention_lines(events):
+        for line in setup_lines(events) + decode_loop_lines(events) \
+                + expert_layer_lines(events) + recurrent_only_lines(events) \
+                + flash_attention_lines(events):
             print(line)
         for ev in errors:
             print(f"[error] {ev['name']}: {ev.get('args', {})}")
