@@ -27,6 +27,11 @@ kept, for the live slots: a slot's pages under its position fetched by page
 from the K and V pools where they lie, online softmax under the indexer's
 mask (ops/attention_ops.py chooses it where a K/V head is whole 128-lane
 slabs and a page whole tiles).
+sparse_attend_chunk — a prefill chunk's attention over the keys an indexer
+kept: dense under the indexer's mask over the slot's gathered pages, tiles of
+queries against tiles of keys up to each query block's last position, the
+scores and the step kernel's online softmax in VMEM (ops/attention_ops.py
+chooses it from the same facts and the chunk's length).
 The three per-slot step kernels' grids walk `partition.live_order`: the live
 slots first, and their count.
 """

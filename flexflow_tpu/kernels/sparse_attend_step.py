@@ -82,6 +82,34 @@ def block_pages(page: int, head_dim: int, itemsize: int, pages_per_slot: int):
     return pages
 
 
+def softmax_block(q, k, v, keep, scale: float, m_s, l_s, acc_s, h):
+    """One key block into head `h`'s running softmax (what this kernel and
+    `kernels/sparse_attend_chunk.py` share): `q` `[rows, width]` against `k`,
+    `v` `[block, width]` under `keep`, bool, `[1, block]` (one set for every
+    row) or `[rows / r, block]` (the rows are `r` heads of the same queries,
+    head-major: one set a query for all of them). Float32 scores of the
+    operands' products, float32 running maximum `m_s[h]`, sum `l_s[h]`
+    (`[rows, LANES]`, a value a row) and accumulator `acc_s[h]` `[rows,
+    width]`, `probs` in `v`'s type against `v` with a float32 sum."""
+    def under(x, other):
+        if keep.shape[0] == 1:
+            return jnp.where(keep, x, other)
+        heads = (x.shape[0] // keep.shape[0],) + keep.shape
+        return jnp.where(keep[None], x.reshape(heads), other).reshape(x.shape)
+
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * scale
+    s = under(s, _NEG)                                      # [rows, block]
+    m_prev = m_s[h]                                         # [rows, LANES]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    p = under(jnp.exp(s - m_new[:, :1]), 0.0)
+    alpha = jnp.exp(m_prev - m_new)
+    l_s[h] = alpha * l_s[h] + jnp.sum(p, axis=-1, keepdims=True)
+    acc_s[h] = alpha[:, :1] * acc_s[h] + jnp.dot(
+        p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+    m_s[h] = m_new
+
+
 def _kernel(order_ref, count_ref, ctx_ref, table_ref, q_ref, keep_ref, k_hbm,
             v_hbm, o_ref, kbuf, vbuf, sems, buf_ref, m_s, l_s, acc_s, *,
             scale: float, pages: int, page: int, per_slot: int):
@@ -202,19 +230,8 @@ def _kernel(order_ref, count_ref, ctx_ref, table_ref, q_ref, keep_ref, k_hbm,
         keep = jnp.logical_and(keep_ref[0] != 0, at < ctx)      # [1, block]
         for h in range(spans):
             lanes = slice(h * width, (h + 1) * width)
-            s = jax.lax.dot_general(
-                q_ref[0, h], kbuf[buf, :, lanes], (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale     # [rows, block]
-            s = jnp.where(keep, s, _NEG)
-            m_prev = m_s[h]                                     # [rows, LANES]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-            p = jnp.where(keep, jnp.exp(s - m_new[:, :1]), 0.0)
-            alpha = jnp.exp(m_prev - m_new)
-            l_s[h] = alpha * l_s[h] + jnp.sum(p, axis=-1, keepdims=True)
-            acc_s[h] = alpha[:, :1] * acc_s[h] + jnp.dot(
-                p.astype(vbuf.dtype), vbuf[buf, :, lanes],
-                preferred_element_type=jnp.float32)
-            m_s[h] = m_new
+            softmax_block(q_ref[0, h], kbuf[buf, :, lanes],
+                          vbuf[buf, :, lanes], keep, scale, m_s, l_s, acc_s, h)
 
         @pl.when(last)
         def _():

@@ -28,20 +28,26 @@ sections (ops/rotary.py).
 
 With `selected` in the params the LAST input is the key set a learned indexer
 chose for each query (ops/sparse_attention_ops.py): one set a token for all
-heads. The whole sequence and a block of `s > 1` tokens over a slot's cache
-(a prefill chunk) take it as a membership mask `[batch, seq, keys]` and run
-dense under it, queries in blocks. A decode step takes the mask `[slots, 1,
-keys]` too, in one of two forms chosen from the cache's shapes and the mesh
-before tracing (`step_path`; a lowered layer says which in its
-`sparse_attend/step_path` span): ONE Pallas kernel a layer
-(kernels/sparse_attend_step.py, `ff_sparse_attend_step`: the live slots
+heads, a membership mask `[batch, seq, keys]`. The whole sequence runs dense
+under it, queries in blocks. Over a slot's cache each of the two block
+lengths has two forms, chosen from the cache's shapes, the block and the mesh
+before tracing (`step_path`, `chunk_path`; a lowered layer says which in its
+`sparse_attend/step_path` or `sparse_attend/chunk_path` span): a Pallas
+kernel where a K/V head is whole 128-lane slabs, a page whole tiles and the
+program runs on one device; an XLA form everywhere else (every tiny model, a
+mesh). A block of `s > 1` tokens (a prefill chunk) is dense under the mask:
+ONE kernel a layer (kernels/sparse_attend_chunk.py, `ff_sparse_attend_chunk`:
+the slot's pages gathered once as the pools hold them, tiles of queries
+against tiles of keys up to each query block's last position, the float32
+scores and the online softmax in VMEM), or the XLA form over the rung of the
+slot's pages that holds the context, queries in blocks, whose scores pass
+through HBM. A decode step takes the mask `[slots, 1, keys]`: ONE kernel a
+layer (kernels/sparse_attend_step.py, `ff_sparse_attend_step`: the live slots
 alone, each slot's pages under its position fetched by page from the pools
-where they lie, online softmax under the mask) where a K/V head is whole
-128-lane slabs, a page whole tiles and the program runs on one device; the
-XLA form everywhere else (every tiny model, a mesh): the mask compacted to
-the kept positions `[slots, 1, k]`, whose K and V rows it gathers from the
-pages, `k` a slot, every slot. The work lies under the named scope
-`ff_sparse_attend`.
+where they lie, the same online softmax under the mask), or the XLA form: the
+mask compacted to the kept positions `[slots, 1, k]`, whose K and V rows it
+gathers from the pages, `k` a slot, every slot. The work lies under the named
+scope `ff_sparse_attend`.
 """
 
 from __future__ import annotations
@@ -58,13 +64,14 @@ if TYPE_CHECKING:
     from flexflow_tpu.core.layer import Layer
 from flexflow_tpu import telemetry as tel
 from flexflow_tpu.core.tensor import TensorSpec
-from flexflow_tpu.kernels import sparse_attend_step
+from flexflow_tpu.kernels import sparse_attend_chunk, sparse_attend_step
 from flexflow_tpu.kernels.partition import dividing, multi_device, per_shard
 from flexflow_tpu.ops.norm_ops import rms_norm
 from flexflow_tpu.ops.op_type import OperatorType
 from flexflow_tpu.ops.registry import register_op, LoweringCtx
 from flexflow_tpu.ops.rotary import apply_rope_half, half_tables
 from flexflow_tpu.ops.sparse_attention_ops import (ATTEND_SCOPE,
+                                                   context_rungs,
                                                    kept_positions,
                                                    over_context, query_blocks)
 
@@ -324,6 +331,23 @@ def step_path(head_dim: int, page: int, pages_per_slot: int, pool_dtype,
     return {"path": "kernel", "block_pages": pages}
 
 
+def chunk_path(head_dim: int, page: int, pages_per_slot: int, chunk: int,
+               pool_dtype, mesh=None) -> dict:
+    """Which form the attention of a block of `chunk > 1` tokens (a prefill
+    chunk) over the keys an indexer kept takes, from the same facts as
+    `step_path` and the block's length: what a lowered layer reports in its
+    `sparse_attend/chunk_path` span. `{"path": "kernel", "query_block": qb,
+    "key_block": kb}` (kernels/sparse_attend_chunk.py) where a K/V head is
+    whole 128-lane slabs, a page whole tiles of the pools' type, the chunk a
+    whole number of query blocks and the program runs on one device;
+    `{"path": "xla"}` everywhere else (every tiny model)."""
+    tiles = None if multi_device(mesh) else sparse_attend_chunk.chunk_tiles(
+        head_dim, page, pages_per_slot, chunk, jnp.dtype(pool_dtype).itemsize)
+    if tiles is None:
+        return {"path": "xla"}
+    return {"path": "kernel", "query_block": tiles[0], "key_block": tiles[1]}
+
+
 def _selected_cache_attention(layer: Layer, qg, k_pool, v_pool, pt, t,
                               selected, scale, ctx: LoweringCtx):
     """Attention of `qg` `[b, s, g, r, d]` at positions `t` `[b, s]` over the
@@ -337,16 +361,26 @@ def _selected_cache_attention(layer: Layer, qg, k_pool, v_pool, pt, t,
     and reads zeros. Or the XLA form: the mask compacted to the kept
     positions (`kept_positions`: `k` a slot, `L` where fewer are kept), those
     rows of K and V gathered from the pools, every slot's, and the places
-    that hold none masked. A block (`s > 1`) gathers the pages its context
-    reaches once (the rung of `sparse_attention_ops.context_rungs` that holds
-    it) and runs dense under the mask, queries in blocks, each K/V head
-    against its group's r x block query rows as one product.
+    that hold none masked.
+
+    A block (`s > 1`: a prefill chunk) runs dense under the mask in the form
+    `chunk_path` says. The kernel `ff_sparse_attend_chunk`
+    (kernels/sparse_attend_chunk.py): the slot's pages gathered once as the
+    pools hold them, tiles of queries against tiles of keys up to each query
+    block's last position, the scores and the online softmax in VMEM. Or the
+    XLA form (`_rung_attention`): the pages the context reaches (the rung of
+    `sparse_attention_ops.context_rungs` that holds it), queries in blocks,
+    each block's float32 scores a value of the program.
 
     Reports `kv_bytes_gathered`: the K and V rows a live slot's attention
-    had to read (the kept keys' in a step, in either form); a step also
-    `kv_bytes_streamed`, the pages' bytes the kernel fetched (the live slots'
-    pages under their positions, whole), and `sparse_attend_kernel_slots`,
-    its grid steps on the first axis: both 0 in the XLA form."""
+    had to read (the kept keys' in a step, in either form; the rows under a
+    block's last position); a step also `kv_bytes_streamed`, the pages' bytes
+    the kernel fetched (the live slots' pages under their positions, whole),
+    and `sparse_attend_kernel_slots`, its grid steps on the first axis; a
+    block `sparse_attend_chunk_tiles`, the (query block, key block) tiles its
+    kernel visited, and `sparse_attend_chunk_tiles_dense`, the tiles of the
+    rectangle the XLA form would compute (every query block against the
+    whole rung): all 0 in the XLA form."""
     b, s, g, r, d = qg.shape
     dt = qg.dtype
     page = k_pool.shape[1]
@@ -392,30 +426,63 @@ def _selected_cache_attention(layer: Layer, qg, k_pool, v_pool, pt, t,
             ctx.add_stat("sparse_attend_kernel_slots", slots)
             return out
         report_gathered(jnp.minimum(t[:, -1] + 1, padded))
+        path = chunk_path(d, page, pt.shape[1], s, k_pool.dtype, ctx.mesh)
+        end = jnp.max(t) + 1
+        tiles = dense = jnp.float32(0)
+        # one span a lowered layer (trace time): the form its block took
+        with tel.span("sparse_attend/chunk_path", cat="compile",
+                      layer=layer.name, **path):
+            if path["path"] == "kernel":
+                qb, kb = path["query_block"], path["key_block"]
+                # the slot's pages as the pools hold them: [b, L, g * d]
+                out, visited = sparse_attend_chunk.sparse_attend_chunk(
+                    qg, selected, k_pool[pt].reshape(b, padded, g * d),
+                    v_pool[pt].reshape(b, padded, g * d), t, scale, qb, kb)
+                out = out.astype(dt)
+                tiles = visited.astype(jnp.float32)
+                # the rectangle the XLA form computes: every query block
+                # against the rung that holds the chunk's last position
+                rungs = jnp.asarray(context_rungs(pt.shape[1]), jnp.float32)
+                rung = rungs[jnp.sum(end > rungs[:-1] * page)]
+                dense = rung * (b * s * page / float(qb * kb))
+            else:
+                out = over_context(
+                    functools.partial(_rung_attention, qg, k_pool, v_pool,
+                                      pt, selected, scale),
+                    end, pt.shape[1], page)
+        ctx.add_stat("sparse_attend_chunk_tiles", tiles)
+        ctx.add_stat("sparse_attend_chunk_tiles_dense", dense)
+        return out
 
-        def over(pages):
-            n = pages * page
-            # [b, g, n, d]: a K/V head's keys in a row, once a block
-            K = k_pool[pt[:, :pages]].reshape(b, n, g, d).astype(dt)
-            V = v_pool[pt[:, :pages]].reshape(b, n, g, d).astype(dt)
-            K, V = K.transpose(0, 2, 1, 3), V.transpose(0, 2, 1, 3)
 
-            def block(q, keep):
-                qb = q.shape[1]
-                rows = q.transpose(0, 2, 3, 1, 4).reshape(b, g, r * qb, d)
-                logits = jnp.einsum("bgmd,bgkd->bgmk", rows, K,
-                                    preferred_element_type=jnp.float32)
-                logits = jnp.where(
-                    keep[:, None, None, :, :n],
-                    logits.reshape(b, g, r, qb, n) * scale,
-                    jnp.finfo(jnp.float32).min).reshape(b, g, r * qb, n)
-                probs = jax.nn.softmax(logits, axis=-1).astype(dt)
-                out = jnp.einsum("bgmk,bgkd->bgmd", probs, V)
-                return out.reshape(b, g, r, qb, d).transpose(0, 3, 1, 2, 4)
+def _rung_attention(qg, k_pool, v_pool, pt, selected, scale, pages: int):
+    """The XLA form of a block's attention under the mask: `qg` `[b, s, g,
+    r, d]` over the first `pages` pages of every row's table, gathered once,
+    dense under `selected` `[b, s, L]`, queries in blocks, each K/V head
+    against its group's r x block query rows as one product with float32
+    scores (which pass through HBM: the kernel's reason)."""
+    b, s, g, r, d = qg.shape
+    dt = qg.dtype
+    n = pages * k_pool.shape[1]
+    # [b, g, n, d]: a K/V head's keys in a row, once a block
+    K = k_pool[pt[:, :pages]].reshape(b, n, g, d).astype(dt)
+    V = v_pool[pt[:, :pages]].reshape(b, n, g, d).astype(dt)
+    K, V = K.transpose(0, 2, 1, 3), V.transpose(0, 2, 1, 3)
 
-            return query_blocks(block, s, qg, selected)
+    def block(q, keep):
+        qb = q.shape[1]
+        rows = q.transpose(0, 2, 3, 1, 4).reshape(b, g, r * qb, d)
+        logits = jnp.einsum("bgmd,bgkd->bgmk", rows, K,
+                            preferred_element_type=jnp.float32)
+        logits = jnp.where(
+            keep[:, None, None, :, :n],
+            logits.reshape(b, g, r, qb, n) * scale,
+            jnp.finfo(jnp.float32).min).reshape(b, g, r * qb, n)
+        probs = jax.nn.softmax(logits, axis=-1).astype(dt)
+        out = jnp.einsum("bgmk,bgkd->bgmd", probs, V)
+        return out.reshape(b, g, r, qb, d).transpose(0, 3, 1, 2, 4)
 
-        return over_context(over, jnp.max(t) + 1, pt.shape[1], page)
+    return query_blocks(block, s, qg, selected)
 
 
 def _merged_axis_attention(qg, K, V, t, *, scale, keep=None):

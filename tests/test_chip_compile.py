@@ -33,7 +33,7 @@ sys.path.insert(0, ROOT)  # chip_smoke
 
 KERNEL_MODULES = ("flash_attention", "dequant_attention", "ssd_scan",
                   "kda_scan", "retention_step", "moe_step", "mamba2_step",
-                  "sparse_attend_step")
+                  "sparse_attend_step", "sparse_attend_chunk")
 
 # GPT-2 medium: batch 8, 16 heads of 64, seq 1024
 B, H, S, D = 8, 16, 1024, 64
@@ -975,8 +975,10 @@ def test_keye_vl_serving_programs_fit_one_chip(described_devices, mosaic,
     membership mask on, and its attention is the kernel `ff_sparse_attend_step`
     a layer, which fetches the live slots' pages from the pools where they
     lie: no value of a slot's kept rows or of a whole slot context's K or V
-    exists in it, and no pool is copied. Both programs append to the pools
-    they were handed."""
+    exists in it, and no pool is copied. The chunk's attention is the kernel
+    `ff_sparse_attend_chunk` a layer over the slot's gathered pages: no
+    float32 scores of 8 x 256 rows against a rung's keys exist in it. Both
+    programs append to the pools they were handed."""
     eng, g, params, state = _described_engine(
         "Keye-VL-2.0-30B-A3B.serve-longprompt", described_devices,
         monkeypatch, one_chip)
@@ -1032,7 +1034,20 @@ def test_keye_vl_serving_programs_fit_one_chip(described_devices, mosaic,
     # the pools go into the kernel where they lie: nothing copies one
     pool = f"bf16[{pages},16,512]"
     assert not re.search(rf"= {re.escape(pool)}\S* copy\(", text)
-    assert attribution.instructions_in_scope(chunk.as_text(), INDEX_SCOPE)
+    # a chunk's attention under the mask is the kernel, once a layer: its
+    # scores never leave VMEM (no float32 value of a query block's heads
+    # against a rung's keys), the rungs' `lax.switch` is the indexer's alone,
+    # and the pools go in by the page gather: nothing copies one
+    text = chunk.as_text()
+    assert attribution.instructions_in_scope(text, INDEX_SCOPE)
+    under = attribution.instructions_in_scope(text, ATTEND_SCOPE)
+    assert sum(n.startswith("ff_sparse_attend_chunk") for n in under) == 6
+    assert len(re.findall(r' custom-call\([^\n]*custom_call_target='
+                          r'"tpu_custom_call"[^\n]*ff_sparse_attend_chunk',
+                          text)) == 6
+    for keys in (4224, 8448, 12672, 16896):
+        assert not re.search(rf"f32\[[0-9,]*,2048,{keys}\]", text)
+    assert not re.search(rf"= {re.escape(pool)}\S* copy\(", text)
 
 
 MOE_CELLS = {   # cell: (inputs of its programs, expert layers, a tile's tn)
