@@ -139,6 +139,8 @@ class FFModel:
                             mrope_section: Optional[Sequence[int]] = None,
                             selected: Optional[Tensor] = None,
                             out_dim: int = 0,
+                            window: Optional[int] = None,
+                            rope_scaling: Optional[Dict[str, Any]] = None,
                             name=None) -> Tensor:
         # decode: single-token serving step reading/writing the paged KV
         # cache via lowering state; kv_out: prefill variant that exposes
@@ -152,7 +154,13 @@ class FFModel:
         # `[batch, seq, axes]` and the head's pairs follow them by these
         # sections; selected: the key set a `sparse_indexer` chose for each
         # query, the LAST input; out_dim: the output's width where it is not
-        # embed_dim (= num_heads * head_dim). All enter the params (and the inputs) only
+        # embed_dim (= num_heads * head_dim); window: a FIXED mask by
+        # position, query t sees the keys t - window < s <= t (0: every
+        # s <= t, the layer of a windowed model that sees the whole context:
+        # its cache attention is stated by position too); rope_scaling: YaRN
+        # for the positions' tables ({"factor", "original_max_position_
+        # embeddings", "beta_fast", "beta_slow", "attention_factor"}). All
+        # enter the params (and the inputs) only
         # where set, so graphs without them keep their fingerprints.
         params = {"embed_dim": int(embed_dim), "num_heads": int(num_heads), "kdim": kdim,
                   "vdim": vdim, "dropout": dropout, "bias": bias, "add_bias_kv": add_bias_kv,
@@ -173,6 +181,14 @@ class FFModel:
             params["selected"] = True
         if out_dim and int(out_dim) != int(embed_dim):
             params["out_dim"] = int(out_dim)
+        if window is not None:
+            if int(window) < 0:
+                raise ValueError(f"window {window} < 0")
+            params["window"] = int(window)
+        if rope_scaling:
+            params["rope_scaling"] = {
+                k: float(v) for k, v in rope_scaling.items()
+                if isinstance(v, (int, float))}
         return self._add_layer(
             OperatorType.MULTIHEAD_ATTENTION, params,
             [query, key, value] + ([positions] if positions is not None else [])

@@ -26,6 +26,16 @@ is applied to all `r` heads, and the online softmax is
 `sparse_attend_step.softmax_block`, the decode step's own. V's rows behind the
 query block's end are zeroed (0 x what lies behind the context is not 0 where
 that is not a number).
+
+The kept keys may also be stated BY POSITION (no mask operand:
+`ops/attention_ops.py: _bounded_cache_attention`): the query at `t` keeps `t
+- window < s <= t` (every `s <= t` at `window` 0), its block's queries one
+position after the other, the gathered keys' row 0 at position `base`. A
+query block then has a FIRST key block as well as a last (the one that holds
+its first query's first key), the grid's key axis counts from it, and its
+bound is the most blocks any query block spans: a window of 1024 behind 2048
+queries visits two or three key blocks of 1024 a query block, not the
+context's.
 """
 
 from __future__ import annotations
@@ -78,20 +88,28 @@ def chunk_tiles(head_dim: int, page: int, pages_per_slot: int, chunk: int,
     return qb, kb
 
 
-def _kernel(ends_ref, lasts_ref, q_ref, keep_ref, k_ref, v_ref, o_ref, m_s,
-            l_s, acc_s, *, scale: float, kb: int, blocks: int):
-    """One (row, K/V heads, query block, key block). ends_ref, lasts_ref `[b
-    * blocks]` in SMEM (`blocks` query blocks a row): a query block's last
-    position + 1 and the last key block that holds a position under it; q_ref
-    `[1, spans, r, qb, width]`; keep_ref `[1, qb, kb]` int8; k_ref, v_ref
-    `[1, kb, spans * width]`; o_ref as q_ref; m_s, l_s `[spans, r * qb,
-    LANES]`, acc_s `[spans, r * qb, width]` float32."""
-    row, i, j = pl.program_id(0), pl.program_id(2), pl.program_id(3)
+def _kernel(ends_ref, lasts_ref, firsts_ref, starts_ref, q_ref, *refs,
+            scale: float, kb: int, blocks: int, masked: bool, window: int):
+    """One (row, K/V heads, query block, key block). ends_ref, lasts_ref,
+    firsts_ref, starts_ref `[b * blocks]` in SMEM (`blocks` query blocks a
+    row): a query block's last position + 1, the last and the first key
+    block that hold a position one of its queries may see (the grid's key
+    axis counts from the first), and the position of its first query, all
+    positions counted from the first key row's; q_ref `[1, spans, r, qb,
+    width]`; with `masked` keep_ref `[1, qb, kb]` int8, without it the kept
+    keys of the query at `t` are the positions `t - window < s <= t` (`s <=
+    t` at `window` 0), its block's queries one position after the other;
+    k_ref, v_ref `[1, kb, spans * width]`; o_ref as q_ref; m_s, l_s `[spans,
+    r * qb, LANES]`, acc_s `[spans, r * qb, width]` float32."""
+    keep_ref = refs[0] if masked else None
+    k_ref, v_ref, o_ref, m_s, l_s, acc_s = refs[1:] if masked else refs
+    row, i = pl.program_id(0), pl.program_id(2)
     spans, r, qb, width = q_ref.shape[1:]
     end = ends_ref[row * blocks + i]
     last = lasts_ref[row * blocks + i]
+    j = pl.program_id(3) + (0 if masked else firsts_ref[row * blocks + i])
 
-    @pl.when(j == 0)
+    @pl.when(pl.program_id(3) == 0)
     def _():
         m_s[...] = jnp.full_like(m_s, _NEG)
         l_s[...] = jnp.zeros_like(l_s)
@@ -102,8 +120,15 @@ def _kernel(ends_ref, lasts_ref, q_ref, keep_ref, k_ref, v_ref, o_ref, m_s,
         # a last key block may reach past the context: what lies behind the
         # query block's end is not the mask's to say, nor V's to weigh
         at = j * kb + jax.lax.broadcasted_iota(jnp.int32, (1, kb), 1)
-        keep = jnp.logical_and(keep_ref[0].astype(jnp.int32) != 0,
-                               at < end)                        # [qb, kb]
+        if masked:
+            keep = jnp.logical_and(keep_ref[0].astype(jnp.int32) != 0,
+                                   at < end)                    # [qb, kb]
+        else:
+            t = starts_ref[row * blocks + i] \
+                + jax.lax.broadcasted_iota(jnp.int32, (qb, 1), 0)
+            keep = at <= t
+            if window:
+                keep = jnp.logical_and(keep, at > t - window)
         rows = j * kb + jax.lax.broadcasted_iota(jnp.int32, (kb, 1), 0)
         for h in range(spans):
             lanes = slice(h * width, (h + 1) * width)
@@ -121,38 +146,46 @@ def _kernel(ends_ref, lasts_ref, q_ref, keep_ref, k_ref, v_ref, o_ref, m_s,
             o_ref[0, h] = out.reshape(r, qb, width).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnums=(5, 6, 7, 8, 9))
-def _call(q, keep, k, v, t, scale, qb, kb, spans, interpret):
+@functools.partial(jax.jit, static_argnums=(6, 7, 8, 9, 10, 11))
+def _call(q, keep, k, v, t, base, scale, qb, kb, spans, window, interpret):
     from jax.experimental.pallas import tpu as pltpu
 
     b, g, r, s, width = q.shape
     context = k.shape[1]
     blocks = s // qb
+    masked = keep is not None
+    # positions from the first key row's on
+    t = (t - base[:, None]).reshape(b, blocks, qb)
     # a query block's last position + 1, within the keys there are
-    ends = jnp.clip(jnp.max(t.reshape(b, blocks, qb), axis=-1) + 1, 1,
-                    context).astype(jnp.int32)
+    ends = jnp.clip(jnp.max(t, axis=-1) + 1, 1, context).astype(jnp.int32)
     lasts = ((ends + kb - 1) // kb - 1).reshape(-1)
+    starts = t[:, :, 0].reshape(-1).astype(jnp.int32)
+    # the first key block a query of the block may see
+    firsts = jnp.clip(starts - window + 1, 0, context - 1) // kb \
+        if window else jnp.zeros_like(lasts)
 
-    def queries(row, h, i, j, ends, lasts):
+    def queries(row, h, i, j, ends, lasts, firsts, starts):
         return (row, h, 0, i, 0)
 
-    def keys(row, h, i, j, ends, lasts):
+    def keys(row, h, i, j, ends, lasts, firsts, starts):
         # a block behind the query block's end: the last one again
-        return (row, jnp.minimum(j, lasts[row * blocks + i]), h)
+        at = row * blocks + i
+        return (row, jnp.minimum(firsts[at] + j, lasts[at]), h)
 
-    def mask(row, h, i, j, ends, lasts):
+    def mask(row, h, i, j, ends, lasts, firsts, starts):
         return (row, i, jnp.minimum(j, lasts[row * blocks + i]))
 
     f32 = jnp.float32
     out = pl.pallas_call(
-        functools.partial(_kernel, scale=scale, kb=kb, blocks=blocks),
+        functools.partial(_kernel, scale=scale, kb=kb, blocks=blocks,
+                          masked=masked, window=window),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(b, g // spans, blocks, jnp.max(lasts) + 1),
-            in_specs=[pl.BlockSpec((1, spans, r, qb, width), queries),
-                      pl.BlockSpec((1, qb, kb), mask),
-                      pl.BlockSpec((1, kb, spans * width), keys),
-                      pl.BlockSpec((1, kb, spans * width), keys)],
+            num_scalar_prefetch=4,
+            grid=(b, g // spans, blocks, jnp.max(lasts - firsts) + 1),
+            in_specs=[pl.BlockSpec((1, spans, r, qb, width), queries)]
+            + ([pl.BlockSpec((1, qb, kb), mask)] if masked else [])
+            + [pl.BlockSpec((1, kb, spans * width), keys),
+               pl.BlockSpec((1, kb, spans * width), keys)],
             out_specs=pl.BlockSpec((1, spans, r, qb, width), queries),
             scratch_shapes=[pltpu.VMEM((spans, r * qb, LANES), f32),
                             pltpu.VMEM((spans, r * qb, LANES), f32),
@@ -164,15 +197,21 @@ def _call(q, keep, k, v, t, scale, qb, kb, spans, interpret):
             vmem_limit_bytes=_VMEM_LIMIT_BYTES),
         interpret=interpret,
         name="ff_sparse_attend_chunk",
-    )(ends.reshape(-1), lasts, q, keep.astype(jnp.int8), k, v)
-    return out, jnp.sum(lasts + 1)
+    )(ends.reshape(-1), lasts, firsts, starts, q,
+      *([keep.astype(jnp.int8)] if masked else []), k, v)
+    return out, jnp.sum(lasts - firsts + 1)
 
 
-def sparse_attend_chunk(qg, keep, k, v, t, scale: float, qb: int, kb: int):
+def sparse_attend_chunk(qg, keep, k, v, t, scale: float, qb: int, kb: int,
+                        base=None, window: int = 0):
     """qg `[b, s, g, r, d]` (g K/V heads of d, whole 128-lane slabs; r query
     heads a group), keep `[b, s, L]` bool (the indexer's kept keys over the
-    slot's padded context: False behind a query's position), k and v `[b, L,
-    g * d]` (the slot's pages gathered, as the pools hold them), t `[b, s]`
+    slot's padded context: False behind a query's position) or None (the
+    query at `t` keeps the positions `t - window < s <= t`, every `s <= t` at
+    `window` 0: no mask operand, a first key block as well as a last; the
+    queries of a row then lie one position after the other), k and v `[b, L,
+    g * d]` (the slot's pages gathered, as the pools hold them, row 0 at
+    position `base` `[b]` int32, 0 where None), t `[b, s]`
     int32 (the queries' positions, rising along a row), `qb` and `kb` as
     `chunk_tiles` says -> (`[b, s, g, r, d]` in k's type: softmax(q k^T
     scale) v over the kept keys, zeros for a query that kept none; the
@@ -180,6 +219,11 @@ def sparse_attend_chunk(qg, keep, k, v, t, scale: float, qb: int, kb: int):
     the layers of a program that call it at one shape trace its body once."""
     g = qg.shape[2]
     spans = _HEADS_A_STEP if g % _HEADS_A_STEP == 0 else 1
+    if keep is not None and (window or base is not None):
+        raise ValueError("sparse_attend_chunk takes a mask or position bounds")
+    if base is None:
+        base = jnp.zeros(t.shape[:1], t.dtype)
     out, tiles = _call(qg.transpose(0, 2, 3, 1, 4).astype(k.dtype), keep, k,
-                       v, t, float(scale), qb, kb, spans, _interpret())
+                       v, t, base, float(scale), qb, kb, spans, int(window),
+                       _interpret())
     return out.transpose(0, 3, 1, 2, 4), tiles

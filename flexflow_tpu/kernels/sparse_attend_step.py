@@ -36,6 +36,16 @@ mask (the indexer's membership AND `position <= t`: the buffer's rows behind
 `t` are not the slot's), float32 running maximum, sum and accumulator in
 VMEM scratch, `probs` in the pools' type against V with a float32 sum. V's
 pages that were not fetched are zeroed (0 x stale bits is not 0).
+
+The kept keys may also be stated BY POSITION (`first <= s <= t`, no mask
+operand: `ops/attention_ops.py: _bounded_cache_attention`, a layer of
+windowed attention or of full attention beside one): a slot's page walk
+then starts at the page that holds `first` and its blocks are counted from
+there, so a window of `w` keys fetches `w / page` pages and a page at each
+end whatever the context; with `ring` the slot's table is a ring whose entry
+`n % entries` holds page `n` of the context (one remainder a block, none a
+page). Under a mask `first` is 0 and the walk is the one above, instruction
+for instruction.
 """
 
 from __future__ import annotations
@@ -110,35 +120,58 @@ def softmax_block(q, k, v, keep, scale: float, m_s, l_s, acc_s, h):
     m_s[h] = m_new
 
 
-def _kernel(order_ref, count_ref, ctx_ref, table_ref, q_ref, keep_ref, k_hbm,
-            v_hbm, o_ref, kbuf, vbuf, sems, buf_ref, m_s, l_s, acc_s, *,
-            scale: float, pages: int, page: int, per_slot: int):
-    """One (live slot, key block). order_ref, ctx_ref `[b]` (the live slots
-    first; a slot's `t + 1`), count_ref `[1]` (the first grid bound: the
+def _kernel(order_ref, count_ref, ctx_ref, lo_ref, table_ref, q_ref, *refs,
+            scale: float, pages: int, page: int, per_slot: int, masked: bool,
+            ring: bool):
+    """One (live slot, key block). order_ref, ctx_ref, lo_ref `[b]` (the live
+    slots first; a slot's `t + 1`; the first position its query may see: 0
+    under a mask), count_ref `[1]` (the first grid bound: the
     interpreter has no `num_programs` of a bound known at run time) and
     table_ref `[b * per_slot]` in SMEM; q_ref `[1, spans, rows, width]`;
-    keep_ref `[1, 1, block]` int32; k_hbm, v_hbm `[pool pages, page, spans *
-    width]` in HBM; o_ref as q_ref, float32; kbuf, vbuf `[2, block, spans *
-    width]`; sems `[2, 2]` (buffer, pool); buf_ref `[1]` SMEM: the buffer
-    the next grid step reads."""
+    with `masked` keep_ref `[1, 1, block]` int32; k_hbm, v_hbm `[pool pages,
+    page, spans * width]` in HBM; o_ref as q_ref, float32; kbuf, vbuf `[2,
+    block, spans * width]`; sems `[2, 2]` (buffer, pool); buf_ref `[1]` SMEM:
+    the buffer the next grid step reads. A slot's blocks start at the page
+    that holds `lo`; with `ring` the table is a ring and page `n` of the
+    context lies at entry `n % per_slot`."""
     from jax.experimental.pallas import tpu as pltpu
 
+    keep_ref = refs[0] if masked else None
+    (k_hbm, v_hbm, o_ref, kbuf, vbuf, sems, buf_ref, m_s, l_s,
+     acc_s) = refs[1:] if masked else refs
     i, j = pl.program_id(0), pl.program_id(1)
     slot = order_ref[i]
     ctx = ctx_ref[slot]
     block = pages * page
-    blocks = (ctx + block - 1) // block
+
+    def first_page(slot):
+        """The page of the context that holds the slot's first position."""
+        return 0 if masked else lo_ref[slot] // page
+
+    def reach(slot):
+        """The pages from the slot's first to the last under its context."""
+        return (ctx_ref[slot] + page - 1) // page - first_page(slot)
+
+    blocks = (reach(slot) + pages - 1) // pages
     spans, rows, width = q_ref.shape[1:]
 
     def under(slot, blk):
         """The pages of (slot, blk) under the slot's context."""
-        return jnp.clip((ctx_ref[slot] + page - 1) // page - blk * pages, 0,
-                        pages)
+        return jnp.clip(reach(slot) - blk * pages, 0, pages)
+
+    def entry(slot, blk):
+        """The table entry of (slot, blk)'s first page (one remainder a
+        block on a ring, none a page)."""
+        at = first_page(slot) + blk * pages
+        return at % per_slot if ring else at
 
     def page_copy(slot, blk, buf, p, act):
         """`act` on the K and the V copy of page `p` of (slot, blk) into
         buffer `buf`."""
-        at = table_ref[slot * per_slot + blk * pages + p]
+        at = entry(slot, blk) + p
+        if ring:
+            at = jnp.where(at >= per_slot, at - per_slot, at)
+        at = table_ref[slot * per_slot + at]
         to = pl.ds(pl.multiple_of(p * page, page), page)
         act(pltpu.make_async_copy(k_hbm.at[at], kbuf.at[buf, to],
                                   sems.at[buf, 0]))
@@ -226,8 +259,10 @@ def _kernel(order_ref, count_ref, ctx_ref, table_ref, q_ref, keep_ref, k_hbm,
 
         wait(slot, j, buf)
 
-        at = j * block + jax.lax.broadcasted_iota(jnp.int32, (1, block), 1)
-        keep = jnp.logical_and(keep_ref[0] != 0, at < ctx)      # [1, block]
+        at = first_page(slot) * page + j * block \
+            + jax.lax.broadcasted_iota(jnp.int32, (1, block), 1)
+        keep = jnp.logical_and(keep_ref[0] != 0 if masked
+                               else at >= lo_ref[slot], at < ctx)  # [1, block]
         for h in range(spans):
             lanes = slice(h * width, (h + 1) * width)
             softmax_block(q_ref[0, h], kbuf[buf, :, lanes],
@@ -239,23 +274,29 @@ def _kernel(order_ref, count_ref, ctx_ref, table_ref, q_ref, keep_ref, k_hbm,
                 o_ref[0, h] = acc_s[h] / l_s[h][:, :1]
 
 
-@functools.partial(jax.jit, static_argnums=(7, 8, 9))
-def _call(q, keep, k_pool, v_pool, table, t, live, scale, pages, interpret):
+@functools.partial(jax.jit, static_argnums=(8, 9, 10, 11))
+def _call(q, keep, k_pool, v_pool, table, t, live, lo, scale, pages, ring,
+          interpret):
     from jax.experimental.pallas import tpu as pltpu
 
     b, spans, rows, width = q.shape
     page = k_pool.shape[1]
     per_slot = table.shape[1]
     block = pages * page
+    masked = keep is not None
     order, count = live_order(live)
-    ctx = jnp.minimum(t + 1, per_slot * page).astype(jnp.int32)
-    blocks = (ctx + block - 1) // block
+    # a ring holds any position; a table none past its last page
+    ctx = (t + 1 if ring else jnp.minimum(t + 1, per_slot * page)
+           ).astype(jnp.int32)
+    lo = jnp.zeros_like(ctx) if masked else jnp.clip(lo, 0, ctx - 1
+                                                      ).astype(jnp.int32)
+    blocks = ((ctx + page - 1) // page - lo // page + pages - 1) // pages
     reach = jnp.max(jnp.where(live, blocks, 0))
 
-    def of_slot(i, j, order, count, ctx, table):
+    def of_slot(i, j, order, count, ctx, lo, table):
         return (order[i], 0, 0, 0)
 
-    def keep_block(i, j, order, count, ctx, table):
+    def keep_block(i, j, order, count, ctx, lo, table):
         # a block behind the context: the last one again (nothing is moved)
         slot = order[i]
         last = jnp.maximum((ctx[slot] + block - 1) // block - 1, 0)
@@ -264,14 +305,14 @@ def _call(q, keep, k_pool, v_pool, table, t, live, scale, pages, interpret):
     f32 = jnp.float32
     out = pl.pallas_call(
         functools.partial(_kernel, scale=scale, pages=pages, page=page,
-                          per_slot=per_slot),
+                          per_slot=per_slot, masked=masked, ring=ring),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
+            num_scalar_prefetch=5,
             grid=(count, reach),
-            in_specs=[pl.BlockSpec((1, spans, rows, width), of_slot),
-                      pl.BlockSpec((1, 1, block), keep_block),
-                      pl.BlockSpec(memory_space=pl.ANY),
-                      pl.BlockSpec(memory_space=pl.ANY)],
+            in_specs=[pl.BlockSpec((1, spans, rows, width), of_slot)]
+            + ([pl.BlockSpec((1, 1, block), keep_block)] if masked else [])
+            + [pl.BlockSpec(memory_space=pl.ANY),
+               pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=pl.BlockSpec((1, spans, rows, width), of_slot),
             scratch_shapes=[
                 pltpu.VMEM((2, block, spans * width), k_pool.dtype),
@@ -287,21 +328,27 @@ def _call(q, keep, k_pool, v_pool, table, t, live, scale, pages, interpret):
             vmem_limit_bytes=_VMEM_LIMIT_BYTES),
         interpret=interpret,
         name="ff_sparse_attend_step",
-    )(order, count.reshape(1), ctx, table.reshape(-1).astype(jnp.int32), q,
-      keep.astype(jnp.int32), k_pool, v_pool)
+    )(order, count.reshape(1), ctx, lo, table.reshape(-1).astype(jnp.int32),
+      q, *([keep.astype(jnp.int32)] if masked else []), k_pool, v_pool)
     # a slot that is not live: no grid step wrote its rows
     return jnp.where(live[:, None, None, None], out, 0.0)
 
 
 def sparse_attend_step(qg, keep, k_pool, v_pool, table, t, live, scale: float,
-                       pages: int):
+                       pages: int, first=None, ring: bool = False):
     """qg `[b, g, r, d]` (g K/V heads of d, whole 128-lane slabs; r query
     heads a group), keep `[b, 1, L]` bool (the indexer's kept keys over the
-    slot's padded context), k_pool and v_pool `[pool pages, page, g * d]`
-    (read where they lie), table `[b, pages_per_slot]` int32, t `[b]` int32
-    (the step's position), live `[b]` bool, `pages` as `block_pages` says ->
-    `[b, g, r, d]` float32: softmax(q k^T scale) v over the kept keys at
-    positions <= t, 0 for a slot that is not live. Interpreted on the CPU;
-    the layers of a program that call it at one shape trace its body once."""
+    slot's padded context) or None (the kept keys are those at positions
+    `first <= s <= t`, `first` `[b]` int32: no mask operand, and the page walk
+    starts at the page that holds `first`; with `ring` the table is a ring
+    whose entry `n % pages_per_slot` holds page `n` of the context), k_pool
+    and v_pool `[pool pages, page, g * d]` (read where they lie), table `[b,
+    pages_per_slot]` int32, t `[b]` int32 (the step's position), live `[b]`
+    bool, `pages` as `block_pages` says -> `[b, g, r, d]` float32: softmax(q
+    k^T scale) v over the kept keys at positions <= t, 0 for a slot that is
+    not live. Interpreted on the CPU; the layers of a program that call it at
+    one shape trace its body once."""
+    if (keep is None) == (first is None):
+        raise ValueError("sparse_attend_step takes a mask or a first position")
     return _call(qg.astype(k_pool.dtype), keep, k_pool, v_pool, table, t,
-                 live, float(scale), pages, _interpret())
+                 live, first, float(scale), pages, bool(ring), _interpret())

@@ -48,6 +48,21 @@ where they lie, the same online softmax under the mask), or the XLA form: the
 mask compacted to the kept positions `[slots, 1, k]`, whose K and V rows it
 gathers from the pages, `k` a slot, every slot. The work lies under the named
 scope `ff_sparse_attend`.
+
+With `window` in the params the kept keys are a FIXED mask by position: query
+`t` sees the keys `t - window < s <= t` (itself among them), every `s <= t`
+at `window` 0 (the layer of a windowed model that sees the whole context).
+The whole sequence takes the causal mask with the window under it on the XLA
+path where `window < seq` (kernels/flash_attention.py knows no window yet).
+Over the cache both kinds take the two kernels above with the kept set stated
+by position (`first <= s <= t`; no mask operand, a page walk and a key axis
+that start at `first`) where `step_path` / `chunk_path` say so, an XLA form
+under the positions' mask elsewhere; a layer with `window > 0` keeps its K
+and V in a RING of pages a slot under `serve/window_table` (position `t` at
+entry `(t // page) % ring`, serving/kv_cache.py), one with `window` 0 in the
+slot's pages under `serve/page_table`. The work lies under the named scopes
+`ff_window_attend` and `ff_full_attend`. `rope_scaling` in the params: YaRN's
+tables (ops/rotary.py) for the layer's rotary positions.
 """
 
 from __future__ import annotations
@@ -74,6 +89,18 @@ from flexflow_tpu.ops.sparse_attention_ops import (ATTEND_SCOPE,
                                                    context_rungs,
                                                    kept_positions,
                                                    over_context, query_blocks)
+
+
+# the named scopes of a layer's cache attention under bounds by position:
+# a windowed layer's and a full one's (both phases)
+WINDOW_SCOPE = "ff_window_attend"
+FULL_SCOPE = "ff_full_attend"
+# the scope both lie under (what reads the two kinds together names this one)
+BOUNDED_SCOPE = "ff_bounded_attend"
+WINDOW_TABLE_KEY = "serve/window_table"
+# `[rows]` int: how many of a block's positions hold a token (the chunk
+# program says; absent in a step, whose every live slot's one position does)
+BLOCK_LENGTHS_KEY = "serve/block_lengths"
 
 
 def _mha_infer(layer: Layer):
@@ -117,6 +144,12 @@ def _mha_infer(layer: Layer):
     if p.get("qk_norm"):
         layer.weight_specs["q_norm"] = TensorSpec((embed // heads,), q.dtype)
         layer.weight_specs["k_norm"] = TensorSpec((embed // heads,), q.dtype)
+    if "window" in p and (not p.get("causal") or p.get("selected")
+                          or p.get("add_bias_kv") or p.get("add_zero_attn")):
+        raise NotImplementedError("a window on attention that is not plain "
+                                  "causal self-attention")
+    if p.get("rope_scaling") and not _has_positions(layer):
+        raise ValueError("rope_scaling without a positions input")
     return [q.with_shape(q.shape[:-1] + (out_dim,))]
 
 
@@ -140,7 +173,7 @@ def _turner(layer: Layer, inputs, weights):
     if _has_positions(layer):
         hd = p["embed_dim"] // p["num_heads"]
         cos, sin = half_tables(inputs[3], hd, p.get("rope_theta", 10000.0),
-                               p.get("mrope_section"))
+                               p.get("mrope_section"), p.get("rope_scaling"))
         tables = cos[:, :, None], sin[:, :, None]           # [b, s, 1, d]
 
     def turn(heads, norm):
@@ -235,14 +268,18 @@ def _mha_decode_lower(layer: Layer, inputs, weights, ctx: LoweringCtx):
     cache = ctx.state[layer.name]
     k_pool, v_pool = cache["k"], cache["v"]
     quantized = "k_scale" in cache
-    pt = ctx.state["serve/page_table"]
+    # a windowed layer's pages are its slot's ring, under a table of its own
+    window = int(p.get("window") or 0)
+    if "window" in p and quantized:
+        raise NotImplementedError("a window with a quantized cache")
+    pt = ctx.state[WINDOW_TABLE_KEY if window else "serve/page_table"]
     pos = ctx.state["serve/pos"]
     page = k_pool.shape[1]
     b, s = q.shape[0], q.shape[1]
     from flexflow_tpu.serving.kv_cache import (append_slots, kv_quantize,
                                                merge_heads)
 
-    t, pageix, off = append_slots(pt, pos, s, page)
+    t, pageix, off = append_slots(pt, pos, s, page, ring=bool(window))
 
     if quantized:
         qk, ks = kv_quantize(kh)
@@ -266,6 +303,10 @@ def _mha_decode_lower(layer: Layer, inputs, weights, ctx: LoweringCtx):
         out = _selected_cache_attention(
             layer, qh.reshape(b, s, kvh, heads // kvh, hd), k_pool, v_pool,
             pt, t, selected, scale, ctx)
+    elif "window" in p:
+        out = _bounded_cache_attention(
+            layer, qh.reshape(b, s, kvh, heads // kvh, hd), k_pool, v_pool,
+            pt, t, window, scale, ctx)
     elif quantized:
         # gather the int8 context + scales: [slots, L, h, (d)]
         Kq = k_pool[pt].reshape(b, -1, heads, hd)
@@ -290,7 +331,7 @@ def _mha_decode_lower(layer: Layer, inputs, weights, ctx: LoweringCtx):
         else:
             K = merge_heads((Kq.astype(jnp.float32) * Ks[..., None]).astype(dt))
             V = merge_heads((Vq.astype(jnp.float32) * Vs[..., None]).astype(dt))
-    elif selected is None:
+    else:
         # gather each slot's pages, heads still merged: [slots, L, kvh * d]
         K = k_pool[pt].reshape(b, -1, kvh * hd).astype(dt)
         V = v_pool[pt].reshape(b, -1, kvh * hd).astype(dt)
@@ -455,6 +496,114 @@ def _selected_cache_attention(layer: Layer, qg, k_pool, v_pool, pt, t,
         return out
 
 
+def _bounded_cache_attention(layer: Layer, qg, k_pool, v_pool, pt, t,
+                             window: int, scale, ctx: LoweringCtx):
+    """Attention of `qg` `[b, s, g, r, d]` at positions `t` `[b, s]` over the
+    slot's cached keys at positions `first <= s <= t`, `first` = `t - window
+    + 1` (a windowed layer: `pt` `[b, ring]` is the slot's RING, page `n` of
+    the context at entry `n % ring`) or 0 (`window` 0, a full layer: `pt` the
+    slot's pages in order). The forms are `_selected_cache_attention`'s, the
+    kept set stated by position in place of a membership mask: a decode step
+    (`s == 1`) the kernel `ff_sparse_attend_step` (the pages between `first
+    // page` and `t // page` fetched where they lie and no others), a block
+    the kernel `ff_sparse_attend_chunk` over the slot's pages gathered in the
+    context's order from the block's first visible page on (a first key
+    block as well as a last), where `step_path` / `chunk_path` say
+    "kernel"; elsewhere (every tiny model, a mesh) the XLA form: the table's
+    pages gathered, each entry's position reckoned, the bounds a mask.
+
+    Under the named scope `ff_window_attend` or `ff_full_attend` (both inside
+    `ff_bounded_attend`); the trace-time spans `window_attend/step_path`,
+    `full_attend/chunk_path`, .. say the form taken. Reports, `<kind>`
+    `window` or `full`: `<kind>_kv_bytes_needed` (the K and V rows a live
+    slot's queries may see: `min(pos + 1, window)` or `pos + 1` a step),
+    `<kind>_keys_seen` (the (query, key) pairs of the queries that exist, a
+    block's by `serve/block_lengths`, and the keys each may see),
+    `<kind>_kv_bytes_streamed` (whole pages fetched: a
+    step's walk, a block's gather; 0 for the XLA form of a step, which gathers
+    the table), and a block `<kind>_attend_chunk_tiles` (the (query block, key
+    block) tiles its kernel visited) and `<kind>_attend_chunk_tiles_dense`
+    (every query block against all the gathered keys), 0 in the XLA form."""
+    b, s, g, r, d = qg.shape
+    dt = qg.dtype
+    page, per_slot = k_pool.shape[1], pt.shape[1]
+    kind = "window" if window else "full"
+    row_bytes = 2.0 * g * d * k_pool.dtype.itemsize
+    live = ctx.state["serve/active"] > 0
+    first = jnp.maximum(t - window + 1, 0) if window else jnp.zeros_like(t)
+    # a table holds no position past its last page; a ring any
+    last = t[:, -1] if window else jnp.minimum(t[:, -1], per_slot * page - 1)
+
+    def report(name, per_slot_count, unit):
+        ctx.add_stat(f"{kind}_{name}", unit * jnp.sum(
+            jnp.where(live, per_slot_count, 0)).astype(jnp.float32))
+
+    def xla_form():
+        """The table's pages gathered `[b, L, g * d]`, each row's position
+        (a ring's entry `e` holds the newest page `n <= last // page` with
+        `n % ring == e`), the bounds a mask `[b, s, L]`."""
+        K = k_pool[pt].reshape(b, -1, g * d).astype(dt)
+        V = v_pool[pt].reshape(b, -1, g * d).astype(dt)
+        entry = jnp.broadcast_to(jnp.arange(per_slot), (b, per_slot))
+        held = entry if not window else \
+            (last // page)[:, None] - ((last // page)[:, None] - entry) \
+            % per_slot
+        at = (held[:, :, None] * page + jnp.arange(page)).reshape(b, 1, -1)
+        keep = (at >= first[:, :, None]) & (at <= t[:, :, None])
+        return _merged_axis_attention(qg, K, V, t, scale=scale, keep=keep)
+
+    report("kv_bytes_needed", last - first[:, 0] + 1, row_bytes)
+    lengths = ctx.state.get(BLOCK_LENGTHS_KEY)
+    exists = True if lengths is None else \
+        jnp.arange(s)[None, :] < lengths[:, None]
+    report("keys_seen", jnp.sum(jnp.where(exists, t - first + 1, 0), axis=1),
+           1.0)
+    with jax.named_scope(BOUNDED_SCOPE), \
+            jax.named_scope(WINDOW_SCOPE if window else FULL_SCOPE):
+        if s == 1:
+            path = step_path(d, page, per_slot, k_pool.dtype, ctx.mesh)
+            with tel.span(f"{kind}_attend/step_path", cat="compile",
+                          layer=layer.name, window=window, **path):
+                if path["path"] == "kernel":
+                    out = sparse_attend_step.sparse_attend_step(
+                        qg[:, 0], None, k_pool, v_pool, pt, t[:, 0], live,
+                        scale, path["block_pages"], first=first[:, 0],
+                        ring=bool(window))[:, None].astype(dt)
+                    report("kv_bytes_streamed",
+                           last // page - first[:, 0] // page + 1,
+                           row_bytes * page)
+                else:
+                    out = xla_form()
+                    report("kv_bytes_streamed", 0, 0.0)
+            return out
+        path = chunk_path(d, page, per_slot, s, k_pool.dtype, ctx.mesh)
+        tiles = dense = jnp.float32(0)
+        with tel.span(f"{kind}_attend/chunk_path", cat="compile",
+                      layer=layer.name, window=window, **path):
+            if path["path"] == "kernel":
+                qb, kb = path["query_block"], path["key_block"]
+                # the pages in the context's order from the first one a query
+                # of the block may see: [b, L, g * d], row 0 at `base`
+                start = first[:, 0] // page
+                pages = pt if not window else jnp.take_along_axis(
+                    pt, (start[:, None] + jnp.arange(per_slot)) % per_slot,
+                    axis=1)
+                out, visited = sparse_attend_chunk.sparse_attend_chunk(
+                    qg, None, k_pool[pages].reshape(b, -1, g * d),
+                    v_pool[pages].reshape(b, -1, g * d), t, scale, qb, kb,
+                    base=start * page, window=window)
+                out = out.astype(dt)
+                tiles = visited.astype(jnp.float32)
+                dense = jnp.float32(b * (s // qb) * -(-per_slot * page // kb))
+                report("kv_bytes_streamed", per_slot, row_bytes * page)
+            else:
+                out = xla_form()
+                report("kv_bytes_streamed", 0, 0.0)
+        ctx.add_stat(f"{kind}_attend_chunk_tiles", tiles)
+        ctx.add_stat(f"{kind}_attend_chunk_tiles_dense", dense)
+        return out
+
+
 def _rung_attention(qg, k_pool, v_pool, pt, selected, scale, pages: int):
     """The XLA form of a block's attention under the mask: `qg` `[b, s, g,
     r, d]` over the first `pages` pages of every row's table, gathered once,
@@ -603,6 +752,14 @@ def _mha_lower(layer: Layer, inputs, weights, ctx: LoweringCtx):
     causal = p.get("causal", False)
     scale = _scale(p, embed // heads)
     out = None
+    # a window the sequence outgrows: the masked XLA path below (the flash
+    # and ring kernels know no window); one that holds the sequence is the
+    # plain causal mask
+    window = int(p.get("window") or 0)
+    windowed = 0 < window < k.shape[1]
+    if windowed and impl == "flash":
+        raise NotImplementedError("impl='flash' with a window under the "
+                                  "sequence's length")
     if p.get("selected"):
         if not causal or "bias_k" in weights or p.get("add_zero_attn") \
                 or q.shape[1] != k.shape[1]:
@@ -615,7 +772,7 @@ def _mha_lower(layer: Layer, inputs, weights, ctx: LoweringCtx):
     # sequence parallelism: the searched strategy may place this attention
     # on the ring path (sp_ring candidate -> {"seq_parallel": axis} attr)
     sp_axis = ctx.op_attrs.get(layer.name, {}).get("seq_parallel")
-    if out is None and sp_axis and ctx.mesh is not None \
+    if out is None and not windowed and sp_axis and ctx.mesh is not None \
             and sp_axis in ctx.mesh.shape \
             and impl != "xla" and qh.shape[1] == kh.shape[1] == vh.shape[1] \
             and qh.shape[1] % ctx.mesh.shape[sp_axis] == 0 \
@@ -634,7 +791,7 @@ def _mha_lower(layer: Layer, inputs, weights, ctx: LoweringCtx):
     # was chosen and then raises (trace, Mosaic compile) propagates: it
     # never silently becomes the einsum path.
     spec = _attn_pspec(layer, ctx, qh.shape[0], heads)
-    if out is None and not needs_dropout and (
+    if out is None and not needs_dropout and not windowed and (
             impl == "flash" or (impl == "auto" and ctx.enable_fusion
                                 and _flash_covers(qh, kh, vh, causal)
                                 and spec is not None)):
@@ -655,6 +812,9 @@ def _mha_lower(layer: Layer, inputs, weights, ctx: LoweringCtx):
             # end) are always attendable and must not shift the band
             sk_orig = k.shape[1]
             mask = jnp.tril(jnp.ones((sq, sk_orig), bool), k=sk_orig - sq)
+            if windowed:    # the keys t - window < s <= t
+                mask &= ~jnp.tril(jnp.ones((sq, sk_orig), bool),
+                                  k=sk_orig - sq - window)
             if sk > sk_orig:
                 mask = jnp.concatenate(
                     [mask, jnp.ones((sq, sk - sk_orig), bool)], axis=1)
@@ -717,8 +877,11 @@ def _mha_page_state(layer: Layer) -> dict:
     """A token's rows in this layer's K and V pools: the K/V heads (fewer
     than the query heads where they are grouped) of head_dim each."""
     p = layer.params
-    return {"heads": _kv_heads(p),
-            "head_dim": int(p["embed_dim"]) // int(p["num_heads"])}
+    state = {"heads": _kv_heads(p),
+             "head_dim": int(p["embed_dim"]) // int(p["num_heads"])}
+    if p.get("window"):     # a second extent: a ring of the window's pages
+        state["window"] = int(p["window"])
+    return state
 
 
 def _mha_span_facts(layer: Layer) -> dict:
@@ -732,6 +895,8 @@ def _mha_span_facts(layer: Layer) -> dict:
         facts["mrope_section"] = list(p["mrope_section"])
     if p.get("qk_norm"):
         facts["qk_norm"] = True
+    if p.get("rope_scaling"):
+        facts["rope_scaling_factor"] = float(p["rope_scaling"]["factor"])
     return facts
 
 

@@ -71,34 +71,12 @@ from flexflow_tpu.kernels.partition import multi_device
 from flexflow_tpu.ops.norm_ops import rms_norm
 from flexflow_tpu.ops.op_type import OperatorType
 from flexflow_tpu.ops.registry import LoweringCtx, register_op
-from flexflow_tpu.ops.rotary import apply_rope, inv_freq  # noqa: F401 (apply_rope: this module's name for it too)
+from flexflow_tpu.ops.rotary import (apply_rope,  # noqa: F401 (this module's names for them too)
+                                     inv_freq, yarn_inv_freq)
 
 
 def yarn_mscale(factor: float, mscale: float) -> float:
     return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
-
-
-def yarn_inv_freq(dim: int, base: float, factor: float = 1.0,
-                  original_len: int = 4096, beta_fast: float = 32,
-                  beta_slow: float = 1) -> np.ndarray:
-    """The rotary frequencies of the `dim // 2` pairs, `[dim // 2]` float64:
-    f_i = base^(-2i/dim), and under YaRN (factor > 1) pairs that turn fewer
-    than `beta_slow` times over the original length are slowed by `factor`,
-    pairs that turn more than `beta_fast` times are kept, and those between
-    are blended along a linear ramp over the pair index."""
-    f = inv_freq(dim, base)
-    if factor <= 1:
-        return f
-    i = np.arange(dim // 2, dtype=np.float64)
-
-    def pair_turning(n):    # the (real) pair index that turns n times
-        return dim * math.log(original_len / (2 * math.pi * n)) \
-            / (2 * math.log(base))
-
-    low = max(math.floor(pair_turning(beta_fast)), 0)
-    high = min(math.ceil(pair_turning(beta_slow)), dim - 1)
-    ramp = np.clip((i - low) / max(high - low, 1e-3), 0.0, 1.0)
-    return f * (1.0 - ramp) + f / factor * ramp
 
 
 def _rope_params(p):

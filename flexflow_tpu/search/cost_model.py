@@ -113,6 +113,12 @@ class KVCacheSpec:
     # values, in whole lanes at rest as a latent's are (0 = none)
     index_dim: int = 0
     index_layers: int = 0
+    # a second EXTENT of K and V: `window_layers` more layers (windowed
+    # attention: a query sees its last `window` keys) whose pools hold a RING
+    # of `window_pages` pages a slot, owned by the slot for good, where the
+    # `layers` above hold `pages_per_slot`, handed out on admission (0 = none)
+    window_layers: int = 0
+    window_pages: int = 0
 
     @property
     def padded_len(self) -> int:
@@ -158,9 +164,27 @@ class KVCacheSpec:
         the per-(page entry, head) scale arrays of a quantized pool."""
         return self.pool_pages * self.page_bytes()
 
+    @property
+    def window_pool_pages(self) -> int:
+        """Pages in one windowed layer's pool: every slot's ring + scratch."""
+        return self.slots * self.window_pages + 1
+
+    def window_layer_bytes(self) -> int:
+        """K + V pool bytes of ONE windowed layer."""
+        return self.window_pool_pages * self.page_bytes()
+
+    def window_bytes(self) -> int:
+        """The windowed layers' pools, all of them."""
+        return self.window_layers * self.window_layer_bytes()
+
+    def one_extent_bytes(self) -> int:
+        """What the K and V pools would hold with ONE extent for every
+        layer: the windowed layers' too at `pages_per_slot` pages a slot."""
+        return (self.layers + self.window_layers) * self.layer_bytes()
+
     def total_bytes(self) -> int:
         return self.layers * self.layer_bytes() + self.index_bytes() \
-            + self.slots * self.state_bytes_per_slot
+            + self.window_bytes() + self.slots * self.state_bytes_per_slot
 
     def per_device_bytes(self, model_degree: int = 1) -> int:
         """Resident bytes per device with the heads dim sharded
@@ -193,7 +217,9 @@ class KVCacheSpec:
                      if self.state_bytes_per_slot else ()) \
             + (("latent", self.latent_dim) if self.latent_dim else ()) \
             + (("index", self.index_dim, self.index_layers)
-               if self.index_dim else ())
+               if self.index_dim else ()) \
+            + (("window", self.window_layers, self.window_pages)
+               if self.window_layers else ())
 
 
 def zero_divisor(spec: TensorSpec, dims: Sequence[DimSharding],

@@ -65,11 +65,13 @@ from flexflow_tpu.compiler.lowering import build_forward, constrainable
 from flexflow_tpu.core.graph import topo_order
 from flexflow_tpu.ops.op_type import OperatorType
 from flexflow_tpu.ops.registry import STATS_KEY, get_op_def
+from flexflow_tpu.ops.attention_ops import BLOCK_LENGTHS_KEY
 from flexflow_tpu.parallel.default_strategy import data_parallel_strategy
 from flexflow_tpu.parallel.machine import MachineSpec, build_mesh
 from flexflow_tpu.search import cost_model as cm
 from flexflow_tpu.serving.kv_cache import (ACTIVE_KEY, PAGE_TABLE_KEY, POS_KEY,
-                                           PagedKVCache, _tree_bytes)
+                                           WINDOW_TABLE_KEY, PagedKVCache,
+                                           _tree_bytes)
 from flexflow_tpu.serving.program import (attn_head_degree, clone_for_serving,
                                           page_geometry, recurrent_layers,
                                           serving_optimize, slot_state_bytes)
@@ -212,6 +214,10 @@ def compile_serving(model, max_batch_slots: Optional[int] = None,
         index_layers = [n for n in attn if get_op_def(
             dec_model.get_layer_by_name(n).op_type).state_kind
             == "paged_index"]
+        # the layers of windowed attention: a second extent of K and V
+        window = int(geometry.get("window", 0))
+        window_layers = [n for n in attn if dec_model.get_layer_by_name(
+            n).params.get("window")]
         beside = [w for w, a in (
             ("the host KV tier (--kv-host-pages)",
              int(getattr(cfg, "kv_host_pages", 0) or 0) > 0),
@@ -220,11 +226,21 @@ def compile_serving(model, max_batch_slots: Optional[int] = None,
         for what, asked in (
                 (f"{len(index_layers)} layers page a sparse-attention "
                  "indexer's key beside K and V", bool(index_layers)),
+                (f"{len(window_layers)} layers keep a window of {window} "
+                 "positions in a ring of pages a slot", bool(window_layers)),
                 (f"the prompt goes in by chunks of {chunk}", chunk > 0)):
             if asked and beside:
                 raise NotImplementedError(
                     f"compile_serving: {what}, which does not support "
                     + "; ".join(beside) + " yet")
+        if window_layers and not chunk:
+            # the ring holds the window behind a chunk's first query and the
+            # chunk: a wave's commit would have to write a prompt's last
+            # window into it, which does not exist yet
+            raise NotImplementedError(
+                f"compile_serving: {len(window_layers)} layers keep a window "
+                f"of {window} positions in a ring of pages a slot, which "
+                "needs the prompt to go in by chunks (--serve-prefill-chunk)")
         if chunk and (latent or recurrent or not attn):
             # a chunk attends over what its slot has cached: K/V pages can be
             # read back as they were written; a latent's chunk form (absorbed
@@ -288,8 +304,14 @@ def compile_serving(model, max_batch_slots: Optional[int] = None,
                                slots * pages_per_slot - host_pages)
         prefetch_ahead = max(1, int(getattr(cfg, "kv_prefetch_ahead", 2)
                                     or 2))
+        # a ring: the window behind a chunk's first query, the chunk, and a
+        # page for where the two ends fall in theirs; never past the context
+        ring_pages = min(-(-(window + chunk) // page) + 1, pages_per_slot) \
+            if window_layers else 0
         kv_spec = cm.KVCacheSpec(
-            layers=len(kv_layers), heads=int(geometry.get("heads", 0)),
+            layers=len(kv_layers) - len(window_layers),
+            window_layers=len(window_layers), window_pages=ring_pages,
+            heads=int(geometry.get("heads", 0)),
             head_dim=int(geometry.get("head_dim", 0)),
             latent_dim=int(geometry.get("latent_dim", 0)),
             index_dim=int(geometry.get("index_dim", 0)),
@@ -356,6 +378,7 @@ def compile_serving(model, max_batch_slots: Optional[int] = None,
                                  spec_tokens=spec_k if draft_engine else 0,
                                  draft=draft_engine, recurrent=recurrent,
                                  index_layers=index_layers,
+                                 window_layers=window_layers,
                                  chunk_model=chunk_model)
         # the in-place append's engagement: the pool as it lies at rest and
         # the bytes of the state leaves a decode step is told to donate
@@ -364,6 +387,13 @@ def compile_serving(model, max_batch_slots: Optional[int] = None,
                 next(iter(engine.kv.state[kv_layers[0]].values())).shape)
             if kv_layers else [],
             prefill_chunk=chunk,
+            **({"kv_pool_pages_full": kv_spec.pool_pages,
+                "kv_pool_bytes_full": kv_spec.layers * kv_spec.layer_bytes(),
+                "kv_pool_pages_window": kv_spec.window_pool_pages,
+                "kv_pool_bytes_window": kv_spec.window_bytes(),
+                "window_ring_pages": ring_pages, "window": window,
+                "kv_pool_bytes_one_extent": kv_spec.one_extent_bytes()}
+               if window_layers else {}),
             state_in_place=engine.kv.writes_state_in_place,
             decode_state_donated_bytes=_tree_bytes(engine.kv.state))
         return engine
@@ -393,7 +423,8 @@ class ServingCompiled:
                  verify_model=None, spec_tokens: int = 0, draft=None,
                  recurrent: Optional[Dict[str, Dict[str, tuple]]] = None,
                  index_layers: Optional[List[str]] = None,
-                 chunk_model=None):
+                 chunk_model=None,
+                 window_layers: Optional[List[str]] = None):
         self.model = model
         self.cfg = model.config
         self.machine = machine
@@ -421,7 +452,8 @@ class ServingCompiled:
         self.kv = PagedKVCache(kv_spec, self.attn_layers, mesh,
                                heads_axis=heads_axis, dtype=self.kv_dtype,
                                quantized=self.kv_quantized, machine=machine,
-                               recurrent=recurrent, index_layers=index_layers)
+                               recurrent=recurrent, index_layers=index_layers,
+                               window_layers=window_layers)
         deg = 1
         if self.kv.heads_axis is not None:
             axes = (self.kv.heads_axis,) if isinstance(self.kv.heads_axis, str) \
@@ -542,6 +574,11 @@ class ServingCompiled:
                 # are (a prefilling slot is not live until its last chunk)
                 view = {n: state[n] for n in paged}
                 view[PAGE_TABLE_KEY] = page_rows
+                if window_layers:   # the slot's two rows: its pages, its ring
+                    view[PAGE_TABLE_KEY], view[WINDOW_TABLE_KEY] = jnp.split(
+                        page_rows, [kv_spec.pages_per_slot], axis=1)
+                    # which of the block's positions hold a token
+                    view[BLOCK_LENGTHS_KEY] = lengths
                 view[POS_KEY] = context
                 view[ACTIVE_KEY] = (lengths > 0).astype(
                     state[ACTIVE_KEY].dtype)

@@ -73,6 +73,22 @@ hides its stale key. 64 values lie in 128 lanes (the latent's reason). The
 host tier, park/spill, the hand-off and a quantized pool raise
 NotImplementedError beside it.
 
+Two EXTENTS of K and V (`spec.window_layers` set): the layers of windowed
+attention (a query sees its last `window` keys) keep pools of their own, each
+a RING of `spec.window_pages` pages a slot (`ceil((window + chunk) / page) +
+1`: the window behind a prefill chunk's first query and the chunk itself),
+`[slots * window_pages + 1, page_size, heads * head_dim]` under a second table
+`state["serve/window_table"]` `[slots, window_pages]`: page `n` of a slot's
+context lies at entry `n % window_pages`, so a long context laps the ring and
+a position overwrites the one a ring's length behind it, which no query sees
+any more. A slot owns its ring for good: nothing is allocated or freed, the
+free list and admission count the full layers' pages alone, and the bounds by
+position (`first <= s <= t`, ops/attention_ops.py) hide what a former occupant
+or a lapped position left. The device's row stays at scratch while the slot is
+not live, as the page table's does, for the same reason. The host tier,
+park/spill, the hand-off, speculative roll-back and a quantized pool raise
+NotImplementedError beside it.
+
 A prompt longer than one program's window is prefilled in CHUNKS over the
 slot's own pages (serving/engine.py: `prefill_chunk`; scheduler.py). Such a
 slot is admitted `prefilling`: it owns its pages on the host, but the device's
@@ -129,6 +145,8 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from flexflow_tpu import attribution
+# the windowed layers' rings: the key the layers' lowering reads them under
+from flexflow_tpu.ops.attention_ops import WINDOW_TABLE_KEY  # noqa: F401
 from flexflow_tpu.search.cost_model import KVCacheSpec
 
 # a wave's fresh recurrent state (slots x the state a slot, every layer) at
@@ -187,15 +205,18 @@ def pad_row(rows, width: int):
         rows, [(0, 0)] * (rows.ndim - 1) + [(0, short)])
 
 
-def append_slots(pt, pos, s: int, page: int):
+def append_slots(pt, pos, s: int, page: int, ring: bool = False):
     """Where a block of `s` tokens a slot lies in the pools: (`t` `[slots,
     s]` the tokens' positions `pos + i`, the page of each, its offset in the
     page). A position past the table's last page goes to the scratch page
     (as `_commit_prefill` routes padding), so the scatter that follows has
-    one shape whatever a slot holds."""
+    one shape whatever a slot holds. With `ring` the table is a ring: page
+    `n` of the context lies at entry `n % entries`, whatever `n`."""
     rows = jnp.arange(pt.shape[0])
     t = pos[:, None] + jnp.arange(s)[None, :]
     pg = t // page
+    if ring:
+        return t, pt[rows[:, None], pg % pt.shape[1]], t % page
     in_range = pg < pt.shape[1]
     pageix = jnp.where(in_range,
                        pt[rows[:, None], jnp.minimum(pg, pt.shape[1] - 1)], 0)
@@ -282,7 +303,8 @@ class PagedKVCache:
                  mesh: Optional[Mesh] = None, heads_axis=None,
                  dtype=jnp.float32, quantized: bool = False, machine=None,
                  recurrent: Optional[Dict[str, Dict[str, tuple]]] = None,
-                 index_layers: Optional[List[str]] = None):
+                 index_layers: Optional[List[str]] = None,
+                 window_layers: Optional[List[str]] = None):
         self.spec = spec
         self.machine = machine  # host_bw source for transfer pricing rows
         # the commit programs as this cache runs them (attribution.op_scopes
@@ -296,6 +318,8 @@ class PagedKVCache:
         # them in graph order, the indexer layers' key pools
         self.attn_layers = list(attn_layers)
         self.index_layers = list(index_layers or [])
+        # those of them that keep a ring of the window's pages a slot
+        self.window_layers = list(window_layers or [])
         # {layer: {leaf: (per-slot shape, dtype)}} (program.recurrent_layers)
         # (compile_serving refuses a host tier beside them)
         self.recurrent = dict(recurrent or {})
@@ -323,9 +347,14 @@ class PagedKVCache:
             raise NotImplementedError(
                 "a quantized (int8) cache of paged_latent state: the "
                 "per-head scales have no heads to belong to")
+        if self.window_layers and (self.quantized or spec.host_pages):
+            raise NotImplementedError(
+                f"{len(self.window_layers)} layers keep a window's ring of "
+                "pages, which does not support a quantized (int8) pool or "
+                "the host tier yet")
         shape = (spec.pool_pages, spec.page_size)
 
-        def pool(width):
+        def pool(width, shape=shape):
             z = jnp.zeros(shape + (width,),
                           jnp.int8 if self.quantized else dtype)
             return (jax.device_put(z, self._pool_sharding)
@@ -341,6 +370,10 @@ class PagedKVCache:
         def layer_state(name):
             if name in self.index_layers:
                 return {"ik": pool(spec.index_row_width())}
+            if name in self.window_layers:
+                return {leaf: pool(width, (spec.window_pool_pages,
+                                           spec.page_size))
+                        for leaf, width in spec.row_widths().items()}
             st = {leaf: pool(width)
                   for leaf, width in spec.row_widths().items()}
             if self.quantized:
@@ -357,6 +390,12 @@ class PagedKVCache:
                 for z in [jnp.zeros((spec.slots,) + tuple(shape), dt)]}
         # host mirrors (authoritative at scheduler sync points)
         self._table = np.zeros((spec.slots, spec.pages_per_slot), np.int32)
+        # the rings: slot i's is pages 1 + i * ring .. of the windowed pools,
+        # for good; the device sees the row while the slot is live
+        self._rings = 1 + np.arange(
+            spec.slots * spec.window_pages, dtype=np.int32).reshape(
+                spec.slots, spec.window_pages)
+        self._window_table = np.zeros_like(self._rings)
         self._pos = np.zeros((spec.slots,), np.int32)
         self._active = np.zeros((spec.slots,), np.int32)
         self.free_pages: List[int] = list(range(1, spec.pool_pages))
@@ -395,6 +434,8 @@ class PagedKVCache:
 
     def _push_tables(self) -> None:
         self.state[PAGE_TABLE_KEY] = self._put_repl(self._table)
+        if self.window_layers:
+            self.state[WINDOW_TABLE_KEY] = self._put_repl(self._window_table)
         self.state[POS_KEY] = self._put_repl(self._pos)
         self.state[ACTIVE_KEY] = self._put_repl(self._active)
 
@@ -406,7 +447,9 @@ class PagedKVCache:
                 and i not in self._prefilling]
 
     def pages_needed(self, total_tokens: int) -> int:
-        if not self.attn_layers:    # nothing pages: a slot is all it takes
+        # nothing pages, or every layer that does keeps a ring its slot owns:
+        # a slot is all it takes
+        if len(self.attn_layers) == len(self.window_layers):
             return 0
         cap = min(int(total_tokens), self.spec.padded_len)
         return -(-cap // self.spec.page_size)
@@ -448,20 +491,23 @@ class PagedKVCache:
             self._prefilling[slot] = row
             return True
         self._table[slot] = row
+        self._window_table[slot] = self._rings[slot]
         self._pos[slot] = prompt_len
         self._active[slot] = 1
         return True
 
     def prefill_row(self, slot: int) -> np.ndarray:
         """The table row `[pages_per_slot]` of a slot admitted `prefilling`,
-        for the chunk program."""
-        return self._prefilling[slot]
+        for the chunk program; with windowed layers its two rows in one,
+        `[pages_per_slot + window_pages]`: the pages, then the ring."""
+        return np.concatenate([self._prefilling[slot], self._rings[slot]])
 
     def activate(self, slot: int, prompt_len: int) -> None:
         """The last chunk of a `prefilling` slot's prompt is in its pages:
         the slot joins the decode steps at position `prompt_len` (the
         caller pushes)."""
         self._table[slot] = self._prefilling.pop(slot)
+        self._window_table[slot] = self._rings[slot]
         self._pos[slot] = prompt_len
         self._active[slot] = 1
 
@@ -475,6 +521,7 @@ class PagedKVCache:
         self._inflight.pop(slot, None)
         self._prefilling.pop(slot, None)
         self._table[slot] = 0
+        self._window_table[slot] = 0
         self._pos[slot] = 0
         self._active[slot] = 0
 
@@ -509,7 +556,8 @@ class PagedKVCache:
         `state_kind` names them (what the cache's spans say they moved)."""
         kinds = [] if not self.attn_layers else \
             ["paged_latent" if self.spec.latent_dim else "paged_kv"]
-        return "+".join(kinds + (["paged_index"] if self.index_layers else [])
+        return "+".join(kinds + (["paged_kv_ring"] if self.window_layers else [])
+                        + (["paged_index"] if self.index_layers else [])
                         + (["recurrent"] if self.recurrent else []))
 
     @property
@@ -530,6 +578,11 @@ class PagedKVCache:
             raise NotImplementedError(
                 f"{what}: the cache holds paged_latent state "
                 f"({len(self.attn_layers)} layers), which this path does "
+                "not move yet")
+        if self.window_layers:
+            raise NotImplementedError(
+                f"{what}: the cache holds a window's ring of pages "
+                f"({len(self.window_layers)} layers), which this path does "
                 "not move yet")
         if self.index_layers:
             raise NotImplementedError(
@@ -742,6 +795,11 @@ class PagedKVCache:
         its recurrent state into the slots the wave prefilled."""
         from flexflow_tpu import telemetry as tel
 
+        if self.window_layers:
+            raise NotImplementedError(
+                "commit_prefill: a wave's K/V into a window's ring of pages "
+                f"({len(self.window_layers)} layers): the prompt goes in by "
+                "chunks (--serve-prefill-chunk)")
         slot_ids = self._put_repl(np.asarray(slot_ids, np.int32))
         lengths = self._put_repl(np.asarray(lengths, np.int32))
         if self.attn_layers:

@@ -74,7 +74,9 @@ def page_geometry(model) -> Dict[str, int]:
     where no layer pages anything (every layer that carries state keeps it
     a slot: `recurrent_layers`). One geometry a kind of state: layers of a
     kind that declare different ones raise, and so do K/V and a latent in
-    one model."""
+    one model. K/V layers may declare two EXTENTS of that geometry: those
+    that state a `window` keep a ring of its pages a slot, the others the
+    whole context (`{"window": w}` then, one window a model)."""
     found: Dict[str, Dict[str, Dict[str, int]]] = {}
     for l in topo_order(model.layers):
         d = get_op_def(l.op_type)
@@ -84,6 +86,12 @@ def page_geometry(model) -> Dict[str, int]:
         raise NotImplementedError(
             f"one cache geometry a model: layers page {sorted(found)}")
     out: Dict[str, int] = {}
+    windows = {g.pop("window") for g in found.get("paged_kv", {}).values()
+               if "window" in g}
+    if len(windows) > 1:
+        raise NotImplementedError(
+            f"one window a model: layers keep {sorted(windows)}")
+    out.update({"window": w for w in windows})
     for layers in found.values():
         first = next(iter(layers))
         for name, geometry in layers.items():
@@ -251,7 +259,10 @@ def _decode_cost_fn(machine: MachineSpec, kv_layer_bytes: int,
                 in PAGED_STATE_KINDS:
             wq = cand.weight_dims.get("wq")
             deg = cm.dims_degree([wq[1]], machine) if wq and len(wq) > 1 else 1
-            t += kv_layer_bytes / max(1, deg) / machine.hbm_bw
+            # a windowed layer's step reads its window's rows, not the context
+            held = kv_spec.window_layer_bytes() if kv_spec is not None \
+                and layer.params.get("window") else kv_layer_bytes
+            t += held / max(1, deg) / machine.hbm_bw
             if kv_spec is not None and kv_spec.host_pages > 0:
                 t += (kv_spec.pages_per_slot * kv_spec.page_bytes()
                       / max(1, deg) / machine.host_bw

@@ -183,11 +183,15 @@ def valid_step_inputs(tokens, state) -> List[Any]:
     return [tokens, jnp.broadcast_to(live, tokens.shape)]
 
 
-def positions_valid_prompt_inputs(ids: np.ndarray,
-                                 lengths: np.ndarray) -> List[np.ndarray]:
+def positions_valid_prompt_inputs(ids: np.ndarray, lengths: np.ndarray,
+                                 offsets=None) -> List[np.ndarray]:
     """Prefill inputs of a model with rotary positions whose layers are also
-    told which positions exist (`input_ids`, `positions`, `valid`)."""
+    told which positions exist (`input_ids`, `positions`, `valid`).
+    `offsets` `[rows]`: where each row's block starts in its sequence (a
+    prefill chunk's context)."""
     ids, pos = gpt2_prompt_inputs(ids, lengths)
+    if offsets is not None:
+        pos = pos + np.asarray(offsets, np.int32)[:, None]
     return [ids, pos, valid_prompt_inputs(ids, lengths)[1]]
 
 
@@ -204,9 +208,7 @@ def positions3_valid_prompt_inputs(ids: np.ndarray, lengths: np.ndarray,
     (`input_ids`, `positions` `[rows, s, 3]`, `valid`): text, whose time,
     height and width positions are one number. `offsets` `[rows]`: where
     each row's block starts in its sequence (a prefill chunk's context)."""
-    ids, pos, valid = positions_valid_prompt_inputs(ids, lengths)
-    if offsets is not None:
-        pos = pos + np.asarray(offsets, np.int32)[:, None]
+    ids, pos, valid = positions_valid_prompt_inputs(ids, lengths, offsets)
     return [ids, np.ascontiguousarray(np.repeat(pos[..., None], 3, axis=-1)),
             valid]
 

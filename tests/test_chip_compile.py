@@ -1050,6 +1050,91 @@ def test_keye_vl_serving_programs_fit_one_chip(described_devices, mosaic,
     assert not re.search(rf"= {re.escape(pool)}\S* copy\(", text)
 
 
+def test_mellum_serving_programs_fit_one_chip(described_devices, mosaic,
+                                              one_chip, monkeypatch):
+    """`Mellum2-12B-A2.5B-Instruct.serve-longprompt`'s two programs at the
+    cell's own sizes (16 slots of 16896 positions, 10.93 GB of bf16 weights:
+    every one of the 64 experts of all twelve layers; three full layers page
+    1056 pages a slot and nine windowed ones a ring of 193), through the
+    normal entry points: the prompt program is the `[1, 2048]` chunk over the
+    slot's own pages and ring, and the chip's compiler must hold the chunk
+    and the decode step beside the weights and both extents of the cache
+    (arguments + temporaries under 15 GB: ISSUE 56's rule for twelve layers).
+    Mosaic accepts the two attention kernels with bounds by position at
+    these tiles, one call a layer in each program (the step kernel fetches
+    the pools' pages where they lie; the chunk kernel reads the gathered
+    pages), and the experts' step kernel at a width of 896 = 7 x 128. No
+    mask `[b, s, L]` and no float32 scores of a chunk's queries against a
+    slot's context exist in either program; both append to the pools they
+    were handed."""
+    eng, g, params, state = _described_engine(
+        "Mellum2-12B-A2.5B-Instruct.serve-longprompt", described_devices,
+        monkeypatch, one_chip)
+    slots, spec = eng.slots, eng.kv_spec
+    layers = g.layers
+    full, ringed = layers // 4, 3 * layers // 4
+    assert (spec.layers, spec.window_layers, spec.heads, spec.head_dim) \
+        == (full, ringed, 4, 128)
+    assert spec.pages_per_slot * spec.page_size == g.seq == 16896
+    assert spec.window_pages == 193
+    assert eng.kv.state_kinds == "paged_kv+paged_kv_ring"
+    assert eng.chunk_tokens == 2048
+    pages, ring_pages = slots * 1056 + 1, slots * 193 + 1
+    assert eng.kv.state["l3_attn"]["k"].shape == (pages, 16, 512)
+    assert eng.kv.state["l0_attn"]["k"].shape == (ring_pages, 16, 512)
+    weights = sum(x.size * x.dtype.itemsize
+                  for x in jax.tree_util.tree_leaves(params))
+    assert weights == 2 * g.param_count()
+    held = sum(x.size * x.dtype.itemsize
+               for x in jax.tree_util.tree_leaves(state))
+    assert held - 1e6 < spec.total_bytes() < held
+    if layers == 12:
+        assert weights == 2 * 5465959680 and 2.57e9 < held < 2.59e9
+    decode = eng._decode_jit.lower(
+        params, state, [_i32(one_chip, slots, 1)] * 3).compile()
+    chunk = eng._chunk_jit.lower(
+        params, state, [_i32(one_chip, 1, 2048)] * 3,
+        _i32(one_chip, 1, 1056 + 193), _i32(one_chip, 1), _i32(one_chip, 1)
+    ).compile()
+    chip = 15.75e9          # what the compiler has of a v5e chip's 16 GB
+    needs = {}
+    for name, program in (("decode", decode), ("chunk", chunk)):
+        m = program.memory_analysis()
+        needs[name] = (m.argument_size_in_bytes + m.output_size_in_bytes
+                       - m.alias_size_in_bytes + m.temp_size_in_bytes)
+        assert needs[name] < 15e9 < chip, (name, needs[name], m)
+        assert m.alias_size_in_bytes >= held - 1e6
+    print("mellum serving programs need", needs)
+    from flexflow_tpu import attribution
+    from flexflow_tpu.ops.attention_ops import (BOUNDED_SCOPE, FULL_SCOPE,
+                                                WINDOW_SCOPE)
+    from flexflow_tpu.ops.moe_ops import EXPERTS_SCOPE
+    for program, kernel in ((decode, "ff_sparse_attend_step"),
+                            (chunk, "ff_sparse_attend_chunk")):
+        text = program.as_text()
+        for scope, n in ((WINDOW_SCOPE, ringed), (FULL_SCOPE, full),
+                         (BOUNDED_SCOPE, layers)):
+            under = attribution.instructions_in_scope(text, scope)
+            assert sum(name.startswith(kernel) for name in under) == n, scope
+        assert len(re.findall(r' custom-call\([^\n]*custom_call_target='
+                              rf'"tpu_custom_call"[^\n]*{kernel}',
+                              text)) == layers
+        # no membership mask over a slot's context, no scores outside VMEM
+        for keys in (3088, 16896):
+            assert not re.search(rf"pred\[[0-9,]*,{keys}\]", text)
+            assert not re.search(rf"f32\[[0-9,]*,2048,{keys}\]", text)
+        # the pools go in where they lie or by the page gather: no copy
+        for pool in (f"bf16[{pages},16,512]", f"bf16[{ring_pages},16,512]"):
+            assert not re.search(rf"= {re.escape(pool)}\S* copy\(", text)
+    text = decode.as_text()
+    under = attribution.instructions_in_scope(text, EXPERTS_SCOPE)
+    assert sum(n.startswith("ff_moe_step") for n in under) == layers
+    assert "ragged-dot" not in text
+    # a step holds no slot's gathered context or window
+    assert f"bf16[{slots},16896,512]" not in text
+    assert f"bf16[{slots},3088,512]" not in text
+
+
 MOE_CELLS = {   # cell: (inputs of its programs, expert layers, a tile's tn)
     "granite-4.0-h-small.serve-chat": (2, 10, 768),
     "GigaChat3.1-702B-A36B.serve-chat": (3, 5, 512),
