@@ -17,6 +17,12 @@ moe_step — one decode step's routed experts: each hit expert's two matrices
 streamed once over all of the step's (at most 16) rows, the gated sum kept on
 the chip (forward; ops/moe_ops.py chooses it from the block's shapes and
 gives it the grouped product's gradient).
+moe_rows — the routed experts of a wave's or a chunk's row buffer: rows
+sorted by expert in tiles of 256, a tile that straddles groups visited once a
+group under a row mask, both products and the activation in one pass with
+`ab` and `mid` kept on the chip and an expert's matrices fetched once while
+consecutive visits name it (forward; ops/moe_ops.py chooses it a rung from
+the buffer's shapes and gives it the grouped product's gradient).
 mamba2_step — one decode step of the Mamba-2 recurrence for the live slots:
 the update of a slot's state and the float32 read-out of the new state on one
 tile, the state read from HBM once and written once in place, a slot that is

@@ -54,14 +54,12 @@ _VMEM_LIMIT_BYTES = 64 * 1024 * 1024
 _TILE_BYTES = 48 * 1024 * 1024
 
 
-def tile_width(tokens: int, k_dim: int, width: int, parts: int,
-               itemsize: int):
-    """The tile `tn` of the middle width a grid step takes, or None where
-    the kernel does not take the block (the grouped product does): it wants
-    at most `ROWS` tokens, and K, the width and the tile in whole 128-lane
-    slabs. `parts`: the matrices `w_in` holds side by side (2 gated, 1
-    not)."""
-    if tokens > ROWS or k_dim % LANES or width % LANES:
+def width_tile(k_dim: int, width: int, parts: int, itemsize: int):
+    """The largest part `tn` of the middle width, in whole 128-lane slabs,
+    whose weight tiles (`[K, tn]` of each of `w_in`'s `parts` matrices and
+    `[tn, K]` of `w_out`) fit `_TILE_BYTES` twice; None where K or the
+    width is not whole slabs. `kernels/moe_rows.py` sizes its own by it."""
+    if k_dim % LANES or width % LANES:
         return None
     slabs = width // LANES
     for tiles in range(1, slabs + 1):
@@ -70,6 +68,18 @@ def tile_width(tokens: int, k_dim: int, width: int, parts: int,
                 and 2 * (parts + 1) * k_dim * tn * itemsize <= _TILE_BYTES:
             return tn
     return None
+
+
+def tile_width(tokens: int, k_dim: int, width: int, parts: int,
+               itemsize: int):
+    """The tile `tn` of the middle width a grid step takes, or None where
+    the kernel does not take the block (the grouped product does): it wants
+    at most `ROWS` tokens, and K, the width and the tile in whole 128-lane
+    slabs. `parts`: the matrices `w_in` holds side by side (2 gated, 1
+    not)."""
+    if tokens > ROWS:
+        return None
+    return width_tile(k_dim, width, parts, itemsize)
 
 
 def hit_experts(sizes, pairs: int):

@@ -195,9 +195,11 @@ MOE_TOKEN_BLOCK = 4096
 # The empty rung: a wave's other blocks (1.6 of 100 rows computed in both
 # serving cells; an all-empty wave 6.9 -> 5.3)
 MOE_ROW_RUNGS = (16, 4)
-# and no rung is smaller than this: the grouped product works on tiles of
-# 128 rows, so a smaller buffer computes no fewer. A decode step's 64-352
-# pairs get no ladder, and where its widths allow no grouped product
+# and no rung is smaller than this: the products work on whole row tiles
+# (the rows kernel's of 256, `moe_rows.ROW_TILE`; `jax.lax.ragged_dot`,
+# which a buffer under one such tile keeps, on the compiler's own, 128 when
+# PR 33 chose this), so a smaller buffer computes no fewer. A decode step's
+# 64-352 pairs get no ladder, and where its widths allow no grouped product
 # either: top-k picks distinct experts, so none of its groups holds more
 # rows than the step has tokens (16, one bf16 sublane tile), and the hit
 # experts are streamed once over all of them (`_step_tile`, `_route_step`).
@@ -268,20 +270,68 @@ def _row_capacities(pairs: int):
 EXPERTS_SCOPE = "ff_moe_experts"
 
 
-def _experts(rows, sizes, weights, p):
+def _params_of(w_out, relu2: bool):
+    """What `_experts` reads of a layer's params, from a kernel's operands
+    (its backward has no layer)."""
+    return {"expert_width": w_out.shape[1],
+            "expert_activation": "relu2" if relu2 else None}
+
+
+def _rows_forward(rows, sizes, w_in, w_out, relu2, tile):
+    from flexflow_tpu.kernels import moe_rows
+
+    return moe_rows.moe_rows(rows, sizes, w_in, w_out, relu2, *tile)
+
+
+def _rows_backward(relu2, _tile, operands, ct):
+    """The grouped-product form's, over the same rows, recomputed."""
+    rows, sizes, w_in, w_out = operands
+
+    def grouped(rows, w_in, w_out):
+        return _experts(rows, sizes, {"w_in": w_in, "w_out": w_out},
+                        _params_of(w_out, relu2))
+
+    d_rows, d_in, d_out = jax.vjp(grouped, rows, w_in, w_out)[1](ct)
+    return d_rows, None, d_in, d_out
+
+
+def _rows_forward_kept(rows, sizes, *rest):
+    """Under differentiation: the kernel does not write the rows past the
+    last group, and the callers' selections keep what lies there out of
+    the result but not out of the gates' gradient (0 times it)."""
+    written = jnp.arange(rows.shape[0])[:, None] < jnp.sum(sizes)
+    return (jnp.where(written, _rows_forward(rows, sizes, *rest), 0),
+            (rows, sizes) + rest[:2])
+
+
+_rows_kernel = jax.custom_vjp(_rows_forward, nondiff_argnums=(4, 5))
+_rows_kernel.defvjp(_rows_forward_kept, _rows_backward)
+
+
+def _experts(rows, sizes, weights, p, tile=None):
     """The experts over `rows` sorted by expert, `sizes[e]` of them on held
     expert e: one grouped product in, the activation in f32, one out. Rows
     are as wide as the experts work (the layer's d, or its latent), the
     middle `expert_width`. Gated SiLU, `silu(a) * b` with `[a | b]` the
     product in, unless the layer's `expert_activation` is "relu2":
     `relu(a)^2`, no gate matrix. Rows past the last group are not
-    multiplied. The form of every block but a decode step's, of a decode
-    step's where a width is not whole 128-lane slabs (`_step_tile`), and of
-    the step kernel's backward; `kernels/moe_step.py` makes the same
-    products with the same roundings of `ab` and `mid`."""
+    multiplied (and hold nothing a caller may read). Two forms with the
+    same roundings of `ab`, `mid` and the result. With a `tile`
+    (`_rows_tile`: one device, K and the width in whole 128-lane slabs,
+    whole row tiles) both products and the activation are ONE kernel,
+    `kernels/moe_rows.py`, `ab` and `mid` never in HBM: `_all_rows` and
+    `_held_rows` call it so for a block of a wave or a chunk. Without one,
+    two `jax.lax.ragged_dot`: the same callers on a mesh of several
+    devices or at widths that are no whole slabs (every tiny model), a
+    decode step's where `_step_tile` gives none, and the backward of both
+    kernels (`_step_backward`, `_rows_backward`)."""
     dt = rows.dtype
     width = p["expert_width"]
     with jax.named_scope(EXPERTS_SCOPE):
+        if tile is not None:
+            return _rows_kernel(rows, sizes, weights["w_in"].astype(dt),
+                                weights["w_out"].astype(dt),
+                                p.get("expert_activation") == "relu2", tile)
         ab = jax.lax.ragged_dot(rows, weights["w_in"].astype(dt), sizes)
         if p.get("expert_activation") == "relu2":
             mid = jnp.square(jax.nn.relu(ab.astype(jnp.float32))).astype(dt)
@@ -291,11 +341,11 @@ def _experts(rows, sizes, weights, p):
         return jax.lax.ragged_dot(mid, weights["w_out"].astype(dt), sizes)
 
 
-def _all_rows(xt, gate, held, order, sizes, weights, p):
+def _all_rows(xt, gate, held, order, sizes, weights, p, tile=None):
     """Every (token, choice) pair of the block has a row: the whole
-    `tokens * k` buffer, whatever is held."""
+    `tokens * k` buffer, whatever is held. `tile`: as `_experts` takes."""
     tokens, k = gate.shape
-    out = _experts(xt[order // k], sizes, weights, p)          # [tokens*k, d]
+    out = _experts(xt[order // k], sizes, weights, p, tile)    # [tokens*k, d]
     # back to (token, choice) order, one choice at a time (a [tokens, k, d]
     # buffer in f32 would be the largest of the program); rows past the
     # last group hold nothing that was computed, so they are selected
@@ -314,7 +364,7 @@ def _no_rows(xt, *_):
     return jnp.zeros_like(xt)
 
 
-def _held_rows(cap, xt, gate, held, order, sizes, weights, p):
+def _held_rows(cap, xt, gate, held, order, sizes, weights, p, tile=None):
     """The same for a block that holds at most `cap` pairs: they are the
     first `cap` of `order` (absent pairs sort last), and gather, products,
     gate and combine are over those rows alone. A token's rows lie apart
@@ -324,7 +374,7 @@ def _held_rows(cap, xt, gate, held, order, sizes, weights, p):
     k = gate.shape[1]
     pair = order[:cap]
     token = pair // k
-    out = _experts(xt[token], sizes, weights, p)               # [cap, d]
+    out = _experts(xt[token], sizes, weights, p, tile)         # [cap, d]
     live = jnp.arange(cap) < jnp.sum(sizes)
     part = jnp.where(live[:, None],
                      gate.reshape(-1)[pair][:, None] * out.astype(jnp.float32),
@@ -370,15 +420,30 @@ def _routing(xt, exists, weights, p):
     return gate, held, local
 
 
-def _route_tokens(xt, exists, weights, p, told_what_exists: bool):
+def _rows_tile(rows: int, d: int, itemsize: int, p):
+    """The rows kernel's tiles for a block's buffer of `rows` rows, None
+    where the grouped product multiplies it. From what the shapes say
+    (`moe_rows.row_tiles`): whole row tiles, and the experts' K and width
+    in whole 128-lane slabs (the tiny test configurations' are not)."""
+    from flexflow_tpu.kernels import moe_rows
+
+    return moe_rows.row_tiles(rows, p.get("latent_size", d),
+                              p["expert_width"], _in_parts(p), itemsize)
+
+
+def _route_tokens(xt, exists, weights, p, told_what_exists: bool,
+                  kernel: bool = False):
     """One block of tokens `[tokens, d]` through the routed layer: (this
     holder's part of the output `[tokens, d]`, rows on each held expert
-    `[held]`, rows the grouped product was sized for). `told_what_exists`:
-    the layer has its `valid` input, so `exists` may name fewer than all."""
+    `[held]`, rows the experts' buffer was sized for; and, where a rung's
+    buffer can take the rows kernel, how many of those rows took it).
+    `told_what_exists`: the layer has its `valid` input, so `exists` may
+    name fewer than all. `kernel`: one device, so a buffer whose shapes
+    admit `kernels/moe_rows.py` takes it (`_rows_tile`)."""
     k = p["top_k"]
     lo, hi = p["experts_held"]
     held_n = hi - lo
-    tokens, _d = xt.shape
+    tokens, d = xt.shape
     gate, held, local = _routing(xt, exists, weights, p)
     order = jnp.argsort(local, stable=True)
     sizes = jnp.bincount(local, length=held_n + 1)[:held_n].astype(jnp.int32)
@@ -388,17 +453,32 @@ def _route_tokens(xt, exists, weights, p, told_what_exists: bool):
     # pair, and a block of a few rows has nothing to save: no ladder
     caps = _row_capacities(tokens * k) \
         if held_n < p["num_experts"] or told_what_exists else [tokens * k]
-    wrap = functools.partial(_in_experts_width, p=p)
-    whole = wrap(functools.partial(_all_rows, p=p))
+    tiles = [_rows_tile(cap, d, xt.dtype.itemsize, p) if kernel and cap
+             else None for cap in caps]
+
+    def rows_fn(fn, tile):
+        return _in_experts_width(functools.partial(fn, p=p, tile=tile), p)
+
+    whole = rows_fn(_all_rows, tiles[-1])
     if len(caps) == 1:
-        return (whole(xt, gate, held, order, sizes, weights), sizes,
-                jnp.int32(tokens * k))
-    rung = jnp.sum(jnp.sum(sizes) > jnp.asarray(caps[:-1], jnp.int32))
-    y = jax.lax.switch(
-        rung, [wrap(functools.partial(_held_rows, cap, p=p)) if cap
-               else _no_rows for cap in caps[:-1]] + [whole],
-        xt, gate, held, order, sizes, weights)
-    return y, sizes, jnp.asarray(caps, jnp.int32)[rung]
+        rung = 0
+        y = whole(xt, gate, held, order, sizes, weights)
+        computed = jnp.int32(tokens * k)
+    else:
+        rung = jnp.sum(jnp.sum(sizes) > jnp.asarray(caps[:-1], jnp.int32))
+        y = jax.lax.switch(
+            rung, [rows_fn(functools.partial(_held_rows, cap), tile) if cap
+                   else _no_rows for cap, tile in zip(caps[:-1], tiles)]
+            + [whole],
+            xt, gate, held, order, sizes, weights)
+        computed = jnp.asarray(caps, jnp.int32)[rung]
+    # the rows of each rung that the kernel multiplies: handed out only
+    # where some rung's are (a program none of whose buffers takes it
+    # lowers to the text it lowered to)
+    by_kernel = [cap if tile else 0 for cap, tile in zip(caps, tiles)]
+    return (y, sizes, computed) \
+        + ((jnp.asarray(by_kernel, jnp.int32)[rung],) if any(by_kernel)
+           else ())
 
 
 def _step_forward(xt, gate, local, ids, count, w_in, w_out, relu2, tn):
@@ -417,8 +497,7 @@ def _step_backward(relu2, tn, operands, ct):
     recomputed."""
     xt, gate, local, _ids, _count, w_in, w_out = operands
     held_n = w_in.shape[0]
-    p = {"expert_width": w_out.shape[1],
-         "expert_activation": "relu2" if relu2 else None}
+    p = _params_of(w_out, relu2)
 
     held = (local < held_n).reshape(gate.shape)
     order = jnp.argsort(local, stable=True)
@@ -495,6 +574,13 @@ def _report_step_kernel(ctx, experts) -> None:
     ctx.add_stat("moe_step_kernel_experts", experts)
 
 
+def _report_rows_kernel(ctx, rows) -> None:
+    """`moe_rows_kernel`: the rows of the buffers that `ff_moe_rows`
+    multiplied, summed over blocks (equal to `moe_rows_computed` where it
+    ran), 0 where the grouped product did."""
+    ctx.add_stat("moe_rows_kernel", rows)
+
+
 def _moe_layer_lower(layer: Layer, inputs, weights, ctx):
     """Dropless top-k layer of experts (gated SiLU, or as `_experts` says)
     over `[batch, seq, d]`, for a holder of the experts `experts_held =
@@ -510,20 +596,20 @@ def _moe_layer_lower(layer: Layer, inputs, weights, ctx):
     nothing, so the result is this holder's part of the layer's output
     (the parts of all holders add up to the whole layer).
 
-    Two forms, chosen from the block's shapes (`_step_tile`; no flag). A
-    DECODE STEP (no ladder, at most 16 tokens, the experts' K and width in
-    whole 128-lane slabs, one device): top-k picks distinct experts, so an
-    expert's group holds at most the step's 16 rows, one bf16 sublane tile,
-    and nothing is sorted, gathered or combined: `kernels/moe_step.py` streams
-    each hit expert's two matrices once over ALL the step's rows and adds
-    `gate * out` into one f32 result on the chip, the gate 0 where a token
-    did not choose the expert (`_route_step`; per-expert rows and gates by
-    compare-and-sum over the `tokens * k` pairs; the backward is the
-    grouped form's, a `custom_vjp`). EVERY OTHER BLOCK (a wave's 4096
-    tokens, a verifier's `[slots, 1 + draft]`, a tiny model's widths):
-    pairs are sorted by expert, absent ones behind the last group, and the
-    held ones' rows multiplied as one grouped product
-    (`jax.lax.ragged_dot`). The row
+    Three forms, chosen from the block's shapes and the mesh (`_step_tile`,
+    `_rows_tile`; no flag). A DECODE STEP (no ladder, at most 16 tokens, the
+    experts' K and width in whole 128-lane slabs, one device): top-k picks
+    distinct experts, so an expert's group holds at most the step's 16 rows,
+    one bf16 sublane tile, and nothing is sorted, gathered or combined:
+    `kernels/moe_step.py` streams each hit expert's two matrices once over
+    ALL the step's rows and adds `gate * out` into one f32 result on the
+    chip, the gate 0 where a token did not choose the expert (`_route_step`;
+    per-expert rows and gates by compare-and-sum over the `tokens * k` pairs;
+    the backward is the grouped form's, a `custom_vjp`). EVERY OTHER BLOCK
+    (a wave's 4096 tokens, a chunk's 2048, a verifier's `[slots, 1 + draft]`,
+    a tiny model's widths): pairs are sorted by expert, absent ones behind
+    the last group, the held ones' rows gathered into a buffer, multiplied
+    group by group (`_experts`) and combined under their gates. The row
     buffers (gather, products, f32 gate, combine) are sized per block of
     `MOE_TOKEN_BLOCK` tokens at run time: the smallest rung of
     `_row_capacities(tokens * k)` that holds the block's held pairs, chosen
@@ -536,7 +622,16 @@ def _moe_layer_lower(layer: Layer, inputs, weights, ctx):
     `valid` `[batch, seq]`, which names the tokens that exist (the others
     are not routed; a padded prefill wave is mostly such). A holder of
     every expert without `valid`, and a block too small for a smaller rung
-    (a decode step in either form), lower with no conditional.
+    (a decode step in either form), lower with no conditional. What
+    multiplies a rung's buffer is chosen a rung: A BUFFER WHOSE SHAPES
+    ADMIT `ff_moe_rows` (one device, K and the width in whole 128-lane
+    slabs, whole row tiles of 256: every served cell's rungs) goes through
+    `kernels/moe_rows.py`, both products and the activation in one pass in
+    which `ab` and `mid` stay on the chip and an expert with no row is not
+    read (the backward is the grouped form's, a `custom_vjp`); EVERY OTHER
+    BUFFER (a mesh of several devices, which GSPMD partitions; widths that
+    are no whole slabs; fewer rows than a tile) through two
+    `jax.lax.ragged_dot` with the activation between them.
 
     Reports (ctx.add_stat): moe_routed_pairs, moe_held_pairs, moe_load_max
     (rows on the fullest held expert), moe_load_mean (held pairs over
@@ -546,7 +641,9 @@ def _moe_layer_lower(layer: Layer, inputs, weights, ctx):
     summed over blocks: equal to moe_rows_static where there is no
     ladder), moe_step_kernel_experts (the step kernel's grid steps on its
     first axis: moe_experts_hit where it ran, 0 where the block took the
-    grouped product)."""
+    grouped product), moe_rows_kernel (the rows of the buffers that the
+    rows kernel multiplied, summed over blocks: moe_rows_computed where it
+    ran, 0 where `ragged_dot` did)."""
     x = inputs[0]
     p = layer.params
     b, s, d = x.shape
@@ -557,21 +654,23 @@ def _moe_layer_lower(layer: Layer, inputs, weights, ctx):
         else inputs[1].reshape(tokens, 1) > 0
     # on a mesh of several devices the grouped product stays: GSPMD
     # partitions it, and cannot partition a Mosaic call
-    tn = None if multi_device(ctx.mesh) \
-        else _step_tile(tokens, d, x.dtype.itemsize, p)
-    kernel_experts = jnp.int32(0)
+    one_device = not multi_device(ctx.mesh)
+    tn = _step_tile(tokens, d, x.dtype.itemsize, p) if one_device else None
+    kernel_experts, kernel_rows = jnp.int32(0), ()
     if tn is not None:
         y, sizes, kernel_experts = _route_step(xt, exists, weights, p, tn)
         computed = jnp.int32(tokens * p["top_k"])
     elif tokens > MOE_TOKEN_BLOCK and tokens % MOE_TOKEN_BLOCK == 0:
         blocks = tokens // MOE_TOKEN_BLOCK
-        y, sizes, computed = jax.lax.map(
-            lambda block: _route_tokens(block[0], block[1], weights, p, told),
+        y, sizes, computed, *kernel_rows = jax.lax.map(
+            lambda block: _route_tokens(block[0], block[1], weights, p, told,
+                                        one_device),
             (xt.reshape(blocks, MOE_TOKEN_BLOCK, d),
              exists.reshape(blocks, MOE_TOKEN_BLOCK, 1)))
         sizes, computed = jnp.sum(sizes, axis=0), jnp.sum(computed)
     else:
-        y, sizes, computed = _route_tokens(xt, exists, weights, p, told)
+        y, sizes, computed, *kernel_rows = _route_tokens(
+            xt, exists, weights, p, told, one_device)
     n_held = jnp.sum(sizes)
     ctx.add_stat("moe_routed_pairs",
                  jnp.sum(exists).astype(jnp.int32) * p["top_k"])
@@ -584,6 +683,8 @@ def _moe_layer_lower(layer: Layer, inputs, weights, ctx):
     ctx.add_stat("moe_rows_static", jnp.int32(tokens * p["top_k"]))
     ctx.add_stat("moe_rows_computed", computed)
     _report_step_kernel(ctx, kernel_experts)
+    _report_rows_kernel(ctx, jnp.sum(kernel_rows[0]) if kernel_rows
+                        else jnp.int32(0))
     return [y.reshape(b, s, d)]
 
 

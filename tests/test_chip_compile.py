@@ -33,7 +33,7 @@ sys.path.insert(0, ROOT)  # chip_smoke
 
 KERNEL_MODULES = ("flash_attention", "dequant_attention", "ssd_scan",
                   "kda_scan", "retention_step", "moe_step", "mamba2_step",
-                  "sparse_attend_step", "sparse_attend_chunk")
+                  "sparse_attend_step", "sparse_attend_chunk", "moe_rows")
 
 # GPT-2 medium: batch 8, 16 heads of 64, seq 1024
 B, H, S, D = 8, 16, 1024, 64
@@ -590,8 +590,8 @@ def test_granite_serving_programs_fit_one_chip(described_devices, mosaic,
     normal entry points: the chip's compiler must hold the prefill wave
     beside the weights, the state and the cache (its first compile ran
     1 GB over the chip: a `[tokens, k, d]` f32 combine and every mixer's
-    xBC kept live to the program's end), and the grouped product and the
-    flash kernel must be what it lowers to. The decode step appends to the
+    xBC kept live to the program's end), and the experts' rows kernel and
+    the flash kernel must be what it lowers to. The decode step appends to the
     pools it was handed: no whole-pool copy, every pool aliased."""
     eng, g, params, state = _described_engine(
         "granite-4.0-h-small.serve-chat", described_devices, monkeypatch,
@@ -615,7 +615,7 @@ def test_granite_serving_programs_fit_one_chip(described_devices, mosaic,
         assert need < 0.9 * chip, (need, m)
     assert prefill.memory_analysis().temp_size_in_bytes < 2.5e9
     text = prefill.as_text()
-    assert "ragged-dot" in text and "tpu_custom_call" in text
+    assert "ff_moe_rows" in text and "ragged-dot" not in text
     _assert_appends_in_place(decode, eng)
     _assert_mixers_step_in_place(decode, 9)
 
@@ -627,7 +627,7 @@ def test_gigachat_serving_programs_fit_one_chip(described_devices, mosaic,
     of `[1281, 16, 640]`: 576 values a row in whole lanes), through the normal entry points: the chip's
     compiler must hold the prefill wave beside the weights and the cache,
     the 192-wide heads must go through the flash kernel and the experts
-    through the grouped product, and the decode step must append to the
+    through the rows kernel, and the decode step must append to the
     pools it was handed (no whole-pool copy, every pool aliased)
     and attend in the latent space: no `[slots, context, heads, 320]`
     decompression of the cache exists in it."""
@@ -658,7 +658,7 @@ def test_gigachat_serving_programs_fit_one_chip(described_devices, mosaic,
                 + m.temp_size_in_bytes + beside)
         assert need < 0.95 * chip, (need, m)
     text = prefill.as_text()
-    assert "ragged-dot" in text and "tpu_custom_call" in text
+    assert "ff_moe_rows" in text and "ragged-dot" not in text
     # each of the five expert layers sizes its row buffers at run time: a
     # real conditional inside the loop over blocks (one branch runs), whose
     # branches need no more room than the whole block's rows did (3.27 GB)
@@ -688,8 +688,8 @@ def test_nemotron_serving_programs_fit_one_chip(described_devices, mosaic,
     of 11 layers 5 keep a recurrent state of 4.26 MB a slot, 1 pages K/V
     256 wide and 5 keep nothing), through the normal entry points: the
     chip's compiler must hold the prefill wave beside the weights, the state
-    and the cache, the 8-group scans and the latent-wide grouped products
-    must be what it lowers to, and the decode step (352 pairs an expert
+    and the cache, the 8-group scans and the latent-wide rows kernel must
+    be what it lowers to, and the decode step (352 pairs an expert
     layer: the first to get a ladder of row rungs) appends to the pools it
     was handed."""
     eng, g, params, state = _described_engine(
@@ -721,7 +721,7 @@ def test_nemotron_serving_programs_fit_one_chip(described_devices, mosaic,
     assert prefill.memory_analysis().temp_size_in_bytes < 2.5e9
     assert decode.memory_analysis().temp_size_in_bytes < 0.1e9
     text = prefill.as_text()
-    assert "ragged-dot" in text and "tpu_custom_call" in text
+    assert "ff_moe_rows" in text and "ragged-dot" not in text
     assert len(re.findall(r" conditional\(", text)) >= 5
     _assert_appends_in_place(decode, eng)
     _assert_mixers_step_in_place(decode, 5)
@@ -774,7 +774,8 @@ def test_ling_serving_programs_fit_one_chip(described_devices, mosaic,
     assert prefill.memory_analysis().temp_size_in_bytes < 3.6e9
     assert decode.memory_analysis().temp_size_in_bytes < 0.1e9
     text = prefill.as_text()
-    assert "ragged-dot" in text and "ff_kda_chunk_scan" in text
+    assert "ff_moe_rows" in text and "ragged-dot" not in text
+    assert "ff_kda_chunk_scan" in text
     assert len(re.findall(r' custom-call\([^\n]*custom_call_target='
                           r'"tpu_custom_call"[^\n]*ff_kda_chunk_scan',
                           text)) == 6
@@ -910,7 +911,7 @@ def test_lfm2_moe_serving_programs_fit_one_chip(described_devices, mosaic,
     points: the chip's compiler must hold the prefill wave and the decode
     step beside the weights and the cache (arguments + temporaries under 15
     GB), attention must go through the flash kernel and the wave's experts
-    through the grouped product, the wave's expert layers must carry the
+    through the rows kernel, the wave's expert layers must carry the
     ladder's conditional (a whole-holder with `valid`), the decode step none
     (its experts are the step kernel: the test after this one), and the
     decode step appends to the pools it was handed."""
@@ -947,7 +948,7 @@ def test_lfm2_moe_serving_programs_fit_one_chip(described_devices, mosaic,
                 - m.alias_size_in_bytes + m.temp_size_in_bytes + beside)
         assert need < 15e9 < chip, (need, m)
     text = prefill.as_text()
-    assert "ragged-dot" in text and "tpu_custom_call" in text
+    assert "ff_moe_rows" in text and "ragged-dot" not in text
     assert " conditional(" in text
     assert " conditional(" not in decode.as_text()
     # the step's experts are the kernel, once a layer, under the scope that
@@ -1022,6 +1023,8 @@ def test_keye_vl_serving_programs_fit_one_chip(described_devices, mosaic,
     under = attribution.instructions_in_scope(text, EXPERTS_SCOPE)
     assert sum(n.startswith("ff_moe_step") for n in under) == 6
     assert "ragged-dot" not in text
+    # a chunk's experts are the rows kernel, once a rung and layer
+    _assert_experts_are_the_rows_kernel(chunk.as_text(), 3 * 6)
     assert attribution.instructions_in_scope(text, INDEX_SCOPE)
     under = attribution.instructions_in_scope(text, ATTEND_SCOPE)
     assert sum(n.startswith("ff_sparse_attend_step") for n in under) == 6
@@ -1130,9 +1133,27 @@ def test_mellum_serving_programs_fit_one_chip(described_devices, mosaic,
     under = attribution.instructions_in_scope(text, EXPERTS_SCOPE)
     assert sum(n.startswith("ff_moe_step") for n in under) == layers
     assert "ragged-dot" not in text
+    # a chunk's experts are the rows kernel, once a rung and layer (1024,
+    # 4096 and all 16 384 rows): no grouped product of XLA's is left in it
+    _assert_experts_are_the_rows_kernel(chunk.as_text(), 3 * layers)
     # a step holds no slot's gathered context or window
     assert f"bf16[{slots},16896,512]" not in text
     assert f"bf16[{slots},3088,512]" not in text
+
+
+def _assert_experts_are_the_rows_kernel(text, calls):
+    """A compiled wave or chunk program's expert layers: `calls` Mosaic
+    calls `ff_moe_rows` under `ff_moe_experts` (`kernels/moe_rows.py`, one a
+    rung of a layer's `lax.switch`) and no grouped product of XLA's own."""
+    from flexflow_tpu import attribution
+    from flexflow_tpu.ops.moe_ops import EXPERTS_SCOPE
+
+    under = attribution.instructions_in_scope(text, EXPERTS_SCOPE)
+    assert sum(n.startswith("ff_moe_rows") for n in under) == calls
+    assert len(re.findall(r' custom-call\([^\n]*custom_call_target='
+                          r'"tpu_custom_call"[^\n]*ff_moe_rows',
+                          text)) == calls
+    assert "ragged-dot" not in text
 
 
 MOE_CELLS = {   # cell: (inputs of its programs, expert layers, a tile's tn)
@@ -1154,7 +1175,7 @@ def test_a_decode_steps_experts_are_the_step_kernel(cell, described_devices,
     product is left in the step, and none of the pair sort, the row gather
     and the combine's gathers under an expert layer's scope (the router's
     `top_k` is the one sort there). The prefill program, whose blocks are
-    4096 tokens, lowers to the grouped product as it did (asked of its
+    4096 tokens, lowers to the rows kernel at every rung (asked of its
     StableHLO: the family's own test compiles it)."""
     from flexflow_tpu import attribution
     from flexflow_tpu.kernels import moe_step
@@ -1188,7 +1209,8 @@ def test_a_decode_steps_experts_are_the_step_kernel(cell, described_devices,
     wave = eng._prefill_first_tokens_jit.lower(
         params, [_i32(one_chip, slots, g.seq)] * inputs,
         _i32(one_chip, slots)).as_text()
-    assert "ragged_dot" in wave and "ff_moe_step" not in wave
+    assert "ff_moe_rows" in wave and "ragged_dot" not in wave \
+        and "ff_moe_step" not in wave
 
 
 def test_gpt2_medium_decode_and_commit_append_in_place(described_devices,

@@ -848,6 +848,8 @@ def test_the_other_models_serving_programs_lower_to_the_parents(name, want,
     monkeypatch.setattr(moe_ops, "_report_experts_held", lambda *a: None)
     # and PR 48's `moe_step_kernel_experts`, 0 in every block of these
     monkeypatch.setattr(moe_ops, "_report_step_kernel", lambda *a: None)
+    # and PR 57's `moe_rows_kernel`, 0 at these widths (no whole slab)
+    monkeypatch.setattr(moe_ops, "_report_rows_kernel", lambda *a: None)
     # and PR 50's `ssm_step_kernel_slots`, 0 at d_state 16 (the XLA form)
     monkeypatch.setattr(ssm_ops, "_report_step_kernel", lambda *a: None)
     build, inputs = BUILDERS[name]
