@@ -205,9 +205,12 @@ def scope_of_op_name(op_name: str, op_types: Dict[str, str]
     everything under `ff.loss` (its own backward too) is `loss`, under
     `ff.update` `update`. Recomputed forward under `jax.checkpoint` carries
     the wrappers of the backward pass it is recomputed in, so it counts as
-    `backward`: it is time the backward pass costs."""
+    `backward`: it is time the backward pass costs. Where a checkpointed
+    unit holds the layers' scopes INSIDE it (`remat_blocks`), the backward
+    pass's wrapper stands on an earlier segment (`transpose(jvp(jvp()))/
+    checkpoint/l1_attn/...`): a `transpose` seen on the way counts too."""
+    wrappers = []
     for seg in _segments(op_name):
-        wrappers = []
         m = _WRAPPED.match(seg)
         while m is not None:
             wrappers.append(m.group(1))

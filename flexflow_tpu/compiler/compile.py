@@ -33,8 +33,9 @@ from flexflow_tpu.config import ensure_compile_cache
 from flexflow_tpu.core.graph import topo_order
 from flexflow_tpu.core.tensor import Tensor
 from flexflow_tpu.compiler.lowering import build_forward, constrainable
+from flexflow_tpu.ops.registry import STATS_KEY
 from flexflow_tpu.dtype import DataType
-from flexflow_tpu.initializers import default_initializer
+from flexflow_tpu.initializers import ZeroInitializer, default_initializer
 from flexflow_tpu.losses import LossType, compute_loss
 from flexflow_tpu.metrics import MetricsType, PerfMetrics, compute_metrics
 from flexflow_tpu.optimizers import Optimizer, SGDOptimizer
@@ -311,6 +312,32 @@ def _zero_moment_pspec(pspec: PartitionSpec, shape, mesh: Mesh,
     return PartitionSpec(*spec)
 
 
+# a layer's state is keyed past any weight of it
+STATE_KEY_OFFSET = 1 << 16
+# the prefix under which a step's counters (`ctx.add_stat`) ride its metrics
+STEP_STATS_PREFIX = "stats/"
+
+
+def build_state_init_fn(layers, overrides):
+    """The same for what layers declare as non-trainable state
+    (`Layer.state_specs`: a selection bias that a step moves without a
+    gradient): `{"<layer>/<name>": array}`, flat like batch norm's running
+    statistics (runtime/checkpoint.py saves such a dict as it is), zeros
+    where the layer names no initializer for it."""
+    def init_fn(key):
+        state = {}
+        for li, layer in enumerate(layers):
+            for i, (sname, spec) in enumerate(
+                    sorted(layer.state_specs.items())):
+                init = overrides.get((layer.name, sname)) or ZeroInitializer()
+                state[f"{layer.name}/{sname}"] = init(
+                    jax.random.fold_in(jax.random.fold_in(key, li),
+                                       STATE_KEY_OFFSET + i), spec)
+        return state
+
+    return init_fn
+
+
 def build_init_fn(layers, overrides, topo_idx=None):
     """Weight-init closure shared by CompiledModel.init and the pipeline
     runtime (parallel/pipeline.py): params for `layers`, each weight keyed
@@ -417,12 +444,15 @@ class CompiledModel:
         # per-layer choices already on strategy.remat.
         if self.cfg.remat and not getattr(strategy, "remat", None):
             strategy.remat = {l.name: "full" for l in model.layers}
+        if self.cfg.remat_blocks:
+            strategy.remat = {l.name: "block" for l in model.layers}
 
         self.forward_fn = build_forward(model.layers, model.input_tensors, outputs,
                                         mesh, strategy,
                                         seq_length=self.cfg.seq_length or None,
                                         compute_dtype=self.cfg.compute_dtype,
-                                        enable_fusion=self.cfg.enable_fusion)
+                                        enable_fusion=self.cfg.enable_fusion,
+                                        collect_stats=True)
         # gradient-accumulation width the step functions are built for
         # (cfg default; fit(accum_steps=...) rebuilds on a different value)
         self._accum_steps = max(1, int(self.cfg.accum_steps))
@@ -544,7 +574,11 @@ class CompiledModel:
         with tel.span("compile/init", cat="compile") as sp:
             self.params = jax.jit(init_fn, out_shardings=shardings)(
                 jax.random.PRNGKey(seed))
-            self.state = {}
+            # what layers declare as state (`Layer.state_specs`), drawn
+            # like the weights; nothing for a model without
+            self.state = jax.jit(build_state_init_fn(layers, overrides))(
+                jax.random.PRNGKey(seed)) \
+                if any(l.state_specs for l in layers) else {}
             # jitted with EXPLICIT out_shardings (vs the old eager tx.init):
             # moments land directly in their target layout — sharded from
             # the first byte under ZeRO, and never paying the transient
@@ -636,6 +670,17 @@ class CompiledModel:
                 return dict(mvals, **health.sentinel_metrics(
                     loss, optax.global_norm(grads)))
 
+        def step_stats(mvals, new_state):
+            """The counters the step's ops reported (`ctx.add_stat`) leave
+            the state they came out in and ride the step's metrics under
+            `STEP_STATS_PREFIX`: the fit loop takes them off again, and a
+            model whose ops report none has none."""
+            counted = new_state.pop(STATS_KEY, None)
+            if not counted:
+                return mvals
+            return dict(mvals, **{STEP_STATS_PREFIX + k: jnp.float32(v)
+                                  for k, v in counted.items()})
+
         def train_step(params, opt_state, state, inputs, label, rng):
             (loss, (logits, new_state)), grads = value_and_grads(
                 params, state, inputs, label, rng)
@@ -644,7 +689,7 @@ class CompiledModel:
                 mvals = compute_metrics(metric_types,
                                         logits.astype(jnp.float32), label)
             return (params, opt_state, new_state, loss,
-                    grad_sentinels(mvals, loss, grads))
+                    grad_sentinels(step_stats(mvals, new_state), loss, grads))
 
         def accum_step(params, opt_state, state, inputs, label, rng):
             """accum_steps=N microbatching: inputs/label carry a leading
@@ -669,7 +714,7 @@ class CompiledModel:
                 with jax.named_scope(attribution.LOSS_SCOPE):
                     mvals = compute_metrics(metric_types,
                                             logits.astype(jnp.float32), lab)
-                return new_state, grads, loss, mvals
+                return new_state, grads, loss, step_stats(mvals, new_state)
 
             def body(j, carry):
                 s, g, lsum, msum = carry
@@ -1047,6 +1092,10 @@ class CompiledModel:
               # bit-exact below fold_after pending steps, ~1e-7 relative
               # beyond (see PerfMetrics docstring)
               pml = PerfMetrics()
+              # and a third for the counters the step's ops reported
+              # (`ctx.add_stat`), keyed by steps: taken off the metrics
+              # below, read where the loss is, at the epoch's end
+              pst = PerfMetrics()
               nb = 0
               # steps/samples re-seeded from a resumed snapshot: the epoch
               # SUMMARY covers the whole epoch, but wall-clock-derived
@@ -1160,6 +1209,12 @@ class CompiledModel:
                   stats["dispatches"] += 1
                   if sent is not None:
                       sent.push(steps, mvals)  # strips health/* keys
+                  counted = [mk for mk in mvals
+                             if mk.startswith(STEP_STATS_PREFIX)]
+                  if counted:
+                      pst.update_deferred(
+                          steps, {mk[len(STEP_STATS_PREFIX):]: mvals.pop(mk)
+                                  for mk in counted})
                   pml.update_deferred(steps, {"loss": loss})
                   pm.update_deferred(batch_size * accum * steps, mvals)
                   gm.lap("loop")
@@ -1197,6 +1252,13 @@ class CompiledModel:
               # its own, not as a mid-epoch host sync)
               with tel.span("fit/epoch_end_sync", cat="fit"):
                   pml.materialize()
+                  if pst.train_all:
+                      # the epoch's counters, a mean a step: one record
+                      step_means = pst.summary()
+                      step_means.pop("samples")
+                      tel.record("fit/step_stats", tel.now_us(), cat="fit",
+                                 epoch=epoch, steps=pst.train_all,
+                                 **step_means)
                   if sent is not None:
                       sent.check(self._iteration,
                                  loss_sum=pml.sums.get("loss", 0.0),

@@ -49,6 +49,34 @@ def maybe_constrain(x, pspec: PartitionSpec, mesh: Mesh):
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, pspec))
 
 
+def checkpoint_units(order: Sequence[Layer], outputs: Sequence[Tensor],
+                     remat_map: Dict[str, str]) -> List[List[Layer]]:
+    """`order` cut into the units a forward pass runs: a layer alone, or a
+    run of consecutive layers whose policy is "block", which `jax.checkpoint`
+    recomputes as ONE function in the backward pass. Such a unit ends after
+    a layer one of whose outputs has more than one consumer or is the
+    graph's (the residual stream after each add, a norm's output that two
+    branches read): those tensors, the units' inputs, are all the forward
+    pass keeps of it."""
+    consumers: Dict[int, int] = {}      # the layers that read a tensor
+    for layer in order:
+        for guid in {t.guid for t in layer.inputs}:
+            consumers[guid] = consumers.get(guid, 0) + 1
+    for t in outputs:
+        consumers[t.guid] = consumers.get(t.guid, 0) + 2
+    units: List[List[Layer]] = []
+    open_unit = False
+    for layer in order:
+        block = remat_map.get(layer.name) == "block"
+        if block and open_unit:
+            units[-1].append(layer)
+        else:
+            units.append([layer])
+        open_unit = block and all(consumers.get(t.guid, 0) <= 1
+                                  for t in layer.outputs)
+    return units
+
+
 def build_forward(
     layers: Sequence[Layer],
     graph_inputs: Sequence[Tensor],
@@ -63,7 +91,8 @@ def build_forward(
     """Returns forward(params, state, input_arrays, training, rng)
     -> (output_arrays, new_state). `collect_stats`: new_state[STATS_KEY]
     holds the counters that ops reported through ctx.add_stat, where any
-    did (the serving programs)."""
+    did (the serving programs; a training step, which hands them out with
+    its metrics)."""
     import jax.numpy as jnp
 
     order = topo_order(layers)
@@ -108,6 +137,88 @@ def build_forward(
             if ex:
                 cast_exempt[_l.name] = ex
 
+    units = checkpoint_units(order, outputs, remat_map)
+    # what each unit hands on: its tensors that a layer of another unit,
+    # or the caller, reads
+    unit_of = {layer.name: i for i, unit in enumerate(units)
+               for layer in unit}
+    handed_on: List[List[int]] = [[] for _ in units]
+    reads = [(unit_of[layer.name], t) for layer in order
+             for t in layer.inputs] + [(None, t) for t in outputs]
+    for reader, t in reads:
+        # (a tensor of a layer outside `layers` is an input here)
+        made_in = unit_of.get(t.owner.name) if t.owner is not None else None
+        if made_in is not None and made_in != reader \
+                and t.guid not in handed_on[made_in]:
+            handed_on[made_in].append(t.guid)
+    ctx_kw = dict(seq_length=seq_length,
+                  compute_dtype=str(cast_to) if cast_to else None, mesh=mesh,
+                  op_attrs=op_attrs, op_shardings=strategy.op_shardings,
+                  enable_fusion=enable_fusion)
+
+    def sub_ctx(state, rng, training):
+        """The context of a checkpointed function: its own new_state and
+        stats, which come back as explicit outputs."""
+        return LoweringCtx(training=training, rng=rng, state=state,
+                           stats={} if collect_stats else None, **ctx_kw)
+
+    def cast_weights(layer, w):
+        # uniform mixed-precision policy: master weights stay f32 in
+        # params/optimizer, every op computes in compute_dtype; grads
+        # flow back through the cast and accumulate in f32. Norm
+        # params (gamma/beta) are exempt — their lowerings compute the
+        # affine in f32 (standard AMP keeps norm params full
+        # precision) — including norms inside fork_join branches.
+        ex = cast_exempt.get(layer.name, ())
+        return {k: (v.astype(cast_to)
+                    if k not in ex and jnp.issubdtype(v.dtype, jnp.floating)
+                    else v)
+                for k, v in w.items()}
+
+    def lower_one(layer, ins, w, ctx):
+        outs = get_op_def(layer.op_type).lower(layer, ins, w, ctx)
+        if mesh is not None:
+            sh = strategy.sharding_for(layer.name)
+            outs = [maybe_constrain(o, sh.output_pspec(i), mesh)
+                    for i, o in enumerate(outs)]
+        return outs
+
+    def run_block(unit, env, params, ctx):
+        """A unit of "block" layers as ONE checkpointed function of the
+        tensors it reads from outside, its layers' weights as they lie (the
+        compute-dtype cast is inside: recomputed, not kept), the state and
+        the rng; its layers keep their own name scopes."""
+        made = {t.guid for layer in unit for t in layer.outputs}
+        reads = list(dict.fromkeys(t.guid for layer in unit
+                                   for t in layer.inputs
+                                   if t.guid not in made))
+        hands_on = handed_on[unit_of[unit[0].name]]
+        training = ctx.training
+
+        def _unit(u_ins, u_w, u_state, u_rng):
+            sub = sub_ctx(u_state, u_rng, training)
+            local = dict(zip(reads, u_ins))
+            for layer in unit:
+                with jax.named_scope(layer.name):
+                    w = u_w.get(layer.name, {})
+                    if cast_to is not None:
+                        w = cast_weights(layer, w)
+                    outs = lower_one(layer, [local[t.guid]
+                                             for t in layer.inputs], w, sub)
+                for t, o in zip(layer.outputs, outs):
+                    local[t.guid] = o
+            return [local[g] for g in hands_on], sub.new_state, \
+                sub.stats or {}
+
+        outs, delta, counted = jax.checkpoint(_unit)(
+            [env[g] for g in reads],
+            {layer.name: params[layer.name] for layer in unit
+             if layer.name in params}, dict(ctx.state), ctx.rng)
+        env.update(zip(hands_on, outs))
+        ctx.new_state.update(delta)
+        for stat, value in counted.items():
+            ctx.add_stat(stat, value)
+
     def forward(params, state, input_arrays, training, rng):
         ctx = LoweringCtx(training=training, rng=rng, seq_length=seq_length,
                           state=dict(state),
@@ -123,7 +234,11 @@ def build_forward(
             if mesh is not None:
                 arr = maybe_constrain(arr, strategy.input_pspec(t.name), mesh)
             env[t.guid] = arr
-        for layer in order:
+        for unit in units:
+            if remat_map.get(unit[0].name) == "block":
+                run_block(unit, env, params, ctx)
+                continue
+            layer, = unit
             ins = [env[t.guid] for t in layer.inputs]
             w = params.get(layer.name, {})
             # stamp the graph-layer name into the name stack: the compiled
@@ -135,19 +250,8 @@ def build_forward(
             # text and joins by instruction name.
             scope = jax.named_scope(layer.name)
             if cast_to is not None:
-                # uniform mixed-precision policy: master weights stay f32 in
-                # params/optimizer, every op computes in compute_dtype; grads
-                # flow back through the cast and accumulate in f32. Norm
-                # params (gamma/beta) are exempt — their lowerings compute the
-                # affine in f32 (standard AMP keeps norm params full
-                # precision) — including norms inside fork_join branches.
-                ex = cast_exempt.get(layer.name, ())
                 with jax.named_scope(layer.name):   # the cast is its work too
-                    w = {k: (v.astype(cast_to)
-                             if k not in ex
-                             and jnp.issubdtype(v.dtype, jnp.floating)
-                             else v)
-                         for k, v in w.items()}
+                    w = cast_weights(layer, w)
             pol = remat_map.get(layer.name)
             if pol in _ckpt_policies:
                 # run the layer inside jax.checkpoint as a pure function of
@@ -155,31 +259,19 @@ def build_forward(
                 # stateful updates come back as an explicit output instead
                 # of leaking tracers through the closed-over ctx
                 def _one(l_ins, l_w, l_state, l_rng, _l=layer):
-                    sub = LoweringCtx(
-                        training=training, rng=l_rng, seq_length=seq_length,
-                        state=l_state,
-                        compute_dtype=str(cast_to) if cast_to else None,
-                        mesh=mesh, op_attrs=op_attrs,
-                        op_shardings=strategy.op_shardings,
-                        enable_fusion=enable_fusion)
-                    l_outs = get_op_def(_l.op_type).lower(_l, l_ins, l_w, sub)
-                    if mesh is not None:
-                        l_sh = strategy.sharding_for(_l.name)
-                        l_outs = [maybe_constrain(o, l_sh.output_pspec(i),
-                                                  mesh)
-                                  for i, o in enumerate(l_outs)]
-                    return l_outs, sub.new_state
+                    sub = sub_ctx(l_state, l_rng, training)
+                    return lower_one(_l, l_ins, l_w, sub), sub.new_state, \
+                        sub.stats or {}
                 ckpt = jax.checkpoint(_one, policy=_ckpt_policies[pol])
                 with scope:
-                    outs, delta = ckpt(ins, w, dict(ctx.state), ctx.rng)
+                    outs, delta, counted = ckpt(ins, w, dict(ctx.state),
+                                                ctx.rng)
                 ctx.new_state.update(delta)
+                for stat, value in counted.items():
+                    ctx.add_stat(stat, value)
             else:
                 with scope:
-                    outs = get_op_def(layer.op_type).lower(layer, ins, w, ctx)
-                    if mesh is not None:
-                        sh = strategy.sharding_for(layer.name)
-                        outs = [maybe_constrain(o, sh.output_pspec(i), mesh)
-                                for i, o in enumerate(outs)]
+                    outs = lower_one(layer, ins, w, ctx)
             for t, o in zip(layer.outputs, outs):
                 env[t.guid] = o
         result = [env[t.guid] for t in outputs]

@@ -194,6 +194,13 @@ class FFConfig:
     # instead of forcing ZeRO or pipelining. The two flags contradict:
     # combining them is rejected (see _check_remat_knobs).
     remat: bool = False  # DEPRECATED alias: uniform "full" policy
+    # every layer recomputed in the backward pass, in UNITS of several
+    # layers (compiler/lowering.py `checkpoint_units`): a unit ends where a
+    # tensor has more than one consumer (the residual stream, a norm's
+    # output that two branches read), so what the forward pass keeps is
+    # those tensors alone, where per-layer "full" keeps every tensor
+    # between two layers. What a long sequence under a full chip needs.
+    remat_blocks: bool = False
     remat_search: bool = False
     remat_policies: str = "none,dots,full"
     donate_state: bool = True
@@ -369,6 +376,10 @@ class FFConfig:
         knobs contradict each other: the alias pins every layer to "full"
         while the search exists to pick per-layer policies. Fail loud
         instead of silently letting one win."""
+        if self.remat_blocks and (self.remat or self.remat_search):
+            raise ValueError(
+                "remat_blocks (every layer recomputed, in units that end at "
+                "the residual stream) contradicts --remat / --remat-search")
         if self.remat and self.remat_search:
             raise ValueError(
                 "--remat (deprecated: uniform 'full' remat) contradicts "

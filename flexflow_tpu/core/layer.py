@@ -45,6 +45,11 @@ class Layer:
         self.name = name or f"{op_type.value}_{self.guid}"
         # filled by compile: weight specs {wname: TensorSpec}
         self.weight_specs: Dict[str, TensorSpec] = {}
+        # and non-trainable state {name: TensorSpec} that `init` draws
+        # beside the weights (zeros, or the layer's initializer of that
+        # name; `state["<layer>/<name>"]`) and that a step hands on: no
+        # gradient, no optimizer moments
+        self.state_specs: Dict[str, TensorSpec] = {}
 
     @property
     def has_weights(self) -> bool:

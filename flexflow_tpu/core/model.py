@@ -141,6 +141,7 @@ class FFModel:
                             out_dim: int = 0,
                             window: Optional[int] = None,
                             rope_scaling: Optional[Dict[str, Any]] = None,
+                            output_gate: bool = False,
                             name=None) -> Tensor:
         # decode: single-token serving step reading/writing the paged KV
         # cache via lowering state; kv_out: prefill variant that exposes
@@ -159,7 +160,9 @@ class FFModel:
         # s <= t, the layer of a windowed model that sees the whole context:
         # its cache attention is stated by position too); rope_scaling: YaRN
         # for the positions' tables ({"factor", "original_max_position_
-        # embeddings", "beta_fast", "beta_slow", "attention_factor"}). All
+        # embeddings", "beta_fast", "beta_slow", "attention_factor"});
+        # output_gate: the heads' output times sigmoid(query input @ wg), a
+        # fifth weight, before wo. All
         # enter the params (and the inputs) only
         # where set, so graphs without them keep their fingerprints.
         params = {"embed_dim": int(embed_dim), "num_heads": int(num_heads), "kdim": kdim,
@@ -185,6 +188,8 @@ class FFModel:
             if int(window) < 0:
                 raise ValueError(f"window {window} < 0")
             params["window"] = int(window)
+        if output_gate:
+            params["output_gate"] = True
         if rope_scaling:
             params["rope_scaling"] = {
                 k: float(v) for k, v in rope_scaling.items()
@@ -294,10 +299,11 @@ class FFModel:
                                 "eps": eps},
                                [input], name)[0]
 
-    def rms_norm(self, input, eps: float = 1e-5, name=None):
+    def rms_norm(self, input, eps: float = 1e-5, name=None,
+                 gamma_initializer=None):
         """x / sqrt(mean(x^2) + eps) * gamma over the last axis."""
         return self._add_layer(OperatorType.RMSNORM, {"eps": eps}, [input],
-                               name)[0]
+                               name, {"gamma": gamma_initializer})[0]
 
     def mamba2(self, input: Tensor, heads: int, head_dim: int, d_state: int,
                d_conv: int = 4, chunk: int = 256, n_groups: int = 1,
@@ -514,10 +520,11 @@ class FFModel:
                   scoring: Optional[str] = None, n_group: int = 0,
                   topk_group: int = 0, norm_topk_prob: bool = False,
                   routed_scaling_factor: Optional[float] = None,
-                  score_bias: bool = False,
+                  score_bias=False,
                   expert_activation: Optional[str] = None,
                   latent_size: int = 0,
                   gate_norm_eps: Optional[float] = None,
+                  score_bias_rate: Optional[float] = None,
                   name=None) -> Tensor:
         """Dropless top-k layer of gated-SiLU experts over `[batch, seq,
         d]`, routed over all `num_experts`, computing those in
@@ -525,7 +532,9 @@ class FFModel:
         chooses and gates beyond top-k + softmax (`scoring` "sigmoid", the
         choice limited to `topk_group` of `n_group` groups, gates
         normalised (over their sum + `gate_norm_eps`) and scaled, a
-        `score_bias` weight for the selection; `moe_ops._choose`) and what
+        `score_bias` for the selection: True a weight, "state" the layer's
+        non-trainable state that a training step moves by `score_bias_rate`
+        against the load; `moe_ops._choose`, `_balance_bias`) and what
         its experts are beyond gated SiLU at
         the layer's own width (`expert_activation` "relu2": un-gated
         squared ReLU; `latent_size`: the experts work in a latent of that
@@ -545,7 +554,9 @@ class FFModel:
         if routed_scaling_factor is not None:
             params["routed_scaling_factor"] = float(routed_scaling_factor)
         if score_bias:
-            params["score_bias"] = True
+            params["score_bias"] = "state" if score_bias == "state" else True
+        if score_bias_rate is not None:
+            params["score_bias_rate"] = float(score_bias_rate)
         if expert_activation is not None:
             params["expert_activation"] = str(expert_activation)
         if latent_size:

@@ -52,8 +52,19 @@ scope `ff_sparse_attend`.
 With `window` in the params the kept keys are a FIXED mask by position: query
 `t` sees the keys `t - window < s <= t` (itself among them), every `s <= t`
 at `window` 0 (the layer of a windowed model that sees the whole context).
-The whole sequence takes the causal mask with the window under it on the XLA
-path where `window < seq` (kernels/flash_attention.py knows no window yet).
+The whole sequence goes through the flash kernels, which take the window
+(kernels/flash_attention.py: the key blocks wholly before the band are not
+visited, forward and backward; K/V heads fewer than the query heads are read
+through the block index, no repeated copy), where `impl` and the shapes say
+flash, and through the causal mask with the window under it on the XLA path
+elsewhere (every tiny model); `impl="flash"` under a window is honoured. Ring
+attention (kernels/ring_attention.py) knows no window: a windowed layer that a
+strategy places on the ring path raises by that name. A lowered layer says
+which form it took in a trace-time span (`flash/window`, `flash/full`,
+`xla/masked`, with its window and the forward kernel's tile), its work lies
+under `ff_window_attend` / `ff_full_attend`, and it reports `window_keys_seen`
+/ `full_keys_seen`: the (query, key) pairs under its band or triangle (and
+`window_keys_causal`, the triangle a band lies under).
 Over the cache both kinds take the two kernels above with the kept set stated
 by position (`first <= s <= t`; no mask operand, a page walk and a key axis
 that start at `first`) where `step_path` / `chunk_path` say so, an XLA form
@@ -63,10 +74,15 @@ entry `(t // page) % ring`, serving/kv_cache.py), one with `window` 0 in the
 slot's pages under `serve/page_table`. The work lies under the named scopes
 `ff_window_attend` and `ff_full_attend`. `rope_scaling` in the params: YaRN's
 tables (ops/rotary.py) for the layer's rotary positions.
+
+With `output_gate` in the params the heads' concatenated output is multiplied
+by `sigmoid(x W_g)` before `wo`, `x` the QUERY input and `wg` a fifth weight
+`[features, embed_dim]`: in all three forms (scope `ff_attn_gate`).
 """
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import math
 
@@ -97,6 +113,7 @@ WINDOW_SCOPE = "ff_window_attend"
 FULL_SCOPE = "ff_full_attend"
 # the scope both lie under (what reads the two kinds together names this one)
 BOUNDED_SCOPE = "ff_bounded_attend"
+GATE_SCOPE = "ff_attn_gate"
 WINDOW_TABLE_KEY = "serve/window_table"
 # `[rows]` int: how many of a block's positions hold a token (the chunk
 # program says; absent in a step, whose every live slot's one position does)
@@ -141,6 +158,8 @@ def _mha_infer(layer: Layer):
     if _positioned(layer) and (p.get("add_bias_kv") or p.get("add_zero_attn")):
         raise NotImplementedError("rotary positions or a q/k norm with "
                                   "add_bias_kv/add_zero_attn")
+    if p.get("output_gate"):
+        layer.weight_specs["wg"] = TensorSpec((q.shape[-1], embed), q.dtype)
     if p.get("qk_norm"):
         layer.weight_specs["q_norm"] = TensorSpec((embed // heads,), q.dtype)
         layer.weight_specs["k_norm"] = TensorSpec((embed // heads,), q.dtype)
@@ -202,6 +221,18 @@ def _scale(p, head_dim: int) -> float:
     attention multiplier), else 1 / sqrt(head_dim)."""
     return float(p["scale"]) if p.get("scale") is not None \
         else 1.0 / math.sqrt(head_dim)
+
+
+def _gated(out, x, weights):
+    """`out * sigmoid(x W_g)` where the layer has an output gate (`wg`),
+    `out` `[b, s, embed]` the heads' concatenated output, `x` the query
+    input; `out` as it is elsewhere."""
+    if "wg" not in weights:
+        return out
+    with jax.named_scope(GATE_SCOPE):
+        gate = jax.nn.sigmoid((x @ weights["wg"].astype(x.dtype))
+                              .astype(jnp.float32))
+        return out * gate.astype(out.dtype)
 
 
 def _split_heads(x, heads):
@@ -349,7 +380,7 @@ def _mha_decode_lower(layer: Layer, inputs, weights, ctx: LoweringCtx):
                 attend, ctx.mesh,
                 (rows, merged, merged, PartitionSpec(spec[0], None)), rows)
         out = attend(qg, K, V, t)
-    out = out.reshape(b, s, embed)
+    out = _gated(out.reshape(b, s, embed), q, weights)
     y = out @ weights["wo"].astype(dt)
     if "bo" in weights:
         y = y + weights["bo"].astype(dt)
@@ -695,6 +726,37 @@ def _flash_covers(qh, kh, vh, causal: bool) -> bool:
     return flash_supported(sq, d, it) and flash_supported(sk, d, it)
 
 
+def _einsum_attention(layer: Layer, qh, kh, vh, sk_orig: int, scale,
+                      window: int, ctx: LoweringCtx):
+    """The XLA form of a whole sequence: q, k, v `[b, s, h, d]`, the scores
+    a value of the program; `sk_orig` the keys that are positions of the
+    sequence (add_bias_kv / add_zero_attn append others), `window` the
+    band under the causal mask (0: none)."""
+    p = layer.params
+    logits = jnp.einsum("bqhd,bkhd->bhqk", qh, kh) * scale
+    if p.get("causal", False):
+        sq, sk = logits.shape[-2], logits.shape[-1]
+        # causal band over the ORIGINAL key positions only; positions
+        # appended by add_bias_kv/add_zero_attn (indices >= sk_orig, at the
+        # end) are always attendable and must not shift the band
+        mask = jnp.tril(jnp.ones((sq, sk_orig), bool), k=sk_orig - sq)
+        if window:    # the keys t - window < s <= t
+            mask &= ~jnp.tril(jnp.ones((sq, sk_orig), bool),
+                              k=sk_orig - sq - window)
+        if sk > sk_orig:
+            mask = jnp.concatenate(
+                [mask, jnp.ones((sq, sk - sk_orig), bool)], axis=1)
+        logits = jnp.where(mask, logits, jnp.finfo(logits.dtype).min)
+    probs = jax.nn.softmax(logits, axis=-1)
+    if ctx.training and p.get("dropout", 0.0) > 0.0:
+        import jax.random as jrandom
+
+        keep = 1.0 - p["dropout"]
+        mask = jrandom.bernoulli(ctx.rng_for(layer), keep, probs.shape)
+        probs = jnp.where(mask, probs / keep, 0.0).astype(probs.dtype)
+    return jnp.einsum("bhqk,bkhd->bqhd", probs, vh)
+
+
 def _mha_lower(layer: Layer, inputs, weights, ctx: LoweringCtx):
     q, k, v = inputs[:3]
     p = layer.params
@@ -742,46 +804,17 @@ def _mha_lower(layer: Layer, inputs, weights, ctx: LoweringCtx):
     if turn is not None:    # k once: what the prefill twin handed out
         qh = turn(qh, "q_norm")
         kh = k_out if p.get("kv_out", False) else turn(kh, "k_norm")
-    if kvh != heads:
-        # each K/V head once per query head of its group: the kernels and
-        # the einsum below then see one K/V head a query head
-        kh = jnp.repeat(kh, heads // kvh, axis=2)
-        vh = jnp.repeat(vh, heads // kvh, axis=2)
-
     impl = p.get("impl", "auto")
     causal = p.get("causal", False)
     scale = _scale(p, embed // heads)
     out = None
-    # a window the sequence outgrows: the masked XLA path below (the flash
-    # and ring kernels know no window); one that holds the sequence is the
-    # plain causal mask
+    # a window that holds the sequence is the plain causal mask: `band` is
+    # the window where it masks anything, else 0
     window = int(p.get("window") or 0)
-    windowed = 0 < window < k.shape[1]
-    if windowed and impl == "flash":
-        raise NotImplementedError("impl='flash' with a window under the "
-                                  "sequence's length")
-    if p.get("selected"):
-        if not causal or "bias_k" in weights or p.get("add_zero_attn") \
-                or q.shape[1] != k.shape[1]:
-            raise NotImplementedError("a selected key set on attention that "
-                                      "is not causal self-attention")
-        out = _selected_sequence_attention(qh, kh, vh, inputs[-1], scale)
+    band = window if 0 < window < k.shape[1] else 0
     # flash kernel has no probs-dropout path: fall back (or fail under
     # impl="flash") rather than silently dropping the dropout mask
     needs_dropout = ctx.training and p.get("dropout", 0.0) > 0.0
-    # sequence parallelism: the searched strategy may place this attention
-    # on the ring path (sp_ring candidate -> {"seq_parallel": axis} attr)
-    sp_axis = ctx.op_attrs.get(layer.name, {}).get("seq_parallel")
-    if out is None and not windowed and sp_axis and ctx.mesh is not None \
-            and sp_axis in ctx.mesh.shape \
-            and impl != "xla" and qh.shape[1] == kh.shape[1] == vh.shape[1] \
-            and qh.shape[1] % ctx.mesh.shape[sp_axis] == 0 \
-            and not needs_dropout and "bias_k" not in weights \
-            and not p.get("add_zero_attn", False):
-        from flexflow_tpu.kernels.ring_attention import ring_attention_qkv
-
-        out = ring_attention_qkv(qh, kh, vh, ctx.mesh, sp_axis,
-                                 causal=causal, scale=scale)
     if impl == "flash" and needs_dropout:
         raise NotImplementedError("impl='flash' does not support attention-prob "
                                   "dropout; use dropout=0.0 or impl='xla'")
@@ -791,44 +824,79 @@ def _mha_lower(layer: Layer, inputs, weights, ctx: LoweringCtx):
     # was chosen and then raises (trace, Mosaic compile) propagates: it
     # never silently becomes the einsum path.
     spec = _attn_pspec(layer, ctx, qh.shape[0], heads)
-    if out is None and not needs_dropout and not windowed and (
-            impl == "flash" or (impl == "auto" and ctx.enable_fusion
-                                and _flash_covers(qh, kh, vh, causal)
-                                and spec is not None)):
+    flash = not p.get("selected") and not needs_dropout and (
+        impl == "flash" or (impl == "auto" and ctx.enable_fusion
+                            and _flash_covers(qh, kh, vh, causal)
+                            and spec is not None))
+    # the flash kernels on one device read a group's K/V head through the
+    # block index; every other form sees one K/V head a query head
+    grouped = flash and kvh != heads and not multi_device(ctx.mesh)
+    if kvh != heads and not grouped:
+        kh = jnp.repeat(kh, heads // kvh, axis=2)
+        vh = jnp.repeat(vh, heads // kvh, axis=2)
+    if p.get("selected"):
+        if not causal or "bias_k" in weights or p.get("add_zero_attn") \
+                or q.shape[1] != k.shape[1]:
+            raise NotImplementedError("a selected key set on attention that "
+                                      "is not causal self-attention")
+        out = _selected_sequence_attention(qh, kh, vh, inputs[-1], scale)
+    # sequence parallelism: the searched strategy may place this attention
+    # on the ring path (sp_ring candidate -> {"seq_parallel": axis} attr)
+    sp_axis = ctx.op_attrs.get(layer.name, {}).get("seq_parallel")
+    if out is None and sp_axis and ctx.mesh is not None \
+            and sp_axis in ctx.mesh.shape \
+            and impl != "xla" and qh.shape[1] == kh.shape[1] == vh.shape[1] \
+            and qh.shape[1] % ctx.mesh.shape[sp_axis] == 0 \
+            and not needs_dropout and "bias_k" not in weights \
+            and not p.get("add_zero_attn", False):
+        if band:
+            raise NotImplementedError(
+                f"{layer.name}: ring attention (kernels/ring_attention.py) "
+                f"knows no window, and this layer's is {window} under a "
+                f"sequence of {k.shape[1]}")
+        from flexflow_tpu.kernels.ring_attention import ring_attention_qkv
+
+        out = ring_attention_qkv(qh, kh, vh, ctx.mesh, sp_axis,
+                                 causal=causal, scale=scale)
+    # a layer that states a window (0: the whole context) says which form
+    # its sequence took and what it saw, under its kind's scope
+    scope = contextlib.nullcontext() if "window" not in p else \
+        jax.named_scope(WINDOW_SCOPE if window else FULL_SCOPE)
+    if out is None and "window" in p:
+        sq, rows = qh.shape[1], qh.shape[0] * heads
+        triangle, outside = sq * (sq + 1) // 2, (sq - band) * (sq - band + 1) // 2
+        ctx.add_stat("window_keys_seen" if window else "full_keys_seen",
+                     jnp.float32(rows * (triangle - (outside if band else 0))))
+        if window:      # the triangle its band lies under
+            ctx.add_stat("window_keys_causal", jnp.float32(rows * triangle))
+        form, tile = "xla/masked", {}
+        if flash:
+            from flexflow_tpu.kernels.flash_attention import tile_plan
+
+            form = "flash/window" if band else "flash/full"
+            tile = tile_plan(sq, sq, qh.shape[3], qh.dtype.itemsize, causal,
+                             band)["fwd"]
+        tel.record(form, tel.now_us(), cat="compile", layer=layer.name,
+                   window=window, seq=sq, **tile)
+    if out is None and flash:
         from flexflow_tpu.kernels.flash_attention import flash_attention_qkv
 
         # seq and depth stay whole per shard, so _flash_covers holds there
-        # (a forced impl="flash" with no placement goes in unsplit)
-        out = per_shard(
-            functools.partial(flash_attention_qkv, causal=causal, scale=scale),
-            ctx.mesh if spec is not None else None,
-            (spec, spec, spec), spec)(qh, kh, vh)
+        # (a forced impl="flash" with no placement goes in unsplit); a call
+        # without a window is the call it was before windows existed
+        with scope:
+            out = per_shard(
+                functools.partial(flash_attention_qkv, causal=causal,
+                                  scale=scale,
+                                  **({"window": band} if band else {})),
+                ctx.mesh if spec is not None else None,
+                (spec, spec, spec), spec)(qh, kh, vh)
     if out is None:
-        logits = jnp.einsum("bqhd,bkhd->bhqk", qh, kh) * scale
-        if causal:
-            sq, sk = logits.shape[-2], logits.shape[-1]
-            # causal band over the ORIGINAL key positions only; positions
-            # appended by add_bias_kv/add_zero_attn (indices >= sk_orig, at the
-            # end) are always attendable and must not shift the band
-            sk_orig = k.shape[1]
-            mask = jnp.tril(jnp.ones((sq, sk_orig), bool), k=sk_orig - sq)
-            if windowed:    # the keys t - window < s <= t
-                mask &= ~jnp.tril(jnp.ones((sq, sk_orig), bool),
-                                  k=sk_orig - sq - window)
-            if sk > sk_orig:
-                mask = jnp.concatenate(
-                    [mask, jnp.ones((sq, sk - sk_orig), bool)], axis=1)
-            logits = jnp.where(mask, logits, jnp.finfo(logits.dtype).min)
-        probs = jax.nn.softmax(logits, axis=-1)
-        if ctx.training and p.get("dropout", 0.0) > 0.0:
-            import jax.random as jrandom
-
-            keep = 1.0 - p["dropout"]
-            mask = jrandom.bernoulli(ctx.rng_for(layer), keep, probs.shape)
-            probs = jnp.where(mask, probs / keep, 0.0).astype(probs.dtype)
-        out = jnp.einsum("bhqk,bkhd->bqhd", probs, vh)
+        with scope:
+            out = _einsum_attention(layer, qh, kh, vh, k.shape[1], scale,
+                                    band, ctx)
     b, sq = q.shape[0], q.shape[1]
-    out = out.reshape(b, sq, embed)
+    out = _gated(out.reshape(b, sq, embed), q, weights)
     y = out @ weights["wo"].astype(dt)
     if "bo" in weights:
         y = y + weights["bo"].astype(dt)
@@ -853,13 +921,25 @@ def _selected_sequence_attention(qh, kh, vh, keep, scale):
 
 
 def _mha_flops(layer: Layer):
+    """Forward: the four projections (and the output gate's, where the layer
+    has one) and the two products over the scores. A layer that states a
+    `window` is priced at the (query, key) pairs it may see, the band or (at
+    0) the triangle, which is what the flash kernels visit; one without, as
+    it always was, at the whole square."""
     q, k = layer.inputs[0].spec, layer.inputs[1].spec
+    p = layer.params
     b, sq, e = q.shape
     sk = k.shape[1]
-    kv_share = _kv_heads(layer.params) / layer.params["num_heads"]
+    kv_share = _kv_heads(p) / p["num_heads"]
     # q,k,v,o projections (approx sq≈sk); grouped K/V heads project less
     proj = 2.0 * b * (2 * sq + 2 * sq * kv_share) * e * e
-    attn = 2.0 * b * sq * sk * e * 2  # qk^T and att@v
+    if p.get("output_gate"):
+        proj += 2.0 * b * sq * e * p["embed_dim"]
+    pairs = sq * sk
+    if "window" in p and sq == sk:
+        out = max(0, sq - p["window"]) if p["window"] else 0
+        pairs = sq * (sq + 1) // 2 - out * (out + 1) // 2
+    attn = 2.0 * b * pairs * e * 2  # qk^T and att@v
     return proj + attn
 
 

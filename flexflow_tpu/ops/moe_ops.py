@@ -172,7 +172,12 @@ def _moe_layer_infer(layer: Layer):
         raise ValueError(f"moe_layer: {groups} groups over "
                          f"{p['num_experts']} experts, topk_group "
                          f"{p.get('topk_group')}")
-    if p.get("score_bias"):
+    if p.get("score_bias") == "state":
+        # no gradient moves it: the layer's own state, which a training
+        # step updates from its counts (`_balance_bias`)
+        layer.state_specs["score_bias"] = TensorSpec((p["num_experts"],),
+                                                     DataType.FLOAT)
+    elif p.get("score_bias"):
         layer.weight_specs["score_bias"] = TensorSpec((p["num_experts"],),
                                                       DataType.FLOAT)
     return [x]
@@ -214,8 +219,10 @@ def _choose(scores, weights, p):
     the ones below set): the top k of the scores, a softmax over those k.
     Where set (DeepSeek-V3's `noaux_tc`):
     - `scoring` "sigmoid": an expert's score is sigmoid(x W_r);
-    - `score_bias`: a weight `[E]` added to the scores for the SELECTION
-      only (the gates use the scores without it);
+    - `score_bias`: `[E]` added to the scores for the SELECTION only (the
+      gates use the scores without it): a weight, or with the layer's
+      `score_bias` "state" its non-trainable state, which a training step
+      moves against the load (`_balance_bias`);
     - `n_group`, `topk_group`: experts lie in `n_group` groups of
       consecutive ids; a group's score is the sum of its two largest
       selection scores, and only the `topk_group` best groups' experts can
@@ -263,6 +270,10 @@ def _row_capacities(pairs: int):
     return ([0] + parts if parts else []) + [pairs]
 
 
+# the named scopes of the router (its product, the choice, the gates) and
+# of a stateful selection bias's update
+ROUTER_SCOPE = "ff_moe_router"
+BIAS_SCOPE = "ff_bias_update"
 # the `jax.named_scope` around the experts' own work (the two grouped
 # products and the activation between them), in every expert layer of a
 # program: one name selects them all (`attribution.instructions_under`),
@@ -409,15 +420,18 @@ def _routing(xt, exists, weights, p):
     """What the router says of a block's (token, choice) pairs: (gates
     `[tokens, k]` f32, which pairs are held here and exist `[tokens, k]`,
     each pair's held expert `[tokens * k]`, counted from this holder's
-    first; an absent pair's is `held`, one past the last)."""
+    first; an absent pair's is `held`, one past the last; and each pair's
+    expert among ALL `[tokens, k]`, `num_experts` for a token that does not
+    exist)."""
     lo, hi = p["experts_held"]
-    scores = jnp.dot(xt.astype(jnp.float32),
-                     weights["router"].astype(jnp.float32),
-                     precision=jax.lax.Precision.HIGHEST)
-    gate, experts = _choose(scores, weights, p)                # [tokens, k]
+    with jax.named_scope(ROUTER_SCOPE):
+        scores = jnp.dot(xt.astype(jnp.float32),
+                         weights["router"].astype(jnp.float32),
+                         precision=jax.lax.Precision.HIGHEST)
+        gate, experts = _choose(scores, weights, p)            # [tokens, k]
     held = (experts >= lo) & (experts < hi) & exists
     local = jnp.where(held, experts - lo, hi - lo).reshape(-1)  # absent: last
-    return gate, held, local
+    return gate, held, local, jnp.where(exists, experts, p["num_experts"])
 
 
 def _rows_tile(rows: int, d: int, itemsize: int, p):
@@ -432,11 +446,13 @@ def _rows_tile(rows: int, d: int, itemsize: int, p):
 
 
 def _route_tokens(xt, exists, weights, p, told_what_exists: bool,
-                  kernel: bool = False):
+                  kernel: bool = False, count_all: bool = False):
     """One block of tokens `[tokens, d]` through the routed layer: (this
     holder's part of the output `[tokens, d]`, rows on each held expert
-    `[held]`, rows the experts' buffer was sized for; and, where a rung's
-    buffer can take the rows kernel, how many of those rows took it).
+    `[held]`, rows the experts' buffer was sized for; where a rung's
+    buffer can take the rows kernel, how many of those rows took it; and
+    last, where `count_all` asks for them, the tokens routed to each of ALL
+    the experts `[num_experts]`).
     `told_what_exists`: the layer has its `valid` input, so `exists` may
     name fewer than all. `kernel`: one device, so a buffer whose shapes
     admit `kernels/moe_rows.py` takes it (`_rows_tile`)."""
@@ -444,7 +460,9 @@ def _route_tokens(xt, exists, weights, p, told_what_exists: bool,
     lo, hi = p["experts_held"]
     held_n = hi - lo
     tokens, d = xt.shape
-    gate, held, local = _routing(xt, exists, weights, p)
+    gate, held, local, experts = _routing(xt, exists, weights, p)
+    routed = jnp.bincount(experts.reshape(-1), length=p["num_experts"] + 1)[
+        :p["num_experts"]].astype(jnp.int32) if count_all else None
     order = jnp.argsort(local, stable=True)
     sizes = jnp.bincount(local, length=held_n + 1)[:held_n].astype(jnp.int32)
     # a block's rows are the pairs that are held here AND exist: the ladder
@@ -478,7 +496,7 @@ def _route_tokens(xt, exists, weights, p, told_what_exists: bool,
     by_kernel = [cap if tile else 0 for cap, tile in zip(caps, tiles)]
     return (y, sizes, computed) \
         + ((jnp.asarray(by_kernel, jnp.int32)[rung],) if any(by_kernel)
-           else ())
+           else ()) + ((routed,) if count_all else ())
 
 
 def _step_forward(xt, gate, local, ids, count, w_in, w_out, relu2, tn):
@@ -552,7 +570,7 @@ def _route_step(xt, exists, weights, p, tn: int):
     from flexflow_tpu.kernels import moe_step
 
     held_n = weights["w_in"].shape[0]
-    gate, _held, local = _routing(xt, exists, weights, p)
+    gate, _held, local, _experts = _routing(xt, exists, weights, p)
     # by compare and sum over the block's few pairs: no scatter
     sizes = jnp.sum(
         local[None] == jnp.arange(held_n, dtype=local.dtype)[:, None],
@@ -579,6 +597,37 @@ def _report_rows_kernel(ctx, rows) -> None:
     multiplied, summed over blocks (equal to `moe_rows_computed` where it
     ran), 0 where the grouped product did."""
     ctx.add_stat("moe_rows_kernel", rows)
+
+
+def _balance_bias(layer: Layer, bias, routed, ctx) -> None:
+    """A stateful selection bias after a block of tokens: reported
+    (`moe_router_load_max` / `_mean`: tokens on the fullest of ALL the
+    experts and on the mean one, by the router's own choice;
+    `moe_bias_abs_max`, with `moe_bias_layers` to divide a sum over layers
+    by), and, training, moved against the load without a gradient
+    (torchtitan's rule): `b <- b + d - mean(d)`, `d = rate * sign(mean(c) -
+    c)`, `c[e]` the tokens the router sent to expert `e` in this call, `rate`
+    the layer's `score_bias_rate`. Under gradient accumulation every
+    microbatch is such a call."""
+    c = routed.astype(jnp.float32)
+    ctx.add_stat("moe_router_load_max", jnp.max(c))
+    ctx.add_stat("moe_router_load_mean", jnp.mean(c))
+    ctx.add_stat("moe_bias_abs_max", jnp.max(jnp.abs(bias)))
+    ctx.add_stat("moe_bias_layers", jnp.float32(1))
+    if not ctx.training:
+        return
+    with jax.named_scope(BIAS_SCOPE):
+        d = float(layer.params.get("score_bias_rate", 0.0)) \
+            * jnp.sign(jnp.mean(c) - c)
+        ctx.new_state[f"{layer.name}/score_bias"] = bias + d - jnp.mean(d)
+
+
+def _moe_serving_params(params: dict, kind: str) -> dict:
+    """Served, nothing moves a selection bias: the layer's state is a
+    weight of its prefill and decode twins."""
+    if params.get("score_bias") == "state":
+        return dict(params, score_bias=True)
+    return params
 
 
 def _moe_layer_lower(layer: Layer, inputs, weights, ctx):
@@ -655,22 +704,41 @@ def _moe_layer_lower(layer: Layer, inputs, weights, ctx):
     # on a mesh of several devices the grouped product stays: GSPMD
     # partitions it, and cannot partition a Mosaic call
     one_device = not multi_device(ctx.mesh)
-    tn = _step_tile(tokens, d, x.dtype.itemsize, p) if one_device else None
+    # a selection bias that is the layer's state: read from it (no
+    # gradient), and a training step moves it by the step's own counts
+    stateful = p.get("score_bias") == "state"
+    if stateful:
+        bias = jax.lax.stop_gradient(ctx.state[f"{layer.name}/score_bias"])
+        weights = dict(weights, score_bias=bias)
+    tn = _step_tile(tokens, d, x.dtype.itemsize, p) \
+        if one_device and not stateful else None
     kernel_experts, kernel_rows = jnp.int32(0), ()
     if tn is not None:
         y, sizes, kernel_experts = _route_step(xt, exists, weights, p, tn)
         computed = jnp.int32(tokens * p["top_k"])
     elif tokens > MOE_TOKEN_BLOCK and tokens % MOE_TOKEN_BLOCK == 0:
         blocks = tokens // MOE_TOKEN_BLOCK
+
+        def one_block(block):
+            return _route_tokens(block[0], block[1], weights, p, told,
+                                 one_device, stateful)
+
+        # training: a block keeps its tokens and is recomputed in the
+        # backward pass; what a block's products save (a kernel's operands,
+        # the weights among them; its row buffers at the largest rung) would
+        # else be kept once a block
         y, sizes, computed, *kernel_rows = jax.lax.map(
-            lambda block: _route_tokens(block[0], block[1], weights, p, told,
-                                        one_device),
+            jax.checkpoint(one_block) if ctx.training else one_block,
             (xt.reshape(blocks, MOE_TOKEN_BLOCK, d),
              exists.reshape(blocks, MOE_TOKEN_BLOCK, 1)))
         sizes, computed = jnp.sum(sizes, axis=0), jnp.sum(computed)
+        if stateful:
+            kernel_rows[-1] = jnp.sum(kernel_rows[-1], axis=0)
     else:
         y, sizes, computed, *kernel_rows = _route_tokens(
-            xt, exists, weights, p, told, one_device)
+            xt, exists, weights, p, told, one_device, stateful)
+    if stateful:        # the counts over all the experts came last
+        _balance_bias(layer, bias, kernel_rows.pop(), ctx)
     n_held = jnp.sum(sizes)
     ctx.add_stat("moe_routed_pairs",
                  jnp.sum(exists).astype(jnp.int32) * p["top_k"])
@@ -706,4 +774,5 @@ def _moe_layer_flops(layer: Layer):
 
 
 register_op(OperatorType.MOE_LAYER, _moe_layer_infer, _moe_layer_lower,
-            _moe_layer_flops, uncast_weights=("score_bias",))
+            _moe_layer_flops, serving_params=_moe_serving_params,
+            uncast_weights=("score_bias",))

@@ -249,6 +249,37 @@ def test_flash_attention_causal_tiles(one_chip, mosaic, bq, bk):
              one_chip, qkv, qkv, qkv, qkv, vec, vec)
 
 
+@pytest.mark.parametrize("window", [2048, 0])
+def test_flash_attention_under_a_window_at_8k(one_chip, mosaic, window):
+    """Trinity-Mini's attention at the trained shape: two sequences of 8192,
+    32 query heads over 4 K/V heads of 128 (read through the block index),
+    the three kernels under a window of 2048 and without one. The backward's
+    whole-sequence operands pass Mosaic's default VMEM scope, so the calls
+    ask for their own."""
+    import importlib
+
+    fa = importlib.import_module("flexflow_tpu.kernels.flash_attention")
+    b, h, kv, s, d = 2, 32, 4, 8192, 128
+
+    def grads(q, k, v, ct):
+        return jax.grad(lambda q, k, v: jnp.sum(fa.flash_attention(
+            q, k, v, causal=True, window=window).astype(jnp.float32) * ct),
+            (0, 1, 2))(q, k, v)
+
+    compiled = _compile(grads, one_chip, ((b, h, s, d), jnp.bfloat16),
+                        ((b, kv, s, d), jnp.bfloat16),
+                        ((b, kv, s, d), jnp.bfloat16),
+                        ((b, h, s, d), jnp.float32))
+    text = compiled.as_text()
+    for kernel in ("fwd", "dq", "dkv"):
+        assert f"ff_flash_attention_{kernel}" in text
+    assert not re.search(r"\[\d+,\d+,8192,8192\]", text)
+    plan = fa.tile_plan(s, s, d, 2, True, window)
+    if window:      # 16 grid steps of 512, at most 5 key blocks a step: 70 of 136
+        assert plan["fwd"]["flash_tiles_visited"] \
+            < 0.55 * fa.tile_plan(s, s, d, 2, True)["fwd"]["flash_tiles_visited"]
+
+
 @pytest.mark.parametrize("q_tokens", [1, 5])
 def test_dequant_decode_attention(one_chip, mosaic, q_tokens):
     """The serving engine's int8 geometry: [slots, L, 16, 64] gathered
@@ -1205,7 +1236,8 @@ def test_a_decode_steps_experts_are_the_step_kernel(cell, described_devices,
     for _op, what in re.findall(
             r' (sort|gather|scatter)\([^\n]*op_name="[^"\n]*/(?:%s)/([^"\n]*)"'
             % layer_scopes, text):
-        assert what in ("top_k", "jit(take_along_axis)/gather"), what
+        assert what in ("ff_moe_router/top_k",
+                        "ff_moe_router/jit(take_along_axis)/gather"), what
     wave = eng._prefill_first_tokens_jit.lower(
         params, [_i32(one_chip, slots, g.seq)] * inputs,
         _i32(one_chip, slots)).as_text()
@@ -1242,3 +1274,48 @@ def test_gpt2_medium_decode_and_commit_append_in_place(described_devices,
     commit = kv_cache._commit_prefill.lower(
         state, fresh, _i32(one_chip, slots), _i32(one_chip, slots)).compile()
     _assert_appends_in_place(commit, eng)
+
+
+def test_trinity_train_step_fits_one_chip(described_devices, mosaic):
+    """The cell Trinity-Mini.train-8k's training step through the normal
+    entry points, compiled for one described chip from shapes: arguments +
+    temporaries under 15 GB (ISSUE 58's rung), every attention layer through
+    the flash kernels (no [.., 8192, 8192] scores anywhere in the program),
+    the held experts' forward through kernels/moe_rows.py."""
+    sys.path.insert(0, os.path.join(ROOT, "benchmarks"))
+    from families import family_of
+    from harness import manifest as mf
+
+    from flexflow_tpu import AdamOptimizer, FFConfig, FFModel
+    from flexflow_tpu.compiler.compile import build_state_init_fn
+    from flexflow_tpu.core.graph import topo_order
+
+    described_devices(1)
+    cell = mf.load_cell(mf.load_manifest(), "Trinity-Mini.train-8k")
+    batch = cell.traffic["global_batch"]
+    model = FFModel(FFConfig(batch_size=batch, seed=1, strategy_cache=False,
+                             log_level="warning", **cell.system["ffconfig"]))
+    gcfg = family_of(cell.config).build(model, cell.config, batch)
+    cm = model.compile(AdamOptimizer(alpha=cell.system["adam_lr"]),
+                       loss_type="sparse_categorical_crossentropy", metrics=[])
+    params, opt, _, ins, label, key = _train_step_shapes(cm, (batch, gcfg.seq))
+    state = jax.eval_shape(
+        build_state_init_fn(topo_order(model.layers),
+                            model._initializer_overrides),
+        jax.ShapeDtypeStruct((2,), jnp.uint32))
+    state = {k: jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=key.sharding)
+             for k, v in state.items()}
+    assert len(state) == 4 and "l1_moe/score_bias" in state
+    assert all("score_bias" not in leaves for leaves in params.values())
+    compiled = cm.train_step.lower(params, opt, state, ins, label,
+                                   key).compile()
+    m = compiled.memory_analysis()
+    held = sum(int(np.prod(s.shape)) for s in jax.tree_util.tree_leaves(params))
+    assert held + 4 * 128 == 705_474_304
+    assert m.argument_size_in_bytes >= 12 * held
+    assert m.argument_size_in_bytes + m.temp_size_in_bytes < 15e9, m
+    text = compiled.as_text()
+    assert not re.search(r"\[(\d+,)*8192,8192\]", text)
+    for kernel in ("ff_flash_attention_fwd", "ff_flash_attention_dq",
+                   "ff_flash_attention_dkv", "ff_moe_rows"):
+        assert kernel in text, kernel

@@ -404,3 +404,107 @@ def test_vmem_reject_falls_back_to_reference_path():
     cm.init(seed=0)
     out = cm.forward(np.zeros((1, 8192, 128), np.float32))
     assert np.asarray(out).shape == (1, 8192, 128)
+
+
+def _banded_reference(q, k, v, window):
+    """Causal attention under `window` (0: none) as the masked XLA form
+    computes it: q [b, h, s, d], k/v [b, kv heads, s, d]."""
+    s, group = q.shape[2], q.shape[1] // k.shape[1]
+    k, v = (jnp.repeat(t, group, axis=1) for t in (k, v))
+    logits = jnp.einsum("bhqd,bhkd->bhqk", q, k) / np.sqrt(q.shape[-1])
+    at = jnp.arange(s)
+    seen = at[None, :] <= at[:, None]
+    if window:
+        seen &= at[None, :] > at[:, None] - window
+    probs = jax.nn.softmax(jnp.where(seen, logits, -jnp.inf), axis=-1)
+    return jnp.einsum("bhqk,bhkd->bhqd", probs, v)
+
+
+@pytest.mark.parametrize("seq,window,heads,kv_heads", [
+    (512, 100, 2, 1),       # under a block: the band crosses the own block
+    (1024, 300, 2, 2),      # no multiple of the tile; whole, crossed, skipped
+    (1024, 511, 4, 2),      # one short of two blocks
+    (512, 512, 2, 2),       # window == seq: the plain causal call
+    (512, 1000, 2, 1),      # window > seq
+])
+def test_a_window_matches_the_masked_form(seq, window, heads, kv_heads):
+    """Forward, dq and dk/dv of the three kernels (interpret mode) under a
+    window against the masked XLA form, at grouped K/V heads too; blocks of
+    256 at head_dim 128 in float32, so 1024 positions are four grid steps."""
+    import importlib
+
+    fa = importlib.import_module("flexflow_tpu.kernels.flash_attention")
+    keys = jax.random.split(jax.random.PRNGKey(seq + window), 4)
+    q = jax.random.normal(keys[0], (1, heads, seq, 128))
+    k = jax.random.normal(keys[1], (1, kv_heads, seq, 128))
+    v = jax.random.normal(keys[2], (1, kv_heads, seq, 128))
+    ct = jax.random.normal(keys[3], q.shape)
+    band = window if window < seq else 0
+
+    def flash(q, k, v):
+        return jnp.sum(fa.flash_attention(q, k, v, causal=True,
+                                          window=window) * ct)
+
+    def masked(q, k, v):
+        return jnp.sum(_banded_reference(q, k, v, band) * ct)
+
+    np.testing.assert_allclose(
+        fa.flash_attention(q, k, v, causal=True, window=window),
+        _banded_reference(q, k, v, band), atol=2e-5)
+    for got, want in zip(jax.grad(flash, (0, 1, 2))(q, k, v),
+                         jax.grad(masked, (0, 1, 2))(q, k, v)):
+        np.testing.assert_allclose(got, want, atol=2e-4)
+    plan = fa.tile_plan(seq, seq, 128, 4, True, band)
+    plain = fa.tile_plan(seq, seq, 128, 4, True)
+    for kernel in fa.KERNELS:
+        if band and seq > 512:      # key blocks wholly before the band
+            assert plan[kernel]["flash_tiles_visited"] \
+                < plain[kernel]["flash_tiles_visited"]
+        if not band:
+            assert plan[kernel] == plain[kernel]
+
+
+def test_what_a_window_cannot_be_raises():
+    import importlib
+
+    fa = importlib.import_module("flexflow_tpu.kernels.flash_attention")
+    q = jnp.zeros((1, 3, 256, 64))
+    with pytest.raises(ValueError, match="not causal"):
+        fa.flash_attention(q, q, q, causal=False, window=8)
+    with pytest.raises(ValueError, match="K/V heads"):
+        fa.flash_attention(q, q[:, :2], q[:, :2], causal=True)
+
+
+# sha256 of the jaxpr of value-and-gradient of a call WITHOUT a window at
+# GPT-2 medium's shape ([8, 16, 1024, 64] bf16, Mosaic path), source
+# locations taken out, as the tree before windows existed traced it (commit
+# 2252dec; /root/scratch/jaxpr_digest.py of PR 58 computed both sides)
+WINDOWLESS_JAXPR = {
+    True: "96f171176bae6d7a433c8fbfb54cbe8109fd4434ad42a51f531b6a56bdce6589",
+    False: "d9044b53714903a17940f3edf405afd31145a3e17364d164ee2de9748c204b74",
+}
+
+
+@pytest.mark.parametrize("causal", [True, False])
+def test_a_call_without_a_window_traces_to_the_program_it_was(causal,
+                                                              monkeypatch):
+    """gpt2-medium.train-b8 reads these kernels under a bound of 1 %: the
+    window, the grouped K/V heads and the VMEM scope enter a call's program
+    only where the call states them. A JAX upgrade that prints a jaxpr
+    otherwise moves both digests: re-pin them from the parent commit."""
+    import hashlib
+    import importlib
+    import re
+
+    fa = importlib.import_module("flexflow_tpu.kernels.flash_attention")
+    monkeypatch.setattr(fa, "_interpret", lambda: False)
+    q = jax.ShapeDtypeStruct((8, 16, 1024, 64), jnp.bfloat16)
+
+    def total(q, k, v):
+        return jnp.sum(fa.flash_attention(q, k, v, causal=causal)
+                       .astype(jnp.float32))
+
+    text = str(jax.make_jaxpr(jax.grad(total, (0, 1, 2)))(q, q, q))
+    text = re.sub(r" at [^\s\]]+:\d+", "", text)
+    text = re.sub(r"/[^\s\"']*flash_attention\.py", "flash_attention.py", text)
+    assert hashlib.sha256(text.encode()).hexdigest() == WINDOWLESS_JAXPR[causal]
