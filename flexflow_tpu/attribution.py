@@ -397,6 +397,30 @@ def op_scope_map(hlo_text: str, layers) -> Dict[str, OpScope]:
     return out
 
 
+def routing_passes(hlo_text: str, op_types: Dict[str, str]
+                   ) -> Optional[float]:
+    """How often a compiled training step runs an expert layer's routing
+    chain for once that its forward pass does: the `top_k` and the sorts by
+    expert (instructions `sort`, or the CPU's `TopK` call, whose own
+    `op_name` ends in `top_k` or `sort`: a scatter's sort of its indices
+    ends in `scatter-add`) under the `moe_layer` layers, in all phases over
+    the forward phase's. 1 where every checkpoint around the layer keeps
+    the decision (`moe_ops.ROUTING_KEPT`), 3 where the layer's block and
+    the `remat_blocks` unit around it each decide again. None for a program
+    with no such instruction in a forward phase."""
+    by_phase: Dict[str, int] = {}
+    for items in _parse_computations(hlo_text)[0].values():
+        for i in items:
+            if i.opcode not in ("sort", "custom-call") or not i.op_name \
+                    or _segments(i.op_name)[-1] not in ("top_k", "sort"):
+                continue
+            layer, phase = scope_of_op_name(i.op_name, op_types)
+            if op_types.get(layer) == "moe_layer":
+                by_phase[phase] = by_phase.get(phase, 0) + 1
+    forward = by_phase.get("forward", 0)
+    return sum(by_phase.values()) / forward if forward else None
+
+
 # ------------------------------------------------------------- the registry
 class Program:
     """One jitted program that puts work on the device: a weak reference to
@@ -441,6 +465,11 @@ class Program:
                    layers=len(self.op_types),
                    layers_named=len({s.layer for s in self.scopes.values()
                                      if s.layer}))
+            # a training step with expert layers: a property of the program
+            if any(s.phase == "backward" for s in self.scopes.values()):
+                passes = routing_passes(text, self.op_types)
+                if passes is not None:
+                    sp.set(moe_routing_passes=passes)
         return self.scopes
 
 

@@ -187,13 +187,21 @@ def build_forward(
         """A unit of "block" layers as ONE checkpointed function of the
         tensors it reads from outside, its layers' weights as they lie (the
         compute-dtype cast is inside: recomputed, not kept), the state and
-        the rng; its layers keep their own name scopes."""
+        the rng; its layers keep their own name scopes. Kept besides: what
+        the unit's ops tag under their `kept_names` (an expert layer's
+        routing decision: two megabytes a layer, where the recomputation
+        would run the `top_k`, the gather, the sort and the counts again,
+        and a third time under the layer's own checkpoint of a token
+        block). A unit whose ops name nothing is checkpointed with no
+        policy, as it was."""
         made = {t.guid for layer in unit for t in layer.outputs}
         reads = list(dict.fromkeys(t.guid for layer in unit
                                    for t in layer.inputs
                                    if t.guid not in made))
         hands_on = handed_on[unit_of[unit[0].name]]
         training = ctx.training
+        kept = sorted({name for layer in unit
+                       for name in get_op_def(layer.op_type).kept_names})
 
         def _unit(u_ins, u_w, u_state, u_rng):
             sub = sub_ctx(u_state, u_rng, training)
@@ -210,7 +218,9 @@ def build_forward(
             return [local[g] for g in hands_on], sub.new_state, \
                 sub.stats or {}
 
-        outs, delta, counted = jax.checkpoint(_unit)(
+        policy = jax.checkpoint_policies.save_only_these_names(*kept) \
+            if kept else None
+        outs, delta, counted = jax.checkpoint(_unit, policy=policy)(
             [env[g] for g in reads],
             {layer.name: params[layer.name] for layer in unit
              if layer.name in params}, dict(ctx.state), ctx.rng)

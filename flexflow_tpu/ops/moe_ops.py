@@ -24,6 +24,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from typing import TYPE_CHECKING
 if TYPE_CHECKING:
@@ -213,9 +214,12 @@ MOE_ROW_RUNGS = (16, 4)
 MOE_MIN_RUNG_ROWS = 128
 
 
-def _choose(scores, weights, p):
+def _choose(scores, weights, p, training: bool = False):
     """(gates `[tokens, k]` f32, experts `[tokens, k]`) from the router's
-    scores of ALL experts `[tokens, E]` f32. As granite routes (no key of
+    scores of ALL experts `[tokens, E]` f32; `training`: the chosen experts
+    and their scores are kept as they leave the `top_k` or the gather
+    (`_kept`: a recomputation needs the values, and the gradient goes
+    through the name). As granite routes (no key of
     the ones below set): the top k of the scores, a softmax over those k.
     Where set (DeepSeek-V3's `noaux_tc`):
     - `scoring` "sigmoid": an expert's score is sigmoid(x W_r);
@@ -235,6 +239,7 @@ def _choose(scores, weights, p):
     groups = p.get("n_group", 0)
     if not (sigmoid or groups or "score_bias" in weights):
         top, experts = jax.lax.top_k(scores, k)
+        top, experts = _kept(top, training), _kept(experts, training)
         gate = jax.nn.softmax(top, axis=-1)
     else:
         own = jax.nn.sigmoid(scores) if sigmoid else scores
@@ -249,8 +254,8 @@ def _choose(scores, weights, p):
                             axis=1)                            # [tokens, groups]
             choice = jnp.where(jnp.repeat(open_, n // groups, axis=1), choice,
                                -jnp.inf)
-        _, experts = jax.lax.top_k(choice, k)
-        picked = jnp.take_along_axis(own, experts, axis=-1)
+        experts = _kept(jax.lax.top_k(choice, k)[1], training)
+        picked = _kept(jnp.take_along_axis(own, experts, axis=-1), training)
         gate = picked if sigmoid else jax.nn.softmax(picked, axis=-1)
     if p.get("norm_topk_prob"):
         gate = gate / (jnp.sum(gate, axis=-1, keepdims=True)
@@ -279,6 +284,26 @@ BIAS_SCOPE = "ff_bias_update"
 # program: one name selects them all (`attribution.instructions_under`),
 # where a layer's own scope is its name
 EXPERTS_SCOPE = "ff_moe_experts"
+# the `checkpoint_name` of what a TRAINING step's routing decides (each
+# pair's expert and its score as gathered, the pairs' order by expert, the
+# rows a held expert gets, the router's load): half a megabyte a block, and
+# dear to make again (a `top_k`, a gather of 8 from 128 a token, a stable
+# sort of every pair, a scatter a count: 0.26-0.8 ms each on the chip).
+# Every `jax.checkpoint` around an expert layer keeps them
+# (`save_only_these_names`): the block's own below, and a `remat_blocks`
+# unit's through the op's `kept_names` (`compiler/lowering.run_block`)
+ROUTING_KEPT = "ff_moe_routing"
+
+
+def _kept(x, training: bool):
+    """`x`, a part of the routing decision: named `ROUTING_KEPT` in a
+    training step, so that a checkpoint's recomputation reads what the
+    forward pass decided; as it is elsewhere (a serving program has no
+    `name` equation). Kept FLAT: the chip pads a `[tokens, 8]` tensor's
+    rows to 128 lanes, sixteen times its 128 KB a block."""
+    if not training:
+        return x
+    return checkpoint_name(x.reshape(-1), ROUTING_KEPT).reshape(x.shape)
 
 
 def _params_of(w_out, relu2: bool):
@@ -352,16 +377,18 @@ def _experts(rows, sizes, weights, p, tile=None):
         return jax.lax.ragged_dot(mid, weights["w_out"].astype(dt), sizes)
 
 
-def _all_rows(xt, gate, held, order, sizes, weights, p, tile=None):
+def _all_rows(xt, gate, held, order, sizes, weights, p, tile=None,
+              training: bool = False):
     """Every (token, choice) pair of the block has a row: the whole
-    `tokens * k` buffer, whatever is held. `tile`: as `_experts` takes."""
+    `tokens * k` buffer, whatever is held. `tile`: as `_experts` takes.
+    `training`: the order's inverse is a sort too, and kept (`_kept`)."""
     tokens, k = gate.shape
     out = _experts(xt[order // k], sizes, weights, p, tile)    # [tokens*k, d]
     # back to (token, choice) order, one choice at a time (a [tokens, k, d]
     # buffer in f32 would be the largest of the program); rows past the
     # last group hold nothing that was computed, so they are selected
     # away, not multiplied by 0
-    where = jnp.argsort(order).reshape(tokens, k)
+    where = _kept(jnp.argsort(order), training).reshape(tokens, k)
     y = jnp.zeros(xt.shape, jnp.float32)
     for j in range(k):
         y = y + jnp.where(held[:, j:j + 1],
@@ -416,19 +443,22 @@ def _in_experts_width(rows_fn, p):
     return _through_latent(rows_fn) if "latent_size" in p else rows_fn
 
 
-def _routing(xt, exists, weights, p):
+def _routing(xt, exists, weights, p, training: bool = False):
     """What the router says of a block's (token, choice) pairs: (gates
     `[tokens, k]` f32, which pairs are held here and exist `[tokens, k]`,
     each pair's held expert `[tokens * k]`, counted from this holder's
     first; an absent pair's is `held`, one past the last; and each pair's
     expert among ALL `[tokens, k]`, `num_experts` for a token that does not
-    exist)."""
+    exist). `training`: the chosen experts and their scores are kept
+    (`_choose`): a recomputation reruns the product, the sigmoid and the
+    gates' arithmetic, which carry the gradient, and neither the `top_k`
+    nor the gather, whose vjps read the indices alone."""
     lo, hi = p["experts_held"]
     with jax.named_scope(ROUTER_SCOPE):
         scores = jnp.dot(xt.astype(jnp.float32),
                          weights["router"].astype(jnp.float32),
                          precision=jax.lax.Precision.HIGHEST)
-        gate, experts = _choose(scores, weights, p)            # [tokens, k]
+        gate, experts = _choose(scores, weights, p, training)  # [tokens, k]
     held = (experts >= lo) & (experts < hi) & exists
     local = jnp.where(held, experts - lo, hi - lo).reshape(-1)  # absent: last
     return gate, held, local, jnp.where(exists, experts, p["num_experts"])
@@ -446,7 +476,8 @@ def _rows_tile(rows: int, d: int, itemsize: int, p):
 
 
 def _route_tokens(xt, exists, weights, p, told_what_exists: bool,
-                  kernel: bool = False, count_all: bool = False):
+                  kernel: bool = False, count_all: bool = False,
+                  training: bool = False):
     """One block of tokens `[tokens, d]` through the routed layer: (this
     holder's part of the output `[tokens, d]`, rows on each held expert
     `[held]`, rows the experts' buffer was sized for; where a rung's
@@ -455,16 +486,21 @@ def _route_tokens(xt, exists, weights, p, told_what_exists: bool,
     the experts `[num_experts]`).
     `told_what_exists`: the layer has its `valid` input, so `exists` may
     name fewer than all. `kernel`: one device, so a buffer whose shapes
-    admit `kernels/moe_rows.py` takes it (`_rows_tile`)."""
+    admit `kernels/moe_rows.py` takes it (`_rows_tile`). `training`: what
+    the routing decides (the chosen experts and their scores, the order,
+    both counts) is kept under `ROUTING_KEPT`; `held`, `local`, the rung
+    and a row's token are elementwise of those and made again."""
     k = p["top_k"]
     lo, hi = p["experts_held"]
     held_n = hi - lo
     tokens, d = xt.shape
-    gate, held, local, experts = _routing(xt, exists, weights, p)
-    routed = jnp.bincount(experts.reshape(-1), length=p["num_experts"] + 1)[
-        :p["num_experts"]].astype(jnp.int32) if count_all else None
-    order = jnp.argsort(local, stable=True)
-    sizes = jnp.bincount(local, length=held_n + 1)[:held_n].astype(jnp.int32)
+    gate, held, local, experts = _routing(xt, exists, weights, p, training)
+    routed = _kept(jnp.bincount(
+        experts.reshape(-1), length=p["num_experts"] + 1)[
+        :p["num_experts"]].astype(jnp.int32), training) if count_all else None
+    order = _kept(jnp.argsort(local, stable=True), training)
+    sizes = _kept(jnp.bincount(local, length=held_n + 1)[:held_n].astype(
+        jnp.int32), training)
     # a block's rows are the pairs that are held here AND exist: the ladder
     # is worth its conditional where either can leave some out. A holder of
     # every expert that is told of no absent token has a row for every
@@ -477,7 +513,8 @@ def _route_tokens(xt, exists, weights, p, told_what_exists: bool,
     def rows_fn(fn, tile):
         return _in_experts_width(functools.partial(fn, p=p, tile=tile), p)
 
-    whole = rows_fn(_all_rows, tiles[-1])
+    whole = rows_fn(functools.partial(_all_rows, training=training),
+                    tiles[-1])
     if len(caps) == 1:
         rung = 0
         y = whole(xt, gate, held, order, sizes, weights)
@@ -682,6 +719,20 @@ def _moe_layer_lower(layer: Layer, inputs, weights, ctx):
     are no whole slabs; fewer rows than a tile) through two
     `jax.lax.ragged_dot` with the activation between them.
 
+    TRAINING (`ctx.training`): a token block is a `jax.checkpoint` that
+    keeps its tokens and its ROUTING DECISION and recomputes the rest in the
+    backward pass. The decision: the chosen experts, their scores as
+    gathered, the pairs' order by expert (and its inverse where the whole
+    block is combined), the rows on each held expert, the router's load:
+    0.5 MB a block of 4096 tokens, tagged `ROUTING_KEPT` (`_kept`), where a
+    recomputation would make the same values again with a `top_k`, a
+    gather, a sort of every pair and a scatter a count. A `remat_blocks`
+    unit around the layer keeps them too (the op's `kept_names`), so that
+    chain runs once a step, not three times (PERF.md, PR 59). What carries
+    the gradient and is cheap is made again: the router's product, the
+    sigmoid, the gates' normalisation. Outside training nothing is tagged
+    and nothing checkpointed.
+
     Reports (ctx.add_stat): moe_routed_pairs, moe_held_pairs, moe_load_max
     (rows on the fullest held expert), moe_load_mean (held pairs over
     experts held), moe_experts_hit (held experts with a row),
@@ -721,14 +772,18 @@ def _moe_layer_lower(layer: Layer, inputs, weights, ctx):
 
         def one_block(block):
             return _route_tokens(block[0], block[1], weights, p, told,
-                                 one_device, stateful)
+                                 one_device, stateful, ctx.training)
 
-        # training: a block keeps its tokens and is recomputed in the
-        # backward pass; what a block's products save (a kernel's operands,
-        # the weights among them; its row buffers at the largest rung) would
-        # else be kept once a block
+        # training: a block keeps its tokens and its routing decision
+        # (`ROUTING_KEPT`: half a megabyte) and the rest is recomputed in
+        # the backward pass; what a block's products save
+        # (a kernel's operands, the weights among them; its row buffers at
+        # the largest rung) would else be kept once a block
         y, sizes, computed, *kernel_rows = jax.lax.map(
-            jax.checkpoint(one_block) if ctx.training else one_block,
+            jax.checkpoint(
+                one_block,
+                policy=jax.checkpoint_policies.save_only_these_names(
+                    ROUTING_KEPT)) if ctx.training else one_block,
             (xt.reshape(blocks, MOE_TOKEN_BLOCK, d),
              exists.reshape(blocks, MOE_TOKEN_BLOCK, 1)))
         sizes, computed = jnp.sum(sizes, axis=0), jnp.sum(computed)
@@ -736,7 +791,7 @@ def _moe_layer_lower(layer: Layer, inputs, weights, ctx):
             kernel_rows[-1] = jnp.sum(kernel_rows[-1], axis=0)
     else:
         y, sizes, computed, *kernel_rows = _route_tokens(
-            xt, exists, weights, p, told, one_device, stateful)
+            xt, exists, weights, p, told, one_device, stateful, ctx.training)
     if stateful:        # the counts over all the experts came last
         _balance_bias(layer, bias, kernel_rows.pop(), ctx)
     n_held = jnp.sum(sizes)
@@ -775,4 +830,4 @@ def _moe_layer_flops(layer: Layer):
 
 register_op(OperatorType.MOE_LAYER, _moe_layer_infer, _moe_layer_lower,
             _moe_layer_flops, serving_params=_moe_serving_params,
-            uncast_weights=("score_bias",))
+            uncast_weights=("score_bias",), kept_names=(ROUTING_KEPT,))

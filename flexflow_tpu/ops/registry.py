@@ -124,6 +124,12 @@ class OpDef:
     # weights that keep their own type under a compute_dtype (a selection
     # bias whose size is that of the gaps it decides)
     uncast_weights: tuple = ()
+    # the `jax.ad_checkpoint.checkpoint_name`s under which a training
+    # lowering of the op tags what is cheap to keep and dear to make again
+    # (an expert layer's routing decision): a checkpoint around the op
+    # keeps them (`compiler/lowering.run_block`:
+    # `save_only_these_names`), and recomputes the rest
+    kept_names: tuple = ()
 
     def flop_count(self, layer: Layer) -> float:
         if self.flops is not None:

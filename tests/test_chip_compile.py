@@ -1281,7 +1281,11 @@ def test_trinity_train_step_fits_one_chip(described_devices, mosaic):
     entry points, compiled for one described chip from shapes: arguments +
     temporaries under 15 GB (ISSUE 58's rung), every attention layer through
     the flash kernels (no [.., 8192, 8192] scores anywhere in the program),
-    the held experts' forward through kernels/moe_rows.py."""
+    the held experts' forward through kernels/moe_rows.py, and the routing
+    decision of an expert layer made ONCE a block (PR 59: the `top_k` and
+    the sorts under the `moe_layer` scopes in the chip's compiled text, all
+    phases over the forward's; 3 before: the `remat_blocks` unit's
+    recomputation and the block's each decided again)."""
     sys.path.insert(0, os.path.join(ROOT, "benchmarks"))
     from families import family_of
     from harness import manifest as mf
@@ -1319,3 +1323,7 @@ def test_trinity_train_step_fits_one_chip(described_devices, mosaic):
     for kernel in ("ff_flash_attention_fwd", "ff_flash_attention_dq",
                    "ff_flash_attention_dkv", "ff_moe_rows"):
         assert kernel in text, kernel
+    from flexflow_tpu import attribution
+
+    assert attribution.routing_passes(
+        text, {l.name: l.op_type.value for l in model.layers}) == 1.0
