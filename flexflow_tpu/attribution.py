@@ -32,8 +32,7 @@ This is FlexFlow's calibrated per-op prediction-vs-measurement discipline
 time, and every row is featurized exactly the way "A Learned Performance
 Model for TPUs" (arXiv 2008.01040) featurizes ops — (op kind, shapes,
 dtype, layout, sharding, machine) — so a profiled fit with telemetry on
-emits `op/attr` events that tools/span_dataset.py compiles into the
-learned cost model's training corpus (ROADMAP item 2).
+emits `op/attr` events (tools/trace_report.py's [ops] section).
 
 Entry points: `CompiledModel.op_attribution()` / `PipelinedModel.
 op_attribution()` (both also feed `profile_report`), `--profile-ops`
@@ -56,8 +55,7 @@ from flexflow_tpu.search import cost_model as cmod
 from flexflow_tpu.search import memo
 
 # telemetry event names (cat "op"): one op/attr per attributed row, one
-# op/drift_topk per report — both consumed by tools/span_dataset.py and
-# surfaced by tools/trace_report.py
+# op/drift_topk per report — both surfaced by tools/trace_report.py
 OP_EVENT = "op/attr"
 DRIFT_EVENT = "op/drift_topk"
 
@@ -68,12 +66,12 @@ SUM_TOLERANCE = 0.15
 
 # ------------------------------------------------------------ featurization
 def op_features(layer, cand, machine) -> Dict[str, Any]:
-    """The learned-cost-model featurization of one placed op (2008.01040:
+    """The featurization of one placed op (2008.01040:
     opcode + shapes + dtype + layout/fusion context, here + sharding +
     machine fingerprint). Everything JSON-serializable; `feature_key`
     hashes the identity-relevant subset (the layer NAME is instance
     identity, not a feature — two gpt2 blocks' identical matmuls must
-    dedup to one corpus row)."""
+    share one key)."""
     out0 = layer.outputs[0].spec if layer.outputs else None
     return {
         "op": layer.op_type.value,
@@ -102,7 +100,7 @@ def _ax_str(d) -> str:
 def feature_key(features: Dict[str, Any]) -> str:
     """Stable dedup key of a feature row: sha1 over the canonical JSON of
     the identity fields. Process-stable (sorted keys, no floats), so
-    corpus rows from different runs/machines merge correctly."""
+    rows from different runs/machines compare equal."""
     ident = {k: features.get(k) for k in
              ("op", "in_shapes", "out_shapes", "weight_shapes", "dtype",
               "params", "layout", "sharding", "machine")}
@@ -719,13 +717,13 @@ def build_report(items: List[Dict[str, Any]],
     instructions with the profile's events; without them there is no
     trace path).
     emit: write op/attr + op/drift_topk telemetry events (default: when
-    the telemetry sink is enabled) — this is what grows the span corpus.
+    the telemetry sink is enabled).
     inference: forward-pass-only regime (serving prefill/decode — ISSUE
     14 satellite): measures each op's jitted FORWARD at shard-local
-    shapes and prices the roofline's forward leg, so the corpus learns
-    the bandwidth-bound decode regime training rows never show it.
+    shapes and prices the roofline's forward leg: the bandwidth-bound
+    decode regime training rows never show.
     tag: emitted as the op/attr events' "source" (e.g. "serve_decode"),
-    so corpus rows record which execution regime measured them.
+    so rows record which execution regime measured them.
     """
     from flexflow_tpu.search.measure import MeasuredCost
 

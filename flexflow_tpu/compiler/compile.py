@@ -954,7 +954,7 @@ class CompiledModel:
                 # exception). --profile-ops: attribute the fit's REAL
                 # measured step time to individual ops
                 # (flexflow_tpu/attribution.py) — only when someone consumes
-                # the result (printed table or the telemetry corpus), and
+                # the result (printed table or the telemetry sink), and
                 # not when profile_report below runs the same join anyway
                 will_report = prof_ctx is not None and verbose
                 if self.cfg.profile_ops and (verbose or tel.enabled()) \
@@ -962,21 +962,6 @@ class CompiledModel:
                     self.op_attribution(print_table=verbose)
                 if will_report:
                     self.profile_report()
-                # self-calibration (ISSUE 14): --auto-refit closes the drift
-                # loop — fold this run's telemetry through span_dataset into
-                # a refreshed learned cost model. Runs AFTER op_attribution
-                # so the refit sees THIS fit's op/attr rows, and on every
-                # profiled fit (not only a tripped drift warn) so the corpus
-                # keeps growing; the model file's content hash re-keys the
-                # strategy cache either way.
-                if getattr(self.cfg, "auto_refit", False):
-                    from flexflow_tpu.search.learned_cost import auto_refit
-
-                    info = auto_refit(self.cfg)
-                    if info is not None and verbose:
-                        print(f"[refit] cost model <- {info['rows']} corpus "
-                              f"rows ({len(info['kinds'])} op kinds) -> "
-                              f"{info['path']} [{info['fingerprint']}]")
         return history
 
     def _fit_end_report(self, verbose: bool) -> None:
@@ -1476,7 +1461,7 @@ class CompiledModel:
         monitor's measured per-update time from the LAST fit (attributed
         times are rescaled to sum to it); with no fit yet, attributed ==
         isolated measured. Emits op/attr + op/drift_topk telemetry events
-        when the sink is on (the span-dataset corpus). Returns the report
+        when the sink is on. Returns the report
         dict ({"rows", "top_drift", "coverage", ...})."""
         from flexflow_tpu import attribution
 

@@ -16,7 +16,7 @@ from typing import Dict, List, Optional, Tuple
 # Everything this package caches on disk lives INSIDE the checkout (the
 # parent of the package directory) at fixed, git-ignored paths — never in
 # the home directory, where a run could be steered by files that are not in
-# the repository: .ff_cache/ (searched strategies, learned cost model) and
+# the repository: .ff_cache/ (searched strategies, measured op costs) and
 # .jax_cache/ (XLA executables).
 CHECKOUT_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 FF_CACHE_ROOT = os.path.join(CHECKOUT_ROOT, ".ff_cache")
@@ -80,18 +80,9 @@ class FFConfig:
     # LogicalTaskgraphBasedSimulator, simulator.h:785-827): "additive"
     # trusts the frontier DP's closed-form costing; "taskgraph" replays the
     # top finalists on per-stream timelines and picks by makespan
-    # "learned" (ISSUE 14) prices the SAME search with the per-op-kind
-    # ridge from search/learned_cost.py (trained by
-    # tools/refit_cost_model.py); no model file -> falls back to additive
     simulator_mode: str = "additive"
     simulator_segment_size: int = 16 * 1024 * 1024  # model.cc:3493
     simulator_topk: int = 4
-    # learned cost model file; "" = $FF_COST_MODEL_PATH or
-    # <checkout>/.ff_cache/cost_model.json
-    cost_model_path: str = ""
-    # refit the learned model from this run's telemetry at fit end
-    # (tools/refit_cost_model.py — the drift report's self-calibration)
-    auto_refit: bool = False
     # machine model (cost model) description file; "" = default v5p-like model
     machine_model_file: str = ""
     # training-loop pipeline (compiler/compile.py _fit_epochs): the fit loop
@@ -179,8 +170,8 @@ class FFConfig:
     # partitioned re-execution) against the search's stamped per-op
     # predicted costs and the roofline bound — per-op MFU, compute-vs-
     # bandwidth classification and the per-op drift top-K, printed via
-    # profile_report and emitted as op/attr telemetry events (the learned
-    # cost model's training corpus, tools/span_dataset.py)
+    # profile_report and emitted as op/attr telemetry events
+    # (tools/trace_report.py's [ops] section)
     profile_ops: bool = False
     allow_tensor_op_math_conversion: bool = True  # = bf16 matmul policy
     compute_dtype: str = "float32"  # params dtype; "bfloat16" enables mixed policy
@@ -212,7 +203,7 @@ class FFConfig:
     telemetry_dir: str = ""
     # size cap per telemetry JSONL segment in MB (flexflow_tpu/health.py
     # era): long elastic runs rotate to telemetry-<pid>.<seq>.jsonl past
-    # this; readers (trace_report / span_dataset / monitor) merge segments
+    # this; readers (trace_report / monitor) merge segments
     # transparently. 0 = unbounded (the pre-rotation behavior).
     telemetry_max_mb: float = 512.0
     # numerics sentinels (flexflow_tpu/health.py): device-resident
@@ -451,12 +442,10 @@ class FFConfig:
                        default=True)
         p.add_argument("--strategy-cache-dir", type=str, default="")
         p.add_argument("--simulator-mode", type=str, default="additive",
-                       choices=("additive", "learned", "taskgraph"))
+                       choices=("additive", "taskgraph"))
         p.add_argument("--simulator-segment-size", type=int,
                        default=16 * 1024 * 1024)
         p.add_argument("--simulator-topk", type=int, default=4)
-        p.add_argument("--cost-model-path", type=str, default="")
-        p.add_argument("--auto-refit", action="store_true")
         p.add_argument("--simulator-trace", type=str, default="")
         p.add_argument("--machine-model-file", type=str, default="")
         p.add_argument("--sync-every", type=int, default=0)
@@ -576,6 +565,9 @@ class FFConfig:
                                  "leaf",
             "--fused-loss": "the loss is always the optax form "
                             "(losses.compute_loss on float32 logits)",
+            "--cost-model-path": "the learned pricing tier is gone; "
+                                 "--simulator-mode is additive or taskgraph",
+            "--auto-refit": "the learned pricing tier is gone",
         }
         for flag in (a.split("=")[0] for a in argv):
             if flag in gone:
@@ -615,8 +607,6 @@ class FFConfig:
             simulator_mode=args.simulator_mode,
             simulator_segment_size=args.simulator_segment_size,
             simulator_topk=args.simulator_topk,
-            cost_model_path=args.cost_model_path,
-            auto_refit=args.auto_refit,
             simulator_trace=args.simulator_trace,
             machine_model_file=args.machine_model_file,
             sync_every=args.sync_every,

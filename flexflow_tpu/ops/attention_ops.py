@@ -99,6 +99,7 @@ from flexflow_tpu.kernels import sparse_attend_chunk, sparse_attend_step
 from flexflow_tpu.kernels.partition import dividing, multi_device, per_shard
 from flexflow_tpu.ops.norm_ops import rms_norm
 from flexflow_tpu.ops.op_type import OperatorType
+from flexflow_tpu.ops.pages import append_slots, kv_quantize, merge_heads
 from flexflow_tpu.ops.registry import register_op, LoweringCtx
 from flexflow_tpu.ops.rotary import apply_rope_half, half_tables
 from flexflow_tpu.ops.sparse_attention_ops import (ATTEND_SCOPE,
@@ -307,9 +308,6 @@ def _mha_decode_lower(layer: Layer, inputs, weights, ctx: LoweringCtx):
     pos = ctx.state["serve/pos"]
     page = k_pool.shape[1]
     b, s = q.shape[0], q.shape[1]
-    from flexflow_tpu.serving.kv_cache import (append_slots, kv_quantize,
-                                               merge_heads)
-
     t, pageix, off = append_slots(pt, pos, s, page, ring=bool(window))
 
     if quantized:

@@ -70,6 +70,8 @@ from flexflow_tpu.core.tensor import TensorSpec
 from flexflow_tpu.kernels.partition import multi_device
 from flexflow_tpu.ops.norm_ops import rms_norm
 from flexflow_tpu.ops.op_type import OperatorType
+from flexflow_tpu.ops.pages import (ACTIVE_KEY, PAGE_TABLE_KEY, POS_KEY,
+                                    pad_row)
 from flexflow_tpu.ops.registry import LoweringCtx, register_op
 from flexflow_tpu.ops.rotary import (apply_rope,  # noqa: F401 (this module's names for them too)
                                      inv_freq, yarn_inv_freq)
@@ -159,9 +161,6 @@ def _whole_sequence_attention(q, k, v, scale, ctx: LoweringCtx, impl: str):
 def _absorbed_decode(layer, q_n, q_r, latent, live, weights, ctx: LoweringCtx):
     """The step's rows against the paged latent pool; returns `[b, s, H dv]`
     before the output projection."""
-    from flexflow_tpu.serving.kv_cache import (ACTIVE_KEY, PAGE_TABLE_KEY,
-                                               POS_KEY, pad_row)
-
     p = layer.params
     heads, _r_q, r, dn, _dr, dv = _sizes(p)
     dt = q_n.dtype

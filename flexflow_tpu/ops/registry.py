@@ -99,7 +99,7 @@ class OpDef:
     infer: Callable[[Layer], List[TensorSpec]]
     lower: Callable[[Layer, List[jnp.ndarray], Dict[str, jnp.ndarray], LoweringCtx], List[jnp.ndarray]]
     flops: Optional[Callable[[Layer], float]] = None  # per forward pass
-    # what the serving stack asks of an op (flexflow_tpu/serving):
+    # what the serving stack asks of an op (the serving/ package):
     # serving_params(params, kind) -> the params of its prefill / decode
     # twin (kind "prefill" | "decode"; None: the layer's own);
     # state_kind: the per-request state it carries ("paged_kv": K/V pages

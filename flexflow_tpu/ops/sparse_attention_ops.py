@@ -65,6 +65,7 @@ if TYPE_CHECKING:
 from flexflow_tpu.core.tensor import TensorSpec
 from flexflow_tpu.dtype import DataType
 from flexflow_tpu.ops.op_type import OperatorType
+from flexflow_tpu.ops.pages import append_slots, pad_row
 from flexflow_tpu.ops.registry import LoweringCtx, register_op
 from flexflow_tpu.ops.rotary import apply_rope_half, half_tables
 
@@ -256,8 +257,6 @@ def _report(ctx, live, kept, cached_rows, layer: Layer, itemsize: int):
 def _lower_cached(layer: Layer, inputs, weights, ctx: LoweringCtx):
     """A block of `s` tokens a slot over the paged cache: the append, the
     scores over the slot's pages, the selection."""
-    from flexflow_tpu.serving.kv_cache import append_slots, pad_row
-
     p = layer.params
     x = inputs[0]
     b, s = x.shape[:2]

@@ -30,17 +30,13 @@ from flexflow_tpu.search.candidates import Candidate, layer_candidates
 # Process-wide search instrumentation (the search fast path's observable):
 # calls = search_graph invocations, expansions = (beam entry x candidate)
 # inner-loop evaluations (the DP's unit of work — a strategy-cache hit must
-# leave this at 0), layers_skipped / prefix_hits = tier-3 prefix reuse,
-# cands_pruned / finalists_pruned = the learned pruner's cuts (ISSUE 14:
-# per-layer candidates dropped before expansion, layout finalists dropped
-# before the event-driven re-rank).
+# leave this at 0), layers_skipped / prefix_hits = tier-3 prefix reuse.
 SEARCH_STATS: Dict[str, int] = {}
 
 
 def reset_search_stats() -> None:
     SEARCH_STATS.update(calls=0, expansions=0, layers_skipped=0,
-                        prefix_hits=0, prefix_misses=0,
-                        cands_pruned=0, finalists_pruned=0)
+                        prefix_hits=0, prefix_misses=0)
 
 
 reset_search_stats()
@@ -117,9 +113,8 @@ class DPPrefixCache:
     position, output slot) and remapped to the resuming graph's guids.
 
     One instance is only valid for a fixed (machine, beam_width, mem_budget,
-    cost_fn, enable flags, learned pruner) — stored traces index into the
-    (possibly learned-pruned) candidate lists — and the substitution loop
-    creates one per search.
+    cost_fn, enable flags) — stored traces index into the candidate lists
+    — and the substitution loop creates one per search.
     """
 
     def __init__(self, max_entries: int = 100_000):
@@ -207,20 +202,9 @@ def _search_graph_impl(model, machine: MachineSpec, beam_width: int = 64,
                  objective: str = "latency",
                  inference: bool = False,
                  remat_policies: Optional[Sequence[str]] = None,
-                 learned=None,
                  ) -> "SearchResult | List[SearchResult]":
     """cost_fn(layer, cand) -> seconds overrides the analytic op time
     (hook for the measured path, search/measure.py).
-
-    `learned` (search/learned_cost.LearnedCost, --simulator-mode learned
-    with a trained model on disk) turns on the LEARNED DP PRUNER: before a
-    layer's candidates expand against the beam, those whose learned time
-    exceeds the layer's best by learned.prune_ratio are dropped
-    (passthroughs and the memory-leanest candidate always survive), so the
-    cut shows up directly in SEARCH_STATS["expansions"]. Pinned layers are
-    never pruned — a pin is an instruction, not a suggestion. None (the
-    default, and every mode but "learned") keeps the exact candidate sets
-    and expansion counts of today.
 
     `remat_policies` promotes rematerialization to a PER-LAYER search
     dimension (ISSUE 12): each compute candidate expands once per policy
@@ -378,11 +362,6 @@ def _search_graph_impl(model, machine: MachineSpec, beam_width: int = 64,
                 raise KeyError(f"pinned candidate {want!r} not available for "
                                f"{layer.name} (have {[c.name for c in cands]})")
             cands = sel
-        elif learned is not None:
-            cands, dropped = learned.prune_candidates(layer, cands)
-            if dropped:
-                SEARCH_STATS["cands_pruned"] = SEARCH_STATS.get(
-                    "cands_pruned", 0) + dropped
         cand_cache[layer.name] = cands
         if li <= resume_li:
             continue  # beam restored from snapshot; candidates only decode traces

@@ -74,23 +74,10 @@ def graph_optimize(model, machine: MachineSpec,
             measure_cache_path = mc.cache_path
         except Exception:
             cost_fn = None
-    # learned tier (ISSUE 14): --simulator-mode learned prices the SAME
-    # search_graph cost_fn with the ridge model trained from the span
-    # corpus, falling back per-op to the analytic roofline when a kind is
-    # out-of-distribution. None whenever the mode is off or no model file
-    # exists — that keeps the default path bitwise-unchanged.
-    from flexflow_tpu.search import learned_cost as lcm
-
-    learned = lcm.load_for_config(cfg, machine)
-    learned_fp = sc.learned_fingerprint(
-        learned.path if learned is not None else None)
-    if learned is not None and cost_fn is None:
-        cost_fn = learned.op_time
     if use_cache:
         calib = sc.calibration_fingerprint(
             measure_cache_path if measure_cache_path else None)
-        key = sc.cache_key(model, machine, cfg, calib, opt_fp,
-                           learned_fp=learned_fp)
+        key = sc.cache_key(model, machine, cfg, calib, opt_fp)
         cached = sc.lookup(cache_dir, key, model, machine)
         if cached is not None:
             return cached
@@ -101,12 +88,7 @@ def graph_optimize(model, machine: MachineSpec,
     with tel.span("search/unity", cat="compile",
                   measured=bool(cost_fn is not None)):
         st, stats = unity_optimize(model, machine, cost_fn=cost_fn,
-                                   opt_mem=opt_mem, learned=learned)
-    if learned is not None:
-        tel.event("search/learned_cost", cat="compile",
-                  coverage=learned.coverage(), hits=learned.hits,
-                  misses=learned.misses, fingerprint=learned.model.fingerprint,
-                  finalists_pruned=stats.finalists_pruned)
+                                   opt_mem=opt_mem)
     # stamp the search's own per-step prediction: the drift monitor
     # compares THIS number (what the search believed when it chose the
     # strategy) against what fit actually measures — and the PER-OP costs,
@@ -124,8 +106,7 @@ def graph_optimize(model, machine: MachineSpec,
             # the next run's lookup (which hashes the populated store)
             # finds this entry instead of orphaning it
             calib = sc.calibration_fingerprint(measure_cache_path)
-            key = sc.cache_key(model, machine, cfg, calib, opt_fp,
-                               learned_fp=learned_fp)
+            key = sc.cache_key(model, machine, cfg, calib, opt_fp)
         meta = {
             "cost_s": stats.best_cost,
             "op_costs_s": dict(stats.op_costs),
@@ -134,9 +115,6 @@ def graph_optimize(model, machine: MachineSpec,
             "search_wallclock_s": time.perf_counter() - t0,
             "calibration": calib,
         }
-        if learned is not None:
-            meta["learned_fingerprint"] = learned.model.fingerprint
-            meta["learned_coverage"] = learned.coverage()
         sc.store(cache_dir, key, st, meta=meta)
     return st
 

@@ -176,7 +176,7 @@ def build_step_tasks(model, choices: Dict[str, Candidate], machine: MachineSpec,
         for b in before:
             prev.add_next(b)
 
-    # frontier layouts, same evolution as mcmc.assignment_cost
+    # frontier layouts, evolved as the DP evolves them
     lay: Dict[int, Tuple] = {
         t.guid: _freeze_dims(_dp_dims(t.shape, machine, batch_sizes))
         for t in model.input_tensors}

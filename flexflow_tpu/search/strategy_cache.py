@@ -153,34 +153,14 @@ def calibration_fingerprint(measure_cache_path: Optional[str]) -> str:
         return "measured:empty"
 
 
-def learned_fingerprint(model_path: Optional[str]) -> str:
-    """Content hash of the learned cost model file (ISSUE 14), or "" when
-    the learned tier is off. A refit (tools/refit_cost_model.py) rewrites
-    the model file, changes this fingerprint, and invalidates every
-    strategy the learned tier priced — same rule as the calibration
-    fingerprint above."""
-    if not model_path:
-        return ""
-    try:
-        with open(model_path, "rb") as f:
-            return "learned:" + hashlib.sha256(f.read()).hexdigest()[:16]
-    except OSError:
-        return "learned:absent"
-
-
 def cache_key(model, machine: MachineSpec, cfg,
-              calib_fp: str = "analytic", opt_fp: str = "",
-              learned_fp: str = "") -> str:
+              calib_fp: str = "analytic", opt_fp: str = "") -> str:
     # opt_fp: the OptMemSpec fingerprint (search/cost_model.py) — the
     # optimizer's moment count/dtype and ZeRO axes change the memory
     # accounting memory-constrained searches rank by
     parts = (CACHE_VERSION, graph_fingerprint(model),
              memo.machine_fingerprint(machine), knob_fingerprint(cfg),
              calib_fp, opt_fp)
-    if learned_fp:
-        # appended only when the learned tier is active so every
-        # pre-existing key (and stored strategy) stays bitwise-identical
-        parts = parts + (learned_fp,)
     return hashlib.sha256(repr(parts).encode()).hexdigest()[:32]
 
 

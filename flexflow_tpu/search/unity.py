@@ -28,7 +28,6 @@ from flexflow_tpu.parallel.sharding import OpSharding, Strategy
 from flexflow_tpu.search import memo
 from flexflow_tpu.search.candidates import _dp_dims, candidate_attrs
 from flexflow_tpu.search.dp import (
-    SEARCH_STATS,
     DPPrefixCache,
     SearchResult,
     _drop_axis,
@@ -57,10 +56,6 @@ class UnityStats:
     # — replayable onto a structurally identical graph (segment memoization)
     best_path: Tuple = ()
     segments_replayed: int = 0
-    # the learned pruner's cuts (ISSUE 14): layout finalists dropped before
-    # the event-driven re-rank (per-layer candidate cuts are counted in
-    # dp.SEARCH_STATS["cands_pruned"] — they happen inside the DP)
-    finalists_pruned: int = 0
     # the DP's PER-OP cost under the winning strategy, model layer name ->
     # seconds — what the search believed each op costs. Stamped on the
     # Strategy (graph_optimize) so the per-op attribution layer
@@ -83,7 +78,6 @@ def substitution_optimize(pcg: PCG, machine: MachineSpec,
                           dp_cache: Optional[DPPrefixCache] = None,
                           opt_mem=None,
                           remat_policies=None,
-                          learned=None,
                           ) -> Tuple[PCG, SearchResult, UnityStats]:
     """Best-first search over xfer applications (base_optimize analog).
 
@@ -101,8 +95,7 @@ def substitution_optimize(pcg: PCG, machine: MachineSpec,
                             enable_parameter=enable_parameter,
                             enable_attribute=enable_attribute,
                             pins=g.pins, prefix_cache=dp_cache,
-                            opt_mem=opt_mem, remat_policies=remat_policies,
-                            learned=learned)
+                            opt_mem=opt_mem, remat_policies=remat_policies)
 
     r0 = cost(pcg)
     stats = UnityStats(baseline_cost=r0.cost, best_cost=r0.cost)
@@ -325,7 +318,7 @@ def _unfreeze(d):
 
 # ------------------------------------------------------------ entry point
 def unity_optimize(model, machine: MachineSpec, cost_fn=None,
-                   opt_mem=None, learned=None) -> Tuple[Strategy, UnityStats]:
+                   opt_mem=None) -> Tuple[Strategy, UnityStats]:
     """graph_optimize with the substitution engine (the Unity search).
 
     Honors FFConfig: search_budget (expansion budget), search_alpha (prune
@@ -381,23 +374,14 @@ def unity_optimize(model, machine: MachineSpec, cost_fn=None,
                             enable_parameter=en_param,
                             enable_attribute=en_attr, pins=g.pins,
                             prefix_cache=dp_cache, opt_mem=opt_mem,
-                            remat_policies=remat_policies, learned=learned)
+                            remat_policies=remat_policies)
 
     def _sim_refine(g: PCG, r: SearchResult) -> SearchResult:
         """simulator_mode='taskgraph': the additive DP prunes, the
         event-driven replay (search/simulator.py — the reference
         LogicalTaskgraphBasedSimulator analog) decides among the segment
-        winner's top layout finalists by simulated makespan.
-
-        simulator_mode='learned' (ISSUE 14): same finalist recovery, but
-        the learned model both PRUNES the finalist list (drop those whose
-        learned whole-graph score exceeds the best by finalist_margin) and
-        prices the re-rank's task times — the middle tier between additive
-        costing and the full event replay."""
-        if cfg.simulator_mode not in ("taskgraph", "learned") \
-                or cfg.simulator_topk < 2:
-            return r
-        if cfg.simulator_mode == "learned" and learned is None:
+        winner's top layout finalists by simulated makespan."""
+        if cfg.simulator_mode != "taskgraph" or cfg.simulator_topk < 2:
             return r
         # layer names ride the key: PCG.key() is name-free, but the cached
         # SearchResult's choices are name-addressed — an isomorphic twin
@@ -418,22 +402,12 @@ def unity_optimize(model, machine: MachineSpec, cost_fn=None,
                                  enable_attribute=en_attr, pins=g.pins,
                                  topk=cfg.simulator_topk,
                                  prefix_cache=dp_cache, opt_mem=opt_mem,
-                                 remat_policies=remat_policies,
-                                 learned=learned)
-        if learned is not None and isinstance(finalists, list):
-            kept, f_dropped = learned.prune_finalists(g, finalists)
-            if f_dropped:
-                stats_all.finalists_pruned += f_dropped
-                SEARCH_STATS["finalists_pruned"] = SEARCH_STATS.get(
-                    "finalists_pruned", 0) + f_dropped
-                finalists = kept
+                                 remat_policies=remat_policies)
         with tel.span("search/sim_rerank", cat="compile",
                       finalists=len(finalists)
                       if isinstance(finalists, list) else 1):
-            rerank_cost = (learned.op_time if learned is not None
-                           and cfg.simulator_mode == "learned" else cost_fn)
             picked, _reports = sim.rerank(
-                g, machine, finalists, cost_fn=rerank_cost,
+                g, machine, finalists, cost_fn=cost_fn,
                 segment_bytes=cfg.simulator_segment_size)
         sim_cache[sim_key] = picked
         return picked
@@ -459,7 +433,7 @@ def unity_optimize(model, machine: MachineSpec, cost_fn=None,
                             enable_parameter=en_param,
                             enable_attribute=en_attr, pins=pins,
                             prefix_cache=dp_cache, opt_mem=opt_mem,
-                            remat_policies=remat_policies, learned=learned)
+                            remat_policies=remat_policies)
                         best, refined_done = replayed, True
                     else:
                         best, best_r = replayed, _cost_pcg(replayed)
@@ -479,7 +453,7 @@ def unity_optimize(model, machine: MachineSpec, cost_fn=None,
                 mem_budget=mem_budget, cost_fn=cost_fn,
                 enable_parameter=en_param, enable_attribute=en_attr,
                 dp_cache=dp_cache, opt_mem=opt_mem,
-                remat_policies=remat_policies, learned=learned)
+                remat_policies=remat_policies)
             budget_left = max(0, budget_left - stats.expansions)
             seg_memo[k] = (stats.best_path, stats.baseline_cost, None)
             stats_all.expansions += stats.expansions
@@ -495,9 +469,7 @@ def unity_optimize(model, machine: MachineSpec, cost_fn=None,
                 # the re-rank may pick a finalist whose additive cost differs
                 stats_all.best_cost += refined.cost - best_r.cost
                 best_r = refined
-            if (cfg.simulator_mode == "taskgraph"
-                    or (cfg.simulator_mode == "learned"
-                        and learned is not None)) and k in seg_memo:
+            if cfg.simulator_mode == "taskgraph" and k in seg_memo:
                 seg_memo[k] = (seg_memo[k][0], seg_memo[k][1],
                            [best_r.choices[l.name].name
                             for l in topo_order(best.layers)])

@@ -10,14 +10,14 @@ from flexflow_tpu import telemetry as _tel
 _T_START_US = _tel.now_us()
 
 from flexflow_tpu.serving import tracefmt
+from flexflow_tpu.serving.admission import AdmissionControl
 from flexflow_tpu.serving.engine import ServingCompiled, compile_serving
-from flexflow_tpu.serving.fleet import (AdmissionControl, FleetRouter,
-                                        RollingSwapController, ServingFleet,
+from flexflow_tpu.serving.fleet import (FleetRouter, RollingSwapController,
+                                        ServingFleet,
                                         merge_histograms, merge_slo_trackers)
 from flexflow_tpu.serving.kv_cache import (ACTIVE_KEY, KVPoolExhausted,
                                            PAGE_TABLE_KEY, POS_KEY,
-                                           PagedKVCache,
-                                           derive_prefetch_ahead)
+                                           PagedKVCache)
 from flexflow_tpu.serving.program import clone_for_serving, serving_optimize
 from flexflow_tpu.serving.reqtrace import (RequestTracer, StreamingHistogram,
                                            TERMINAL_FIELDS, terminal_record)
@@ -47,7 +47,6 @@ __all__ = [
     "terminal_record",
     "ServingFleet", "AdmissionControl", "FleetRouter",
     "RollingSwapController", "merge_histograms", "merge_slo_trackers",
-    "derive_prefetch_ahead",
     "tracefmt", "Trace", "TraceRecord", "load_trace", "save_trace",
     "TwinSpec", "TwinCosts", "TwinResult", "simulate", "capacity_curve",
 ]

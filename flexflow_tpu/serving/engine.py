@@ -451,7 +451,7 @@ class ServingCompiled:
         heads_axis = _wq_heads_axis(decode_strategy, self.attn_layers)
         self.kv = PagedKVCache(kv_spec, self.attn_layers, mesh,
                                heads_axis=heads_axis, dtype=self.kv_dtype,
-                               quantized=self.kv_quantized, machine=machine,
+                               quantized=self.kv_quantized,
                                recurrent=recurrent, index_layers=index_layers,
                                window_layers=window_layers)
         deg = 1
@@ -1126,12 +1126,11 @@ class ServingCompiled:
         serving face of CompiledModel.op_attribution. One report per
         program (prefill / decode), each row featurized against the
         placement that actually compiled and priced by the SAME serving
-        cost functions the search ranked with — so the op/attr events the
-        telemetry sink collects teach the span corpus (and through it the
-        learned cost model) the bandwidth-bound seq=1 decode regime that
-        training fits never exercise. step_time_s normalizes decode rows
-        (the scheduler passes its median per-token wall), prefill_step_
-        time_s the prefill rows (the shed estimator's EMA). With
+        cost functions the search ranked with, so the op/attr events the
+        telemetry sink collects show the bandwidth-bound seq=1 decode
+        regime that training fits never exercise. step_time_s normalizes
+        decode rows (the scheduler passes its median per-token wall),
+        prefill_step_time_s the prefill rows (the shed estimator's EMA). With
         `profile_dir` (a `jax.profiler.trace` of a serving run of this
         engine) and a step time, a program's rows are measured from the
         profile: its device events joined by instruction name with the

@@ -147,6 +147,10 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 from flexflow_tpu import attribution
 # the windowed layers' rings: the key the layers' lowering reads them under
 from flexflow_tpu.ops.attention_ops import WINDOW_TABLE_KEY  # noqa: F401
+# the page format (the ops' lowering reads the same definitions)
+from flexflow_tpu.ops.pages import (ACTIVE_KEY, PAGE_TABLE_KEY,  # noqa: F401
+                                    POS_KEY, append_slots, kv_dequantize,
+                                    kv_quantize, merge_heads, pad_row)
 from flexflow_tpu.search.cost_model import KVCacheSpec
 
 # a wave's fresh recurrent state (slots x the state a slot, every layer) at
@@ -155,29 +159,6 @@ from flexflow_tpu.search.cost_model import KVCacheSpec
 # sixteenth of a v5e's memory: granite's, Nemotron's and Ling's states are
 # 0.2-0.6 GB a wave, a power-retention model's 3.3
 IN_PLACE_STATE_BYTES = 1 << 30
-
-PAGE_TABLE_KEY = "serve/page_table"
-POS_KEY = "serve/pos"
-ACTIVE_KEY = "serve/active"
-
-
-def kv_quantize(x):
-    """Symmetric per-(position, head) int8 quantization over head_dim:
-    `scale = max|x| / 127` along the last axis, values rounded into
-    [-127, 127]. Returns (int8 values, f32 scales) with the scales one
-    rank lower — the per-page-entry-per-head arrays the quantized pools
-    store next to the values. The scale floor keeps all-zero rows (fresh
-    pages, padding routed to scratch) exactly representable as zeros."""
-    x = x.astype(jnp.float32)
-    amax = jnp.max(jnp.abs(x), axis=-1)
-    scale = jnp.maximum(amax, 1e-8) / 127.0
-    q = jnp.clip(jnp.round(x / scale[..., None]), -127, 127).astype(jnp.int8)
-    return q, scale
-
-
-def kv_dequantize(q, scale):
-    """Inverse of kv_quantize: f32 values from int8 + per-row scales."""
-    return q.astype(jnp.float32) * scale[..., None]
 
 
 class KVPoolExhausted(Exception):
@@ -195,38 +176,6 @@ class KVPoolExhausted(Exception):
         self.slot = slot
         self.need = need
         self.have = have
-
-
-def pad_row(rows, width: int):
-    """Token rows `[.., n]` as a pool `[pages, page, width]` holds them:
-    zeros up to `width` (a latent row's whole lanes)."""
-    short = width - rows.shape[-1]
-    return rows if short == 0 else jnp.pad(
-        rows, [(0, 0)] * (rows.ndim - 1) + [(0, short)])
-
-
-def append_slots(pt, pos, s: int, page: int, ring: bool = False):
-    """Where a block of `s` tokens a slot lies in the pools: (`t` `[slots,
-    s]` the tokens' positions `pos + i`, the page of each, its offset in the
-    page). A position past the table's last page goes to the scratch page
-    (as `_commit_prefill` routes padding), so the scatter that follows has
-    one shape whatever a slot holds. With `ring` the table is a ring: page
-    `n` of the context lies at entry `n % entries`, whatever `n`."""
-    rows = jnp.arange(pt.shape[0])
-    t = pos[:, None] + jnp.arange(s)[None, :]
-    pg = t // page
-    if ring:
-        return t, pt[rows[:, None], pg % pt.shape[1]], t % page
-    in_range = pg < pt.shape[1]
-    pageix = jnp.where(in_range,
-                       pt[rows[:, None], jnp.minimum(pg, pt.shape[1] - 1)], 0)
-    return t, pageix, t % page
-
-
-def merge_heads(x):
-    """`[.., heads, head_dim]` token rows as the pools hold them:
-    `[.., heads * head_dim]`, heads-major."""
-    return x.reshape(x.shape[:-2] + (x.shape[-2] * x.shape[-1],))
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
@@ -301,12 +250,11 @@ class PagedKVCache:
 
     def __init__(self, spec: KVCacheSpec, attn_layers: List[str],
                  mesh: Optional[Mesh] = None, heads_axis=None,
-                 dtype=jnp.float32, quantized: bool = False, machine=None,
+                 dtype=jnp.float32, quantized: bool = False,
                  recurrent: Optional[Dict[str, Dict[str, tuple]]] = None,
                  index_layers: Optional[List[str]] = None,
                  window_layers: Optional[List[str]] = None):
         self.spec = spec
-        self.machine = machine  # host_bw source for transfer pricing rows
         # the commit programs as this cache runs them (attribution.op_scopes
         # "serve/commit"): they hold no graph layer, so their device time
         # is a wave's `other`: found, because they are registered
@@ -599,46 +547,11 @@ class PagedKVCache:
         return bool(self.host_pages) and bool(self._active[slot]) and \
             len(self.free_host_pages) >= len(self._slot_pages.get(slot, []))
 
-    def _transfer_row(self, direction: str, pages: int, measured_s: float) -> None:
-        """Emit one `op/attr` telemetry row for a tier transfer, shaped like
-        the per-op attribution rows: the learned cost model refits a
-        `kv_transfer` coefficient from these exactly as it refits any op
-        kind (features carry the shapes + machine fingerprint; predicted_s
-        is the host-link roofline the refit corrects)."""
-        from flexflow_tpu import telemetry as tel
-        from flexflow_tpu.attribution import OP_EVENT, feature_key
-        from flexflow_tpu.search import memo
-        moved = self.spec.layers * pages * self.spec.page_bytes()
-        host_bw = getattr(self.machine, "host_bw", 0.0) or 16e9
-        predicted = moved / host_bw
-        features = {
-            "op": "kv_transfer",
-            "in_shapes": [[pages, self.spec.page_size, self.spec.heads,
-                           self.spec.head_dim]],
-            "out_shapes": [[pages, self.spec.page_size, self.spec.heads,
-                            self.spec.head_dim]],
-            "weight_shapes": [],
-            "dtype": "int8" if self.quantized else "float32",
-            "params": 0,
-            "layout": direction,
-            "sharding": {"out": [], "weights": []},
-            "machine": (memo.machine_fingerprint(self.machine)
-                        if self.machine is not None else ()),
-        }
-        tel.event(OP_EVENT, cat="op", layer=f"kv_cache/{direction}",
-                  op="kv_transfer", candidate=direction,
-                  predicted_s=predicted, measured_s=measured_s,
-                  attributed_s=measured_s, roofline_s=predicted,
-                  bound="host_bw", mfu=0.0, mfu_ceiling=0.0,
-                  key=feature_key(features), features=features,
-                  source="serve", bytes=moved)
-
     def spill(self, slot: int, decode_step: int) -> None:
         """Park an active slot: gather its pages from every layer's pools
         to the host buffers (one `jax.device_get` per leaf), return the
         device pages, and deactivate the slot keeping its position. The
         caller (scheduler) batches `push()` after a rotation round."""
-        import time as _time
         from flexflow_tpu import telemetry as tel
         self._kv_pages_only("spill")
         if not self.can_spill(slot):
@@ -646,7 +559,6 @@ class PagedKVCache:
         pages = self._slot_pages.pop(slot)
         host_ids = [self.free_host_pages.pop() for _ in pages]
         idx = jnp.asarray(np.asarray(pages, np.int32))
-        t0 = _time.perf_counter()
         with tel.span("serve/kv_spill", cat="serve", slot=int(slot),
                       pages=len(pages)):
             for n in self.attn_layers:
@@ -660,7 +572,6 @@ class PagedKVCache:
         moved = self.spec.layers * len(pages) * self.spec.page_bytes()
         self.tier_counters["kv_spills"] += 1
         self.tier_counters["kv_spilled_bytes"] += moved
-        self._transfer_row("spill", len(pages), _time.perf_counter() - t0)
 
     def prefetch(self, slot: int, decode_step: int) -> bool:
         """Issue the host→HBM refill for a parked slot: allocate device
@@ -670,7 +581,6 @@ class PagedKVCache:
         slot's table row. The slot stays INACTIVE until `join` so the hit/
         stall ledger reflects when the scheduler actually needed it.
         Returns False (no-op) when the device free list can't cover it."""
-        import time as _time
         from flexflow_tpu import telemetry as tel
         host_ids = self._cold.get(slot)
         if host_ids is None or slot in self._inflight:
@@ -680,7 +590,6 @@ class PagedKVCache:
             return False
         pages = [self.free_pages.pop() for _ in range(need)]
         idx = jnp.asarray(np.asarray(pages, np.int32))
-        t0 = _time.perf_counter()
         with tel.span("serve/kv_prefetch", cat="serve", slot=int(slot),
                       pages=need, step=int(decode_step)):
             for n in self.attn_layers:
@@ -699,7 +608,6 @@ class PagedKVCache:
         moved = self.spec.layers * need * self.spec.page_bytes()
         self.tier_counters["kv_refills"] += 1
         self.tier_counters["kv_refilled_bytes"] += moved
-        self._transfer_row("prefetch", need, _time.perf_counter() - t0)
         return True
 
     def join(self, slot: int, decode_step: int, prefetch_ahead: int) -> bool:
@@ -748,19 +656,15 @@ class PagedKVCache:
         side of the disaggregated handoff). The slot lands PARKED with its
         position preserved, so the ordinary rotation (prefetch + join)
         carries it into HBM — the handoff rides the exact spill/prefetch
-        path and stays bitwise-identical to a colocated prefill. The copy
-        is priced and emitted as a `kv_transfer` op/attr row (direction
-        "handoff") so the learned model refits the DCN/host link like any
-        other op. Raises `KVPoolExhausted` when the host free list is
-        short — backpressure, the fleet retries the delivery."""
-        import time as _time
+        path and stays bitwise-identical to a colocated prefill. Raises
+        `KVPoolExhausted` when the host free list is short — backpressure,
+        the fleet retries the delivery."""
         self._kv_pages_only("import_parked")
         if self._active[slot] or slot in self._cold:
             raise ValueError(f"slot {slot} is occupied")
         need = int(payload["pages"])
         if not self.can_import(payload):
             raise KVPoolExhausted(slot, need, len(self.free_host_pages))
-        t0 = _time.perf_counter()
         host_ids = [self.free_host_pages.pop() for _ in range(need)]
         for n in self.attn_layers:
             for key, rows in payload["layers"][n].items():
@@ -772,7 +676,6 @@ class PagedKVCache:
         moved = self.spec.layers * need * self.spec.page_bytes()
         self.tier_counters["kv_handoffs"] += 1
         self.tier_counters["kv_handoff_bytes"] += moved
-        self._transfer_row("handoff", need, _time.perf_counter() - t0)
 
     def tier_stats(self) -> Dict[str, int]:
         """Counters + occupancy snapshot for telemetry/monitoring."""
@@ -853,64 +756,3 @@ class PagedKVCache:
                     total += sum(s.data.nbytes for s in shards
                                  if s.device == dev)
         return total
-
-
-# -------------------------------------------------- prefetch-ahead autotune
-def learned_kv_transfer_seconds(cfg, spec: KVCacheSpec,
-                                quantized: bool = False, machine=None,
-                                pages: Optional[int] = None
-                                ) -> Optional[float]:
-    """Learned seconds for one slot-sized host↔HBM transfer, or None when
-    no learned model resolves a `kv_transfer` prediction (no model file on
-    the resolution chain, or the model never saw the kind). Features are
-    built exactly like `PagedKVCache._transfer_row` emits them, so the
-    coefficient refit from serving telemetry prices this query."""
-    import os
-    try:
-        from flexflow_tpu.search.learned_cost import (LearnedCostModel,
-                                                      resolve_model_path)
-        from flexflow_tpu.search import memo
-    except ImportError:
-        return None
-    path = resolve_model_path(cfg)
-    if not path or not os.path.isfile(path):
-        return None
-    try:
-        model = LearnedCostModel.load(path)
-    except Exception:  # noqa: BLE001 — a corrupt model never breaks serving
-        return None
-    n_pages = int(pages if pages is not None else spec.pages_per_slot)
-    moved = spec.layers * n_pages * spec.page_bytes()
-    host_bw = getattr(machine, "host_bw", 0.0) or 16e9
-    predicted = moved / host_bw
-    features = {
-        "op": "kv_transfer",
-        "in_shapes": [[n_pages, spec.page_size, spec.heads, spec.head_dim]],
-        "out_shapes": [[n_pages, spec.page_size, spec.heads, spec.head_dim]],
-        "weight_shapes": [],
-        "dtype": "int8" if quantized else "float32",
-        "params": 0,
-        "layout": "prefetch",
-        "sharding": {"out": [], "weights": []},
-        "machine": (memo.machine_fingerprint(machine)
-                    if machine is not None else ()),
-    }
-    try:
-        return model.predict_features(features, predicted_s=predicted,
-                                      roofline_s=predicted)
-    except Exception:  # noqa: BLE001
-        return None
-
-
-def derive_prefetch_ahead(transfer_s: Optional[float],
-                          decode_step_s: Optional[float],
-                          fallback: int) -> int:
-    """The rotation lead (in decode steps) that hides one slot refill
-    behind decode compute: ceil(learned transfer time / decode step time),
-    clamped to [1, 64]. Falls back to the `--kv-prefetch-ahead` flag value
-    when either side of the ratio is unavailable — the flag is the
-    fallback, not the authority (ISSUE 18 satellite)."""
-    if not transfer_s or not decode_step_s or decode_step_s <= 0:
-        return max(1, int(fallback))
-    return max(1, min(64, -(-int(transfer_s * 1e9)
-                            // max(1, int(decode_step_s * 1e9)))))

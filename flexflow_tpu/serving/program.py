@@ -243,8 +243,7 @@ def _decode_cost_fn(machine: MachineSpec, kv_layer_bytes: int,
     over the host link, amortized over the `prefetch_ahead` steps the
     scheduler issues it early — traffic hidden behind more decode steps
     costs less per step, which is exactly the knob --kv-prefetch-ahead
-    turns. The learned cost model refits this term from the kv_transfer
-    telemetry rows like any other op.
+    turns.
 
     A layer with per-slot recurrent state reads and writes it every step
     (every slot's, at worst): the cache term of a model that pages nothing

@@ -91,7 +91,7 @@ in-flight dispatch referencing the retiring param tree.
 
 Fleet integration (ISSUE 18): this class is the REPLICA-LOCAL decode
 loop. The admission policy brain (shed-or-queue, queue-cap displacement,
-staleness sweeps) lives in `fleet.AdmissionControl` — one instance here
+staleness sweeps) lives in `admission.AdmissionControl` — one instance here
 for standalone use, the same class at fleet level for cross-replica
 admission — and three hooks let `fleet.ServingFleet` drive N loops:
 `self.feed` (a thread-safe arrival feed replacing the static trace),
@@ -124,9 +124,9 @@ import numpy as np
 from flexflow_tpu import telemetry as tel
 from flexflow_tpu.ops.registry import STATS_KEY
 from flexflow_tpu.runtime.resilience import RetryPolicy, run_resilient
-from flexflow_tpu.serving.kv_cache import (ACTIVE_KEY, KVPoolExhausted, POS_KEY,
-                                           derive_prefetch_ahead,
-                                           learned_kv_transfer_seconds)
+from flexflow_tpu.serving.admission import AdmissionControl, _urgency
+from flexflow_tpu.serving.kv_cache import (ACTIVE_KEY, KVPoolExhausted,
+                                           POS_KEY)
 from flexflow_tpu.serving.reqtrace import RequestTracer, terminal_record
 
 
@@ -241,10 +241,6 @@ def _greedy_tokens(logits):
     return jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32)[:, None]
 
 
-def _urgency(r: Request):
-    return (r.priority, r.arrival_s, r.rid)
-
-
 # Steps the overlapped decode loop keeps in flight: one running and one
 # queued behind it, which feeds the chip as long as the host turns a step
 # round faster than the device runs one (2.1-2.4 ms against 4-12 in the
@@ -324,24 +320,13 @@ class ContinuousBatchingScheduler:
         self.tiered = bool(getattr(self.kv, "host_pages", 0))
         self.prefetch_ahead = max(1, int(
             getattr(cfg, "kv_prefetch_ahead", 2) or 2))
-        # autotuned prefetch-ahead (ISSUE 18 satellite): when a learned
-        # model resolves a kv_transfer prediction for this cache geometry,
-        # the lead is re-derived from it at the first measured decode step
-        # — the flag value above is the fallback, not the authority
-        self._autotune_transfer_s: Optional[float] = None
-        self._autotuned = False
-        if self.tiered:
-            self._autotune_transfer_s = learned_kv_transfer_seconds(
-                cfg, self.kv.spec, quantized=self.kv.quantized,
-                machine=self.kv.machine)
         self.max_context = int(getattr(cfg, "serve_max_context", 0) or 0)
         # chunked prefill: tokens a chunk (0: one padded wave);
         # slot -> [request, prompt tokens cached], oldest first
         self.chunk = int(getattr(engine, "chunk_tokens", 0) or 0)
         self._prefilling: Dict[int, List[Any]] = {}
-        # the admission policy brain is the fleet-level class (ISSUE 18
-        # control-plane split); a standalone scheduler owns one instance
-        from flexflow_tpu.serving.fleet import AdmissionControl
+        # the admission policy brain (a fleet shares the same class across
+        # replicas); a standalone scheduler owns one instance
         self.admission = AdmissionControl(
             # chunked, `seq` is a slot's whole context: the longest prompt
             # leaves room for the longest answer
@@ -464,7 +449,7 @@ class ContinuousBatchingScheduler:
     def _enqueue(self, req: Request, waiting: List[Request],
                  now_s: float) -> None:
         """The shed-or-queue decision for one arrival. The decisions
-        themselves live in `fleet.AdmissionControl` (the PR 11 machinery,
+        themselves live in `admission.AdmissionControl` (the PR 11 machinery,
         lifted to where the fleet can share it); this wrapper keeps the
         side effects — tracing, shed telemetry, terminal records — on the
         replica that owns the request."""
@@ -888,20 +873,6 @@ class ContinuousBatchingScheduler:
             self._emit_tier()
         return changed
 
-    def _maybe_autotune(self, decode_step_s: float) -> None:
-        """First measured decode step closes the autotune loop: the lead
-        becomes ceil(learned kv_transfer seconds / measured step seconds)
-        — the number of steps a slot refill actually needs to hide behind
-        decode compute on THIS machine, per the refit host-link
-        coefficient. No learned model resolved -> `self._autotune_transfer_s`
-        is None and the flag value stays authoritative."""
-        if self._autotune_transfer_s is None or self._autotuned:
-            return
-        self._autotuned = True
-        tuned = derive_prefetch_ahead(self._autotune_transfer_s,
-                                      decode_step_s, self.prefetch_ahead)
-        self.prefetch_ahead = tuned
-
     # -------------------------------------------------- disaggregated handoff
     def _handoff_all(self, active: Dict[int, Request]) -> None:
         """Prefill-only mode (ISSUE 18 `--serve-fleet-topology disagg`):
@@ -1101,7 +1072,6 @@ class ContinuousBatchingScheduler:
             steps = len(mats)
             per_step = (t_now - window_t0) / steps
             self.step_times.extend([per_step] * steps)
-            self._maybe_autotune(per_step)
             adv = np.zeros((self.slots,), np.int32)
             # a slot that finished at an earlier sync decoded these too
             self.stats["overdecode_tokens"] += steps * len(self._finishing)
@@ -1266,7 +1236,6 @@ class ContinuousBatchingScheduler:
         tel.counter("serve/spec_accept_rate", self._accept_ema, cat="serve")
         per_tok = wall / max_commit
         self.step_times.extend([per_tok] * max_commit)
-        self._maybe_autotune(per_tok)
         if self.tracer is not None:
             self.tracer.hists["decode_step"].add(per_tok, n=max_commit)
         self.decode_steps += K + 1
@@ -1500,11 +1469,9 @@ class ContinuousBatchingScheduler:
         if self.slo is not None and tel.enabled():
             tel.event("serve/slo", cat="serve", report=self.slo.report())
         if getattr(self.engine.cfg, "profile_ops", False) and tel.enabled():
-            # --profile-ops (ISSUE 14 satellite): featurize this run's
-            # prefill + decode placements into op/attr corpus rows, with
-            # the run's REAL wall times as the step normalizers — the
-            # learned cost model's only window into the bandwidth-bound
-            # seq=1 decode regime training fits never exercise
+            # --profile-ops: this run's prefill + decode placements as
+            # op/attr rows (tools/trace_report.py's [ops] section), with
+            # the run's REAL wall times as the step normalizers
             try:
                 self.engine.op_attribution(
                     step_time_s=(float(np.median(self.step_times))

@@ -16,17 +16,15 @@ generators are interchangeable) through the REAL control-plane classes:
   `kv_prefetch_ahead` hiding rule),
 - spec rounds as expected-commit batching (1 + accept_rate * K tokens
   per verify round),
-- prefill/decode disaggregation with the KV handoff priced like the
-  PR-18 `kv_transfer` rows.
+- prefill/decode disaggregation with the KV handoff priced at the
+  host-link rate.
 
-Durations come from `TwinCosts`, resolved learned-model-first
-(`search/learned_cost.py` rows the twin itself emits close the loop via
-tools/refit_cost_model.py), then live-measurement calibration, then the
-analytic roofline. Outputs are bitwise the live schema: terminal records
-through `reqtrace.terminal_record`, the same `StreamingHistogram` metrics,
-and an `SLOTracker` scoreboard — so twin-vs-live validation is a plain
-report diff, and `health.scaling_signal` reads twin output exactly as it
-reads production output.
+Durations come from `TwinCosts`: calibrated off a live run's histograms
+where there is one, else the analytic roofline. Outputs are bitwise the
+live schema: terminal records through `reqtrace.terminal_record`, the same
+`StreamingHistogram` metrics, and an `SLOTracker` scoreboard — so
+twin-vs-live validation is a plain report diff, and `health.scaling_signal`
+reads twin output exactly as it reads production output.
 """
 
 from __future__ import annotations
@@ -38,15 +36,15 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 from flexflow_tpu.health import (SLOTracker, parse_slo, scaling_signal)
 from flexflow_tpu.search.cost_model import KVCacheSpec
-from flexflow_tpu.serving.fleet import AdmissionControl, FleetRouter
+from flexflow_tpu.serving.admission import AdmissionControl, _urgency
+from flexflow_tpu.serving.fleet import FleetRouter
 from flexflow_tpu.serving.reqtrace import (HIST_METRICS, StreamingHistogram,
                                            terminal_record)
-from flexflow_tpu.serving.scheduler import _urgency
 from flexflow_tpu.serving.tracefmt import TraceRecord, scale_rate
 
 __all__ = ["TwinSpec", "TwinCosts", "TwinResult", "simulate",
-           "capacity_curve", "validate", "emit_residual_rows",
-           "signal_timeline", "calibrate_window_overhead"]
+           "capacity_curve", "validate", "signal_timeline",
+           "calibrate_window_overhead"]
 
 
 class _Len:
@@ -164,35 +162,12 @@ class TwinSpec:
 
 
 # ------------------------------------------------------------------ costs
-def _twin_features(kind: str, spec: KVCacheSpec, slots: int,
-                   machine: Any = None) -> Dict[str, Any]:
-    """Feature row for the learned model's `twin_*` kinds — built here AND
-    emitted here (emit_residual_rows), so a refit-trained coefficient
-    prices exactly the query the twin asks."""
-    try:
-        from flexflow_tpu.search import memo
-        fp = memo.machine_fingerprint(machine) if machine is not None else ()
-    except ImportError:
-        fp = ()
-    return {
-        "op": kind,
-        "in_shapes": [[slots, spec.page_size, spec.heads, spec.head_dim]],
-        "out_shapes": [[slots, spec.page_size, spec.heads, spec.head_dim]],
-        "weight_shapes": [],
-        "dtype": "int8" if spec.scale_itemsize else "float32",
-        "params": 0,
-        "layout": f"L{spec.layers}",
-        "sharding": {"out": [], "weights": []},
-        "machine": fp,
-    }
-
-
 @dataclasses.dataclass
 class TwinCosts:
     """The temporal half: every duration the event loop charges.
     `source` records which rung of the resolution ladder priced it —
-    "learned" > "measured" > "analytic" — so reports say where their
-    numbers came from."""
+    "measured" > "analytic" — so reports say where their numbers came
+    from."""
 
     decode_step_s: float = 1e-3       # one decode step (all slots)
     prefill_base_s: float = 1e-3      # per prefill program launch
@@ -277,60 +252,20 @@ class TwinCosts:
             source="measured")
 
     @classmethod
-    def resolve(cls, spec: KVCacheSpec, cfg: Any = None, machine: Any = None,
+    def resolve(cls, spec: KVCacheSpec, machine: Any = None,
                 live_report: Optional[Dict[str, Any]] = None,
                 param_bytes: int = 0, step_floor_s: float = 0.0,
-                model_degree: int = 1, slots: int = 0) -> "TwinCosts":
-        """The pricing ladder: learned model (kinds the twin's own
-        residual rows teach it) > live measurement > analytic roofline.
-        Per-field: a learned kind that never fit falls through alone."""
+                model_degree: int = 1) -> "TwinCosts":
+        """The pricing ladder: live measurement > analytic roofline."""
         out = cls.analytic(spec, machine, param_bytes=param_bytes,
                            step_floor_s=step_floor_s,
                            model_degree=model_degree)
         if live_report is not None:
             out = cls.from_live_report(live_report, out)
-        learned = _learned_costs(spec, cfg, machine,
-                                 slots=slots or spec.slots)
-        if learned:
-            for field, val in learned.items():
-                setattr(out, field, val)
-            out.source = "learned" if len(learned) >= 2 else out.source
-        # a learned/measured step can't beat a simulated device floor
+        # a measured step can't beat a simulated device floor
         out.decode_step_s = max(out.decode_step_s, step_floor_s)
         out.prefill_base_s = max(out.prefill_base_s, step_floor_s)
         return out
-
-
-def _learned_costs(spec: KVCacheSpec, cfg: Any, machine: Any,
-                   slots: int) -> Dict[str, float]:
-    """Query the resolved learned cost model for the twin's op kinds.
-    Missing model / unknown kinds return {} — the ladder falls through."""
-    import os
-    try:
-        from flexflow_tpu.search.learned_cost import (LearnedCostModel,
-                                                      resolve_model_path)
-    except ImportError:
-        return {}
-    path = resolve_model_path(cfg) if cfg is not None else \
-        resolve_model_path(type("_C", (), {"cost_model_path": ""})())
-    if not path or not os.path.isfile(path):
-        return {}
-    try:
-        model = LearnedCostModel.load(path)
-    except Exception:  # noqa: BLE001 — a corrupt model never breaks the twin
-        return {}
-    out: Dict[str, float] = {}
-    for kind, field in (("twin_decode_step", "decode_step_s"),
-                        ("twin_prefill", "prefill_base_s")):
-        feats = _twin_features(kind, spec, slots, machine)
-        try:
-            t = model.predict_features(feats, predicted_s=None,
-                                       roofline_s=None)
-        except Exception:  # noqa: BLE001
-            t = None
-        if t is not None and t > 0:
-            out[field] = float(t)
-    return out
 
 
 # ------------------------------------------------------------- sim replica
@@ -767,41 +702,3 @@ def validate(live: Dict[str, float], twin: Dict[str, float],
     return {"metrics": metrics, "max_rel_err": worst,
             "bound": max_rel_err,
             "ok": bool(metrics) and worst <= max_rel_err}
-
-
-def emit_residual_rows(live_report: Dict[str, Any], costs: TwinCosts,
-                       spec: KVCacheSpec, slots: int,
-                       machine: Any = None) -> int:
-    """Close the calibration loop: emit op/attr telemetry rows pairing the
-    twin's priced step/prefill against the live-measured means, shaped
-    exactly like `PagedKVCache._transfer_row` — tools/refit_cost_model.py
-    folds them into the corpus and the next `TwinCosts.resolve` prices
-    from the refit `twin_*` kinds. Returns the number of rows emitted."""
-    from flexflow_tpu import telemetry as tel
-    from flexflow_tpu.attribution import OP_EVENT, feature_key
-
-    def _mean(m: str) -> Optional[float]:
-        h = (live_report.get("hists") or {}).get(m)
-        if h is None:
-            return None
-        if isinstance(h, dict):
-            return h.get("mean")
-        mean = getattr(h, "mean", None)
-        return mean() if callable(mean) else None
-
-    rows = 0
-    for kind, predicted, metric in (
-            ("twin_decode_step", costs.decode_step_s, "decode_step"),
-            ("twin_prefill", costs.prefill_base_s, "prefill")):
-        measured = _mean(metric)
-        if not measured or measured <= 0:
-            continue
-        features = _twin_features(kind, spec, slots, machine)
-        tel.event(OP_EVENT, cat="op", layer=f"twin/{kind}", op=kind,
-                  candidate="twin", predicted_s=predicted,
-                  measured_s=measured, attributed_s=measured,
-                  roofline_s=predicted, bound="twin", mfu=0.0,
-                  mfu_ceiling=0.0, key=feature_key(features),
-                  features=features, source="twin", bytes=0)
-        rows += 1
-    return rows

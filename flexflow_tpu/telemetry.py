@@ -87,9 +87,7 @@ ANNOTATION_PREFIX = "ff/"
 JAX_SPAN_MIN_NS = 1_000_000
 
 # cost-model drift guardrail: measured/predicted step-time ratios beyond
-# this factor (either direction) flag the calibration as stale — the
-# `[drift]` report sections point at tools/refit_cost_model.py (the
-# self-calibrating loop; `--auto-refit` runs it at fit end)
+# this factor (either direction) flag the calibration as stale
 DRIFT_WARN_RATIO = 3.0
 
 
@@ -104,7 +102,7 @@ class _Sink:
     `telemetry-<pid>.<seq>.jsonl`. Segments are never renamed or deleted
     (concurrent readers — tools/monitor.py tailing the dir — stay valid),
     and read_events() merges every `telemetry-*.jsonl` in the dir
-    ts-sorted, so trace_report / span_dataset / monitor see one stream."""
+    ts-sorted, so trace_report / monitor see one stream."""
 
     def __init__(self, dir_: str, max_bytes: Optional[int] = None):
         os.makedirs(dir_, exist_ok=True)
@@ -632,10 +630,7 @@ def drift_stats(predicted_s: Optional[float],
     more than one exists it is excluded and the rest reduce by MEDIAN;
     warn only trips (past DRIFT_WARN_RATIO in either direction) when at
     least one post-compilation window exists — a 1-epoch fit reports the
-    ratio for the record but can't distinguish drift from compile cost.
-    A tripped warn is the cue to refit the learned cost model from this
-    run's telemetry (tools/refit_cost_model.py; `--auto-refit` does it
-    automatically at fit end)."""
+    ratio for the record but can't distinguish drift from compile cost."""
     ws = [(int(n), float(t)) for n, t in windows if n > 0 and t > 0.0]
     steady = ws[1:] if len(ws) >= 2 else ws
     measured = statistics.median(t / n for n, t in steady) if steady \
@@ -689,6 +684,5 @@ def format_drift(d: Dict[str, Any]) -> List[str]:
         lines.append(
             f"[drift] WARNING: measured/predicted ratio {d['ratio']:.2f}x "
             f"outside [1/{DRIFT_WARN_RATIO:g}, {DRIFT_WARN_RATIO:g}] — the "
-            "cost model has drifted; refit from this run's telemetry with "
-            "tools/refit_cost_model.py (or pass --auto-refit)")
+            "cost model has drifted")
     return lines

@@ -1,12 +1,12 @@
 """Per-op performance attribution (ISSUE 7): op-level measured vs predicted
-vs roofline joins, the per-op drift top-K, the telemetry→dataset pipeline,
+vs roofline joins, the per-op drift top-K, the op/attr telemetry rows,
 and the CI wiring of the new tools' --check smokes.
 
 Acceptance anchors: per-op attributed times sum to the measured step time
 within attribution.SUM_TOLERANCE on the gpt2 CPU twin (single-device data
-mesh, sharded mesh, and pipelined S=2), dataset rows round-trip through
-span_dataset with stable feature keys, and the drift top-K is populated
-after a fit with telemetry on.
+mesh, sharded mesh, and pipelined S=2), a profiled fit's op/attr rows carry
+stable feature keys and reach trace_report, and the drift top-K is
+populated after a fit with telemetry on.
 """
 
 import os
@@ -18,7 +18,6 @@ import pytest
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "tools"))
 
 import profile_attribution
-import span_dataset
 import trace_report
 
 from flexflow_tpu import (FFConfig, FFModel, LossType, SGDOptimizer,
@@ -74,7 +73,7 @@ def test_attribution_gpt2_twin(devices, tmp_path):
     td = report["top_drift"]
     assert td["rows"] and td["rows"][0]["layer"]
     assert 0.0 < td["explained"] <= 1.0 + 1e-9
-    # attribution emitted the op/attr corpus events
+    # attribution emitted the op/attr events
     tel.flush()
     evs = tel.read_events(tdir)
     assert any(e.get("name") == attribution.OP_EVENT for e in evs)
@@ -158,35 +157,26 @@ def test_attribution_pipelined_s2(devices, tmp_path):
     tel.shutdown()
 
 
-# ------------------------------------------------- telemetry -> dataset
-def test_span_dataset_roundtrip_from_profiled_fit(devices, tmp_path):
+# ------------------------------------------------ telemetry -> trace_report
+def test_profiled_fit_op_rows_reach_trace_report(devices, tmp_path):
     cm, tdir = _gpt2_twin_fit(tmp_path, "corpus", profile_ops=True)
     tel.flush()
-    out = str(tmp_path / "corpus.jsonl")
-    rows = span_dataset.build(tdir, out_path=out, quiet=True)
-    assert rows, "profiled fit (--profile-ops) grew no corpus"
-    back = span_dataset.read_jsonl(out)
-    assert len(back) == len(rows)
-    for r in back:
-        # stable feature keys: recomputing from the round-tripped features
-        # reproduces the dedup key
-        assert attribution.feature_key(r["features"]) == r["key"]
-        assert r["n"] >= 1 and r["measured_s"]["mean"] is not None
-        assert r["predicted_s"] is not None
-        assert r["roofline_s"] is not None
-    # identical ops across the model (none in the 1-block twin's blocks,
-    # but keys must at least be unique per row)
-    assert len({r["key"] for r in back}) == len(back)
-    # trace_report surfaces the same events in its [ops] section
+    # trace_report surfaces the fit's op/attr events in its [ops] section
     rep = trace_report.render(tdir, out_path=None, quiet=True)
     assert rep["ops"], "trace_report found no op/attr rows"
+    for r in rep["ops"]:
+        # stable feature keys: recomputing from the features that went
+        # through the telemetry file reproduces the key
+        assert attribution.feature_key(r["features"]) == r["key"]
+        assert r["predicted_s"] is not None
+        assert r["roofline_s"] is not None
     assert rep["op_drift"], "trace_report found no op/drift_topk event"
     tel.shutdown()
 
 
 def test_feature_key_dedups_structural_twins(devices):
     """Two identically-shaped layers (different names) produce the SAME
-    feature key — the corpus dedups structural twins — while a different
+    feature key — structural twins share one — while a different
     shape changes the key."""
     from flexflow_tpu.parallel.machine import MachineSpec
     from flexflow_tpu.search.candidates import layer_candidates
@@ -561,16 +551,9 @@ def test_perf_probe_emits_into_sink(tmp_path):
 
 
 # ------------------------------------------------------------- CI wiring
-def test_span_dataset_check_smoke():
-    """tools/span_dataset.py --check wired into tier-1 (the --check
-    convention of the operator tools)."""
-    assert span_dataset.main(["--check"]) == 0
-    assert not tel.enabled()
-
-
 def test_profile_attribution_check_smoke():
     """tools/profile_attribution.py --check: the ISSUE 7 acceptance chain
     (attributed sums to step within 15%, full rows, drift top-K named,
-    non-empty corpus) on the gpt2 CPU twin."""
+    op/attr rows in the telemetry dir) on the gpt2 CPU twin."""
     assert profile_attribution.main(["--check"]) == 0
     assert not tel.enabled()

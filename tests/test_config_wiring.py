@@ -432,3 +432,34 @@ def test_remat_and_fused_kernel_flags_wired():
     with pytest.raises(ValueError, match="unknown remat policies"):
         Cfg.parse_args(["--remat-policies", "none,sometimes"])
     assert "--remat-policies" in Cfg.launcher_value_flags()
+
+
+@pytest.mark.parametrize("argv", [["--simulator-mode", "learned"],
+                                  ["--cost-model-path", "x"],
+                                  ["--auto-refit"]])
+def test_the_learned_tiers_flags_are_refused(argv, capsys):
+    """PR 60 took the learned pricing tier out: argparse refuses the
+    `--simulator-mode` value itself (exit 2), and its two flags are refused
+    by name as every flag of ours that is gone (`parse_known_args` would
+    take them for the user script's)."""
+    with pytest.raises(SystemExit) as exc:
+        FFConfig.parse_args(argv)
+    # argparse exits 2 and names the flag on stderr; the table's refusal
+    # is the exit's own message
+    assert argv[0] in capsys.readouterr().err + str(exc.value.code)
+    assert (exc.value.code == 2) == (argv[0] == "--simulator-mode")
+    assert FFConfig.parse_args(["--simulator-mode", "taskgraph"]) \
+        .simulator_mode == "taskgraph"
+
+
+def test_the_default_strategy_cache_key_is_the_parents():
+    """The key of a default configuration, taken at PR 59's tree (where the
+    learned tier's fingerprint was appended only when that tier ran): a
+    strategy stored before PR 60 is still found."""
+    from flexflow_tpu.parallel.machine import MachineSpec
+    from flexflow_tpu.search import strategy_cache as sc
+
+    m = _tiny(FFConfig(batch_size=16))
+    mach = MachineSpec(mesh_axes={"data": 2, "model": 2}, chip="v5e")
+    assert sc.cache_key(m, mach, m.config) == \
+        "0b38863d2a4520803bed194657dcc37b"

@@ -3,12 +3,12 @@
 Covers the control-plane pieces in isolation (no engines): the lifted
 AdmissionControl policy brain, exact cross-replica histogram merges, the
 merged SLO scoreboard vs a union-fed tracker, the least-loaded/burn-aware
-router, rolling-swap cursor gating + rollback-on-burn, and the autotuned
-`--kv-prefetch-ahead` derivation (flag = fallback, learned model =
-authority). Two real engines then serve through the fleet: a
-single-replica fleet is bitwise the plain scheduler, a disaggregated pair
-hands every request's KV pages off once and serves the colocated pair's
-tokens, and a rollout swaps every replica under load without a drop.
+router and rolling-swap cursor gating + rollback-on-burn. Two real engines
+then serve through the fleet: a single-replica fleet is bitwise the plain
+scheduler, a tiered scheduler's rotation lead is `--kv-prefetch-ahead`
+throughout, a disaggregated pair hands every request's KV pages off once
+and serves the colocated pair's tokens, and a rollout swaps every replica
+under load without a drop.
 """
 
 import time
@@ -19,8 +19,7 @@ import pytest
 from flexflow_tpu.health import SLOTracker, parse_slo
 from flexflow_tpu.serving import (AdmissionControl, FleetRouter,
                                   Request, RollingSwapController,
-                                  derive_prefetch_ahead, merge_histograms,
-                                  merge_slo_trackers)
+                                  merge_histograms, merge_slo_trackers)
 from flexflow_tpu.serving.fleet import ReplicaHandle
 from flexflow_tpu.serving.reqtrace import StreamingHistogram
 
@@ -346,41 +345,6 @@ def test_rolling_swap_no_burn_objectives_never_rolls_back():
     assert not ctl.rollbacks and not ctl.halted
 
 
-# ------------------------------------------------- prefetch-ahead autotune
-def test_derive_prefetch_ahead_pinned_math():
-    """The autotuned rotation lead is ceil(learned kv_transfer seconds /
-    measured decode-step seconds), clamped to [1, 64]; the flag value is
-    the FALLBACK when either side of the ratio is unavailable."""
-    assert derive_prefetch_ahead(0.01, 0.002, 4) == 5     # ceil(5.0)
-    assert derive_prefetch_ahead(0.0101, 0.002, 4) == 6   # ceil(5.05)
-    assert derive_prefetch_ahead(0.0001, 0.1, 4) == 1     # floor clamp
-    assert derive_prefetch_ahead(10.0, 0.001, 4) == 64    # ceiling clamp
-    assert derive_prefetch_ahead(None, 0.002, 4) == 4     # no learned model
-    assert derive_prefetch_ahead(0.01, None, 7) == 7      # no step sample
-    assert derive_prefetch_ahead(0.01, 0.0, 3) == 3       # degenerate step
-
-
-def test_scheduler_autotune_closes_loop_once():
-    """First measured decode step re-derives the lead from the learned
-    kv_transfer coefficient; later (noisier) steps leave it alone."""
-    from flexflow_tpu.serving.scheduler import ContinuousBatchingScheduler
-    s = ContinuousBatchingScheduler.__new__(ContinuousBatchingScheduler)
-    s._autotune_transfer_s = 0.01
-    s._autotuned = False
-    s.prefetch_ahead = 4
-    s._maybe_autotune(0.002)
-    assert s.prefetch_ahead == 5
-    s._maybe_autotune(0.0001)                 # second sample: ignored
-    assert s.prefetch_ahead == 5
-    # no learned model resolved -> the flag value stays authoritative
-    s2 = ContinuousBatchingScheduler.__new__(ContinuousBatchingScheduler)
-    s2._autotune_transfer_s = None
-    s2._autotuned = False
-    s2.prefetch_ahead = 4
-    s2._maybe_autotune(0.002)
-    assert s2.prefetch_ahead == 4
-
-
 # ------------------------------------------------- shared-runtime engine proxy
 @pytest.mark.parametrize("call", ["prefill", "prefill_first_tokens",
                                   "decode_step"])
@@ -483,6 +447,25 @@ def test_single_replica_fleet_is_the_plain_scheduler(fleet_env):
     # every window; the plain scheduler no more often than that
     assert fs.stats["drains"] == fs.materializations
     assert sched.stats["drains"] <= fs.stats["drains"]
+
+
+def test_tiered_schedulers_prefetch_lead_is_the_flags_value(
+        fleet_env, monkeypatch):
+    """`--kv-prefetch-ahead` is the lead a tiered scheduler rotates by,
+    when it is built and after it has timed decode steps: nothing
+    re-derives it during a run."""
+    from flexflow_tpu.serving import (ContinuousBatchingScheduler,
+                                      gpt2_prompt_inputs, gpt2_step_inputs)
+
+    (eng, _), gc, _, _ = fleet_env
+    monkeypatch.setattr(eng.cfg, "kv_prefetch_ahead", 3)
+    sched = ContinuousBatchingScheduler(eng, eng.params, gpt2_prompt_inputs,
+                                        gpt2_step_inputs, eos_id=None,
+                                        dispatch_ahead=4)
+    assert sched.tiered and sched.prefetch_ahead == 3
+    done = sched.run(_trace(gc, 8, 1, eng.max_decode_len))
+    assert len(done) == 8 and sched.step_times
+    assert sched.prefetch_ahead == 3
 
 
 def test_disagg_hands_every_request_off_once(fleet_env):

@@ -117,8 +117,9 @@ def test_goodput_accounts_fit_wall(devices, kind):
 def test_goodput_drops_under_heavy_checkpointing(devices, tmp_path, kind):
     """--checkpoint-every-steps 1 forces a durable snapshot per step; the
     lost time must land in the checkpoint bucket (not vanish into
-    residual) and lower goodput vs the unperturbed twin, whose losses the
-    snapshots leave as they were."""
+    residual) and come off goodput, and the snapshots leave the losses of
+    the unperturbed twin as they were. Every assertion reads ONE run's own
+    buckets: two fits timed on a loaded CPU have no order (ROADMAP D19)."""
     x, y = _data(kind=kind)
     cm0 = _build(kind=kind)
     h0 = cm0.fit(x, y, epochs=2, verbose=False)
@@ -129,7 +130,13 @@ def test_goodput_drops_under_heavy_checkpointing(devices, tmp_path, kind):
     np.testing.assert_allclose(_losses(h1), _losses(h0), rtol=1e-6)
     assert heavy["buckets"]["checkpoint"] > 0.0
     assert base["buckets"]["checkpoint"] == pytest.approx(0.0)
-    assert heavy["goodput"] < base["goodput"]
+    ck_share = heavy["buckets"]["checkpoint"] / heavy["wall_s"]
+    assert ck_share > 0.0
+    # the snapshots' time is a bucket beside the residual, and no part of
+    # what goodput counts as productive
+    assert heavy["residual_s"] + heavy["buckets"]["checkpoint"] <= \
+        heavy["wall_s"] * (1.0 + 1e-9)
+    assert heavy["goodput"] <= 1.0 - ck_share + 1e-9
     assert heavy["accounted_frac"] >= 0.95
 
 
@@ -373,7 +380,7 @@ def test_watermark_drift_and_tracker(devices):
 def test_telemetry_rotation_and_readers(tmp_path):
     """Satellite (b): a small --telemetry-max-mb cap rotates the sink to
     numbered segments (no renames — concurrent readers never chase a
-    moved file) and read_events / trace_report / span_dataset read the
+    moved file) and read_events / trace_report read the
     segment family transparently, ts-sorted."""
     tdir = str(tmp_path / "tele")
     try:

@@ -511,10 +511,10 @@ def test_scheduler_never_compiles_the_full_logits_program(gpt2_serve, rng):
 
 
 def test_serve_profile_ops_emits_corpus_rows(gpt2_serve, rng, tmp_path):
-    """--profile-ops on a serving engine (ISSUE 14 satellite): a served
-    batch featurizes its prefill + decode placements into op/attr corpus
-    rows priced by the serving search's OWN cost fns — the learned cost
-    model's only window into the bandwidth-bound seq=1 decode regime."""
+    """--profile-ops on a serving engine: a served batch featurizes its
+    prefill + decode placements into op/attr rows priced by the serving
+    search's OWN cost fns (the bandwidth-bound seq=1 decode regime that
+    training fits never exercise)."""
     from flexflow_tpu import telemetry as tel
     from flexflow_tpu.attribution import OP_EVENT
 

@@ -6,7 +6,7 @@ timelines, segmented transfers, and emergent compute/comm overlap. The tests
 pin the behaviors the closed-form additive model cannot express: gradient
 all-reduces hiding behind the backward pass, POSITION-dependent comm
 exposure (an early layer's grad sync cannot hide — its backward runs last),
-transfer segmentation, and the re-rank/MCMC integration."""
+transfer segmentation, and the re-rank integration."""
 
 import math
 
@@ -15,7 +15,6 @@ import pytest
 from flexflow_tpu import FFConfig, FFModel
 from flexflow_tpu.core.graph import topo_order
 from flexflow_tpu.parallel.machine import MachineSpec
-from flexflow_tpu.search import mcmc
 from flexflow_tpu.search.candidates import layer_candidates
 from flexflow_tpu.search.dp import SearchResult, search_graph
 from flexflow_tpu.search.simulator import (
@@ -47,7 +46,9 @@ def plan(model, machine, shard=()):
     for nm in shard:
         a[nm] = [c.name for c in cls[nm]].index("tp_row:model")
     choices = {nm: cls[nm][i] for nm, i in a.items()}
-    additive = mcmc.assignment_cost(layers, model.input_tensors, a, cls, machine)
+    # the additive cost of this full assignment: the DP with every layer pinned
+    additive = search_graph(model, machine,
+                            pins={nm: c.name for nm, c in choices.items()}).cost
     return choices, additive
 
 
@@ -190,18 +191,6 @@ def test_unity_taskgraph_mode():
     st, stats = unity_optimize(m, mach)
     assert st.op_shardings
     assert stats.best_cost > 0
-
-
-def test_mcmc_taskgraph_evaluator():
-    """MCMC with the event-driven evaluator (the reference's MCMC always
-    scored through its simulator) finds a strategy at least as good under
-    the simulated metric as the all-dp start."""
-    mach = MachineSpec(**MESH22)
-    m = chain_model(d=1024, n=3, b=8, s=128)
-    st, stats = mcmc.mcmc_optimize(m, mach, budget=40, seed=3,
-                                   evaluator="taskgraph")
-    assert stats.best_cost <= stats.init_cost
-    assert st.op_shardings
 
 
 def test_simulator_trace_export_flag(tmp_path, devices):

@@ -104,7 +104,7 @@ def _emit_telemetry(out, **meta):
     is active (--telemetry-dir here, or a prior telemetry.configure in the
     process): one `probe/<variant>` span per measurement, dur = the
     measured per-step time, so probe runs join the same corpus
-    trace_report/span_dataset read instead of living on stdout only
+    trace_report reads instead of living on stdout only
     (ISSUE 7 satellite)."""
     from flexflow_tpu import telemetry as tel
 

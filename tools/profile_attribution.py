@@ -13,8 +13,8 @@ the profile the fit wrote and verifies the acceptance contract end to end:
   * every op row carries predicted cost, measured time, roofline bound
     and MFU,
   * the per-op drift top-K names the worst-mispriced op,
-  * tools/span_dataset.py compiles the run's telemetry dir into a
-    non-empty featurized corpus.
+  * the run's telemetry dir holds the `op/attr` rows
+    (tools/trace_report.py's [ops] section).
 
 Usage:
     python tools/profile_attribution.py [--out attribution.json]
@@ -60,7 +60,7 @@ def run(epochs: int = 3, blocks: int = 2, batch: int = 8,
     import numpy as np
 
     from flexflow_tpu import attribution, telemetry
-    import span_dataset
+    import trace_report
 
     own_tmp = None
     if telemetry_dir is None:
@@ -79,7 +79,8 @@ def run(epochs: int = 3, blocks: int = 2, batch: int = 8,
         cm.fit([ids, pos], y, epochs=max(2, epochs), verbose=False)
         report = cm.op_attribution(print_table=verbose)
         telemetry.flush()
-        corpus = span_dataset.build(telemetry_dir, out_path=None, quiet=True)
+        op_rows = trace_report.op_attr_rows(
+            telemetry.read_events(telemetry_dir))
 
         step = report["step_time_s"]
         att = report["attributed_total_s"]
@@ -103,7 +104,7 @@ def run(epochs: int = 3, blocks: int = 2, batch: int = 8,
                                        if r["bound"] == "bandwidth"),
             "compute_bound_ops": sum(1 for r in rows
                                      if r["bound"] == "compute"),
-            "corpus_rows": len(corpus),
+            "telemetry_op_rows": len(op_rows),
             "outside_layers_s": report["outside_s"],
             "top_ops": [{k: r.get(k) for k in
                          ("layer", "op", "predicted_s", "attributed_s",
@@ -133,7 +134,8 @@ def verify(result: Dict[str, Any], report_rows_checked: bool = True) -> None:
         f"attributed {att:.6f}s vs measured step {step:.6f}s " \
         f"(> {attribution.SUM_TOLERANCE:.0%})"
     assert result["worst_mispriced_op"], "per-op drift top-K is empty"
-    assert result["corpus_rows"] > 0, "span_dataset corpus is empty"
+    assert result["telemetry_op_rows"] > 0, \
+        "the telemetry dir holds no op/attr rows"
     if report_rows_checked:
         for r in result["top_ops"]:
             for k in ("predicted_s", "attributed_s", "roofline_s", "mfu"):
@@ -147,7 +149,7 @@ def _check() -> int:
     print(f"profile_attribution --check OK ({result['rows']} op rows, "
           f"attributed/step={result['attributed_over_step']:.3f}, "
           f"worst={result['worst_mispriced_op']}, "
-          f"corpus={result['corpus_rows']} rows)")
+          f"telemetry={result['telemetry_op_rows']} op/attr rows)")
     return 0
 
 

@@ -151,8 +151,8 @@ def drift_events(events: List[Dict[str, Any]]) -> List[Dict[str, Any]]:
 
 def op_attr_rows(events: List[Dict[str, Any]]) -> List[Dict[str, Any]]:
     """Per-op attribution rows (op/attr events from --profile-ops runs,
-    flexflow_tpu/attribution.py), newest occurrence per (layer, stage) —
-    the [ops] section and the raw material of tools/span_dataset.py."""
+    flexflow_tpu/attribution.py), newest occurrence per (layer, stage):
+    the [ops] section."""
     by_op: Dict[Any, Dict[str, Any]] = {}
     for ev in events:
         if ev.get("name") != "op/attr":

@@ -64,7 +64,7 @@ def run(cfg, out_path: str = "") -> Dict[str, Any]:
     if not trace.records:
         raise SystemExit(f"{cfg.twin_trace}: no records")
     spec = spec_from_config(cfg, trace.records, trace.meta)
-    costs = TwinCosts.resolve(spec.kv_spec(), cfg=cfg, slots=spec.slots)
+    costs = TwinCosts.resolve(spec.kv_spec())
     res = simulate(trace.records, spec, costs)
     report = res.report()
     report["trace"] = {"path": cfg.twin_trace, "records": len(trace),
