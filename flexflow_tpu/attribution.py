@@ -395,28 +395,59 @@ def op_scope_map(hlo_text: str, layers) -> Dict[str, OpScope]:
     return out
 
 
-def routing_passes(hlo_text: str, op_types: Dict[str, str]
-                   ) -> Optional[float]:
-    """How often a compiled training step runs an expert layer's routing
-    chain for once that its forward pass does: the `top_k` and the sorts by
-    expert (instructions `sort`, or the CPU's `TopK` call, whose own
-    `op_name` ends in `top_k` or `sort`: a scatter's sort of its indices
-    ends in `scatter-add`) under the `moe_layer` layers, in all phases over
-    the forward phase's. 1 where every checkpoint around the layer keeps
-    the decision (`moe_ops.ROUTING_KEPT`), 3 where the layer's block and
-    the `remat_blocks` unit around it each decide again. None for a program
-    with no such instruction in a forward phase."""
-    by_phase: Dict[str, int] = {}
+def _decides(i: _Instr, op_type: str) -> bool:
+    """A `top_k` or a sort by expert of an expert layer's routing chain:
+    an instruction `sort`, or the CPU's `TopK` call, whose own `op_name`
+    ends in `top_k` or `sort` (a scatter's sort of its indices ends in
+    `scatter-add`)."""
+    return op_type == "moe_layer" and i.opcode in ("sort", "custom-call") \
+        and _segments(i.op_name)[-1] in ("top_k", "sort")
+
+
+def _runs_flash_forward(i: _Instr, op_type: str) -> bool:
+    """A call of the flash forward kernel under one of the graph's layers."""
+    return bool(op_type) and i.opcode == "custom-call" \
+        and fold_name(i.name) == "ff_flash_attention_fwd"
+
+
+# what a checkpoint around an op may keep (`OpDef.kept_names`), by the
+# instructions that run again where it does not
+_KEPT_WORK = {"moe_routing_passes": _decides,
+              "flash_fwd_passes": _runs_flash_forward}
+
+
+def step_passes(hlo_text: str, op_types: Dict[str, str]) -> Dict[str, float]:
+    """How often a compiled training step runs a piece of work for once
+    that its forward pass does: the instructions of each kind of
+    `_KEPT_WORK` in all phases over those of the forward phase (a
+    checkpoint's recomputation carries the backward pass's wrappers). A
+    kind with no instruction in a forward phase is left out.
+
+    `moe_routing_passes`: 1 where every checkpoint around an expert layer
+    keeps the decision (`moe_ops.ROUTING_KEPT`), 3 where the layer's block
+    and the `remat_blocks` unit around it each decide again.
+    `flash_fwd_passes`: 1 with no checkpoint around the flash call, and
+    where the one around it keeps what the forward kernel wrote
+    (`flash_attention.FLASH_KEPT`: a `remat_blocks` unit's); 2 where the
+    recomputation runs the kernel again; absent from every CPU program,
+    whose kernels are interpreted."""
+    by_phase: Dict[str, Dict[str, int]] = {kind: {} for kind in _KEPT_WORK}
     for items in _parse_computations(hlo_text)[0].values():
         for i in items:
-            if i.opcode not in ("sort", "custom-call") or not i.op_name \
-                    or _segments(i.op_name)[-1] not in ("top_k", "sort"):
+            if not i.op_name:
                 continue
             layer, phase = scope_of_op_name(i.op_name, op_types)
-            if op_types.get(layer) == "moe_layer":
-                by_phase[phase] = by_phase.get(phase, 0) + 1
-    forward = by_phase.get("forward", 0)
-    return sum(by_phase.values()) / forward if forward else None
+            for kind, counted in _KEPT_WORK.items():
+                if counted(i, op_types.get(layer, "")):
+                    seen = by_phase[kind]
+                    seen[phase] = seen.get(phase, 0) + 1
+    return {kind: sum(seen.values()) / seen["forward"]
+            for kind, seen in by_phase.items() if seen.get("forward")}
+
+
+def routing_passes(hlo_text: str, op_types: Dict[str, str]
+                   ) -> Optional[float]:
+    return step_passes(hlo_text, op_types).get("moe_routing_passes")
 
 
 # ------------------------------------------------------------- the registry
@@ -463,11 +494,10 @@ class Program:
                    layers=len(self.op_types),
                    layers_named=len({s.layer for s in self.scopes.values()
                                      if s.layer}))
-            # a training step with expert layers: a property of the program
+            # a training step: how often it runs what a checkpoint around
+            # an op may keep, a property of the program
             if any(s.phase == "backward" for s in self.scopes.values()):
-                passes = routing_passes(text, self.op_types)
-                if passes is not None:
-                    sp.set(moe_routing_passes=passes)
+                sp.set(**step_passes(text, self.op_types))
         return self.scopes
 
 

@@ -53,11 +53,19 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 
 from flexflow_tpu import telemetry as tel
 
 _BLOCK_CANDIDATES = (1024, 512, 256, 128)
+# the `checkpoint_name` of what a differentiated call's forward kernel writes
+# (`o`, and the rows' logsumexp): the residuals the two backward kernels read
+# as they are. A `jax.checkpoint` whose policy keeps the name (a
+# `remat_blocks` unit's, through `multihead_attention`'s `kept_names`) does
+# not run `ff_flash_attention_fwd` again in its recomputation; under any
+# other checkpoint, and under none, the name lowers to nothing
+FLASH_KEPT = "ff_flash_residuals"
 _NEG_INF = float("-inf")
 # what a pair outside the band scores in a block BEFORE the step's own: a
 # row may meet such a block with no pair inside the band, and a maximum of
@@ -719,7 +727,13 @@ def _flash(q, k, v, causal, scale, window):
 
 
 def _flash_fwd(q, k, v, causal, scale, window):
+    """The forward rule: both results of the kernel named `FLASH_KEPT` (a
+    recomputation drops the call only where every result is kept). `lse` is
+    named FLAT: as `(b, h, s, 1)` float32 the chip pads its last dimension
+    to 128 lanes, 128 times its size where it survives a recomputation."""
     o, lse = _fwd(q, k, v, causal, scale, window)
+    o = checkpoint_name(o, FLASH_KEPT)
+    lse = checkpoint_name(lse.reshape(-1), FLASH_KEPT).reshape(lse.shape)
     return o, (q, k, v, o, lse)
 
 

@@ -96,6 +96,7 @@ if TYPE_CHECKING:
 from flexflow_tpu import telemetry as tel
 from flexflow_tpu.core.tensor import TensorSpec
 from flexflow_tpu.kernels import sparse_attend_chunk, sparse_attend_step
+from flexflow_tpu.kernels.flash_attention import FLASH_KEPT
 from flexflow_tpu.kernels.partition import dividing, multi_device, per_shard
 from flexflow_tpu.ops.norm_ops import rms_norm
 from flexflow_tpu.ops.op_type import OperatorType
@@ -980,7 +981,8 @@ def _mha_span_facts(layer: Layer) -> dict:
 
 register_op(OperatorType.MULTIHEAD_ATTENTION, _mha_infer, _mha_lower, _mha_flops,
             serving_params=_mha_serving_params, state_kind="paged_kv",
-            page_state=_mha_page_state, span_facts=_mha_span_facts)
+            page_state=_mha_page_state, span_facts=_mha_span_facts,
+            kept_names=(FLASH_KEPT,))
 
 
 def _sdpa_infer(layer: Layer):

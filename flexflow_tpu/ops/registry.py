@@ -126,9 +126,10 @@ class OpDef:
     uncast_weights: tuple = ()
     # the `jax.ad_checkpoint.checkpoint_name`s under which a training
     # lowering of the op tags what is cheap to keep and dear to make again
-    # (an expert layer's routing decision): a checkpoint around the op
-    # keeps them (`compiler/lowering.run_block`:
-    # `save_only_these_names`), and recomputes the rest
+    # (an expert layer's routing decision; what the flash forward kernel
+    # of an attention layer wrote): a checkpoint around the op keeps them
+    # (`compiler/lowering.run_block`: `save_only_these_names`), and
+    # recomputes the rest
     kept_names: tuple = ()
 
     def flop_count(self, layer: Layer) -> float:

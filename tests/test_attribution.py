@@ -356,6 +356,38 @@ def test_op_scope_map_credits_a_fusion_to_its_one_dot():
     assert not {"a", "zero", "init", "out", "dot.5", "lt"} & set(scopes)
 
 
+STEP_WITH_A_UNIT = """HloModule jit_step, is_scheduled=true
+
+ENTRY %main.9 (a: f32[8,8]) -> f32[8,8] {
+  %a = f32[8,8]{1,0} parameter(0)
+  %ff_flash_attention_fwd.1 = (f32[8,8]{1,0}, f32[8]{0}) custom-call(%a), custom_call_target="tpu_custom_call", metadata={op_name="jit(step)/jvp()/checkpoint/attn/jit(_fwd_call)/pallas_call"}
+  %ff_flash_attention_fwd.2 = (f32[8,8]{1,0}, f32[8]{0}) custom-call(%a), custom_call_target="tpu_custom_call", metadata={op_name="jit(step)/jvp()/checkpoint/attn2/jit(_fwd_call)/pallas_call"}
+%AGAIN%  %ff_flash_attention_dq.3 = f32[8,8]{1,0} custom-call(%a), custom_call_target="tpu_custom_call", metadata={op_name="jit(step)/transpose(jvp())/checkpoint/attn/jit(_dq_call)/pallas_call"}
+  ROOT %out = f32[8,8]{1,0} copy(%ff_flash_attention_dq.3), metadata={op_name="jit(step)/ff.update/sub"}
+}
+"""
+AGAIN = '  %ff_flash_attention_fwd.5 = (f32[8,8]{1,0}, f32[8]{0}) ' \
+    'custom-call(%a), custom_call_target="tpu_custom_call", ' \
+    'metadata={op_name="jit(step)/transpose(jvp())/checkpoint/' \
+    'rematted_computation/LAYER/jit(_fwd_call)/pallas_call"}\n'
+
+
+@pytest.mark.parametrize("again,passes", [
+    ("", 1.0), (AGAIN.replace("LAYER", "attn"), 1.5),
+    (AGAIN.replace("LAYER", "attn") + AGAIN.replace("LAYER", "attn2"), 2.0)])
+def test_step_passes_counts_the_recomputations_flash_calls(again, passes):
+    """The forward kernel's calls under the graph's layers, all phases over
+    the forward's: a checkpoint's recomputation carries the backward
+    pass's wrapper. A program without the kernel says nothing."""
+    op_types = {"attn": "multihead_attention", "attn2": "multihead_attention"}
+    text = STEP_WITH_A_UNIT.replace("%AGAIN%", again)
+    assert attribution.step_passes(text, op_types) \
+        == {"flash_fwd_passes": passes}
+    assert attribution.step_passes(
+        text.replace("ff_flash_attention_fwd", "fusion"), op_types) == {}
+    assert attribution.routing_passes(text, op_types) is None
+
+
 def test_join_ambiguous_unattributed_and_containers():
     S = attribution.OpScope
     prefill = {"fusion.1": S("attn", "multihead_attention", "forward",

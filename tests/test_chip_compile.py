@@ -1285,7 +1285,9 @@ def test_trinity_train_step_fits_one_chip(described_devices, mosaic):
     decision of an expert layer made ONCE a block (PR 59: the `top_k` and
     the sorts under the `moe_layer` scopes in the chip's compiled text, all
     phases over the forward's; 3 before: the `remat_blocks` unit's
-    recomputation and the block's each decided again)."""
+    recomputation and the block's each decided again), and the flash
+    forward kernel run ONCE a layer (PR 61: the unit keeps what it writes,
+    `lse` flat, so the five kept pairs cost 0.68 GB and not 2 GB)."""
     sys.path.insert(0, os.path.join(ROOT, "benchmarks"))
     from families import family_of
     from harness import manifest as mf
@@ -1317,13 +1319,22 @@ def test_trinity_train_step_fits_one_chip(described_devices, mosaic):
     held = sum(int(np.prod(s.shape)) for s in jax.tree_util.tree_leaves(params))
     assert held + 4 * 128 == 705_474_304
     assert m.argument_size_in_bytes >= 12 * held
+    # the room the next kept tensor is sized from (`CHANGES.md` quotes it)
+    print("trinity step: arguments", m.argument_size_in_bytes, "+ temporaries",
+          m.temp_size_in_bytes, "=",
+          m.argument_size_in_bytes + m.temp_size_in_bytes)
     assert m.argument_size_in_bytes + m.temp_size_in_bytes < 15e9, m
     text = compiled.as_text()
     assert not re.search(r"\[(\d+,)*8192,8192\]", text)
+    assert "ff_moe_rows" in text
+    # five attention layers: each kernel once a layer, the forward one too
+    # (PR 61: the unit keeps its `o` and `lse`; ten calls before)
     for kernel in ("ff_flash_attention_fwd", "ff_flash_attention_dq",
-                   "ff_flash_attention_dkv", "ff_moe_rows"):
-        assert kernel in text, kernel
+                   "ff_flash_attention_dkv"):
+        calls = re.findall(rf"%{kernel}[.\d]* = \S.* custom-call\(", text)
+        assert len(calls) == 5, (kernel, len(calls))
     from flexflow_tpu import attribution
 
-    assert attribution.routing_passes(
-        text, {l.name: l.op_type.value for l in model.layers}) == 1.0
+    op_types = {l.name: l.op_type.value for l in model.layers}
+    assert attribution.step_passes(text, op_types) \
+        == {"moe_routing_passes": 1.0, "flash_fwd_passes": 1.0}

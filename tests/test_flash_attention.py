@@ -4,6 +4,8 @@ Reference capability: fused cuDNN attention (src/ops/attention.cu:35). On the
 CPU test mesh the pallas kernels run in interpreter mode; on TPU they compile.
 """
 
+import collections
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -475,13 +477,95 @@ def test_what_a_window_cannot_be_raises():
         fa.flash_attention(q, q[:, :2], q[:, :2], causal=True)
 
 
+def equations(jaxpr):
+    """Every equation of a jaxpr, through every sub-jaxpr."""
+    for e in jaxpr.eqns:
+        yield e
+        for v in e.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else [v]):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from equations(sub)
+
+
+def kernel_calls(jaxpr):
+    """{kernel name: `pallas_call` equations}."""
+    return dict(collections.Counter(
+        e.params["name"] for e in equations(jaxpr)
+        if e.primitive.name == "pallas_call"))
+
+
+def named(jaxpr, name):
+    """The shapes of what `checkpoint_name` tagged `name`."""
+    return [e.outvars[0].aval.shape for e in equations(jaxpr)
+            if e.primitive.name == "name" and e.params["name"] == name]
+
+
+@pytest.mark.parametrize("window,heads,kv_heads,shards", [
+    (0, 2, 2, 1), (50, 2, 2, 1), (0, 2, 1, 1), (50, 4, 2, 1), (50, 4, 2, 2)])
+def test_a_checkpoint_that_keeps_the_residuals_runs_the_forward_kernel_once(
+        window, heads, kv_heads, shards):
+    """PR 61: the forward rule names `o` and `lse` `FLASH_KEPT`. Under a
+    `jax.checkpoint` whose policy keeps that name the gradient's program
+    holds `ff_flash_attention_fwd` once (twice under one that does not),
+    and dq, dk, dv are the un-checkpointed call's to the bit; so too where
+    the call is a shard's, its heads split over a mesh as `per_shard` in
+    `ops/attention_ops.py` splits them (the name is inside the shard's
+    function). `lse` is named flat: `(b, h, s, 1)` would be padded to 128
+    lanes where it is kept."""
+    import functools
+    import importlib
+
+    from jax.sharding import Mesh, PartitionSpec
+
+    from flexflow_tpu.kernels.partition import per_shard
+
+    fa = importlib.import_module("flexflow_tpu.kernels.flash_attention")
+    seq, depth = 128, 64
+    keys = jax.random.split(jax.random.PRNGKey(window + heads), 4)
+    q = jax.random.normal(keys[0], (1, heads, seq, depth))
+    k = jax.random.normal(keys[1], (1, kv_heads, seq, depth))
+    v = jax.random.normal(keys[2], (1, kv_heads, seq, depth))
+    ct = jax.random.normal(keys[3], q.shape)
+    by_head = PartitionSpec(None, "model")
+    attend = per_shard(
+        functools.partial(fa.flash_attention, causal=True, window=window),
+        Mesh(np.array(jax.devices()[:shards]), ("model",)),
+        (by_head, by_head, by_head), by_head)
+
+    def total(q, k, v):
+        return jnp.sum(attend(q, k, v) * ct)
+
+    keeps = jax.checkpoint_policies.save_only_these_names(fa.FLASH_KEPT)
+    plain = jax.grad(total, (0, 1, 2))
+    kept = jax.grad(jax.checkpoint(total, policy=keeps), (0, 1, 2))
+    again = jax.grad(jax.checkpoint(total), (0, 1, 2))
+    once = {"ff_flash_attention_fwd": 1, "ff_flash_attention_dq": 1,
+            "ff_flash_attention_dkv": 1}
+    traced = jax.make_jaxpr(kept)(q, k, v).jaxpr
+    assert kernel_calls(traced) == once
+    assert kernel_calls(jax.make_jaxpr(plain)(q, k, v).jaxpr) == once
+    assert kernel_calls(jax.make_jaxpr(again)(q, k, v).jaxpr) \
+        == dict(once, ff_flash_attention_fwd=2)
+    assert sorted(set(named(traced, fa.FLASH_KEPT))) \
+        == [(1, heads // shards, seq, depth), (heads // shards * seq,)]
+    # a call that is not differentiated names nothing
+    assert not named(jax.make_jaxpr(total)(q, k, v).jaxpr, fa.FLASH_KEPT)
+    for got, want in zip(jax.jit(kept)(q, k, v), jax.jit(plain)(q, k, v)):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
 # sha256 of the jaxpr of value-and-gradient of a call WITHOUT a window at
 # GPT-2 medium's shape ([8, 16, 1024, 64] bf16, Mosaic path), source
 # locations taken out, as the tree before windows existed traced it (commit
-# 2252dec; /root/scratch/jaxpr_digest.py of PR 58 computed both sides)
+# 2252dec; /root/scratch/jaxpr_digest.py of PR 58 computed both sides), and
+# since PR 61 with what the forward rule adds in every differentiated call:
+# two `name` equations (`FLASH_KEPT`) and `lse`'s reshape to flat and back,
+# which lower to nothing where no checkpoint keeps the name (re-pinned from
+# 96f17117.. / d9044b53..; the four equations are the whole of the diff)
 WINDOWLESS_JAXPR = {
-    True: "96f171176bae6d7a433c8fbfb54cbe8109fd4434ad42a51f531b6a56bdce6589",
-    False: "d9044b53714903a17940f3edf405afd31145a3e17364d164ee2de9748c204b74",
+    True: "6b28aa81cdd4f8768c5678cab61fca3e7d8c63bff91df5aca5e48b302383de5a",
+    False: "79b2b8a4638abfedddfafd0a9c31082943e22f06f84cc88cfbac64f6c1ff21b0",
 }
 
 

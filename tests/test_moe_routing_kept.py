@@ -9,8 +9,16 @@ Here: a tiny afmoe decoder (four expert layers, a part holder with a stateful
 selection bias) whose step holds two token blocks (`MOE_TOKEN_BLOCK` set to
 32), on the CPU. "Patched out" means `moe_ops._kept` returns its argument:
 no tag, so both policies keep nothing and the step is the parent's.
+
+The same hook's second user (PR 61): the flash forward kernel's `o` and `lse`,
+named `flash_attention.FLASH_KEPT` in the call's forward rule and kept by a
+`remat_blocks` unit through `multihead_attention`'s `kept_names`.
+
+Every case of a (remat_blocks, patched, ladder) reads ONE compiled step
+(`steps`, a module's worth): its jaxpr, its text and three runs of it.
 """
 
+import collections
 import sys
 from pathlib import Path
 
@@ -25,15 +33,20 @@ sys.path.insert(0, str(ROOT / "tests"))
 
 from flexflow_tpu import AdamOptimizer, FFModel, attribution  # noqa: E402
 from flexflow_tpu.compiler import lowering  # noqa: E402
+from flexflow_tpu.compiler.compile import build_state_init_fn  # noqa: E402
+from flexflow_tpu.core.graph import topo_order  # noqa: E402
+from flexflow_tpu.kernels.flash_attention import FLASH_KEPT  # noqa: E402
 from flexflow_tpu.models import (GPT2Config, build_afmoe,  # noqa: E402
                                  build_gpt2)
 from flexflow_tpu.ops import get_op_def, moe_ops  # noqa: E402
 from flexflow_tpu.ops.op_type import OperatorType  # noqa: E402
 from flexflow_tpu.ops.registry import LoweringCtx  # noqa: E402
+import test_afmoe  # noqa: E402
 from test_afmoe import batch_of, compiled, ffconfig  # noqa: E402
 from test_afmoe import held_tiny as held_tiny_and_file  # noqa: E402
+from test_flash_attention import equations, kernel_calls  # noqa: E402
 
-EXPERT_LAYERS = 4
+EXPERT_LAYERS, ATTENTION_LAYERS = 4, 5
 
 
 @pytest.fixture
@@ -57,24 +70,70 @@ def held_tiny():
     return held_tiny_and_file()[0]
 
 
-def step_args(cm, g):
-    ids, pos, labels = batch_of(g, 2)
-    return (cm.params, cm.opt_state, cm.state,
-            [jnp.asarray(ids), jnp.asarray(pos)], jnp.asarray(labels),
-            jax.random.PRNGKey(0))
-
-
-def primitives(jaxpr, counts=None):
+def primitives(jaxpr):
     """{primitive name: equations}, through every sub-jaxpr."""
-    counts = {} if counts is None else counts
-    for e in jaxpr.eqns:
-        counts[e.primitive.name] = counts.get(e.primitive.name, 0) + 1
-        for v in e.params.values():
-            for sub in (v if isinstance(v, (list, tuple)) else [v]):
-                sub = getattr(sub, "jaxpr", sub)
-                if hasattr(sub, "eqns"):
-                    primitives(sub, counts)
-    return counts
+    return collections.Counter(e.primitive.name for e in equations(jaxpr))
+
+
+def leaves_after(cm):
+    """Everything a step leaves behind: the parameters, Adam's moments (the
+    gradients), the biases."""
+    return jax.tree_util.tree_leaves(
+        (cm.params, cm.opt_state[0].mu, cm.opt_state[0].nu, cm.state))
+
+
+def three_steps(g, patches, **config):
+    """The tiny model's training step traced ONCE under `patches`
+    ([(module, attribute, value)]), compiled once, and that executable run
+    over three batches: (cm, the step's jaxpr, its compiled text, the three
+    losses, `leaves_after`)."""
+    ids, pos, labels = (jnp.asarray(a) for a in batch_of(g, 6))
+    cm = compiled(g, **config)
+    with pytest.MonkeyPatch.context() as mp:
+        for target, name, value in patches:
+            mp.setattr(target, name, value)
+        traced = cm.train_step.trace(
+            cm.params, cm.opt_state, cm.state, [ids[:2], pos[:2]], labels[:2],
+            jax.random.PRNGKey(0))
+    step, losses = traced.lower().compile(), []
+    for at in range(0, 6, 2):       # the step donates what it updates
+        cm.params, cm.opt_state, cm.state, loss, _ = step(
+            cm.params, cm.opt_state, cm.state,
+            [ids[at:at + 2], pos[at:at + 2]], labels[at:at + 2],
+            jax.random.PRNGKey(0))
+        losses.append(float(loss))
+    return cm, traced.jaxpr.jaxpr, step.as_text(), losses, leaves_after(cm)
+
+
+def assert_the_same_steps(ran, other):
+    """Two `three_steps`: the losses and everything left behind, bit for
+    bit."""
+    assert ran[3] == other[3]
+    assert len(ran[4]) == len(other[4]) > 50
+    for a, b in zip(ran[4], other[4]):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.fixture(scope="module")
+def steps():
+    """(remat_blocks, patched, ladder) -> `three_steps` of the part holder
+    whose step holds two token blocks, built once a module: the counts and
+    the bit-for-bit comparison read the same compiled program."""
+    built = {}
+
+    def step(remat_blocks, patched, ladder):
+        key = (remat_blocks, patched, ladder)
+        if key not in built:
+            patches = [(moe_ops, "MOE_TOKEN_BLOCK", 32)]
+            if ladder:
+                patches.append((moe_ops, "MOE_MIN_RUNG_ROWS", 4))
+            if patched:
+                patches.append((moe_ops, "_kept", lambda x, training: x))
+            built[key] = three_steps(held_tiny(), patches,
+                                     remat_blocks=remat_blocks)
+        return built[key]
+
+    return step
 
 
 # (remat_blocks, tagging patched out) -> how often the step decides: the
@@ -86,19 +145,14 @@ PASSES = {(False, False): 1, (True, False): 1,
 @pytest.mark.parametrize("ladder", (False, True))
 @pytest.mark.parametrize("remat_blocks, patched", sorted(PASSES))
 def test_a_training_step_decides_once_a_block_a_layer(
-        two_blocks, monkeypatch, remat_blocks, patched, ladder):
+        steps, remat_blocks, patched, ladder):
     """The whole step (`value_and_grad` and the update) holds one `top_k`
     and one pair of sorts (the order by expert, and its inverse where the
     whole block's buffer is combined) a token block a layer: `lax.map`
     traces a block once, so one of each a layer. Without the tags: twice
     (the block's checkpoint), three times under `remat_blocks`."""
-    two_blocks(rung_rows=4 if ladder else None)
-    if patched:
-        patch_out(monkeypatch)
-    g = held_tiny()
-    cm = compiled(g, remat_blocks=remat_blocks)
-    args = step_args(cm, g)
-    counts = primitives(jax.make_jaxpr(cm.train_step)(*args).jaxpr)
+    cm, jaxpr, text, _, _ = steps(remat_blocks, patched, ladder)
+    counts = primitives(jaxpr)
     passes = PASSES[remat_blocks, patched]
     assert counts["top_k"] == EXPERT_LAYERS * passes
     assert counts["sort"] == 2 * EXPERT_LAYERS * passes
@@ -106,33 +160,59 @@ def test_a_training_step_decides_once_a_block_a_layer(
     assert ("cond" in counts) == ladder
     assert ("name" in counts) == (not patched)
     # the engagement counter, from the compiled program's own text
-    text = cm.train_step.lower(*args).compile().as_text()
     assert attribution.routing_passes(
         text, {l.name: l.op_type.value for l in cm.model.layers}) == passes
 
 
 @pytest.mark.parametrize("remat_blocks", (False, True))
-def test_losses_and_every_gradient_are_the_untagged_steps(
-        two_blocks, monkeypatch, remat_blocks):
+def test_losses_and_every_gradient_are_the_untagged_steps(steps,
+                                                          remat_blocks):
     """The same program but for what is rerun: the same integers, so the
     same rows in the same order through the same rung. Losses, Adam's
     moments (the gradients), the parameters and the biases after three
     steps, bit for bit."""
-    two_blocks(rung_rows=4)
+    assert_the_same_steps(steps(remat_blocks, False, True),
+                          steps(remat_blocks, True, True))
+
+
+def flash_tiny():
+    """The part holder cut to two layers at 128 positions (a dense one
+    under the window of 8, an expert one that sees every key; grouped K/V
+    heads in both), whose attention goes through the flash kernels
+    (interpreted here) as `test_afmoe.py::
+    test_the_flash_path_is_taken_under_a_window_and_says_so` forces one."""
     g = held_tiny()
-    ids, pos, labels = batch_of(g, 6)
-    seen = []
-    for patched in (False, True):
-        if patched:
-            patch_out(monkeypatch)
-        cm = compiled(g, remat_blocks=remat_blocks)
-        hist = cm.fit([ids, pos], labels, epochs=1, verbose=False)
-        seen.append(([h["loss"] for h in hist], cm.params,
-                     cm.opt_state[0].mu, cm.opt_state[0].nu, cm.state))
-    kept, plain = (jax.tree_util.tree_leaves(s) for s in seen)
-    assert len(kept) == len(plain) > 50
-    for a, b in zip(kept, plain):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    g.seq, g.layer_types = 128, ("sliding_attention", "full_attention")
+    return g
+
+
+def forced_flash(build):
+    """`build_afmoe` with `impl="flash"` on every attention layer."""
+    def built(model, g, **kw):
+        out = build(model, g, **kw)
+        for layer in model.layers:
+            if layer.op_type == OperatorType.MULTIHEAD_ATTENTION:
+                layer.params["impl"] = "flash"
+        return out
+    return built
+
+
+def test_a_unit_keeps_what_the_flash_forward_kernel_wrote(monkeypatch):
+    """`remat_blocks` over attention layers that run the flash kernels: the
+    step holds `ff_flash_attention_fwd` once a layer, beside one `_dq` and
+    one `_dkv` (twice with `multihead_attention`'s `kept_names` emptied:
+    the unit's recomputation runs it again), and losses, moments,
+    parameters and biases after three steps are that step's, bit for bit."""
+    monkeypatch.setattr(test_afmoe, "build_afmoe", forced_flash(build_afmoe))
+    mha = get_op_def(OperatorType.MULTIHEAD_ATTENTION)
+    assert mha.kept_names == (FLASH_KEPT,)
+    layers, ran = len(flash_tiny().layer_types), {}
+    for passes, patches in ((1, []), (2, [(mha, "kept_names", ())])):
+        ran[passes] = three_steps(flash_tiny(), patches, remat_blocks=True)
+        assert kernel_calls(ran[passes][1]) == {
+            "ff_flash_attention_fwd": layers * passes,
+            "ff_flash_attention_dq": layers, "ff_flash_attention_dkv": layers}
+    assert_the_same_steps(ran[1], ran[2])
 
 
 def moe_layer_jaxpr(training: bool):
@@ -174,41 +254,60 @@ def test_outside_training_nothing_is_tagged(two_blocks, monkeypatch, ladder):
 
 
 def checkpoint_policies(monkeypatch, build):
-    """The `policy=` of every `jax.checkpoint` that `run_block` makes while
-    a model's training step is traced."""
+    """What every `jax.checkpoint` that `run_block` makes keeps, while a
+    model's training step is traced: the names its policy was made from,
+    None for no policy."""
     seen = []
-    real = jax.checkpoint
+    real, real_names = jax.checkpoint, \
+        jax.checkpoint_policies.save_only_these_names
+
+    def save_only_these_names(*names):
+        policy = real_names(*names)
+        policy.names = names
+        return policy
 
     def checkpoint(fun, **options):
         if fun.__name__ == "_unit":
-            seen.append(options.get("policy"))
+            policy = options.get("policy")
+            seen.append(policy if policy is None else policy.names)
         return real(fun, **options)
 
     monkeypatch.setattr(lowering.jax, "checkpoint", checkpoint)
+    monkeypatch.setattr(lowering.jax.checkpoint_policies,
+                        "save_only_these_names", save_only_these_names)
     m = FFModel(ffconfig(2, remat_blocks=True))
     g = build(m)
     cm = m.compile(AdamOptimizer(alpha=1e-3),
                    loss_type="sparse_categorical_crossentropy", metrics=[])
-    cm.init(seed=3)
+    # traced from shapes: nothing is drawn, no init program compiled
+    params, _ = cm._param_templates()
+    state = jax.eval_shape(
+        build_state_init_fn(topo_order(m.layers), m._initializer_overrides),
+        jax.random.PRNGKey(0))
     ids, pos, labels = batch_of(g, 2)
     ins = [jnp.asarray(ids), jnp.asarray(pos)][:len(m.input_tensors)]
-    jax.make_jaxpr(cm.train_step)(cm.params, cm.opt_state, cm.state, ins,
-                                  jnp.asarray(labels), jax.random.PRNGKey(0))
+    jax.make_jaxpr(cm.train_step)(
+        params, jax.eval_shape(cm.tx.init, params), state, ins,
+        jnp.asarray(labels), jax.random.PRNGKey(0))
     return seen
 
 
 def test_a_unit_whose_ops_name_nothing_has_no_policy(monkeypatch):
-    """`remat_blocks` over a dense model: every unit is checkpointed with
-    `policy=None`, as before PR 59 (the program's text is the parent's).
-    Over the expert model: the units that hold an expert layer, and only
-    those, keep `ROUTING_KEPT`."""
+    """`remat_blocks` over a dense model: a unit is checkpointed with
+    `policy=None`, as before PR 59, unless it holds an attention layer,
+    whose flash call's residuals it keeps (PR 61; where the layer takes the
+    einsum form nothing bears the name and nothing is kept). Over the
+    expert model: the units that hold an expert layer, and only those,
+    keep `ROUTING_KEPT`."""
     def gpt2(m):
         g = GPT2Config.tiny()
         build_gpt2(m, g, batch=2)
         return g
 
     dense = checkpoint_policies(monkeypatch, gpt2)
-    assert len(dense) > 3 and all(p is None for p in dense)
+    assert dense.count((FLASH_KEPT,)) == GPT2Config.tiny().layers
+    assert dense.count(None) >= 3
+    assert set(dense) == {None, (FLASH_KEPT,)}
 
     def afmoe(m):
         g = held_tiny()
@@ -216,8 +315,9 @@ def test_a_unit_whose_ops_name_nothing_has_no_policy(monkeypatch):
         return g
 
     expert = checkpoint_policies(monkeypatch, afmoe)
-    assert sum(p is not None for p in expert) == EXPERT_LAYERS
-    assert sum(p is None for p in expert) > EXPERT_LAYERS
+    assert expert.count((moe_ops.ROUTING_KEPT,)) == EXPERT_LAYERS
+    assert expert.count((FLASH_KEPT,)) == ATTENTION_LAYERS
+    assert expert.count(None) > EXPERT_LAYERS
     assert get_op_def(OperatorType.MOE_LAYER).kept_names \
         == (moe_ops.ROUTING_KEPT,)
     assert get_op_def(OperatorType.LINEAR).kept_names == ()
