@@ -320,6 +320,21 @@ class FFModel:
              "chunk": int(chunk), "n_groups": int(n_groups), "eps": eps},
             ins, name, initializers)[0]
 
+    def mamba(self, input: Tensor, d_inner: int, d_state: int, dt_rank: int,
+              d_conv: int = 4, eps: float = 1e-6,
+              valid: Optional[Tensor] = None,
+              initializers: Optional[Dict[str, Any]] = None,
+              name=None) -> Tensor:
+        """Mamba-1 mixer over `[batch, seq, d]`: the selective scan over a
+        `[d_state, d_inner]` state (ops/mamba_ops.py). `valid` `[batch, seq]`
+        int: which positions hold a token."""
+        ins = [input] + ([valid] if valid is not None else [])
+        return self._add_layer(
+            OperatorType.MAMBA,
+            {"d_inner": int(d_inner), "d_state": int(d_state),
+             "dt_rank": int(dt_rank), "d_conv": int(d_conv), "eps": eps},
+            ins, name, initializers)[0]
+
     def kda(self, input: Tensor, heads: int, head_dim: int, d_conv: int = 4,
             lower_bound: float = -5.0, eps: float = 1e-6,
             valid: Optional[Tensor] = None,

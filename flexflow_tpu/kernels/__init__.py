@@ -28,6 +28,12 @@ the update of a slot's state and the float32 read-out of the new state on one
 tile, the state read from HBM once and written once in place, a slot that is
 not live never touched (ops/ssm_ops.py chooses it where a head's state is
 whole f32 tiles and a B/C group's heads whole sublane tiles).
+selective_scan — the Mamba-1 selective scan of a `[tokens, channels]` block
+from a state: the recurrence stepped a position at a time with the `[N,
+channels]` state in vector registers, `exp(dt A)` made on the tile, the skip
+and the gate applied before the tile is written (forward; ops/mamba_ops.py
+chooses it where the channels are whole 512-lane tiles and gives it the XLA
+form's gradient).
 sparse_attend_step — one decode step's attention over the keys an indexer
 kept, for the live slots: a slot's pages under its position fetched by page
 from the K and V pools where they lie, online softmax under the indexer's

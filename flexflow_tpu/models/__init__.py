@@ -22,6 +22,7 @@ from flexflow_tpu.models.lfm2_moe import Lfm2MoeConfig, build_lfm2_moe
 from flexflow_tpu.models.keye_vl import KeyeVLConfig, build_keye_vl
 from flexflow_tpu.models.mellum import MellumConfig, build_mellum
 from flexflow_tpu.models.afmoe import AfmoeConfig, build_afmoe
+from flexflow_tpu.models.jamba import JambaConfig, build_jamba
 from flexflow_tpu.models.bert import build_bert
 from flexflow_tpu.models.moe import build_moe_mlp
 from flexflow_tpu.models.inception import build_inception_v3
@@ -43,4 +44,5 @@ __all__ = [
     "build_keye_vl", "KeyeVLConfig",
     "build_mellum", "MellumConfig",
     "build_afmoe", "AfmoeConfig",
+    "build_jamba", "JambaConfig",
 ]

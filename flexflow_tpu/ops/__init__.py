@@ -23,6 +23,7 @@ from flexflow_tpu.ops import (  # noqa: F401
     attention_ops,
     moe_ops,
     ssm_ops,
+    mamba_ops,
     latent_attention_ops,
     kda_ops,
     power_retention_ops,

@@ -99,6 +99,9 @@ class OperatorType(enum.Enum):
     MOE_LAYER = "moe_layer"
     # state-space mixer (Mamba-2, ops/ssm_ops.py)
     MAMBA2 = "mamba2"
+    # state-space mixer (Mamba-1: a decay a channel and state index, the
+    # selective scan; ops/mamba_ops.py)
+    MAMBA = "mamba"
     # multi-head latent attention (low-rank q and K/V, rotary positions on a
     # slice, a latent cache; ops/latent_attention_ops.py)
     LATENT_ATTENTION = "latent_attention"
@@ -148,6 +151,7 @@ WEIGHTED_OPS = frozenset(
         OperatorType.EXPERTS,
         OperatorType.MOE_LAYER,
         OperatorType.MAMBA2,
+        OperatorType.MAMBA,
         OperatorType.LATENT_ATTENTION,
         OperatorType.KDA,
         OperatorType.POWER_RETENTION,

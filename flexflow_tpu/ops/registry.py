@@ -116,6 +116,12 @@ class OpDef:
     state_kind: Optional[str] = None
     page_state: Optional[Callable[[Layer], Dict[str, int]]] = None
     slot_state: Optional[Callable[[Layer], Dict[str, Any]]] = None
+    # whether a "recurrent" op's decode twin at a block of `s > 1` positions
+    # is its sequence form STARTED FROM A STATE: `state[name]` holds the rows'
+    # leaves before the block, `new_state[name]` what they are after its last
+    # real position. What a prefill chunk (serving/engine.py) needs of every
+    # recurrent layer; an op that does not declare it is refused there
+    chunk_from_state: bool = False
     # span_facts(layer) -> what a layer that carries state says of it on the
     # serving compile span (a "recurrent" layer of its state's layout,
     # {"ssm_groups": n}; a paged one of what the pool's rows went through,

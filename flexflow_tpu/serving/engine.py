@@ -64,7 +64,7 @@ from flexflow_tpu.config import ensure_compile_cache
 from flexflow_tpu.compiler.lowering import build_forward, constrainable
 from flexflow_tpu.core.graph import topo_order
 from flexflow_tpu.ops.op_type import OperatorType
-from flexflow_tpu.ops.registry import STATS_KEY, get_op_def
+from flexflow_tpu.ops.registry import STATS_KEY, get_op_def, rows_taken
 from flexflow_tpu.ops.attention_ops import BLOCK_LENGTHS_KEY
 from flexflow_tpu.parallel.default_strategy import data_parallel_strategy
 from flexflow_tpu.parallel.machine import MachineSpec, build_mesh
@@ -152,7 +152,9 @@ def compile_serving(model, max_batch_slots: Optional[int] = None,
     (--serve-prefill-chunk) tokens a chunk the prompt program is a `[1, prefill_chunk]` block over one
     slot's own pages (`ServingCompiled.prefill_chunk`), the
     model's `seq` is a slot's whole context (prompt + answer), and the
-    `[slots, seq]` wave is never compiled."""
+    `[slots, seq]` wave is never compiled. A recurrent layer whose op
+    declares it (`OpDef.chunk_from_state`) starts the block from its slot's
+    state and leaves it the state after the block's last real position."""
     cfg = model.config
     ensure_compile_cache()
     # --telemetry-dir arms the process-global span stream for serving-only
@@ -241,17 +243,28 @@ def compile_serving(model, max_batch_slots: Optional[int] = None,
                 f"compile_serving: {len(window_layers)} layers keep a window "
                 f"of {window} positions in a ring of pages a slot, which "
                 "needs the prompt to go in by chunks (--serve-prefill-chunk)")
-        if chunk and (latent or recurrent or not attn):
+        # the recurrent ops whose sequence form cannot start from a state
+        stateless_start = sorted({
+            op.value for op in (dec_model.get_layer_by_name(n).op_type
+                                for n in recurrent)
+            if not get_op_def(op).chunk_from_state})
+        lacking = [what for what, found in (
+            ("paged_latent layers", latent),
+            (f"recurrent layers of {', '.join(stateless_start)}, whose ops "
+             "declare no such form (OpDef.chunk_from_state)", stateless_start),
+            ("none that pages", not attn)) if found]
+        if chunk and lacking:
             # a chunk attends over what its slot has cached: K/V pages can be
-            # read back as they were written; a latent's chunk form (absorbed
-            # or decompressed over the cache) and a recurrent layer's (the
-            # sequence form started from a slot's state) do not exist yet
+            # read back as they were written, and a recurrent layer whose op
+            # declares it (OpDef.chunk_from_state) starts its sequence form
+            # from the slot's state; a latent's chunk form (absorbed or
+            # decompressed over the cache) does not exist yet, nor the other
+            # recurrent ops' start from a state
             raise NotImplementedError(
                 "compile_serving: chunked prefill (--serve-prefill-chunk) "
-                "needs every stateful layer to page K/V; this model has "
-                + ("paged_latent layers" if latent else
-                   f"{len(recurrent)} recurrent layers" if recurrent else
-                   "none that pages"))
+                "needs every stateful layer to page K/V or to start its "
+                "sequence form from a slot's state; this model has "
+                + "; ".join(lacking))
         if latent:
             unsupported = [
                 what for what, asked in (
@@ -269,6 +282,7 @@ def compile_serving(model, max_batch_slots: Optional[int] = None,
                     + " yet")
         compile_span.set(kv_layers=len(attn), state_layers=len(recurrent),
                          state_bytes_per_slot=state_bytes,
+                         chunk_state_layers=len(recurrent) if chunk else 0,
                          paged_state="paged_latent" if latent
                          else "paged_kv" if attn else "none")
         expert_layers = [l for l in model.layers
@@ -566,17 +580,33 @@ class ServingCompiled:
                 chunk_model.input_tensors[0].spec.shape[1])
             c_body, c_head = split_head(chunk_model, decode_strategy)
             paged = list(self.attn_layers)
+            # the layers a chunk starts from its slot's state
+            carried = list(self.kv.recurrent)
+            # whether a layer's cache attention is stated by position (a
+            # window, or `window` 0: the whole context) and counts the keys
+            # its real queries see
+            by_position = any("window" in l.params
+                              for l in chunk_model.layers)
 
             def _prefill_chunk(params, state, inputs, page_rows, context,
-                               lengths):
+                               lengths, slots=None):
                 # the block sees its slot's own pages and position; the
                 # cache's table, positions and live set pass through as they
                 # are (a prefilling slot is not live until its last chunk)
                 view = {n: state[n] for n in paged}
+                # and each recurrent layer's leaves of its slot: zeros for a
+                # prompt's first chunk, whatever the slot held before
+                for n in carried:
+                    view[n] = {
+                        key: rows_taken(leaf[slots],
+                                        jnp.zeros_like(leaf[slots]),
+                                        context > 0)
+                        for key, leaf in state[n].items()}
                 view[PAGE_TABLE_KEY] = page_rows
                 if window_layers:   # the slot's two rows: its pages, its ring
                     view[PAGE_TABLE_KEY], view[WINDOW_TABLE_KEY] = jnp.split(
                         page_rows, [kv_spec.pages_per_slot], axis=1)
+                if by_position:
                     # which of the block's positions hold a token
                     view[BLOCK_LENGTHS_KEY] = lengths
                 view[POS_KEY] = context
@@ -588,8 +618,19 @@ class ServingCompiled:
                     jnp.maximum(lengths.astype(jnp.int32) - 1, 0), c_head)
                 new = dict(state)
                 new.update({n: ns[n] for n in paged})
-                if STATS_KEY in ns:
-                    new[STATS_KEY] = ns[STATS_KEY]
+                # what the block left goes into the slot's rows, in place
+                # (the state is donated); a row without a token keeps its own
+                for n in carried:
+                    new[n] = {
+                        key: leaf.at[slots].set(rows_taken(
+                            ns[n][key], leaf[slots], lengths > 0))
+                        for key, leaf in state[n].items()}
+                stats = dict(ns.get(STATS_KEY, {}))
+                if carried:
+                    stats["chunk_state_in"] = jnp.any(
+                        (context > 0) & (lengths > 0)).astype(jnp.float32)
+                if stats:
+                    new[STATS_KEY] = stats
                 return tokens, new
 
             self._chunk_jit = jax.jit(_prefill_chunk, donate_argnums=(1,))
@@ -930,7 +971,7 @@ class ServingCompiled:
         return tokens, kv_state
 
     def prefill_chunk(self, params, state, input_arrays, page_rows, context,
-                      lengths):
+                      lengths, slots=None):
         """One chunk of one prompt, `chunk_tokens` tokens (every array has
         a leading axis of 1): the tokens sit at positions `context[0] ..` of
         the slot whose table row is `page_rows[0]` (`kv.prefill_row`),
@@ -939,7 +980,11 @@ class ServingCompiled:
         itself. Returns (tokens `[1]` int32: the greedy token after the last
         real position, which is the request's first token where the chunk
         is its prompt's last; the new cache state, the step's counters
-        under STATS_KEY). `state` is DONATED, as in `decode_step`."""
+        under STATS_KEY). `slots` `[1]` int: the slot itself, for a model
+        with recurrent layers: each starts the block from that slot's state
+        (from zeros where `context[0]` is 0, whatever the slot held) and
+        leaves it what it is after the block's last real position. `state`
+        is DONATED, as in `decode_step`."""
         if self._chunk_jit is None:
             raise RuntimeError("prefill_chunk: engine compiled without "
                                "--serve-prefill-chunk")
@@ -947,6 +992,12 @@ class ServingCompiled:
                 jnp.asarray(page_rows, jnp.int32),
                 jnp.asarray(context, jnp.int32),
                 jnp.asarray(lengths, jnp.int32))
+        if self.kv.recurrent:
+            # the program of a model without such layers takes no slot
+            if slots is None:
+                raise ValueError("prefill_chunk: a model with recurrent "
+                                 "layers needs the chunk's slot (`slots`)")
+            args += (jnp.asarray(slots, jnp.int32),)
         prog = self._programs["prefill_chunk"]
         if prog.compiled is None:
             prog.first_run(*args)

@@ -95,7 +95,10 @@ slot is admitted `prefilling`: it owns its pages on the host, but the device's
 table row stays at the scratch page and the slot inactive, so that a decode
 step between two chunks writes nothing into them; the chunk program is handed
 the slot's real row (`prefill_row`), and `activate` publishes it with the
-prompt's length once the last chunk is in.
+prompt's length once the last chunk is in. Beside the row it is told the slot
+itself: a recurrent layer's chunk starts from that slot's rows of the state
+arrays below (zeros for a prompt's first chunk, whatever a former occupant
+left there) and writes what the chunk leaves back into them, in place.
 
 Recurrent layers (a state-space mixer) keep the other kind of per-request
 state in the same manager: fixed-size arrays per slot, `state[layer_name] =

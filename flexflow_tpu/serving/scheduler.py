@@ -166,11 +166,14 @@ def gpt2_step_inputs(tokens, state) -> List[Any]:
     return [tokens, pos]
 
 
-def valid_prompt_inputs(ids: np.ndarray, lengths: np.ndarray) -> List[np.ndarray]:
+def valid_prompt_inputs(ids: np.ndarray, lengths: np.ndarray,
+                        offsets=None) -> List[np.ndarray]:
     """Prefill inputs of a model without positions (`input_ids`, `valid`):
     token ids, and which positions of the padded wave hold a prompt token.
     Layers whose state depends on where a row ends (a state-space mixer)
-    read it from `valid`."""
+    read it from `valid`. `offsets` (where a prefill chunk starts in its
+    sequence) is the chunk scheduler's argument; nothing here has a use for
+    it: the slot's cache and state are where a chunk's past is."""
     valid = np.arange(ids.shape[1])[None, :] < np.asarray(lengths)[:, None]
     return [ids.astype(np.int32), valid.astype(np.int32)]
 
@@ -584,7 +587,8 @@ class ContinuousBatchingScheduler:
                     lambda s=self.kv.state: self.engine.prefill_chunk(
                         self.params, s,
                         self.prompt_inputs_fn(ids, lengths, context),
-                        page_rows, context, lengths),
+                        page_rows, context, lengths,
+                        np.array([req.slot], np.int32)),
                     policy=self.retry_policy)
                 stats = state.pop(STATS_KEY, None)
                 self.kv.adopt(state)

@@ -33,7 +33,8 @@ sys.path.insert(0, ROOT)  # chip_smoke
 
 KERNEL_MODULES = ("flash_attention", "dequant_attention", "ssd_scan",
                   "kda_scan", "retention_step", "moe_step", "mamba2_step",
-                  "sparse_attend_step", "sparse_attend_chunk", "moe_rows")
+                  "sparse_attend_step", "sparse_attend_chunk", "moe_rows",
+                  "selective_scan")
 
 # GPT-2 medium: batch 8, 16 heads of 64, seq 1024
 B, H, S, D = 8, 16, 1024, 64
@@ -1338,3 +1339,79 @@ def test_trinity_train_step_fits_one_chip(described_devices, mosaic):
     op_types = {l.name: l.op_type.value for l in model.layers}
     assert attribution.step_passes(text, op_types) \
         == {"moe_routing_passes": 1.0, "flash_fwd_passes": 1.0}
+
+
+def test_jamba_serving_programs_fit_one_chip(described_devices, mosaic,
+                                             one_chip, monkeypatch):
+    """`AI21-Jamba2-3B.serve-longprompt`'s two programs at the cell's own
+    sizes (16 slots of 16896 positions, 6.39 GB of bf16 weights: all 28
+    layers, the whole vocabulary; two attention layers page 1056 pages a slot
+    of one K/V head, 26 Mamba layers keep a `[16, 5120]` float32 state and a
+    conv tail a slot), through the normal entry points: the prompt program is
+    the `[1, 2048]` chunk that starts every Mamba layer from its slot's
+    state. Mosaic accepts the selective-scan kernel at these tiles, one call
+    a Mamba layer, and the two attention kernels by position at 20 query
+    heads over ONE K/V head; no float32 `[2048, 16, 5120]` intermediate of
+    the scan and no scores of a chunk's queries against a slot's context
+    exist in the chunk program; both programs write the state and the pools
+    they were handed in place."""
+    eng, g, params, state = _described_engine(
+        "AI21-Jamba2-3B.serve-longprompt", described_devices, monkeypatch,
+        one_chip)
+    slots, spec = eng.slots, eng.kv_spec
+    assert (g.layers, g.layer_types.count("attention")) == (28, 2)
+    assert (spec.layers, spec.heads, spec.head_dim) == (2, 1, 128)
+    assert spec.pages_per_slot * spec.page_size == g.seq == 16896
+    assert eng.kv.state_kinds == "paged_kv+recurrent"
+    assert eng.chunk_tokens == 2048 and len(eng.kv.recurrent) == 26
+    pages = slots * 1056 + 1
+    assert eng.kv.state["l7_attn"]["k"].shape == (pages, 16, 128)
+    assert eng.kv.state["l0_mamba"]["ssm"].shape == (slots, 16, 5120)
+    assert eng.kv.state["l0_mamba"]["conv"].shape == (slots, 3, 5120)
+    weights = sum(x.size * x.dtype.itemsize
+                  for x in jax.tree_util.tree_leaves(params))
+    # A_log, D and dt_bias are float32: 2 more bytes each
+    assert weights == 2 * g.param_count() + 26 * 2 * (16 + 2) * 5120
+    assert g.param_count() == 3197109632
+    held = sum(x.size * x.dtype.itemsize
+               for x in jax.tree_util.tree_leaves(state))
+    assert spec.state_bytes_per_slot == g.state_bytes_per_slot() == 9318400
+    assert 0.42e9 < held < 0.43e9
+    decode = eng._decode_jit.lower(
+        params, state, [_i32(one_chip, slots, 1)] * 2).compile()
+    chunk = eng._chunk_jit.lower(
+        params, state, [_i32(one_chip, 1, 2048)] * 2,
+        _i32(one_chip, 1, 1056), _i32(one_chip, 1), _i32(one_chip, 1),
+        _i32(one_chip, 1)).compile()
+    needs = {}
+    for name, program in (("decode", decode), ("chunk", chunk)):
+        m = program.memory_analysis()
+        needs[name] = (m.argument_size_in_bytes + m.output_size_in_bytes
+                       - m.alias_size_in_bytes + m.temp_size_in_bytes)
+        assert 6.8e9 < needs[name] < 9e9, (name, needs[name], m)
+        assert m.alias_size_in_bytes >= held - 1e6
+    print("jamba serving programs need", needs)
+    from flexflow_tpu import attribution
+    from flexflow_tpu.ops.attention_ops import FULL_SCOPE
+    from flexflow_tpu.ops.mamba_ops import SCAN_SCOPE, STEP_SCOPE
+
+    def kernel_calls(text, kernel):
+        return len(re.findall(r' custom-call\([^\n]*custom_call_target='
+                              rf'"tpu_custom_call"[^\n]*{kernel}', text))
+
+    text = chunk.as_text()
+    assert kernel_calls(text, "ff_selective_scan") == 26
+    assert kernel_calls(text, "ff_sparse_attend_chunk") == 2
+    under = attribution.instructions_in_scope(text, SCAN_SCOPE)
+    assert sum(n.startswith("ff_selective_scan") for n in under) == 26
+    under = attribution.instructions_in_scope(text, FULL_SCOPE)
+    assert sum(n.startswith("ff_sparse_attend_chunk") for n in under) == 2
+    assert not re.search(r"f32\[[0-9,]*2048,16,5120\]", text)
+    assert not re.search(r"f32\[[0-9,]*,2048,16896\]", text)
+    text = decode.as_text()
+    assert kernel_calls(text, "ff_sparse_attend_step") == 2
+    assert attribution.instructions_in_scope(text, STEP_SCOPE)
+    for program in (chunk, decode):
+        for pool in (f"bf16[{pages},16,128]", "f32[16,16,5120]"):
+            assert not re.search(rf"= {re.escape(pool)}\S* copy\(",
+                                 program.as_text())
