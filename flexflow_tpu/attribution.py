@@ -45,6 +45,7 @@ import bisect
 import glob
 import hashlib
 import json
+import math
 import os
 import re
 import weakref
@@ -416,7 +417,54 @@ _KEPT_WORK = {"moe_routing_passes": _decides,
               "flash_fwd_passes": _runs_flash_forward}
 
 
-def step_passes(hlo_text: str, op_types: Dict[str, str]) -> Dict[str, float]:
+_RESULT = re.compile(r"^\(?(\w+)\[([\d,]*)\]")
+_ITEMSIZE = {"f64": 8, "s64": 8, "u64": 8, "f32": 4, "s32": 4, "u32": 4,
+             "bf16": 2, "f16": 2, "s16": 2, "u16": 2}
+
+
+def _result_bytes(i: _Instr) -> int:
+    """Bytes of an instruction's result (of a tuple's first array)."""
+    m = _RESULT.match(i.rest)
+    if m is None:
+        return 0
+    return _ITEMSIZE.get(m.group(1), 1) * math.prod(
+        int(d) for d in m.group(2).split(",") if d)
+
+
+def flash_relayouts(comps: Dict[str, list], scopes: Dict[str, OpScope]
+                    ) -> Optional[float]:
+    """`copy` / `transpose` instructions of a compiled program (`comps`: its
+    parsed computations) that belong to a `multihead_attention` layer (by
+    their own scope, or the compiler's by what they serve: `scopes`, the
+    program's `op_scope_map`) and whose result is at least as large as the
+    layer's q (the first result of its flash forward call), for each layer
+    that calls the flash forward kernel; None where none does (every CPU
+    program). 0 where the kernels read q, k, v and write o as the
+    projections hold them (`flash_attention.entry_of`: `merged`,
+    `two_heads`); the `swapped` entry's `[b, s, h, d]` <-> `[b, h, s, d]`
+    and whatever the compiler relays for the head norm and the rotation
+    show here. A `copy-start` is the compiler's prefetch, no relayout."""
+    q_bytes: Dict[str, int] = {}
+    moved: list = []
+    for items in comps.values():
+        for i in items:
+            at = scopes.get(i.name)
+            if at is None or at.op_type != "multihead_attention":
+                continue
+            if at.opcode == "ff_flash_attention_fwd":
+                q_bytes[at.layer] = min(_result_bytes(i),
+                                        q_bytes.get(at.layer, 1 << 62))
+            elif i.opcode in ("copy", "transpose"):
+                moved.append((at.layer, _result_bytes(i)))
+    if not q_bytes:
+        return None
+    return sum(size >= q_bytes[layer] for layer, size in moved
+               if layer in q_bytes) / len(q_bytes)
+
+
+def step_passes(hlo_text: str, op_types: Dict[str, str],
+                scopes: Optional[Dict[str, OpScope]] = None
+                ) -> Dict[str, float]:
     """How often a compiled training step runs a piece of work for once
     that its forward pass does: the instructions of each kind of
     `_KEPT_WORK` in all phases over those of the forward phase (a
@@ -430,9 +478,12 @@ def step_passes(hlo_text: str, op_types: Dict[str, str]) -> Dict[str, float]:
     where the one around it keeps what the forward kernel wrote
     (`flash_attention.FLASH_KEPT`: a `remat_blocks` unit's); 2 where the
     recomputation runs the kernel again; absent from every CPU program,
-    whose kernels are interpreted."""
+    whose kernels are interpreted. Beside them `flash_relayouts`, no
+    ratio of passes: the q-sized relayouts a flash layer (`scopes`: the
+    program's `op_scope_map` where the caller has it)."""
     by_phase: Dict[str, Dict[str, int]] = {kind: {} for kind in _KEPT_WORK}
-    for items in _parse_computations(hlo_text)[0].values():
+    comps = _parse_computations(hlo_text)[0]
+    for items in comps.values():
         for i in items:
             if not i.op_name:
                 continue
@@ -441,8 +492,13 @@ def step_passes(hlo_text: str, op_types: Dict[str, str]) -> Dict[str, float]:
                 if counted(i, op_types.get(layer, "")):
                     seen = by_phase[kind]
                     seen[phase] = seen.get(phase, 0) + 1
-    return {kind: sum(seen.values()) / seen["forward"]
-            for kind, seen in by_phase.items() if seen.get("forward")}
+    out = {kind: sum(seen.values()) / seen["forward"]
+           for kind, seen in by_phase.items() if seen.get("forward")}
+    relayouts = flash_relayouts(
+        comps, op_scope_map(hlo_text, op_types) if scopes is None else scopes)
+    if relayouts is not None:
+        out["flash_relayouts"] = relayouts
+    return out
 
 
 def routing_passes(hlo_text: str, op_types: Dict[str, str]
@@ -497,7 +553,7 @@ class Program:
             # a training step: how often it runs what a checkpoint around
             # an op may keep, a property of the program
             if any(s.phase == "backward" for s in self.scopes.values()):
-                sp.set(**step_passes(text, self.op_types))
+                sp.set(**step_passes(text, self.op_types, self.scopes))
         return self.scopes
 
 

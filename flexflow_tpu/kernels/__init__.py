@@ -1,7 +1,11 @@
 """Pallas TPU kernels.
 
 flash_attention — block-wise online-softmax attention (fwd + custom VJP),
-the cuDNN-fused-attention replacement (reference src/ops/attention.cu:35).
+the cuDNN-fused-attention replacement (reference src/ops/attention.cu:35);
+the shape picks how its operands lie (`merged`, `two_heads`, `swapped`).
+head_turn — a head's RMS norm and rotate-half turn on the merged axis `[b,
+s, h * d]`, forward and backward: what sits between a projection and the
+flash kernels' merged entry.
 dequant_attention — fused int8-dequant + decode attention over the
 quantized paged KV cache (serving --kv-cache-dtype int8).
 ssd_scan — the Mamba-2 chunked scan with the mixer's skip, gate and norm on

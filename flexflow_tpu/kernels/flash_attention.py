@@ -12,6 +12,37 @@ Forward saves the per-row logsumexp; the backward pass is two more pallas
 kernels (dq gridded over q blocks; dk/dv gridded over k blocks) recomputing
 the probabilities from the saved lse — the flash-attention v2 recipe.
 
+Layout (PR 63). How operands and residuals lie in HBM is decided by the
+shape (`entry_of`), never by a flag:
+
+- `merged`, head widths of whole 128-lane slabs (Trinity-Mini's, granite's,
+  Nemotron's 128): q, o, dq are `[b, s, h * d]` and k, v, dk, dv `[b, s,
+  K/V heads * d]`, exactly what `x @ wq` writes and `@ wo` reads; a block is
+  `(rows, d)` and the head is its LANE block index (K/V head `h // group`).
+  Nothing is split, swapped or relaid around a call: on one device
+  `ops/attention_ops._mha_lower` hands `flash_attention_merged` its
+  projections as they lie, and where the layer norms or rotates its heads,
+  kernels/head_turn.py does that on the merged axis too.
+- `two_heads`, width 64 with an even number of heads and one K/V head a
+  query head (GPT-2): the same arrays, two heads a 128-lane block, the grid
+  over `h / 2`, each head's 64 lanes a static slice of the tile.
+- `swapped`, every other width (the latent layers' 192; grouped heads of
+  64): `[b, h, s, d]`, as before; `flash_attention_qkv` swaps the axes
+  around the call.
+
+The two row statistics, `lse` (forward -> both backward kernels) and `delta
+= rowsum(do * o)` (dq kernel -> dk/dv kernel), are `(b, h, 1, s)` float32
+whatever the entry: the sequence on the lanes, 4 bytes a row (as `[.., s,
+1]` the chip pads a number to a 128-lane tile: 268 MB for 2 MB at `[2, 32,
+8192]`). The forward lays a tile's column along the lanes once a q tile;
+the dq kernel makes `delta` from `o`'s rows (a column, as it reads it),
+turns its block's `lse` row into a column once a grid step, and hands
+`delta` on as a row; the dk/dv kernel works on the TRANSPOSED score tile `k
+q^T`, where both statistics are rows as they lie and dk = ds^T q, dv = p^T
+do need no transposed operand. A call nobody differentiates writes no `lse`
+at all. The kernels see one shape whatever the entry: an operand `[rows,
+heads_a_block * d]`, a statistic `[heads_a_block, 1, rows]`.
+
 A VMEM budget bounds a block from above (`_blocks_for`). A non-causal call
 takes that bound as its tile and loops over the key blocks with the online
 softmax. A causal call holds a block of that size per grid step and tiles
@@ -40,7 +71,7 @@ A call without a window traces to the program it traced to before windows
 existed. K/V heads may be fewer than the query heads (`k.shape[1]` divides
 `q.shape[1]`): query head j reads K/V head j // group through the block
 index, no repeated copy; dk/dv come out a query head in float32 and are
-summed over each group.
+summed over each group (on the merged axis by lane slabs: `_group_sum`).
 
 All matmuls accumulate in float32 (preferred_element_type) regardless of the
 input dtype; bf16 inputs hit the MXU at full rate.
@@ -217,12 +248,19 @@ def _band_q_tile(k_start: int, bq: int, bk: int, window: int, rows: int):
     return -(-min(rows, k_start + bk - 1 + window) // bq)
 
 
-def _in_band(offset, bq: int, bk: int, window: int):
+def _ahead(bq: int, bk: int, keys_first: bool = False):
+    """How far each pair's query lies after its key, counted from the
+    tile's first of each: (bq, bk) int32, or (`keys_first`) the transposed
+    tile's (bk, bq)."""
+    shape = (bk, bq) if keys_first else (bq, bk)
+    return jax.lax.broadcasted_iota(jnp.int32, shape, int(keys_first)) \
+        - jax.lax.broadcasted_iota(jnp.int32, shape, int(not keys_first))
+
+
+def _in_band(offset, bq: int, bk: int, window: int, keys_first=False):
     """(bq, bk) bool: the pairs whose query lies under `window` after the
     key, the tile's first query `offset` after its first key."""
-    ahead = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0) \
-        - jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-    return ahead < window - offset
+    return _ahead(bq, bk, keys_first) < window - offset
 
 
 def _schedule(kernel: str, seq_q: int, seq_k: int, bq: int, bk: int,
@@ -302,44 +340,48 @@ _VMEM_DEFAULT_SCOPE = 16 * 1024 * 1024
 _VMEM_ASK_FROM = 10 * 1024 * 1024
 
 
-def _params(seq: int = 0, depth: int = 0, itemsize: int = 0,
+def _params(seq: int = 0, width: int = 0, itemsize: int = 0,
             vectors: int = 0):
     """The kernels' compiler parameters. A grid step holds two whole-
-    sequence operands of `depth` (k and v, or q and do) and `vectors`
-    per-row f32 columns (lse, delta: a `[seq, 1]` block lies in VMEM a
-    128-lane tile a row), each double-buffered: where that passes
+    sequence operands of `width` lanes (k and v, or q and do) and `vectors`
+    per-row f32 rows (lse, delta: a `[1, seq]` block lies in VMEM eight
+    sublanes a 128-lane tile), each double-buffered: where that passes
     `_VMEM_ASK_FROM` (8192 x 128 does in the backward) the call states its
     own scope, the resident bytes and the default beside them."""
     from jax.experimental.pallas import tpu as pltpu
 
     # batch/head/q-block grid dims are independent; lets Mosaic pipeline them
     semantics = ("parallel", "parallel", "arbitrary")
-    resident = 2 * seq * (2 * depth * itemsize + vectors * 128 * 4)
+    resident = 2 * seq * (2 * width * itemsize + vectors * 8 * 4)
     if resident <= _VMEM_ASK_FROM:
         return pltpu.CompilerParams(dimension_semantics=semantics)
     return pltpu.CompilerParams(dimension_semantics=semantics,
                                 vmem_limit_bytes=resident + _VMEM_DEFAULT_SCOPE)
 
 
-def _traced_once(arrays: int):
-    """`call(*arrays, causal, scale, bq, bk, window, interpret)` under `jax.jit`,
-    all but the arrays static: a model's layers call a kernel at one shape,
-    and a trace of the step then traces and lowers the kernel's body once
-    and not once a layer (the static schedule under the diagonal is
+def _traced_once(arrays: int, **static):
+    """`call(*arrays, causal, scale, bq, bk, *static, interpret)` under
+    `jax.jit`, all but the arrays static (`static`: the keywords a caller
+    may give, with their defaults): a model's layers call a kernel at one
+    shape, and a trace of the step then traces and lowers the kernel's body
+    once and not once a layer (the static schedule under the diagonal is
     straight-line code, twice the parent's loop to trace). Interpret mode
     is part of the key: tests switch it within a process."""
     def wrap(call):
-        jitted = jax.jit(call, static_argnums=tuple(range(arrays, arrays + 6)))
-        return lambda *args, window=0: jitted(*args, window, _interpret())
+        jitted = jax.jit(call, static_argnums=tuple(
+            range(arrays, arrays + 5 + len(static))))
+
+        def traced(*args, **given):
+            return jitted(*args, *({**static, **given}.values()), _interpret())
+
+        return traced
     return wrap
 
 
-def _under_diagonal(q_start, k_start, bq: int, bk: int):
+def _under_diagonal(q_start, k_start, bq: int, bk: int, keys_first=False):
     """(bq, bk) bool: the pairs of a tile the diagonal crosses whose query
-    is at or after the key."""
-    ahead = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0) \
-        - jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-    return ahead >= k_start - q_start
+    is at or after the key; `keys_first`: the transposed tile, (bk, bq)."""
+    return _ahead(bq, bk, keys_first) >= k_start - q_start
 
 
 def _nt(a, b):
@@ -388,6 +430,86 @@ def _at(base, offset: int, size: int, align: int):
     return pl.ds(pl.multiple_of(base + offset, align), size)
 
 
+# ------------------------------------------------------- layout at the boundary
+# How operands and residuals lie in HBM (module docstring, "Layout"). A kernel
+# sees one shape whatever the entry: an operand `[rows, heads_a_block * d]`
+# (its block's other dimensions squeezed away), a row statistic
+# `[heads_a_block, 1, rows]`, and walks the block's heads by their lanes.
+# how the rows' statistics lie, as the lowering span says it: the sequence on
+# the lanes
+RESIDUAL = "lanes"
+
+
+def entry_of(depth: int, heads: int, kv_heads: int) -> str:
+    """Which entry a shape takes: head widths of whole 128-lane slabs are
+    read from `[b, s, h * d]` as the projections write it (`merged`), width
+    64 two heads a 128-lane block (`two_heads`: an even number of heads, one
+    K/V head a query head); every other width through `[b, h, s, d]`
+    (`swapped`: a lane block narrower or wider than its head cannot be
+    indexed by the head)."""
+    if depth % 128 == 0:
+        return "merged"
+    if depth == 64 and heads % 2 == 0 and kv_heads == heads:
+        return "two_heads"
+    return "swapped"
+
+
+def _dims(q, k, heads: int):
+    """(b, h, K/V heads, sq, sk, d) of a call's operands: `heads` 0, q `(b,
+    h, sq, d)`; else q `[b, sq, heads * d]` and k `[b, sk, K/V heads * d]`."""
+    if not heads:
+        b, h, sq, d = q.shape
+        return b, h, k.shape[1], sq, k.shape[2], d
+    b, sq, e = q.shape
+    return b, heads, k.shape[2] * heads // e, sq, k.shape[1], e // heads
+
+
+def _heads_a_block(heads: int, depth: int) -> int:
+    """Query heads a grid step holds: the heads of one 128-lane block."""
+    return max(1, 128 // depth) if heads else 1
+
+
+def _operand(heads: int, depth: int, rows: int, whole: bool, group: int = 1):
+    """The BlockSpec of `rows` rows of one (batch, head block): the grid
+    step's block of them, or (`whole`) the sequence; `group` query heads
+    read one K/V head (query head j K/V head j // group)."""
+    at = (lambda i: 0) if whole else (lambda i: i)
+    head = (lambda h_: h_) if group == 1 else (lambda h_: h_ // group)
+    if heads:       # the head (or pair) is the LANE block
+        return pl.BlockSpec(
+            (None, rows, _heads_a_block(heads, depth) * depth),
+            lambda b_, h_, i: (b_, at(i), head(h_)))
+    return pl.BlockSpec((None, None, rows, depth),
+                        lambda b_, h_, i: (b_, head(h_), at(i), 0))
+
+
+def _statistic(heads: int, depth: int, rows: int, whole: bool):
+    """The BlockSpec of a row statistic `(b, h, 1, s)` float32: the
+    sequence on the LANES (as `[.., s, 1]` the chip pads every number to a
+    128-lane tile, in HBM and in VMEM)."""
+    at = (lambda i: 0) if whole else (lambda i: i)
+    return pl.BlockSpec((None, _heads_a_block(heads, depth), 1, rows),
+                        lambda b_, h_, i: (b_, h_, 0, at(i)))
+
+
+def _shape_of(heads: int, b: int, h: int, s: int, d: int, dtype):
+    return jax.ShapeDtypeStruct((b, s, h * d) if heads else (b, h, s, d),
+                                dtype)
+
+
+def _turned(vector):
+    """A tile's column `(n, 1)` laid along the lanes `(1, n)`, or a
+    statistic's row as the column a score tile with the queries first
+    subtracts: one relayout of n numbers on the chip."""
+    return vector.T
+
+
+def _lanes_of(ref, depth: int):
+    """[(j, lanes)] of the heads of a kernel's block."""
+    return [(j, pl.ds(j * depth, depth))
+            for j in range(ref.shape[-1] // depth)]
+
+
 # --------------------------------------------------------------------- forward
 # Each kernel: the whole key (or q) blocks in a loop first, `carry` None
 # where there are none; then, causal, the step's own block by static tiles.
@@ -409,25 +531,32 @@ def _block_loops(step, steps: int, window: int, block: int, after: bool):
     return [(jnp.maximum(0, step - out + 1), edge, True), (edge, step, False)]
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal, bq, bk,
-                window=0):
-    gq, d = q_ref.shape[2:]
-    sk = k_ref.shape[2]
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref=None, *, scale, causal,
+                bq, bk, depth, window=0):
+    for j, lanes in _lanes_of(q_ref, depth):
+        _fwd_head(q_ref, k_ref, v_ref, o_ref, lse_ref, j, lanes, scale,
+                  causal, bq, bk, window)
+
+
+def _fwd_head(q_ref, k_ref, v_ref, o_ref, lse_ref, j, lanes, scale, causal,
+              bq, bk, window):
+    gq, sk, d = q_ref.shape[0], k_ref.shape[0], lanes.size
     qi = pl.program_id(2)
     block_k = gq if causal else bk
 
     def finish(carry, rows):
         m, l, acc = carry
-        o_ref[0, 0, rows, :] = (acc / l).astype(o_ref.dtype)
-        lse_ref[0, 0, rows, :] = m + jnp.log(l)         # (rows, 1)
+        o_ref[rows, lanes] = (acc / l).astype(o_ref.dtype)
+        if lse_ref is not None:     # the rows' column, laid along the lanes
+            lse_ref[j, :, rows] = _turned(m + jnp.log(l))
 
     def whole_block(ki, carry, banded=False):
         keys = _at(ki * block_k, 0, block_k, block_k)
-        s = _nt(q_ref[0, 0], k_ref[0, 0, keys, :]) * scale
+        s = _nt(q_ref[:, lanes], k_ref[keys, lanes]) * scale
         if banded:
             s = jnp.where(_in_band((qi - ki) * gq, gq, block_k, window), s,
                           _OUT_OF_BAND)
-        return _online(carry, [(s, v_ref[0, 0, keys, :])])
+        return _online(carry, [(s, v_ref[keys, lanes])])
 
     carry = None
     if not causal or gq != sk:
@@ -446,25 +575,25 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal, bq, bk,
     banded = 0 < window < gq
     for q_at in range(0, gq, bq):
         rows = slice(q_at, q_at + bq)
-        q = q_ref[0, 0, rows, :]
+        q = q_ref[rows, lanes]
         full, visit = _k_tile_bounds(q_at, bq, bk)
         lo = min(_band_k_tile(q_at, bk, window), full) if banded else 0
         parts = []
         if full > lo:
             keys = _at(base, lo * bk, (full - lo) * bk, bk)
-            s = _nt(q, k_ref[0, 0, keys, :]) * scale
+            s = _nt(q, k_ref[keys, lanes]) * scale
             if banded:
                 s = jnp.where(_in_band(q_at - lo * bk, *s.shape, window), s,
                               _NEG_INF)
-            parts.append((s, v_ref[0, 0, keys, :]))
+            parts.append((s, v_ref[keys, lanes]))
         keys = _at(base, full * bk, (visit - full) * bk, bk)
-        s = _nt(q, k_ref[0, 0, keys, :]) * scale
+        s = _nt(q, k_ref[keys, lanes]) * scale
         # every row meets its own position here: its max is finite
         keep = _under_diagonal(q_at, full * bk, *s.shape)
         if banded:
             keep &= _in_band(q_at - full * bk, *s.shape, window)
         s = jnp.where(keep, s, _NEG_INF)
-        parts.append((s, v_ref[0, 0, keys, :]))
+        parts.append((s, v_ref[keys, lanes]))
         before = None if carry is None else tuple(x[rows] for x in carry)
         finish(_online(before, parts), rows)
 
@@ -476,85 +605,83 @@ def _grid_block(seq: int, depth: int, itemsize: int, causal: bool,
     return max(_bound(seq, depth, itemsize), tile, other) if causal else tile
 
 
-def _kv_index(group: int, whole: bool):
-    """The block index of a K/V operand for query head `h_`: its group's
-    K/V head (`group` query heads read one), the whole sequence or the
-    grid step's block."""
-    if group == 1:
-        return (lambda b_, h_, i: (b_, h_, 0, 0)) if whole \
-            else (lambda b_, h_, i: (b_, h_, i, 0))
-    return (lambda b_, h_, i: (b_, h_ // group, 0, 0)) if whole \
-        else (lambda b_, h_, i: (b_, h_ // group, i, 0))
-
-
 def _band_args(window: int):
     """A kernel's keyword for the window, none without one (the partial of
     a call without a window is the one it was)."""
     return {"window": window} if window else {}
 
 
-@_traced_once(3)
-def _fwd_call(q, k, v, causal, scale, bq, bk, window, interpret):
-    b, h, sq, d = q.shape
-    sk = k.shape[2]
+@_traced_once(3, window=0, heads=0, stats=True)
+def _fwd_call(q, k, v, causal, scale, bq, bk, window, heads, stats, interpret):
+    """(o, lse) of either form of operands (`_dims`); `stats` False: `o`
+    alone, and no `lse` leaves the kernel (a call nobody differentiates)."""
+    b, h, kvh, sq, sk, d = _dims(q, k, heads)
     gq = _grid_block(sq, d, q.dtype.itemsize, causal, bq, bk)
+    hb = _heads_a_block(heads, d)
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
-                               bq=bq, bk=bk, **_band_args(window))
-    k_full = pl.BlockSpec((1, 1, sk, d), _kv_index(h // k.shape[1], True))
-    return pl.pallas_call(
+                               bq=bq, bk=bk, depth=d, **_band_args(window))
+    q_spec = _operand(heads, d, gq, False)
+    k_full = _operand(heads, d, sk, True, h // kvh)
+    out = pl.pallas_call(
         kernel,
-        grid=(b, h, sq // gq),
-        in_specs=[
-            pl.BlockSpec((1, 1, gq, d), lambda b_, h_, i: (b_, h_, i, 0)),
-            k_full,
-            k_full,
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, gq, d), lambda b_, h_, i: (b_, h_, i, 0)),
-            # lse is (b, h, sq, 1): the trailing singleton keeps the block's
-            # last-two dims TPU-tileable ((gq, 1) with 1 == full array dim)
-            pl.BlockSpec((1, 1, gq, 1), lambda b_, h_, i: (b_, h_, i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
-            jax.ShapeDtypeStruct((b, h, sq, 1), jnp.float32),
-        ],
-        compiler_params=_params(sk, d, k.dtype.itemsize),
+        grid=(b, h // hb, sq // gq),
+        in_specs=[q_spec, k_full, k_full],
+        out_specs=[q_spec] + [_statistic(heads, d, gq, False)] * stats,
+        out_shape=[_shape_of(heads, b, h, sq, d, q.dtype)]
+        + [jax.ShapeDtypeStruct((b, h, 1, sq), jnp.float32)] * stats,
+        compiler_params=_params(sk, hb * d, k.dtype.itemsize),
         interpret=interpret,
         name="ff_flash_attention_fwd",
     )(q, k, v)
+    return tuple(out) if stats else out[0]
 
 
-def _fwd(q, k, v, causal, scale, window=0):
-    """q: (b, h, sq, d); k/v: (b, h or its K/V heads, sk, d) -> (o, lse)."""
-    bq, bk = _tiles("fwd", q.shape[2], k.shape[2], q.shape[3],
-                    q.dtype.itemsize, causal)
-    return _fwd_call(q, k, v, causal, scale, bq, bk, window=window)
+def _fwd(q, k, v, causal, scale, window=0, heads=0, stats=True):
+    """q: (b, h, sq, d); k/v: (b, h or its K/V heads, sk, d), or with
+    `heads` all three `[b, s, heads * d]` -> (o, lse `(b, h, 1, sq)`)."""
+    _, _, _, sq, sk, d = _dims(q, k, heads)
+    bq, bk = _tiles("fwd", sq, sk, d, q.dtype.itemsize, causal)
+    return _fwd_call(q, k, v, causal, scale, bq, bk, window=window,
+                     heads=heads, stats=stats)
 
 
 # -------------------------------------------------------------------- backward
-def _ds(q, k, v, do, lse, delta, scale, mask):
-    """(p, ds) f32 (rows, keys) of some q rows against some keys, the
-    probabilities recomputed from the saved lse; `mask` where the diagonal
-    (or a band's edge) crosses."""
-    p = jnp.exp(_nt(q, k) * scale - lse)
-    if mask is not None:
-        p = jnp.where(mask, p, 0.0)
-    return p, p * (_nt(do, v) - delta) * scale
+# `lse` and `delta` come in as ROWS (the sequence on the lanes). The dq
+# kernel holds a block of q rows against every key: it turns its block's two
+# rows into columns once a grid step and works on the score tile as the
+# forward does. The dk/dv kernel holds a block of keys against every q row
+# and would turn a vector a tile: it works on the TRANSPOSED tile `k q^T`
+# instead, where the two statistics are rows as they lie and dk = ds^T q,
+# dv = p^T do need no transposed operand either.
+def _dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref, delta_ref,
+               *, scale, causal, bq, bk, depth, window=0):
+    for j, lanes in _lanes_of(q_ref, depth):
+        # delta = rowsum(do * o), made here from the block's own rows (a
+        # column, as this kernel reads it) and handed on as a row
+        delta = jnp.sum(do_ref[:, lanes].astype(jnp.float32)
+                        * o_ref[:, lanes].astype(jnp.float32),
+                        axis=-1, keepdims=True)
+        delta_ref[j] = _turned(delta)
+        _dq_head(q_ref, k_ref, v_ref, do_ref, _turned(lse_ref[j]), delta, dq_ref,
+                 lanes, scale, causal, bq, bk, window)
 
 
-def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-               *, scale, causal, bq, bk, window=0):
-    gq, d = q_ref.shape[2:]
-    sk = k_ref.shape[2]
+def _dq_head(q_ref, k_ref, v_ref, do_ref, lse, delta, dq_ref, lanes, scale,
+             causal, bq, bk, window):
+    """`lse`, `delta`: the block's columns `(gq, 1)`."""
+    gq, sk, d = q_ref.shape[0], k_ref.shape[0], lanes.size
     qi = pl.program_id(2)
     block_k = gq if causal else bk
 
     def dq_of(rows, keys, mask=None):
-        k = k_ref[0, 0, keys, :]
-        _, ds = _ds(q_ref[0, 0, rows, :], k, v_ref[0, 0, keys, :],
-                    do_ref[0, 0, rows, :], lse_ref[0, 0, rows, :],
-                    delta_ref[0, 0, rows, :], scale, mask)
+        k = k_ref[keys, lanes]
+        # the probabilities recomputed from the saved lse; `mask` where the
+        # diagonal (or a band's edge) crosses
+        p = jnp.exp(_nt(q_ref[rows, lanes], k) * scale - lse[rows])
+        if mask is not None:
+            p = jnp.where(mask, p, 0.0)
+        ds = p * (_nt(do_ref[rows, lanes], v_ref[keys, lanes])
+                  - delta[rows]) * scale
         return _nn(ds.astype(k.dtype), k)
 
     def whole_block(ki, dq, banded=False):
@@ -573,7 +700,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
                 lo, hi, functools.partial(whole_block, banded=banded)
                 if banded else whole_block, dq)
     if not causal:
-        dq_ref[0, 0] = dq.astype(dq_ref.dtype)
+        dq_ref[:, lanes] = dq.astype(dq_ref.dtype)
         return
     base = 0 if gq == sk else qi * gq
     banded = 0 < window < gq
@@ -593,26 +720,35 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
                 if banded else None)
         if dq is not None:
             acc = acc + dq[rows]
-        dq_ref[0, 0, rows, :] = acc.astype(dq_ref.dtype)
+        dq_ref[rows, lanes] = acc.astype(dq_ref.dtype)
 
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
-                *, scale, causal, bq, bk, window=0):
-    gk, d = k_ref.shape[2:]
-    sq = q_ref.shape[2]
+                *, scale, causal, bq, bk, depth, window=0):
+    for j, lanes in _lanes_of(k_ref, depth):
+        _dkv_head(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
+                  dv_ref, j, lanes, scale, causal, bq, bk, window)
+
+
+def _dkv_head(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
+              j, lanes, scale, causal, bq, bk, window):
+    gk, sq, d = k_ref.shape[0], q_ref.shape[0], lanes.size
     kj = pl.program_id(2)
     block_q = gk if causal else bq
 
     def dkv_of(rows, keys, mask=None):
-        q, do = q_ref[0, 0, rows, :], do_ref[0, 0, rows, :]
-        p, ds = _ds(q, k_ref[0, 0, keys, :], v_ref[0, 0, keys, :], do,
-                    lse_ref[0, 0, rows, :], delta_ref[0, 0, rows, :], scale,
-                    mask)
-        return _tn(ds.astype(q.dtype), q), _tn(p.astype(do.dtype), do)
+        """(dk, dv) of some keys from some q rows, on the tile (keys, rows):
+        `mask` (keys first) where the diagonal or a band's edge crosses."""
+        q, do = q_ref[rows, lanes], do_ref[rows, lanes]
+        p = jnp.exp(_nt(k_ref[keys, lanes], q) * scale - lse_ref[j, :, rows])
+        if mask is not None:
+            p = jnp.where(mask, p, 0.0)
+        ds = p * (_nt(v_ref[keys, lanes], do) - delta_ref[j, :, rows]) * scale
+        return _nn(ds.astype(q.dtype), q), _nn(p.astype(do.dtype), do)
 
     def whole_block(qi, carry, banded=False):
-        mask = _in_band((qi - kj) * gk, block_q, gk, window) if banded \
-            else None
+        mask = _in_band((qi - kj) * gk, block_q, gk, window, True) \
+            if banded else None
         dk, dv = dkv_of(_at(qi * block_q, 0, block_q, block_q), slice(None),
                         mask)
         return carry[0] + dk, carry[1] + dv
@@ -628,8 +764,8 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
                 lo, hi, functools.partial(whole_block, banded=banded)
                 if banded else whole_block, carry)
     if not causal:
-        dk_ref[0, 0] = carry[0].astype(dk_ref.dtype)
-        dv_ref[0, 0] = carry[1].astype(dv_ref.dtype)
+        dk_ref[:, lanes] = carry[0].astype(dk_ref.dtype)
+        dv_ref[:, lanes] = carry[1].astype(dv_ref.dtype)
         return
     base = 0 if gk == sq else kj * gk
     banded = 0 < window < gk
@@ -637,103 +773,116 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
         keys = slice(k_at, k_at + bk)
         first, full = _q_tile_bounds(k_at, bq, bk)
         crossed = (full - first) * bq
-        keep = _under_diagonal(first * bq, k_at, crossed, bk)
+        keep = _under_diagonal(first * bq, k_at, crossed, bk, True)
         if banded:
-            keep &= _in_band(first * bq - k_at, crossed, bk, window)
+            keep &= _in_band(first * bq - k_at, crossed, bk, window, True)
         dk, dv = dkv_of(_at(base, first * bq, crossed, bq), keys, keep)
         last = max(full, _band_q_tile(k_at, bq, bk, window, gk)) if banded \
             else gk // bq
         if full < last:
             more = dkv_of(
                 _at(base, full * bq, (last - full) * bq, bq), keys,
-                _in_band(full * bq - k_at, (last - full) * bq, bk, window)
-                if banded else None)
+                _in_band(full * bq - k_at, (last - full) * bq, bk, window,
+                         True) if banded else None)
             dk, dv = dk + more[0], dv + more[1]
         if carry is not None:
             dk, dv = dk + carry[0][keys], dv + carry[1][keys]
-        dk_ref[0, 0, keys, :] = dk.astype(dk_ref.dtype)
-        dv_ref[0, 0, keys, :] = dv.astype(dv_ref.dtype)
+        dk_ref[keys, lanes] = dk.astype(dk_ref.dtype)
+        dv_ref[keys, lanes] = dv.astype(dv_ref.dtype)
 
 
-@_traced_once(6)
-def _dq_call(q, k, v, g, lse, delta, causal, scale, bq, bk, window, interpret):
-    b, h, sq, d = q.shape
-    sk = k.shape[2]
+@_traced_once(6, window=0, heads=0)
+def _dq_call(q, k, v, g, o, lse, causal, scale, bq, bk, window, heads,
+             interpret):
+    """(dq, delta): `delta` = rowsum(do * o) `(b, h, 1, sq)` float32, the
+    second statistic of the dk/dv kernel, which runs after this one."""
+    b, h, kvh, sq, sk, d = _dims(q, k, heads)
     gq = _grid_block(sq, d, q.dtype.itemsize, causal, bq, bk)
-    q_spec = pl.BlockSpec((1, 1, gq, d), lambda b_, h_, i: (b_, h_, i, 0))
-    k_full = pl.BlockSpec((1, 1, sk, d), _kv_index(h // k.shape[1], True))
-    vec_q = pl.BlockSpec((1, 1, gq, 1), lambda b_, h_, i: (b_, h_, i, 0))
+    hb = _heads_a_block(heads, d)
+    q_spec = _operand(heads, d, gq, False)
+    k_full = _operand(heads, d, sk, True, h // kvh)
+    vec_q = _statistic(heads, d, gq, False)
     return pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, causal=causal, bq=bq, bk=bk,
-                          **_band_args(window)),
-        grid=(b, h, sq // gq),
-        in_specs=[q_spec, k_full, k_full, q_spec, vec_q, vec_q],
-        out_specs=q_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
-        compiler_params=_params(sk, d, k.dtype.itemsize),
+                          depth=d, **_band_args(window)),
+        grid=(b, h // hb, sq // gq),
+        in_specs=[q_spec, k_full, k_full, q_spec, q_spec, vec_q],
+        out_specs=[q_spec, vec_q],
+        out_shape=[_shape_of(heads, b, h, sq, d, q.dtype),
+                   jax.ShapeDtypeStruct((b, h, 1, sq), jnp.float32)],
+        compiler_params=_params(sk, hb * d, k.dtype.itemsize),
         interpret=interpret,
         name="ff_flash_attention_dq",
-    )(q, k, v, g, lse, delta)
+    )(q, k, v, g, o, lse)
 
 
-@_traced_once(6)
-def _dkv_call(q, k, v, g, lse, delta, causal, scale, bq, bk, window, interpret):
-    b, h, sq, d = q.shape
-    sk = k.shape[2]
-    group = h // k.shape[1]
+@_traced_once(6, window=0, heads=0)
+def _dkv_call(q, k, v, g, lse, delta, causal, scale, bq, bk, window, heads,
+              interpret):
+    b, h, kvh, sq, sk, d = _dims(q, k, heads)
+    group = h // kvh
     gk = _grid_block(sk, d, k.dtype.itemsize, causal, bk, bq)
-    q_full = pl.BlockSpec((1, 1, sq, d), lambda b_, h_, i: (b_, h_, 0, 0))
-    k_in = pl.BlockSpec((1, 1, gk, d), _kv_index(group, False))
+    hb = _heads_a_block(heads, d)
+    q_full = _operand(heads, d, sq, True)
+    k_in = _operand(heads, d, gk, False, group)
     # a query head's own dk, dv: in f32 where a group's are summed after
-    k_out = pl.BlockSpec((1, 1, gk, d), lambda b_, h_, i: (b_, h_, i, 0))
-    vec_full = pl.BlockSpec((1, 1, sq, 1), lambda b_, h_, i: (b_, h_, 0, 0))
+    k_out = _operand(heads, d, gk, False)
+    vec_full = _statistic(heads, d, sq, True)
     out_dt = (k.dtype, v.dtype) if group == 1 else (jnp.float32, jnp.float32)
     return pl.pallas_call(
         functools.partial(_dkv_kernel, scale=scale, causal=causal, bq=bq, bk=bk,
-                          **_band_args(window)),
-        grid=(b, h, sk // gk),
+                          depth=d, **_band_args(window)),
+        grid=(b, h // hb, sk // gk),
         in_specs=[q_full, k_in, k_in, q_full, vec_full, vec_full],
         out_specs=[k_out, k_out],
-        out_shape=[jax.ShapeDtypeStruct((b, h, sk, d), out_dt[0]),
-                   jax.ShapeDtypeStruct((b, h, sk, d), out_dt[1])],
-        compiler_params=_params(sq, d, q.dtype.itemsize, vectors=2),
+        out_shape=[_shape_of(heads, b, h, sk, d, out_dt[0]),
+                   _shape_of(heads, b, h, sk, d, out_dt[1])],
+        compiler_params=_params(sq, hb * d, q.dtype.itemsize, vectors=2),
         interpret=interpret,
         name="ff_flash_attention_dkv",
     )(q, k, v, g, lse, delta)
 
 
-def _bwd(causal, scale, window, res, g):
+def _group_sum(x, group: int, depth: int, heads: int, dtype):
+    """A K/V head's dk or dv: its group's query heads' (float32), summed.
+    On the merged axis by lane slabs: splitting `[b, s, h * d]` into heads
+    would relay all of it (a head's rows lie eight positions a tile)."""
+    if not heads:
+        b, h, sk, d = x.shape
+        return x.reshape(b, h // group, group, sk, d).sum(axis=2).astype(dtype)
+    slabs = [x[:, :, j * depth:(j + 1) * depth] for j in range(heads)]
+    return jnp.concatenate(
+        [functools.reduce(jnp.add, slabs[j:j + group])
+         for j in range(0, heads, group)], axis=-1).astype(dtype)
+
+
+def _bwd(causal, scale, window, heads, res, g):
     q, k, v, o, lse = res
     # FLEXFLOW_FLASH_BLOCK_BWD tunes the backward independently (the dq /
     # dkv kernels have different VMEM/recompute balance than the forward);
     # unset = inherit FLEXFLOW_FLASH_BLOCK's choice
-    shape = (q.shape[2], k.shape[2], q.shape[3], q.dtype.itemsize, causal)
-    do = g.astype(jnp.float32)
-    delta = jnp.sum(do * o.astype(jnp.float32), axis=-1, keepdims=True)  # (b, h, sq, 1)
-    dq = _dq_call(q, k, v, g, lse, delta, causal, scale, *_tiles("dq", *shape),
-                  window=window)
+    _, h, kvh, sq, sk, d = _dims(q, k, heads)
+    shape = (sq, sk, d, q.dtype.itemsize, causal)
+    dq, delta = _dq_call(q, k, v, g, o, lse, causal, scale,
+                         *_tiles("dq", *shape), window=window, heads=heads)
     dk, dv = _dkv_call(q, k, v, g, lse, delta, causal, scale,
-                       *_tiles("dkv", *shape), window=window)
-    if k.shape[1] != q.shape[1]:    # a K/V head's: its group's, summed
-        b, kvh, sk, d = k.shape
-        dk, dv = (x.reshape(b, kvh, -1, sk, d).sum(axis=2).astype(k.dtype)
-                  for x in (dk, dv))
+                       *_tiles("dkv", *shape), window=window, heads=heads)
+    if kvh != h:
+        dk, dv = (_group_sum(x, h // kvh, d, heads, k.dtype) for x in (dk, dv))
     return dq, dk, dv
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _flash(q, k, v, causal, scale, window):
-    return _fwd(q, k, v, causal, scale, window)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash(q, k, v, causal, scale, window, heads):
+    return _fwd(q, k, v, causal, scale, window, heads, stats=False)
 
 
-def _flash_fwd(q, k, v, causal, scale, window):
+def _flash_fwd(q, k, v, causal, scale, window, heads):
     """The forward rule: both results of the kernel named `FLASH_KEPT` (a
-    recomputation drops the call only where every result is kept). `lse` is
-    named FLAT: as `(b, h, s, 1)` float32 the chip pads its last dimension
-    to 128 lanes, 128 times its size where it survives a recomputation."""
-    o, lse = _fwd(q, k, v, causal, scale, window)
-    o = checkpoint_name(o, FLASH_KEPT)
-    lse = checkpoint_name(lse.reshape(-1), FLASH_KEPT).reshape(lse.shape)
+    recomputation drops the call only where every result is kept), `lse` as
+    it lies: `(b, h, 1, s)` float32, the sequence on the lanes."""
+    o, lse = _fwd(q, k, v, causal, scale, window, heads)
+    o, lse = checkpoint_name(o, FLASH_KEPT), checkpoint_name(lse, FLASH_KEPT)
     return o, (q, k, v, o, lse)
 
 
@@ -741,58 +890,94 @@ _flash.defvjp(_flash_fwd, _bwd)
 
 
 # ------------------------------------------------------------------ public API
-def flash_attention(q, k, v, causal: bool = False, scale: float | None = None,
-                    window: int = 0):
-    """q: (b, h, sq, d), k/v: (b, h or K/V heads that divide h, sk, d) ->
-    (b, h, sq, d). `window` (causal only): query t sees the keys
-    t - window < s <= t; 0, or one that holds the sequence: every s <= t.
-
-    Raises ValueError when shapes don't qualify (sequence not divisible by a
-    block size, causal with sq != sk) — callers precheck with
-    flash_supported; a kernel that was chosen and then fails must fail.
-    """
-    if q.ndim != 4 or k.ndim != 4 or v.ndim != 4:
-        raise ValueError(f"expected rank-4 q/k/v, got {q.shape}/{k.shape}/{v.shape}")
-    if causal and q.shape[2] != k.shape[2]:
+def _checked(q, k, v, causal, scale, window, heads):
+    """Either form's call (`_dims`): raises ValueError when shapes don't
+    qualify (sequence not divisible by a block size, causal with sq != sk);
+    callers precheck with flash_supported, and a kernel that was chosen and
+    then fails must fail."""
+    b, h, kvh, sq, sk, d = _dims(q, k, heads)
+    if causal and sq != sk:
         raise ValueError("causal flash attention requires sq == sk "
-                         f"(got {q.shape[2]} vs {k.shape[2]})")
-    if k.shape[2] != v.shape[2]:
-        raise ValueError(f"k/v length mismatch {k.shape} vs {v.shape}")
-    if k.shape[1] != v.shape[1] or q.shape[1] % k.shape[1]:
-        raise ValueError(f"{k.shape[1]}/{v.shape[1]} K/V heads under "
-                         f"{q.shape[1]} query heads")
+                         f"(got {sq} vs {sk})")
+    if k.shape != v.shape:
+        raise ValueError(f"k/v mismatch {k.shape} vs {v.shape}")
+    if h % kvh or q.shape[-1] != (h * d if heads else d):
+        raise ValueError(f"{kvh} K/V heads under {h} query heads "
+                         f"({q.shape} / {k.shape})")
     if window < 0 or (window and not causal):
         raise ValueError(f"window {window} on a call that is not causal")
-    window = 0 if window >= k.shape[2] else int(window)
-    _pick_block(q.shape[2], q.shape[3], q.dtype.itemsize)
-    _pick_block(k.shape[2], k.shape[3], k.dtype.itemsize)
-    for s_, d_, it in ((q.shape[2], q.shape[3], q.dtype.itemsize),
-                      (k.shape[2], k.shape[3], k.dtype.itemsize)):
-        if 2 * s_ * d_ * it > _VMEM_SEQ_BYTES:
+    window = 0 if window >= sk else int(window)
+    _pick_block(sq, d, q.dtype.itemsize)
+    _pick_block(sk, d, k.dtype.itemsize)
+    for s_, it in ((sq, q.dtype.itemsize), (sk, k.dtype.itemsize)):
+        if 2 * s_ * d * it > _VMEM_SEQ_BYTES:
             # the Mosaic-reject precheck (same bound as flash_supported):
             # shapes whose VMEM-resident operands can't fit raise HERE, at
             # trace time, with a message that names the shape
             raise ValueError(
-                f"sequence {s_} x depth {d_} exceeds the VMEM-resident budget "
+                f"sequence {s_} x depth {d} exceeds the VMEM-resident budget "
                 f"({_VMEM_SEQ_BYTES} bytes); use the einsum or ring path")
     if scale is None:
-        scale = 1.0 / math.sqrt(q.shape[-1])
+        scale = 1.0 / math.sqrt(d)
     # one span a lowered call (trace time): the schedule each of the three
-    # kernels takes at this shape, for tools/trace_report.py
+    # kernels takes at this shape and how its operands lie, for
+    # tools/trace_report.py
     facts = {"window": window} if window else {}
     with tel.span("lower/flash_attention", cat="compile",
-                  batch_heads=q.shape[0] * q.shape[1], seq_q=q.shape[2],
-                  seq_k=k.shape[2], depth=q.shape[3], causal=bool(causal),
-                  kernels=tile_plan(q.shape[2], k.shape[2], q.shape[3],
-                                    q.dtype.itemsize, causal, window),
+                  batch_heads=b * h, seq_q=sq, seq_k=sk, depth=d,
+                  causal=bool(causal),
+                  entry=entry_of(d, h, kvh) if heads else "swapped",
+                  residual=RESIDUAL,
+                  kernels=tile_plan(sq, sk, d, q.dtype.itemsize, causal,
+                                    window),
                   **facts):
-        return _flash(q, k, v, causal, float(scale), window)
+        return _flash(q, k, v, causal, float(scale), window, heads)
+
+
+def flash_attention(q, k, v, causal: bool = False, scale: float | None = None,
+                    window: int = 0):
+    """The `swapped` entry. q: (b, h, sq, d), k/v: (b, h or K/V heads that
+    divide h, sk, d) -> (b, h, sq, d). `window` (causal only): query t sees
+    the keys t - window < s <= t; 0, or one that holds the sequence: every
+    s <= t. Unsupported shapes raise ValueError (`_checked`)."""
+    if q.ndim != 4 or k.ndim != 4 or v.ndim != 4:
+        raise ValueError(f"expected rank-4 q/k/v, got {q.shape}/{k.shape}/{v.shape}")
+    return _checked(q, k, v, causal, scale, window, 0)
+
+
+def flash_attention_merged(q, k, v, heads: int, causal: bool = False,
+                           scale: float | None = None, window: int = 0):
+    """The `merged` and `two_heads` entries: q `[b, sq, heads * d]`, k/v
+    `[b, sk, K/V heads * d]`, as `x @ wq` writes them and `@ wo` reads the
+    result `[b, sq, heads * d]`; no operand is relaid for the kernels, whose
+    blocks index the head (or pair of heads) along the lanes."""
+    if q.ndim != 3 or k.ndim != 3 or v.ndim != 3 or q.shape[2] % heads:
+        raise ValueError(f"expected [b, s, {heads} * d] q/k/v, got "
+                         f"{q.shape}/{k.shape}/{v.shape}")
+    d = q.shape[2] // heads
+    if k.shape[2] % d or entry_of(d, heads, k.shape[2] // d) == "swapped":
+        raise ValueError(f"heads of {d} under {q.shape}/{k.shape} are not "
+                         "whole 128-lane blocks: the swapped entry's")
+    return _checked(q, k, v, causal, scale, window, heads)
 
 
 def flash_attention_qkv(q, k, v, causal: bool = False, scale: float | None = None,
                         window: int = 0):
-    """Head-minor layout entry used by ops/attention_ops: q/k/v (b, s, h, d),
-    returns (b, sq, h, d). Unsupported shapes raise ValueError."""
+    """For a caller that holds split heads (a mesh's shard of
+    ops/attention_ops, the latent layers): q/k/v (b, s, h, d), returns (b,
+    sq, h, d). The shape decides the entry (`entry_of`): where a head is
+    whole lanes the split is undone and the kernels read the projections'
+    own layout; else the heads are swapped before the sequence and back.
+    On one device `_mha_lower` asks `entry_of` itself and hands
+    `flash_attention_merged` its projections unsplit. Unsupported shapes
+    raise ValueError."""
+    (b, sq, h, d), kvh = q.shape, k.shape[2]
+    if entry_of(d, h, kvh) != "swapped":
+        out = flash_attention_merged(
+            q.reshape(b, sq, h * d), k.reshape(b, -1, kvh * d),
+            v.reshape(b, -1, kvh * d), h, causal=causal, scale=scale,
+            window=window)
+        return out.reshape(b, sq, h, d)
     qt = jnp.swapaxes(q, 1, 2)
     kt = jnp.swapaxes(k, 1, 2)
     vt = jnp.swapaxes(v, 1, 2)

@@ -96,7 +96,9 @@ if TYPE_CHECKING:
 from flexflow_tpu import telemetry as tel
 from flexflow_tpu.core.tensor import TensorSpec
 from flexflow_tpu.kernels import sparse_attend_chunk, sparse_attend_step
-from flexflow_tpu.kernels.flash_attention import FLASH_KEPT
+from flexflow_tpu.kernels.flash_attention import (FLASH_KEPT, entry_of,
+                                                  flash_supported)
+from flexflow_tpu.kernels.head_turn import head_turn, turn_supported
 from flexflow_tpu.kernels.partition import dividing, multi_device, per_shard
 from flexflow_tpu.ops.norm_ops import rms_norm
 from flexflow_tpu.ops.op_type import OperatorType
@@ -185,14 +187,18 @@ def _positioned(layer: Layer) -> bool:
     return _has_positions(layer) or bool(layer.params.get("qk_norm"))
 
 
-def _turner(layer: Layer, inputs, weights):
+def _turner(layer: Layer, inputs, weights, merged: bool = False):
     """`turn(heads [b, s, h, d], norm weight's name)`: the per-head RMS norm
     where the layer has one, then the rotation at `positions` (the fourth
-    input) where it has them."""
+    input) where it has them. `merged`: `turn` takes and returns the
+    projection as it lies, `[b, s, h * d]`, for a sequence that goes on to
+    the flash kernels so: one pass over the merged axis
+    (kernels/head_turn.py) where a head is whole lanes, else the steps
+    above between a split and a merge."""
     p = layer.params
+    hd = p["embed_dim"] // p["num_heads"]
     tables = None
     if _has_positions(layer):
-        hd = p["embed_dim"] // p["num_heads"]
         cos, sin = half_tables(inputs[3], hd, p.get("rope_theta", 10000.0),
                                p.get("mrope_section"), p.get("rope_scaling"))
         tables = cos[:, :, None], sin[:, :, None]           # [b, s, 1, d]
@@ -202,7 +208,19 @@ def _turner(layer: Layer, inputs, weights):
             heads = rms_norm(heads, weights[norm], p.get("qk_norm_eps", 1e-6))
         return heads if tables is None else apply_rope_half(heads, *tables)
 
-    return turn
+    def turn_merged(x, norm):
+        b, s, e = x.shape
+        if not turn_supported(s, hd):
+            return turn(x.reshape(b, s, e // hd, hd), norm).reshape(x.shape)
+        # a table a position of each sequence, the sine's sign folded in
+        signed = (None, None) if tables is None else (
+            jnp.broadcast_to(cos, (b, s, hd)),
+            jnp.broadcast_to(sin * jnp.where(jnp.arange(hd) < hd // 2,
+                                             -1.0, 1.0), (b, s, hd)))
+        return head_turn(x, weights[norm] if p.get("qk_norm") else None,
+                         *signed, e // hd, float(p.get("qk_norm_eps", 1e-6)))
+
+    return turn_merged if merged else turn
 
 
 def _kv_heads(p) -> int:
@@ -713,16 +731,15 @@ def _attn_pspec(layer: Layer, ctx: LoweringCtx, batch, heads: int):
     return PartitionSpec(bdim, None, hdim, None)
 
 
-def _flash_covers(qh, kh, vh, causal: bool) -> bool:
+def _flash_covers(sq: int, sk: int, sv: int, depth: int, itemsize: int,
+                  causal: bool) -> bool:
     """The auto path's precheck, from shapes alone: exactly the conditions
-    flash_attention() validates (q/k/v are (b, s, h, d))."""
-    from flexflow_tpu.kernels.flash_attention import flash_supported
-
-    sq, sk, d = qh.shape[1], kh.shape[1], qh.shape[3]
-    if vh.shape[1] != sk or (causal and sq != sk):
+    flash_attention() validates (the lengths of q, k and v, a head's
+    width)."""
+    if sv != sk or (causal and sq != sk):
         return False
-    it = qh.dtype.itemsize
-    return flash_supported(sq, d, it) and flash_supported(sk, d, it)
+    return flash_supported(sq, depth, itemsize) \
+        and flash_supported(sk, depth, itemsize)
 
 
 def _einsum_attention(layer: Layer, qh, kh, vh, sk_orig: int, scale,
@@ -779,38 +796,10 @@ def _mha_lower(layer: Layer, inputs, weights, ctx: LoweringCtx):
 
     kp = proj(k, "wk", "bk")
     vp = proj(v, "wv", "bv")
-    turn = _turner(layer, inputs, weights) if _positioned(layer) else None
-    if p.get("kv_out", False):
-        # serving prefill: expose the per-head K/V of the prompt tokens so
-        # the engine can commit them into the paged cache (captured BEFORE
-        # any bias_kv/zero_attn positions could pollute the cache; k as the
-        # scores see it: after its norm and rotation)
-        k_out = _split_heads(kp, kvh)
-        if turn is not None:
-            k_out = turn(k_out, "k_norm")
-        ctx.new_state[layer.name] = {"k": k_out, "v": _split_heads(vp, kvh)}
-    if "bias_k" in weights:  # add_bias_kv: learned extra kv position
-        b_ = k.shape[0]
-        kp = jnp.concatenate([kp, jnp.broadcast_to(weights["bias_k"].astype(dt), (b_, 1, embed))], axis=1)
-        vp = jnp.concatenate([vp, jnp.broadcast_to(weights["bias_v"].astype(dt), (b_, 1, embed))], axis=1)
-    if p.get("add_zero_attn", False):
-        b_ = k.shape[0]
-        kp = jnp.concatenate([kp, jnp.zeros((b_, 1, embed), dt)], axis=1)
-        vp = jnp.concatenate([vp, jnp.zeros((b_, 1, embed), dt)], axis=1)
-    qh = _split_heads(proj(q, "wq", "bq"), heads)  # (b, sq, h, d)
-    kh = _split_heads(kp, kvh)
-    vh = _split_heads(vp, kvh)
-    if turn is not None:    # k once: what the prefill twin handed out
-        qh = turn(qh, "q_norm")
-        kh = k_out if p.get("kv_out", False) else turn(kh, "k_norm")
     impl = p.get("impl", "auto")
     causal = p.get("causal", False)
-    scale = _scale(p, embed // heads)
-    out = None
-    # a window that holds the sequence is the plain causal mask: `band` is
-    # the window where it masks anything, else 0
-    window = int(p.get("window") or 0)
-    band = window if 0 < window < k.shape[1] else 0
+    head_dim = embed // heads
+    scale = _scale(p, head_dim)
     # flash kernel has no probs-dropout path: fall back (or fail under
     # impl="flash") rather than silently dropping the dropout mask
     needs_dropout = ctx.training and p.get("dropout", 0.0) > 0.0
@@ -819,14 +808,56 @@ def _mha_lower(layer: Layer, inputs, weights, ctx: LoweringCtx):
                                   "dropout; use dropout=0.0 or impl='xla'")
     # "auto" uses the fused pallas kernel only when fusion is enabled
     # (--fusion, reference FusedOp gate) AND the shape qualifies — decided
-    # here, before tracing; impl="flash" forces it regardless. A kernel that
-    # was chosen and then raises (trace, Mosaic compile) propagates: it
-    # never silently becomes the einsum path.
-    spec = _attn_pspec(layer, ctx, qh.shape[0], heads)
+    # here from shapes, before tracing; impl="flash" forces it regardless. A
+    # kernel that was chosen and then raises (trace, Mosaic compile)
+    # propagates: it never silently becomes the einsum path.
+    extra = ("bias_k" in weights) + bool(p.get("add_zero_attn", False))
+    spec = _attn_pspec(layer, ctx, q.shape[0], heads)
     flash = not p.get("selected") and not needs_dropout and (
         impl == "flash" or (impl == "auto" and ctx.enable_fusion
-                            and _flash_covers(qh, kh, vh, causal)
+                            and _flash_covers(q.shape[1], k.shape[1] + extra,
+                                              v.shape[1] + extra, head_dim,
+                                              dt.itemsize, causal)
                             and spec is not None))
+    # one device and a head in whole 128-lane blocks (or two heads a block):
+    # the flash kernels read q, k, v as the projections wrote them and hand
+    # `o` back so, `[b, s, h * d]`; nothing from here to `@ wo` splits them
+    # (a mesh's shards split first: a shard's heads are the mesh's)
+    merged = flash and not multi_device(ctx.mesh) \
+        and entry_of(head_dim, heads, kvh) != "swapped"
+    heads_of = (lambda x, n: x) if merged else _split_heads
+    turn = _turner(layer, inputs, weights, merged) if _positioned(layer) \
+        else None
+    if p.get("kv_out", False):
+        # serving prefill: expose the per-head K/V of the prompt tokens so
+        # the engine can commit them into the paged cache (captured BEFORE
+        # any bias_kv/zero_attn positions could pollute the cache; k as the
+        # scores see it: after its norm and rotation)
+        k_out = heads_of(kp, kvh)
+        if turn is not None:
+            k_out = turn(k_out, "k_norm")
+        ctx.new_state[layer.name] = {
+            "k": _split_heads(k_out, kvh) if merged else k_out,
+            "v": _split_heads(vp, kvh)}
+    if "bias_k" in weights:  # add_bias_kv: learned extra kv position
+        b_ = k.shape[0]
+        kp = jnp.concatenate([kp, jnp.broadcast_to(weights["bias_k"].astype(dt), (b_, 1, embed))], axis=1)
+        vp = jnp.concatenate([vp, jnp.broadcast_to(weights["bias_v"].astype(dt), (b_, 1, embed))], axis=1)
+    if p.get("add_zero_attn", False):
+        b_ = k.shape[0]
+        kp = jnp.concatenate([kp, jnp.zeros((b_, 1, embed), dt)], axis=1)
+        vp = jnp.concatenate([vp, jnp.zeros((b_, 1, embed), dt)], axis=1)
+    qh = heads_of(proj(q, "wq", "bq"), heads)   # (b, sq, h, d) or as it lies
+    kh = heads_of(kp, kvh)
+    vh = heads_of(vp, kvh)
+    if turn is not None:    # k once: what the prefill twin handed out
+        qh = turn(qh, "q_norm")
+        kh = k_out if p.get("kv_out", False) else turn(kh, "k_norm")
+    out = None
+    # a window that holds the sequence is the plain causal mask: `band` is
+    # the window where it masks anything, else 0
+    window = int(p.get("window") or 0)
+    band = window if 0 < window < k.shape[1] else 0
     # the flash kernels on one device read a group's K/V head through the
     # block index; every other form sees one K/V head a query head
     grouped = flash and kvh != heads and not multi_device(ctx.mesh)
@@ -842,7 +873,7 @@ def _mha_lower(layer: Layer, inputs, weights, ctx: LoweringCtx):
     # sequence parallelism: the searched strategy may place this attention
     # on the ring path (sp_ring candidate -> {"seq_parallel": axis} attr)
     sp_axis = ctx.op_attrs.get(layer.name, {}).get("seq_parallel")
-    if out is None and sp_axis and ctx.mesh is not None \
+    if out is None and sp_axis and multi_device(ctx.mesh) \
             and sp_axis in ctx.mesh.shape \
             and impl != "xla" and qh.shape[1] == kh.shape[1] == vh.shape[1] \
             and qh.shape[1] % ctx.mesh.shape[sp_axis] == 0 \
@@ -862,7 +893,7 @@ def _mha_lower(layer: Layer, inputs, weights, ctx: LoweringCtx):
     scope = contextlib.nullcontext() if "window" not in p else \
         jax.named_scope(WINDOW_SCOPE if window else FULL_SCOPE)
     if out is None and "window" in p:
-        sq, rows = qh.shape[1], qh.shape[0] * heads
+        sq, rows = q.shape[1], q.shape[0] * heads
         triangle, outside = sq * (sq + 1) // 2, (sq - band) * (sq - band + 1) // 2
         ctx.add_stat("window_keys_seen" if window else "full_keys_seen",
                      jnp.float32(rows * (triangle - (outside if band else 0))))
@@ -873,20 +904,22 @@ def _mha_lower(layer: Layer, inputs, weights, ctx: LoweringCtx):
             from flexflow_tpu.kernels.flash_attention import tile_plan
 
             form = "flash/window" if band else "flash/full"
-            tile = tile_plan(sq, sq, qh.shape[3], qh.dtype.itemsize, causal,
+            tile = tile_plan(sq, sq, head_dim, dt.itemsize, causal,
                              band)["fwd"]
         tel.record(form, tel.now_us(), cat="compile", layer=layer.name,
                    window=window, seq=sq, **tile)
     if out is None and flash:
-        from flexflow_tpu.kernels.flash_attention import flash_attention_qkv
+        from flexflow_tpu.kernels.flash_attention import (
+            flash_attention_merged, flash_attention_qkv)
 
         # seq and depth stay whole per shard, so _flash_covers holds there
         # (a forced impl="flash" with no placement goes in unsplit); a call
         # without a window is the call it was before windows existed
+        attend = functools.partial(flash_attention_merged, heads=heads) \
+            if merged else flash_attention_qkv
         with scope:
             out = per_shard(
-                functools.partial(flash_attention_qkv, causal=causal,
-                                  scale=scale,
+                functools.partial(attend, causal=causal, scale=scale,
                                   **({"window": band} if band else {})),
                 ctx.mesh if spec is not None else None,
                 (spec, spec, spec), spec)(qh, kh, vh)

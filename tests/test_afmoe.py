@@ -395,6 +395,10 @@ def test_the_flash_path_is_taken_under_a_window_and_says_so():
     spans = [s for s in tel.ring_spans() if s.args
              and s.args.get("layer") == "attn"][-2:]
     assert [s.name for s in spans] == ["flash/window", "xla/masked"]
+    # heads of 128: the merged entry, the head norm and the rotation on the
+    # merged axis before it (PR 63: kernels/head_turn.py)
+    assert tel.ring_spans("lower/flash_attention")[-1].args["entry"] \
+        == "merged"
     assert spans[0].args["window"] == 100 and spans[0].args["flash_tile_q"]
     assert spans[0].args["flash_tiles_visited"] \
         < spans[0].args["flash_tiles_total"]

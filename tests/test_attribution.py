@@ -372,17 +372,34 @@ AGAIN = '  %ff_flash_attention_fwd.5 = (f32[8,8]{1,0}, f32[8]{0}) ' \
     'rematted_computation/LAYER/jit(_fwd_call)/pallas_call"}\n'
 
 
-@pytest.mark.parametrize("again,passes", [
-    ("", 1.0), (AGAIN.replace("LAYER", "attn"), 1.5),
-    (AGAIN.replace("LAYER", "attn") + AGAIN.replace("LAYER", "attn2"), 2.0)])
-def test_step_passes_counts_the_recomputations_flash_calls(again, passes):
+# what the swapped entry leaves around a flash call: the operand swapped (q's
+# size: counted) and the compiler's own copy of the layer's result (no name
+# stack: the layer's by what it serves; q's size too), beside a small copy
+# (a statistic's: not counted) and the optimizer's (no attention layer's)
+RELAYOUTS = """  %swap.7 = f32[8,8]{0,1} transpose(%a), dimensions={1,0}, metadata={op_name="jit(step)/jvp()/checkpoint/attn/transpose"}
+  %copy.8 = f32[8,8]{1,0} copy(%swap.7)
+  %ff_flash_attention_dkv.9 = f32[8,8]{1,0} custom-call(%copy.8), custom_call_target="tpu_custom_call", metadata={op_name="jit(step)/transpose(jvp())/checkpoint/attn/jit(_dkv_call)/pallas_call"}
+  %small.10 = f32[8]{0} copy(%a), metadata={op_name="jit(step)/jvp()/checkpoint/attn/reduce"}
+"""
+
+
+@pytest.mark.parametrize("again,passes,relayouts", [
+    ("", 1.0, 0.0), (AGAIN.replace("LAYER", "attn"), 1.5, 0.0),
+    (AGAIN.replace("LAYER", "attn") + AGAIN.replace("LAYER", "attn2"), 2.0,
+     0.0), (RELAYOUTS, 1.0, 1.0)])
+def test_step_passes_counts_the_recomputations_flash_calls(again, passes,
+                                                           relayouts):
     """The forward kernel's calls under the graph's layers, all phases over
     the forward's: a checkpoint's recomputation carries the backward
-    pass's wrapper. A program without the kernel says nothing."""
+    pass's wrapper. Beside them `flash_relayouts` (PR 63): the `copy` /
+    `transpose` instructions of an attention layer as large as its q, a
+    layer that calls the kernel (two of them over two layers here; 0 where
+    the kernels read what the projections wrote). A program without the
+    kernel says nothing."""
     op_types = {"attn": "multihead_attention", "attn2": "multihead_attention"}
     text = STEP_WITH_A_UNIT.replace("%AGAIN%", again)
     assert attribution.step_passes(text, op_types) \
-        == {"flash_fwd_passes": passes}
+        == {"flash_fwd_passes": passes, "flash_relayouts": relayouts}
     assert attribution.step_passes(
         text.replace("ff_flash_attention_fwd", "fusion"), op_types) == {}
     assert attribution.routing_passes(text, op_types) is None

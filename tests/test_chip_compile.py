@@ -34,7 +34,7 @@ sys.path.insert(0, ROOT)  # chip_smoke
 KERNEL_MODULES = ("flash_attention", "dequant_attention", "ssd_scan",
                   "kda_scan", "retention_step", "moe_step", "mamba2_step",
                   "sparse_attend_step", "sparse_attend_chunk", "moe_rows",
-                  "selective_scan")
+                  "selective_scan", "head_turn")
 
 # GPT-2 medium: batch 8, 16 heads of 64, seq 1024
 B, H, S, D = 8, 16, 1024, 64
@@ -228,7 +228,9 @@ def test_flash_attention_causal_tiles(one_chip, mosaic, bq, bk):
     """The three kernels at the tiles the causal rule takes at GPT-2
     medium's shape, and at unequal q and k tiles both ways: the static
     schedule under the diagonal (slices at tile multiples, a masked product
-    per row of tiles) has to pass Mosaic, not only interpret mode."""
+    per row of tiles) has to pass Mosaic, not only interpret mode; through
+    the swapped entry and two heads a 128-lane block (PR 63: each head's 64
+    lanes a static slice of the tile), `lse` and `delta` as rows."""
     import importlib
 
     fa = importlib.import_module("flexflow_tpu.kernels.flash_attention")
@@ -239,46 +241,82 @@ def test_flash_attention_causal_tiles(one_chip, mosaic, bq, bk):
                 == (bq, bk)
             assert plan[kern]["flash_tiles_visited"] \
                 < plan[kern]["flash_tiles_total"]
-    qkv = ((B, H, S, D), jnp.bfloat16)
-    vec = ((B, H, S, 1), jnp.float32)
+    vec = ((B, H, 1, S), jnp.float32)
     scale = 1.0 / np.sqrt(D)
-    _compile(lambda q, k, v: fa._fwd_call(q, k, v, True, scale, bq, bk),
-             one_chip, qkv, qkv, qkv)
-    _compile(lambda *a: fa._dq_call(*a, True, scale, bq, bk),
-             one_chip, qkv, qkv, qkv, qkv, vec, vec)
-    _compile(lambda *a: fa._dkv_call(*a, True, scale, bq, bk),
-             one_chip, qkv, qkv, qkv, qkv, vec, vec)
+    assert fa.entry_of(D, H, H) == "two_heads"
+    for heads, qkv in ((0, ((B, H, S, D), jnp.bfloat16)),
+                       (H, ((B, S, H * D), jnp.bfloat16))):
+        form = {"heads": heads}
+        _compile(lambda q, k, v: fa._fwd_call(q, k, v, True, scale, bq, bk,
+                                              **form),
+                 one_chip, qkv, qkv, qkv)
+        _compile(lambda *a: fa._dq_call(*a, True, scale, bq, bk, **form),
+                 one_chip, qkv, qkv, qkv, qkv, qkv, vec)
+        _compile(lambda *a: fa._dkv_call(*a, True, scale, bq, bk, **form),
+                 one_chip, qkv, qkv, qkv, qkv, vec, vec)
 
 
-@pytest.mark.parametrize("window", [2048, 0])
-def test_flash_attention_under_a_window_at_8k(one_chip, mosaic, window):
+@pytest.mark.parametrize("window,entry", [
+    (2048, "merged"), (0, "merged"), (2048, "swapped")])
+def test_flash_attention_under_a_window_at_8k(one_chip, mosaic, window, entry):
     """Trinity-Mini's attention at the trained shape: two sequences of 8192,
     32 query heads over 4 K/V heads of 128 (read through the block index),
-    the three kernels under a window of 2048 and without one. The backward's
-    whole-sequence operands pass Mosaic's default VMEM scope, so the calls
-    ask for their own."""
+    the three kernels under a window of 2048 and without one, as the step
+    calls them (PR 63: operands `[b, s, h * d]`, the head the lane block)
+    and through the swapped entry. The backward's whole-sequence operands
+    pass Mosaic's default VMEM scope, so the calls ask for their own. No
+    statistic is `[.., 8192, 1]` and none is relaid: `lse` and `delta` are
+    `f32[2,32,1,8192]`, 2.1 MB as they lie."""
     import importlib
 
     fa = importlib.import_module("flexflow_tpu.kernels.flash_attention")
     b, h, kv, s, d = 2, 32, 4, 8192, 128
+    assert fa.entry_of(d, h, kv) == "merged"
+    attend = fa.flash_attention if entry == "swapped" else \
+        lambda q, k, v, **kw: fa.flash_attention_merged(q, k, v, h, **kw)
 
     def grads(q, k, v, ct):
-        return jax.grad(lambda q, k, v: jnp.sum(fa.flash_attention(
+        return jax.grad(lambda q, k, v: jnp.sum(attend(
             q, k, v, causal=True, window=window).astype(jnp.float32) * ct),
             (0, 1, 2))(q, k, v)
 
-    compiled = _compile(grads, one_chip, ((b, h, s, d), jnp.bfloat16),
-                        ((b, kv, s, d), jnp.bfloat16),
-                        ((b, kv, s, d), jnp.bfloat16),
-                        ((b, h, s, d), jnp.float32))
+    def shape(heads):
+        return (b, heads, s, d) if entry == "swapped" else (b, s, heads * d)
+
+    compiled = _compile(grads, one_chip, (shape(h), jnp.bfloat16),
+                        (shape(kv), jnp.bfloat16), (shape(kv), jnp.bfloat16),
+                        (shape(h), jnp.float32))
     text = compiled.as_text()
     for kernel in ("fwd", "dq", "dkv"):
         assert f"ff_flash_attention_{kernel}" in text
     assert not re.search(r"\[\d+,\d+,8192,8192\]", text)
+    assert "f32[2,32,1,8192]{3,2,1,0:T(1,128)" in text
+    assert not re.search(r"f32\[[\d,]*8192,1\]", text)
     plan = fa.tile_plan(s, s, d, 2, True, window)
     if window:      # 16 grid steps of 512, at most 5 key blocks a step: 70 of 136
         assert plan["fwd"]["flash_tiles_visited"] \
             < 0.55 * fa.tile_plan(s, s, d, 2, True)["fwd"]["flash_tiles_visited"]
+
+
+def test_head_turn_at_the_trained_widths(one_chip, mosaic):
+    """PR 63: the head norm and the rotation on the merged axis
+    (kernels/head_turn.py) at Trinity-Mini's q and k, forward and backward
+    through Mosaic: a lane roll by half a head, a lane reduction a head,
+    d gamma summed over a grid axis."""
+    from flexflow_tpu.kernels.head_turn import head_turn
+
+    b, s, d = 2, 8192, 128
+    for heads, tables in ((32, True), (4, False)):
+        def grads(x, gamma, cos, sin, ct):
+            return jax.grad(lambda x, gamma: jnp.sum(head_turn(
+                x, gamma, cos if tables else None, sin if tables else None,
+                heads, 1e-6).astype(jnp.float32) * ct), (0, 1))(x, gamma)
+
+        text = _compile(grads, one_chip, ((b, s, heads * d), jnp.bfloat16),
+                        ((d,), jnp.bfloat16), ((b, s, d), jnp.float32),
+                        ((b, s, d), jnp.float32),
+                        ((b, s, heads * d), jnp.float32)).as_text()
+        assert "ff_head_turn_bwd" in text
 
 
 @pytest.mark.parametrize("q_tokens", [1, 5])
@@ -483,6 +521,16 @@ def test_the_update_passes_over_each_leaf_as_it_lies(described_devices,
     assert "ff_fused_optim" not in text
     assert cs.kernels_in(text)["flash_attention"] >= 6
     assert _whole_weight_relayouts(text, cm) == []
+    # PR 63: the flash kernels read q, k, v and write o as the projections
+    # hold them (two heads of 64 a 128-lane block): no q-sized `copy` /
+    # `transpose` under an attention layer (8 a layer before), and no
+    # `[b, h, s, 1]` statistic in the step (`lse`, `delta`: `f32[8,16,1,1024]`)
+    from flexflow_tpu import attribution
+
+    op_types = {l.name: l.op_type.value for l in cm.model.layers}
+    assert attribution.step_passes(text, op_types) \
+        == {"flash_fwd_passes": 1.0, "flash_relayouts": 0.0}
+    assert not re.search(r"f32\[\d+,\d+,1024,1\]", text)
     pshapes, _ = cm._param_templates()
     held = 3 * sum(s.size * s.dtype.itemsize
                    for s in jax.tree_util.tree_leaves(pshapes))
@@ -693,9 +741,36 @@ def test_gigachat_serving_programs_fit_one_chip(described_devices, mosaic,
     assert "ff_moe_rows" in text and "ragged-dot" not in text
     # each of the five expert layers sizes its row buffers at run time: a
     # real conditional inside the loop over blocks (one branch runs), whose
-    # branches need no more room than the whole block's rows did (3.27 GB)
+    # branches need no more room than the whole block's rows do: that form
+    # (no ladder, one buffer of tokens x k rows) is compiled beside it here,
+    # and the two differ by 1.6 MB (3 431.8 against 3 430.2 MB; 3 260.2
+    # against 3 258.5 at PR 62's tree: the wave is fullest elsewhere)
     assert len(re.findall(r" conditional\(", text)) >= 5
-    assert prefill.memory_analysis().temp_size_in_bytes <= 3.27e9
+    from flexflow_tpu.ops import moe_ops
+
+    monkeypatch.setattr(moe_ops, "_row_capacities", lambda pairs: [pairs])
+    eng_w, _g, params_w, _state = _described_engine(
+        "GigaChat3.1-702B-A36B.serve-chat", described_devices, monkeypatch,
+        one_chip)
+    whole = eng_w._prefill_first_tokens_jit.lower(
+        params_w, wave, _i32(one_chip, slots)).compile()
+    assert " conditional(" not in whole.as_text()
+    m = prefill.memory_analysis()
+    assert m.temp_size_in_bytes \
+        <= whole.memory_analysis().temp_size_in_bytes + 4e6
+    # `temp_size_in_bytes` is the compiler's heap: what is live where the
+    # wave is fullest AND the holes between, of a schedule nothing pressed
+    # (3.26 GB at PR 62's tree, 3.43 here; told that the chip has 12 GiB,
+    # the same compiler packs PR 62's wave into 2.77 GiB and this one into
+    # 2.72). What is live there is the number a buffer that outlives its
+    # call would move: 2.670 GB, at layer 0's dense `[16,1024,36864]` and
+    # its copy. Until PR 63 it read 2.963 GB, at a latent layer's flash
+    # call: q, k, v, o of 512 MiB each (192 lanes in 256) and 512 MiB of
+    # `lse` as `f32[16,64,1024,1]`, which a wave no longer writes
+    live = prefill.runtime_executable().get_compiled_memory_stats() \
+        .peak_memory_in_bytes - m.argument_size_in_bytes \
+        - m.output_size_in_bytes
+    assert live <= 2.68e9, live
     text = decode.as_text()
     assert " conditional(" not in text
     # the decompressed K/V of a slot's context: [.., 1280, 64, 320] or merged
@@ -1288,7 +1363,8 @@ def test_trinity_train_step_fits_one_chip(described_devices, mosaic):
     phases over the forward's; 3 before: the `remat_blocks` unit's
     recomputation and the block's each decided again), and the flash
     forward kernel run ONCE a layer (PR 61: the unit keeps what it writes,
-    `lse` flat, so the five kept pairs cost 0.68 GB and not 2 GB)."""
+    so the five kept pairs cost 0.68 GB and not 2 GB; since PR 63 `lse`
+    lane-dense as the kernel writes it)."""
     sys.path.insert(0, os.path.join(ROOT, "benchmarks"))
     from families import family_of
     from harness import manifest as mf
@@ -1337,8 +1413,19 @@ def test_trinity_train_step_fits_one_chip(described_devices, mosaic):
     from flexflow_tpu import attribution
 
     op_types = {l.name: l.op_type.value for l in model.layers}
+    # PR 63: the kept `lse` is `f32[2,32,1,8192]` as it lies (2.1 MB a
+    # layer), no statistic is `[.., 8192, 1]`, and no q-sized `copy` /
+    # `transpose` stands under an attention layer (9 a layer before): the
+    # kernels read `[b, s, h * d]` as the projections and the head norm and
+    # rotation (`ff_head_turn_*`, on the merged axis too) write it
     assert attribution.step_passes(text, op_types) \
-        == {"moe_routing_passes": 1.0, "flash_fwd_passes": 1.0}
+        == {"moe_routing_passes": 1.0, "flash_fwd_passes": 1.0,
+            "flash_relayouts": 0.0}
+    assert not re.search(r"f32\[\d+,\d+,8192,1\]", text)
+    assert "f32[2,32,1,8192]{3,2,1,0:T(1,128)" in text
+    for kernel, calls in (("ff_head_turn_fwd", 20), ("ff_head_turn_bwd", 10)):
+        found = re.findall(rf"%{kernel}[.\d]* = \S.* custom-call\(", text)
+        assert len(found) == calls, (kernel, len(found))
 
 
 def test_jamba_serving_programs_fit_one_chip(described_devices, mosaic,

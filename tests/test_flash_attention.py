@@ -11,7 +11,37 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from flexflow_tpu.kernels.flash_attention import flash_attention, flash_attention_qkv
+from flexflow_tpu.kernels.flash_attention import (entry_of, flash_attention,
+                                                  flash_attention_merged,
+                                                  flash_attention_qkv)
+
+
+def _merge(x):
+    """(b, h, s, d) -> [b, s, h * d], as a projection writes it."""
+    b, h, s, d = x.shape
+    return jnp.swapaxes(x, 1, 2).reshape(b, s, h * d)
+
+
+def _split(x, heads):
+    b, s, e = x.shape
+    return jnp.swapaxes(x.reshape(b, s, heads, e // heads), 1, 2)
+
+
+def _through(entry):
+    """`attend(q, k, v (b, h, s, d), **kw) -> (b, h, s, d)` through one of
+    the kernels' entries: `swapped` reads the operands as they are given,
+    `merged` (d = 128) and `two_heads` (d = 64) read them merged, the head
+    (or pair of heads) the block index along the lanes. The shape has to
+    be one that takes the entry (`entry_of`)."""
+    if entry == "swapped":
+        return flash_attention
+
+    def attend(q, k, v, **kw):
+        assert entry_of(q.shape[3], q.shape[1], k.shape[1]) == entry
+        return _split(flash_attention_merged(_merge(q), _merge(k), _merge(v),
+                                             q.shape[1], **kw), q.shape[1])
+
+    return attend
 
 
 def _reference(q, k, v, causal, scale):
@@ -24,33 +54,42 @@ def _reference(q, k, v, causal, scale):
     return jnp.einsum("bhqk,bhkd->bhqd", probs.astype(q.dtype), v)
 
 
-@pytest.mark.parametrize("causal", [False, True])
-def test_forward_matches_einsum(causal):
+@pytest.mark.parametrize("causal,entry,h", [
+    (False, "two_heads", 4), (True, "swapped", 3)])
+def test_forward_matches_einsum(causal, entry, h):
+    """Without a mask through two heads a 128-lane block (a width-64
+    encoder's path), causal through the swapped entry (three heads are no
+    pairs)."""
     rng = np.random.default_rng(0)
-    b, h, s, d = 2, 3, 256, 64
+    b, s, d = 2, 256, 64
     q = jnp.asarray(rng.normal(size=(b, h, s, d)), jnp.float32)
     k = jnp.asarray(rng.normal(size=(b, h, s, d)), jnp.float32)
     v = jnp.asarray(rng.normal(size=(b, h, s, d)), jnp.float32)
-    out = flash_attention(q, k, v, causal=causal)
+    out = _through(entry)(q, k, v, causal=causal)
     ref = _reference(q, k, v, causal, 1.0 / np.sqrt(d))
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5, rtol=2e-5)
 
 
-def test_cross_attention_lengths():
+@pytest.mark.parametrize("entry,d", [("swapped", 32), ("merged", 128)])
+def test_cross_attention_lengths(entry, d):
+    """sq != sk, through the swapped entry and from the projections' own
+    layout: q's blocks and the keys' follow their own lengths."""
     rng = np.random.default_rng(1)
-    b, h, d = 2, 2, 32
+    b, h = 2, 2
     q = jnp.asarray(rng.normal(size=(b, h, 128, d)), jnp.float32)
     k = jnp.asarray(rng.normal(size=(b, h, 256, d)), jnp.float32)
     v = jnp.asarray(rng.normal(size=(b, h, 256, d)), jnp.float32)
-    out = flash_attention(q, k, v, causal=False)
+    out = _through(entry)(q, k, v, causal=False)
     ref = _reference(q, k, v, False, 1.0 / np.sqrt(d))
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5, rtol=2e-5)
 
 
-@pytest.mark.parametrize("causal", [False, True])
-def test_gradients_match_einsum(causal):
+@pytest.mark.parametrize("causal,entry,d", [
+    (False, "two_heads", 64), (True, "swapped", 32)])
+def test_gradients_match_einsum(causal, entry, d):
     rng = np.random.default_rng(2)
-    b, h, s, d = 1, 2, 128, 32
+    b, h, s = 1, 2, 128
+    flash_attention = _through(entry)
     q = jnp.asarray(rng.normal(size=(b, h, s, d)), jnp.float32)
     k = jnp.asarray(rng.normal(size=(b, h, s, d)), jnp.float32)
     v = jnp.asarray(rng.normal(size=(b, h, s, d)), jnp.float32)
@@ -109,8 +148,8 @@ def test_mha_layer_uses_flash():
     np.testing.assert_allclose(outs["flash"], outs["xla"], atol=2e-4, rtol=2e-4)
 
 
-@pytest.mark.parametrize("causal", [False, True])
-def test_head_dim_128_parity(causal):
+@pytest.mark.parametrize("causal,entry", [(False, "merged"), (True, "swapped")])
+def test_head_dim_128_parity(causal, entry):
     """Satellite (round-5 MFU note): the block-shape ceiling was sized for
     head_dim 64 — head_dim 128 must pick a depth-aware block (512-row f32
     blocks would double the per-operand VMEM footprint) and still match
@@ -128,13 +167,14 @@ def test_head_dim_128_parity(causal):
     k = jnp.asarray(rng.normal(size=(b, h, s, d)), jnp.float32)
     v = jnp.asarray(rng.normal(size=(b, h, s, d)), jnp.float32)
     scale = 1.0 / np.sqrt(d)
-    out = flash_attention(q, k, v, causal=causal)
+    attend = _through(entry)
+    out = attend(q, k, v, causal=causal)
     ref = _reference(q, k, v, causal, scale)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=5e-5, rtol=5e-5)
 
     def f_flash(q, k, v):
-        return jnp.sum(flash_attention(q, k, v, causal=causal) ** 2)
+        return jnp.sum(attend(q, k, v, causal=causal) ** 2)
 
     def f_ref(q, k, v):
         return jnp.sum(_reference(q, k, v, causal, scale) ** 2)
@@ -146,8 +186,9 @@ def test_head_dim_128_parity(causal):
                                    atol=1e-3, rtol=1e-3)
 
 
-@pytest.mark.parametrize("causal", [False, True])
-def test_head_dim_64_tile_rule_parity(causal):
+@pytest.mark.parametrize("causal,entry", [
+    (False, "swapped"), (True, "two_heads")])
+def test_head_dim_64_tile_rule_parity(causal, entry):
     """ISSUE 36: the VMEM budget bounds a tile from above (narrow heads up
     to 1024 rows) and a causal call picks under it: never the whole causal
     square where a smaller tile divides the sequence, so the tiles above
@@ -178,13 +219,14 @@ def test_head_dim_64_tile_rule_parity(causal):
     k = jnp.asarray(rng.normal(size=(b, h, s, d)), jnp.float32)
     v = jnp.asarray(rng.normal(size=(b, h, s, d)), jnp.float32)
     scale = 1.0 / np.sqrt(d)
-    out = flash_attention(q, k, v, causal=causal)
+    attend = _through(entry)
+    out = attend(q, k, v, causal=causal)
     ref = _reference(q, k, v, causal, scale)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=5e-5, rtol=5e-5)
 
     def f_flash(q, k, v):
-        return jnp.sum(flash_attention(q, k, v, causal=causal) ** 2)
+        return jnp.sum(attend(q, k, v, causal=causal) ** 2)
 
     def f_ref(q, k, v):
         return jnp.sum(_reference(q, k, v, causal, scale) ** 2)
@@ -261,30 +303,43 @@ def test_visited_tiles_are_those_with_an_unmasked_pair(seq):
                 assert list(every[:, j]) == [i >= full for i in range(seq // bq)]
 
 
-def _three_kernels(q, k, v, g, scale, bq, bk):
+def _three_kernels(q, k, v, g, scale, bq, bk, entry="swapped"):
+    """The three calls at tiles (bq, bk), operands (b, h, s, d) handed to
+    the entry's form; `lse` and `delta` `(b, h, 1, s)`, the sequence on the
+    lanes, whatever the entry."""
     from flexflow_tpu.kernels.flash_attention import (_dkv_call, _dq_call,
                                                       _fwd_call)
 
-    o, lse = _fwd_call(q, k, v, True, scale, bq, bk)
-    delta = jnp.sum(g * o, axis=-1, keepdims=True)
-    dq = _dq_call(q, k, v, g, lse, delta, True, scale, bq, bk)
-    dk, dv = _dkv_call(q, k, v, g, lse, delta, True, scale, bq, bk)
+    heads = 0 if entry == "swapped" else q.shape[1]
+    form = {"heads": heads}
+    if heads:
+        assert entry_of(q.shape[3], heads, k.shape[1]) == entry
+        q, k, v, g = (_merge(x) for x in (q, k, v, g))
+    o, lse = _fwd_call(q, k, v, True, scale, bq, bk, **form)
+    dq, delta = _dq_call(q, k, v, g, o, lse, True, scale, bq, bk, **form)
+    dk, dv = _dkv_call(q, k, v, g, lse, delta, True, scale, bq, bk, **form)
+    assert lse.shape == delta.shape == (o.shape[0], heads or o.shape[1], 1,
+                                        o.shape[1 if heads else 2])
+    if heads:
+        o, dq, dk, dv = (_split(x, heads) for x in (o, dq, dk, dv))
     return o, lse, dq, dk, dv
 
 
-@pytest.mark.parametrize("seq,bq,bk", [
-    (1024, 128, 128), (1024, 256, 128), (1024, 128, 256), (1024, 512, 256),
-    (1024, 256, 512), (384, 128, 128)])
-def test_unequal_tiles_match_einsum_at_head_dim_64(seq, bq, bk):
+@pytest.mark.parametrize("seq,bq,bk,entry", [
+    (1024, 128, 128, "swapped"), (1024, 256, 128, "two_heads"),
+    (1024, 128, 256, "swapped"), (1024, 512, 256, "two_heads"),
+    (1024, 256, 512, "swapped"), (384, 128, 128, "two_heads")])
+def test_unequal_tiles_match_einsum_at_head_dim_64(seq, bq, bk, entry):
     """Each of the three kernels at its own (bq, bk), causal, d = 64:
     forward and both gradients against einsum + mask + softmax, to the
-    tolerances of the d = 64 parity test above."""
+    tolerances of the d = 64 parity test above; through the swapped entry
+    and two heads a 128-lane block by turns."""
     rng = np.random.default_rng(7)
     b, h, d = 1, 2, 64
     q, k, v, g = (jnp.asarray(rng.normal(size=(b, h, seq, d)), jnp.float32)
                   for _ in range(4))
     scale = 1.0 / np.sqrt(d)
-    o, _lse, dq, dk, dv = _three_kernels(q, k, v, g, scale, bq, bk)
+    o, _lse, dq, dk, dv = _three_kernels(q, k, v, g, scale, bq, bk, entry)
     ref, vjp = jax.vjp(lambda q, k, v: _reference(q, k, v, True, scale),
                        q, k, v)
     np.testing.assert_allclose(np.asarray(o), np.asarray(ref),
@@ -298,7 +353,8 @@ def test_a_sequence_of_several_blocks_loops_over_the_earlier_ones():
     """A causal sequence longer than the block a grid step holds (384 = 3
     x 128: only 128 divides it): a step meets the key blocks before its
     own whole, in a loop, and carries the statistics into its own block.
-    Through the public entry, forward and gradients."""
+    Through the public entry, forward and gradients (two heads a block at
+    this length: the unequal-tiles case above)."""
     rng = np.random.default_rng(9)
     b, h, s, d = 1, 2, 384, 64
     q, k, v = (jnp.asarray(rng.normal(size=(b, h, s, d)), jnp.float32)
@@ -334,7 +390,7 @@ def test_rows_that_meet_only_diagonal_tiles_normalise(bq, bk):
     logits = np.where(np.tril(np.ones((s, s), bool)), logits, -np.inf)
     want = np.log(np.exp(logits - logits.max(-1, keepdims=True)).sum(-1)) \
         + logits.max(-1)
-    np.testing.assert_allclose(np.asarray(lse[0, 0, :, 0]), want,
+    np.testing.assert_allclose(np.asarray(lse[0, 0, 0, :]), want,
                                atol=5e-5, rtol=5e-5)
     np.testing.assert_allclose(np.asarray(o[0, 0, 0]), np.asarray(v[0, 0, 0]),
                                atol=1e-6)
@@ -362,6 +418,13 @@ def test_lowering_span_carries_the_tile_plan_and_trace_report_prints_it():
     args = spans[0].args
     assert (args["batch_heads"], args["seq_q"], args["depth"],
             args["causal"]) == (6, 512, 64, True)
+    # how the operands and the rows' statistics lie (PR 63): this entry
+    # takes (b, h, s, d); the projections' own layout says what it took
+    assert (args["entry"], args["residual"]) == ("swapped", "lanes")
+    wide = jnp.zeros((2, 512, 4, 128), jnp.bfloat16)
+    jax.eval_shape(lambda q: flash_attention_qkv(q, q, q, causal=True), wide)
+    assert tel.ring_spans("lower/flash_attention")[-1].args["entry"] \
+        == "merged"
     for kern in KERNELS:
         got = args["kernels"][kern]
         bq, bk = got["flash_tile_q"], got["flash_tile_k"]
@@ -374,7 +437,7 @@ def test_lowering_span_carries_the_tile_plan_and_trace_report_prints_it():
     lines = trace_report.flash_attention_lines(events)
     assert len(lines) == 1
     assert lines[0].startswith("[lower] flash attention x2 [6, 512x512, 64] "
-                               "causal: fwd ")
+                               "causal swapped/lanes: fwd ")
     fwd = args["kernels"]["fwd"]
     assert (f"{fwd['flash_tiles_visited']} of {fwd['flash_tiles_total']} "
             f"visited ({fwd['flash_tiles_masked']} masked)") in lines[0]
@@ -422,17 +485,20 @@ def _banded_reference(q, k, v, window):
     return jnp.einsum("bhqk,bhkd->bhqd", probs, v)
 
 
-@pytest.mark.parametrize("seq,window,heads,kv_heads", [
-    (512, 100, 2, 1),       # under a block: the band crosses the own block
-    (1024, 300, 2, 2),      # no multiple of the tile; whole, crossed, skipped
-    (1024, 511, 4, 2),      # one short of two blocks
-    (512, 512, 2, 2),       # window == seq: the plain causal call
-    (512, 1000, 2, 1),      # window > seq
+@pytest.mark.parametrize("seq,window,heads,kv_heads,entry", [
+    # under a block: the band crosses the own block
+    (512, 100, 2, 1, "merged"),
+    # no multiple of the tile; whole, crossed, skipped
+    (1024, 300, 2, 2, "swapped"),
+    (1024, 511, 4, 2, "merged"),        # one short of two blocks
+    (512, 512, 2, 2, "swapped"),        # window == seq: the plain causal call
+    (512, 1000, 2, 1, "merged"),        # window > seq
 ])
-def test_a_window_matches_the_masked_form(seq, window, heads, kv_heads):
+def test_a_window_matches_the_masked_form(seq, window, heads, kv_heads, entry):
     """Forward, dq and dk/dv of the three kernels (interpret mode) under a
-    window against the masked XLA form, at grouped K/V heads too; blocks of
-    256 at head_dim 128 in float32, so 1024 positions are four grid steps."""
+    window against the masked XLA form, at grouped K/V heads too (a group's
+    dk, dv summed by lane slabs on the merged axis); blocks of 256 at
+    head_dim 128 in float32, so 1024 positions are four grid steps."""
     import importlib
 
     fa = importlib.import_module("flexflow_tpu.kernels.flash_attention")
@@ -443,15 +509,16 @@ def test_a_window_matches_the_masked_form(seq, window, heads, kv_heads):
     ct = jax.random.normal(keys[3], q.shape)
     band = window if window < seq else 0
 
+    attend = _through(entry)
+
     def flash(q, k, v):
-        return jnp.sum(fa.flash_attention(q, k, v, causal=True,
-                                          window=window) * ct)
+        return jnp.sum(attend(q, k, v, causal=True, window=window) * ct)
 
     def masked(q, k, v):
         return jnp.sum(_banded_reference(q, k, v, band) * ct)
 
     np.testing.assert_allclose(
-        fa.flash_attention(q, k, v, causal=True, window=window),
+        attend(q, k, v, causal=True, window=window),
         _banded_reference(q, k, v, band), atol=2e-5)
     for got, want in zip(jax.grad(flash, (0, 1, 2))(q, k, v),
                          jax.grad(masked, (0, 1, 2))(q, k, v)):
@@ -548,24 +615,133 @@ def test_a_checkpoint_that_keeps_the_residuals_runs_the_forward_kernel_once(
     assert kernel_calls(jax.make_jaxpr(again)(q, k, v).jaxpr) \
         == dict(once, ff_flash_attention_fwd=2)
     assert sorted(set(named(traced, fa.FLASH_KEPT))) \
-        == [(1, heads // shards, seq, depth), (heads // shards * seq,)]
+        == [(1, heads // shards, 1, seq), (1, heads // shards, seq, depth)]
     # a call that is not differentiated names nothing
     assert not named(jax.make_jaxpr(total)(q, k, v).jaxpr, fa.FLASH_KEPT)
     for got, want in zip(jax.jit(kept)(q, k, v), jax.jit(plain)(q, k, v)):
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
+@pytest.mark.parametrize("norm,turn,dt,tol,shape", [
+    (True, True, jnp.float32, 1e-4, (2, 48, 16)),
+    (True, False, jnp.bfloat16, 2.0 ** -7, (2, 48, 16)),
+    (False, True, jnp.bfloat16, 2.0 ** -7, (1, 16, 2)),
+    (True, True, jnp.bfloat16, 2.0 ** -5, (1, 16, 2))])
+def test_the_head_turn_on_the_merged_axis_is_the_ops_own(norm, turn, dt, tol,
+                                                         shape):
+    """kernels/head_turn.py (PR 63) against what it replaces where a head is
+    whole lanes: `rms_norm` a head, then `apply_rope_half`, on `[b, s, h,
+    d]`: the value, d x and d gamma, the same steps (the kernel
+    interpreted): in float32 to rounding, in bfloat16 to one unit in the
+    last place (a product and a sum contracted or not; and with both a norm
+    and a turn the kernel keeps the head float32 between them, as the chip's
+    compiler does with the two ops, where this CPU rounds it to bfloat16). Two sequences of 48 positions and 16 heads are a grid of 2 x 3 x
+    2 (rows in blocks of 16, eight heads a step): the tables' block follows
+    the batch and the row block and stays put over the head steps, and d
+    gamma is begun at the first head step of each row block and added to at
+    the second."""
+    from flexflow_tpu.kernels import head_turn as ht
+    from flexflow_tpu.kernels.head_turn import head_turn
+    from flexflow_tpu.ops.norm_ops import rms_norm
+    from flexflow_tpu.ops.rotary import apply_rope_half, half_tables
+
+    (b, s, h), d = shape, 128
+    rows, step = ht._tiles(s, h, d)
+    assert (b, s // rows, h // step) == ((2, 3, 2) if b == 2 else (1, 1, 1))
+    rng = np.random.default_rng(11)
+    cos, sin = half_tables(jnp.asarray(rng.integers(0, 4000, (b, s))), d, 1e4)
+    signed = sin * jnp.where(jnp.arange(d) < d // 2, -1.0, 1.0)
+    ct = jnp.asarray(rng.normal(size=(b, s, h * d)), jnp.float32)
+    x = jnp.asarray(rng.normal(size=(b, s, h * d)), dt)
+    gamma = jnp.asarray(1 + 0.1 * rng.normal(size=(d,)), dt)
+
+    def ops(x, gamma):
+        y = x.reshape(b, s, h, d)
+        y = rms_norm(y, gamma, 1e-6) if norm else y
+        y = apply_rope_half(y, cos[:, :, None], sin[:, :, None]) \
+            if turn else y
+        return jnp.sum(y.reshape(x.shape).astype(jnp.float32) * ct)
+
+    def kernel(x, gamma):
+        y = head_turn(x, gamma if norm else None, cos if turn else None,
+                      signed if turn else None, h, 1e-6)
+        return jnp.sum(y.astype(jnp.float32) * ct)
+
+    if turn and b > 1:      # a table broadcast over the batch is refused
+        with pytest.raises(ValueError, match="a table"):
+            head_turn(x, None, cos[:1], signed[:1], h, 1e-6)
+    want = jax.value_and_grad(ops, (0, 1))(x, gamma)
+    got = jax.value_and_grad(kernel, (0, 1))(x, gamma)
+    for a, b_ in zip(jax.tree_util.tree_leaves(got),
+                     jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(np.asarray(a, np.float32),
+                                   np.asarray(b_, np.float32),
+                                   atol=tol, rtol=tol)
+
+
+def _flash_call_values(jaxpr):
+    """The avals that enter or leave a flash kernel call of a jaxpr."""
+    return [x.aval for e in equations(jaxpr)
+            if e.primitive.name == "pallas_call"
+            and e.params["name"].startswith("ff_flash_attention")
+            for x in list(e.invars) + list(e.outvars)]
+
+
+@pytest.mark.parametrize("entry,heads,kv_heads,depth", [
+    ("swapped", 16, 16, 192), ("merged", 32, 4, 128), ("two_heads", 16, 16, 64)])
+def test_no_lane_sparse_statistic_enters_or_leaves_a_flash_call(
+        entry, heads, kv_heads, depth, monkeypatch):
+    """PR 63: in the jaxpr of value-and-gradient no float32 value of shape
+    `[.., 1]` (a number a 128-lane tile on the chip: `lse` and `delta` as
+    they lay) enters or leaves one of the three kernels, whatever the
+    entry; the two statistics are `(b, h, 1, s)`, `delta` is made by the dq
+    kernel and read by the dk/dv kernel, and a call nobody differentiates
+    writes no `lse` at all. Traced for the chip, nothing runs."""
+    import importlib
+
+    fa = importlib.import_module("flexflow_tpu.kernels.flash_attention")
+    monkeypatch.setattr(fa, "_interpret", lambda: False)
+    b, s = 2, 1024
+    q = jax.ShapeDtypeStruct((b, s, heads, depth), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((b, s, kv_heads, depth), jnp.bfloat16)
+    assert fa.entry_of(depth, heads, kv_heads) == entry
+
+    def total(q, k, v):
+        return jnp.sum(fa.flash_attention_qkv(q, k, v, causal=True)
+                       .astype(jnp.float32))
+
+    traced = jax.make_jaxpr(jax.value_and_grad(total, (0, 1, 2)))(q, kv, kv)
+    values = _flash_call_values(traced.jaxpr)
+    assert kernel_calls(traced.jaxpr) == {
+        "ff_flash_attention_fwd": 1, "ff_flash_attention_dq": 1,
+        "ff_flash_attention_dkv": 1}
+    stats = [a for a in values if a.dtype == jnp.float32 and a.ndim == 4
+             and a.shape[2] == 1]
+    assert len(stats) == 5 and {a.shape for a in stats} == {(b, heads, 1, s)}
+    assert not [a for a in values if a.shape[-1] == 1]
+    # the operands as the projections hold them, but for the swapped entry
+    wide = {a.shape for a in values if a.dtype == jnp.bfloat16}
+    assert wide == ({(b, heads, s, depth)} if entry == "swapped" else
+                    {(b, s, heads * depth), (b, s, kv_heads * depth)})
+    primal = jax.make_jaxpr(total)(q, kv, kv).jaxpr
+    assert not [a for a in _flash_call_values(primal)
+                if a.dtype == jnp.float32]
+
+
 # sha256 of the jaxpr of value-and-gradient of a call WITHOUT a window at
 # GPT-2 medium's shape ([8, 16, 1024, 64] bf16, Mosaic path), source
-# locations taken out, as the tree before windows existed traced it (commit
-# 2252dec; /root/scratch/jaxpr_digest.py of PR 58 computed both sides), and
-# since PR 61 with what the forward rule adds in every differentiated call:
-# two `name` equations (`FLASH_KEPT`) and `lse`'s reshape to flat and back,
-# which lower to nothing where no checkpoint keeps the name (re-pinned from
-# 96f17117.. / d9044b53..; the four equations are the whole of the diff)
+# locations taken out. Re-pinned by PR 63 (from 6b28aa81.. / 79b2b8a4..,
+# PR 61's), whose whole point is a different program at the kernels'
+# boundary: the blocks' leading dimensions are squeezed (one kernel body
+# for every entry), `lse` leaves as `(b, h, 1, s)` and is named as it lies
+# (PR 61's reshape pair is gone), `delta` is made in the dq kernel from
+# `o`'s rows and read by the dk/dv kernel, which works on the transposed
+# score tile. What the pin still holds: a window, grouped K/V heads and a
+# VMEM scope of its own enter a call's program only where the call states
+# them.
 WINDOWLESS_JAXPR = {
-    True: "6b28aa81cdd4f8768c5678cab61fca3e7d8c63bff91df5aca5e48b302383de5a",
-    False: "79b2b8a4638abfedddfafd0a9c31082943e22f06f84cc88cfbac64f6c1ff21b0",
+    True: "a5a22f5dfb1e4871d6d79552f8a295e64690faf28c3242cc4d25c17bcbb460dd",
+    False: "7f048dcdaf1bbfd518fc1202b6d9c4583fca77721c90386f2414f0957a319003",
 }
 
 

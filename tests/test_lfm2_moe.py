@@ -693,10 +693,19 @@ OTHERS = {
                                            batch=4)}
 # sha256[:24] of the StableHLO each layer's own lowering gave at commit
 # fd7a46f (PR 46), by (model, graph, op type): the training graph and both
-# serving clones
+# serving clones. GPT-2's training and prefill layers were re-pinned by PR 63
+# (from ed154ffb.. / dea5b01a..): their causal attention is the interpreted
+# flash call, whose program at the kernels' boundary that PR changed on
+# purpose (four heads of 64: the `two_heads` entry, so the layer hands the
+# kernels its projections `[4, 128, 256]` as they lie and no reshape or
+# transposition stands between `x @ wq` and `@ wo`; blocks with their
+# leading dimensions squeezed, `lse` `(b, h, 1, s)` and none at all in the
+# prefill clone, `delta` made in the dq kernel). The
+# decode clone, which calls no flash kernel, kept its text, as did granite's
+# and Nemotron's layers (the XLA form at these widths)
 PARENT_TEXT = {
-    ("gpt2", "train", "multihead_attention"): "ed154ffb0588479958ded92a",
-    ("gpt2", "prefill", "multihead_attention"): "dea5b01a31c9385f18f9ae21",
+    ("gpt2", "train", "multihead_attention"): "3577388bc88f67e03343c564",
+    ("gpt2", "prefill", "multihead_attention"): "8975e3df859cb3094f427462",
     ("gpt2", "decode", "multihead_attention"): "b8dad677ccb735ebc850a721",
     ("granite", "train", "multihead_attention"): "cd7db45e046bb7ecd5e11fee",
     ("granite", "prefill", "multihead_attention"): "834730d09cb1303498000a9d",

@@ -350,7 +350,8 @@ def flash_attention_lines(events: List[Dict[str, Any]]) -> List[str]:
     """One line per shape the flash-attention kernels were lowered at
     (`lower/flash_attention` spans, one a lowered call): for each of the
     three kernels its tile and how many tiles of the score matrix it
-    computes, with those of them that take the causal mask."""
+    computes, with those of them that take the causal mask; since PR 63
+    also how its operands and row statistics lie (`entry` / `residual`)."""
     calls: Dict[str, int] = {}
     for ev in events:
         a = ev.get("args") or {}
@@ -358,7 +359,9 @@ def flash_attention_lines(events: List[Dict[str, Any]]) -> List[str]:
                 or "kernels" not in a:
             continue
         what = (f"[{a.get('batch_heads')}, {a.get('seq_q')}x{a.get('seq_k')}"
-                f", {a.get('depth')}]{' causal' if a.get('causal') else ''}: ")
+                f", {a.get('depth')}]{' causal' if a.get('causal') else ''}"
+                + (f" {a['entry']}/{a['residual']}" if "entry" in a else "")
+                + ": ")
         what += "; ".join(
             f"{kern} {k['flash_tile_q']}x{k['flash_tile_k']} tiles, "
             f"{k['flash_tiles_visited']} of {k['flash_tiles_total']} visited"
