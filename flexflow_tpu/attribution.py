@@ -193,6 +193,19 @@ def _segments(op_name: str) -> List[str]:
     return out
 
 
+def _unwrap(seg: str) -> "tuple[list, str]":
+    """A segment of a name stack as (JAX's transform wrappers around it,
+    outermost first; what they wrap): `transpose(jvp(up))` -> (["transpose",
+    "jvp"], "up")."""
+    wrappers = []
+    m = _WRAPPED.match(seg)
+    while m is not None:
+        wrappers.append(m.group(1))
+        seg = m.group(2)
+        m = _WRAPPED.match(seg)
+    return wrappers, seg
+
+
 def scope_of_op_name(op_name: str, op_types: Dict[str, str]
                      ) -> "tuple[str, str]":
     """(layer, phase) of one `metadata.op_name`. A layer is matched as a
@@ -210,11 +223,8 @@ def scope_of_op_name(op_name: str, op_types: Dict[str, str]
     checkpoint/l1_attn/...`): a `transpose` seen on the way counts too."""
     wrappers = []
     for seg in _segments(op_name):
-        m = _WRAPPED.match(seg)
-        while m is not None:
-            wrappers.append(m.group(1))
-            seg = m.group(2)
-            m = _WRAPPED.match(seg)
+        around, seg = _unwrap(seg)
+        wrappers += around
         if seg in _STEP_SCOPES:
             return "", _STEP_SCOPES[seg]
         if seg in op_types:
@@ -411,10 +421,34 @@ def _runs_flash_forward(i: _Instr, op_type: str) -> bool:
         and fold_name(i.name) == "ff_flash_attention_fwd"
 
 
+def _runs_rows_kernel(i: _Instr, op_type: str) -> bool:
+    """A call of the rows kernel (`kernels/moe_rows.py`: forward only) under
+    an expert layer."""
+    return op_type == "moe_layer" and i.opcode == "custom-call" \
+        and fold_name(i.name) == "ff_moe_rows"
+
+
+def _multiplies_rows(i: _Instr, op_type: str) -> bool:
+    """A product of an expert layer's rows where no kernel runs, in a
+    forward evaluation of `moe_ops._experts`: a dot-like instruction whose
+    name stack holds `moe_ops.EXPERTS_SCOPE` as it is or inside `jvp(..)`;
+    inside `transpose(..)` it is the backward's own (a training block's
+    rows are differentiated on the spot, `moe_ops._switched_rows`, so the
+    scope carries the wrapper)."""
+    if op_type != "moe_layer" or i.opcode not in _DOT_OPCODES:
+        return False
+    return any(inner == "ff_moe_experts" and "transpose" not in around
+               for around, inner in map(_unwrap, _segments(i.op_name)))
+
+
 # what a checkpoint around an op may keep (`OpDef.kept_names`), by the
-# instructions that run again where it does not
-_KEPT_WORK = {"moe_routing_passes": _decides,
-              "flash_fwd_passes": _runs_flash_forward}
+# instructions that run again where it does not: a kind's ways of telling
+# them, the first that finds one in a forward phase counts (the rows'
+# forward by the kernel's calls where it runs, since its backward multiplies
+# the rows again without it; by the products elsewhere)
+_KEPT_WORK = {"moe_routing_passes": (_decides,),
+              "flash_fwd_passes": (_runs_flash_forward,),
+              "moe_rows_passes": (_runs_rows_kernel, _multiplies_rows)}
 
 
 _RESULT = re.compile(r"^\(?(\w+)\[([\d,]*)\]")
@@ -478,22 +512,32 @@ def step_passes(hlo_text: str, op_types: Dict[str, str],
     where the one around it keeps what the forward kernel wrote
     (`flash_attention.FLASH_KEPT`: a `remat_blocks` unit's); 2 where the
     recomputation runs the kernel again; absent from every CPU program,
-    whose kernels are interpreted. Beside them `flash_relayouts`, no
+    whose kernels are interpreted. `moe_rows_passes`: the forward
+    evaluations of an expert layer's rows products (`ff_moe_rows`' calls,
+    or where no kernel runs the grouped products that are no transpose):
+    2 where a `remat_blocks` unit keeps the layer's result
+    (`moe_ops.LAYER_KEPT`: the forward pass, and the token block's own
+    recomputation for the gates' gradient), 3 where the unit's
+    recomputation runs the layer again. Beside them `flash_relayouts`, no
     ratio of passes: the q-sized relayouts a flash layer (`scopes`: the
     program's `op_scope_map` where the caller has it)."""
-    by_phase: Dict[str, Dict[str, int]] = {kind: {} for kind in _KEPT_WORK}
+    by_phase: Dict[Any, Dict[str, int]] = {
+        counted: {} for ways in _KEPT_WORK.values() for counted in ways}
     comps = _parse_computations(hlo_text)[0]
     for items in comps.values():
         for i in items:
             if not i.op_name:
                 continue
             layer, phase = scope_of_op_name(i.op_name, op_types)
-            for kind, counted in _KEPT_WORK.items():
+            for counted, seen in by_phase.items():
                 if counted(i, op_types.get(layer, "")):
-                    seen = by_phase[kind]
                     seen[phase] = seen.get(phase, 0) + 1
-    out = {kind: sum(seen.values()) / seen["forward"]
-           for kind, seen in by_phase.items() if seen.get("forward")}
+    out = {}
+    for kind, ways in _KEPT_WORK.items():
+        seen = next((by_phase[counted] for counted in ways
+                     if by_phase[counted].get("forward")), None)
+        if seen is not None:
+            out[kind] = sum(seen.values()) / seen["forward"]
     relayouts = flash_relayouts(
         comps, op_scope_map(hlo_text, op_types) if scopes is None else scopes)
     if relayouts is not None:
@@ -591,9 +635,12 @@ def op_scopes(name: str) -> List[Dict[str, OpScope]]:
 def instructions_in_scope(hlo_text: str, scope: str) -> "set[str]":
     """Names of the instructions of one compiled program's optimized HLO
     text that run as events of their own under the `jax.named_scope`
-    `scope` (a whole segment of the name stack): an instruction whose own
-    `op_name` holds it, a fusion most of whose named body does, what the
-    compiler made (no name stack) inside a loop or branch that does, and
+    `scope` (a whole segment of the name stack, as it is or inside JAX's
+    transform wrappers: a scope entered under a `jax.vjp` that a backward
+    rule calls reads `jvp(scope)` and `transpose(jvp(scope))`): an
+    instruction whose own `op_name` holds it, a fusion most of whose named
+    body does, what the compiler made (no name stack) inside a loop or
+    branch that does, and
     what the chip's compiler made OF a matrix product that does (a
     ragged-dot becomes a Mosaic call named `ragged-dot-none.N` with no name
     stack: it is under the scope where an instruction it reads, or one that
@@ -604,7 +651,7 @@ def instructions_in_scope(hlo_text: str, scope: str) -> "set[str]":
         return set()
 
     def named(op_name):
-        return any(seg == scope for seg in _segments(op_name))
+        return any(_unwrap(seg)[1] == scope for seg in _segments(op_name))
 
     def under(i, inherited):
         if i.opcode == "fusion":

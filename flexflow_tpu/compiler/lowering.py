@@ -192,8 +192,10 @@ def build_forward(
         routing decision: two megabytes a layer, where the recomputation
         would run the `top_k`, the gather, the sort and the counts again,
         and a third time under the layer's own checkpoint of a token
-        block; an attention layer's `o` and flat `lse` where its sequence
-        went through the flash kernels, whose forward one the
+        block; the expert layer's result, which is all that the unit's
+        later layers read of it, so the recomputation runs none of the
+        layer's work; an attention layer's `o` and flat `lse` where its
+        sequence went through the flash kernels, whose forward one the
         recomputation then leaves out). A unit whose ops name nothing is
         checkpointed with no policy, as it was."""
         made = {t.guid for layer in unit for t in layer.outputs}

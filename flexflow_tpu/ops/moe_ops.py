@@ -293,6 +293,13 @@ EXPERTS_SCOPE = "ff_moe_experts"
 # (`save_only_these_names`): the block's own below, and a `remat_blocks`
 # unit's through the op's `kept_names` (`compiler/lowering.run_block`)
 ROUTING_KEPT = "ff_moe_routing"
+# the `checkpoint_name` of a TRAINING step's result of the whole layer
+# (`[b, s, d]` in the compute dtype: 67 MB at 16 384 tokens of 2048): a
+# `remat_blocks` unit keeps it (`kept_names`), and its recomputation then
+# holds none of the layer's own work (the cast, the gathers, the products,
+# the combine: all of them fed this tensor alone); the layer's backward
+# reads the unit's inputs made again, the kept decision, and this
+LAYER_KEPT = "ff_moe_y"
 
 
 def _kept(x, training: bool):
@@ -320,7 +327,12 @@ def _rows_forward(rows, sizes, w_in, w_out, relu2, tile):
 
 
 def _rows_backward(relu2, _tile, operands, ct):
-    """The grouped-product form's, over the same rows, recomputed."""
+    """The grouped-product form's, over the same rows, recomputed. A row
+    past the last group takes cotangent ZERO (nothing of the result
+    depends on it): the grouped product's transpose does not write those
+    rows on the chip either, and the callers' gather adds every row's
+    cotangent to its token's (PERF.md, PR 64: what lay there read as 1.2 %
+    of the tokens' gradient at the 8192-row rung, 120 times it at 2048)."""
     rows, sizes, w_in, w_out = operands
 
     def grouped(rows, w_in, w_out):
@@ -328,7 +340,8 @@ def _rows_backward(relu2, _tile, operands, ct):
                         _params_of(w_out, relu2))
 
     d_rows, d_in, d_out = jax.vjp(grouped, rows, w_in, w_out)[1](ct)
-    return d_rows, None, d_in, d_out
+    written = jnp.arange(rows.shape[0])[:, None] < jnp.sum(sizes)
+    return jnp.where(written, d_rows, 0), None, d_in, d_out
 
 
 def _rows_forward_kept(rows, sizes, *rest):
@@ -377,18 +390,19 @@ def _experts(rows, sizes, weights, p, tile=None):
         return jax.lax.ragged_dot(mid, weights["w_out"].astype(dt), sizes)
 
 
-def _all_rows(xt, gate, held, order, sizes, weights, p, tile=None,
-              training: bool = False):
+def _all_rows(xt, gate, held, order, where, sizes, weights, p, tile=None):
     """Every (token, choice) pair of the block has a row: the whole
     `tokens * k` buffer, whatever is held. `tile`: as `_experts` takes.
-    `training`: the order's inverse is a sort too, and kept (`_kept`)."""
+    `where`: the order's inverse (each pair's row), None where the caller
+    has not made it (`_order_inverse`: a training step's, made once)."""
     tokens, k = gate.shape
     out = _experts(xt[order // k], sizes, weights, p, tile)    # [tokens*k, d]
     # back to (token, choice) order, one choice at a time (a [tokens, k, d]
     # buffer in f32 would be the largest of the program); rows past the
     # last group hold nothing that was computed, so they are selected
     # away, not multiplied by 0
-    where = _kept(jnp.argsort(order), training).reshape(tokens, k)
+    where = (jnp.argsort(order) if where is None else where).reshape(
+        tokens, k)
     y = jnp.zeros(xt.shape, jnp.float32)
     for j in range(k):
         y = y + jnp.where(held[:, j:j + 1],
@@ -402,7 +416,8 @@ def _no_rows(xt, *_):
     return jnp.zeros_like(xt)
 
 
-def _held_rows(cap, xt, gate, held, order, sizes, weights, p, tile=None):
+def _held_rows(cap, xt, gate, held, order, _where, sizes, weights, p,
+               tile=None):
     """The same for a block that holds at most `cap` pairs: they are the
     first `cap` of `order` (absent pairs sort last), and gather, products,
     gate and combine are over those rows alone. A token's rows lie apart
@@ -464,6 +479,72 @@ def _routing(xt, exists, weights, p, training: bool = False):
     return gate, held, local, jnp.where(exists, experts, p["num_experts"])
 
 
+# the weights a block's rows read (`_experts`, `_through_latent`): what the
+# rule below takes and hands a cotangent; the router's and a selection
+# bias get theirs from `_routing`
+ROWS_WEIGHTS = ("w_in", "w_out", "w_latent_in", "w_latent_out")
+
+
+def _order_inverse(order, rung, rungs: int, training: bool):
+    """Each pair's row in the whole block's buffer (`order`'s inverse: a
+    sort of every pair too) for a TRAINING step: made once, where the rung
+    taken is the whole block (zeros elsewhere: no other branch reads it),
+    and kept (`_kept`), so that neither a recomputation nor the backward's
+    branch sorts again. None outside training: `_all_rows` makes its own,
+    inside its branch."""
+    if not training:
+        return None
+    if rungs == 1:
+        return _kept(jnp.argsort(order), True)
+    return _kept(jax.lax.cond(rung == rungs - 1, jnp.argsort, jnp.zeros_like,
+                              order), True)
+
+
+def _switched_rows(branches):
+    """A TRAINING block's rows, `lax.switch(rung, branches, ...)`, as ONE
+    `jax.custom_vjp` of `(rung, xt, gate, held, order, where, sizes,
+    weights)`. The forward rule is the same switch and keeps its operands
+    alone (the block's tokens, the gates, the integers of the routing
+    decision that are kept anyway, the weights as they lie). The backward
+    rule is one `lax.switch` over the SAME rung whose branch i is `jax.vjp`
+    of branch i on the spot (gather, products, gate, combine: the rows
+    kernel's own `_rows_backward` inside it) and returns the cotangents of
+    `xt`, `gate` and the weights: every branch the same shapes, so nothing
+    else leaves the conditional, and `_no_rows`' zeros are written only
+    where it is the branch that runs. Left to JAX under the block's
+    `jax.checkpoint`, the differentiated conditional is partially
+    evaluated: every branch hands on the residuals of ALL branches, so the
+    rung that runs writes zeros the size of the whole block's row buffers
+    (and of a transposed copy of the weights), and the results are copied
+    out (PERF.md, PR 64). The integers, the booleans and the rung take no
+    cotangent."""
+    def switched(rung, *operands):
+        return jax.lax.switch(rung, branches, *operands)
+
+    def forward(*operands):
+        return switched(*operands), operands
+
+    def backward(operands, ct):
+        rung, xt, gate, *routing, weights = operands
+
+        def pulled(branch):
+            def pull(xt, gate, routing, weights, ct):
+                return jax.vjp(
+                    lambda xt, gate, weights: branch(xt, gate, *routing,
+                                                     weights),
+                    xt, gate, weights)[1](ct)
+            return pull
+
+        d_xt, d_gate, d_weights = jax.lax.switch(
+            rung, [pulled(branch) for branch in branches],
+            xt, gate, routing, weights, ct)
+        return (None, d_xt, d_gate) + (None,) * len(routing) + (d_weights,)
+
+    rows = jax.custom_vjp(switched)
+    rows.defvjp(forward, backward)
+    return rows
+
+
 def _rows_tile(rows: int, d: int, itemsize: int, p):
     """The rows kernel's tiles for a block's buffer of `rows` rows, None
     where the grouped product multiplies it. From what the shapes say
@@ -487,9 +568,11 @@ def _route_tokens(xt, exists, weights, p, told_what_exists: bool,
     `told_what_exists`: the layer has its `valid` input, so `exists` may
     name fewer than all. `kernel`: one device, so a buffer whose shapes
     admit `kernels/moe_rows.py` takes it (`_rows_tile`). `training`: what
-    the routing decides (the chosen experts and their scores, the order,
-    both counts) is kept under `ROUTING_KEPT`; `held`, `local`, the rung
-    and a row's token are elementwise of those and made again."""
+    the routing decides (the chosen experts and their scores, the order
+    and its inverse, both counts) is kept under `ROUTING_KEPT`; `held`,
+    `local`, the rung and a row's token are elementwise of those and made
+    again; and the rows (one rung or the ladder's switch) go through
+    `_switched_rows`, whose gradient is the taken branch's own vjp."""
     k = p["top_k"]
     lo, hi = p["experts_held"]
     held_n = hi - lo
@@ -513,20 +596,22 @@ def _route_tokens(xt, exists, weights, p, told_what_exists: bool,
     def rows_fn(fn, tile):
         return _in_experts_width(functools.partial(fn, p=p, tile=tile), p)
 
-    whole = rows_fn(functools.partial(_all_rows, training=training),
-                    tiles[-1])
-    if len(caps) == 1:
-        rung = 0
-        y = whole(xt, gate, held, order, sizes, weights)
-        computed = jnp.int32(tokens * k)
-    else:
-        rung = jnp.sum(jnp.sum(sizes) > jnp.asarray(caps[:-1], jnp.int32))
-        y = jax.lax.switch(
-            rung, [rows_fn(functools.partial(_held_rows, cap), tile) if cap
-                   else _no_rows for cap, tile in zip(caps[:-1], tiles)]
-            + [whole],
-            xt, gate, held, order, sizes, weights)
-        computed = jnp.asarray(caps, jnp.int32)[rung]
+    branches = [rows_fn(functools.partial(_held_rows, cap), tile) if cap
+                else _no_rows for cap, tile in zip(caps[:-1], tiles)] \
+        + [rows_fn(_all_rows, tiles[-1])]
+    ladder = len(caps) > 1
+    rung = jnp.sum(jnp.sum(sizes) > jnp.asarray(caps[:-1], jnp.int32)) \
+        if ladder else 0
+    where = _order_inverse(order, rung, len(caps), training)
+    if training:
+        y = _switched_rows(branches)(
+            rung, xt, gate, held, order, where, sizes,
+            {name: w for name, w in weights.items() if name in ROWS_WEIGHTS})
+    else:           # one branch: called as it is, no conditional
+        y = jax.lax.switch(rung, branches, xt, gate, held, order, where,
+                           sizes, weights)
+    computed = jnp.asarray(caps, jnp.int32)[rung] if ladder \
+        else jnp.int32(tokens * k)
     # the rows of each rung that the kernel multiplies: handed out only
     # where some rung's are (a program none of whose buffers takes it
     # lowers to the text it lowered to)
@@ -559,7 +644,7 @@ def _step_backward(relu2, tn, operands, ct):
     sizes = jnp.bincount(local, length=held_n + 1)[:held_n].astype(jnp.int32)
 
     def grouped(xt, gate, w_in, w_out):
-        return _all_rows(xt, gate, held, order, sizes,
+        return _all_rows(xt, gate, held, order, None, sizes,
                          {"w_in": w_in, "w_out": w_out},
                          p).astype(jnp.float32)
 
@@ -719,19 +804,34 @@ def _moe_layer_lower(layer: Layer, inputs, weights, ctx):
     are no whole slabs; fewer rows than a tile) through two
     `jax.lax.ragged_dot` with the activation between them.
 
-    TRAINING (`ctx.training`): a token block is a `jax.checkpoint` that
-    keeps its tokens and its ROUTING DECISION and recomputes the rest in the
+    TRAINING (`ctx.training`): the layer says itself what outlives its
+    forward pass. (1) A token block is a `jax.checkpoint` that keeps its
+    tokens and its ROUTING DECISION and recomputes the rest in the
     backward pass. The decision: the chosen experts, their scores as
-    gathered, the pairs' order by expert (and its inverse where the whole
-    block is combined), the rows on each held expert, the router's load:
-    0.5 MB a block of 4096 tokens, tagged `ROUTING_KEPT` (`_kept`), where a
-    recomputation would make the same values again with a `top_k`, a
-    gather, a sort of every pair and a scatter a count. A `remat_blocks`
-    unit around the layer keeps them too (the op's `kept_names`), so that
-    chain runs once a step, not three times (PERF.md, PR 59). What carries
-    the gradient and is cheap is made again: the router's product, the
-    sigmoid, the gates' normalisation. Outside training nothing is tagged
-    and nothing checkpointed.
+    gathered, the pairs' order by expert (and its inverse, made where the
+    whole block is combined: `_order_inverse`), the rows on each held
+    expert, the router's load: 0.5 MB a block of 4096 tokens, tagged
+    `ROUTING_KEPT` (`_kept`), where a recomputation would make the same
+    values again with a `top_k`, a gather, a sort of every pair and a
+    scatter a count. What carries the gradient and is cheap is made again:
+    the router's product, the sigmoid, the gates' normalisation. (2) A
+    block's rows (the `lax.switch` over the rungs) are ONE `jax.custom_vjp`
+    (`_switched_rows`) that keeps its operands alone and whose backward is
+    a `lax.switch` over the same rung of the branches' own vjps: nothing
+    but the cotangents of the tokens, the gates and the weights leaves the
+    backward's conditional, where JAX's partial evaluation of the
+    conditional under the block's checkpoint made every branch hand on the
+    residuals of all (zeros the size of the whole block's row buffers,
+    written by the rung that runs). The gates' cotangent reads the
+    products' result, so the rows kernel runs once more there: twice a
+    step. (3) The layer's result is tagged `LAYER_KEPT`. A `remat_blocks`
+    unit around the layer keeps (1)'s decision and (3) (the op's
+    `kept_names`): its recomputation then runs NONE of the layer's own work
+    (no cast, no gather, no product, no combine), only what feeds the
+    layer's backward from outside (the norm before it), and the chain of
+    (1) runs once a step, not three times (PERF.md, PRs 59 and 64). Outside
+    training nothing is tagged, nothing checkpointed, and the conditional
+    is JAX's own.
 
     Reports (ctx.add_stat): moe_routed_pairs, moe_held_pairs, moe_load_max
     (rows on the fullest held expert), moe_load_mean (held pairs over
@@ -808,7 +908,8 @@ def _moe_layer_lower(layer: Layer, inputs, weights, ctx):
     _report_step_kernel(ctx, kernel_experts)
     _report_rows_kernel(ctx, jnp.sum(kernel_rows[0]) if kernel_rows
                         else jnp.int32(0))
-    return [y.reshape(b, s, d)]
+    y = y.reshape(b, s, d)
+    return [checkpoint_name(y, LAYER_KEPT) if ctx.training else y]
 
 
 def _moe_layer_flops(layer: Layer):
@@ -830,4 +931,5 @@ def _moe_layer_flops(layer: Layer):
 
 register_op(OperatorType.MOE_LAYER, _moe_layer_infer, _moe_layer_lower,
             _moe_layer_flops, serving_params=_moe_serving_params,
-            uncast_weights=("score_bias",), kept_names=(ROUTING_KEPT,))
+            uncast_weights=("score_bias",),
+            kept_names=(ROUTING_KEPT, LAYER_KEPT))

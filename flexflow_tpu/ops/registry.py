@@ -132,9 +132,11 @@ class OpDef:
     uncast_weights: tuple = ()
     # the `jax.ad_checkpoint.checkpoint_name`s under which a training
     # lowering of the op tags what is cheap to keep and dear to make again
-    # (an expert layer's routing decision; what the flash forward kernel
-    # of an attention layer wrote): a checkpoint around the op keeps them
-    # (`compiler/lowering.run_block`: `save_only_these_names`), and
+    # (an expert layer's routing decision, and its result `y`, which is
+    # all the unit's later layers read of it; what the flash forward
+    # kernel of an attention layer wrote): the op decides, and a
+    # `remat_blocks` unit around it keeps them
+    # (`compiler/lowering.run_block`: `save_only_these_names`) and
     # recomputes the rest
     kept_names: tuple = ()
 

@@ -405,6 +405,46 @@ def test_step_passes_counts_the_recomputations_flash_calls(again, passes,
     assert attribution.routing_passes(text, op_types) is None
 
 
+# an expert layer's rows (PR 64): the kernel's call in the forward pass and
+# in the block's backward, whose rule differentiates the taken branch on the
+# spot (the scope then reads `jvp(..)` / `transpose(jvp(..))`); %UNIT%: the
+# call a `remat_blocks` unit's recomputation makes where it does not keep
+# the layer's result
+ROWS = """HloModule jit_step
+
+ENTRY %main.9 (a: f32[8,8]) -> f32[8,8] {
+  %a = f32[8,8]{1,0} parameter(0)
+  %ROWS.1 = f32[8,8]{1,0} CALL(%a), metadata={op_name="jit(step)/jvp()/checkpoint/moe/while/body/checkpoint/cond/branch_1_fun/ff_moe_experts/pallas_call"}
+%UNIT%  %ROWS.3 = f32[8,8]{1,0} CALL(%a), metadata={op_name="jit(step)/transpose(jvp())/checkpoint/moe/while/body/checkpoint/cond/branch_1_fun/jvp(ff_moe_experts)/pallas_call"}
+  %dot.4 = f32[8,8]{1,0} dot(%ROWS.3, %a), lhs_contracting_dims={1}, rhs_contracting_dims={0}, metadata={op_name="jit(step)/transpose(jvp())/checkpoint/moe/while/body/checkpoint/cond/branch_1_fun/transpose(jvp(ff_moe_experts))/dot_general"}
+  ROOT %out = f32[8,8]{1,0} copy(%dot.4), metadata={op_name="jit(step)/ff.update/sub"}
+}
+"""
+UNIT = '  %ROWS.2 = f32[8,8]{1,0} CALL(%a), metadata={op_name="jit(step)/' \
+    'transpose(jvp())/checkpoint/rematted_computation/moe/while/body/' \
+    'checkpoint/cond/branch_1_fun/ff_moe_experts/pallas_call"}\n'
+
+
+@pytest.mark.parametrize("unit,passes", [("", 2.0), (UNIT, 3.0)])
+@pytest.mark.parametrize("name,call", [
+    ("ff_moe_rows", 'custom-call(%a), custom_call_target="tpu_custom_call"'),
+    ("dot", "dot(%a, %a)")])
+def test_step_passes_counts_the_rows_forward_evaluations(name, call, unit,
+                                                         passes):
+    """`moe_rows_passes`: the rows kernel's calls under an expert layer,
+    all phases over the forward's; where no kernel runs, the products under
+    `ff_moe_experts` that are no transpose (the backward's own product is
+    not a forward evaluation). And a scope is found inside JAX's wrappers:
+    all of it is under `ff_moe_experts`."""
+    text = ROWS.replace("%UNIT%", unit).replace("ROWS", name).replace(
+        "CALL(%a)", call)
+    assert attribution.step_passes(text, {"moe": "moe_layer"}) \
+        == {"moe_rows_passes": passes}
+    assert attribution.instructions_in_scope(text, "ff_moe_experts") \
+        == {f"{name}.{n}" for n in ((1, 2, 3) if unit else (1, 3))} \
+        | {"dot.4"}
+
+
 def test_join_ambiguous_unattributed_and_containers():
     S = attribution.OpScope
     prefill = {"fusion.1": S("attn", "multihead_attention", "forward",

@@ -1364,7 +1364,10 @@ def test_trinity_train_step_fits_one_chip(described_devices, mosaic):
     recomputation and the block's each decided again), and the flash
     forward kernel run ONCE a layer (PR 61: the unit keeps what it writes,
     so the five kept pairs cost 0.68 GB and not 2 GB; since PR 63 `lse`
-    lane-dense as the kernel writes it)."""
+    lane-dense as the kernel writes it), and the rows kernel's forward run
+    TWICE a layer (PR 64: the unit keeps the layer's `y`, 67 MB a layer, so
+    its recomputation multiplies no row; 3 before. The second run is the
+    block's backward, whose gates' gradient reads the products' result)."""
     sys.path.insert(0, os.path.join(ROOT, "benchmarks"))
     from families import family_of
     from harness import manifest as mf
@@ -1399,7 +1402,8 @@ def test_trinity_train_step_fits_one_chip(described_devices, mosaic):
     # the room the next kept tensor is sized from (`CHANGES.md` quotes it)
     print("trinity step: arguments", m.argument_size_in_bytes, "+ temporaries",
           m.temp_size_in_bytes, "=",
-          m.argument_size_in_bytes + m.temp_size_in_bytes)
+          m.argument_size_in_bytes + m.temp_size_in_bytes, "room left",
+          int(15e9) - m.argument_size_in_bytes - m.temp_size_in_bytes)
     assert m.argument_size_in_bytes + m.temp_size_in_bytes < 15e9, m
     text = compiled.as_text()
     assert not re.search(r"\[(\d+,)*8192,8192\]", text)
@@ -1420,7 +1424,10 @@ def test_trinity_train_step_fits_one_chip(described_devices, mosaic):
     # rotation (`ff_head_turn_*`, on the merged axis too) write it
     assert attribution.step_passes(text, op_types) \
         == {"moe_routing_passes": 1.0, "flash_fwd_passes": 1.0,
-            "flash_relayouts": 0.0}
+            "moe_rows_passes": 2.0, "flash_relayouts": 0.0}
+    # four expert layers, three rungs that multiply rows, two passes
+    calls = re.findall(r"%ff_moe_rows[.\d]* = \S.* custom-call\(", text)
+    assert len(calls) == 4 * 3 * 2, len(calls)
     assert not re.search(r"f32\[\d+,\d+,8192,1\]", text)
     assert "f32[2,32,1,8192]{3,2,1,0:T(1,128)" in text
     for kernel, calls in (("ff_head_turn_fwd", 20), ("ff_head_turn_bwd", 10)):

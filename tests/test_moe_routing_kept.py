@@ -7,12 +7,21 @@ unit's (`compiler/lowering.run_block`, through the op's `kept_names`).
 
 Here: a tiny afmoe decoder (four expert layers, a part holder with a stateful
 selection bias) whose step holds two token blocks (`MOE_TOKEN_BLOCK` set to
-32), on the CPU. "Patched out" means `moe_ops._kept` returns its argument:
-no tag, so both policies keep nothing and the step is the parent's.
+32), on the CPU. "Patched out" means `moe_ops.checkpoint_name` returns its
+argument: no tag, so both policies keep nothing and the step is the parent's.
 
 The same hook's second user (PR 61): the flash forward kernel's `o` and `lse`,
 named `flash_attention.FLASH_KEPT` in the call's forward rule and kept by a
 `remat_blocks` unit through `multihead_attention`'s `kept_names`.
+
+The layer says what else outlives its forward pass (PR 64): its result is
+named `moe_ops.LAYER_KEPT`, which a unit keeps too, so the unit's
+recomputation runs none of the layer's own work; and a training block's rows
+are one `jax.custom_vjp` (`moe_ops._switched_rows`) whose backward is a
+`lax.switch` of the branches' own vjps, so no branch hands on another's
+residuals. "Patched out" takes this name away too; "the plain switch" puts
+`lax.switch` back where the rule stands, which JAX then differentiates as it
+did.
 
 Every case of a (remat_blocks, patched, ladder) reads ONE compiled step
 (`steps`, a module's worth): its jaxpr, its text and three runs of it.
@@ -26,6 +35,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.extend.core import Literal
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 ROOT = Path(__file__).resolve().parents[1]
 sys.path.insert(0, str(ROOT / "benchmarks"))
@@ -61,8 +72,19 @@ def two_blocks(monkeypatch):
     return set_sizes
 
 
+# no tag: neither the routing decision's nor the layer's result's
+UNTAGGED = [(moe_ops, "checkpoint_name", lambda x, name: x)]
+
+
 def patch_out(monkeypatch):
-    monkeypatch.setattr(moe_ops, "_kept", lambda x, training: x)
+    for target, name, value in UNTAGGED:
+        monkeypatch.setattr(target, name, value)
+
+
+def plain_switch(branches):
+    """In `moe_ops._switched_rows`' place: the conditional as JAX
+    differentiates it (the parent's)."""
+    return lambda rung, *operands: jax.lax.switch(rung, branches, *operands)
 
 
 def held_tiny():
@@ -128,7 +150,7 @@ def steps():
             if ladder:
                 patches.append((moe_ops, "MOE_MIN_RUNG_ROWS", 4))
             if patched:
-                patches.append((moe_ops, "_kept", lambda x, training: x))
+                patches.extend(UNTAGGED)
             built[key] = three_steps(held_tiny(), patches,
                                      remat_blocks=remat_blocks)
         return built[key]
@@ -140,6 +162,11 @@ def steps():
 # forward pass, the block's recomputation, the unit's
 PASSES = {(False, False): 1, (True, False): 1,
           (False, True): 2, (True, True): 3}
+# and how often it multiplies a block's rows for their result: the forward
+# pass and the block's backward (the gates' gradient reads `out`), and the
+# unit's recomputation where the unit does not keep the layer's result
+ROWS_PASSES = {(False, False): 2, (True, False): 2,
+               (False, True): 2, (True, True): 3}
 
 
 @pytest.mark.parametrize("ladder", (False, True))
@@ -173,6 +200,75 @@ def test_losses_and_every_gradient_are_the_untagged_steps(steps,
     steps, bit for bit."""
     assert_the_same_steps(steps(remat_blocks, False, True),
                           steps(remat_blocks, True, True))
+
+
+def rows_forwards(jaxpr, g):
+    """The `ragged_dot` equations that are the experts' FIRST product in a
+    forward evaluation: rows `[.., d]` by `w_in` `[held, d, 2 * width]`
+    contracting `d`, the rows ragged (a transpose contracts the width, or
+    the ragged rows themselves)."""
+    found = 0
+    for e in equations(jaxpr):
+        if e.primitive.name != "ragged_dot_general":
+            continue
+        dims = e.params["ragged_dot_dimension_numbers"]
+        (lhs_c, rhs_c), _ = dims.dot_dimension_numbers
+        found += (list(lhs_c), list(rhs_c)) == ([1], [1]) \
+            and list(dims.lhs_ragged_dimensions) == [0] \
+            and e.invars[1].aval.shape[-1] == 2 * g.expert_width
+    return found
+
+
+@pytest.mark.parametrize("ladder", (False, True))
+@pytest.mark.parametrize("remat_blocks, patched", sorted(ROWS_PASSES))
+def test_a_unit_keeps_the_layers_result(steps, remat_blocks, patched, ladder):
+    """The step multiplies a block's rows for their result twice a rung a
+    layer: in the forward pass, and in the block's backward, whose gates'
+    gradient reads the products' result. The `remat_blocks` unit keeps the
+    layer's `y` (`LAYER_KEPT`), so its recomputation runs no product;
+    without the name it runs them a third time. The engagement counter
+    says the same from the compiled program's text."""
+    cm, jaxpr, text, _, _ = steps(remat_blocks, patched, ladder)
+    passes = ROWS_PASSES[remat_blocks, patched]
+    rungs = 3 if ladder else 1          # of 64 pairs: 4, 16 and all
+    assert rows_forwards(jaxpr, held_tiny()) \
+        == EXPERT_LAYERS * rungs * passes
+    kept = [e.outvars[0].aval.shape for e in equations(jaxpr)
+            if e.primitive.name == "name"
+            and e.params["name"] == moe_ops.LAYER_KEPT]
+    assert kept == ([] if patched else [(2, 32, 64)] * EXPERT_LAYERS)
+    assert attribution.step_passes(
+        text, {l.name: l.op_type.value for l in cm.model.layers})[
+        "moe_rows_passes"] == passes
+
+
+@pytest.mark.parametrize("remat_blocks", (False, True))
+def test_the_backwards_conditional_returns_the_cotangents_alone(
+        steps, remat_blocks):
+    """A layer's three conditionals a block: the order's inverse (made only
+    where the whole block is combined), the forward's switch, which returns
+    `y` alone, and the backward's, which returns the cotangents of the
+    tokens, the gates and the two weights and nothing else: no residual
+    leaves a branch, so none is written as zeros by the others. The only
+    zeros are `_no_rows`' own cotangents, written where it runs."""
+    _, jaxpr, _, _, _ = steps(remat_blocks, False, True)
+    conds = [e for e in equations(jaxpr) if e.primitive.name == "cond"]
+    assert sorted(len(e.outvars) for e in conds) \
+        == [1] * 2 * EXPERT_LAYERS + [4] * EXPERT_LAYERS
+
+    def zeros_returned(branch):
+        made = {e.outvars[0]: e for e in branch.jaxpr.eqns
+                if e.primitive.name == "broadcast_in_dim"}
+        return [v.aval.shape for v in branch.jaxpr.outvars
+                if v in made
+                and isinstance(made[v].invars[0], Literal)]
+
+    for e in conds:
+        if len(e.outvars) == 4:
+            nothing, *rungs = e.params["branches"]
+            assert zeros_returned(nothing) \
+                == [(32, 64), (32, 2), (4, 64, 96), (4, 48, 64)]
+            assert [zeros_returned(b) for b in rungs] == [[], [], []]
 
 
 def flash_tiny():
@@ -215,11 +311,17 @@ def test_a_unit_keeps_what_the_flash_forward_kernel_wrote(monkeypatch):
     assert_the_same_steps(ran[1], ran[2])
 
 
-def moe_layer_jaxpr(training: bool):
+def moe_layer(training: bool, latent: bool = False, mesh=None):
+    """One expert layer of the part holder alone: (its lowering as a
+    function of (inputs, weights, state), the three as shapes). `latent`:
+    its experts work in a latent of 32."""
     g = held_tiny()
     m = FFModel(ffconfig(2))
     build_afmoe(m, g, batch=2)
     layer = next(l for l in m.layers if l.op_type.value == "moe_layer")
+    if latent:
+        layer.params["latent_size"] = 32
+        moe_ops._moe_layer_infer(layer)
     w = {k: jax.ShapeDtypeStruct(s.shape, s.dtype.jnp_dtype)
          for k, s in layer.weight_specs.items()}
     ins = [jax.ShapeDtypeStruct(t.spec.shape, t.spec.dtype.jnp_dtype)
@@ -228,29 +330,83 @@ def moe_layer_jaxpr(training: bool):
              jax.ShapeDtypeStruct((g.num_experts,), jnp.float32)}
 
     def f(ins, w, state):
-        ctx = LoweringCtx(state=state, training=training)
+        ctx = LoweringCtx(state=state, training=training, mesh=mesh)
         return get_op_def(layer.op_type).lower(layer, ins, w, ctx)
 
-    return jax.make_jaxpr(f)(ins, w, state)
+    return f, ins, w, state
+
+
+def moe_layer_jaxpr(training: bool):
+    f, *shapes = moe_layer(training)
+    return jax.make_jaxpr(f)(*shapes)
+
+
+def drawn(shapes, seed):
+    leaves, tree = jax.tree_util.tree_flatten(shapes)
+    keys = jax.random.split(jax.random.PRNGKey(seed), len(leaves))
+    return jax.tree_util.tree_unflatten(
+        tree, [jax.random.normal(k, s.shape, s.dtype) * 0.5
+               for k, s in zip(keys, leaves)])
+
+
+@pytest.mark.parametrize("ladder, latent, devices", [
+    (True, False, 1), (False, False, 1), (True, True, 1), (True, True, 8)])
+def test_the_rule_is_the_vjp_jax_took(two_blocks, ladder, latent, devices):
+    """One layer alone, two token blocks under the block's checkpoint, the
+    selection bias its state: `y` and the cotangents of the tokens and of
+    every weight (the router's too, through the gates) under
+    `_switched_rows` are, bit for bit, those of the plain `lax.switch` that
+    JAX differentiates itself: with and without the ladder, with the
+    experts in a latent, on one device and with the tokens over eight."""
+    two_blocks(rung_rows=4 if ladder else None)
+    mesh = Mesh(np.array(jax.devices()[:devices]), ("data",)) \
+        if devices > 1 else None
+    ran = []
+    for rule in (moe_ops._switched_rows, plain_switch):
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(moe_ops, "_switched_rows", rule)
+            f, ins, w, state = moe_layer(True, latent, mesh)
+            ins, w = drawn(ins, 1), drawn(w, 2)
+            state = jax.tree_util.tree_map(
+                lambda s: jnp.zeros(s.shape, s.dtype), state)
+            if mesh is not None:
+                ins = jax.device_put(ins, NamedSharding(mesh,
+                                                        P(None, "data")))
+
+            def pulled(ins, w, ct):
+                (y,), pull = jax.vjp(lambda ins, w: f(ins, w, state), ins, w)
+                return y, pull([ct])
+
+            ran.append(jax.jit(pulled)(ins, w, drawn(ins[0], 3)))
+    assert len(jax.tree_util.tree_leaves(ran[0])) == (7 if latent else 5)
+    for a, b in zip(*map(jax.tree_util.tree_leaves, ran)):
+        assert np.any(np.asarray(a) != 0)
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 @pytest.mark.parametrize("ladder", (False, True))
 def test_outside_training_nothing_is_tagged(two_blocks, monkeypatch, ladder):
     """A layer lowered with `ctx.training` false (every serving program, an
-    evaluation) has no `name` equation and no checkpoint, and is equation
-    for equation the layer with the tagging patched out; lowered for
-    training it names the six parts of the decision."""
+    evaluation) has no `name` equation, no checkpoint and no rule of its
+    own, and is equation for equation the layer with the tagging and the
+    rule patched out; lowered for training it names the six parts of the
+    decision and its result, and a block's rows are one `custom_vjp`."""
     two_blocks(rung_rows=4 if ladder else None)
     served = moe_layer_jaxpr(training=False)
     counts = primitives(served.jaxpr)
-    assert "name" not in counts and "remat2" not in counts
+    assert not {"name", "remat2", "custom_vjp_call"} & set(counts)
+    assert counts["cond"] == (1 if ladder else 0)
     trained = primitives(moe_layer_jaxpr(training=True).jaxpr)
-    # experts, their scores, routed, order, sizes, and the order's inverse
-    # in the branch that combines the whole block
-    assert trained["name"] == 6 and trained["remat2"] == 1
+    # experts, their scores, routed, order, sizes, the order's inverse (in
+    # a conditional of its own under the ladder), and `y`
+    assert trained["name"] == 7 and trained["remat2"] == 1
+    assert trained["custom_vjp_call"] == 1
+    assert trained["cond"] == (2 if ladder else 0)
     patch_out(monkeypatch)
+    monkeypatch.setattr(moe_ops, "_switched_rows", plain_switch)
     assert str(moe_layer_jaxpr(training=False)) == str(served)
-    assert "name" not in primitives(moe_layer_jaxpr(training=True).jaxpr)
+    untagged = primitives(moe_layer_jaxpr(training=True).jaxpr)
+    assert not {"name", "custom_vjp_call"} & set(untagged)
 
 
 def checkpoint_policies(monkeypatch, build):
@@ -298,7 +454,7 @@ def test_a_unit_whose_ops_name_nothing_has_no_policy(monkeypatch):
     whose flash call's residuals it keeps (PR 61; where the layer takes the
     einsum form nothing bears the name and nothing is kept). Over the
     expert model: the units that hold an expert layer, and only those,
-    keep `ROUTING_KEPT`."""
+    keep `ROUTING_KEPT` and `LAYER_KEPT` (PR 64: the layer's result)."""
     def gpt2(m):
         g = GPT2Config.tiny()
         build_gpt2(m, g, batch=2)
@@ -315,9 +471,10 @@ def test_a_unit_whose_ops_name_nothing_has_no_policy(monkeypatch):
         return g
 
     expert = checkpoint_policies(monkeypatch, afmoe)
-    assert expert.count((moe_ops.ROUTING_KEPT,)) == EXPERT_LAYERS
+    assert expert.count((moe_ops.ROUTING_KEPT, moe_ops.LAYER_KEPT)) \
+        == EXPERT_LAYERS
     assert expert.count((FLASH_KEPT,)) == ATTENTION_LAYERS
     assert expert.count(None) > EXPERT_LAYERS
     assert get_op_def(OperatorType.MOE_LAYER).kept_names \
-        == (moe_ops.ROUTING_KEPT,)
+        == (moe_ops.ROUTING_KEPT, moe_ops.LAYER_KEPT)
     assert get_op_def(OperatorType.LINEAR).kept_names == ()

@@ -112,6 +112,45 @@ def test_the_rows_kernel_against_the_grouped_product(case):
         assert np.abs(got[:live]).max() > 0.1
 
 
+def test_a_row_past_the_last_group_takes_no_cotangent(monkeypatch):
+    """PR 64, found on the chip: the grouped product writes nothing past
+    the last group there, and neither does its transpose, so the rows'
+    cotangent behind it was what the buffer held, and the layer's gather
+    added it to the tokens' gradient. Here `ragged_dot`'s transpose is
+    made to leave 1e4 in those rows, as the chip leaves what lay there;
+    the kernel's backward rule hands on zeros for them, and the rows of a
+    group what the grouped product gives."""
+    real = jax.lax.ragged_dot
+
+    def product(lhs, rhs, sizes):
+        return real(lhs, rhs, sizes)
+
+    def transposed(kept, ct):
+        lhs, rhs, sizes = kept
+        d_lhs, d_rhs = jax.vjp(lambda l, r: real(l, r, sizes), lhs, rhs)[1](ct)
+        stale = jnp.arange(lhs.shape[0])[:, None] >= jnp.sum(sizes)
+        return jnp.where(stale, 1e4, d_lhs), d_rhs, None
+
+    as_on_the_chip = jax.custom_vjp(product)
+    as_on_the_chip.defvjp(lambda *kept: (product(*kept), kept), transposed)
+    sizes, rows, _k, width, relu2, tn, _dtype = CASES[
+        "rows_past_the_last_group"]
+    x, sizes_, w_in, w_out = operands("rows_past_the_last_group")
+    ct = jnp.asarray(np.random.default_rng(1).normal(size=x.shape), x.dtype)
+
+    def d_rows(fn):
+        return np.asarray(jax.grad(lambda x: jnp.sum(fn(x) * ct))(x))
+
+    want = d_rows(lambda x: _grouped(width, relu2)(x, sizes_, w_in, w_out))
+    monkeypatch.setattr(jax.lax, "ragged_dot", as_on_the_chip)
+    got = d_rows(lambda x: moe_ops._rows_kernel(x, sizes_, w_in, w_out, relu2,
+                                                (TM, tn)))
+    live = sum(sizes)
+    assert not got[live:].any() and np.abs(got[:live]).max() > 0.1
+    assert np.abs(got[:live] - want[:live]).max() \
+        <= 1e-5 * np.abs(want[:live]).max()
+
+
 @pytest.mark.parametrize("sizes,rows", [
     ([0, 0, 0], 256), ([256, 0, 0], 256), ([0, 0, 256], 256),
     ([100, 100, 56], 256), ([1, 1, 1], 128), ([128, 128, 128], 384),
